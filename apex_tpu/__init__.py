@@ -25,8 +25,6 @@ XLA collectives over a device mesh (ICI/DCN) instead of NCCL process groups.
 
 import logging as _logging
 
-import apex_tpu._compat  # noqa: F401 — installs jax version aliases
-
 __version__ = "0.1.0"
 
 from apex_tpu.utils.logging import RankInfoFormatter, get_logger

@@ -48,6 +48,7 @@ __all__ = [
     "host_transfer_sites",
     "arg_shardings", "sharding_of", "assert_sharding",
     "spmd_collective_sites", "assert_spmd_collectives",
+    "pallas_kernels",
 ]
 
 #: collective ops that carry a reduction REGION in StableHLO — their
@@ -841,7 +842,11 @@ def spmd_collective_sites(artifact, kind: str) -> List[dict]:
     underscore spelling (``all_reduce``); compiled HLO prints dashes
     and may split async pairs — only the ``-start``/plain op counts,
     never the ``-done``."""
-    txt = _compiled_text(artifact)
+    # a combined (tuple-shaped) collective of more than five operands
+    # prints ``/*index=5*/`` markers inside its result type; their "="
+    # would end the type match below, hiding exactly the big fused
+    # grad all-reduce
+    txt = re.sub(r'/\*index=\d+\*/', '', _compiled_text(artifact))
     dashed = kind.replace("_", "-")
     sites = []
     for m in re.finditer(
@@ -899,6 +904,26 @@ def assert_spmd_collectives(artifact, kind: str, axes=None, mesh=None, *,
         assert not bad, (
             f"every matched {label} must run in {dtype}, found {bad}")
     return n
+
+
+# the kernel name sits last in the op-name stack, bare inside scans and
+# remat ("…/apex_ln_fwd/pallas_call") or wrapped by the transform that
+# produced the op ("jit(f)/transpose(jvp(apex_ln_bwd))/pallas_call")
+_PALLAS_CALL = re.compile(
+    r'custom_call_target="tpu_custom_call".*?'
+    r'op_name="[^"]*?([A-Za-z_][\w.\-]*)\)*/pallas_call"')
+
+
+def pallas_kernels(artifact) -> List[str]:
+    """Names of the Pallas (Mosaic) kernels a COMPILED TPU module holds:
+    one entry per ``tpu_custom_call`` instruction, in program order.
+
+    The name is the kernel's ``pallas_call(name=...)``, which rides the
+    instruction's op-name stack (``.../<name>/pallas_call``).  This is
+    the proof that a kernel made it into the executable — the fallback
+    registry's ``kernel_calls`` are counted at trace time, before a
+    deferred lowering failure can happen."""
+    return _PALLAS_CALL.findall(_compiled_text(artifact))
 
 
 def donated_buffer_count(artifact) -> int:

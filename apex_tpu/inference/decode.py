@@ -32,13 +32,16 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.inference.kv_cache import KVCacheConfig, write_prompt_kv
+from apex_tpu.inference.kv_cache import (
+    KVCacheConfig, alloc_pools, write_prompt_kv,
+)
 from apex_tpu.models.gpt import GPTConfig, forward_decode, gpt_forward
 from apex_tpu.ops.decode_sampling_pallas import fused_sample
 
 __all__ = [
-    "DecodeConfig", "make_decode_step", "make_prefill",
-    "make_prefill_chunk", "make_sample_head", "make_verify_step",
+    "DecodeConfig", "decode_logits_tokenwise", "make_decode_step",
+    "make_prefill", "make_prefill_chunk", "make_sample_head",
+    "make_verify_step",
 ]
 
 
@@ -283,3 +286,44 @@ def make_sample_head(config: GPTConfig, dcfg: DecodeConfig):
         return tok[0]
 
     return jax.jit(head)
+
+
+def decode_logits_tokenwise(params, config: GPTConfig, dcfg: DecodeConfig,
+                            tokens, prefix: int, page_table_row):
+    """The decode↔training parity probe: prefill ``tokens[:, :prefix]``
+    through the training forward, then decode positions ``prefix..S-1``
+    one token at a time through the jitted decode step
+    (``return_logits=True``, ``dcfg``'s attention impl and cache dtype).
+
+    ``tokens`` is (1, S); the sequence rides slot 0 of the
+    ``dcfg.max_batch`` slots, the rest stay inactive.  Returns the
+    (S - prefix, V) fp32 logits that
+    ``gpt_forward(params, tokens, config)[prefix:, 0]`` must match — to
+    reduction-reorder ulps in fp32, to the storage dtype's rounding with
+    a bf16 cache (tests/test_inference.py; ``chip_smoke.py`` runs it on
+    the compiled kernels)."""
+    S = tokens.shape[1]
+    B = dcfg.max_batch
+    _, kv = jax.jit(
+        lambda p, t: gpt_forward(p, t, config, return_kv=True))(
+            params, tokens)
+    ks = kv[0][:, 0].transpose(0, 2, 1, 3)[:, :prefix]  # (L, prefix, KVH, hd)
+    vs = kv[1][:, 0].transpose(0, 2, 1, 3)[:, :prefix]
+    pools = alloc_pools(config.num_layers, config.kv_heads, config.head_dim,
+                        dcfg.cache)
+    kp, vp = write_prompt_kv(pools["k"], pools["v"], ks, vs, page_table_row,
+                             jnp.int32(prefix))
+    pools = {"k": kp, "v": vp}
+    step = make_decode_step(config, dcfg, return_logits=True)
+    tables = jnp.zeros((B, page_table_row.shape[0]), jnp.int32) \
+        .at[0].set(page_table_row)
+    active = jnp.arange(B) == 0
+    seeds = jnp.zeros((B,), jnp.uint32)
+    out = []
+    for pos in range(prefix, S):
+        tok = jnp.zeros((B,), jnp.int32).at[0].set(tokens[0, pos])
+        pools, logits = step(params, pools, tok,
+                             jnp.full((B,), pos, jnp.int32), active,
+                             tables, seeds)
+        out.append(logits[0])
+    return jnp.stack(out)

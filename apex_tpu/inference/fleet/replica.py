@@ -174,7 +174,7 @@ class LocalReplica:
 
     def step(self) -> bool:
         """One scheduler step, with the chaos fault checks at the top
-        — where a real SIGKILL or dead tunnel would land, i.e. before
+        — where a real SIGKILL or hung dispatch would land, i.e. before
         any of this step's work becomes visible."""
         if self.state in ("dead", "starting") or self.sched is None:
             return False

@@ -3,8 +3,12 @@
 The serving-side memory manager (the vLLM PagedAttention layout,
 recast for TPU static shapes): the KV cache for ALL resident sequences
 lives in ONE preallocated pool per layer —
-``(num_layers, num_pages, page_size, kv_heads, head_dim)`` for each of
-k and v — and every sequence owns a *page table*: a fixed-width row of
+``(num_layers, num_pages, kv_heads, page_size, head_dim)`` for each of
+k and v (head-major pages: one (page, kv head) is a contiguous
+``(page_size, head_dim)`` tile, the block the decode-attention kernel
+DMAs — Mosaic needs a block's two minor dims whole or (8, 128)-aligned,
+which a one-head slice of a ``(kv_heads, head_dim)`` minor pair is
+not) — and every sequence owns a *page table*: a fixed-width row of
 page ids mapping its logical positions ``[p * page_size, (p+1) *
 page_size)`` onto pool pages.  Sequences of wildly different lengths
 pack the pool densely, admission/eviction recycles pages between
@@ -83,10 +87,10 @@ def pages_needed(total_positions: int, page_size: int) -> int:
 def alloc_pools(num_layers: int, kv_heads: int, head_dim: int,
                 cfg: KVCacheConfig) -> Dict[str, jnp.ndarray]:
     """Zero-initialized k/v pools:
-    ``(L, num_pages, page_size, kv_heads, head_dim)`` each, in the
+    ``(L, num_pages, kv_heads, page_size, head_dim)`` each, in the
     storage dtype.  Donated through the decode/prefill jits — the pool
     is updated in place across the whole serve loop."""
-    shape = (num_layers, cfg.num_pages, cfg.page_size, kv_heads, head_dim)
+    shape = (num_layers, cfg.num_pages, kv_heads, cfg.page_size, head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
@@ -202,7 +206,7 @@ def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
                     active):
     """Scatter one decode step's k/v into a layer's pools.
 
-    ``k_pool``/``v_pool``: (num_pages, page_size, H_kv, D);
+    ``k_pool``/``v_pool``: (num_pages, H_kv, page_size, D);
     ``k_new``/``v_new``: (B, H_kv, D) the current tokens' heads;
     ``page_tables``: (B, P) int32; ``positions``: (B,) the tokens'
     0-based positions; ``active``: (B,) bool — the WRITE mask (a
@@ -211,14 +215,16 @@ def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
     Inactive rows write the garbage page; all page-table reads are
     clamped (APX107).
     """
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+    num_pages, page_size = k_pool.shape[0], k_pool.shape[2]
     P = page_tables.shape[1]
     page_ix = jnp.clip(positions // page_size, 0, P - 1)
     rows = jnp.take_along_axis(page_tables, page_ix[:, None], axis=1)[:, 0]
     dest = jnp.where(active, jnp.clip(rows, 0, num_pages - 1), GARBAGE_PAGE)
     slot = jnp.where(active, positions % page_size, 0)
-    k_pool = k_pool.at[dest, slot].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[dest, slot].set(v_new.astype(v_pool.dtype))
+    # (dest, slot) are split by the head slice, so the indexed view is
+    # (B, H_kv, D) — k_new's own layout
+    k_pool = k_pool.at[dest, :, slot].set(k_new.astype(k_pool.dtype))
+    v_pool = v_pool.at[dest, :, slot].set(v_new.astype(v_pool.dtype))
     return k_pool, v_pool
 
 
@@ -226,7 +232,7 @@ def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
                     prompt_len, start=0):
     """Scatter a prefilled prompt's k/v into ALL layers' pools at once.
 
-    ``k_pool``/``v_pool``: (L, num_pages, page_size, H_kv, D);
+    ``k_pool``/``v_pool``: (L, num_pages, H_kv, page_size, D);
     ``k_stack``/``v_stack``: (L, S, H_kv, D) the training forward's
     per-layer post-RoPE keys/values for the (padded) prompt;
     ``page_table_row``: (P,) the sequence's page table;
@@ -236,7 +242,7 @@ def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
     positions' k/v already live in shared pool pages, which must not be
     rewritten through this sequence's table).
     """
-    num_pages, page_size = k_pool.shape[1], k_pool.shape[2]
+    num_pages, page_size = k_pool.shape[1], k_pool.shape[3]
     P = page_table_row.shape[0]
     S = k_stack.shape[1]
     s = jnp.arange(S, dtype=jnp.int32)
@@ -245,6 +251,10 @@ def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
     valid = (s >= start) & (s < prompt_len)
     dest = jnp.where(valid, jnp.clip(rows, 0, num_pages - 1), GARBAGE_PAGE)
     slot = jnp.where(valid, s % page_size, 0)
-    k_pool = k_pool.at[:, dest, slot].set(k_stack.astype(k_pool.dtype))
-    v_pool = v_pool.at[:, dest, slot].set(v_stack.astype(v_pool.dtype))
+    # advanced indices split by slices lead the indexed view: (S, L,
+    # H_kv, D)
+    k_pool = k_pool.at[:, dest, :, slot].set(
+        jnp.moveaxis(k_stack, 1, 0).astype(k_pool.dtype))
+    v_pool = v_pool.at[:, dest, :, slot].set(
+        jnp.moveaxis(v_stack, 1, 0).astype(v_pool.dtype))
     return k_pool, v_pool

@@ -61,7 +61,7 @@ training).
 
 Wedge resilience: a ``watchdog=`` (:class:`apex_tpu.resilience
 .StepWatchdog`) gets a heartbeat per scheduler step; a decode step that
-never returns (dead tunnel, hung collective) fires it — the scheduler's
+never returns (hung compile, hung collective) fires it — the scheduler's
 ``on_wedge`` hook logs every queued and in-flight request id
 (``serve.step_wedged`` — the requeue manifest for the layer above) and
 records ``apex_serve_wedges_total``, then the watchdog drains and exits
@@ -396,6 +396,19 @@ class ContinuousBatchingScheduler:
         any number of steps at any occupancy/length/draft-hit mix)."""
         step = self._verify if self.dcfg.draft_len > 0 else self._decode
         return step._cache_size()
+
+    def lower_decode_step(self):
+        """The plain decode step lowered at exactly the shapes the loop
+        runs it — for reading the compiled program (which kernels it
+        holds), beside :meth:`decode_cache_size`."""
+        if self._decode is None:
+            raise ValueError("speculative serving runs the verify step; "
+                             "there is no plain decode step to lower")
+        B = self.dcfg.max_batch
+        return self._decode.lower(
+            self.params, self.pools, jnp.asarray(self._tokens),
+            jnp.asarray(self._positions), jnp.asarray(self._active),
+            jnp.asarray(self._page_tables), jnp.zeros((B,), jnp.uint32))
 
     def _call(self, attr: str, *args):
         """Run a compiled step; on a deferred kernel-compile failure,
@@ -883,8 +896,8 @@ class ContinuousBatchingScheduler:
         monkey = active_monkey()
         if monkey is not None:
             # deterministic wedged-decode-step fault: the sleep holds
-            # THIS step past the watchdog deadline, exactly how a dead
-            # tunnel presents (plan key: decode steps taken so far)
+            # THIS step past the watchdog deadline, exactly how a hung
+            # dispatch presents (plan key: decode steps taken so far)
             monkey.maybe_wedge_step(self.stats["decode_steps"])
         admitted = self._admit()
         progressed = False

@@ -1,19 +1,22 @@
 """ctypes loader for the native runtime library.
 
-Builds ``native/apex_tpu_native.cpp`` with g++ on first use (cached in
-``native/build/``) and exposes flatten/unflatten/gather_rows.  Falls
-back to NumPy loops when no compiler is available — all callers must
-work either way (the reference's lazy-and-tolerant extension import
-pattern, ``apex/multi_tensor_apply/multi_tensor_apply.py:8-14``).
+Builds ``native/apex_tpu_native.cpp`` with g++ on first use and exposes
+flatten/unflatten/gather_rows.  The library file is named by a hash of
+the source (``native/build/libapex_tpu_native-<sha>.so``), so what gets
+loaded is always the source git has — never a stale or foreign ``.so``
+that happened to be on disk.  A failed build raises: the trainer's
+batch assembly and the checkpointer run on this library, and a silent
+drop to Python loops would change what a run measures without a word.
 """
 
 import contextlib
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 from pathlib import Path
-from typing import List, Optional
+from typing import List
 
 import numpy as np
 
@@ -59,50 +62,61 @@ def atomic_output(path):
 
 _REPO = Path(__file__).resolve().parents[2]
 _SRC = _REPO / "native" / "apex_tpu_native.cpp"
-_SO = _REPO / "native" / "build" / "libapex_tpu_native.so"
 
 _lock = threading.Lock()
 _lib = None
-_tried = False
 
 DEFAULT_THREADS = min(8, os.cpu_count() or 1)
 
 
-def _build() -> Optional[ctypes.CDLL]:
-    _SO.parent.mkdir(parents=True, exist_ok=True)
+def _so_path() -> Path:
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _REPO / "native" / "build" / f"libapex_tpu_native-{digest}.so"
+
+
+def _build(so: Path) -> None:
+    so.parent.mkdir(parents=True, exist_ok=True)
+    # build beside the target and rename: a concurrent process (the
+    # supervisor's child, a second replica) never loads a half-written file
+    tmp = so.with_name(f"{so.name}.{os.getpid()}.tmp")
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        str(_SRC), "-o", str(_SO),
+        str(_SRC), "-o", str(tmp),
     ]
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except Exception:
-        return None
-    return ctypes.CDLL(str(_SO))
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120,
+                       text=True)
+    except (OSError, subprocess.SubprocessError) as e:
+        tmp.unlink(missing_ok=True)
+        raise RuntimeError(
+            f"building the native library failed ({' '.join(cmd)}): {e}\n"
+            f"{getattr(e, 'stderr', '') or ''}") from e
+    os.replace(tmp, so)
 
 
-def get_lib() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+def get_lib() -> ctypes.CDLL:
+    """The loaded library, built from the current source if its hash-named
+    file is not there yet.  Raises when it cannot be built or loaded."""
+    global _lib
     with _lock:
-        if _tried:
-            return _lib
-        _tried = True
-        if _SO.exists():
-            try:
-                _lib = ctypes.CDLL(str(_SO))
-            except OSError:
-                _lib = _build()
-        else:
-            _lib = _build()
-        if _lib is not None:
-            _lib.apex_tpu_native_abi_version.restype = ctypes.c_int
-            if _lib.apex_tpu_native_abi_version() != 1:
-                _lib = None
+        if _lib is None:
+            so = _so_path()
+            if not so.exists():
+                _build(so)
+            lib = ctypes.CDLL(str(so))
+            lib.apex_tpu_native_abi_version.restype = ctypes.c_int
+            abi = lib.apex_tpu_native_abi_version()
+            if abi != 1:
+                raise RuntimeError(
+                    f"{so} reports ABI version {abi}; this loader speaks 1")
+            _lib = lib
         return _lib
 
 
 def available() -> bool:
-    return get_lib() is not None
+    """True once the library is built and loaded (raises if it cannot be)."""
+    get_lib()
+    return True
 
 
 def flatten(arrays: List[np.ndarray], threads: int = DEFAULT_THREADS) -> np.ndarray:
@@ -113,12 +127,6 @@ def flatten(arrays: List[np.ndarray], threads: int = DEFAULT_THREADS) -> np.ndar
     total = int(sizes.sum())
     out = np.empty(total, np.uint8)
     lib = get_lib()
-    if lib is None:
-        off = 0
-        for a, s in zip(arrays, sizes):
-            out[off : off + s] = a.view(np.uint8).reshape(-1)
-            off += int(s)
-        return out
     srcs = (ctypes.c_void_p * len(arrays))(
         *[a.ctypes.data_as(ctypes.c_void_p) for a in arrays]
     )
@@ -137,12 +145,6 @@ def unflatten(buf: np.ndarray, shapes, dtypes, threads: int = DEFAULT_THREADS) -
     outs = [np.empty(s, d) for s, d in zip(shapes, dtypes)]
     sizes = np.array([o.nbytes for o in outs], np.int64)
     lib = get_lib()
-    if lib is None:
-        off = 0
-        for o, s in zip(outs, sizes):
-            o.view(np.uint8).reshape(-1)[:] = buf[off : off + s]
-            off += int(s)
-        return outs
     buf = np.ascontiguousarray(buf)
     dsts = (ctypes.c_void_p * len(outs))(
         *[o.ctypes.data_as(ctypes.c_void_p) for o in outs]
@@ -164,9 +166,6 @@ def gather_rows(src: np.ndarray, indices: np.ndarray, threads: int = DEFAULT_THR
     n = len(indices)
     out = np.empty((n,) + src.shape[1:], src.dtype)
     lib = get_lib()
-    if lib is None:
-        np.take(src, indices, axis=0, out=out)
-        return out
     row_bytes = src[0].nbytes if src.shape[0] else 0
     lib.apex_tpu_gather_rows(
         src.ctypes.data_as(ctypes.c_void_p),

@@ -50,14 +50,12 @@ DEFAULT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
 
 
 def _rank() -> int:
-    """JAX process index, read lazily (metrics work before
-    ``jax.distributed.initialize`` and in no-jax contexts)."""
-    try:
-        import jax
+    """JAX process index; 0 while this process holds no backend (a
+    supervisor parent's metrics must not open the device)."""
+    from apex_tpu.utils.platform import process_rank
 
-        return int(jax.process_index())
-    except Exception:  # noqa: BLE001 — rank is best-effort decoration
-        return 0
+    rank = process_rank()
+    return 0 if rank is None else rank[0]
 
 
 def _label_key(labelnames: Sequence[str], labels: Dict[str, str]) -> Tuple:

@@ -56,8 +56,10 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
     """Single-query attention over a paged KV cache, in XLA.
 
     ``q``: (B, H, D) — one query per sequence (the current token's
-    heads).  ``k_pool``/``v_pool``: (num_pages, page_size, H_kv, D)
-    one layer's page pool.  ``page_table``: (B, P) int32 page ids,
+    heads).  ``k_pool``/``v_pool``: (num_pages, H_kv, page_size, D)
+    one layer's page pool (head-major pages: each (page, kv head) is one
+    contiguous (page_size, D) tile — the block the kernel DMAs).
+    ``page_table``: (B, P) int32 page ids,
     CLAMPED into the pool before the gather (a stale/garbage entry
     reads the reserved garbage page instead of wrapping).  ``lengths``:
     (B,) int32 valid cache positions per sequence (0 = inactive slot —
@@ -77,7 +79,7 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
     forward in fp32.
     """
     Bq, H, D = q.shape
-    num_pages, page_size, h_kv, _ = k_pool.shape
+    num_pages, h_kv, page_size, _ = k_pool.shape
     B, P = page_table.shape
     group = H // h_kv
     if B * width != Bq:
@@ -85,9 +87,9 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
             f"q rows ({Bq}) must equal page-table rows ({B}) x width "
             f"({width})")
     pt = jnp.clip(page_table, 0, num_pages - 1)
-    # (B, P, page, H_kv, D) -> (B, H_kv, S_max, D)
-    k = k_pool[pt].reshape(B, P * page_size, h_kv, D).transpose(0, 2, 1, 3)
-    v = v_pool[pt].reshape(B, P * page_size, h_kv, D).transpose(0, 2, 1, 3)
+    # (B, P, H_kv, page, D) -> (B, H_kv, S_max, D)
+    k = k_pool[pt].transpose(0, 2, 1, 3, 4).reshape(B, h_kv, P * page_size, D)
+    v = v_pool[pt].transpose(0, 2, 1, 3, 4).reshape(B, h_kv, P * page_size, D)
     if group > 1:
         k = jnp.repeat(k, group, axis=1)
         v = jnp.repeat(v, group, axis=1)
@@ -150,8 +152,8 @@ def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(p * page_size < length)
     def _compute():
         q = q_ref[0, 0]          # (group, D)
-        k = k_ref[0, :, 0, :]    # (page, D) — group-shared GQA page
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]          # (page, D) — group-shared GQA page
+        v = v_ref[0, 0]
         if k.dtype != q.dtype:
             # bf16 (or narrower) cache with an f32 query: widen the
             # cache read rather than rounding q down (APX306)
@@ -201,7 +203,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
     per verify width.
     """
     B, H, D = q.shape
-    num_pages, page_size, h_kv, _ = k_pool.shape
+    num_pages, h_kv, page_size, _ = k_pool.shape
     n_seq, P = page_table.shape
     if H % h_kv != 0:
         raise ValueError(f"q heads ({H}) not divisible by kv heads ({h_kv})")
@@ -218,9 +220,9 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
         .reshape(n_seq * P).astype(jnp.int32)
 
     kv_spec = pl.BlockSpec(
-        (1, page_size, 1, D),
+        (1, 1, page_size, D),
         lambda b, g, p, pt_ref, len_ref: (pt_ref[(b // width) * P + p],
-                                          0, g, 0),
+                                          g, 0, 0),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -248,6 +250,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
         out_shape=jax.ShapeDtypeStruct((B, h_kv, group, D), v_pool.dtype),
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
+        name="apex_decode_attention",
     )(pt, lengths.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(B, H, D)
 
@@ -257,11 +260,9 @@ def pallas_decode_attn_available(q, k_pool) -> bool:
     """Kernel path: real TPU, MXU-friendly head dim, sublane-aligned
     pages.  (No env-var override — thread ``attn_impl`` through
     :class:`apex_tpu.inference.DecodeConfig` instead; APX101/102.)"""
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-    return (on_tpu and q.shape[-1] % 8 == 0 and k_pool.shape[1] % 8 == 0
+    from apex_tpu.utils.platform import on_tpu
+
+    return (on_tpu() and q.shape[-1] % 8 == 0 and k_pool.shape[2] % 8 == 0
             and q.dtype in (jnp.float32, jnp.bfloat16))
 
 

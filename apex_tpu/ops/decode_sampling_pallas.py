@@ -71,7 +71,11 @@ def gumbel_from_seed(seeds, cols):
     and the double log never hits an infinity."""
     z = _hash_u32(seeds.astype(jnp.uint32)
                   ^ (cols.astype(jnp.uint32) * jnp.uint32(0x9E3779B9)))
-    u = ((z >> 8).astype(jnp.float32) + 0.5) * jnp.float32(1.0 / (1 << 24))
+    # the 24 kept bits fit int32, and Mosaic has no uint32 -> float32
+    # convert: go through int32 (same value, same stream in the kernel
+    # and in the XLA reference)
+    bits = (z >> 8).astype(jnp.int32)
+    u = (bits.astype(jnp.float32) + 0.5) * jnp.float32(1.0 / (1 << 24))
     return -jnp.log(-jnp.log(u))
 
 
@@ -223,17 +227,16 @@ def fused_sample_pallas(x2, embed, seeds, temperature=1.0, top_k=0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="apex_fused_sample",
     )(x2, embed, seeds.reshape(N, 1).astype(jnp.uint32))
     return tok[:, 0]
 
 
 # ---------------------------------------------------------------- dispatch
 def pallas_sample_available(x2, embed, top_k) -> bool:
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-    return (on_tpu and (not top_k or top_k <= MAX_KERNEL_TOP_K)
+    from apex_tpu.utils.platform import on_tpu
+
+    return (on_tpu() and (not top_k or top_k <= MAX_KERNEL_TOP_K)
             and x2.dtype in (jnp.float32, jnp.bfloat16))
 
 

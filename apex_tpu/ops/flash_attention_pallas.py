@@ -332,6 +332,7 @@ def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
         ],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
+        name="apex_flash_fwd",
     )
 
 
@@ -547,6 +548,7 @@ def _dq_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
+        name="apex_flash_dq",
     )
 
 
@@ -601,6 +603,7 @@ def _dkv_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
         ],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
+        name="apex_flash_dkv",
     )
 
 
@@ -684,14 +687,12 @@ def flash_attention_pallas(q, k, v, causal=True, softmax_scale=None,
 def pallas_flash_available(q, k) -> bool:
     """Kernel path: real TPU, lane-aligned sequence blocks, ≥8 head dim.
     Disable with APEX_TPU_PALLAS_ATTN=0."""
+    from apex_tpu.utils.platform import on_tpu
+
     if os.environ.get("APEX_TPU_PALLAS_ATTN", "1") == "0":
         return False
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
     return (
-        on_tpu
+        on_tpu()
         and q.shape[2] % 128 == 0
         and k.shape[2] % 128 == 0
         and q.shape[3] % 8 == 0

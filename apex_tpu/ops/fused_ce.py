@@ -74,12 +74,9 @@ def _pallas_mode() -> tuple:
         # would invalidate the exact A/B the knob exists for
         raise ValueError(f"APEX_TPU_FUSED_CE_PALLAS={env!r}: use 0/1, "
                          f"on/off, true/false, yes/no, auto, or interpret")
-    try:
-        if jax.devices()[0].platform == "tpu":
-            return "on", False
-    except Exception:  # noqa: BLE001 — no backend yet: scan path
-        pass
-    return "off", False
+    from apex_tpu.utils.platform import on_tpu
+
+    return ("on" if on_tpu() else "off"), False
 
 
 def _resolve_mode(impl) -> tuple:

@@ -177,6 +177,7 @@ def fused_ce_fwd_pallas(x2, embed, t, dot_dtype=None,
         scratch_shapes=[pltpu.VMEM((bn, _LANES), jnp.float32)] * 3,
         compiler_params=_DIMSEM_FWD,
         interpret=interpret,
+        name="apex_fused_ce_fwd",
     )(x2, embed, t.reshape(N, 1).astype(jnp.int32))
     return m[:, 0], l[:, 0], tgt[:, 0]
 
@@ -273,6 +274,7 @@ def fused_ce_bwd_pallas(x2, embed, t, lse, g, dot_dtype=None,
         scratch_shapes=[pltpu.VMEM((bn, H), jnp.float32)],
         compiler_params=_DIMSEM_DX,
         interpret=interpret,
+        name="apex_fused_ce_dx",
     )(x2, embed, t2, lse2, g2)
 
     vrow_spec = pl.BlockSpec((bn, 1), lambda i, j: (j, 0),
@@ -294,5 +296,6 @@ def fused_ce_bwd_pallas(x2, embed, t, lse, g, dot_dtype=None,
         scratch_shapes=[pltpu.VMEM((bv, H), jnp.float32)],
         compiler_params=_DIMSEM_DE,
         interpret=interpret,
+        name="apex_fused_ce_dembed",
     )(x2, embed, t2, lse2, g2)
     return dx, dembed

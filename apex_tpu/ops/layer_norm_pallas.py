@@ -101,6 +101,7 @@ def layer_norm_fwd_pallas(x2, weight, bias, eps, rms=False, block_r=DEFAULT_BLOC
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="apex_ln_fwd",
     )(*args)
     return y, mean, rstd
 
@@ -185,6 +186,7 @@ def layer_norm_bwd_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="apex_ln_bwd",
     )(*args)
     if not affine:
         return outs[0], None, None
@@ -199,10 +201,8 @@ def pallas_available(x2, normalized_size: int) -> bool:
     the fallback and is equally memory-bound)."""
     import os
 
+    from apex_tpu.utils.platform import on_tpu
+
     if os.environ.get("APEX_TPU_PALLAS_NORM", "1") == "0":
         return False
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-    return on_tpu and normalized_size % 128 == 0 and x2.dtype in (jnp.float32, jnp.bfloat16)
+    return on_tpu() and normalized_size % 128 == 0 and x2.dtype in (jnp.float32, jnp.bfloat16)

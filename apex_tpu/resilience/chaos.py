@@ -1,10 +1,9 @@
 """Deterministic fault injection for the resilience runtime.
 
-The faults this harness injects are the ones the project has actually
-suffered (VERDICT r5): NaN gradients mid-run, Pallas kernels dying at
-launch on hardware they were never proven on, preemptions that kill a
-run between checkpoint flushes, and tunnel wedges that hang a section
-forever.  Each is injected *deterministically* (a static plan, no RNG,
+The faults this harness injects: NaN gradients mid-run, Pallas kernels
+dying at launch, preemptions that kill a run between checkpoint
+flushes, and wedges (a hung collective, a hung compile) that hold a
+step forever.  Each is injected *deterministically* (a static plan, no RNG,
 no clocks) so the virtual 8-device mesh tests can assert exact recovery
 behavior — skip THIS step, fall back on THAT kernel, resume at exactly
 step k — today on CPU and unchanged on real TPU later.
@@ -120,7 +119,7 @@ class ChaosPlan:
     of N").  Per-rank, so a matrix test can kill host 2 of 4 and
     resume the survivors at world 3.
     ``wedge_step_at``: loop step whose dispatch wedges for
-    ``wedge_step_seconds`` (a hung whole-step: dead tunnel, compile
+    ``wedge_step_seconds`` (a hung whole-step: hung collective, compile
     hang) — the step-watchdog fault.
     ``wedge_collective_rank``/``wedge_collective_at_step``: ONE mesh
     rank sleeps ``wedge_collective_seconds`` INSIDE the compiled step,
@@ -294,7 +293,7 @@ class ChaosMonkey:
 
     def maybe_wedge_step(self, step) -> float:
         """Host-side whole-step wedge: sleep the planned seconds before
-        dispatching ``step`` (a dead tunnel / hung compile presents as
+        dispatching ``step`` (a hung collective / hung compile presents as
         the dispatch never returning).  Returns the seconds slept —
         the step watchdog should fire mid-sleep."""
         if self.plan.wedge_step_at is None \
@@ -327,7 +326,7 @@ class ChaosMonkey:
 
     def maybe_wedge_replica(self, replica_id: str, step: int) -> bool:
         """True exactly once, at the planned (replica, step): the
-        replica's decode dispatch has wedged (dead tunnel shape) — the
+        replica's decode dispatch has wedged (hung-dispatch shape) — the
         caller runs the watchdog path (``serve.step_wedged`` manifest,
         exit 75) instead of sleeping a real watchdog out."""
         planned = self.plan.wedge_replica_at.get(str(replica_id))

@@ -22,7 +22,7 @@ cross-replica-sharded state layout of arxiv 2004.13336:
   mismatch fails loudly.
 - **Step watchdog** (:class:`StepWatchdog`): a heartbeat thread that
   notices a step exceeding its deadline (wedged collective, hung
-  Pallas compile, dead tunnel), emits a structured
+  Pallas compile), emits a structured
   ``watchdog.step_wedged`` record, drains the async checkpointer (so
   every ACCEPTED save is durable — the wedged step itself is lost by
   definition), and exits with :data:`EXIT_WEDGED` so a supervisor

@@ -2,8 +2,8 @@
 
 Reference: ``apex/__init__.py:32-43`` (``RankInfoFormatter``) and
 ``apex/transformer/log_util.py``.  On TPU the "rank" is the JAX process
-index plus the local device set, read lazily so logging works before
-``jax.distributed.initialize``.
+index, shown only once this process holds a backend: formatting a log
+line must never open the device (see ``utils/platform.process_rank``).
 """
 
 import json
@@ -12,12 +12,10 @@ import sys
 
 
 def _rank_info() -> str:
-    try:
-        import jax
+    from apex_tpu.utils.platform import process_rank
 
-        return f"[p{jax.process_index()}/{jax.process_count()}]"
-    except Exception:
-        return "[p?/?]"
+    rank = process_rank()
+    return "[p-/-]" if rank is None else f"[p{rank[0]}/{rank[1]}]"
 
 
 class RankInfoFormatter(logging.Formatter):

@@ -37,6 +37,7 @@ Runs out of the box on the virtual CPU mesh (synthetic data):
 """
 
 import argparse
+import json
 import os
 import sys
 import time
@@ -49,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=8)
     p.add_argument("--tp", type=int, default=1)
@@ -85,6 +86,10 @@ def parse_args():
     p.add_argument("--fused-ce", action="store_true",
                    help="chunked fused LM-head+CE: never materializes "
                         "the fp32 (S,B,V) logits (ops/fused_ce.py)")
+    p.add_argument("--flash-attention", action="store_true",
+                   help="flash attention core (ops/attention.py): the "
+                        "Pallas kernels on TPU, the scan composite "
+                        "elsewhere")
     p.add_argument("--checkpoint", default=None, help="save dir (async)")
     p.add_argument("--save-every", type=int, default=4)
     p.add_argument("--keep", type=int, default=3,
@@ -163,11 +168,17 @@ def parse_args():
     from apex_tpu.resilience.supervisor import add_supervisor_args
 
     add_supervisor_args(p)
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Run the trainer; returns what the run is judged by — per-step
+    losses, the device, per-device memory, the kernel-fallback registry,
+    the first step's dispatch (trace + compile) seconds, the seconds of
+    each backend compile of the step (one entry = compiled once), and
+    ``lower`` (a thunk lowering the step the loop ran, for reading its
+    compiled text)."""
+    args = parse_args(argv)
 
     if args.supervise:
         # the self-healing outer loop: relaunch THIS command (minus the
@@ -181,7 +192,12 @@ def main():
             raise SystemExit("--supervise needs --auto-resume with "
                              "--checkpoint: a restarted child that does "
                              "not resume would retrain from step 0")
-        raise SystemExit(run_supervised_cli(args))
+        raise SystemExit(run_supervised_cli(
+            args, argv=(None if argv is None else [__file__, *argv])))
+
+    from apex_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from apex_tpu import io, resilience
     from apex_tpu.amp import DynamicLossScaler
@@ -219,6 +235,7 @@ def main():
         sequence_parallel=args.sequence_parallel,
         position_embedding_type="rope" if args.rope else "learned",
         num_query_groups=args.num_query_groups,
+        use_flash_attention=args.flash_attention,
         fused_ce=args.fused_ce,
         # largest divisor of seq <= 128, so the flag always engages
         # (the gpt_loss guard silently falls back on indivisibility)
@@ -358,13 +375,16 @@ def main():
                 w_steps * args.global_batch * args.seq / max(dt, 1e-9))
         last_window_wall[0], last_window_wall[1] = now, at_step
 
+    losses = []  # per step, in step order (the fetcher is FIFO)
+
     def emit_harvested(kind, at_step, tree):
         """Print/record one harvested async fetch (host numpy values —
         the loop never touches device scalars)."""
         if kind == "loss":
             extra = (f" scale={float(tree['scale']):.0f}"
                      if "scale" in tree else "")
-            print(f"step {at_step}: loss={float(tree['loss']):.4f}{extra}",
+            losses.append(float(tree["loss"]))
+            print(f"step {at_step}: loss={losses[-1]:.4f}{extra}",
                   flush=True)
         else:  # a StepStats window
             s = stepstats.StepTelemetry.emit(registry, tree)
@@ -656,7 +676,7 @@ def main():
             wedge_step_seconds=args.chaos_wedge_secs,
         ))
 
-    # step watchdog: a wedged step (hung collective, dead tunnel) gets
+    # step watchdog: a wedged step (hung collective, hung compile) gets
     # one structured log, a bounded drain of the async queue, and the
     # distinct exit 75 so a supervisor restarts with backoff
     def on_wedge(info):
@@ -822,9 +842,44 @@ def main():
                 step = build_step()
         return step(*step_args)
 
+    if not multiproc:
+        # lay params and optimizer state out as the step shards them
+        # BEFORE the first call: fresh (or restored) arrays sit unsharded
+        # on device 0, step 0's outputs come back sharded, and a step 1
+        # fed those would compile the whole step a second time
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        def placed(tree, spec_tree):
+            return jax.device_put(tree, jax.tree.map(
+                lambda spec: NamedSharding(mesh, spec), spec_tree,
+                is_leaf=lambda x: isinstance(x, P)))
+
+        specs = ckpt_specs()
+        params = placed(params, specs["params"])
+        state = placed(state, specs["state"])
+        # the small replicated carries ride the same rule
+        if scaler_state is not None:
+            scaler_state = jax.device_put(scaler_state,
+                                          NamedSharding(mesh, P()))
+        if stats is not None:
+            stats = jax.device_put(stats, NamedSharding(mesh, P()))
+
+    # every backend compile of the train step, in seconds: one entry is
+    # the contract (a second means the step's inputs changed layout or
+    # shape under the loop)
+    step_compile_s = []
+
+    def on_compile(event, duration, fun_name=None, **_):
+        if event.endswith("backend_compile_duration") \
+                and fun_name == f"jit({step.__name__})":
+            step_compile_s.append(round(duration, 2))
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
     t0 = time.time()
     last_saved = None
     done = 0
+    first_step_s = None
     for i in range(start_step, start_step + args.steps):
         done = i - start_step + 1
         # heartbeat + chaos delivery (wedge: the watchdog fires
@@ -837,7 +892,12 @@ def main():
             batch = next(prefetch)
         tokens = jnp.asarray(batch[:, :-1])
         targets = jnp.asarray(batch[:, 1:])
+        t_dispatch = time.time()
         out = run_step(tokens, targets)
+        if first_step_s is None:
+            # jit traces and compiles inside the first call, before the
+            # async dispatch returns: this is the step's set-up time
+            first_step_s = time.time() - t_dispatch
         params, state = out[0], out[1]
         k = 2
         if scaler is not None:
@@ -887,6 +947,7 @@ def main():
                   f"step {i}; rerun the same command to resume",
                   flush=True)
             break
+    jax.monitoring.unregister_event_duration_listener(on_compile)
     if watchdog is not None:
         watchdog.stop()  # the loop is done; the queue flush below may
         # legitimately outlast a step deadline
@@ -910,8 +971,6 @@ def main():
         (Path(args.metrics_dir) / f"metrics{rank_sfx}.prom").write_text(
             registry.prometheus_text())
     if acct is not None:  # process 0 owns the goodput record
-        import json
-
         from apex_tpu.observability import goodput as gp
 
         acct.finalize("preempted" if (pre is not None and pre.preempted)
@@ -939,7 +998,26 @@ def main():
     dt = time.time() - t0
     print(f"{done} steps in {dt:.1f}s "
           f"({args.global_batch * args.seq * done / dt:.0f} tokens/s)")
+    # where the run is judged: the device it ran on and whether any
+    # kernel degraded to its reference along the way
+    from apex_tpu.utils.platform import device_facts, device_memory
+
+    result = {"steps": done, "losses": losses,
+              "first_step_s": first_step_s,
+              "step_compile_s": step_compile_s,
+              "device": device_facts(),
+              "memory": device_memory(params=params, opt_state=state),
+              "kernel_fallback": resilience.get_registry().status()}
+    for key in ("step_compile_s", "device", "memory", "kernel_fallback"):
+        print(f"{key}: " + json.dumps(result[key]), flush=True)
+    final_args = [params, state]
+    if scaler is not None:
+        final_args.append(scaler_state)
+    if stats is not None:
+        final_args.append(stats)
+    result["lower"] = lambda: step.lower(*final_args, tokens, targets)
+    return result
 
 
 if __name__ == "__main__":
-    main()
+    main()  # the returned summary is for callers; it is printed above

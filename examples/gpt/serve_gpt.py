@@ -139,7 +139,7 @@ def build_args():
                         "decode step")
     p.add_argument("--watchdog-secs", type=float, default=None,
                    help="serving step watchdog: a decode step exceeding "
-                        "this many seconds (dead tunnel, wedged "
+                        "this many seconds (hung compile, wedged "
                         "collective) logs every queued/in-flight request "
                         "id (the requeue manifest), records "
                         "apex_serve_wedges_total, and exits 75 so a "
@@ -242,29 +242,11 @@ def check_greedy_parity(params, config, completions, max_check=3):
             seq.append(tok)
 
 
-def main(argv=None):
-    args = build_args().parse_args(argv)
-    if args.supervise:
-        # same self-healing outer loop as the trainer (no checkpoint
-        # dir: a serving restart is stateless — the wedge manifest in
-        # the logs is what a frontend replays)
-        from apex_tpu.resilience.supervisor import run_supervised_cli
-
-        return run_supervised_cli(args, argv=(None if argv is None
-                                              else [sys.argv[0], *argv]),
-                                  checkpoint_dir=None)
-    if args.smoke:
-        # tiny, deterministic, greedy: the CPU acceptance contract
-        args.layers, args.hidden, args.heads, args.vocab = 2, 64, 4, 128
-        args.streams, args.requests, args.arrival_rate = 3, 7, 0.0
-        args.prompt_len, args.max_new = 8, 4
-        args.page_size, args.kv_dtype = 4, "float32"
-        args.temperature, args.top_k = 0.0, 0
-        if args.attn_impl == "pallas":
-            args.attn_impl = "interpret"
-        if args.sample_impl == "pallas":
-            args.sample_impl = "interpret"
-
+def build_scheduler(args, watchdog=None, anomaly=None):
+    """The model, the KV cache and the scheduler exactly as ``main``
+    serves them, from a parsed ``build_args()`` namespace.  Returns
+    ``(scheduler, params, config)``.  ``chip_smoke.py`` drives its own
+    request mix through the scheduler this builds."""
     total_prompt = args.system_prompt_len + args.prompt_len
     config = GPTConfig(
         vocab_size=args.vocab, hidden_size=args.hidden,
@@ -276,7 +258,6 @@ def main(argv=None):
         compute_dtype=jnp.float32 if args.smoke else jnp.bfloat16,
         checkpoint_layers=False,
     )
-    rng = np.random.RandomState(args.seed)
     params = init_params(config, jax.random.PRNGKey(args.seed))
 
     # worst-case footprint: full prompt + generation budget + the
@@ -302,6 +283,35 @@ def main(argv=None):
         ngram_min=args.ngram_min, prefill_chunk=args.prefill_chunk,
         prefix_sharing=args.prefix_sharing,
     )
+    sched = ContinuousBatchingScheduler(params, config, dcfg,
+                                        watchdog=watchdog, anomaly=anomaly)
+    return sched, params, config
+
+
+def main(argv=None):
+    args = build_args().parse_args(argv)
+    if args.supervise:
+        # same self-healing outer loop as the trainer (no checkpoint
+        # dir: a serving restart is stateless — the wedge manifest in
+        # the logs is what a frontend replays)
+        from apex_tpu.resilience.supervisor import run_supervised_cli
+
+        return run_supervised_cli(
+            args, argv=(None if argv is None else [__file__, *argv]),
+            checkpoint_dir=None)
+
+    from apex_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    if args.smoke:
+        # tiny, deterministic, greedy: the CPU acceptance contract
+        # (kernel impls stay as asked — a CPU caller passes "interpret")
+        args.layers, args.hidden, args.heads, args.vocab = 2, 64, 4, 128
+        args.streams, args.requests, args.arrival_rate = 3, 7, 0.0
+        args.prompt_len, args.max_new = 8, 4
+        args.page_size, args.kv_dtype = 4, "float32"
+        args.temperature, args.top_k = 0.0, 0
+
     from apex_tpu.observability import (
         AnomalyMonitor, get_metrics, set_step_context,
     )
@@ -350,10 +360,9 @@ def main(argv=None):
             wedge_step_at=args.chaos_wedge_decode_step,
             wedge_step_seconds=args.chaos_wedge_secs))
 
-    sched = ContinuousBatchingScheduler(params, config, dcfg,
-                                        watchdog=watchdog,
-                                        anomaly=anomaly)
-    reqs, arrivals = make_requests(args, rng)
+    sched, params, config = build_scheduler(args, watchdog=watchdog,
+                                            anomaly=anomaly)
+    reqs, arrivals = make_requests(args, np.random.RandomState(args.seed))
 
     t0 = time.monotonic()
     if monkey is not None:
@@ -365,9 +374,16 @@ def main(argv=None):
     if watchdog is not None:
         watchdog.stop()
 
+    from apex_tpu.resilience.fallback import get_registry
+    from apex_tpu.utils.platform import device_facts
+
     out = report(completions, wall)
     out["stats"] = dict(sched.stats)
     out["decode_compiles"] = sched.decode_cache_size()
+    # where the run is judged: the device it ran on and whether any
+    # kernel degraded to its reference along the way
+    out["device"] = device_facts()
+    out["kernel_fallback"] = get_registry().status()
     if args.draft_len > 0:
         out["accepted_tokens_per_step"] = round(
             sched.stats["spec_emitted"]

@@ -40,6 +40,9 @@ def main():
 
     import jax
 
+    from apex_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     jax.distributed.initialize(
         coordinator_address=args.coordinator,
         num_processes=args.num_processes,

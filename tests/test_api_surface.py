@@ -331,7 +331,7 @@ class TestGroupGetters:
             assert parallel_state.get_amax_reduction_group() == parallel_state.TENSOR_AXIS
 
     def test_group_usable_in_collective(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         with parallel_state_ctx(tp=4):
             mesh = parallel_state.get_mesh()
@@ -351,7 +351,7 @@ class TestGroupGetters:
     def test_multislice_mesh_and_hierarchical_dp_group(self):
         """num_distributed_slices splits dp into (dcn, dp); the dp group
         spans both axes so one psum is the hierarchical reduction."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         with parallel_state_ctx(tp=2, slices=2):
             mesh = parallel_state.get_mesh()
@@ -380,7 +380,7 @@ class TestGroupGetters:
         parallel_state.destroy_model_parallel()
 
     def test_masked_psum_sums_members_only(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         with parallel_state_ctx(pp=4):
             mesh = parallel_state.get_mesh()
@@ -407,7 +407,7 @@ class TestGroupGetters:
             np.testing.assert_array_equal(np.asarray(out2), [10.0] * 4)
 
     def test_model_parallel_group_is_axis_tuple(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         with parallel_state_ctx(tp=2, pp=2):
             mesh = parallel_state.get_mesh()

@@ -1090,8 +1090,8 @@ class TestQuantizedGradSync:
     def test_compressed_state_mismatch_fails_loudly(self, devices8):
         """The remainder-master discipline, mirrored: compressed state
         into an uncompressed optimizer (and the reverse) is refused by
-        every load path — and the raw-pytree trace path fails naming
-        the residual field, never a shape crash mid-math."""
+        every load path — and the raw-pytree trace path fails at trace
+        time, never a shape crash mid-math."""
         params = make_tree(6)
         mesh = Mesh(np.array(devices8[:2]), ("dp",))
         opt_q = DistributedFusedAdam(lr=1e-2, axis_name="dp",
@@ -1111,12 +1111,21 @@ class TestQuantizedGradSync:
         with pytest.raises(ValueError, match="residual_kind"):
             DistributedFusedAdam.load_sharded_state_dicts(
                 shards, world_size=2, grad_sync_dtype=None)
-        # raw-pytree trace path: the state/spec trees disagree exactly
-        # at the residual field and jax names it
-        with pytest.raises(ValueError, match="residual"):
+        # raw-pytree trace path: the state and spec trees disagree at
+        # the residual field, so shard_map's own in_specs check refuses
+        # the call at trace time, before any math (jax 0.9 words it
+        # "pytree structure error: different lengths of tuple" and no
+        # longer prints the field's name) ...
+        with pytest.raises(ValueError, match="pytree structure"):
             zero_step(opt_w, mesh, params, s_q, g)
-        with pytest.raises(ValueError, match="residual"):
+        with pytest.raises(ValueError, match="pytree structure"):
             zero_step(opt_q, mesh, params, s_w, g)
+        # ... and the optimizer's own check, behind that boundary, names
+        # the residual and the knob
+        with pytest.raises(ValueError, match="residual.*grad_sync_dtype"):
+            opt_w._check_residual_state(opt_w._plan, s_q.residual)
+        with pytest.raises(ValueError, match="grad_sync_dtype.*residual"):
+            opt_q._check_residual_state(opt_q._plan, s_w.residual)
 
     def test_quantized_state_spec_and_wire_accounting(self, devices8):
         """Residuals ride the state spec (donatable like m/v) at full

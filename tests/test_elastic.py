@@ -404,7 +404,7 @@ class TestPodChaos:
         assert fired[0]["exit_code"] == resilience.EXIT_WEDGED
 
     def test_host_side_step_wedge(self):
-        """The whole-step dispatch wedge (dead tunnel shape): the plan
+        """The whole-step dispatch wedge (hung-dispatch shape): the plan
         sleeps at exactly the armed step."""
         monkey = ChaosMonkey(ChaosPlan.make(wedge_step_at=2,
                                             wedge_step_seconds=0.2))

@@ -205,7 +205,7 @@ def test_scaled_moe_trains_with_dp_vote(devices8):
 def test_found_inf_vote_spans_given_axes(devices8):
     """One rank's overflow must veto the step on EVERY rank of every
     sync axis (the dp-sharded-expert-grads / ZeRO-local-grads case)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from apex_tpu.transformer.amp.grad_scaler import sync_found_inf

@@ -185,12 +185,6 @@ def test_t5_fused_matches_dense():
         got_g, ref_g)
 
 
-@pytest.mark.xfail(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax 0.4.x CPU: accumulation-order noise on one fc2 element "
-           "exceeds the post-Adam rtol (g/sqrt(v) amplifies tiny-grad "
-           "differences); strict on the chip image's newer jax",
-    strict=False)
 def test_pp_fused_matches_dense_oracle(devices8):
     """The pipeline post-stage head (models/gpt.py post_fn) must produce
     the same loss/params through the fused path as the dense oracle."""

@@ -25,7 +25,9 @@ from apex_tpu.inference import (
     PageAllocator, Request, alloc_pools, pages_needed, write_decode_kv,
     write_prompt_kv,
 )
-from apex_tpu.inference.decode import make_decode_step, make_prefill
+from apex_tpu.inference.decode import (
+    decode_logits_tokenwise, make_decode_step, make_prefill,
+)
 from apex_tpu.models.gpt import (
     GPTConfig, forward_decode, gpt_forward, init_params, param_specs,
 )
@@ -52,26 +54,10 @@ def tiny_cfg(**kw):
 
 def _decode_logits_tokenwise(params, cfg, tokens, prefix, kcfg, pt_row,
                              attn_impl="xla"):
-    """Prefill ``tokens[:prefix]`` through the training forward, then
-    decode positions ``prefix..S-1`` one token at a time, returning the
-    per-position fp32 logits."""
-    S = tokens.shape[1]
-    _, kv = gpt_forward(params, tokens[:, :S], cfg, return_kv=True)
-    ks = kv[0][:, 0].transpose(0, 2, 1, 3)[:, :prefix]
-    vs = kv[1][:, 0].transpose(0, 2, 1, 3)[:, :prefix]
-    pools = alloc_pools(cfg.num_layers, cfg.kv_heads, cfg.head_dim, kcfg)
-    kp, vp = write_prompt_kv(pools["k"], pools["v"], ks, vs, pt_row,
-                             jnp.int32(prefix))
-    pools = {"k": kp, "v": vp}
-    out = []
-    for pos in range(prefix, S):
-        hidden, pools = forward_decode(
-            params, tokens[:, pos], jnp.asarray([pos], jnp.int32),
-            jnp.asarray([True]), pools, pt_row[None], cfg,
-            attn_impl=attn_impl)
-        out.append(jnp.matmul(hidden.astype(jnp.float32),
-                              params["embed"].T.astype(jnp.float32))[0])
-    return jnp.stack(out)  # (S - prefix, V)
+    return decode_logits_tokenwise(
+        params, cfg,
+        DecodeConfig(cache=kcfg, max_batch=1, attn_impl=attn_impl),
+        tokens, prefix, pt_row)
 
 
 # ------------------------------------------------------ prefill <-> decode
@@ -155,7 +141,7 @@ class TestDecodeParity:
         kcfg = KVCacheConfig(num_pages=6, page_size=4, pages_per_seq=3,
                              dtype=jnp.float32)
         mesh = Mesh(np.array(devices8[:2]).reshape(2, 1), ("tp", "dp"))
-        pool_spec = P(None, None, None, "tp", None)
+        pool_spec = P(None, None, "tp", None, None)
         pools = alloc_pools(cfg.num_layers, cfg.kv_heads, cfg.head_dim, kcfg)
         pt_row = jnp.asarray([[1, 2, 3]], jnp.int32)
 
@@ -187,8 +173,8 @@ class TestDecodeParity:
 class TestDecodeAttentionKernel:
     def _case(self, rng, B=3, H=4, KVH=2, D=16, num_pages=9, page=8, P=4):
         q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
-        kp = jnp.asarray(rng.randn(num_pages, page, KVH, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(num_pages, page, KVH, D), jnp.float32)
+        kp = jnp.asarray(rng.randn(num_pages, KVH, page, D), jnp.float32)
+        vp = jnp.asarray(rng.randn(num_pages, KVH, page, D), jnp.float32)
         pt = jnp.asarray(rng.randint(1, num_pages, size=(B, P)), jnp.int32)
         return q, kp, vp, pt
 
@@ -325,7 +311,7 @@ class TestKVCache:
 
     def test_inactive_decode_write_hits_garbage_page_only(self):
         rng = np.random.RandomState(0)
-        kp = jnp.asarray(rng.randn(4, 2, 1, 8), jnp.float32)
+        kp = jnp.asarray(rng.randn(4, 1, 2, 8), jnp.float32)
         vp = kp + 1
         k_new = jnp.ones((2, 1, 8))
         pt = jnp.asarray([[2], [3]], jnp.int32)
@@ -336,15 +322,15 @@ class TestKVCache:
         np.testing.assert_array_equal(np.asarray(nv[1:]), np.asarray(vp[1:]))
 
     def test_prompt_pad_tail_hits_garbage_page_only(self):
-        kp = jnp.zeros((2, 5, 4, 1, 8))
+        kp = jnp.zeros((2, 5, 1, 4, 8))
         ks = jnp.ones((2, 6, 1, 8))
         row = jnp.asarray([2, 3], jnp.int32)
         nk, _ = write_prompt_kv(kp, kp, ks, ks, row, jnp.int32(5))
         # positions 0..4 land in pages 2 (0..3) and 3 (slot 0); the
         # padded position 5 must NOT touch page 3 slot 1
-        assert float(jnp.sum(jnp.abs(nk[:, 3, 1:]))) == 0.0
+        assert float(jnp.sum(jnp.abs(nk[:, 3, :, 1:]))) == 0.0
         assert float(jnp.sum(nk[:, 2])) == 4 * 8 * 2
-        assert float(jnp.sum(nk[:, 3, 0])) == 8 * 2
+        assert float(jnp.sum(nk[:, 3, :, 0])) == 8 * 2
 
 
 # -------------------------------------------------------------- scheduler

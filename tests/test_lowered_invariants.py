@@ -878,6 +878,20 @@ class TestGspmdTrainStep:
                                    minimum=1)
         lw.assert_spmd_collectives(txt, "reduce_scatter", maximum=0)
 
+    def test_combined_all_reduce_with_index_markers_is_read(self):
+        """XLA prints ``/*index=5*/`` inside the result type of a
+        combined collective with more than five operands — the fused
+        grad all-reduce.  The site parser must still see it (it once
+        read this module as "no dp sync at all")."""
+        txt = ("  %all-reduce.118 = (f32[16]{0}, f32[32]{0}, f32[16]{0}, "
+               "f32[16]{0}, f32[64,32]{1,0}, /*index=5*/f32[64]{0}) "
+               "all-reduce(%a, %b, %c, %d, %e, /*index=5*/%f), "
+               "channel_id=7, replica_groups=[2,4]<=[4,2]T(1,0), "
+               "use_global_device_ids=true, to_apply=%add.clone\n")
+        assert lw.spmd_collective_sites(txt, "all_reduce") == [
+            {"dtype": "f32",
+             "replica_groups": [[0, 2, 4, 6], [1, 3, 5, 7]]}]
+
     def test_donation_survives_spmd_compilation(self, gspmd):
         """donate_state=True must alias params AND optimizer state
         through the PARTITIONED executable — the APX208 hazard
@@ -1182,7 +1196,7 @@ class TestDivergenceRuleProof:
 
     SRC = """
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def grad_sync(g):
@@ -1218,7 +1232,7 @@ class TestDivergenceRuleProof:
         ``if``: rank 0's trace launches the psum, rank 1's skips it.
         ``assert_same_collective_schedule`` names the divergence — the
         proof the static rule's deadlock claim rests on."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.array(devices8).reshape(DP), ("dp",))
         sync = shard_map(lambda x: jax.lax.psum(x, "dp"), mesh=mesh,

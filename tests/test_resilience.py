@@ -492,9 +492,8 @@ class TestPreemptionHandler:
 
 # ------------------------------------------------- bench.py fault paths
 class TestBenchHarness:
-    """The wedge/timeout seams in bench.py, driven by chaos — the
-    subprocess section runner is what lets a ResNet-50 compile wedge
-    bank its partials without killing the later sections."""
+    """The wedge/timeout and failure seams of bench.py's section
+    runner, driven by chaos."""
 
     @pytest.fixture(autouse=True)
     def _bench(self, tmp_path, monkeypatch):
@@ -516,51 +515,19 @@ class TestBenchHarness:
         assert "timeout" in r["error"]
         assert self.bench._DEVICE_WEDGED  # in-process: thread unkillable
 
-    def test_subprocess_section_timeout_does_not_wedge_device(self):
-        import sys
+    def test_failed_section_is_recorded_and_counted(self, monkeypatch):
+        """A section that raises is recorded as an error AND lands in
+        ``_FAILED`` — what makes bench.py exit non-zero instead of
+        printing a clean-looking JSON around the hole."""
+        monkeypatch.setattr(self.bench, "_FAILED", [])
 
-        r = self.bench._try_subprocess(
-            "resnet50_b64", section_budget=1.0,
-            cmd=[sys.executable, "-c", "import time; time.sleep(30)"])
-        assert "timeout" in r["error"]
-        assert not self.bench._DEVICE_WEDGED  # the wedge died with the child
+        def boom():
+            raise ValueError("no such kernel")
 
-    def test_subprocess_section_result_round_trip(self):
-        import sys
-
-        child = ("import json; print('noise'); print(json.dumps("
-                 "{'section': 'resnet50_b64', "
-                 "'result': {'images_per_sec': 9.0}}))")
-        r = self.bench._try_subprocess("resnet50_b64", section_budget=30.0,
-                                       cmd=[sys.executable, "-c", child])
-        assert r == {"images_per_sec": 9.0}
-
-    def test_subprocess_device_acquisition_failure_retries_in_process(
-            self, monkeypatch):
-        """Exclusive local TPU: the parent process owns the chip, so no
-        child can ever acquire it — the section retries in-process (the
-        only way to get a number there) instead of failing every round."""
-        import sys
-
-        monkeypatch.setitem(self.bench._SUBPROCESS_SECTIONS,
-                            "resnet50_b64",
-                            lambda: {"images_per_sec": 7.0})
-        r = self.bench._try_subprocess(
-            "resnet50_b64", section_budget=30.0,
-            cmd=[sys.executable, "-c",
-                 "import sys; print('The TPU is already in use by another "
-                 "process', file=sys.stderr); sys.exit(1)"])
-        assert r == {"images_per_sec": 7.0}
-        assert not self.bench._DEVICE_WEDGED
-
-    def test_subprocess_child_crash_is_recorded_not_raised(self):
-        import sys
-
-        r = self.bench._try_subprocess(
-            "resnet50_b64", section_budget=30.0,
-            cmd=[sys.executable, "-c",
-                 "import sys; print('dying', file=sys.stderr); sys.exit(3)"])
-        assert "rc=3" in r["error"]
+        r = self.bench._try("broken", boom, section_budget=5.0)
+        assert r == {"error": "ValueError: no such kernel"}
+        assert self.bench._try("fine", lambda: {"v": 1}) == {"v": 1}
+        assert self.bench._FAILED == ["broken"]
 
 
 # --------------------------------------------------- end-to-end survival

@@ -3289,7 +3289,7 @@ class TestCliPerformanceAndHygiene:
 #: collective inside a shard_map step
 _STEP_PRELUDE = textwrap.dedent("""
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def grad_sync(g):

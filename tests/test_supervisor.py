@@ -12,6 +12,8 @@ kill → wedge → corrupt-checkpoint) lives in tests/test_gpt_example.py.
 import json
 import random
 import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -519,3 +521,35 @@ class TestSeams:
     def test_fault_dataclass_defaults(self):
         f = SupervisorFault()
         assert f.extra_args == () and not f.corrupt_newest_checkpoint
+
+
+def test_restart_cycle_leaves_parent_without_backend(tmp_path):
+    """A chip belongs to one process: the supervisor parent logs,
+    counts metrics and backs off between two real children, and must
+    come out of that restart cycle WITHOUT a JAX backend — a parent that
+    initialised one (formatting a log record used to) would take the
+    device from the child it is about to spawn.  Runs in a fresh
+    interpreter, since this one already holds the CPU backend."""
+    child = ("import os, sys; p = sys.argv[1]\n"
+             "if os.path.exists(p): sys.exit(0)\n"
+             "open(p, 'w').close(); sys.exit(137)")
+    parent = f"""
+import json, sys
+from jax._src import xla_bridge
+from apex_tpu.observability import metrics
+from apex_tpu.resilience import Supervisor
+sup = Supervisor([sys.executable, "-c", {child!r}, {str(tmp_path / "marker")!r}],
+                 metrics_dir={str(tmp_path)!r}, run_id="t",
+                 backoff_base=0.01, backoff_cap=0.01)
+rc = sup.run()
+metrics.get_metrics().prometheus_text()
+print(json.dumps({{"rc": rc, "restarts": sup.restarts,
+                  "backend": xla_bridge.backends_are_initialized()}}))
+"""
+    r = subprocess.run([sys.executable, "-c", parent], capture_output=True,
+                       text=True, timeout=120,
+                       cwd=str(Path(__file__).resolve().parents[1]))
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out == {"rc": 0, "restarts": 1, "backend": False}, (out, r.stderr)
+    assert "supervisor.restarting" in r.stderr  # it did log in between

@@ -323,7 +323,7 @@ class TestTensorParallelAttributes:
         """With axis_name, per-rank sharded views psum norm² over the
         group (reference utils.py:234-238 all-reduces across mp)."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from apex_tpu.transformer.pipeline_parallel.utils import calc_params_l2_norm
 
@@ -343,7 +343,7 @@ class TestTensorParallelAttributes:
         group (traced axis_index-0 weighting), sharded leaves from every
         rank — matching reference utils.py:217-238 filter-then-allreduce."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from apex_tpu.transformer.pipeline_parallel.utils import calc_params_l2_norm
         from apex_tpu.transformer.tensor_parallel import attributes_tree
@@ -370,7 +370,7 @@ class TestTensorParallelAttributes:
         the tp axis only, so every pp rank's slice counts (the reference
         filters TP duplicates then all-reduces over the full mp group)."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from apex_tpu.transformer.pipeline_parallel.utils import calc_params_l2_norm
         from apex_tpu.transformer.tensor_parallel import attributes_tree

@@ -1,0 +1,226 @@
+"""What can be proven about the chip path without a chip.
+
+- Every Pallas kernel lowers for a TPU at the shapes ``chip_smoke.py``
+  runs (``jax.export(platforms=["tpu"])``: the Pallas→Mosaic lowering,
+  where ``kv_heads > 1`` and ``temperature > 0`` used to fail), and,
+  where this installation's libtpu can build a compile-only v5e
+  client, compiles through the Mosaic backend with its kernel name in
+  the executable.
+- The compile-cache rule, the one platform probe, and ``chip_smoke.py``'s
+  refusal to run without a TPU.
+
+Whether the compiled kernels compute the right thing is ``chip_smoke.py``'s
+job, on the chip.
+"""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import export as jexport
+
+from apex_tpu.ops.decode_attention_pallas import paged_decode_attention_pallas
+from apex_tpu.ops.decode_sampling_pallas import fused_sample_pallas
+from apex_tpu.ops.flash_attention_pallas import flash_attention_pallas
+from apex_tpu.ops.fused_ce_pallas import (
+    fused_ce_bwd_pallas, fused_ce_fwd_pallas,
+)
+from apex_tpu.ops.layer_norm_pallas import (
+    layer_norm_bwd_pallas, layer_norm_fwd_pallas,
+)
+
+REPO = Path(__file__).resolve().parents[1]
+BF16, F32, I32, U32 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.uint32
+VOCAB = 50304
+
+
+def _decode_attn(heads, kv_heads, width):
+    B, D, P, page, pages = 8, 64, 12, 16, 97
+    pool = ((pages, kv_heads, page, D), BF16)
+    return (lambda q, k, v, pt, n: paged_decode_attention_pallas(
+        q, k, v, pt, n, width=width),
+        [((B * width, heads, D), BF16), pool, pool, ((B, P), I32),
+         ((B * width,), I32)])
+
+
+def _sample(temperature, top_k, rows=8):
+    return (lambda x, e, s: fused_sample_pallas(
+        x, e, s, temperature=temperature, top_k=top_k),
+        [((rows, 768), BF16), ((VOCAB, 768), F32), ((rows,), U32)])
+
+
+def _flash(heads, kv_heads):
+    q, kv = ((8, heads, 1024, 64), BF16), ((8, kv_heads, 1024, 64), BF16)
+    return (jax.grad(lambda q, k, v: flash_attention_pallas(q, k, v)
+                     .astype(F32).sum(), argnums=(0, 1, 2)), [q, kv, kv])
+
+
+def _layer_norm(rows, hidden):
+    def fwd_bwd(x, w, b, dy):
+        y, mean, rstd = layer_norm_fwd_pallas(x, w, b, 1e-5)
+        return y, layer_norm_bwd_pallas(x, w, dy, mean, rstd)
+
+    return (fwd_bwd, [((rows, hidden), BF16), ((hidden,), F32),
+                      ((hidden,), F32), ((rows, hidden), BF16)])
+
+
+def _fused_ce(vocab):
+    def fwd_bwd(x, e, t, g):
+        m, l, tgt = fused_ce_fwd_pallas(x, e, t)
+        return tgt, fused_ce_bwd_pallas(x, e, t, m + jnp.log(l), g)
+
+    return (fwd_bwd, [((8192, 1024), BF16), ((vocab, 1024), F32),
+                      ((8192,), I32), ((8192,), F32)])
+
+
+#: name -> (fn, [(shape, dtype)...], kernel names the executable must hold)
+CASES = {
+    # serving, GPT-124M heads; 345M heads; GQA; MQA; the verify width
+    "decode_attn_mha12": (*_decode_attn(12, 12, 1), {"apex_decode_attention"}),
+    "decode_attn_mha16": (*_decode_attn(16, 16, 1), {"apex_decode_attention"}),
+    "decode_attn_gqa16_4": (*_decode_attn(16, 4, 1), {"apex_decode_attention"}),
+    "decode_attn_mqa": (*_decode_attn(12, 1, 1), {"apex_decode_attention"}),
+    "decode_attn_verify5": (*_decode_attn(12, 12, 5),
+                            {"apex_decode_attention"}),
+    "sample_greedy": (*_sample(0.0, 0), {"apex_fused_sample"}),
+    "sample_t1": (*_sample(1.0, 0), {"apex_fused_sample"}),
+    "sample_t1_top40": (*_sample(1.0, 40), {"apex_fused_sample"}),
+    "sample_t1_top40_verify5": (*_sample(1.0, 40, rows=40),
+                                {"apex_fused_sample"}),
+    # training, GPT-345M and GPT-124M shapes
+    "flash_345m": (*_flash(16, 16),
+                   {"apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv"}),
+    "flash_124m": (*_flash(12, 12),
+                   {"apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv"}),
+    "flash_gqa16_4": (*_flash(16, 4),
+                      {"apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv"}),
+    "layer_norm_train": (*_layer_norm(8192, 1024),
+                         {"apex_ln_fwd", "apex_ln_bwd"}),
+    "layer_norm_decode": (*_layer_norm(8, 768), {"apex_ln_fwd", "apex_ln_bwd"}),
+    "fused_ce": (*_fused_ce(VOCAB), {"apex_fused_ce_fwd", "apex_fused_ce_dx",
+                                     "apex_fused_ce_dembed"}),
+    "fused_ce_tp2_shard": (*_fused_ce(VOCAB // 2),
+                           {"apex_fused_ce_fwd", "apex_fused_ce_dx",
+                            "apex_fused_ce_dembed"}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_lowers_for_tpu(name):
+    fn, avals, _ = CASES[name]
+    exp = jexport.export(jax.jit(fn), platforms=["tpu"])(
+        *[jax.ShapeDtypeStruct(s, d) for s, d in avals])
+    assert "tpu_custom_call" in exp.mlir_module()
+
+
+_AOT_CHILD = """
+import json, os, sys
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+import jax
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    dev = topologies.get_topology_desc(
+        topology_name="v5e:2x2", platform="tpu").devices[0]
+except Exception as e:  # no compile-only TPU client on this installation
+    print(json.dumps({"skip": f"{type(e).__name__}: {e}"[:300]}))
+    sys.exit(0)
+sys.path.insert(0, "tests")
+from test_tpu_bringup import CASES
+from apex_tpu.analysis.lowered import pallas_kernels
+out = {}
+for name, (fn, avals, want) in sorted(CASES.items()):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=SingleDeviceSharding(dev))
+            for s, d in avals]
+    try:
+        found = set(pallas_kernels(jax.jit(fn).lower(*args).compile()))
+        out[name] = sorted(want - found)
+    except Exception as e:
+        out[name] = f"{type(e).__name__}: {e}"[:600]
+print(json.dumps({"device_kind": dev.device_kind, "missing": out}))
+"""
+
+
+def test_kernels_compile_for_v5e_without_a_chip():
+    """The full XLA:TPU + Mosaic backend compile of every case, on a
+    compile-only v5e client (libtpu builds one from a topology name; no
+    device is opened).  In a child: libtpu stays out of this process."""
+    r = subprocess.run([sys.executable, "-c", _AOT_CHILD], cwd=str(REPO),
+                       capture_output=True, text=True, timeout=600,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(f"no compile-only TPU client: {out['skip']}")
+    bad = {k: v for k, v in out["missing"].items() if v}
+    assert not bad, (f"on {out['device_kind']}: kernels that failed to "
+                     f"compile, or compiled without their name: {bad}")
+
+
+# ----------------------------------------------------------- compile cache
+#: the config option's name, spelled so that a grep for it over the tree
+#: still finds only the helper
+_OPTION = "jax_compilation_" "cache_dir"
+
+
+def _cache_dir_seen_by_child(env):
+    code = ("import jax; from apex_tpu.utils.compile_cache import "
+            "enable_compile_cache as e; p = e(); "
+            f"print(p); print(getattr(jax.config, {_OPTION!r}))")
+    r = subprocess.run([sys.executable, "-c", code], cwd=str(REPO), env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return r.stdout.strip().splitlines()[-2:]
+
+
+def test_compile_cache_env_wins(tmp_path):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+    assert _cache_dir_seen_by_child(env) == [str(tmp_path), str(tmp_path)]
+
+
+def test_compile_cache_defaults_into_the_checkout():
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    want = str(REPO / ".jax_cache")
+    assert _cache_dir_seen_by_child(env) == [want, want]
+
+
+def test_no_other_code_sets_a_cache_dir():
+    """A grep for the option over the tree finds only the helper."""
+    hits = [str(p.relative_to(REPO))
+            for p in REPO.rglob("*.py")
+            if not {".chip_scratch", "chiprun_out"} & set(p.parts)
+            and _OPTION in p.read_text()]
+    assert hits == ["apex_tpu/utils/compile_cache.py"], hits
+
+
+# ------------------------------------------------------------- chip_smoke
+def test_chip_smoke_refuses_to_run_on_cpu():
+    r = subprocess.run([sys.executable, str(REPO / "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=300,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode != 0
+    assert "needs a TPU" in r.stderr and "cpu" in r.stderr
+    assert r.stdout.strip() == "", "no result may be printed without a chip"
+
+
+def test_platform_probe_lets_backend_errors_out(monkeypatch):
+    """``on_tpu`` must not turn "the backend failed to start" into "not
+    a TPU, use the reference"."""
+    from apex_tpu.utils import platform
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        platform.on_tpu()
