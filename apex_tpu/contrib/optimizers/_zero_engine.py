@@ -1007,30 +1007,6 @@ class ZeroOptimizerBase:
         out["hops"] = hops
         return out
 
-    def sync_plan_hops(self):
-        """Per-``(bucket, hop)`` wire records — the trace-side spelling
-        of :meth:`wire_bytes_per_step` (``tracing.emit_sync_plan``
-        emits one ``zero_sync.bucket<k>.hop_<axis>`` marker per record,
-        so span duration ÷ hop bytes bounds the per-hop achieved
-        bandwidth).  One record per bucket on a flat plan, two (inner,
-        outer) on a hierarchical one."""
-        plan = self._require_plan()
-        hier = self._hier_plan
-        out = []
-        for i, b in enumerate(plan.buckets):
-            hop_bytes = qs.grad_sync_bytes(
-                b.total, self._grad_dtype(b), hier=hier,
-                flat_hop=self.axis_name)
-            for hop, hb in hop_bytes.items():
-                out.append({
-                    "bucket": i, "hop": hop,
-                    "bucket_dtype": str(jnp.dtype(b.dtype)),
-                    "wire_dtype": str(jnp.dtype(self._grad_dtype(b))),
-                    "payload_bytes": int(hb["payload"]),
-                    "scale_bytes": int(hb["scales"]),
-                })
-        return out
-
     def _state_arrays(self, state) -> Dict[str, Sequence]:
         """name -> per-bucket arrays, in the subclass's field order."""
         return {f: getattr(state, f) for f in state._fields if f != "step"}

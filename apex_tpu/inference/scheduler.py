@@ -116,7 +116,10 @@ class Request:
     ``submit`` when the caller did not bring one — it is stamped on
     every span and latency-histogram exemplar the request produces, so
     a p99 outlier in ``apex_serve_ttft_seconds`` joins back to this
-    request's admission-wait/prefill/decode spans."""
+    request's admission-wait/prefill/decode spans.  ``blocked_on`` is
+    the scheduler's: why this request last blocked at the head of its
+    queue (``"slot"``, ``"pages"``, or None when it never waited as
+    head)."""
 
     rid: int
     prompt: List[int]
@@ -124,19 +127,27 @@ class Request:
     eos_id: Optional[int] = None
     lane: str = "interactive"
     trace_id: Optional[str] = None
+    blocked_on: Optional[str] = None
 
 
 @dataclasses.dataclass
 class Completion:
-    """A finished request with its wall-clock trace: ``token_times[i]``
-    is when ``tokens[i]`` became available (``token_times[0]`` is the
-    prefill / time-to-first-token).  ``preemptions`` counts how often a
+    """Finished request: ``submit_time`` at submit(), ``admit_time`` at admission.
+
+    ``submit_time`` is when ``submit()`` took the request,
+    ``admit_time`` when a slot and its pages were reserved (for a
+    preempted request, both of the FIRST leg), and ``token_times[i]``
+    when ``tokens[i]`` was on the host.
+    So ``admit_time - submit_time`` is the queue, ``token_times[0] -
+    admit_time`` the prefill, and ``token_times[0] - submit_time`` the
+    time to first token.  ``preemptions`` counts how often a
     best-effort generation was evicted-and-requeued on the way."""
 
     rid: int
     prompt: List[int]
     tokens: List[int]
     submit_time: float
+    admit_time: float
     finish_time: float
     token_times: List[float]
     lane: str = "interactive"
@@ -170,13 +181,14 @@ class ManifestEntry:
 @dataclasses.dataclass
 class _Carry:
     """Cross-preemption continuation state for one rid: the ORIGINAL
-    prompt and submit time, plus tokens/times already emitted by
-    earlier residency legs."""
+    prompt, submit and admit times, plus tokens/times already emitted
+    by earlier residency legs."""
 
     prompt: List[int]
     tokens: List[int]
     times: List[float]
     submit_time: float
+    admit_time: float
     preemptions: int = 0
 
 
@@ -186,9 +198,9 @@ class _Slot:
     pages: List[int]               # page-table entries, in index order
     generated: List[int]
     token_times: List[float]
-    submit_time: float
+    submit_time: float             # when submit() took it (TTFT base)
+    admit_time: float              # slot and pages reserved
     admit_seq: int = 0             # admission order (preemption picks max)
-    submitted_at: float = 0.0      # true submit wall-time (TTFT base)
     shared_len: int = 0            # prompt positions served by shared pages
     cow_reserve: Optional[int] = None
     chunk_next: Optional[int] = None  # next prompt position to chunk-prefill
@@ -246,7 +258,13 @@ class ContinuousBatchingScheduler:
             "preemptions": 0, "chunk_steps": 0, "cow_copies": 0,
             "shared_full_pages": 0, "shared_tail_pages": 0,
             "spec_steps": 0, "spec_emitted": 0,
+            # admission passes on which the head of a queue blocked,
+            # by what it lacked
+            "admit_blocked_slot": 0, "admit_blocked_pages": 0,
         }
+        #: prefills run since the last decode/verify step ended: above
+        #: zero, that step's token gap holds a prefill as well
+        self._prefills_since_step = 0
         self._rebuilt_once = False
         self._draining = False
         # record-only uniformity seam: the serve config shapes every
@@ -258,9 +276,8 @@ class ContinuousBatchingScheduler:
             "decode": dataclasses.asdict(dcfg),
             "model": dataclasses.asdict(config),
         })
-        #: true submit wall-time per queued rid (Completion.submit_time
-        #: is the ADMIT time for driver compatibility; the metrics
-        #: histograms — admission wait, TTFT — need the real submit)
+        #: submit wall-time per queued rid, until admission moves it
+        #: into the slot
         self._submit_times: Dict[int, float] = {}
         #: per-lane SLO-burn detection (an
         #: :class:`~apex_tpu.observability.anomaly.AnomalyMonitor`):
@@ -574,16 +591,31 @@ class ContinuousBatchingScheduler:
         return total, match, total - match.num_full
 
     def _admit(self) -> int:
-        if self._draining:
+        queued = len(self.queue) + len(self.be_queue)
+        if self._draining or not queued:
+            # nothing to plan, and no span: an empty server calls
+            # step() every millisecond and would flood the ring
             return 0
-        admitted = self._admit_from(self.queue, can_preempt=True)
-        if not self.queue:
-            # best-effort fills leftover capacity only while no
-            # interactive request waits (the lane priority contract)
-            admitted += self._admit_from(self.be_queue, can_preempt=False)
+        self._admit_span = _tracing.span("serve.admit")
+        with self._admit_span:
+            admitted, blocked_on = self._admit_from(
+                self.queue, can_preempt=True)
+            if not self.queue:
+                # best-effort fills leftover capacity only while no
+                # interactive request waits (the lane priority
+                # contract), so at most one head blocks a pass
+                more, blocked_on = self._admit_from(
+                    self.be_queue, can_preempt=False)
+                admitted += more
+            self._admit_span.set(queued=queued, admitted=admitted,
+                                 blocked_on=blocked_on)
         return admitted
 
-    def _admit_from(self, queue: deque, can_preempt: bool) -> int:
+    def _admit_from(self, queue: deque, can_preempt: bool):
+        """Admit from the head of ``queue`` while heads fit.  Returns
+        ``(admitted, blocked_on)``: what the head that stopped the pass
+        lacked (``"slot"`` or ``"pages"``), None when the queue
+        emptied."""
         admitted = 0
         while queue:
             req = queue[0]
@@ -596,11 +628,14 @@ class ContinuousBatchingScheduler:
                     continue  # trie refs dropped — re-plan and retry
                 if can_preempt and self._preempt_one():
                     continue  # a best-effort resident yielded — retry
-                break  # FIFO: the head blocks, nothing overtakes it
+                # FIFO: the head blocks, nothing overtakes it
+                req.blocked_on = "slot" if slot is None else "pages"
+                self.stats["admit_blocked_" + req.blocked_on] += 1
+                return admitted, req.blocked_on
             queue.popleft()
             self._admit_into(slot, req, total, match, need_fresh)
             admitted += 1
-        return admitted
+        return admitted, None
 
     def _admit_into(self, slot: int, req: Request, total: int,
                     match: PrefixMatch, need_fresh: int) -> None:
@@ -614,10 +649,12 @@ class ContinuousBatchingScheduler:
                          lane=req.lane)
         tracer = _tracing.get_tracer()
         if tracer is not None:
-            # both endpoints are known only now — retro-emit the wait
+            # both endpoints are known only now — retro-emit the wait,
+            # caused by the admission pass that ended it
             tracer.emit("serve.admission_wait", self._epoch(submitted),
-                        t0 - submitted, rid=req.rid,
-                        trace_id=req.trace_id, lane=req.lane)
+                        t0 - submitted, parent=self._admit_span.id,
+                        rid=req.rid, trace_id=req.trace_id,
+                        lane=req.lane, blocked_on=req.blocked_on)
         fresh = self.allocator.allocate(need_fresh)
         assert fresh is not None  # _admit_from checked can_allocate
         if match.num_full:
@@ -639,8 +676,8 @@ class ContinuousBatchingScheduler:
         plen = len(req.prompt)
         self._admit_counter += 1
         s = _Slot(request=req, pages=table, generated=[],
-                  token_times=[], submit_time=t0,
-                  admit_seq=self._admit_counter, submitted_at=submitted,
+                  token_times=[], submit_time=submitted, admit_time=t0,
+                  admit_seq=self._admit_counter,
                   shared_len=match.shared_len, cow_reserve=cow_reserve)
         self._slots[slot] = s
         self.stats["admitted"] += 1
@@ -653,20 +690,27 @@ class ContinuousBatchingScheduler:
             return
         prompt = np.zeros((1, self.dcfg.max_prompt_len), np.int32)
         prompt[0, :plen] = req.prompt
+        # the span ends when the first token is ON THE HOST (the
+        # readback waits for the device); dispatch_us is the enqueue
         with _tracing.span("serve.prefill", rid=req.rid,
                            trace_id=req.trace_id, lane=req.lane,
                            prompt_len=plen,
-                           shared_len=match.shared_len):
+                           shared_len=match.shared_len) as sp:
             self.pools, first = self._call(
                 "_prefill", self.params, self.pools,
                 jnp.asarray(prompt), jnp.int32(plen),
                 jnp.int32(match.shared_len), jnp.asarray(row),
                 jnp.uint32(self._seed(slot)))
-        self.stats["prefills"] += 1
-        self._start_decoding(slot, int(first), submitted)
+            sp.set(dispatch_us=int(sp.elapsed() * 1e6))
+            first = int(first)
+        self._prefill_done()
+        self._start_decoding(slot, first)
 
-    def _start_decoding(self, slot: int, first: int,
-                        submitted: float) -> None:
+    def _prefill_done(self) -> None:
+        self.stats["prefills"] += 1
+        self._prefills_since_step += 1
+
+    def _start_decoding(self, slot: int, first: int) -> None:
         """Common prefill epilogue (classic and chunked): record the
         first token, index the prompt's full pages into the prefix
         trie, arm the slot for decode, and evict degenerate (1-token /
@@ -674,6 +718,7 @@ class ContinuousBatchingScheduler:
         s = self._slots[slot]
         req = s.request
         t_first = self._time()
+        submitted = s.submit_time
         _metrics.observe("apex_serve_ttft_seconds", t_first - submitted,
                          help="submit -> first token (prefill incl. queue)",
                          exemplar={"trace_id": req.trace_id,
@@ -717,7 +762,8 @@ class ContinuousBatchingScheduler:
         c = self._carry.get(req.rid)
         if c is None:
             c = _Carry(prompt=list(req.prompt), tokens=[], times=[],
-                       submit_time=s.submit_time)
+                       submit_time=s.submit_time,
+                       admit_time=s.admit_time)
             self._carry[req.rid] = c
         c.preemptions += 1
         remaining = req.max_new_tokens - len(s.generated)
@@ -777,24 +823,29 @@ class ContinuousBatchingScheduler:
             + list(s.generated)
         times = (list(c.times) if c is not None else []) \
             + list(s.token_times)
-        submit = c.submit_time if c is not None else s.submit_time
+        first_leg = c if c is not None else s
+        submit, admit = first_leg.submit_time, first_leg.admit_time
         self._release_slot(slot)
         finish = self._time()
         self.completed.append(Completion(
             rid=s.request.rid, prompt=prompt, tokens=tokens,
-            submit_time=submit, finish_time=finish,
+            submit_time=submit, admit_time=admit, finish_time=finish,
             token_times=times, lane=s.request.lane,
             preemptions=c.preemptions if c is not None else 0,
             trace_id=s.request.trace_id))
         tracer = _tracing.get_tracer()
         if tracer is not None:
-            # the whole-lifetime span (admit-time submit -> eviction):
-            # what the TTFT-exemplar trace_id joins to
+            # the whole-lifetime span (submit -> eviction), what the
+            # TTFT-exemplar trace_id joins to, with the first token
+            # taken apart: queue_s + prefill_s == ttft_s
             tracer.emit(
                 "serve.request", self._epoch(submit), finish - submit,
                 rid=s.request.rid, trace_id=s.request.trace_id,
                 lane=s.request.lane, tokens=len(tokens),
+                queue_s=round(admit - submit, 6),
+                prefill_s=round(times[0] - admit, 6) if times else None,
                 ttft_s=round(times[0] - submit, 6) if times else None,
+                blocked_on=s.request.blocked_on,
                 preemptions=c.preemptions if c is not None else 0)
         self.stats["evicted"] += 1
         _metrics.inc("apex_serve_completions_total",
@@ -820,24 +871,29 @@ class ContinuousBatchingScheduler:
             n_valid = min(C, plen - start)
             tok = np.zeros((C,), np.int32)
             tok[:n_valid] = s.request.prompt[start:start + n_valid]
+            last = start + n_valid >= plen
+            # a chunk that is not the last is left in flight (its span
+            # times the enqueue); the last one's span ends when the
+            # first token is on the host
             with _tracing.span("serve.prefill_chunk", rid=s.request.rid,
                                trace_id=s.request.trace_id,
                                lane=s.request.lane, chunk_start=start,
-                               chunk_tokens=n_valid):
+                               chunk_tokens=n_valid, last=last):
                 self.pools, h_last = self._call(
                     "_chunk", self.params, self.pools, jnp.asarray(tok),
                     jnp.int32(start), jnp.int32(n_valid),
                     jnp.int32(s.shared_len),
                     jnp.asarray(self._page_tables[i]))
+                if last:
+                    first = int(self._call(
+                        "_sample_head", self.params, h_last,
+                        jnp.uint32(self._seed(i))))
             self.stats["chunk_steps"] += 1
             s.chunk_next = start + n_valid
             progressed = True
-            if s.chunk_next >= plen:
-                first = int(self._call(
-                    "_sample_head", self.params, h_last,
-                    jnp.uint32(self._seed(i))))
-                self.stats["prefills"] += 1
-                self._start_decoding(i, first, s.submitted_at)
+            if last:
+                self._prefill_done()
+                self._start_decoding(i, first)
         return progressed
 
     # ------------------------------------------------------------- COW
@@ -925,41 +981,51 @@ class ContinuousBatchingScheduler:
         # the serving path and the off case must stay near-zero
         attrs = (dict(decode_step=self.stats["decode_steps"],
                       active=int(self._active.sum()),
-                      trace_ids=self._active_trace_ids())
+                      trace_ids=self._active_trace_ids(),
+                      prefills_before=self._prefills_since_step)
                  if _tracing.enabled() else {})
-        with _tracing.span("serve.decode_step", **attrs):
+        with _tracing.span("serve.decode_step", **attrs) as sp:
             self.pools, next_tokens = self._call(
                 "_decode", self.params, self.pools,
                 jnp.asarray(self._tokens), jnp.asarray(self._positions),
                 jnp.asarray(self._active), jnp.asarray(self._page_tables),
                 jnp.asarray(seeds))
+            sp.set(dispatch_us=int(sp.elapsed() * 1e6))
             next_tokens = np.asarray(next_tokens)
-        now = self._time()
-        self.stats["decode_steps"] += 1
-        self._record_occupancy()
-        for i in range(B):
-            if not self._active[i]:
-                continue
-            s = self._slots[i]
-            tok = int(next_tokens[i])
-            _metrics.observe("apex_serve_inter_token_seconds",
-                             now - s.token_times[-1],
-                             help="previous token -> this token",
-                             exemplar={"trace_id": s.request.trace_id,
-                                       "rid": s.request.rid},
-                             lane=s.request.lane)
-            if self._anomaly is not None:
-                self._anomaly.observe("inter_token",
-                                      now - s.token_times[-1],
-                                      lane=s.request.lane)
-            s.generated.append(tok)
-            s.token_times.append(now)
-            self._tokens[i] = tok
-            self._positions[i] += 1
-            if (len(s.generated) >= s.request.max_new_tokens
-                    or (s.request.eos_id is not None
-                        and tok == s.request.eos_id)):
-                self._evict(i)
+        self._prefills_since_step = 0
+        # readback -> end of the step: token bookkeeping, histogram
+        # observations, evictions
+        with _tracing.span("serve.emit") as emit_span:
+            now = self._time()
+            self.stats["decode_steps"] += 1
+            self._record_occupancy()
+            tokens, evicted = 0, self.stats["evicted"]
+            for i in range(B):
+                if not self._active[i]:
+                    continue
+                s = self._slots[i]
+                tok = int(next_tokens[i])
+                _metrics.observe("apex_serve_inter_token_seconds",
+                                 now - s.token_times[-1],
+                                 help="previous token -> this token",
+                                 exemplar={"trace_id": s.request.trace_id,
+                                           "rid": s.request.rid},
+                                 lane=s.request.lane)
+                if self._anomaly is not None:
+                    self._anomaly.observe("inter_token",
+                                          now - s.token_times[-1],
+                                          lane=s.request.lane)
+                s.generated.append(tok)
+                s.token_times.append(now)
+                tokens += 1
+                self._tokens[i] = tok
+                self._positions[i] += 1
+                if (len(s.generated) >= s.request.max_new_tokens
+                        or (s.request.eos_id is not None
+                            and tok == s.request.eos_id)):
+                    self._evict(i)
+            emit_span.set(tokens=tokens,
+                          evicted=self.stats["evicted"] - evicted)
 
     def _step_verify(self) -> None:
         """The speculative step: draft, verify all ``draft_len + 1``
@@ -988,10 +1054,13 @@ class ContinuousBatchingScheduler:
         verify_attrs = (dict(decode_step=self.stats["decode_steps"],
                              active=int(self._active.sum()),
                              draft_len=W - 1,
-                             trace_ids=self._active_trace_ids())
+                             trace_ids=self._active_trace_ids(),
+                             prefills_before=self._prefills_since_step)
                         if _tracing.enabled() else {})
         verify_span = _tracing.span("serve.verify_step", **verify_attrs)
+        emit_span = None
         emitted_before = self.stats["spec_emitted"]
+        evicted_before = self.stats["evicted"]
         try:
             self.pools, sampled = self._call(
                 "_verify", self.params, self.pools,
@@ -999,6 +1068,9 @@ class ContinuousBatchingScheduler:
                 jnp.asarray(self._active), jnp.asarray(self._page_tables),
                 jnp.asarray(seeds))
             sampled = np.asarray(sampled)
+            self._prefills_since_step = 0
+            # the accept loop, under the verify span as its child
+            emit_span = _tracing.span("serve.emit")
             now = self._time()
             self.stats["decode_steps"] += 1
             self.stats["spec_steps"] += 1
@@ -1046,11 +1118,15 @@ class ContinuousBatchingScheduler:
             verify_span.set(error=True)
             raise
         finally:
-            # the accept loop can raise too — the span must never leak
-            # open (it would render as a phantom wedged verify step in
-            # every later export and flight-recorder dump)
-            verify_span.end(
-                emitted=self.stats["spec_emitted"] - emitted_before)
+            # the accept loop can raise too — the spans must never leak
+            # open (they would render as a phantom wedged verify step
+            # in every later export and flight-recorder dump)
+            emitted = self.stats["spec_emitted"] - emitted_before
+            if emit_span is not None:
+                emit_span.end(
+                    tokens=emitted,
+                    evicted=self.stats["evicted"] - evicted_before)
+            verify_span.end(emitted=emitted)
 
     def run_until_drained(self, max_steps: int = 10_000) -> List[Completion]:
         """Drive ``step()`` until queues and slots are empty (the

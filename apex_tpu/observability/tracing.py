@@ -46,9 +46,17 @@ Design constraints, each load-bearing:
 
 Span naming schema (see docs/observability.md for the full table):
 ``<subsystem>.<phase>`` — ``train.step.dispatch``, ``train.data_wait``,
-``train.checkpoint_save``, ``zero_sync.bucket<k>.hop_<axis>``,
-``serve.admission_wait``, ``serve.decode_step``, ``serve.request``,
+``train.checkpoint_save``, ``serve.admit``, ``serve.prefill``,
+``serve.decode_step``, ``serve.emit``, ``serve.request``,
 ``supervisor.attempt``, ``bench.section.<name>``.
+
+A span around device work ends when its result is ON THE HOST, or is
+named ``.dispatch`` (``serve.prefill`` and ``serve.decode_step`` end in
+a readback; ``train.step.dispatch`` times the enqueue and says so).
+
+Every span has an ``id`` (process-monotonic) and a ``parent``: the id
+of the span that was open on the same thread when it started, or None.
+A retro-emitted span (:meth:`Tracer.emit`) names its parent itself.
 """
 
 import itertools
@@ -63,8 +71,8 @@ from apex_tpu.observability.correlation import step_context
 
 __all__ = [
     "TracedStep", "Tracer", "TracingScope", "configure", "disable",
-    "emit_sync_plan", "enabled", "export_run", "get_tracer", "instant",
-    "new_trace_id", "overlap_fraction", "span",
+    "enabled", "export_run", "get_tracer", "instant", "new_trace_id",
+    "span",
 ]
 
 SCHEMA = "apex_tpu_trace_v1"
@@ -72,6 +80,8 @@ SCHEMA = "apex_tpu_trace_v1"
 _TRACER: Optional["Tracer"] = None
 
 _TRACE_IDS = itertools.count()
+
+_SPAN_IDS = itertools.count(1)
 
 
 def new_trace_id() -> str:
@@ -88,7 +98,7 @@ class _Span:
     Also usable as a context manager (the common spelling)."""
 
     __slots__ = ("_tracer", "name", "attrs", "ts", "_t0", "tid",
-                 "thread", "_done")
+                 "thread", "_done", "id", "parent", "_stack")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -102,6 +112,11 @@ class _Span:
         self.tid = t.ident or 0
         self.thread = t.name
         self._done = False
+        self.id = next(_SPAN_IDS)
+        # the span that caused this one: whatever this thread had open
+        self._stack = tracer._open_stack()
+        self.parent = self._stack[-1].id if self._stack else None
+        self._stack.append(self)
         tracer._opened(self)
 
     def elapsed(self) -> float:
@@ -117,6 +132,9 @@ class _Span:
         if self._done:
             return
         self._done = True
+        # usually the top; a handle ended out of order (or from another
+        # thread) still leaves the stack it was pushed on
+        self._stack.remove(self)
         if attrs:
             self.attrs.update(attrs)
         self._tracer._finished(self, self.elapsed())
@@ -136,6 +154,8 @@ class _NoopSpan:
     """The disabled-tracing singleton: every operation is a no-op."""
 
     __slots__ = ()
+    id = None
+    parent = None
 
     def __enter__(self):
         return self
@@ -171,6 +191,9 @@ class Tracer:
         self._lock = threading.Lock()
         self._spans: deque = deque(maxlen=self.capacity)
         self._open: Dict[int, _Span] = {}
+        # per-thread stack of this tracer's open spans (``.stack``): its
+        # top is the parent of whatever starts next on that thread
+        self._local = threading.local()
         self._listeners: List[Callable[[dict], None]] = []
         self.started = 0
         self.finished = 0
@@ -185,22 +208,28 @@ class Tracer:
     def instant(self, name: str, **attrs) -> None:
         """A zero-duration marker event (Chrome ``i`` phase)."""
         t = threading.current_thread()
+        stack = self._open_stack()
         self._record({
             "name": name, "ph": "i", "ts": time.time(), "dur_us": 0,
             "tid": t.ident or 0, "thread": t.name,
+            "id": next(_SPAN_IDS),
+            "parent": stack[-1].id if stack else None,
             "attrs": {**step_context(), **attrs},
         })
 
     def emit(self, name: str, start_ts: float, dur_s: float,
-             **attrs) -> None:
+             parent: Optional[int] = None, **attrs) -> None:
         """Retro-record a COMPLETED span from its measured endpoints
         (the serving scheduler's admission wait: both timestamps are
-        known only at admit time)."""
+        known only at admit time).  It started before whatever is open
+        now, so its ``parent`` is what the caller says (the ``id`` of
+        the span that ended it), not the top of the thread's stack."""
         t = threading.current_thread()
         self._record({
             "name": name, "ph": "X", "ts": float(start_ts),
             "dur_us": max(int(dur_s * 1e6), 0),
             "tid": t.ident or 0, "thread": t.name,
+            "id": next(_SPAN_IDS), "parent": parent,
             "attrs": {**step_context(), **attrs},
         })
 
@@ -211,6 +240,12 @@ class Tracer:
         self._listeners.append(fn)
 
     # ------------------------------------------------------- internals
+    def _open_stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def _opened(self, s: _Span) -> None:
         with self._lock:
             self.started += 1
@@ -222,7 +257,8 @@ class Tracer:
         self._record({
             "name": s.name, "ph": "X", "ts": s.ts,
             "dur_us": max(int(dur_s * 1e6), 0),
-            "tid": s.tid, "thread": s.thread, "attrs": dict(s.attrs),
+            "tid": s.tid, "thread": s.thread,
+            "id": s.id, "parent": s.parent, "attrs": dict(s.attrs),
         })
 
     def _record(self, rec: dict) -> None:
@@ -253,6 +289,7 @@ class Tracer:
             "name": s.name, "ph": "X", "ts": s.ts,
             "dur_us": max(int(s.elapsed() * 1e6), 0),
             "tid": s.tid, "thread": s.thread,
+            "id": s.id, "parent": s.parent,
             "attrs": dict(s.attrs), "open": True,
         } for s in live]
 
@@ -268,6 +305,7 @@ class Tracer:
                 "span": rec["name"], "ph": rec["ph"],
                 "ts": round(rec["ts"], 6), "dur_us": rec["dur_us"],
                 "tid": rec["tid"], "thread": rec["thread"],
+                "id": rec["id"], "parent": rec["parent"],
                 "rank": rank, "open": rec.get("open", False),
                 **rec.get("attrs", {}),
             }, sort_keys=True, default=str))
@@ -291,7 +329,8 @@ class Tracer:
         threads = {}
         for rec in self.spans() + self.open_spans():
             threads.setdefault(rec["tid"], rec["thread"])
-            args = dict(rec.get("attrs", {}))
+            args = dict(rec.get("attrs", {}), id=rec["id"],
+                        parent=rec["parent"])
             if rec.get("open"):
                 args["open"] = True
             events.append({
@@ -432,62 +471,3 @@ class TracedStep:
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
-
-
-def overlap_fraction(tracer: Optional[Tracer] = None,
-                     prefix: str = "zero_sync.bucket") -> float:
-    """Span-concurrency of the wire plan against dispatch: the
-    fraction of ``prefix``-named instant markers in the tracer's
-    buffer whose timestamp falls INSIDE some ``*step.dispatch`` span's
-    ``[ts, ts + dur]`` interval.  A marker emitted while a dispatch is
-    in flight is a sync whose host-side bookkeeping overlapped the
-    step — the host-observable proxy for the compiled step's
-    compute/communication overlap (the collectives themselves run on
-    device, where per-hop host timing would need forbidden host
-    transfers).  ``prefix`` defaults to the ZeRO wire plan's
-    ``zero_sync.bucket`` markers (:func:`emit_sync_plan`); ring
-    attention's bench section passes ``"ring_attn.hop"`` to measure
-    its hop plan against the same dispatch windows.  0.0 with no
-    tracer, no markers, or no dispatch spans."""
-    tracer = tracer if tracer is not None else _TRACER
-    if tracer is None:
-        return 0.0
-    spans = tracer.spans() + tracer.open_spans()
-    windows = [(s["ts"], s["ts"] + s["dur_us"] / 1e6) for s in spans
-               if s["name"].endswith("step.dispatch")]
-    marks = [s["ts"] for s in spans
-             if s["ph"] == "i" and s["name"].startswith(prefix)]
-    if not marks or not windows:
-        return 0.0
-    inside = sum(1 for ts in marks
-                 if any(lo <= ts <= hi for lo, hi in windows))
-    return inside / len(marks)
-
-
-def emit_sync_plan(optimizer, tracer: Optional[Tracer] = None) -> dict:
-    """Emit one ``zero_sync.bucket<k>.hop_<axis>`` marker per (bucket,
-    hop) of a ZeRO optimizer's sync plan, attributes carrying the
-    per-hop payload/scale bytes (:meth:`~apex_tpu.contrib.optimizers.
-    _zero_engine.ZeroOptimizerBase.sync_plan_hops`).  The markers give
-    a trace its wire-plan track; the per-step ``train.step.dispatch``
-    span carries the same per-hop totals, so span duration ÷ hop bytes
-    bounds the achieved per-hop bandwidth (the sync itself runs inside
-    the compiled step — per-hop host timing would need host transfers
-    the zero-overhead contract forbids).
-
-    Returns ``{"markers": n, "overlap_fraction": f}``: markers emitted
-    this call (0 when tracing is off or the optimizer has no plan) and
-    :func:`overlap_fraction` over the tracer's whole buffer — calling
-    this inside the step loop (markers land inside the live dispatch
-    span) folds the wire plan's dispatch concurrency into the same
-    record the bench reports as its ``overlap_fraction`` column."""
-    tracer = tracer if tracer is not None else _TRACER
-    hops_fn = getattr(optimizer, "sync_plan_hops", None)
-    if tracer is None or hops_fn is None:
-        return {"markers": 0, "overlap_fraction": 0.0}
-    n = 0
-    for rec in hops_fn():
-        tracer.instant(
-            f"zero_sync.bucket{rec['bucket']}.hop_{rec['hop']}", **rec)
-        n += 1
-    return {"markers": n, "overlap_fraction": overlap_fraction(tracer)}

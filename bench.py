@@ -480,21 +480,12 @@ def bench_ring_attention(roofline_tflops, iters=16, cp=None,
     ring) vs ``overlap=True`` (unrolled — hop r+1's ppermute issued
     before chunk r's compute, double-buffered k/v).  The two schedules
     are bitwise-equal in fp32 (pinned in tier-1), so any ms delta here
-    is pure ICI/compute overlap.  The overlapped run executes under a
-    tracing scope that emits one ``ring_attn.hop.*`` marker per planned
-    rotation while the dispatch span is live, so
-    ``tracing.overlap_fraction(tracer, prefix="ring_attn.hop")`` is the
-    hop plan's dispatch concurrency — the same host-observable overlap
-    column the ZeRO section reports for its wire plan (the hops
-    themselves run on device; per-hop host timing would need forbidden
-    transfers).  cp defaults to min(4, devices): the real ring on a
-    slice, the degenerate 1-device ring on a single chip — which still
-    compiles the unrolled schedule and banks the A/B shape."""
-    import contextlib
-
+    is pure ICI/compute overlap.  cp defaults to min(4, devices): the
+    real ring on a slice, the degenerate 1-device ring on a single chip
+    — which still compiles the unrolled schedule and banks the A/B
+    shape."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from apex_tpu.observability import tracing
     from apex_tpu.transformer.context_parallel import ring_attention
 
     devs = jax.devices()
@@ -505,14 +496,6 @@ def bench_ring_attention(roofline_tflops, iters=16, cp=None,
     q = jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
     k = jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
     v = jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
-
-    # the unrolled ring's hop plan: cp-1 k/v rotations fwd, cp-1 more
-    # bwd, plus cp dk/dv accumulator rotations (required either way —
-    # each moves the accumulator one hop toward home)
-    chunk_bytes = 2 * B * H * (S // cp) * D * q.dtype.itemsize  # k+v pair
-    hops = ([("fwd_kv", r) for r in range(cp - 1)]
-            + [("bwd_kv", r) for r in range(cp - 1)]
-            + [("bwd_acc", r) for r in range(cp)])
 
     def variant(overlap):
         def local_loss(q, k, v):
@@ -528,42 +511,24 @@ def bench_ring_attention(roofline_tflops, iters=16, cp=None,
             check_vma=False,
         ))
 
-        def dispatch(*a):
-            r = step(*a)
-            # markers land inside the live dispatch span, mirroring the
-            # ZeRO section's emit_sync_plan placement
-            for kind, hop in hops:
-                tracing.instant(f"ring_attn.hop.{kind}{hop}",
-                                bytes=chunk_bytes)
-            return r
-
-        run = (tracing.TracedStep(dispatch, name="ring.step.dispatch")
-               if overlap else step)
         g = step(q, k, v)
         block(g)
         n = 1 if _SMOKE else iters
-        scope = (tracing.TracingScope() if overlap
-                 else contextlib.nullcontext())
-        with scope as tracer:
-            t0 = time.perf_counter()
-            for _ in range(n):
-                g = run(q, k, v)
-            block(g)
-            dt = (time.perf_counter() - t0) / n
-            # causal fwd+bwd attention FLOPs over the GLOBAL sequence:
-            # 2 matmuls of 2·S²·D halved by causality, bwd ~2.5x fwd
-            flops = B * H * 2 * 2 * S * S * D / 2 * 3.5
-            tflops = flops / dt / 1e12
-            rec = {
-                "ms_per_step": round(dt * 1e3, 2),
-                "tflops": round(tflops, 2),
-                "pct_roofline": (round(100 * tflops / roofline_tflops, 1)
-                                 if roofline_tflops else None),
-            }
-            if overlap:
-                rec["overlap_fraction"] = round(tracing.overlap_fraction(
-                    tracer, prefix="ring_attn.hop"), 3)
-        return rec
+        t0 = time.perf_counter()
+        for _ in range(n):
+            g = step(q, k, v)
+        block(g)
+        dt = (time.perf_counter() - t0) / n
+        # causal fwd+bwd attention FLOPs over the GLOBAL sequence:
+        # 2 matmuls of 2·S²·D halved by causality, bwd ~2.5x fwd
+        flops = B * H * 2 * 2 * S * S * D / 2 * 3.5
+        tflops = flops / dt / 1e12
+        return {
+            "ms_per_step": round(dt * 1e3, 2),
+            "tflops": round(tflops, 2),
+            "pct_roofline": (round(100 * tflops / roofline_tflops, 1)
+                             if roofline_tflops else None),
+        }
 
     out = {"cp": cp, "shape": list(shape), "impl": impl}
     _progress("ring_attn_cp: serial ring...")
@@ -814,51 +779,26 @@ def bench_zero_gpt124(iters=8, dp=None, layers=12, hidden=768, heads=12,
 
     def time_mode(optimizer, state, sspec, use_mesh=None, dp_axis="dp",
                   overlap=False):
-        import contextlib
-
-        from apex_tpu.observability import tracing
-
         m = mesh if use_mesh is None else use_mesh
         step = make_train_step(cfg, optimizer, m, donate_state=True,
                                opt_state_spec=sspec, dp_axis=dp_axis,
                                overlap_grad_sync=overlap)
-        run = step
-        if overlap:
-            # emit the wire-plan markers while the dispatch span is
-            # live: tracing.overlap_fraction then reports the span
-            # concurrency of the sync plan against step dispatch — the
-            # host-observable overlap column (the collectives run on
-            # device; PR 14's zero-overhead contract forbids per-hop
-            # host timing inside the step)
-            def dispatch(*a):
-                r = step(*a)
-                tracing.emit_sync_plan(optimizer)
-                return r
-
-            run = tracing.TracedStep(dispatch, name="train.step.dispatch")
         params = jax.tree.map(lambda x: x.copy(), params0)
         live = _per_device_bytes(params, pspecs, m) + \
             _per_device_bytes(state, sspec, m)
         params, state, loss = step(params, state, tokens, targets)
         block(loss)
         n = 1 if _SMOKE else iters
-        scope = tracing.TracingScope() if overlap else \
-            contextlib.nullcontext()
-        with scope as tracer:
-            t0 = time.perf_counter()
-            for _ in range(n):
-                params, state, loss = run(params, state, tokens, targets)
-            block(loss)
-            dt = (time.perf_counter() - t0) / n
-            rec = {
-                "tokens_per_sec": round(tokens.size / dt, 0),
-                "ms_per_step": round(dt * 1e3, 2),
-                "live_bytes_per_device_mb": round(live / 2 ** 20, 1),
-            }
-            if overlap:
-                rec["overlap_fraction"] = round(
-                    tracing.overlap_fraction(tracer), 3)
-        return rec
+        t0 = time.perf_counter()
+        for _ in range(n):
+            params, state, loss = step(params, state, tokens, targets)
+        block(loss)
+        dt = (time.perf_counter() - t0) / n
+        return {
+            "tokens_per_sec": round(tokens.size / dt, 0),
+            "ms_per_step": round(dt * 1e3, 2),
+            "live_bytes_per_device_mb": round(live / 2 ** 20, 1),
+        }
 
     out = {"dp": dp, "params_m": round(n_params / 1e6, 1),
            "batch": int(tokens.shape[0])}
@@ -931,9 +871,8 @@ def bench_zero_gpt124(iters=8, dp=None, layers=12, hidden=768, heads=12,
     # SAME wire plans with each bucket's hop-1 collective issued as its
     # grads materialize inside the segmented backward.  Loss/params are
     # bitwise vs the unoverlapped builds (tests/
-    # test_distributed_optimizers.py pins it); what moves is the trace
-    # placement, reported as the overlap_fraction span-concurrency
-    # column and the ms_per_step delta.
+    # test_distributed_optimizers.py pins it); what moves is the
+    # ms_per_step delta.
     # --smoke builds only overlap_3level below: it compiles the deepest
     # overlap path (segmented backward + three requantizing hops), a
     # strict superset of the flat and two-level builds, and each

@@ -12,7 +12,6 @@ tolerance**, so a perf claim can't silently rot between rounds:
 - every ``*.tokens_per_sec`` (training + serving throughput),
 - every ``*.cross_slice_wire_cut`` (hierarchical sync's headline),
 - every ``*.wire_cut_vs_default`` (compressed sync's headline),
-- every ``*.overlap_fraction`` (grad-sync / ring-hop dispatch overlap),
 - ``gpt124_s4096.mfu_ratio_vs_s1024`` (long-context MFU retention).
 
 All headline columns are higher-is-better; tolerance is relative
@@ -52,7 +51,6 @@ HEADLINE_LEAVES = (
     "cross_slice_wire_cut",
     "cross_dcn_wire_cut",
     "wire_cut_vs_default",
-    "overlap_fraction",
     "mfu_ratio_vs_s1024",
 )
 

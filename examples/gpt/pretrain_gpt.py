@@ -423,11 +423,6 @@ def main(argv=None):
         return tracing.TracedStep(built, name="train.step.dispatch")
 
     step = build_step()
-    # one marker per (bucket, hop) of the ZeRO sync plan: the trace's
-    # wire-plan track (dispatch-span duration ÷ hop bytes bounds the
-    # achieved per-hop bandwidth); no-op when tracing is off or the
-    # optimizer has no bucket plan
-    tracing.emit_sync_plan(optimizer)
 
     # Corpus: a memmapped token file (--data, the real-pretraining path:
     # the OS pages in only the rows each batch touches) or a synthetic

@@ -524,18 +524,31 @@ class TestScheduler:
         occupancy (1..3 active), cache lengths, admissions and
         evictions all reuse ONE compiled decode step (pinned through
         the generalized ``analysis.lowered.assert_no_recompile``
-        guard-rail, post-hoc spelling)."""
+        guard-rail, post-hoc spelling).  Spans are observers: with a
+        tracer installed the step still compiles once, and the tokens
+        are those of the untraced run."""
+        import contextlib
+
         from apex_tpu.analysis import lowered as lw
+        from apex_tpu.observability import tracing
 
         cfg, params = model
-        sched = _sched(params, cfg)
-        rng = np.random.RandomState(8)
-        for r in _requests(rng, 7, cfg.vocab_size, plen=(2, 8),
-                           max_new=(2, 8)):
-            sched.submit(r)
-        sched.run_until_drained()
-        lw.assert_no_recompile(sched._decode, label="decode_step")
-        assert sched.decode_cache_size() == 1
+        served = {}
+        for traced in (False, True):
+            scope = tracing.TracingScope() if traced \
+                else contextlib.nullcontext()
+            with scope as tracer:
+                sched = _sched(params, cfg)
+                rng = np.random.RandomState(8)
+                for r in _requests(rng, 7, cfg.vocab_size, plen=(2, 8),
+                                   max_new=(2, 8)):
+                    sched.submit(r)
+                done = sched.run_until_drained()
+            lw.assert_no_recompile(sched._decode, label="decode_step")
+            assert sched.decode_cache_size() == 1
+            served[traced] = {c.rid: tuple(c.tokens) for c in done}
+        assert any(s["name"] == "serve.emit" for s in tracer.spans())
+        assert served[True] == served[False]
 
     def test_chaos_wedged_decode_step_fires_serving_watchdog(self, model):
         """The serving-side watchdog contract: one decode step stalls

@@ -156,7 +156,7 @@ class TestTracerCore:
 
     def test_instant_and_retro_emit(self):
         tr = tracing.Tracer()
-        tr.instant("zero_sync.bucket0.hop_dp", payload_bytes=1024)
+        tr.instant("train.marker", payload_bytes=1024)
         t0 = time.time() - 0.5
         tr.emit("serve.admission_wait", t0, 0.25, rid=3)
         marker, emitted = tr.spans()
@@ -179,15 +179,126 @@ class TestTracerCore:
             tracing.Tracer(capacity=0)
 
 
+class TestSpanCausality:
+    """Every span names the span that caused it: ``parent`` is the id
+    of what the same thread had open when it started."""
+
+    def test_id_and_parent_under_nesting(self):
+        tr = tracing.Tracer()
+        with tr.span("outer") as outer:
+            with tr.span("first") as first:
+                tr.instant("marker")
+            with tr.span("second") as second:
+                pass
+        with tr.span("after") as after:
+            pass
+        by = {s["name"]: s for s in tr.spans()}
+        assert by["outer"]["parent"] is None
+        assert by["first"]["parent"] == by["outer"]["id"] == outer.id
+        assert by["marker"]["parent"] == first.id
+        assert by["second"]["parent"] == outer.id   # a sibling, not a child
+        assert by["after"]["parent"] is None
+        ids = [outer.id, first.id, by["marker"]["id"], second.id, after.id]
+        assert ids == sorted(ids) and len(set(ids)) == 5
+
+    def test_each_thread_has_its_own_stack(self):
+        tr = tracing.Tracer()
+        inside = threading.Event()
+        release = threading.Event()
+
+        def work():
+            with tr.span("worker.outer"):
+                with tr.span("worker.inner"):
+                    inside.set()
+                    assert release.wait(timeout=10)
+
+        t = threading.Thread(target=work)
+        with tr.span("main.outer"):
+            t.start()
+            assert inside.wait(timeout=10)
+            # the worker has two spans open right now; this thread's
+            # next span is still a child of main.outer alone
+            with tr.span("main.inner"):
+                pass
+            release.set()
+            t.join(timeout=10)
+        assert not t.is_alive()
+        by = {s["name"]: s for s in tr.spans()}
+        assert by["main.inner"]["parent"] == by["main.outer"]["id"]
+        assert by["worker.inner"]["parent"] == by["worker.outer"]["id"]
+        assert by["worker.outer"]["parent"] is None
+        assert by["main.outer"]["parent"] is None
+
+    def test_retro_emit_names_its_parent_itself(self):
+        tr = tracing.Tracer()
+        with tr.span("serve.admit") as admit:
+            tr.emit("serve.admission_wait", time.time() - 1, 1.0,
+                    parent=admit.id, rid=3)
+            tr.emit("serve.request", time.time() - 1, 1.0, rid=3)
+        wait, whole, _ = tr.spans()
+        assert wait["parent"] == admit.id and wait["attrs"] == {"rid": 3}
+        # it began before what is open now: the stack is not consulted
+        assert whole["parent"] is None
+        assert wait["id"] != whole["id"]
+
+    def test_stack_is_empty_after_an_exception_inside_a_span(self):
+        tr = tracing.Tracer()
+        with pytest.raises(RuntimeError):
+            with tr.span("outer"):
+                with tr.span("inner"):
+                    raise RuntimeError("boom")
+        assert tr._open_stack() == []
+        with tr.span("next"):
+            pass
+        assert tr.spans()[-1]["parent"] is None
+
+    def test_a_handle_ended_out_of_order_leaves_the_stack(self):
+        tr = tracing.Tracer()
+        a = tr.span("a")
+        b = tr.span("b")
+        a.end()                       # not the top
+        assert tr._open_stack() == [b]
+        b.end()
+        assert tr._open_stack() == []
+
+    def test_exports_carry_id_and_parent(self, tmp_path):
+        tr = tracing.Tracer()
+        with tr.span("outer") as outer:
+            with tr.span("inner"):
+                pass
+        tr.export_jsonl(tmp_path / "s.jsonl")
+        tr.export_chrome(tmp_path / "t.json")
+        lines = [json.loads(l)
+                 for l in (tmp_path / "s.jsonl").read_text().splitlines()]
+        inner = next(l for l in lines if l["span"] == "inner")
+        assert inner["parent"] == outer.id and inner["id"] > outer.id
+        events = json.loads((tmp_path / "t.json").read_text())["traceEvents"]
+        inner = next(e for e in events if e["name"] == "inner")
+        assert inner["args"]["parent"] == outer.id
+        assert inner["args"]["id"] > outer.id
+
+
 class TestModuleApi:
     def test_span_without_tracer_is_the_noop_singleton(self):
+        import sys
+
         a = tracing.span("x", attr=1)
         b = tracing.span("y")
-        assert a is b  # no allocation on the disabled path
+        assert a is b is tracing._NOOP
         with a:
             a.set(z=2)
         assert a.elapsed() == 0.0
+        assert a.id is None and a.parent is None
         assert not tracing.enabled()
+        # the disabled path allocates nothing: no id is drawn and no
+        # object outlives a call
+        next_id = next(tracing._SPAN_IDS)
+        before = sys.getallocatedblocks()
+        for _ in range(10_000):
+            with tracing.span("serve.decode_step"):
+                pass
+        assert sys.getallocatedblocks() - before < 100
+        assert next(tracing._SPAN_IDS) == next_id + 1
 
     def test_configure_routes_module_span(self):
         tr = tracing.configure(capacity=16)
@@ -300,52 +411,6 @@ class TestTracedStep:
         w = tracing.TracedStep(FakeStep())
         assert w.lower() == "lowering"
         assert w._cache_size() == 1
-
-    def test_emit_sync_plan_markers(self):
-        class FakeOpt:
-            def sync_plan_hops(self):
-                return [
-                    {"bucket": 0, "hop": "dp_in", "payload_bytes": 10},
-                    {"bucket": 0, "hop": "dp_out", "payload_bytes": 5},
-                    {"bucket": 1, "hop": "dp_in", "payload_bytes": 8},
-                ]
-
-        # tracing off
-        assert tracing.emit_sync_plan(FakeOpt()) == \
-            {"markers": 0, "overlap_fraction": 0.0}
-        with tracing.TracingScope() as tr:
-            out = tracing.emit_sync_plan(FakeOpt())
-            assert out["markers"] == 3
-            # markers emitted outside any dispatch span: no concurrency
-            assert out["overlap_fraction"] == 0.0
-            assert tracing.emit_sync_plan(object()) == \
-                {"markers": 0, "overlap_fraction": 0.0}  # no plan
-        names = [s["name"] for s in tr.spans()]
-        assert names == ["zero_sync.bucket0.hop_dp_in",
-                         "zero_sync.bucket0.hop_dp_out",
-                         "zero_sync.bucket1.hop_dp_in"]
-        assert tr.spans()[1]["attrs"]["payload_bytes"] == 5
-
-    def test_overlap_fraction_counts_markers_inside_dispatch(self):
-        class FakeOpt:
-            def sync_plan_hops(self):
-                return [{"bucket": 0, "hop": "dp"},
-                        {"bucket": 1, "hop": "dp"}]
-
-        assert tracing.overlap_fraction() == 0.0  # tracing off
-        with tracing.TracingScope() as tr:
-            # two markers inside a live dispatch span...
-            wrapped = tracing.TracedStep(
-                lambda: tracing.emit_sync_plan(FakeOpt()),
-                name="train.step.dispatch")
-            inside = wrapped()
-            assert inside["markers"] == 2
-            assert inside["overlap_fraction"] == 1.0
-            # ...then two more outside any dispatch window
-            out = tracing.emit_sync_plan(FakeOpt())
-            assert out["markers"] == 2
-            assert out["overlap_fraction"] == pytest.approx(0.5)
-            assert tracing.overlap_fraction(tr) == pytest.approx(0.5)
 
 
 # ------------------------------------------------------------ parity band
@@ -938,30 +1003,8 @@ class TestServeTraceJoin:
     its request's spans through the shared trace_id exemplar."""
 
     def _completions(self, tr):
-        from apex_tpu.inference import (
-            ContinuousBatchingScheduler, DecodeConfig, KVCacheConfig,
-            Request,
-        )
-
-        cfg = GPTConfig(vocab_size=61, hidden_size=32, num_layers=2,
-                        num_attention_heads=4, max_seq_len=128,
-                        position_embedding_type="rope",
-                        compute_dtype=jnp.float32,
-                        checkpoint_layers=False)
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        dcfg = DecodeConfig(
-            cache=KVCacheConfig(num_pages=40, page_size=4,
-                                pages_per_seq=16, dtype=jnp.float32),
-            max_batch=2, max_prompt_len=16, temperature=0.0,
-            attn_impl="xla", sample_impl="xla",
-            sample_dot_dtype=jnp.float32)
-        sched = ContinuousBatchingScheduler(params, cfg, dcfg)
-        rng = np.random.RandomState(0)
-        for rid in range(2):
-            sched.submit(Request(
-                rid=rid, prompt=rng.randint(0, 61, size=6).tolist(),
-                max_new_tokens=3))
-        return sched.run_until_drained()
+        return _submit_and_drain(_tiny_scheduler(max_batch=2, num_pages=40),
+                                 n=2, plen=6, new=3)
 
     def test_trace_id_joins_exemplar_to_spans(self):
         with metrics.MetricsScope() as reg, \
@@ -1001,6 +1044,19 @@ class TestServeTraceJoin:
         for tid in ids.values():  # every request decoded at least once
             assert any(tid in s["attrs"]["trace_ids"] for s in decode)
 
+    def test_admission_wait_is_caused_by_its_admission_pass(self):
+        """``serve.admission_wait`` is caused by the admission pass that
+        ended it, and carries why its request had blocked."""
+        with tracing.TracingScope() as tr:
+            self._completions(tr)
+        spans = tr.spans()
+        admits = {s["id"] for s in spans if s["name"] == "serve.admit"}
+        waits = [s for s in spans if s["name"] == "serve.admission_wait"]
+        assert len(waits) == 2
+        for w in waits:
+            assert w["parent"] in admits
+            assert w["attrs"]["blocked_on"] is None   # two slots, two requests
+
     def test_window_max_exemplar_survives_ring_eviction(self):
         """serve_gpt.py drains exemplars exactly once, at the end of
         the run: a mid-run p99 outlier must still be present after
@@ -1036,3 +1092,175 @@ class TestServeTraceJoin:
         assert ex[0]["metric"] == "apex_serve_ttft_seconds_exemplar"
         assert ex[0]["trace_id"] == "t-1" and ex[0]["rid"] == 7
         assert ex[0]["labels"] == {"lane": "interactive"}
+
+
+# ------------------------------------------------ scheduler span tiling
+LEAVES = ("serve.admit", "serve.prefill", "serve.prefill_chunk",
+          "serve.decode_step", "serve.verify_step", "serve.emit")
+
+#: name -> scheduler settings and requests (count, prompt length, new
+#: tokens).  ``slots``: more requests than slots; ``pages``: slots to
+#: spare but a pool that holds one request (3 pages of 4) at a time;
+#: ``chunked_spec``: the chunked-prefill and verify-step paths.
+SERVERS = {
+    "slots": (dict(max_batch=2, num_pages=40), (5, 6, 4)),
+    "pages": (dict(max_batch=3, num_pages=5), (3, 6, 3)),
+    "chunked_spec": (dict(max_batch=2, num_pages=40, prefill_chunk=4,
+                          draft_len=2), (4, 10, 6)),
+}
+
+
+def _tiny_scheduler(num_pages, **knobs):
+    """The suite's tiny server: a 2-layer model, pages of 4, greedy."""
+    from apex_tpu.inference import (
+        ContinuousBatchingScheduler, DecodeConfig, KVCacheConfig,
+    )
+
+    cfg = GPTConfig(vocab_size=61, hidden_size=32, num_layers=2,
+                    num_attention_heads=4, max_seq_len=128,
+                    position_embedding_type="rope",
+                    compute_dtype=jnp.float32, checkpoint_layers=False)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    dcfg = DecodeConfig(
+        cache=KVCacheConfig(num_pages=num_pages, page_size=4,
+                            pages_per_seq=16, dtype=jnp.float32),
+        max_prompt_len=16, temperature=0.0, attn_impl="xla",
+        sample_impl="xla", sample_dot_dtype=jnp.float32, **knobs)
+    return ContinuousBatchingScheduler(params, cfg, dcfg)
+
+
+def _submit_and_drain(sched, n, plen, new):
+    from apex_tpu.inference import Request
+
+    rng = np.random.RandomState(0)
+    for rid in range(n):
+        sched.submit(Request(
+            rid=rid, prompt=rng.randint(0, 61, size=plen).tolist(),
+            max_new_tokens=new))
+    return sched.run_until_drained()
+
+
+def _serve(name):
+    """One traced run of the tiny server: (completions, spans, stats)."""
+    knobs, (n, plen, new) = SERVERS[name]
+    with tracing.TracingScope() as tr:
+        sched = _tiny_scheduler(**knobs)
+        done = _submit_and_drain(sched, n, plen, new)
+    return done, tr.spans(), dict(sched.stats)
+
+
+class TestServeSpans:
+    """The scheduler's leaf spans tile ``step()``, and a request's
+    record holds the decomposition of its own first token."""
+
+    @pytest.fixture(scope="class", params=sorted(SERVERS))
+    def served(self, request):
+        return (request.param,) + _serve(request.param)
+
+    def test_leaf_spans_never_overlap_except_parent_and_child(self, served):
+        _, _, spans, _ = served
+        leaves = sorted((s for s in spans if s["name"] in LEAVES),
+                        key=lambda s: (s["ts"], -s["dur_us"]))
+        assert {s["name"] for s in leaves} >= {"serve.admit", "serve.emit"}
+        assert len({s["tid"] for s in leaves}) == 1
+        by_id = {s["id"]: s for s in leaves}
+
+        def ancestors(s):
+            while s["parent"] in by_id:
+                s = by_id[s["parent"]]
+                yield s["id"]
+
+        for a, b in zip(leaves, leaves[1:]):
+            a_end = a["ts"] + a["dur_us"] / 1e6
+            if b["ts"] < a_end - 2e-6:          # dur_us is truncated
+                assert a["id"] in set(ancestors(b)), (
+                    f"{b['name']} starts inside {a['name']}, which is "
+                    f"not its ancestor")
+
+    def test_prefill_is_caused_by_an_admission_pass(self, served):
+        name, _, spans, stats = served
+        admits = {s["id"]: s for s in spans if s["name"] == "serve.admit"}
+        prefills = [s for s in spans if s["name"] == "serve.prefill"]
+        if name == "chunked_spec":      # chunks run beside admission
+            assert not prefills
+            chunks = [s for s in spans if s["name"] == "serve.prefill_chunk"]
+            assert all(c["parent"] is None for c in chunks)
+            assert sum(c["attrs"]["last"] for c in chunks) \
+                == stats["prefills"]
+            return
+        assert len(prefills) == stats["prefills"]
+        for p in prefills:
+            assert p["parent"] in admits
+            assert 0 <= p["attrs"]["dispatch_us"] <= p["dur_us"]
+        assert sum(a["attrs"]["admitted"] for a in admits.values()) \
+            == stats["admitted"]
+        assert all(a["attrs"]["queued"] >= 1 for a in admits.values())
+
+    def test_emit_follows_every_step_and_counts_its_tokens(self, served):
+        name, done, spans, stats = served
+        step = ("serve.verify_step" if name == "chunked_spec"
+                else "serve.decode_step")
+        steps = [s for s in spans if s["name"] == step]
+        emits = [s for s in spans if s["name"] == "serve.emit"]
+        assert len(steps) == len(emits) == stats["decode_steps"]
+        # every token but each request's first comes out of a step
+        assert sum(e["attrs"]["tokens"] for e in emits) \
+            == sum(len(c.tokens) - 1 for c in done)
+        assert sum(e["attrs"]["evicted"] for e in emits) \
+            <= stats["evicted"]
+        step_ids = {s["id"] for s in steps}
+        for e in emits:     # a child of the verify span, after a decode
+            assert (e["parent"] in step_ids) == (name == "chunked_spec")
+        # prefills are charged to the step whose gap they lengthened
+        assert sum(s["attrs"]["prefills_before"] for s in steps) \
+            <= stats["prefills"]
+        assert steps[0]["attrs"]["prefills_before"] >= 1
+
+    def test_request_span_takes_the_first_token_apart(self, served):
+        _, done, spans, _ = served
+        reqs = {s["attrs"]["rid"]: s for s in spans
+                if s["name"] == "serve.request"}
+        assert sorted(reqs) == sorted(c.rid for c in done)
+        for c in done:
+            at = reqs[c.rid]["attrs"]
+            assert {"queue_s", "prefill_s", "ttft_s", "blocked_on"} \
+                <= set(at)
+            assert at["queue_s"] + at["prefill_s"] \
+                == pytest.approx(at["ttft_s"], abs=1e-3)
+            assert at["ttft_s"] == pytest.approx(
+                c.token_times[0] - c.submit_time, abs=1e-5)
+            assert at["queue_s"] == pytest.approx(
+                c.admit_time - c.submit_time, abs=1e-5)
+            assert reqs[c.rid]["dur_us"] / 1e6 == pytest.approx(
+                c.finish_time - c.submit_time, abs=1e-4)
+
+    def test_completion_times_are_ordered(self, served):
+        _, done, _, _ = served
+        for c in done:
+            assert c.submit_time <= c.admit_time <= c.token_times[0] \
+                <= c.finish_time
+        # every request was submitted before the first step: the later
+        # ones queued, and their submit time says so
+        assert max(c.admit_time - c.submit_time for c in done) \
+            > min(c.admit_time - c.submit_time for c in done)
+
+    def test_blocked_on_names_what_the_head_lacked(self, served):
+        name, done, spans, stats = served
+        reason = "pages" if name == "pages" else "slot"
+        other = "slot" if reason == "pages" else "pages"
+        assert stats["admit_blocked_" + reason] >= 1
+        assert stats["admit_blocked_" + other] == 0
+        reqs = {s["attrs"]["rid"]: s["attrs"] for s in spans
+                if s["name"] == "serve.request"}
+        waits = {s["attrs"]["rid"]: s["attrs"] for s in spans
+                 if s["name"] == "serve.admission_wait"}
+        first = min(c.rid for c in done)
+        assert reqs[first]["blocked_on"] is None    # admitted at once
+        blocked = [rid for rid, at in reqs.items() if at["blocked_on"]]
+        assert blocked and all(reqs[r]["blocked_on"] == reason
+                               and waits[r]["blocked_on"] == reason
+                               for r in blocked)
+        passes = [s["attrs"]["blocked_on"] for s in spans
+                  if s["name"] == "serve.admit"]
+        assert passes.count(reason) == stats["admit_blocked_" + reason]
+        assert set(passes) <= {reason, None}
