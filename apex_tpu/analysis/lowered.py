@@ -32,6 +32,7 @@ the real GPT train steps.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import List, Optional, Sequence
 
@@ -48,7 +49,7 @@ __all__ = [
     "host_transfer_sites",
     "arg_shardings", "sharding_of", "assert_sharding",
     "spmd_collective_sites", "assert_spmd_collectives",
-    "pallas_kernels",
+    "pallas_kernels", "large_result_instructions",
 ]
 
 #: collective ops that carry a reduction REGION in StableHLO — their
@@ -924,6 +925,64 @@ def pallas_kernels(artifact) -> List[str]:
     registry's ``kernel_calls`` are counted at trace time, before a
     deferred lowering failure can happen."""
     return _PALLAS_CALL.findall(_compiled_text(artifact))
+
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_HLO_ARRAY = re.compile(r"\b[a-z]+[0-9]*\[([0-9,]*)\]")
+
+
+def _split_result_type(rest: str):
+    """``rest`` is an instruction after ``name =``: its result type (an
+    array, or a tuple with nested parentheses and layouts) and the
+    opcode that follows it."""
+    depth = 0
+    for i, c in enumerate(rest):
+        if c in "({":
+            depth += 1
+        elif c in ")}":
+            depth -= 1
+        elif c == " " and depth == 0:
+            return rest[:i], rest[i + 1:].split("(", 1)[0].strip()
+    return rest, ""
+
+
+def large_result_instructions(artifact, min_elements: int,
+                              containing: Sequence[int] = ()) -> List[dict]:
+    """Instructions of a COMPILED module with a large array result.
+
+    Every instruction with an array of at least ``min_elements``
+    elements in its result — ``{"name", "opcode", "elements", "line"}``
+    each, in program order, fusion bodies included.  ``containing`` keeps only arrays with these dimensions
+    side by side somewhere in their shape (a pool's ``(num_pages,
+    kv_heads)``: stacked weights are as large and are not the pool).
+
+    The question it answers: what in this program produces (or passes
+    on) a value as large as X?  For a serving step with X one layer of
+    the KV pool, the sound answer is "its parameters, the tuples,
+    ``get-tuple-element``s and ``while`` that carry them, and custom
+    calls that alias an operand" — a ``copy``, ``fusion``,
+    ``scatter`` or ``dynamic-update-slice`` there is XLA re-laying out,
+    slicing or rebuilding the pool (PERF.md, PR 25: 54% of a decode
+    step).  Which opcodes are sound is the caller's to say; an aliased
+    custom call shows as ``custom-call`` with
+    ``output_to_operand_aliasing`` in its line."""
+    out = []
+    want = tuple(int(d) for d in containing)
+    for line in _compiled_text(artifact).splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        rtype, opcode = _split_result_type(m.group(2))
+        sizes = []
+        for dims in _HLO_ARRAY.findall(rtype):
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            if any(dims[i:i + len(want)] == want
+                   for i in range(len(dims) - len(want) + 1)):
+                sizes.append(math.prod(dims))
+        if sizes and max(sizes) >= min_elements:
+            out.append({"name": m.group(1), "opcode": opcode,
+                        "elements": max(sizes), "line": line.strip()})
+    return out
 
 
 def donated_buffer_count(artifact) -> int:

@@ -2,7 +2,8 @@
 
 - APX110: an in-place scatter (``.at[...].set/.add/...``) into a
   kv/pool-named buffer whose page index is not provably routed through
-  the allocator/clamp seam — the COW-bypass hazard class.
+  the allocator/clamp seam — the COW-bypass hazard class — or a
+  ``dynamic_update_slice`` on such a buffer, which has no seam at all.
 
 The paged-KV pool has exactly one safe mutation discipline
 (``inference/kv_cache.py``): every destination page index is either
@@ -18,6 +19,16 @@ it mutates a page other sequences (and the prefix trie) still read,
 silently changing *their* logits.  Copy-on-write only protects writes
 that go through the scheduler's COW pass; a raw ``pool.at[idx].set``
 is invisible to it.
+
+There is a second reason to keep raw XLA writes off the pool, found on
+the chip (PERF.md, PR 25): inside a jitted step an XLA scatter or
+``dynamic_update_slice`` on the pool makes layout assignment copy the
+WHOLE pool into the layout that write prefers, and back — more than
+half of a decode step at GPT-2 large.  The sanctioned writers
+(``write_decode_kv`` / ``write_prompt_kv``) therefore write in place
+through one aliased Pallas kernel (``ops/kv_write_pallas.py``,
+``apex_kv_write``) wherever a TPU runs them; their plain-XLA twin on
+the same indices is the CPU and degrade path.
 """
 
 from __future__ import annotations
@@ -37,6 +48,18 @@ _POOL_NAMES = ("pool", "kv_cache", "kvcache")
 #: clamp/re-route (clip/where — the garbage-page discipline) and the
 #: host-int normalization the allocator seam applies (int)
 _SEAM_CALLS = ("clip", "where", "int")
+
+#: kv_cache's own routing helpers — they return (dest, slot/live)
+#: already clamped and garbage-routed, for the sanctioned writers
+#: (``write_decode_kv`` / ``write_prompt_kv``, in place through
+#: ``kv_write_pallas`` or their plain-XLA twin)
+_SEAM_HELPERS = ("_row_targets", "_tile_targets")
+
+#: XLA's slice-write family: no index discipline of its own, and on a
+#: pool inside a step it makes XLA re-lay out the whole pool
+_SLICE_WRITES = frozenset(
+    {"dynamic_update_slice", "dynamic_update_slice_in_dim",
+     "dynamic_update_index_in_dim"})
 
 #: ``.at[...]`` verbs that WRITE (jnp's functional scatter family) —
 #: ``.get`` is a read and stays out of reach
@@ -61,7 +84,7 @@ def _mentions_pool(node: ast.AST) -> bool:
 def _contains_seam_call(node: ast.AST, routed: Set[str]) -> bool:
     for sub in ast.walk(node):
         if isinstance(sub, ast.Call) \
-                and last_name(sub.func) in _SEAM_CALLS:
+                and last_name(sub.func) in _SEAM_CALLS + _SEAM_HELPERS:
             return True
         if isinstance(sub, ast.Name) and sub.id in routed:
             return True
@@ -89,6 +112,11 @@ def _routed_names(fn: ast.AST) -> Set[str]:
                     and len(tgt.elts) == len(node.value.elts):
                 # src, dst = int(src), int(dst) — element-wise
                 pairs = list(zip(tgt.elts, node.value.elts))
+            elif isinstance(tgt, ast.Tuple) \
+                    and isinstance(node.value, ast.Call) \
+                    and last_name(node.value.func) in _SEAM_HELPERS:
+                # dest, slot = _row_targets(...) — every result routed
+                pairs = [(t, node.value) for t in tgt.elts]
             for t, v in pairs:
                 if isinstance(t, ast.Name) and t.id not in routed \
                         and _contains_seam_call(v, routed):
@@ -100,7 +128,7 @@ def _routed_names(fn: ast.AST) -> Set[str]:
 class KvPoolScatterBypassesSeam(Rule):
     """APX110: ``pool.at[idx].set(...)`` where ``idx`` is neither
     clamped/garbage-routed device data nor an allocator-normalized
-    host int."""
+    host int; or any ``dynamic_update_slice`` on a pool."""
 
     rule_id = "APX110"
     severity = "error"
@@ -109,12 +137,31 @@ class KvPoolScatterBypassesSeam(Rule):
         "into the pool and re-route masked rows to the garbage page "
         "(dest = jnp.where(mask, jnp.clip(rows, 0, num_pages - 1), "
         "GARBAGE_PAGE)), or normalize allocator-issued host ids with "
-        "int(...) — or better, scatter through the kv_cache seam "
+        "int(...) — or better, write through the kv_cache seam "
         "helpers (write_decode_kv / write_prompt_kv / copy_page), "
-        "which the scheduler's copy-on-write pass knows about")
+        "which the scheduler's copy-on-write pass knows about and "
+        "which, inside a step, write in place through the aliased "
+        "Pallas kernel (ops.kv_write_pallas, `apex_kv_write`) so that "
+        "XLA never re-lays out the pool")
+
+    _RELAYOUT = (
+        "; and inside a jitted step a raw XLA write of the pool makes "
+        "layout assignment copy the WHOLE pool to the layout the write "
+        "prefers and back (PERF.md, PR 25: more than half of a decode "
+        "step)")
 
     def check(self, ctx: ModuleContext) -> Iterator[Finding]:
         for node in ast.walk(ctx.tree):
+            if isinstance(node, ast.Call) \
+                    and last_name(node.func) in _SLICE_WRITES \
+                    and node.args and _mentions_pool(node.args[0]):
+                yield self.finding(
+                    ctx, node,
+                    f"kv/pool buffer written through "
+                    f"`{last_name(node.func)}`: the write bypasses the "
+                    f"allocator/clamp seam, so the scheduler's "
+                    f"copy-on-write pass cannot see it" + self._RELAYOUT)
+                continue
             if not isinstance(node, ast.Subscript):
                 continue
             at = node.value
@@ -144,7 +191,7 @@ class KvPoolScatterBypassesSeam(Rule):
                 f"pages this write can mutate a page OTHER sequences "
                 f"(and the prefix trie) still read — invisible to the "
                 f"scheduler's copy-on-write pass, corrupting their "
-                f"logits silently")
+                f"logits silently" + self._RELAYOUT)
 
     @staticmethod
     def _index_is_static(slice_node: ast.AST) -> bool:

@@ -15,15 +15,18 @@ arrays — and occupancy/length live in DATA (``active``, ``positions``),
 so the step traces exactly once and serves every batch occupancy and
 cache length from that one executable
 (tests/test_lowered_invariants.py pins the trace count and that the
-lowering has zero host transfers).  The pools donate: the caller
-rebinds them every step, and XLA updates the cache in place instead of
-holding two pool copies live.
+lowering has zero host transfers).  The pools donate — the caller
+rebinds them every step — and every program here writes them IN PLACE
+through one aliased Pallas call (``apex_kv_write``): no XLA op in a
+step produces a pool-sized value, so the pool is held once and never
+re-laid out (:mod:`apex_tpu.inference.kv_cache` has the why;
+tests/test_tpu_bringup.py pins it on the compiled programs).
 
 ``make_prefill`` runs an admitted sequence's prompt through the
 EXISTING training forward (``gpt_forward(return_kv=True)``) at one
-static padded shape, scatters the captured per-layer k/v into the
-sequence's pages, and samples the first generated token from the last
-prompt position's hidden state.
+static padded shape, writes the captured per-layer k/v into the
+sequence's pages as page tiles, and samples the first generated token
+from the last prompt position's hidden state.
 """
 
 import dataclasses
@@ -219,7 +222,8 @@ def make_prefill(config: GPTConfig, dcfg: DecodeConfig):
         ks = k_stack[:, 0].transpose(0, 2, 1, 3)  # (L, S, KVH, hd)
         vs = v_stack[:, 0].transpose(0, 2, 1, 3)
         kp, vp = write_prompt_kv(pools["k"], pools["v"], ks, vs,
-                                 page_table_row, prompt_len, start=start)
+                                 page_table_row, prompt_len, start=start,
+                                 impl=dcfg.attn_impl)
         h_last = hidden[jnp.clip(prompt_len - 1, 0, S - 1), 0]  # (H,)
         first = fused_sample(
             h_last[None], params["embed"], seed[None],
@@ -312,7 +316,7 @@ def decode_logits_tokenwise(params, config: GPTConfig, dcfg: DecodeConfig,
     pools = alloc_pools(config.num_layers, config.kv_heads, config.head_dim,
                         dcfg.cache)
     kp, vp = write_prompt_kv(pools["k"], pools["v"], ks, vs, page_table_row,
-                             jnp.int32(prefix))
+                             jnp.int32(prefix), impl=dcfg.attn_impl)
     pools = {"k": kp, "v": vp}
     step = make_decode_step(config, dcfg, return_logits=True)
     tables = jnp.zeros((B, page_table_row.shape[0]), jnp.int32) \

@@ -2,13 +2,9 @@
 
 The serving-side memory manager (the vLLM PagedAttention layout,
 recast for TPU static shapes): the KV cache for ALL resident sequences
-lives in ONE preallocated pool per layer —
-``(num_layers, num_pages, kv_heads, page_size, head_dim)`` for each of
-k and v (head-major pages: one (page, kv head) is a contiguous
-``(page_size, head_dim)`` tile, the block the decode-attention kernel
-DMAs — Mosaic needs a block's two minor dims whole or (8, 128)-aligned,
-which a one-head slice of a ``(kv_heads, head_dim)`` minor pair is
-not) — and every sequence owns a *page table*: a fixed-width row of
+lives in ONE preallocated pool —
+``(num_layers, num_pages, kv_heads, head_dim, page_size)`` for each of
+k and v — and every sequence owns a *page table*: a fixed-width row of
 page ids mapping its logical positions ``[p * page_size, (p+1) *
 page_size)`` onto pool pages.  Sequences of wildly different lengths
 pack the pool densely, admission/eviction recycles pages between
@@ -16,13 +12,40 @@ decode steps, and the decode step's SHAPES never change (the pool, the
 (max_batch, pages_per_seq) page-table block, the per-slot scalars), so
 it compiles exactly once.
 
+**The pool's order, and why.**  Pages are stored head-dim-major: one
+(page, kv head) is a contiguous ``(head_dim, page_size)`` tile with
+the page's positions in the minor dimension.  That is the order in
+which row-major IS the TPU's own tiled layout for the array — 128
+positions fill the 128 lanes, where a 64-wide head dim would leave
+half of them as padding and XLA would prefer ANOTHER layout for the
+array than the row-major one a Pallas kernel reads.  The pool lives in
+this one order for its whole life and nothing re-lays it out:
+
+- the decode-attention kernel reads a ``(head_dim, page_size)`` tile
+  of the stacked pool at ``(layer, page, kv head)`` from its block
+  index map (the layer and the page table are scalar-prefetched);
+- every program that writes the pool — the decode step, the verify
+  step, a prefill chunk, the prefill — writes through ONE aliased
+  Pallas call (:mod:`apex_tpu.ops.kv_write_pallas`): a page tile is
+  read, the written columns replaced, the tile written back;
+- the pools are the decode step's layer-loop CARRY.  No XLA op inside
+  a step produces a pool-sized value: an XLA scatter, slice or
+  ``dynamic_update_slice`` on the pool invites layout assignment to
+  copy the whole pool to the layout that op prefers and back (PERF.md,
+  PR 25: 57 ms of a 106 ms decode step, and the pool held twice).
+  tests/test_tpu_bringup.py compiles both programs for a v5e and pins
+  that.
+
+The plain-XLA read and write on the same order (``impl="xla"``) stay
+as the CPU path and the numerics specification.
+
 Storage dtype is configurable (bf16 default — halves the pool bytes;
 the attention kernels widen the page reads at the seam, the APX306
 contract).
 
 Page id 0 is the **garbage page**: :class:`PageAllocator` never hands
 it out, and every masked write (inactive slot, padded prompt tail) is
-routed there instead of being predicated out — the scatter stays a
+routed there instead of being predicated out — the write stays a
 dense static-shape op and can never corrupt a live sequence's page.
 Every page-table read is clamped into the pool (the APX107 contract:
 a stale or corrupt table entry reads/writes garbage, never wraps).
@@ -57,6 +80,12 @@ class KVCacheConfig:
     capacity is ``num_pages - 1`` pages.  ``pages_per_seq`` is the
     page-table width: the longest supportable sequence is
     ``pages_per_seq * page_size`` positions.
+
+    On the chip ``page_size`` is the pool's minor (lane) dimension: a
+    multiple of 128 stores the pool with no padding; any other size is
+    correct and pads every page to the next multiple of 128 lanes (a
+    16-position page holds 8x its bytes).  ``head_dim`` rides the
+    sublanes, where multiples of 8 (fp32) or 16 (bf16) are whole.
     """
 
     num_pages: int = 128
@@ -87,10 +116,11 @@ def pages_needed(total_positions: int, page_size: int) -> int:
 def alloc_pools(num_layers: int, kv_heads: int, head_dim: int,
                 cfg: KVCacheConfig) -> Dict[str, jnp.ndarray]:
     """Zero-initialized k/v pools:
-    ``(L, num_pages, kv_heads, page_size, head_dim)`` each, in the
-    storage dtype.  Donated through the decode/prefill jits — the pool
-    is updated in place across the whole serve loop."""
-    shape = (num_layers, cfg.num_pages, kv_heads, cfg.page_size, head_dim)
+    ``(L, num_pages, kv_heads, head_dim, page_size)`` each, in the
+    storage dtype (head-dim-major pages: module doc).  Donated through
+    the decode/prefill jits — the pool is updated in place across the
+    whole serve loop."""
+    shape = (num_layers, cfg.num_pages, kv_heads, head_dim, cfg.page_size)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
@@ -188,6 +218,9 @@ def copy_page(pools, src: int, dst: int):
     once, before the first divergent write to a shared (refcount > 1)
     page, then repoints the writing sequence's page table at ``dst``.
     Neither side may be the reserved garbage page.
+
+    A program of its own, outside the steps, and plain XLA: it may copy
+    the pool to do it (the steps may not — module doc).
     """
     src, dst = int(src), int(dst)
     num_pages = pools["k"].shape[1]
@@ -202,59 +235,197 @@ def copy_page(pools, src: int, dst: int):
             "v": pools["v"].at[:, dst].set(pools["v"][:, src])}
 
 
-def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
-                    active):
-    """Scatter one decode step's k/v into a layer's pools.
+def _write(impl, k_new, k_pool, kernel_impl, xla_impl):
+    """The one dispatch between the in-place kernel
+    (``apex_kv_write``) and the plain-XLA write.  ``impl`` is the
+    step's ``attn_impl``, so the write follows the attention kernel
+    (same availability test, same forcing); a chosen kernel degrades
+    once to ``xla_impl`` through the fallback registry ("kv_write")."""
+    from apex_tpu.ops.decode_attention_pallas import dispatch_pool_kernel
 
-    ``k_pool``/``v_pool``: (num_pages, H_kv, page_size, D);
+    return dispatch_pool_kernel("kv_write", impl, k_new, k_pool,
+                                kernel_impl, xla_impl)
+
+
+def _row_targets(page_tables, positions, mask, page_size, num_pages):
+    """Per written ROW: its pool page (every table read clamped,
+    APX107; masked rows and positions outside the table go to the
+    garbage page) and its slot in the page."""
+    P = page_tables.shape[-1]
+    page_ix = positions // page_size
+    mask = mask & (page_ix >= 0) & (page_ix < P)
+    rows = jnp.take_along_axis(
+        page_tables, jnp.clip(page_ix, 0, P - 1)[:, None], axis=1)[:, 0]
+    dest = jnp.where(mask, jnp.clip(rows, 0, num_pages - 1), GARBAGE_PAGE)
+    slot = jnp.where(mask, positions % page_size, 0)
+    return dest, slot
+
+
+def _tile_targets(table_rows, page_ix, live, num_pages):
+    """Per written TILE (``page_ix``: its logical page in its
+    sequence's table row, ``live``: (..., page_size) the columns it
+    writes): the pool page — clamped; a tile outside the table or with
+    no live column goes to the garbage page — and the live columns."""
+    P = table_rows.shape[-1]
+    live = live & ((page_ix >= 0) & (page_ix < P))[..., None]
+    rows = jnp.take_along_axis(table_rows, jnp.clip(page_ix, 0, P - 1),
+                               axis=-1)
+    dest = jnp.where(jnp.any(live, axis=-1),
+                     jnp.clip(rows, 0, num_pages - 1), GARBAGE_PAGE)
+    return dest, live
+
+
+def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
+                    active, layer=None, width=1, impl="auto"):
+    """Write a decode step's k/v into ONE layer of the pools, in place.
+
+    ``k_pool``/``v_pool``: the stacked (L, num_pages, H_kv, D,
+    page_size) pools with ``layer`` the (traced) layer index — what the
+    decode step's layer loop carries — or one layer's 4-D pool
+    (``layer=None``: the ``layer=0`` case of a leading-1 view).
     ``k_new``/``v_new``: (B, H_kv, D) the current tokens' heads;
-    ``page_tables``: (B, P) int32; ``positions``: (B,) the tokens'
-    0-based positions; ``active``: (B,) bool — the WRITE mask (a
-    multi-position verify/chunk caller may pass a narrower mask than
+    ``page_tables``: (B // width, P) int32; ``positions``: (B,) the
+    tokens' 0-based positions; ``active``: (B,) bool — the WRITE mask
+    (a multi-position verify/chunk caller may pass a narrower mask than
     slot liveness, e.g. to leave shared prefix pages untouched).
-    Inactive rows write the garbage page; all page-table reads are
-    clamped (APX107).
+    Inactive rows write the garbage page or nothing; all page-table
+    reads are clamped (APX107).
+
+    ``width`` W > 1: rows come in groups of W CONSECUTIVE positions of
+    one sequence sharing a table row (``positions[s * W + w] ==
+    positions[s * W] + w``).  The kernel path places a group's rows at
+    their columns of the (at most ``ceil((W - 1) / page_size) + 1``)
+    page tiles they touch and writes each tile in ONE grid step.
+
+    ``impl``: "auto" | "pallas" | "interpret" | "xla" (the step's
+    ``attn_impl``).  "xla" is a plain scatter: correct everywhere, the
+    CPU path and the registry's degrade path — and slow on the chip,
+    where an XLA write of the pool makes layout assignment re-lay out
+    the whole pool around it (module doc).
     """
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[2]
-    P = page_tables.shape[1]
-    page_ix = jnp.clip(positions // page_size, 0, P - 1)
-    rows = jnp.take_along_axis(page_tables, page_ix[:, None], axis=1)[:, 0]
-    dest = jnp.where(active, jnp.clip(rows, 0, num_pages - 1), GARBAGE_PAGE)
-    slot = jnp.where(active, positions % page_size, 0)
-    # (dest, slot) are split by the head slice, so the indexed view is
-    # (B, H_kv, D) — k_new's own layout
-    k_pool = k_pool.at[dest, :, slot].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[dest, :, slot].set(v_new.astype(v_pool.dtype))
+    from apex_tpu.ops.decode_attention_pallas import as_stacked_pools
+
+    one_layer = k_pool.ndim == 4
+    k_pool, v_pool, layer = as_stacked_pools(k_pool, v_pool, layer)
+    _, num_pages, h_kv, D, page_size = k_pool.shape
+    B = k_new.shape[0]
+    S, P = page_tables.shape
+    if S * width != B:
+        raise ValueError(
+            f"rows ({B}) must equal page-table rows ({S}) x width "
+            f"({width})")
+    positions = positions.astype(jnp.int32)
+
+    def xla_impl():
+        tables = (jnp.repeat(page_tables, width, axis=0) if width > 1
+                  else page_tables)
+        dest, slot = _row_targets(tables, positions, active, page_size,
+                                  num_pages)
+        # advanced indices split by the (head, dim) slices lead the
+        # indexed view: (B, H_kv, D) — k_new's own layout
+        return (k_pool.at[layer, dest, :, :, slot].set(
+                    k_new.astype(k_pool.dtype)),
+                v_pool.at[layer, dest, :, :, slot].set(
+                    v_new.astype(v_pool.dtype)))
+
+    def kernel_impl():
+        from apex_tpu.ops.kv_write_pallas import kv_write_pallas
+
+        lane = jnp.arange(page_size, dtype=jnp.int32)
+        if width == 1:
+            # one tile a slot; the source is the new column, which
+            # every masked lane (only ``slot``) takes
+            dest, slot = _row_targets(page_tables, positions, active,
+                                      page_size, num_pages)
+            live = lane[None, :] == slot[:, None]
+
+            def tiles(x):
+                return x[None, :, None]
+        else:
+            n_t = (width + page_size - 2) // page_size + 1
+            first = positions.reshape(S, width)[:, 0]
+            page_ix = (first // page_size)[:, None] \
+                + jnp.arange(n_t, dtype=jnp.int32)[None, :]      # (S, n_t)
+            # column c of tile j holds the group's row w
+            w = page_ix[:, :, None] * page_size + lane[None, None, :] \
+                - first[:, None, None]                           # (S, n_t, p)
+            wc = jnp.clip(w, 0, width - 1).reshape(S, n_t * page_size)
+            live = ((w >= 0) & (w < width)) & jnp.take_along_axis(
+                active.reshape(S, width), wc, axis=1,
+                mode="clip").reshape(w.shape)
+            dest, live = _tile_targets(page_tables, page_ix, live, num_pages)
+            dest = dest.reshape(S * n_t)
+            live = live.reshape(S * n_t, page_size)
+
+            def tiles(x):
+                x = jnp.take_along_axis(x.reshape(S, width, h_kv, D),
+                                        wc[:, :, None, None], axis=1,
+                                        mode="clip")
+                return x.reshape(1, S * n_t, page_size, h_kv, D)
+
+        return tuple(kv_write_pallas(
+            k_pool, v_pool, tiles(k_new), tiles(v_new), dest, live, layer,
+            interpret=(impl == "interpret")))
+
+    k_pool, v_pool = _write(impl, k_new, k_pool, kernel_impl, xla_impl)
+    if one_layer:
+        return k_pool[0], v_pool[0]
     return k_pool, v_pool
 
 
 def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
-                    prompt_len, start=0):
-    """Scatter a prefilled prompt's k/v into ALL layers' pools at once.
+                    prompt_len, start=0, impl="auto"):
+    """Write a prefilled prompt's k/v into ALL layers' pools, in place.
 
-    ``k_pool``/``v_pool``: (L, num_pages, H_kv, page_size, D);
+    ``k_pool``/``v_pool``: (L, num_pages, H_kv, D, page_size);
     ``k_stack``/``v_stack``: (L, S, H_kv, D) the training forward's
     per-layer post-RoPE keys/values for the (padded) prompt;
     ``page_table_row``: (P,) the sequence's page table;
     ``prompt_len``: scalar int32 — positions >= it (the pad tail)
-    write the garbage page.  ``start``: scalar int32 — positions < it
-    ALSO write the garbage page: the prefix-sharing window (those
-    positions' k/v already live in shared pool pages, which must not be
-    rewritten through this sequence's table).
+    are not written (a tile of nothing but pad goes to the garbage
+    page).  ``start``: scalar int32 — positions < it are NOT written
+    either: the prefix-sharing window (those positions' k/v already
+    live in shared pool pages, which must not be rewritten through
+    this sequence's table).
+
+    The kernel path transposes the prompt's k/v into ``ceil(S /
+    page_size)`` page tiles a layer in XLA (the prompt's size, not the
+    pool's) and ``[start, prompt_len)`` becomes each tile's lane mask.
+    ``impl`` as :func:`write_decode_kv`.
     """
-    num_pages, page_size = k_pool.shape[1], k_pool.shape[3]
-    P = page_table_row.shape[0]
+    L, num_pages, h_kv, D, page_size = k_pool.shape
     S = k_stack.shape[1]
     s = jnp.arange(S, dtype=jnp.int32)
-    page_ix = jnp.clip(s // page_size, 0, P - 1)
-    rows = jnp.take(page_table_row, page_ix)
-    valid = (s >= start) & (s < prompt_len)
-    dest = jnp.where(valid, jnp.clip(rows, 0, num_pages - 1), GARBAGE_PAGE)
-    slot = jnp.where(valid, s % page_size, 0)
-    # advanced indices split by slices lead the indexed view: (S, L,
-    # H_kv, D)
-    k_pool = k_pool.at[:, dest, :, slot].set(
-        jnp.moveaxis(k_stack, 1, 0).astype(k_pool.dtype))
-    v_pool = v_pool.at[:, dest, :, slot].set(
-        jnp.moveaxis(v_stack, 1, 0).astype(v_pool.dtype))
-    return k_pool, v_pool
+
+    def xla_impl():
+        dest, slot = _row_targets(
+            jnp.broadcast_to(page_table_row[None],
+                             (S,) + page_table_row.shape),
+            s, (s >= start) & (s < prompt_len), page_size, num_pages)
+        # advanced indices split by slices lead the indexed view:
+        # (S, L, H_kv, D)
+        return (k_pool.at[:, dest, :, :, slot].set(
+                    jnp.moveaxis(k_stack, 1, 0).astype(k_pool.dtype)),
+                v_pool.at[:, dest, :, :, slot].set(
+                    jnp.moveaxis(v_stack, 1, 0).astype(v_pool.dtype)))
+
+    def kernel_impl():
+        from apex_tpu.ops.kv_write_pallas import kv_write_pallas
+
+        n_t = pages_needed(S, page_size)
+        col = jnp.arange(n_t * page_size, dtype=jnp.int32) \
+            .reshape(n_t, page_size)
+        dest, live = _tile_targets(
+            page_table_row, jnp.arange(n_t, dtype=jnp.int32),
+            (col >= start) & (col < prompt_len) & (col < S), num_pages)
+
+        def tiles(x):
+            x = jnp.pad(x, ((0, 0), (0, n_t * page_size - S), (0, 0),
+                            (0, 0)))
+            return x.reshape(L, n_t, page_size, h_kv, D)
+
+        return tuple(kv_write_pallas(
+            k_pool, v_pool, tiles(k_stack), tiles(v_stack), dest, live, 0,
+            interpret=(impl == "interpret")))
+
+    return _write(impl, k_stack, k_pool, kernel_impl, xla_impl)

@@ -632,8 +632,12 @@ def forward_decode(params, tokens, positions, active, kv_pools, page_tables,
     block expression (the LN/projection/MLP helpers are shared, run at
     sequence length 1), but attention is single-query over the page
     pool (:func:`apex_tpu.ops.decode_attention_pallas.decode_attention`)
-    and each layer first scatters the current token's post-RoPE k/v
-    into its pages.  Every shape is static — batch is the slot count,
+    and each layer first writes the current token's post-RoPE k/v
+    into its pages, in place
+    (:func:`apex_tpu.inference.kv_cache.write_decode_kv`).  The stacked
+    pools are the layer loop's carry and the layer index rides to both
+    kernels as a scalar: no layer is sliced out, no new pool stacked.
+    Every shape is static — batch is the slot count,
     the page-table block is (B, pages_per_seq) — so the jitted step
     compiles ONCE and is reused across all cache lengths and batch
     occupancies (inactive slots are masked, their writes land on the
@@ -694,17 +698,10 @@ def forward_decode(params, tokens, positions, active, kv_pools, page_tables,
     lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     if write_mask is None:
         write_mask = active
-    if verify_width > 1:
-        if B % verify_width != 0:
-            raise ValueError(
-                f"batch ({B}) must be a multiple of verify_width "
-                f"({verify_width})")
-        # one table ROW per sequence rides the attention seam as-is
-        # (the kernel folds b // width); the scatter wants a row per
-        # flattened position
-        write_tables = jnp.repeat(page_tables, verify_width, axis=0)
-    else:
-        write_tables = page_tables
+    if B % verify_width != 0:
+        raise ValueError(
+            f"batch ({B}) must be a multiple of verify_width "
+            f"({verify_width})")
 
     if axis_name is None:
         emb = jnp.take(params["embed"], tokens, axis=0)  # (B, H)
@@ -718,8 +715,12 @@ def forward_decode(params, tokens, positions, active, kv_pools, page_tables,
         x = x + pos[None]
     x = x.astype(config.compute_dtype)
 
-    def layer(x, inp):
-        p, k_pool, v_pool = inp
+    # the pools are the loop's CARRY and the layer index rides to the
+    # two kernels as a scalar: nothing slices a layer out, nothing
+    # stacks a new pool (kv_cache's module doc has the why)
+    def layer(carry, inp):
+        x, k_pool, v_pool = carry
+        p, li = inp
         ln1 = fused_layer_norm_affine(
             x, p["ln1_scale"], p["ln1_bias"], (H,), config.layernorm_eps)
         h = ln1.astype(config.compute_dtype)
@@ -733,9 +734,10 @@ def forward_decode(params, tokens, positions, active, kv_pools, page_tables,
             q = apply_rope_at(q, positions, config.rope_theta)
             k = apply_rope_at(k, positions, config.rope_theta)
         k_pool, v_pool = write_decode_kv(
-            k_pool, v_pool, k, v, write_tables, positions, write_mask)
+            k_pool, v_pool, k, v, page_tables, positions, write_mask,
+            layer=li, width=verify_width, impl=attn_impl)
         ctx = decode_attention(q, k_pool, v_pool, page_tables, lengths,
-                               impl=attn_impl, width=verify_width)
+                               impl=attn_impl, width=verify_width, layer=li)
         ctx = ctx.astype(config.compute_dtype).reshape(
             1, B, n_local_heads * hd)
         if axis_name is None:
@@ -749,10 +751,11 @@ def forward_decode(params, tokens, positions, active, kv_pools, page_tables,
         ln2 = fused_layer_norm_affine(
             x, p["ln2_scale"], p["ln2_bias"], (H,), config.layernorm_eps)
         x = x + _mlp(ln2.astype(config.compute_dtype), p, config, axis_name)
-        return x, (k_pool, v_pool)
+        return (x, k_pool, v_pool), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv_pools["k"], kv_pools["v"]))
+    (x, new_k, new_v), _ = jax.lax.scan(
+        layer, (x, kv_pools["k"], kv_pools["v"]),
+        (params["layers"], jnp.arange(config.num_layers, dtype=jnp.int32)))
     x = fused_layer_norm_affine(
         x, params["final_ln_scale"], params["final_ln_bias"], (H,),
         config.layernorm_eps)

@@ -10,11 +10,16 @@ Fusion", arxiv 2502.17728), so the whole per-head attention — page
 gather, scores, online softmax, weighted sum — runs as ONE kernel:
 
 - grid ``(batch, kv_heads, pages_per_seq)``, pages sequential;
-- the page table rides as a **scalar-prefetch** operand
+- the page table AND the layer ride as **scalar-prefetch** operands
   (``pltpu.PrefetchScalarGridSpec``), so each k/v BlockSpec index map
-  dereferences ``page_table[b, p]`` and the DMA fetches exactly that
-  page out of the pool — the gathered (B, S_max, H_kv, D) key tensor
-  the XLA reference materializes in HBM never exists here;
+  dereferences ``(layer, page_table[b, p])`` and the DMA fetches
+  exactly that ``(head_dim, page_size)`` tile out of the STACKED pool
+  — neither one layer's pool nor the gathered (B, S_max, H_kv, D) key
+  tensor the XLA reference materializes in HBM ever exists here;
+- pages are stored head-dim-major (:mod:`apex_tpu.inference.kv_cache`):
+  the page's positions sit in the lanes, which is the device's own
+  layout for the pool, so no consumer re-lays it out.  ``q·k``
+  contracts ``(1),(0)`` and ``p·v`` contracts ``(1),(1)``;
 - grouped-query attention reads the group-shared kv page ONCE per kv
   head and scores all ``H // H_kv`` q heads of the group against it
   (no ``repeat_kv_heads`` materialization, same as the flash kernels);
@@ -51,14 +56,36 @@ _DIM_SEMANTICS = pltpu.CompilerParams(
 
 
 # ---------------------------------------------------------------- reference
+def as_stacked_pools(k_pool, v_pool, layer):
+    """The pools as stacked 5-D arrays, with their layer.
+
+    One layer's (num_pages, H_kv, D, page_size) pool is the case
+    ``layer=0`` of a leading-1 view."""
+    if k_pool.ndim == 4:
+        if layer is not None:
+            raise ValueError("layer given with a one-layer (4-D) pool")
+        return k_pool[None], v_pool[None], 0
+    if layer is None:
+        raise ValueError("a stacked (5-D) pool needs its layer")
+    return k_pool, v_pool, layer
+
+
 def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
-                         softmax_scale=None, width=1):
+                         softmax_scale=None, width=1, layer=None):
     """Single-query attention over a paged KV cache, in XLA.
 
+    Correct everywhere; on the chip it is SLOW, and not only for the
+    gather: an XLA read of the pool inside a step makes layout
+    assignment copy the pool into the layout the gather prefers, once
+    a layer (PERF.md, PR 25) — the kernel exists so that nothing but
+    an aliased Pallas call ever touches the pool.
+
     ``q``: (B, H, D) — one query per sequence (the current token's
-    heads).  ``k_pool``/``v_pool``: (num_pages, H_kv, page_size, D)
-    one layer's page pool (head-major pages: each (page, kv head) is one
-    contiguous (page_size, D) tile — the block the kernel DMAs).
+    heads).  ``k_pool``/``v_pool``: the stacked pool (L, num_pages,
+    H_kv, D, page_size) with ``layer`` a (traced) scalar, or one
+    layer's (num_pages, H_kv, D, page_size) — head-dim-major pages:
+    each (page, kv head) is one contiguous (D, page_size) tile, the
+    block the kernel DMAs.
     ``page_table``: (B, P) int32 page ids,
     CLAMPED into the pool before the gather (a stale/garbage entry
     reads the reserved garbage page instead of wrapping).  ``lengths``:
@@ -78,8 +105,9 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
     sum) so decode logits can be compared bitwise against the training
     forward in fp32.
     """
+    k_pool, v_pool, layer = as_stacked_pools(k_pool, v_pool, layer)
     Bq, H, D = q.shape
-    num_pages, h_kv, page_size, _ = k_pool.shape
+    _, num_pages, h_kv, _, page_size = k_pool.shape
     B, P = page_table.shape
     group = H // h_kv
     if B * width != Bq:
@@ -87,9 +115,11 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
             f"q rows ({Bq}) must equal page-table rows ({B}) x width "
             f"({width})")
     pt = jnp.clip(page_table, 0, num_pages - 1)
-    # (B, P, H_kv, page, D) -> (B, H_kv, S_max, D)
-    k = k_pool[pt].transpose(0, 2, 1, 3, 4).reshape(B, h_kv, P * page_size, D)
-    v = v_pool[pt].transpose(0, 2, 1, 3, 4).reshape(B, h_kv, P * page_size, D)
+    # (B, P, H_kv, D, page) -> (B, H_kv, S_max, D)
+    k = k_pool[layer, pt].transpose(0, 2, 1, 4, 3) \
+        .reshape(B, h_kv, P * page_size, D)
+    v = v_pool[layer, pt].transpose(0, 2, 1, 4, 3) \
+        .reshape(B, h_kv, P * page_size, D)
     if group > 1:
         k = jnp.repeat(k, group, axis=1)
         v = jnp.repeat(v, group, axis=1)
@@ -129,13 +159,14 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
 
 
 # ------------------------------------------------------------------ kernel
-def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_ref, l_ref, acc_ref, *,
+def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                        o_ref, m_ref, l_ref, acc_ref, *,
                         page_size, pages_per_seq, denom, scale):
     """One (sequence, kv-head) pair; the sequential grid dim walks that
     sequence's pages through VMEM.  Online softmax exactly as the flash
     forward: running max/sum/accumulator in f32 scratch, finalize on
     the last page."""
+    del pt_ref, layer_ref  # consumed by the BlockSpec index maps
     b, p = pl.program_id(0), pl.program_id(2)
 
     @pl.when(p == 0)
@@ -152,15 +183,15 @@ def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(p * page_size < length)
     def _compute():
         q = q_ref[0, 0]          # (group, D)
-        k = k_ref[0, 0]          # (page, D) — group-shared GQA page
-        v = v_ref[0, 0]
+        k = k_ref[0, 0, 0]       # (D, page) — group-shared GQA page
+        v = v_ref[0, 0, 0]
         if k.dtype != q.dtype:
             # bf16 (or narrower) cache with an f32 query: widen the
             # cache read rather than rounding q down (APX306)
             k = k.astype(q.dtype)
             v = v.astype(q.dtype)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         s = s / denom if scale is None else s * scale
         pos = p * page_size + jax.lax.broadcasted_iota(
@@ -174,7 +205,7 @@ def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(pexp, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            pexp.astype(v.dtype), v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         acc_ref[:] = acc_ref[:] * corr + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -188,22 +219,23 @@ def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
                                   softmax_scale=None, width=1,
-                                  interpret=False):
+                                  interpret=False, layer=None):
     """The Pallas paged decode-attention launcher (see module doc).
 
-    Shapes as :func:`decode_attention_xla`.  The flattened page table
-    and the lengths ride as scalar-prefetch operands so the k/v
-    BlockSpec index maps can dereference them — each grid step DMAs
-    exactly one (page_size, D) page of the group-shared kv head out of
-    the pool.  With ``width`` > 1 (the verify/chunk layout: q rows in
+    Shapes as :func:`decode_attention_xla`.  The flattened page table,
+    the lengths and the layer ride as scalar-prefetch operands so the
+    k/v BlockSpec index maps can dereference them — each grid step DMAs
+    exactly one (D, page_size) tile of the group-shared kv head out of
+    the stacked pool, which is never sliced, copied or re-laid out.  With ``width`` > 1 (the verify/chunk layout: q rows in
     groups of ``width`` consecutive positions of one sequence) the
     index maps fold the row back onto its sequence's table row —
     ``pt[(b // width) * P + p]`` — so the table is prefetched once per
     SEQUENCE, not once per query row; ``width`` is static, one compile
     per verify width.
     """
+    k_pool, v_pool, layer = as_stacked_pools(k_pool, v_pool, layer)
     B, H, D = q.shape
-    num_pages, h_kv, page_size, _ = k_pool.shape
+    _, num_pages, h_kv, _, page_size = k_pool.shape
     n_seq, P = page_table.shape
     if H % h_kv != 0:
         raise ValueError(f"q heads ({H}) not divisible by kv heads ({h_kv})")
@@ -220,21 +252,18 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
         .reshape(n_seq * P).astype(jnp.int32)
 
     kv_spec = pl.BlockSpec(
-        (1, 1, page_size, D),
-        lambda b, g, p, pt_ref, len_ref: (pt_ref[(b // width) * P + p],
-                                          g, 0, 0),
+        (1, 1, 1, D, page_size),
+        lambda b, g, p, pt_ref, len_ref, layer_ref: (
+            layer_ref[0], pt_ref[(b // width) * P + p], g, 0, 0),
     )
+    q_spec = pl.BlockSpec(
+        (1, 1, group, D),
+        lambda b, g, p, pt_ref, len_ref, layer_ref: (b, g, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, h_kv, P),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, D),
-                         lambda b, g, p, pt_ref, len_ref: (b, g, 0, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, D),
-                               lambda b, g, p, pt_ref, len_ref: (b, g, 0, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((group, _LANES), jnp.float32),
             pltpu.VMEM((group, _LANES), jnp.float32),
@@ -251,23 +280,55 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
         name="apex_decode_attention",
-    )(pt, lengths.astype(jnp.int32), qg, k_pool, v_pool)
+    )(pt, lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool, v_pool)
     return out.reshape(B, H, D)
 
 
 # ---------------------------------------------------------------- dispatch
 def pallas_decode_attn_available(q, k_pool) -> bool:
-    """Kernel path: real TPU, MXU-friendly head dim, sublane-aligned
-    pages.  (No env-var override — thread ``attn_impl`` through
-    :class:`apex_tpu.inference.DecodeConfig` instead; APX101/102.)"""
+    """Kernel path: a real TPU and a sublane-aligned head dim.
+
+    A k/v block is a whole (head_dim, page_size) tile, so any page size
+    lowers; one under 128 pads the lanes.  (No env-var override — thread
+    ``attn_impl`` through :class:`apex_tpu.inference.DecodeConfig`
+    instead; APX101/102.)"""
     from apex_tpu.utils.platform import on_tpu
 
-    return (on_tpu() and q.shape[-1] % 8 == 0 and k_pool.shape[2] % 8 == 0
+    return (on_tpu() and q.shape[-1] % 8 == 0
+            and k_pool.shape[-2] == q.shape[-1]
             and q.dtype in (jnp.float32, jnp.bfloat16))
 
 
+def dispatch_pool_kernel(name, impl, q, k_pool, kernel_impl, xla_impl):
+    """Run one of the two kernels that touch the KV pool, or its XLA
+    twin: ``impl`` "xla" is the twin, "pallas"/"interpret" force the
+    kernel (fail loudly), "auto" is the kernel where
+    :func:`pallas_decode_attn_available` and the twin elsewhere — and a
+    chosen (not forced) kernel routes through the fallback registry
+    under ``name``, degrading to the twin once.  Shared by the read
+    (:func:`decode_attention`) and the write
+    (:mod:`apex_tpu.inference.kv_cache`), which follow one ``attn_impl``.
+    """
+    if impl not in ("auto", "pallas", "interpret", "xla"):
+        raise ValueError(
+            f"impl must be 'auto', 'pallas', 'interpret', or 'xla'; "
+            f"got {impl!r}")
+    if impl == "xla":
+        return xla_impl()
+    forced = impl in ("pallas", "interpret")
+    if not forced and not pallas_decode_attn_available(q, k_pool):
+        return xla_impl()
+
+    from apex_tpu.resilience.fallback import get_registry, registry_engaged
+
+    if registry_engaged(forced=forced):
+        return get_registry().call(name, kernel_impl, xla_impl)
+    return kernel_impl()
+
+
 def decode_attention(q, k_pool, v_pool, page_table, lengths,
-                     impl="auto", softmax_scale=None, width=1):
+                     impl="auto", softmax_scale=None, width=1, layer=None):
     """Paged single-query decode attention — the ONE dispatch between
     the Pallas kernel and the XLA reference.
 
@@ -281,30 +342,23 @@ def decode_attention(q, k_pool, v_pool, page_table, lengths,
     ("decode_attention"): the first Mosaic/launch failure degrades this
     process to the reference once, with one structured warning, instead
     of killing the serve loop (:mod:`apex_tpu.resilience.fallback`).
-    """
-    if impl not in ("auto", "pallas", "interpret", "xla"):
-        raise ValueError(
-            f"impl must be 'auto', 'pallas', 'interpret', or 'xla'; "
-            f"got {impl!r}")
 
+    ``k_pool``/``v_pool`` are the stacked pools with ``layer`` the
+    (traced) layer index — the kernel indexes the layer in its block
+    index map, so a layer loop carries the pools untouched — or one
+    layer's 4-D pool (``layer=None``), the ``layer=0`` case of a
+    leading-1 view.
+    """
     def xla_impl():
         return decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
-                                    softmax_scale=softmax_scale, width=width)
-
-    if impl == "xla":
-        return xla_impl()
-    forced = impl in ("pallas", "interpret")
-    if not forced and not pallas_decode_attn_available(q, k_pool):
-        return xla_impl()
+                                    softmax_scale=softmax_scale, width=width,
+                                    layer=layer)
 
     def kernel_impl():
         return paged_decode_attention_pallas(
             q, k_pool, v_pool, page_table, lengths,
             softmax_scale=softmax_scale, width=width,
-            interpret=(impl == "interpret"))
+            interpret=(impl == "interpret"), layer=layer)
 
-    from apex_tpu.resilience.fallback import get_registry, registry_engaged
-
-    if registry_engaged(forced=forced):
-        return get_registry().call("decode_attention", kernel_impl, xla_impl)
-    return kernel_impl()
+    return dispatch_pool_kernel("decode_attention", impl, q, k_pool,
+                                kernel_impl, xla_impl)

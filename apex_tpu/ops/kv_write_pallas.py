@@ -1,0 +1,148 @@
+"""Pallas TPU in-place write into the paged KV pool.
+
+Every program that writes the pool (:mod:`apex_tpu.inference.kv_cache`)
+does so through this ONE kernel, ``apex_kv_write``, with the pools
+aliased input-to-output.  The reason is layout, not speed of the write
+itself: an XLA scatter or ``dynamic_update_slice`` on the pool inside a
+step makes XLA's layout assignment re-lay out the WHOLE pool to the
+layout the write prefers and back again (PERF.md, PR 25: more than
+half of a decode step at GPT-2 large, and a step that stops fitting
+the chip once the pool is the layer loop's carry).  An aliased custom
+call has no preferred layout, so the pool stays where it is.
+
+The unit of a write is a **page tile**: one pool page across all kv
+heads, ``(kv_heads, head_dim, page_size)``, positions in the lanes
+(split over a block of heads a step where all of them would not fit
+VMEM).  One grid step reads the tile, replaces the masked columns from a
+source and writes it back::
+
+    tile[:, :, c] = src[:, :, c]  where mask[c]  else  tile[:, :, c]
+
+The three writers differ only in what XLA prepares around it (all of
+it small — never pool-sized):
+
+- the decode token: the source is the new column, one value a (head,
+  head-dim element), broadcast along the lanes; the mask is ``lane ==
+  slot``; one tile a slot;
+- ``width`` consecutive rows of a sequence (speculative verify, a
+  prefill chunk): XLA places the rows at their columns of up to
+  ``ceil((width - 1) / page_size) + 1`` source tiles a sequence;
+- the prompt: its k/v transposed into ``ceil(S / page_size)`` source
+  tiles a layer, the grid running over the layers too.
+
+**One grid step a block of a tile, never two.**  The pipeline reads
+step ``t + 1``'s block while step ``t`` computes, before ``t``'s
+write-back: two steps on one tile would lose the first's columns.  Callers hand every
+live tile to exactly one step; steps with nothing to write go to the
+garbage page, whose content nobody reads.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from apex_tpu.ops._pallas_tiling import LANES, VMEM_BUDGET
+
+__all__ = ["kv_write_pallas"]
+
+
+def _heads_per_block(h_kv, head_dim, page_size, dtype) -> int:
+    """The most kv heads a block can hold: six blocks a step (two pool
+    tiles in, two sources, two tiles out), each double-buffered, within
+    half the VMEM budget.  A page under 128 pads the lanes."""
+    per_head = head_dim * max(page_size, LANES) * jnp.dtype(dtype).itemsize
+    fit = max(1, (VMEM_BUDGET // 2) // (12 * per_head))
+    return max(d for d in range(1, h_kv + 1) if h_kv % d == 0 and d <= fit)
+
+
+def _source_blocks(x, hb, dtype):
+    """Sources as the kernel reads them: (Ls, T, C, H_kv, D) -> (Ls, T,
+    H_kv // hb, D, hb * C) — head dim on the sublanes as in the pool,
+    and a block's ``hb`` heads side by side in the lanes, so that 20
+    heads of one column are 20 lanes, not 20 lane-padded vectors (a
+    (H_kv, D, 1) source is 128x its bytes on the chip and took longer
+    to make than the write itself, PERF.md PR 25)."""
+    Ls, T, C, h_kv, D = x.shape
+    x = x.astype(dtype).reshape(Ls, T, C, h_kv // hb, hb, D)
+    return x.transpose(0, 1, 3, 5, 4, 2).reshape(
+        Ls, T, h_kv // hb, D, hb * C)
+
+
+def _kv_write_kernel(dest_ref, layer_ref, mask_ref, k_src_ref, v_src_ref,
+                     k_pool_ref, v_pool_ref, k_out_ref, v_out_ref, *,
+                     heads, cols):
+    del dest_ref, layer_ref  # consumed by the BlockSpec index maps
+    keep_new = mask_ref[0] != 0          # (1, page): broadcasts over D
+    for src_ref, pool_ref, out_ref in ((k_src_ref, k_pool_ref, k_out_ref),
+                                       (v_src_ref, v_pool_ref, v_out_ref)):
+        src = src_ref[0, 0, 0]           # (D, heads * cols)
+        for h in range(heads):
+            # (D, page) columns of head h, or its one column (D, 1)
+            new = src[:, h * cols:(h + 1) * cols]
+            out_ref[0, 0, h] = jnp.where(keep_new, new, pool_ref[0, 0, h])
+
+
+def kv_write_pallas(k_pool, v_pool, k_src, v_src, dest, mask, layer,
+                    interpret=False):
+    """Write source tiles into pool pages, in place.
+
+    ``k_pool``/``v_pool``: (L, num_pages, H_kv, D, page_size), donated
+    to the result.  ``k_src``/``v_src``: (Ls, T, C, H_kv, D) — per
+    tile its ``C`` columns, each a token's heads as the model computes
+    them; ``C`` is ``page_size`` (a whole tile) or 1 (the one column
+    that every masked lane takes).  ``dest``: (T,) int32 page ids, ALREADY
+    clamped into the pool and garbage-routed by the caller, live ones
+    pairwise distinct (module doc).  ``mask``: (T, page_size) bool, the
+    columns to take from the source.  ``layer``: scalar int32; source
+    layer ``l`` lands in pool layer ``layer + l`` (the decode step
+    passes ``Ls = 1`` and its loop index, the prefill ``Ls = L`` and
+    0).  Returns the two pools.
+    """
+    _, _, h_kv, D, page_size = k_pool.shape
+    Ls, T, C = k_src.shape[:3]
+    if k_src.shape != (Ls, T, C, h_kv, D) or C not in (1, page_size) \
+            or v_src.shape != k_src.shape or v_pool.shape != k_pool.shape:
+        raise ValueError(
+            f"sources {k_src.shape}/{v_src.shape} do not fit pools "
+            f"{k_pool.shape}/{v_pool.shape}")
+    if mask.shape != (T, page_size) or dest.shape != (T,):
+        raise ValueError(
+            f"dest {dest.shape} / mask {mask.shape} do not match {T} "
+            f"tiles of {page_size} columns")
+
+    hb = _heads_per_block(h_kv, D, page_size, k_pool.dtype)
+    src_spec = pl.BlockSpec(
+        (1, 1, 1, D, hb * C),
+        lambda l, t, h, dest_ref, layer_ref: (l, t, h, 0, 0))
+    pool_spec = pl.BlockSpec(
+        (1, 1, hb, D, page_size),
+        lambda l, t, h, dest_ref, layer_ref: (
+            layer_ref[0] + l, dest_ref[t], h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(Ls, T, h_kv // hb),
+        in_specs=[
+            pl.BlockSpec((1, 1, page_size),
+                         lambda l, t, h, dest_ref, layer_ref: (t, 0, 0)),
+            src_spec, src_spec, pool_spec, pool_spec,
+        ],
+        out_specs=[pool_spec, pool_spec],
+    )
+    pool_t = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
+    # operand numbering counts the two prefetched scalars
+    return pl.pallas_call(
+        functools.partial(_kv_write_kernel, heads=hb, cols=C),
+        grid_spec=grid_spec,
+        out_shape=[pool_t, pool_t],
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="apex_kv_write",
+    )(dest.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      mask.astype(jnp.int32).reshape(T, 1, page_size),
+      _source_blocks(k_src, hb, k_pool.dtype),
+      _source_blocks(v_src, hb, v_pool.dtype), k_pool, v_pool)

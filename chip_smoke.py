@@ -56,7 +56,8 @@ TRAIN_KERNELS = {"apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv",
 SERVE_ARGV = ["--streams", "8", "--requests", "16", "--prompt-len", "128",
               "--max-new", "64", "--attn-impl", "pallas",
               "--sample-impl", "pallas"]  # widths: serve_gpt's 124M defaults
-SERVE_KERNELS = {"apex_decode_attention", "apex_fused_sample", "apex_ln_fwd"}
+SERVE_KERNELS = {"apex_decode_attention", "apex_kv_write", "apex_fused_sample",
+                 "apex_ln_fwd"}
 
 # Kernel-vs-reference bounds for one bf16 forward+backward of GPT-345M.
 # Both sides round activations to bf16 at every op and differ only in

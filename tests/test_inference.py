@@ -83,6 +83,28 @@ class TestDecodeParity:
             err_msg="token-by-token decode logits diverged from the "
                     "training forward beyond fp32 reduction-reorder ulps")
 
+    @pytest.mark.parametrize("page", [4, 16])
+    def test_decode_logits_match_training_through_the_kernels(self, page):
+        """The same parity with the in-place write and the paged read
+        as KERNELS (interpreted): the pools ride the layer loop's carry
+        through two aliased Pallas calls a layer, the prompt lands
+        through the prompt write, and the logits still match the
+        training forward."""
+        cfg = tiny_cfg(num_query_groups=2, num_layers=3)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        rng = np.random.RandomState(1)
+        S, prefix = 12, 5
+        tokens = jnp.asarray(rng.randint(0, cfg.vocab_size, size=(1, S)))
+        ref = gpt_forward(params, tokens, cfg)
+        P = -(-S // page) + 1
+        kcfg = KVCacheConfig(num_pages=P + 2, page_size=page,
+                             pages_per_seq=P, dtype=jnp.float32)
+        dec = _decode_logits_tokenwise(
+            params, cfg, tokens, prefix, kcfg,
+            jnp.arange(1, P + 1, dtype=jnp.int32), attn_impl="interpret")
+        np.testing.assert_allclose(
+            np.asarray(dec), np.asarray(ref[prefix:, 0]), rtol=0, atol=5e-6)
+
     def test_first_token_decode_is_bitwise(self):
         """At matching contraction shapes (a length-1 sequence) the
         decode expression IS the training expression: bitwise fp32.
@@ -173,8 +195,8 @@ class TestDecodeParity:
 class TestDecodeAttentionKernel:
     def _case(self, rng, B=3, H=4, KVH=2, D=16, num_pages=9, page=8, P=4):
         q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
-        kp = jnp.asarray(rng.randn(num_pages, KVH, page, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(num_pages, KVH, page, D), jnp.float32)
+        kp = jnp.asarray(rng.randn(num_pages, KVH, D, page), jnp.float32)
+        vp = jnp.asarray(rng.randn(num_pages, KVH, D, page), jnp.float32)
         pt = jnp.asarray(rng.randint(1, num_pages, size=(B, P)), jnp.int32)
         return q, kp, vp, pt
 
@@ -311,7 +333,7 @@ class TestKVCache:
 
     def test_inactive_decode_write_hits_garbage_page_only(self):
         rng = np.random.RandomState(0)
-        kp = jnp.asarray(rng.randn(4, 1, 2, 8), jnp.float32)
+        kp = jnp.asarray(rng.randn(4, 1, 8, 2), jnp.float32)
         vp = kp + 1
         k_new = jnp.ones((2, 1, 8))
         pt = jnp.asarray([[2], [3]], jnp.int32)
@@ -322,15 +344,155 @@ class TestKVCache:
         np.testing.assert_array_equal(np.asarray(nv[1:]), np.asarray(vp[1:]))
 
     def test_prompt_pad_tail_hits_garbage_page_only(self):
-        kp = jnp.zeros((2, 5, 1, 4, 8))
+        kp = jnp.zeros((2, 5, 1, 8, 4))
         ks = jnp.ones((2, 6, 1, 8))
         row = jnp.asarray([2, 3], jnp.int32)
         nk, _ = write_prompt_kv(kp, kp, ks, ks, row, jnp.int32(5))
         # positions 0..4 land in pages 2 (0..3) and 3 (slot 0); the
         # padded position 5 must NOT touch page 3 slot 1
-        assert float(jnp.sum(jnp.abs(nk[:, 3, :, 1:]))) == 0.0
+        assert float(jnp.sum(jnp.abs(nk[:, 3, :, :, 1:]))) == 0.0
         assert float(jnp.sum(nk[:, 2])) == 4 * 8 * 2
-        assert float(jnp.sum(nk[:, 3, :, 0])) == 8 * 2
+        assert float(jnp.sum(nk[:, 3, :, :, 0])) == 8 * 2
+
+
+# ------------------------------------------------- the pool stays in place
+def _np_write_rows(pool, new, tables, positions, mask, layer, width):
+    """The plain reference of a decode/verify write on the head-dim-major
+    pool: row by row, in NumPy.  Page 0 (garbage) is not modelled."""
+    out = np.array(pool, np.float32)
+    n_pages, page = out.shape[1], out.shape[-1]
+    P = tables.shape[1]
+    for i in range(new.shape[0]):
+        page_ix = int(positions[i]) // page
+        if not mask[i] or not 0 <= page_ix < P:
+            continue
+        dest = int(np.clip(tables[i // width, page_ix], 0, n_pages - 1))
+        out[layer, dest, :, :, int(positions[i]) % page] = \
+            np.asarray(new[i], np.float32)
+    return out
+
+
+class TestPoolInPlace:
+    """The in-place kernel write (``apex_kv_write``, interpreted) and
+    the kernel read on the head-dim-major stacked pool, against a plain
+    NumPy write and ``decode_attention_xla``."""
+
+    @pytest.mark.parametrize("width", [1, 3])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["fp32", "bf16"])
+    @pytest.mark.parametrize("D", [64, 128])
+    @pytest.mark.parametrize("page", [4, 16, 128])
+    def test_write_then_attend_matches_plain_reference(self, page, D,
+                                                       dtype, width):
+        rng = np.random.RandomState(page + D + width)
+        L, layer, N, KVH, H, S, P = 2, 1, 11, 2, 4, 4, 3
+        kp = jnp.asarray(rng.randn(L, N, KVH, D, page), dtype)
+        vp = jnp.asarray(rng.randn(L, N, KVH, D, page), dtype)
+        # distinct live pages a sequence (the allocator's contract)
+        pt = np.asarray(rng.permutation(np.arange(1, N))[:S * 2]
+                        .reshape(S, 2).tolist(), np.int32)
+        pt = np.concatenate([pt, np.zeros((S, P - 2), np.int32)], axis=1)
+        # seq 0: a fresh sequence; seq 1: its rows straddle pages 0|1
+        # (width 3) or sit on a page's last slot; seq 2: INACTIVE; seq 3:
+        # mid-page
+        first = np.array([0, page - 1, 1, min(page + 1, 2 * page - width)])
+        pos = (first[:, None] + np.arange(width)[None]).reshape(-1)
+        act = np.repeat(np.array([True, True, False, True]), width)
+        kn = jnp.asarray(rng.randn(S * width, KVH, D), dtype)
+        vn = jnp.asarray(rng.randn(S * width, KVH, D), dtype)
+
+        got = {impl: write_decode_kv(
+            kp, vp, kn, vn, jnp.asarray(pt), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(act), layer=layer, width=width, impl=impl)
+            for impl in ("interpret", "xla")}
+        for which, (pool, new) in enumerate(((kp, kn), (vp, vn))):
+            want = _np_write_rows(pool, new, pt, pos, act, layer, width)
+            for impl, pools in got.items():
+                # everything but the garbage page, bit for bit: the
+                # written columns, every untouched page and layer, and
+                # the inactive slot's pages (its write lands on page 0
+                # and nowhere else)
+                np.testing.assert_array_equal(
+                    np.asarray(pools[which], np.float32)[:, 1:],
+                    want[:, 1:], err_msg=f"{impl} write, pool {which}")
+
+        k_new, v_new = got["interpret"]
+        q = jnp.asarray(rng.randn(S * width, H, D), jnp.float32)
+        lengths = jnp.asarray(np.where(act, pos + 1, 0), jnp.int32)
+        ref = decode_attention_xla(q, k_new, v_new, jnp.asarray(pt),
+                                   lengths, width=width, layer=layer)
+        out = paged_decode_attention_pallas(
+            q, k_new, v_new, jnp.asarray(pt), lengths, width=width,
+            interpret=True, layer=layer)
+        tol = 1e-5 if dtype == jnp.float32 else 0.05
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            rtol=0, atol=tol)
+        rows = np.asarray(out, np.float32).reshape(S, width, H, D)
+        assert float(np.abs(rows[2]).max()) == 0.0, (
+            "the inactive sequence must attend to nothing")
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["fp32", "bf16"])
+    @pytest.mark.parametrize("page", [4, 16, 128])
+    def test_prompt_write_window_and_padded_tail(self, page, dtype):
+        """``start > 0`` (shared-prefix window) and a padded tail:
+        only positions in ``[start, prompt_len)`` reach the sequence's
+        pages, in every layer; kernel and XLA agree with a NumPy
+        loop outside the garbage page."""
+        rng = np.random.RandomState(page)
+        L, N, KVH, D, P = 3, 9, 2, 64, 4
+        S = 2 * page + page // 2 + 1         # a ragged last tile
+        start, plen = page // 2 + 1, S - 2
+        kp = jnp.asarray(rng.randn(L, N, KVH, D, page), dtype)
+        vp = kp + 1
+        ks = jnp.asarray(rng.randn(L, S, KVH, D), dtype)
+        vs = jnp.asarray(rng.randn(L, S, KVH, D), dtype)
+        row = np.asarray([5, 2, 7, 3], np.int32)
+        want = [np.array(kp, np.float32), np.array(vp, np.float32)]
+        for s in range(start, plen):
+            for w, x in zip(want, (ks, vs)):
+                w[:, row[s // page], :, :, s % page] = \
+                    np.asarray(x[:, s], np.float32)
+        for impl in ("interpret", "xla"):
+            got = write_prompt_kv(kp, vp, ks, vs, jnp.asarray(row),
+                                  jnp.int32(plen), start=jnp.int32(start),
+                                  impl=impl)
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(
+                    np.asarray(g, np.float32)[:, 1:], w[:, 1:],
+                    err_msg=f"{impl} prompt write")
+
+    def test_positions_outside_the_table_go_to_the_garbage_page(self):
+        """A position past the page table (a caller's bug) must not
+        alias the table's last page: both paths drop it on page 0."""
+        kp = jnp.zeros((1, 4, 1, 8, 4))
+        kn = jnp.ones((2, 1, 8))
+        pt = jnp.asarray([[2], [3]], jnp.int32)      # P = 1: positions 0..3
+        pos = jnp.asarray([4, -1], jnp.int32)
+        for impl in ("interpret", "xla"):
+            nk, _ = write_decode_kv(kp, kp, kn, kn, pt, pos,
+                                    jnp.asarray([True, True]), layer=0,
+                                    impl=impl)
+            assert float(jnp.abs(nk[:, 1:]).sum()) == 0.0, impl
+
+    def test_one_layer_pool_is_layer_zero_of_a_stacked_view(self):
+        rng = np.random.RandomState(0)
+        kp = jnp.asarray(rng.randn(5, 2, 8, 4), jnp.float32)
+        kn = jnp.asarray(rng.randn(2, 2, 8), jnp.float32)
+        pt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+        pos = jnp.asarray([5, 2], jnp.int32)
+        act = jnp.asarray([True, True])
+        flat = write_decode_kv(kp, kp, kn, kn, pt, pos, act,
+                               impl="interpret")
+        stacked = write_decode_kv(kp[None], kp[None], kn, kn, pt, pos, act,
+                                  layer=0, impl="interpret")
+        np.testing.assert_array_equal(np.asarray(flat[0]),
+                                      np.asarray(stacked[0][0]))
+        with pytest.raises(ValueError, match="layer"):
+            write_decode_kv(kp[None], kp[None], kn, kn, pt, pos, act)
+        with pytest.raises(ValueError, match="layer"):
+            decode_attention_xla(kn, kp[None], kp[None], pt, pos + 1)
 
 
 # -------------------------------------------------------------- scheduler

@@ -83,8 +83,8 @@ class TestVerifyWidthAttention:
     def _case(self, rng, B=2, W=3, H=4, KVH=2, D=16, num_pages=9, page=8,
               P=4):
         q = jnp.asarray(rng.randn(B * W, H, D), jnp.float32)
-        kp = jnp.asarray(rng.randn(num_pages, KVH, page, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(num_pages, KVH, page, D), jnp.float32)
+        kp = jnp.asarray(rng.randn(num_pages, KVH, D, page), jnp.float32)
+        vp = jnp.asarray(rng.randn(num_pages, KVH, D, page), jnp.float32)
         pt = jnp.asarray(rng.randint(1, num_pages, size=(B, P)), jnp.int32)
         lengths = jnp.asarray(
             rng.randint(0, page * P, size=(B * W,)), jnp.int32)

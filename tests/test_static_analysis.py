@@ -1772,6 +1772,55 @@ class TestKvPoolScatterBypassesSeam:
             """, tmp_path, [KvPoolScatterBypassesSeam()])
         assert got == []
 
+    def test_finding_says_why_a_raw_write_costs_a_pool_copy(self, tmp_path):
+        """The message carries the second reason (the chip's): a raw
+        XLA write makes XLA re-lay out the pool; the fix names the
+        in-place kernel write."""
+        got = run("""
+            def poison(pools, page, slot, val):
+                return pools["k"].at[page, slot].set(val)
+            """, tmp_path, [KvPoolScatterBypassesSeam()])
+        assert "layout" in got[0].message
+        assert "apex_kv_write" in got[0].fix_hint
+
+    @pytest.mark.parametrize("call", [
+        "jax.lax.dynamic_update_slice(k_pool, row, (layer, page, 0, 0, 0))",
+        "lax.dynamic_update_slice_in_dim(pools['k'], row, page, 1)",
+        "jax.lax.dynamic_update_index_in_dim(kv_cache, row, page, 1)"])
+    def test_positive_dynamic_update_slice_on_a_pool(self, tmp_path, call):
+        """No routed spelling exists for the slice-write family: on a
+        pool it is always a finding, clamped index or not."""
+        got = run(f"""
+            import jax
+            from jax import lax
+            import jax.numpy as jnp
+
+            def write(k_pool, pools, kv_cache, row, layer, page, n):
+                page = jnp.clip(page, 0, n - 1)
+                return {call}
+            """, tmp_path, [KvPoolScatterBypassesSeam()])
+        assert rule_ids(got) == ["APX110"]
+        assert "layout" in got[0].message
+
+    def test_negative_dynamic_update_slice_elsewhere(self, tmp_path):
+        got = run("""
+            import jax
+
+            def bump(acc, row, i):
+                return jax.lax.dynamic_update_slice(acc, row, (i, 0))
+            """, tmp_path, [KvPoolScatterBypassesSeam()])
+        assert got == []
+
+    def test_negative_targets_from_the_seam_helpers(self, tmp_path):
+        """kv_cache's own XLA twin: (dest, slot) unpacked from
+        ``_row_targets`` are the seam's output."""
+        got = run("""
+            def write(k_pool, tables, positions, active, k_new, layer):
+                dest, slot = _row_targets(tables, positions, active, 16, 9)
+                return k_pool.at[layer, dest, :, :, slot].set(k_new)
+            """, tmp_path, [KvPoolScatterBypassesSeam()])
+        assert got == []
+
 
 # ------------------------------ APX306 kv-cache read dtype (decode path)
 class TestKvCacheReadDtypeMismatch:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax import export as jexport
 
+from apex_tpu.inference.kv_cache import write_decode_kv, write_prompt_kv
 from apex_tpu.ops.decode_attention_pallas import paged_decode_attention_pallas
 from apex_tpu.ops.decode_sampling_pallas import fused_sample_pallas
 from apex_tpu.ops.flash_attention_pallas import flash_attention_pallas
@@ -39,13 +40,34 @@ BF16, F32, I32, U32 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.uint32
 VOCAB = 50304
 
 
-def _decode_attn(heads, kv_heads, width):
-    B, D, P, page, pages = 8, 64, 12, 16, 97
-    pool = ((pages, kv_heads, page, D), BF16)
+def _decode_attn(heads, kv_heads, width, page=16):
+    B, D, P, pages = 8, 64, 12, 97
+    pool = ((pages, kv_heads, D, page), BF16)
     return (lambda q, k, v, pt, n: paged_decode_attention_pallas(
         q, k, v, pt, n, width=width),
         [((B * width, heads, D), BF16), pool, pool, ((B, P), I32),
          ((B * width,), I32)])
+
+
+def _kv_write(kv_heads, width, page=16, dtype=BF16, D=64):
+    """One layer's decode/verify write into a 4-layer stacked pool."""
+    B, P, pages = 8, 12, 97
+    pool = ((4, pages, kv_heads, D, page), dtype)
+    new = ((B * width, kv_heads, D), BF16)
+    return (lambda k, v, kn, vn, pt, pos, act, layer: write_decode_kv(
+        k, v, kn, vn, pt, pos, act, layer=layer, width=width,
+        impl="pallas"),
+        [pool, pool, new, new, ((B, P), I32), ((B * width,), I32),
+         ((B * width,), jnp.bool_), ((), I32)])
+
+
+def _prompt_write(kv_heads, page=16):
+    L, S, P, pages, D = 4, 128, 12, 97, 64
+    pool = ((L, pages, kv_heads, D, page), BF16)
+    stack = ((L, S, kv_heads, D), BF16)
+    return (lambda k, v, ks, vs, row, n, start: write_prompt_kv(
+        k, v, ks, vs, row, n, start=start, impl="pallas"),
+        [pool, pool, stack, stack, ((P,), I32), ((), I32), ((), I32)])
 
 
 def _sample(temperature, top_k, rows=8):
@@ -87,6 +109,22 @@ CASES = {
     "decode_attn_mqa": (*_decode_attn(12, 1, 1), {"apex_decode_attention"}),
     "decode_attn_verify5": (*_decode_attn(12, 12, 5),
                             {"apex_decode_attention"}),
+    "decode_attn_page128": (*_decode_attn(20, 20, 1, page=128),
+                            {"apex_decode_attention"}),
+    # the in-place pool writes: decode token, verify rows, prompt;
+    # page 16 (chip_smoke) and 128 (the benchmark's cells); an fp32
+    # cache at head dim 128 splits a tile over blocks of heads
+    "kv_write_decode": (*_kv_write(12, 1), {"apex_kv_write"}),
+    "kv_write_gqa4": (*_kv_write(4, 1), {"apex_kv_write"}),
+    "kv_write_verify5": (*_kv_write(12, 5), {"apex_kv_write"}),
+    "kv_write_page128": (*_kv_write(20, 1, page=128), {"apex_kv_write"}),
+    "kv_write_verify3_page128": (*_kv_write(20, 3, page=128),
+                                 {"apex_kv_write"}),
+    "kv_write_fp32_d128": (*_kv_write(20, 1, page=128, dtype=F32, D=128),
+                           {"apex_kv_write"}),
+    "kv_write_prompt": (*_prompt_write(12), {"apex_kv_write"}),
+    "kv_write_prompt_page128": (*_prompt_write(20, page=128),
+                                {"apex_kv_write"}),
     "sample_greedy": (*_sample(0.0, 0), {"apex_fused_sample"}),
     "sample_t1": (*_sample(1.0, 0), {"apex_fused_sample"}),
     "sample_t1_top40": (*_sample(1.0, 40), {"apex_fused_sample"}),
@@ -118,7 +156,8 @@ def test_kernel_lowers_for_tpu(name):
     assert "tpu_custom_call" in exp.mlir_module()
 
 
-_AOT_CHILD = """
+#: a compile-only v5e device, or one JSON line {"skip": why} and exit 0
+_DESCRIBED_V5E = """
 import json, os, sys
 os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
 os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
@@ -132,6 +171,9 @@ try:
 except Exception as e:  # no compile-only TPU client on this installation
     print(json.dumps({"skip": f"{type(e).__name__}: {e}"[:300]}))
     sys.exit(0)
+"""
+
+_AOT_CHILD = _DESCRIBED_V5E + """
 sys.path.insert(0, "tests")
 from test_tpu_bringup import CASES
 from apex_tpu.analysis.lowered import pallas_kernels
@@ -162,6 +204,99 @@ def test_kernels_compile_for_v5e_without_a_chip():
     bad = {k: v for k, v in out["missing"].items() if v}
     assert not bad, (f"on {out['device_kind']}: kernels that failed to "
                      f"compile, or compiled without their name: {bad}")
+
+
+# --------------------------------------------- the pool stays where it is
+_POOL_CHILD = _DESCRIBED_V5E + """
+import jax.numpy as jnp
+from apex_tpu.analysis.lowered import large_result_instructions
+from apex_tpu.inference import DecodeConfig, KVCacheConfig, alloc_pools
+from apex_tpu.inference.decode import make_decode_step, make_prefill
+from apex_tpu.models.gpt import GPTConfig, init_params
+
+# GPT-2 large as the benchmark's serve cells run it: 20 slots x 1,024
+# tokens at page 128, prompts padded to 768, kernels forced
+B, PAGE, PPS, S = 20, 128, 8, 768
+cfg = GPTConfig(vocab_size=50304, hidden_size=1280, num_layers=36,
+                num_attention_heads=20, max_seq_len=1024,
+                position_embedding_type="learned",
+                compute_dtype=jnp.bfloat16, checkpoint_layers=False)
+dcfg = DecodeConfig(
+    cache=KVCacheConfig(num_pages=1 + B * PPS, page_size=PAGE,
+                        pages_per_seq=PPS, dtype=jnp.bfloat16),
+    max_batch=B, max_prompt_len=S, temperature=0.0,
+    attn_impl="pallas", sample_impl="pallas")
+sh = SingleDeviceSharding(dev)
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+params = put(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+pools = put(jax.eval_shape(lambda: alloc_pools(
+    cfg.num_layers, cfg.kv_heads, cfg.head_dim, dcfg.cache)))
+I, U = jnp.int32, jnp.uint32
+programs = {
+    "decode_step": (make_decode_step(cfg, dcfg), (
+        params, pools, arg((B,), I), arg((B,), I), arg((B,), jnp.bool_),
+        arg((B, PPS), I), arg((B,), U))),
+    "prefill": (make_prefill(cfg, dcfg), (
+        params, pools, arg((1, S), I), arg((), I), arg((), I),
+        arg((PPS,), I), arg((), U))),
+}
+layer_pool = pools["k"].size // cfg.num_layers
+out = {"pool_bytes": pools["k"].size * 2}
+for name, (fn, args) in programs.items():
+    try:
+        c = fn.lower(*args).compile()
+    except Exception as e:
+        out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}
+        continue
+    found = large_result_instructions(
+        c, layer_pool, containing=(dcfg.cache.num_pages, cfg.kv_heads))
+    out[name] = {
+        "temp_bytes": c.memory_analysis().temp_size_in_bytes,
+        "instructions": [
+            [i["name"], i["opcode"],
+             "tpu_custom_call" in i["line"]
+             and "output_to_operand_aliasing" in i["line"]]
+            for i in found]}
+print(json.dumps(out))
+"""
+
+#: what may carry a pool through a compiled step without copying it
+_POOL_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while"}
+
+
+def test_no_program_copies_the_kv_pool():
+    """The decode step and the prefill, compiled for a v5e at GPT-2
+    large's serving shapes, hold no instruction that PRODUCES a value
+    as large as one layer's KV pool: only parameters, the tuples and
+    the ``while`` that carry the pools, and the aliased Pallas calls
+    that write them in place.  A ``copy``, ``fusion``, ``scatter`` or
+    ``dynamic-update-slice`` of that size is XLA re-laying out, slicing
+    or rebuilding the pool around a write or a read (PERF.md, PR 25:
+    eight such copies were 54% of a decode step and held the pool
+    twice).  Temporaries stay under one pool's bytes."""
+    r = subprocess.run([sys.executable, "-c", _POOL_CHILD], cwd=str(REPO),
+                       capture_output=True, text=True, timeout=600,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(f"no compile-only TPU client: {out['skip']}")
+    for name in ("decode_step", "prefill"):
+        got = out[name]
+        assert "error" not in got, f"{name} does not compile: {got}"
+        bad = [(n, op) for n, op, aliased_kernel in got["instructions"]
+               if op not in _POOL_PLUMBING
+               and not (op == "custom-call" and aliased_kernel)]
+        assert not bad, (
+            f"{name}: instructions that produce a pool-sized value: {bad}")
+        kernels = [n for n, op, _ in got["instructions"]
+                   if op == "custom-call"]
+        assert kernels, f"{name}: no aliased kernel writes the pool"
+        assert got["temp_bytes"] < out["pool_bytes"], (
+            f"{name}: {got['temp_bytes']} B of temporaries, one pool is "
+            f"{out['pool_bytes']} B")
 
 
 # ----------------------------------------------------------- compile cache
