@@ -1,11 +1,21 @@
 """Fused single-token decode step and the prefill step.
 
+The steps are built for a **served model**: any object with
+``cache_spec()`` (pool name -> ``(layers, heads, dim)``), ``prefill``,
+``decode``, ``head(params)`` (the (V, H) matrix the sampling head
+multiplies), ``multi_position``, ``counter_names`` and
+``max_positions`` — or a model configuration whose ``served_model()``
+gives one (:func:`served`).  ``models.gpt.GPTServed`` is the first,
+``models.mla_moe.MLAMoEServed`` (a latent cache, held experts) the
+second; nothing here imports a model (docs/inference.md has the
+interface).
+
 ``make_decode_step`` builds ONE jitted function that advances every
 resident sequence by one token: embedding lookup, all transformer
-blocks (QKV projection, RoPE at each sequence's own position, paged
-single-query attention, MLP — the block code shared with training via
-:func:`apex_tpu.models.gpt.forward_decode`), and the fused sampling
-head (logits → temperature/top-k → token in one kernel,
+blocks (projections, RoPE at each sequence's own position, paged
+single-query attention, FFN — the block code shared with the model's
+full forward, e.g. :func:`apex_tpu.models.gpt.forward_decode`), and the
+fused sampling head (logits → temperature/top-k → token in one kernel,
 :mod:`apex_tpu.ops.decode_sampling_pallas` — the full-vocab fp32
 softmax never reaches HBM).
 
@@ -23,29 +33,36 @@ re-laid out (:mod:`apex_tpu.inference.kv_cache` has the why;
 tests/test_tpu_bringup.py pins it on the compiled programs).
 
 ``make_prefill`` runs an admitted sequence's prompt through the
-EXISTING training forward (``gpt_forward(return_kv=True)``) at one
-static padded shape, writes the captured per-layer k/v into the
-sequence's pages as page tiles, and samples the first generated token
-from the last prompt position's hidden state.
+model's full forward (GPT: the training forward,
+``gpt_forward(return_kv=True)``) at a static padded shape — ONE
+(``max_prompt_len``) or the few of ``prefill_buckets`` — writes the
+captured per-layer cache columns into the sequence's pages as page
+tiles, and samples the first generated token from the last prompt
+position's hidden state.
 """
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from apex_tpu.inference.kv_cache import (
-    KVCacheConfig, alloc_pools, write_prompt_kv,
+    KVCacheConfig, alloc_named_pools, write_prompt_pools,
 )
-from apex_tpu.models.gpt import GPTConfig, forward_decode, gpt_forward
 from apex_tpu.ops.decode_sampling_pallas import fused_sample
 
 __all__ = [
     "DecodeConfig", "decode_logits_tokenwise", "make_decode_step",
     "make_prefill", "make_prefill_chunk", "make_sample_head",
-    "make_verify_step",
+    "make_verify_step", "served",
 ]
+
+
+def served(model):
+    """The served model: ``model`` itself, or — given a model
+    configuration — what its ``served_model()`` builds."""
+    return model.served_model() if hasattr(model, "served_model") else model
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +73,10 @@ class DecodeConfig:
 
     ``max_batch``: decode-slot count (the step's batch dimension).
     ``max_prompt_len``: the prefill pad length (one prefill compile).
+    ``prefill_buckets``: further, shorter pad lengths: a prompt is
+    padded to the shortest of them (or ``max_prompt_len``) that holds
+    it — one prefill compile a length, for prompt mixes whose median is
+    a fraction of the longest.
     ``temperature``/``top_k``: the sampling head; ``temperature=0`` is
     greedy argmax and ignores ``top_k``.
     ``attn_impl``/``sample_impl``: "auto" | "pallas" | "interpret" |
@@ -90,8 +111,21 @@ class DecodeConfig:
     ngram_min: int = 1
     prefill_chunk: Optional[int] = None
     prefix_sharing: bool = False
+    prefill_buckets: Tuple[int, ...] = ()
+
+    @property
+    def prefill_lengths(self) -> Tuple[int, ...]:
+        """Every padded prompt length the prefill compiles for,
+        ascending; the last is ``max_prompt_len``."""
+        return tuple(sorted({int(b) for b in self.prefill_buckets}
+                            | {self.max_prompt_len}))
 
     def __post_init__(self):
+        if any(not 1 <= int(b) <= self.max_prompt_len
+               for b in self.prefill_buckets):
+            raise ValueError(
+                f"prefill_buckets {self.prefill_buckets} must lie in "
+                f"[1, max_prompt_len = {self.max_prompt_len}]")
         if self.max_batch < 1:
             raise ValueError("max_batch must be >= 1")
         if self.temperature < 0.0:
@@ -112,15 +146,16 @@ class DecodeConfig:
                              f"{self.prefill_chunk}); None disables it")
 
 
-def make_decode_step(config: GPTConfig, dcfg: DecodeConfig,
+def make_decode_step(model, dcfg: DecodeConfig,
                      return_logits: bool = False):
     """Build the jitted one-token-per-sequence decode step.
 
     Returns ``step(params, pools, tokens, positions, active,
     page_tables, seeds) -> (pools, next_tokens)`` with
 
-    - ``pools``: the ``{"k", "v"}`` page pools (DONATED — rebind on
-      every call);
+    - ``pools``: the carried cache state — the model's named page
+      pools (GPT: ``{"k", "v"}``), with its device-side counters under
+      ``"counters"`` if it keeps any (DONATED — rebind on every call);
     - ``tokens``/``positions``/``active``: (B,) current token ids,
       their positions, slot liveness; inactive slots are fully masked
       (their cache writes land on the garbage page, their sampled
@@ -133,16 +168,18 @@ def make_decode_step(config: GPTConfig, dcfg: DecodeConfig,
     training forward computes it — for the prefill↔decode parity band;
     serving never materializes those logits.
     """
+    m = served(model)
+
     def step(params, pools, tokens, positions, active, page_tables, seeds):
-        hidden, pools = forward_decode(
+        hidden, pools = m.decode(
             params, tokens, positions, active, pools, page_tables,
-            config, attn_impl=dcfg.attn_impl)
+            attn_impl=dcfg.attn_impl)
         if return_logits:
             logits = jnp.matmul(hidden.astype(jnp.float32),
-                                params["embed"].T.astype(jnp.float32))
+                                m.head(params).T.astype(jnp.float32))
             return pools, logits
         next_tokens = fused_sample(
-            hidden, params["embed"], seeds,
+            hidden, m.head(params), seeds,
             temperature=dcfg.temperature, top_k=dcfg.top_k,
             impl=dcfg.sample_impl, dot_dtype=dcfg.sample_dot_dtype)
         return pools, next_tokens
@@ -150,7 +187,7 @@ def make_decode_step(config: GPTConfig, dcfg: DecodeConfig,
     return jax.jit(step, donate_argnums=(1,))
 
 
-def make_verify_step(config: GPTConfig, dcfg: DecodeConfig):
+def make_verify_step(model, dcfg: DecodeConfig):
     """Build the jitted speculative VERIFY step — the decode step grown
     to ``W = draft_len + 1`` positions per slot, still compile-once.
 
@@ -178,6 +215,7 @@ def make_verify_step(config: GPTConfig, dcfg: DecodeConfig):
     yields the standard-path token.
     """
     W = dcfg.draft_len + 1
+    m = served(model)
 
     def verify(params, pools, tokens, positions, active, page_tables,
                seeds):
@@ -185,12 +223,12 @@ def make_verify_step(config: GPTConfig, dcfg: DecodeConfig):
         off = jnp.arange(W, dtype=jnp.int32)
         pos_f = (positions.astype(jnp.int32)[:, None]
                  + off[None, :]).reshape(B * W)
-        hidden, pools = forward_decode(
+        hidden, pools = m.decode(
             params, tokens.reshape(B * W), pos_f,
-            jnp.repeat(active, W), pools, page_tables, config,
+            jnp.repeat(active, W), pools, page_tables,
             attn_impl=dcfg.attn_impl, verify_width=W)
         sampled = fused_sample(
-            hidden, params["embed"], seeds.reshape(B * W),
+            hidden, m.head(params), seeds.reshape(B * W),
             temperature=dcfg.temperature, top_k=dcfg.top_k,
             impl=dcfg.sample_impl, dot_dtype=dcfg.sample_dot_dtype)
         return pools, sampled.reshape(B, W)
@@ -198,43 +236,44 @@ def make_verify_step(config: GPTConfig, dcfg: DecodeConfig):
     return jax.jit(verify, donate_argnums=(1,))
 
 
-def make_prefill(config: GPTConfig, dcfg: DecodeConfig):
-    """Build the jitted prompt-prefill step (one static padded shape).
+def make_prefill(model, dcfg: DecodeConfig):
+    """Build the jitted prompt-prefill step (one compile a padded
+    length: ``dcfg.prefill_lengths``).
 
     Returns ``prefill(params, pools, prompt, prompt_len, start,
     page_table_row, seed) -> (pools, first_token)`` where ``prompt``
-    is (1, max_prompt_len) int32 (zero-padded past ``prompt_len``; the
-    padded tail's k/v go to the garbage page and its causal rows are
-    never read), ``start`` is the prefix-sharing write window (k/v for
-    positions < ``start`` already live in shared pool pages and are
-    NOT rewritten; 0 = unshared), ``page_table_row`` is the admitted
-    sequence's (P,) table, and ``first_token`` is sampled from the
-    LAST prompt position's hidden state with the same sampling head as
-    decode.  Pools donate, as in the decode step.
+    is (1, S) int32, S one of the padded lengths (zero-padded past
+    ``prompt_len``; the padded tail's cache columns go to the garbage
+    page and its causal rows are never read), ``start`` is the
+    prefix-sharing write window (positions < ``start`` already live in
+    shared pool pages and are NOT rewritten; 0 = unshared),
+    ``page_table_row`` is the admitted sequence's (P,) table, and
+    ``first_token`` is sampled from the LAST prompt position's hidden
+    state with the same sampling head as decode.  Pools donate, as in
+    the decode step.
     """
-    S = dcfg.max_prompt_len
+    m = served(model)
 
     def prefill(params, pools, prompt, prompt_len, start, page_table_row,
                 seed):
-        hidden, kv = gpt_forward(params, prompt, config,
-                                 return_hidden=True, return_kv=True)
-        k_stack, v_stack = kv  # (L, 1, KVH, S, hd)
-        ks = k_stack[:, 0].transpose(0, 2, 1, 3)  # (L, S, KVH, hd)
-        vs = v_stack[:, 0].transpose(0, 2, 1, 3)
-        kp, vp = write_prompt_kv(pools["k"], pools["v"], ks, vs,
-                                 page_table_row, prompt_len, start=start,
-                                 impl=dcfg.attn_impl)
+        S = prompt.shape[1]
+        hidden, stacks = m.prefill(params, prompt, prompt_len,
+                                   dcfg.attn_impl)
+        names = sorted(stacks)
+        written = write_prompt_pools(
+            [pools[n] for n in names], [stacks[n] for n in names],
+            page_table_row, prompt_len, start=start, impl=dcfg.attn_impl)
         h_last = hidden[jnp.clip(prompt_len - 1, 0, S - 1), 0]  # (H,)
         first = fused_sample(
-            h_last[None], params["embed"], seed[None],
+            h_last[None], m.head(params), seed[None],
             temperature=dcfg.temperature, top_k=dcfg.top_k,
             impl=dcfg.sample_impl, dot_dtype=dcfg.sample_dot_dtype)
-        return {"k": kp, "v": vp}, first[0]
+        return dict(pools, **dict(zip(names, written))), first[0]
 
     return jax.jit(prefill, donate_argnums=(1,))
 
 
-def make_prefill_chunk(config: GPTConfig, dcfg: DecodeConfig):
+def make_prefill_chunk(model, dcfg: DecodeConfig):
     """Build the jitted chunked-prefill step: ONE compile per chunk
     size serves every prompt length.
 
@@ -258,6 +297,7 @@ def make_prefill_chunk(config: GPTConfig, dcfg: DecodeConfig):
     streams).
     """
     C = int(dcfg.prefill_chunk)
+    m = served(model)
 
     def chunk(params, pools, tokens, start_pos, valid, write_start,
               page_table_row):
@@ -265,26 +305,25 @@ def make_prefill_chunk(config: GPTConfig, dcfg: DecodeConfig):
         pos = start_pos.astype(jnp.int32) + off
         act = off < valid
         wmask = act & (pos >= write_start)
-        hidden, pools = forward_decode(
+        hidden, pools = m.decode(
             params, tokens, pos, act, pools, page_table_row[None],
-            config, attn_impl=dcfg.attn_impl, verify_width=C,
-            write_mask=wmask)
+            attn_impl=dcfg.attn_impl, verify_width=C, write_mask=wmask)
         h_last = hidden[jnp.clip(valid - 1, 0, C - 1)]
         return pools, h_last
 
     return jax.jit(chunk, donate_argnums=(1,))
 
 
-def make_sample_head(config: GPTConfig, dcfg: DecodeConfig):
+def make_sample_head(model, dcfg: DecodeConfig):
     """The standalone jitted sampling head — hidden (H,) + seed →
     token — used once per chunked admission (the final chunk returns
     ``h_last``; sampling stays OUT of the chunk step so intermediate
     chunks never pay the vocab matmul)."""
-    del config  # the head is fully described by dcfg + params
+    m = served(model)
 
     def head(params, hidden, seed):
         tok = fused_sample(
-            hidden[None], params["embed"], seed[None],
+            hidden[None], m.head(params), seed[None],
             temperature=dcfg.temperature, top_k=dcfg.top_k,
             impl=dcfg.sample_impl, dot_dtype=dcfg.sample_dot_dtype)
         return tok[0]
@@ -292,33 +331,33 @@ def make_sample_head(config: GPTConfig, dcfg: DecodeConfig):
     return jax.jit(head)
 
 
-def decode_logits_tokenwise(params, config: GPTConfig, dcfg: DecodeConfig,
+def decode_logits_tokenwise(params, model, dcfg: DecodeConfig,
                             tokens, prefix: int, page_table_row):
-    """The decode↔training parity probe: prefill ``tokens[:, :prefix]``
-    through the training forward, then decode positions ``prefix..S-1``
-    one token at a time through the jitted decode step
+    """The decode↔full-forward parity probe: prefill ``tokens[:,
+    :prefix]`` through the model's full forward, then decode positions
+    ``prefix..S-1`` one token at a time through the jitted decode step
     (``return_logits=True``, ``dcfg``'s attention impl and cache dtype).
 
     ``tokens`` is (1, S); the sequence rides slot 0 of the
     ``dcfg.max_batch`` slots, the rest stay inactive.  Returns the
-    (S - prefix, V) fp32 logits that
-    ``gpt_forward(params, tokens, config)[prefix:, 0]`` must match — to
-    reduction-reorder ulps in fp32, to the storage dtype's rounding with
-    a bf16 cache (tests/test_inference.py; ``chip_smoke.py`` runs it on
-    the compiled kernels)."""
+    (S - prefix, V) fp32 logits that the model's full forward gives at
+    positions ``prefix..S-1`` (GPT: ``gpt_forward(params, tokens,
+    config)[prefix:, 0]``) — to reduction-reorder ulps in fp32, to the
+    storage dtype's rounding with a bf16 cache (tests/test_inference.py;
+    ``chip_smoke.py`` runs it on the compiled kernels)."""
+    m = served(model)
     S = tokens.shape[1]
     B = dcfg.max_batch
-    _, kv = jax.jit(
-        lambda p, t: gpt_forward(p, t, config, return_kv=True))(
+    _, stacks = jax.jit(
+        lambda p, t: m.prefill(p, t, jnp.int32(S), dcfg.attn_impl))(
             params, tokens)
-    ks = kv[0][:, 0].transpose(0, 2, 1, 3)[:, :prefix]  # (L, prefix, KVH, hd)
-    vs = kv[1][:, 0].transpose(0, 2, 1, 3)[:, :prefix]
-    pools = alloc_pools(config.num_layers, config.kv_heads, config.head_dim,
-                        dcfg.cache)
-    kp, vp = write_prompt_kv(pools["k"], pools["v"], ks, vs, page_table_row,
-                             jnp.int32(prefix), impl=dcfg.attn_impl)
-    pools = {"k": kp, "v": vp}
-    step = make_decode_step(config, dcfg, return_logits=True)
+    names = sorted(stacks)
+    pools = alloc_named_pools(m.cache_spec(), dcfg.cache)
+    written = write_prompt_pools(
+        [pools[n] for n in names], [stacks[n][:, :prefix] for n in names],
+        page_table_row, jnp.int32(prefix), impl=dcfg.attn_impl)
+    pools = dict(zip(names, written))
+    step = make_decode_step(m, dcfg, return_logits=True)
     tables = jnp.zeros((B, page_table_row.shape[0]), jnp.int32) \
         .at[0].set(page_table_row)
     active = jnp.arange(B) == 0

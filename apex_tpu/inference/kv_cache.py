@@ -1,10 +1,16 @@
 """Paged KV cache: fixed-size pages in a preallocated pool.
 
 The serving-side memory manager (the vLLM PagedAttention layout,
-recast for TPU static shapes): the KV cache for ALL resident sequences
-lives in ONE preallocated pool —
-``(num_layers, num_pages, kv_heads, head_dim, page_size)`` for each of
-k and v — and every sequence owns a *page table*: a fixed-width row of
+recast for TPU static shapes): the cache for ALL resident sequences
+lives in preallocated pools —
+``(num_layers, num_pages, heads, dim, page_size)`` each — and WHICH
+pools is the served model's to say (its *cache spec*, name ->
+``(layers, heads, dim)``): GPT gives ``{"k", "v"}`` of ``kv_heads x
+head_dim``, a latent-attention model ONE pool of one "head" whose
+``dim`` is its compressed latent plus its shared rotary key.  The
+allocator, the page tables, the garbage page, :func:`copy_page` and the
+in-place writers serve any such set of named pools.  Every sequence
+owns a *page table*: a fixed-width row of
 page ids mapping its logical positions ``[p * page_size, (p+1) *
 page_size)`` onto pool pages.  Sequences of wildly different lengths
 pack the pool densely, admission/eviction recycles pages between
@@ -62,13 +68,21 @@ from typing import Any, Dict, List, Optional
 import jax.numpy as jnp
 
 __all__ = [
-    "GARBAGE_PAGE", "KVCacheConfig", "PageAllocator", "alloc_pools",
-    "copy_page", "pages_needed", "write_decode_kv", "write_prompt_kv",
+    "COUNTERS", "GARBAGE_PAGE", "KVCacheConfig", "PageAllocator",
+    "alloc_named_pools", "alloc_pools", "copy_page", "named_pools",
+    "pages_needed", "write_decode_kv", "write_decode_pools",
+    "write_prompt_kv", "write_prompt_pools",
 ]
 
 #: page id 0 — reserved, never allocated; the destination of every
 #: masked (inactive / padded) cache write
 GARBAGE_PAGE = 0
+
+#: the one key of a step's carried cache state that is no pool: a small
+#: int32 vector of device-side counters that a served model accumulates
+#: in its decode step (read back once, after a window, by
+#: ``ContinuousBatchingScheduler.read_counters``)
+COUNTERS = "counters"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,15 +127,29 @@ def pages_needed(total_positions: int, page_size: int) -> int:
     return -(-int(total_positions) // int(page_size))
 
 
+def alloc_named_pools(spec, cfg: KVCacheConfig) -> Dict[str, jnp.ndarray]:
+    """Zero-initialized pools from a served model's cache spec, name ->
+    ``(layers, heads, dim)``: ``(layers, num_pages, heads, dim,
+    page_size)`` each, in the storage dtype (dim-major pages: module
+    doc).  Donated through the decode/prefill jits — the pools are
+    updated in place across the whole serve loop."""
+    return {name: jnp.zeros((int(layers), cfg.num_pages, int(heads),
+                             int(dim), cfg.page_size), cfg.dtype)
+            for name, (layers, heads, dim) in spec.items()}
+
+
 def alloc_pools(num_layers: int, kv_heads: int, head_dim: int,
                 cfg: KVCacheConfig) -> Dict[str, jnp.ndarray]:
-    """Zero-initialized k/v pools:
-    ``(L, num_pages, kv_heads, head_dim, page_size)`` each, in the
-    storage dtype (head-dim-major pages: module doc).  Donated through
-    the decode/prefill jits — the pool is updated in place across the
-    whole serve loop."""
-    shape = (num_layers, cfg.num_pages, kv_heads, head_dim, cfg.page_size)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    """The k/v pools of a GPT-style cache:
+    ``(L, num_pages, kv_heads, head_dim, page_size)`` each."""
+    shape = (num_layers, kv_heads, head_dim)
+    return alloc_named_pools({"k": shape, "v": shape}, cfg)
+
+
+def named_pools(pools) -> Dict[str, jnp.ndarray]:
+    """The pools of a step's carried cache state, without its
+    :data:`COUNTERS` entry."""
+    return {k: v for k, v in pools.items() if k != COUNTERS}
 
 
 class PageAllocator:
@@ -211,7 +239,7 @@ class PageAllocator:
 # ----------------------------------------------------------- device writes
 def copy_page(pools, src: int, dst: int):
     """Copy-on-write seam: duplicate pool page ``src`` into ``dst``
-    across every layer of both pools.
+    across every layer of every named pool.
 
     ``src``/``dst`` are HOST ints handed out by :class:`PageAllocator`
     (``dst`` freshly allocated, refcount 1) — the scheduler calls this
@@ -223,7 +251,7 @@ def copy_page(pools, src: int, dst: int):
     the pool to do it (the steps may not — module doc).
     """
     src, dst = int(src), int(dst)
-    num_pages = pools["k"].shape[1]
+    num_pages = next(iter(named_pools(pools).values())).shape[1]
     for p in (src, dst):
         if not (GARBAGE_PAGE < p < num_pages):
             raise ValueError(
@@ -231,8 +259,9 @@ def copy_page(pools, src: int, dst: int):
                 f"allocatable pool (1, {num_pages})")
     if src == dst:
         raise ValueError(f"copy_page: src == dst == {src}")
-    return {"k": pools["k"].at[:, dst].set(pools["k"][:, src]),
-            "v": pools["v"].at[:, dst].set(pools["v"][:, src])}
+    return {name: (p if name == COUNTERS
+                   else p.at[:, dst].set(p[:, src]))
+            for name, p in pools.items()}
 
 
 def _write(impl, k_new, k_pool, kernel_impl, xla_impl):
@@ -275,15 +304,17 @@ def _tile_targets(table_rows, page_ix, live, num_pages):
     return dest, live
 
 
-def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
-                    active, layer=None, width=1, impl="auto"):
-    """Write a decode step's k/v into ONE layer of the pools, in place.
+def write_decode_pools(pools, news, page_tables, positions, active,
+                       layer=None, width=1, impl="auto"):
+    """Write a decode step's new columns into ONE layer of the pools,
+    in place.
 
-    ``k_pool``/``v_pool``: the stacked (L, num_pages, H_kv, D,
-    page_size) pools with ``layer`` the (traced) layer index — what the
-    decode step's layer loop carries — or one layer's 4-D pool
+    ``pools``: a tuple of pools of one shape (a cache's named pools in
+    a fixed order): the stacked (L, num_pages, H_kv, D, page_size)
+    pools with ``layer`` the (traced) layer index — what the decode
+    step's layer loop carries — or one layer's 4-D pools
     (``layer=None``: the ``layer=0`` case of a leading-1 view).
-    ``k_new``/``v_new``: (B, H_kv, D) the current tokens' heads;
+    ``news``: one (B, H_kv, D) array a pool, the current tokens' heads;
     ``page_tables``: (B // width, P) int32; ``positions``: (B,) the
     tokens' 0-based positions; ``active``: (B,) bool — the WRITE mask
     (a multi-position verify/chunk caller may pass a narrower mask than
@@ -301,14 +332,16 @@ def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
     ``attn_impl``).  "xla" is a plain scatter: correct everywhere, the
     CPU path and the registry's degrade path — and slow on the chip,
     where an XLA write of the pool makes layout assignment re-lay out
-    the whole pool around it (module doc).
+    the whole pool around it (module doc).  Returns the pools, as a
+    tuple in the order given.
     """
-    from apex_tpu.ops.decode_attention_pallas import as_stacked_pools
+    from apex_tpu.ops.decode_attention_pallas import stacked_pools
 
-    one_layer = k_pool.ndim == 4
-    k_pool, v_pool, layer = as_stacked_pools(k_pool, v_pool, layer)
-    _, num_pages, h_kv, D, page_size = k_pool.shape
-    B = k_new.shape[0]
+    one_layer = pools[0].ndim == 4
+    pools, layer = stacked_pools(tuple(pools), layer)
+    news = tuple(news)
+    _, num_pages, h_kv, D, page_size = pools[0].shape
+    B = news[0].shape[0]
     S, P = page_tables.shape
     if S * width != B:
         raise ValueError(
@@ -322,14 +355,12 @@ def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
         dest, slot = _row_targets(tables, positions, active, page_size,
                                   num_pages)
         # advanced indices split by the (head, dim) slices lead the
-        # indexed view: (B, H_kv, D) — k_new's own layout
-        return (k_pool.at[layer, dest, :, :, slot].set(
-                    k_new.astype(k_pool.dtype)),
-                v_pool.at[layer, dest, :, :, slot].set(
-                    v_new.astype(v_pool.dtype)))
+        # indexed view: (B, H_kv, D) — the new rows' own layout
+        return tuple(p.at[layer, dest, :, :, slot].set(x.astype(p.dtype))
+                     for p, x in zip(pools, news))
 
     def kernel_impl():
-        from apex_tpu.ops.kv_write_pallas import kv_write_pallas
+        from apex_tpu.ops.kv_write_pallas import pool_write_pallas
 
         lane = jnp.arange(page_size, dtype=jnp.int32)
         if width == 1:
@@ -363,38 +394,51 @@ def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
                                         mode="clip")
                 return x.reshape(1, S * n_t, page_size, h_kv, D)
 
-        return tuple(kv_write_pallas(
-            k_pool, v_pool, tiles(k_new), tiles(v_new), dest, live, layer,
-            interpret=(impl == "interpret")))
+        return pool_write_pallas(
+            pools, [tiles(x) for x in news], dest, live, layer,
+            interpret=(impl == "interpret"))
 
-    k_pool, v_pool = _write(impl, k_new, k_pool, kernel_impl, xla_impl)
+    pools = _write(impl, news[0], pools[0], kernel_impl, xla_impl)
     if one_layer:
-        return k_pool[0], v_pool[0]
-    return k_pool, v_pool
+        return tuple(p[0] for p in pools)
+    return tuple(pools)
 
 
-def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
-                    prompt_len, start=0, impl="auto"):
-    """Write a prefilled prompt's k/v into ALL layers' pools, in place.
+def write_decode_kv(k_pool, v_pool, k_new, v_new, page_tables, positions,
+                    active, layer=None, width=1, impl="auto"):
+    """:func:`write_decode_pools` for a cache of two pools, ``k`` and
+    ``v``.  Returns the two pools."""
+    return write_decode_pools(
+        (k_pool, v_pool), (k_new, v_new), page_tables, positions, active,
+        layer=layer, width=width, impl=impl)
 
-    ``k_pool``/``v_pool``: (L, num_pages, H_kv, D, page_size);
-    ``k_stack``/``v_stack``: (L, S, H_kv, D) the training forward's
-    per-layer post-RoPE keys/values for the (padded) prompt;
-    ``page_table_row``: (P,) the sequence's page table;
-    ``prompt_len``: scalar int32 — positions >= it (the pad tail)
-    are not written (a tile of nothing but pad goes to the garbage
-    page).  ``start``: scalar int32 — positions < it are NOT written
-    either: the prefix-sharing window (those positions' k/v already
-    live in shared pool pages, which must not be rewritten through
-    this sequence's table).
 
-    The kernel path transposes the prompt's k/v into ``ceil(S /
+def write_prompt_pools(pools, stacks, page_table_row, prompt_len, start=0,
+                       impl="auto"):
+    """Write a prefilled prompt's columns into ALL layers' pools, in
+    place.
+
+    ``pools``: a tuple of (L, num_pages, H_kv, D, page_size) pools of
+    one shape; ``stacks``: one (L, S, H_kv, D) array a pool — the
+    forward's per-layer values for the (padded) prompt, as they are
+    cached (a GPT's post-RoPE keys and its values; a latent model's
+    normed latent with its rotary key); ``page_table_row``: (P,) the
+    sequence's page table; ``prompt_len``: scalar int32 — positions >=
+    it (the pad tail) are not written (a tile of nothing but pad goes
+    to the garbage page).  ``start``: scalar int32 — positions < it are
+    NOT written either: the prefix-sharing window (those positions
+    already live in shared pool pages, which must not be rewritten
+    through this sequence's table).
+
+    The kernel path transposes the prompt's stacks into ``ceil(S /
     page_size)`` page tiles a layer in XLA (the prompt's size, not the
     pool's) and ``[start, prompt_len)`` becomes each tile's lane mask.
-    ``impl`` as :func:`write_decode_kv`.
+    ``impl`` as :func:`write_decode_pools`.  Returns the pools, as a
+    tuple in the order given.
     """
-    L, num_pages, h_kv, D, page_size = k_pool.shape
-    S = k_stack.shape[1]
+    pools, stacks = tuple(pools), tuple(stacks)
+    L, num_pages, h_kv, D, page_size = pools[0].shape
+    S = stacks[0].shape[1]
     s = jnp.arange(S, dtype=jnp.int32)
 
     def xla_impl():
@@ -404,13 +448,12 @@ def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
             s, (s >= start) & (s < prompt_len), page_size, num_pages)
         # advanced indices split by slices lead the indexed view:
         # (S, L, H_kv, D)
-        return (k_pool.at[:, dest, :, :, slot].set(
-                    jnp.moveaxis(k_stack, 1, 0).astype(k_pool.dtype)),
-                v_pool.at[:, dest, :, :, slot].set(
-                    jnp.moveaxis(v_stack, 1, 0).astype(v_pool.dtype)))
+        return tuple(p.at[:, dest, :, :, slot].set(
+                         jnp.moveaxis(x, 1, 0).astype(p.dtype))
+                     for p, x in zip(pools, stacks))
 
     def kernel_impl():
-        from apex_tpu.ops.kv_write_pallas import kv_write_pallas
+        from apex_tpu.ops.kv_write_pallas import pool_write_pallas
 
         n_t = pages_needed(S, page_size)
         col = jnp.arange(n_t * page_size, dtype=jnp.int32) \
@@ -424,8 +467,17 @@ def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
                             (0, 0)))
             return x.reshape(L, n_t, page_size, h_kv, D)
 
-        return tuple(kv_write_pallas(
-            k_pool, v_pool, tiles(k_stack), tiles(v_stack), dest, live, 0,
-            interpret=(impl == "interpret")))
+        return pool_write_pallas(
+            pools, [tiles(x) for x in stacks], dest, live, 0,
+            interpret=(impl == "interpret"))
 
-    return _write(impl, k_stack, k_pool, kernel_impl, xla_impl)
+    return tuple(_write(impl, stacks[0], pools[0], kernel_impl, xla_impl))
+
+
+def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
+                    prompt_len, start=0, impl="auto"):
+    """:func:`write_prompt_pools` for a cache of two pools, ``k`` and
+    ``v``.  Returns the two pools."""
+    return write_prompt_pools(
+        (k_pool, v_pool), (k_stack, v_stack), page_table_row, prompt_len,
+        start=start, impl=impl)

@@ -14,8 +14,9 @@ this scheduler fills its slots —
   not fit, the youngest best-effort resident is evicted through the
   ordinary evict→recycle path and requeued (continuation: prompt +
   tokens generated so far, remaining budget) at its lane's head.
-- **prefill**: an admitted prompt runs through the training forward at
-  ONE static padded shape (``DecodeConfig.max_prompt_len``) — or, with
+- **prefill**: an admitted prompt runs through the model's full forward
+  at a static padded shape (``DecodeConfig.max_prompt_len``, or the
+  shortest of ``prefill_buckets`` that holds it) — or, with
   ``prefill_chunk`` set, as fixed-size CHUNKS through the
   multi-position decode forward, one chunk per scheduler step,
   interleaved with resident streams' decode steps (arbitrary prompt
@@ -81,14 +82,14 @@ import jax.numpy as jnp
 
 from apex_tpu.inference.decode import (
     DecodeConfig, make_decode_step, make_prefill, make_prefill_chunk,
-    make_sample_head, make_verify_step,
+    make_sample_head, make_verify_step, served,
 )
 from apex_tpu.inference.kv_cache import (
-    GARBAGE_PAGE, PageAllocator, alloc_pools, copy_page, pages_needed,
+    COUNTERS, GARBAGE_PAGE, PageAllocator, alloc_named_pools, copy_page,
+    pages_needed,
 )
 from apex_tpu.inference.prefix import PrefixCache, PrefixMatch
 from apex_tpu.inference.spec import NGramProposer, accepted_tokens
-from apex_tpu.models.gpt import GPTConfig
 from apex_tpu.observability import metrics as _metrics
 from apex_tpu.observability import tracing as _tracing
 from apex_tpu.resilience.chaos import active_monkey
@@ -215,23 +216,35 @@ class ContinuousBatchingScheduler:
     sampling seeds, and degrade-once step rebuild on deferred kernel
     failures (see the module docstring for the full semantics)."""
 
-    def __init__(self, params, config: GPTConfig, dcfg: DecodeConfig,
+    def __init__(self, params, model, dcfg: DecodeConfig,
                  time_fn=time.monotonic, watchdog=None, anomaly=None):
+        """``model``: the served model (docs/inference.md: cache spec,
+        prefill, decode forward, head matrix), or a model configuration
+        whose ``served_model()`` builds it."""
         cache = dcfg.cache
-        if config.moe:
-            raise NotImplementedError("MoE decode is not wired")
-        if dcfg.max_prompt_len > config.max_seq_len \
-                and config.position_embedding_type == "learned":
+        self.model = served(model)
+        config = self.model.config
+        if (dcfg.draft_len > 0 or dcfg.prefill_chunk is not None) \
+                and not self.model.multi_position:
+            raise NotImplementedError(
+                f"{type(self.model).__name__}'s decode forward scores one "
+                "position a slot: speculative verify (draft_len) and "
+                "chunked prefill (prefill_chunk) are not built for it")
+        limit = self.model.max_positions
+        if limit is not None and dcfg.max_prompt_len > limit:
             raise ValueError(
                 f"max_prompt_len ({dcfg.max_prompt_len}) exceeds the "
-                f"learned position table ({config.max_seq_len})")
+                f"learned position table ({limit})")
         self.params = params
         self.config = config
         self.dcfg = dcfg
         self._time = time_fn
-        tp_local_kv = config.kv_heads  # single-process serving: tp=1
-        self.pools = alloc_pools(config.num_layers, tp_local_kv,
-                                 config.head_dim, cache)
+        # the carried cache state: the model's named pools and, if it
+        # keeps any, its device-side counters (read_counters)
+        self.pools = alloc_named_pools(self.model.cache_spec(), cache)
+        if self.model.counter_names:
+            self.pools[COUNTERS] = jnp.zeros(
+                (len(self.model.counter_names),), jnp.int32)
         self.allocator = PageAllocator(cache.num_pages)
         self.prefix: Optional[PrefixCache] = (
             PrefixCache(self.allocator, cache.page_size)
@@ -393,17 +406,17 @@ class ContinuousBatchingScheduler:
     def _build_steps(self) -> None:
         d = self.dcfg
         if d.draft_len > 0:
-            self._verify = make_verify_step(self.config, d)
+            self._verify = make_verify_step(self.model, d)
             self._decode = None
         else:
-            self._decode = make_decode_step(self.config, d)
+            self._decode = make_decode_step(self.model, d)
             self._verify = None
         if d.prefill_chunk is not None:
-            self._chunk = make_prefill_chunk(self.config, d)
-            self._sample_head = make_sample_head(self.config, d)
+            self._chunk = make_prefill_chunk(self.model, d)
+            self._sample_head = make_sample_head(self.model, d)
             self._prefill = None
         else:
-            self._prefill = make_prefill(self.config, d)
+            self._prefill = make_prefill(self.model, d)
             self._chunk = None
             self._sample_head = None
 
@@ -426,6 +439,16 @@ class ContinuousBatchingScheduler:
             self.params, self.pools, jnp.asarray(self._tokens),
             jnp.asarray(self._positions), jnp.asarray(self._active),
             jnp.asarray(self._page_tables), jnp.zeros((B,), jnp.uint32))
+
+    def read_counters(self) -> Dict[str, int]:
+        """The model's device-side counters (``counter_names``), summed
+        over every decode step so far: ONE readback, for after a window
+        — the steps themselves never read them."""
+        names = self.model.counter_names
+        if not names:
+            return {}
+        values = np.asarray(self.pools[COUNTERS])
+        return {n: int(v) for n, v in zip(names, values)}
 
     def _call(self, attr: str, *args):
         """Run a compiled step; on a deferred kernel-compile failure,
@@ -480,12 +503,12 @@ class ContinuousBatchingScheduler:
                 f"admit long prompts as chunks")
         if request.max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
-        if self.config.position_embedding_type == "learned" \
-                and plen + request.max_new_tokens > self.config.max_seq_len:
+        limit = self.model.max_positions
+        if limit is not None and plen + request.max_new_tokens > limit:
             raise ValueError(
                 f"prompt + max_new_tokens ({plen} + "
                 f"{request.max_new_tokens}) exceeds the learned position "
-                f"table ({self.config.max_seq_len})")
+                f"table ({limit})")
         need = self._total_pages(request)
         P = self.dcfg.cache.pages_per_seq
         if need > P:
@@ -688,13 +711,16 @@ class ContinuousBatchingScheduler:
             s.chunk_next = (match.shared_len if match.shared_len < plen
                             else plen - 1)
             return
-        prompt = np.zeros((1, self.dcfg.max_prompt_len), np.int32)
+        # padded to the shortest compiled length that holds the prompt
+        padded = next(b for b in self.dcfg.prefill_lengths if b >= plen)
+        prompt = np.zeros((1, padded), np.int32)
         prompt[0, :plen] = req.prompt
         # the span ends when the first token is ON THE HOST (the
         # readback waits for the device); dispatch_us is the enqueue
         with _tracing.span("serve.prefill", rid=req.rid,
                            trace_id=req.trace_id, lane=req.lane,
-                           prompt_len=plen,
+                           prompt_len=plen, tokens=plen,
+                           padded_tokens=padded,
                            shared_len=match.shared_len) as sp:
             self.pools, first = self._call(
                 "_prefill", self.params, self.pools,

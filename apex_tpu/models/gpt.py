@@ -130,6 +130,10 @@ class GPTConfig:
             )
         return self.num_query_groups
 
+    def served_model(self) -> "GPTServed":
+        """This family's served-model adapter for ``apex_tpu.inference``."""
+        return GPTServed(self)
+
 
 def init_params(config: GPTConfig, key) -> Dict[str, Any]:
     """Global (unsharded) fp32 params; shard via PartitionSpecs from
@@ -677,8 +681,10 @@ def forward_decode(params, tokens, positions, active, kv_pools, page_tables,
 
     if config.moe:
         raise NotImplementedError(
-            "MoE decode is not wired (expert routing at batch 1 needs "
-            "its own capacity plan); see ROADMAP follow-ons")
+            "this GPT block's MoE (capacity factor, dropped tokens) has "
+            "no decode path; the served expert layer is "
+            "expert_parallel.held_experts_ffn, which models.mla_moe "
+            "uses (docs/moe.md)")
     if config.sequence_parallel:
         raise ValueError(
             "sequence_parallel shards the sequence axis; a decode step "
@@ -766,6 +772,55 @@ def forward_decode(params, tokens, positions, active, kv_pools, page_tables,
 
         x = copy_to_tensor_model_parallel_region(x, axis_name)
     return x[0], {"k": new_k, "v": new_v}
+
+
+class GPTServed:
+    """What :mod:`apex_tpu.inference` needs of this family (the
+    served-model interface, docs/inference.md): the cache spec, the
+    prefill, the decode forward and the head matrix."""
+
+    #: the decode forward takes ``verify_width`` > 1 (speculative
+    #: verify, prefill chunks)
+    multi_position = True
+    counter_names = ()
+
+    def __init__(self, config: GPTConfig):
+        if config.moe:
+            raise NotImplementedError(
+                "this GPT block's MoE has no decode path (forward_decode)")
+        self.config = config
+
+    @property
+    def max_positions(self):
+        """Positions a learned table holds; None for rotary."""
+        c = self.config
+        return c.max_seq_len if c.position_embedding_type == "learned" \
+            else None
+
+    def cache_spec(self):
+        c = self.config  # single-process serving: tp = 1
+        shape = (c.num_layers, c.kv_heads, c.head_dim)
+        return {"k": shape, "v": shape}
+
+    def head(self, params):
+        return params["embed"]
+
+    def prefill(self, params, prompt, prompt_len, attn_impl):
+        """(1, S) padded prompt -> hidden (S, 1, H) and the post-RoPE
+        keys and values by pool name, (L, S, KVH, hd) each."""
+        del prompt_len, attn_impl  # the training forward pads causally
+        hidden, kv = gpt_forward(params, prompt, self.config,
+                                 return_hidden=True, return_kv=True)
+        k_stack, v_stack = kv  # (L, 1, KVH, S, hd)
+        return hidden, {"k": k_stack[:, 0].transpose(0, 2, 1, 3),
+                        "v": v_stack[:, 0].transpose(0, 2, 1, 3)}
+
+    def decode(self, params, tokens, positions, active, pools, page_tables,
+               attn_impl, verify_width=1, write_mask=None):
+        return forward_decode(
+            params, tokens, positions, active, pools, page_tables,
+            self.config, attn_impl=attn_impl, verify_width=verify_width,
+            write_mask=write_mask)
 
 
 def sp_grad_sync(grads, axis_name: str):

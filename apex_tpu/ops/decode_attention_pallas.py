@@ -56,17 +56,24 @@ _DIM_SEMANTICS = pltpu.CompilerParams(
 
 
 # ---------------------------------------------------------------- reference
-def as_stacked_pools(k_pool, v_pool, layer):
-    """The pools as stacked 5-D arrays, with their layer.
+def stacked_pools(pools, layer):
+    """The pools (a tuple of one shape) as stacked 5-D arrays, with
+    their layer.
 
-    One layer's (num_pages, H_kv, D, page_size) pool is the case
+    One layer's (num_pages, H_kv, D, page_size) pools are the case
     ``layer=0`` of a leading-1 view."""
-    if k_pool.ndim == 4:
+    if pools[0].ndim == 4:
         if layer is not None:
             raise ValueError("layer given with a one-layer (4-D) pool")
-        return k_pool[None], v_pool[None], 0
+        return tuple(p[None] for p in pools), 0
     if layer is None:
         raise ValueError("a stacked (5-D) pool needs its layer")
+    return tuple(pools), layer
+
+
+def as_stacked_pools(k_pool, v_pool, layer):
+    """:func:`stacked_pools` for the two pools of a k/v cache."""
+    (k_pool, v_pool), layer = stacked_pools((k_pool, v_pool), layer)
     return k_pool, v_pool, layer
 
 

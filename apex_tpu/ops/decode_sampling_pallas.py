@@ -47,6 +47,10 @@ from apex_tpu.ops.fused_ce_pallas import (
 #: lane row and a cross-lane merge; the dispatch falls back to XLA
 MAX_KERNEL_TOP_K = 128
 
+#: what the two double-buffered operand blocks (hidden rows, vocab
+#: tile) may take of the 16 MiB a kernel is given
+_OPERAND_VMEM = 12 * 2 ** 20
+
 
 # ------------------------------------------------------------ shared noise
 def _hash_u32(z):
@@ -197,6 +201,12 @@ def fused_sample_pallas(x2, embed, seeds, temperature=1.0, top_k=0,
             f"({MAX_KERNEL_TOP_K}); top_k={top_k} must take the XLA path")
     bn = _ceil_block(N, block_n, align=_sublane(x2.dtype))
     bv = _ceil_block(V, block_v, align=_LANES)
+    # both operand blocks are double-buffered: a wide model (H = 7168)
+    # halves the vocab tile until they fit the scoped VMEM
+    while bv > _LANES and 2 * H * (
+            bn * x2.dtype.itemsize + bv * embed.dtype.itemsize) \
+            > _OPERAND_VMEM:
+        bv //= 2
     nn, nv = _grid(N, bn), _grid(V, bv)
 
     tok = pl.pallas_call(

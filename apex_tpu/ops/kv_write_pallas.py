@@ -2,7 +2,10 @@
 
 Every program that writes the pool (:mod:`apex_tpu.inference.kv_cache`)
 does so through this ONE kernel, ``apex_kv_write``, with the pools
-aliased input-to-output.  The reason is layout, not speed of the write
+aliased input-to-output.  A cache is one pool or several of one shape
+(GPT: ``k`` and ``v``; a latent cache: one): :func:`pool_write_pallas`
+writes them all in one call, :func:`kv_write_pallas` is its two-pool
+spelling.  The reason is layout, not speed of the write
 itself: an XLA scatter or ``dynamic_update_slice`` on the pool inside a
 step makes XLA's layout assignment re-lay out the WHOLE pool to the
 layout the write prefers and back again (PERF.md, PR 25: more than
@@ -46,15 +49,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops._pallas_tiling import LANES, VMEM_BUDGET
 
-__all__ = ["kv_write_pallas"]
+__all__ = ["kv_write_pallas", "pool_write_pallas"]
 
 
-def _heads_per_block(h_kv, head_dim, page_size, dtype) -> int:
-    """The most kv heads a block can hold: six blocks a step (two pool
-    tiles in, two sources, two tiles out), each double-buffered, within
-    half the VMEM budget.  A page under 128 pads the lanes."""
+def _heads_per_block(h_kv, head_dim, page_size, dtype, n_pools=2) -> int:
+    """The most kv heads a block can hold: three blocks a pool a step
+    (the pool tile in, its source, the tile out), each double-buffered,
+    within half the VMEM budget.  A page under 128 pads the lanes."""
     per_head = head_dim * max(page_size, LANES) * jnp.dtype(dtype).itemsize
-    fit = max(1, (VMEM_BUDGET // 2) // (12 * per_head))
+    fit = max(1, (VMEM_BUDGET // 2) // (6 * n_pools * per_head))
     return max(d for d in range(1, h_kv + 1) if h_kv % d == 0 and d <= fit)
 
 
@@ -71,13 +74,12 @@ def _source_blocks(x, hb, dtype):
         Ls, T, h_kv // hb, D, hb * C)
 
 
-def _kv_write_kernel(dest_ref, layer_ref, mask_ref, k_src_ref, v_src_ref,
-                     k_pool_ref, v_pool_ref, k_out_ref, v_out_ref, *,
-                     heads, cols):
+def _kv_write_kernel(dest_ref, layer_ref, mask_ref, *refs, heads, cols):
     del dest_ref, layer_ref  # consumed by the BlockSpec index maps
+    n = len(refs) // 3       # sources, pool tiles in, pool tiles out
     keep_new = mask_ref[0] != 0          # (1, page): broadcasts over D
-    for src_ref, pool_ref, out_ref in ((k_src_ref, k_pool_ref, k_out_ref),
-                                       (v_src_ref, v_pool_ref, v_out_ref)):
+    for src_ref, pool_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                          refs[2 * n:]):
         src = src_ref[0, 0, 0]           # (D, heads * cols)
         for h in range(heads):
             # (D, page) columns of head h, or its one column (D, 1)
@@ -85,35 +87,40 @@ def _kv_write_kernel(dest_ref, layer_ref, mask_ref, k_src_ref, v_src_ref,
             out_ref[0, 0, h] = jnp.where(keep_new, new, pool_ref[0, 0, h])
 
 
-def kv_write_pallas(k_pool, v_pool, k_src, v_src, dest, mask, layer,
-                    interpret=False):
+def pool_write_pallas(pools, srcs, dest, mask, layer, interpret=False):
     """Write source tiles into pool pages, in place.
 
-    ``k_pool``/``v_pool``: (L, num_pages, H_kv, D, page_size), donated
-    to the result.  ``k_src``/``v_src``: (Ls, T, C, H_kv, D) — per
-    tile its ``C`` columns, each a token's heads as the model computes
-    them; ``C`` is ``page_size`` (a whole tile) or 1 (the one column
-    that every masked lane takes).  ``dest``: (T,) int32 page ids, ALREADY
-    clamped into the pool and garbage-routed by the caller, live ones
-    pairwise distinct (module doc).  ``mask``: (T, page_size) bool, the
-    columns to take from the source.  ``layer``: scalar int32; source
-    layer ``l`` lands in pool layer ``layer + l`` (the decode step
-    passes ``Ls = 1`` and its loop index, the prefill ``Ls = L`` and
-    0).  Returns the two pools.
+    ``pools``: a tuple of pools of ONE shape and dtype, each (L,
+    num_pages, H_kv, D, page_size), donated to the result.  ``srcs``:
+    one (Ls, T, C, H_kv, D) source a pool — per tile its ``C`` columns,
+    each a token's heads as the model computes them; ``C`` is
+    ``page_size`` (a whole tile) or 1 (the one column that every masked
+    lane takes).  ``dest``: (T,) int32 page ids, ALREADY clamped into
+    the pool and garbage-routed by the caller, live ones pairwise
+    distinct (module doc).  ``mask``: (T, page_size) bool, the columns
+    to take from the source.  ``layer``: scalar int32; source layer
+    ``l`` lands in pool layer ``layer + l`` (the decode step passes
+    ``Ls = 1`` and its loop index, the prefill ``Ls = L`` and 0).
+    Returns the pools, as a tuple.
     """
-    _, _, h_kv, D, page_size = k_pool.shape
-    Ls, T, C = k_src.shape[:3]
-    if k_src.shape != (Ls, T, C, h_kv, D) or C not in (1, page_size) \
-            or v_src.shape != k_src.shape or v_pool.shape != k_pool.shape:
+    pools, srcs = tuple(pools), tuple(srcs)
+    n = len(pools)
+    _, _, h_kv, D, page_size = pools[0].shape
+    Ls, T, C = srcs[0].shape[:3]
+    if n < 1 or len(srcs) != n or C not in (1, page_size) \
+            or any(x.shape != (Ls, T, C, h_kv, D) for x in srcs) \
+            or any(p.shape != pools[0].shape or p.dtype != pools[0].dtype
+                   for p in pools):
         raise ValueError(
-            f"sources {k_src.shape}/{v_src.shape} do not fit pools "
-            f"{k_pool.shape}/{v_pool.shape}")
+            f"sources {[x.shape for x in srcs]} do not fit pools "
+            f"{[p.shape for p in pools]}")
     if mask.shape != (T, page_size) or dest.shape != (T,):
         raise ValueError(
             f"dest {dest.shape} / mask {mask.shape} do not match {T} "
             f"tiles of {page_size} columns")
 
-    hb = _heads_per_block(h_kv, D, page_size, k_pool.dtype)
+    dtype = pools[0].dtype
+    hb = _heads_per_block(h_kv, D, page_size, dtype, n)
     src_spec = pl.BlockSpec(
         (1, 1, 1, D, hb * C),
         lambda l, t, h, dest_ref, layer_ref: (l, t, h, 0, 0))
@@ -127,22 +134,29 @@ def kv_write_pallas(k_pool, v_pool, k_src, v_src, dest, mask, layer,
         in_specs=[
             pl.BlockSpec((1, 1, page_size),
                          lambda l, t, h, dest_ref, layer_ref: (t, 0, 0)),
-            src_spec, src_spec, pool_spec, pool_spec,
-        ],
-        out_specs=[pool_spec, pool_spec],
+        ] + [src_spec] * n + [pool_spec] * n,
+        out_specs=[pool_spec] * n,
     )
-    pool_t = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
-    # operand numbering counts the two prefetched scalars
-    return pl.pallas_call(
+    pool_t = jax.ShapeDtypeStruct(pools[0].shape, dtype)
+    # operand numbering counts the two prefetched scalars and the mask
+    out = pl.pallas_call(
         functools.partial(_kv_write_kernel, heads=hb, cols=C),
         grid_spec=grid_spec,
-        out_shape=[pool_t, pool_t],
-        input_output_aliases={5: 0, 6: 1},
+        out_shape=[pool_t] * n,
+        input_output_aliases={3 + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="apex_kv_write",
     )(dest.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
       mask.astype(jnp.int32).reshape(T, 1, page_size),
-      _source_blocks(k_src, hb, k_pool.dtype),
-      _source_blocks(v_src, hb, v_pool.dtype), k_pool, v_pool)
+      *[_source_blocks(x, hb, dtype) for x in srcs], *pools)
+    return tuple(out)
+
+
+def kv_write_pallas(k_pool, v_pool, k_src, v_src, dest, mask, layer,
+                    interpret=False):
+    """:func:`pool_write_pallas` for a cache of two pools, ``k`` and
+    ``v``.  Returns the two pools."""
+    return pool_write_pallas((k_pool, v_pool), (k_src, v_src), dest, mask,
+                             layer, interpret=interpret)

@@ -96,6 +96,7 @@ KERNELS: Dict[str, tuple] = {
                          "paged_decode_attention_pallas",
                          "_decode_attn_kernel"),
     "kv_write": ("kv_write_pallas", "_kv_write_kernel"),
+    "mla_decode_attention": ("mla_decode_pallas", "_mla_decode_kernel"),
     "decode_sampling": ("decode_sampling_pallas", "fused_sample_pallas",
                         "_sample_kernel", "_merge_top_k"),
 }

@@ -17,6 +17,22 @@ device computes full gradients for its own experts (the all-to-all
 brings every token routed to them), so data-parallel gradient sync must
 SKIP expert parameters — :func:`is_expert_param` tells the train step
 which ones.
+
+**A second layer, for serving a share of a wide expert group**
+(:func:`held_experts_ffn`, with its router :func:`route_group_limited`):
+the layer is TOLD which experts it holds (``held``, a static range of
+ids), routes over ALL of them — sigmoid scores, a bias that corrects
+the choice but not the weight, group-limited top-k, weights
+renormalised over the chosen and scaled — and computes the part of the
+result its own experts give, dropping nothing: the live assignments
+are sorted by expert and each projection is ONE grouped matmul (the
+Pallas grouped GEMM that ships with JAX on a TPU, ``jax.lax.ragged_dot``
+elsewhere: :func:`_grouped_matmul`) over static buffers sized for the
+worst case, every assignment held.  With ``held`` = all experts it is the whole layer; what absent
+experts would add is simply not there (no exchange, no stand-in).
+:func:`moe_ffn` above stays the training layer (capacity factor,
+dropping, ``all_to_all``); this one has no backward-tuned path and no
+exchange yet (ROADMAP, Queue 2).
 """
 
 from functools import partial
@@ -167,3 +183,153 @@ def moe_param_specs(ep_axis: Optional[str] = "dp", layers: bool = True):
         "w2": P(*ld, ep_axis, None, None),
         "b2": P(*ld, ep_axis, None),
     }
+
+
+# ------------------------------------------------ held experts (serving)
+def route_group_limited(x, router_w, bias, *, top_k: int, n_group: int,
+                        topk_group: int, scale: float):
+    """Bias-corrected, group-limited top-k routing over ALL experts.
+
+    ``x``: (T, H); ``router_w``: (H, E); ``bias``: (E,) — added to the
+    scores for the CHOICE only.  In float32 whatever the inputs' dtype:
+    ``s = sigmoid(x W)``; the ``n_group`` groups of ``E / n_group``
+    consecutive experts are each scored by the sum of their 2 best
+    ``s + bias``, the best ``topk_group`` groups stay, and the
+    ``top_k`` best ``s + bias`` among them pick the experts.  Their
+    weights are the ORIGINAL ``s``, divided by their sum and multiplied
+    by ``scale``.  Returns ``(ids (T, top_k) int32, weights (T, top_k)
+    float32)``.
+    """
+    T = x.shape[0]
+    E = router_w.shape[1]
+    s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32),
+                                  router_w.astype(jnp.float32)))
+    choice = s + bias.astype(jnp.float32)[None]
+    per_group = choice.reshape(T, n_group, E // n_group)
+    group_score = jax.lax.top_k(per_group, 2)[0].sum(-1)       # (T, G)
+    keep = jax.lax.top_k(group_score, topk_group)[1]           # (T, tg)
+    group_ok = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], keep].set(True)
+    masked = jnp.where(jnp.repeat(group_ok, E // n_group, axis=1),
+                       choice, -jnp.inf)
+    ids = jax.lax.top_k(masked, top_k)[1].astype(jnp.int32)    # (T, k)
+    picked = jnp.take_along_axis(s, ids, axis=1, mode="clip")  # top_k's
+    weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+    return ids, weights
+
+
+#: (rows, contraction, columns) tile of the Pallas grouped matmul: 88%
+#: of the HBM roofline at 64 live rows over 16 experts of 7168 x 2048 on
+#: a v5e, where XLA's own ``ragged_dot`` kernel reached 40% (PERF.md,
+#: PR 26)
+GROUPED_TILING = (128, 1024, 1024)
+
+
+def _grouped_matmul(rows, w, group_sizes, impl):
+    """``rows[sizes[:g].sum() : sizes[:g+1].sum()] @ w[g]`` for every
+    group ``g``: ``jax.lax.ragged_dot`` ("xla"), or the Pallas grouped
+    GEMM that ships with JAX (megablox ``gmm``; "pallas", "interpret",
+    and "auto" on a TPU), which visits only the row tiles that hold a
+    live row.  Rows past the live ones come out undefined."""
+    from apex_tpu.utils.platform import on_tpu
+
+    if impl not in ("auto", "pallas", "interpret", "xla"):
+        raise ValueError(f"impl must be 'auto', 'pallas', 'interpret' or "
+                         f"'xla'; got {impl!r}")
+    tm = next((t for t in (GROUPED_TILING[0], 64, 32, 16, 8)
+               if rows.shape[0] % t == 0), None)
+    if impl == "xla" or (impl == "auto" and not on_tpu()) or tm is None:
+        return jax.lax.ragged_dot(rows, w, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    _, tk, tn = GROUPED_TILING
+    return gmm(rows, w, group_sizes, preferred_element_type=rows.dtype,
+               tiling=(tm, min(tk, w.shape[1]), min(tn, w.shape[2])),
+               interpret=(impl == "interpret"))
+
+
+def grouped_gated_ffn(rows, w_gate, w_up, w_down, group_sizes,
+                      impl="auto"):
+    """The gated-SiLU FFN of ``G`` experts over rows sorted by expert:
+    ``rows`` (M, H), the first ``group_sizes[0]`` of them expert 0's
+    and so on; ``w_gate``/``w_up``: (G, H, F); ``w_down``: (G, F, H).
+    One grouped matmul a projection (:func:`_grouped_matmul`); rows
+    past ``sum(group_sizes)`` come out UNDEFINED (the caller masks
+    them).  An expert with no row costs nothing: its weights are not
+    read."""
+    gate = _grouped_matmul(rows, w_gate, group_sizes, impl)
+    up = _grouped_matmul(rows, w_up, group_sizes, impl)
+    return _grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes,
+                           impl)
+
+
+def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
+                     topk_group: int, scale: float, token_mask=None,
+                     layer=None, impl="auto"):
+    """The routed part of an expert layer that the experts ``held``
+    give, for every token, with no assignment dropped.
+
+    ``x``: (T, H).  ``params``: ``router`` (H, E) and ``router_bias``
+    (E,) over ALL ``E`` experts; ``we_gate``/``we_up`` (n_held, H, F)
+    and ``we_down`` (n_held, F, H), the held experts' weights in id
+    order.  ``held``: the static ``range`` of ids held
+    (``range(E)``: the whole layer).  ``token_mask``: (T,) bool —
+    tokens that are padding or an empty slot route nowhere.
+
+    Each token's ``top_k`` assignments are chosen over all experts and
+    weighted over all ``top_k`` (:func:`route_group_limited`); those to
+    a held expert are sorted by expert and run through
+    :func:`grouped_gated_ffn` in a static buffer of ``T * top_k`` rows,
+    the worst case.  Returns ``(out (T, H), counts)`` with ``counts``
+    the int32 scalars ``assignments_held`` (assignments computed
+    here), ``assignments_all`` (``top_k`` a live token) and
+    ``experts_hit`` (held experts with at least one).
+    """
+    T, H = x.shape
+    n_held = len(held)
+    experts = {k: params[k] for k in ("we_gate", "we_up", "we_down")}
+    if layer is None:
+        experts = {k: w[None] for k, w in experts.items()}
+        layer = 0
+    n_layers = experts["we_gate"].shape[0]
+    if held.step != 1 or experts["we_gate"].shape[1] != n_held:
+        raise ValueError(
+            f"held {held} must be a contiguous range matching the "
+            f"{experts['we_gate'].shape[1]} experts' weights given")
+    ids, weights = route_group_limited(
+        x, params["router"], params["router_bias"], top_k=top_k,
+        n_group=n_group, topk_group=topk_group, scale=scale)
+    live = (ids >= held.start) & (ids < held.stop)
+    if token_mask is not None:
+        live = live & token_mask[:, None]
+    A = T * top_k
+    local = jnp.where(live, ids - held.start, n_held).reshape(A)
+    order = jnp.argsort(local, stable=True)           # held first, by expert
+    group_sizes = jnp.sum(
+        local[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None],
+        axis=0, dtype=jnp.int32)
+    rows = jnp.take(x, order // top_k, axis=0)        # (A, H)
+    # the layers' experts side by side as groups; only this layer's
+    # have rows
+    all_sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_layers * n_held,), jnp.int32), group_sizes,
+        (jnp.asarray(layer, jnp.int32) * n_held,))
+    flat = {k: w.reshape((n_layers * n_held,) + w.shape[2:])
+            for k, w in experts.items()}
+    y = grouped_gated_ffn(rows, flat["we_gate"], flat["we_up"],
+                          flat["we_down"], all_sizes, impl=impl)
+    # a row past the live ones belongs to no group: whatever the grouped
+    # matmul left there is replaced, not multiplied away
+    w = jnp.take(jnp.where(live, weights, 0.0).reshape(A), order)
+    y = jnp.where(w[:, None] > 0, y.astype(jnp.float32) * w[:, None], 0.0)
+    # back to assignment order, then each token sums its own
+    back = jnp.zeros((A,), jnp.int32).at[order].set(
+        jnp.arange(A, dtype=jnp.int32))
+    out = jnp.take(y, back, axis=0).reshape(T, top_k, H).sum(1)
+    n_tokens = T if token_mask is None else jnp.sum(token_mask)
+    counts = {
+        "assignments_held": jnp.sum(group_sizes),
+        "assignments_all": jnp.asarray(n_tokens * top_k, jnp.int32),
+        "experts_hit": jnp.sum(group_sizes > 0, dtype=jnp.int32),
+    }
+    return out.astype(x.dtype), counts

@@ -1,7 +1,7 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
 Drives the two main paths once, through the entry points a user calls,
-at the full width of two models the repo supports (depth included;
+at the full width of three models the repo supports (depth included;
 weights random from a seed), on whatever TPU JAX finds:
 
 - the trainer: ``examples/gpt/pretrain_gpt.main`` at GPT-345M (L24 H1024
@@ -13,7 +13,13 @@ weights random from a seed), on whatever TPU JAX finds:
   through ``ContinuousBatchingScheduler`` as ``serve_gpt.main`` builds
   it, kernels forced (``attn_impl="pallas"``, ``sample_impl="pallas"``),
   16 requests once greedy and once ``temperature=1, top_k=40``; then
-  decode-step logits against the training forward.
+  decode-step logits against the training forward;
+- the second served family: latent attention and held experts
+  (``models/mla_moe.py``) at GigaChat3.1-702B-A36B's published widths,
+  1 dense + 1 expert layer, 16 of 256 experts held, through the same
+  ``serve_gpt.build_scheduler``: 12 greedy requests, the one-pool
+  ``apex_kv_write`` and ``apex_mla_decode_attention`` among the decode
+  step's kernels, decode logits against the family's full forward.
 
 It refuses to start without a TPU, never sets ``JAX_PLATFORMS``, and
 runs everything in this one process (a chip belongs to one process).
@@ -58,6 +64,16 @@ SERVE_ARGV = ["--streams", "8", "--requests", "16", "--prompt-len", "128",
               "--sample-impl", "pallas"]  # widths: serve_gpt's 124M defaults
 SERVE_KERNELS = {"apex_decode_attention", "apex_kv_write", "apex_fused_sample",
                  "apex_ln_fwd"}
+# the second served family (latent attention, held experts) at its
+# published widths, cut to 1 dense + 1 expert layer: 3.3 GB of bf16
+LATENT_CONFIG = ROOT / "cellbench" / "configs" \
+    / "gigachat3.1-702b-a36b-serve-ep16.json"
+LATENT_ARGV = ["--streams", "8", "--requests", "12", "--prompt-len", "256",
+               "--max-new", "24", "--page-size", "128",
+               "--prefill-buckets", "128", "--temperature", "0",
+               "--attn-impl", "pallas", "--sample-impl", "pallas"]
+LATENT_KERNELS = {"apex_mla_decode_attention", "apex_kv_write",
+                  "apex_fused_sample"}
 
 # Kernel-vs-reference bounds for one bf16 forward+backward of GPT-345M.
 # Both sides round activations to bf16 at every op and differ only in
@@ -325,6 +341,85 @@ def server_phase(temperature, top_k):
     return out
 
 
+def latent_server_phase():
+    """The latent-attention, sparse-expert family through the same
+    entry point and scheduler: greedy requests served, the one-pool
+    ``apex_kv_write`` and ``apex_mla_decode_attention`` in the compiled
+    decode step, and its logits (absorbed attention over the paged bf16
+    latent cache) against the family's full forward (flash kernel,
+    keys and values expanded per head)."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+
+    import serve_gpt
+    from apex_tpu.inference import Request
+    from apex_tpu.inference.decode import decode_logits_tokenwise
+    from apex_tpu.models import mla_moe
+    from apex_tpu.resilience.fallback import get_registry
+
+    where = "server(latent)"
+    conf = json.loads(LATENT_CONFIG.read_text())
+    conf.update(num_hidden_layers=2, first_k_dense_replace=1)
+    t0 = time.time()
+    with tempfile.NamedTemporaryFile("w", suffix=".json") as f:
+        json.dump(conf, f)
+        f.flush()
+        args = serve_gpt.build_args().parse_args(
+            [*LATENT_ARGV, "--model-config", f.name])
+        sched, params, config = serve_gpt.build_scheduler(args)
+    vocab = config.vocab_size
+    rng = np.random.RandomState(0)
+    for rid in range(args.requests):
+        plen = int(rng.randint(32, args.prompt_len + 1))
+        sched.submit(Request(
+            rid=rid, prompt=rng.randint(0, vocab, size=plen).tolist(),
+            max_new_tokens=args.max_new))
+    sched.step()
+    setup_s = time.time() - t0
+    while not sched.idle():
+        sched.step()
+    done = sched.completed
+    out = {"requests": len(done),
+           "generated_tokens": sum(len(c.tokens) for c in done),
+           "decode_compiles": sched.decode_cache_size(),
+           "step_rebuilds": sched.stats["step_rebuilds"],
+           "counters": sched.read_counters(),
+           "setup_s": round(setup_s, 1),
+           "wall_s": round(time.time() - t0, 1)}
+    print(f"{where}: " + json.dumps(out), flush=True)
+    check(len(done) == args.requests
+          and all(len(c.tokens) == args.max_new for c in done)
+          and all(0 <= t < vocab for c in done for t in c.tokens),
+          f"{where}: want {args.requests} requests of {args.max_new} "
+          f"tokens inside the vocabulary")
+    check(out["decode_compiles"] == 1 and out["step_rebuilds"] == 0,
+          f"{where}: decode_compiles={out['decode_compiles']} "
+          f"step_rebuilds={out['step_rebuilds']} (want 1 and 0)")
+    c = out["counters"]
+    check(0 < c["moe_assignments_held"] < c["moe_assignments_all"],
+          f"{where}: the held experts' counters read {c}")
+    no_trip(get_registry().status(), where)
+    out.update(kernels_in(sched.lower_decode_step(), LATENT_KERNELS, where))
+
+    S, prefix = 136, 128
+    tokens = jnp.asarray(rng.randint(0, vocab, size=(1, S)), jnp.int32)
+    row = jnp.arange(1, sched.dcfg.cache.pages_per_seq + 1, dtype=jnp.int32)
+    dec = decode_logits_tokenwise(params, config, sched.dcfg, tokens,
+                                  prefix, row)
+    ref = jax.jit(lambda p, t: mla_moe.forward(
+        p, t, config, attn_impl="pallas"))(params, tokens)
+    err = float(jnp.max(jnp.abs(dec - ref[0, prefix:])))
+    out["logits_max_abs_err"] = err
+    print(f"{where}: logits max abs err {err:.4f}", flush=True)
+    check(err <= LOGITS_ATOL,
+          f"{where}: decode logits differ from the full forward by "
+          f"{err} (atol {LOGITS_ATOL})")
+    out["ok"] = True
+    return out
+
+
 # --------------------------------------------------------------------- main
 def main():
     import jax
@@ -357,6 +452,8 @@ def main():
     gc.collect()
     phases["server_greedy"] = server_phase(temperature=0.0, top_k=0)
     phases["server_sampled"] = server_phase(temperature=1.0, top_k=40)
+    gc.collect()
+    phases["server_latent"] = latent_server_phase()
     if device["count"] >= 4:
         gc.collect()
         phases["trainer_tp2_dp2"] = trainer_phase(

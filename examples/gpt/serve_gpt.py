@@ -10,6 +10,12 @@ by.
 
     python examples/gpt/serve_gpt.py --streams 8 --requests 32
     python examples/gpt/serve_gpt.py --smoke     # tiny CPU acceptance
+    # the second family (latent attention + held experts), built from a
+    # published-style config.json: bf16 weights, a one-pool latent cache
+    python examples/gpt/serve_gpt.py --model-config \
+        cellbench/configs/gigachat3.1-702b-a36b-serve-ep16.json \
+        --streams 128 --page-size 128 --prompt-len 1024 --max-new 1024 \
+        --prefill-buckets 256,512 --temperature 0
     # serving v2: speculative decode + shared system prompt + chunked
     # prefill + a preemptible best-effort lane, one command
     python examples/gpt/serve_gpt.py --draft-len 4 --prefix-sharing \\
@@ -70,6 +76,22 @@ def build_args():
     p.add_argument("--kv-groups", type=int, default=None,
                    help="GQA query groups (None = MHA)")
     p.add_argument("--vocab", type=int, default=50304)
+    p.add_argument("--model-config", default=None,
+                   help="a published-style config.json of the "
+                        "latent-attention, sparse-expert family "
+                        "(model_type deepseek_v3; models/mla_moe.py) "
+                        "instead of the GPT flags above.  Its "
+                        "n_routed_experts is the number of experts HELD "
+                        "here, from --held-start on; the router's width "
+                        "is published.n_routed_experts where the file "
+                        "has it")
+    p.add_argument("--held-start", type=int, default=0,
+                   help="first expert id this process holds "
+                        "(--model-config)")
+    p.add_argument("--prefill-buckets", default="",
+                   help="comma-separated prompt pad lengths below "
+                        "--prompt-len: one prefill compile each, a "
+                        "prompt is padded to the shortest that holds it")
     p.add_argument("--page-size", type=int, default=16)
     p.add_argument("--num-pages", type=int, default=None,
                    help="pool pages (default: sized for streams x "
@@ -227,38 +249,71 @@ def report(completions, wall_secs):
 
 
 def check_greedy_parity(params, config, completions, max_check=3):
-    """Every generated token must be the training forward's argmax
-    continuation — the decision-level decode↔training parity the smoke
+    """Every generated token must be the model's full forward's argmax
+    continuation — the decision-level decode↔forward parity the smoke
     contract promises."""
     for c in completions[:max_check]:
         seq = list(c.prompt)
         for tok in c.tokens:
-            logits = gpt_forward(params, jnp.asarray([seq]), config)
-            pred = int(jnp.argmax(logits[len(seq) - 1, 0]))
+            if isinstance(config, GPTConfig):
+                logits = gpt_forward(params, jnp.asarray([seq]), config)
+                pred = int(jnp.argmax(logits[len(seq) - 1, 0]))
+            else:
+                from apex_tpu.models import mla_moe
+
+                logits = mla_moe.forward(params, jnp.asarray([seq]), config,
+                                         attn_impl="xla")
+                pred = int(jnp.argmax(logits[0, len(seq) - 1]))
             assert pred == tok, (
-                f"rid={c.rid}: decode produced {tok} where the training "
+                f"rid={c.rid}: decode produced {tok} where the full "
                 f"forward's greedy continuation is {pred} at position "
-                f"{len(seq)} — decode/training parity broke")
+                f"{len(seq)} — decode/forward parity broke")
             seq.append(tok)
 
 
-def build_scheduler(args, watchdog=None, anomaly=None):
-    """The model, the KV cache and the scheduler exactly as ``main``
-    serves them, from a parsed ``build_args()`` namespace.  Returns
-    ``(scheduler, params, config)``.  ``chip_smoke.py`` drives its own
-    request mix through the scheduler this builds."""
-    total_prompt = args.system_prompt_len + args.prompt_len
+def build_model(args, max_seq_len):
+    """``(config, params)`` of the family the flags name: GPT from
+    ``--layers/--hidden/--heads/...``, or — with ``--model-config`` — the
+    latent-attention, sparse-expert family from a published-style
+    ``config.json`` (weights in bf16, random)."""
+    key = jax.random.PRNGKey(args.seed)
+    if args.model_config:
+        from apex_tpu.models import mla_moe
+
+        conf = json.loads(Path(args.model_config).read_text())
+        dtype = jnp.float32 if args.smoke else jnp.bfloat16
+        config = mla_moe.MLAMoEConfig.from_published(
+            conf,
+            n_routed_experts=conf.get("published", {}).get(
+                "n_routed_experts", conf["n_routed_experts"]),
+            held_start=args.held_start,
+            held_count=conf["n_routed_experts"],
+            param_dtype=dtype, compute_dtype=dtype)
+        args.vocab = config.vocab_size
+        return config, mla_moe.init_params(config, key)
     config = GPTConfig(
         vocab_size=args.vocab, hidden_size=args.hidden,
         num_layers=args.layers, num_attention_heads=args.heads,
-        num_query_groups=args.kv_groups,
-        max_seq_len=max(total_prompt + args.max_new + args.draft_len + 1,
-                        64),
+        num_query_groups=args.kv_groups, max_seq_len=max_seq_len,
         position_embedding_type="rope",
         compute_dtype=jnp.float32 if args.smoke else jnp.bfloat16,
         checkpoint_layers=False,
     )
-    params = init_params(config, jax.random.PRNGKey(args.seed))
+    return config, init_params(config, key)
+
+
+def build_scheduler(args, watchdog=None, anomaly=None):
+    """The model, its paged cache and the scheduler exactly as ``main``
+    serves them, from a parsed ``build_args()`` namespace: either
+    family (:func:`build_model`) behind the one
+    ``ContinuousBatchingScheduler``, which asks the model's config for
+    its served-model adapter (cache spec, prefill, decode forward, head
+    matrix: docs/inference.md).  Returns ``(scheduler, params,
+    config)``.  ``chip_smoke.py`` drives its own request mixes through
+    the schedulers this builds."""
+    total_prompt = args.system_prompt_len + args.prompt_len
+    config, params = build_model(
+        args, max(total_prompt + args.max_new + args.draft_len + 1, 64))
 
     # worst-case footprint: full prompt + generation budget + the
     # speculative write window (draft k/v land past the accepted stream)
@@ -275,6 +330,8 @@ def build_scheduler(args, watchdog=None, anomaly=None):
             pages_per_seq=pages_per_seq,
             dtype=jnp.dtype(args.kv_dtype)),
         max_batch=args.streams, max_prompt_len=total_prompt,
+        prefill_buckets=tuple(int(b) for b in
+                              args.prefill_buckets.split(",") if b),
         temperature=args.temperature, top_k=args.top_k,
         attn_impl=args.attn_impl, sample_impl=args.sample_impl,
         sample_dot_dtype=jnp.float32 if args.smoke else None,
@@ -306,7 +363,8 @@ def main(argv=None):
     if args.smoke:
         # tiny, deterministic, greedy: the CPU acceptance contract
         # (kernel impls stay as asked — a CPU caller passes "interpret")
-        args.layers, args.hidden, args.heads, args.vocab = 2, 64, 4, 128
+        args.layers, args.hidden, args.heads = 2, 64, 4
+        args.vocab = 128  # (--model-config brings its own)
         args.streams, args.requests, args.arrival_rate = 3, 7, 0.0
         args.prompt_len, args.max_new = 8, 4
         args.page_size, args.kv_dtype = 4, "float32"
@@ -380,6 +438,9 @@ def main(argv=None):
     out = report(completions, wall)
     out["stats"] = dict(sched.stats)
     out["decode_compiles"] = sched.decode_cache_size()
+    if sched.model.counter_names:
+        # the model's device-side counters: one readback, after the run
+        out["model_counters"] = sched.read_counters()
     # where the run is judged: the device it ran on and whether any
     # kernel degraded to its reference along the way
     out["device"] = device_facts()

@@ -24,13 +24,17 @@ import jax.numpy as jnp
 import pytest
 from jax import export as jexport
 
-from apex_tpu.inference.kv_cache import write_decode_kv, write_prompt_kv
+from apex_tpu.inference.kv_cache import (
+    write_decode_kv, write_decode_pools, write_prompt_kv,
+    write_prompt_pools,
+)
 from apex_tpu.ops.decode_attention_pallas import paged_decode_attention_pallas
 from apex_tpu.ops.decode_sampling_pallas import fused_sample_pallas
 from apex_tpu.ops.flash_attention_pallas import flash_attention_pallas
 from apex_tpu.ops.fused_ce_pallas import (
     fused_ce_bwd_pallas, fused_ce_fwd_pallas,
 )
+from apex_tpu.ops.mla_decode_pallas import mla_decode_pallas
 from apex_tpu.ops.layer_norm_pallas import (
     layer_norm_bwd_pallas, layer_norm_fwd_pallas,
 )
@@ -70,10 +74,38 @@ def _prompt_write(kv_heads, page=16):
         [pool, pool, stack, stack, ((P,), I32), ((), I32), ((), I32)])
 
 
-def _sample(temperature, top_k, rows=8):
+def _sample(temperature, top_k, rows=8, hidden=768, vocab=VOCAB,
+            embed=F32):
     return (lambda x, e, s: fused_sample_pallas(
         x, e, s, temperature=temperature, top_k=top_k),
-        [((rows, 768), BF16), ((VOCAB, 768), F32), ((rows,), U32)])
+        [((rows, hidden), BF16), ((vocab, hidden), embed),
+         ((rows,), U32)])
+
+
+# the latent-attention family at its published widths: 64 heads over a
+# 512 + 64 latent column, 128 slots, page 128, a 6-layer stacked pool
+_LATENT_POOL = ((6, 257, 1, 576, 128), BF16)
+
+
+def _mla_decode(slots=128, P=16):
+    return (lambda q, pool, pt, n, layer: mla_decode_pallas(
+        q, pool, pt, n, 512, 0.1447, layer=layer),
+        [((slots, 64, 576), BF16), _LATENT_POOL, ((slots, P), I32),
+         ((slots,), I32), ((), I32)])
+
+
+def _latent_write(slots=128, P=16):
+    return (lambda pool, new, pt, pos, act, layer: write_decode_pools(
+        (pool,), (new,), pt, pos, act, layer=layer, impl="pallas"),
+        [_LATENT_POOL, ((slots, 1, 576), BF16), ((slots, P), I32),
+         ((slots,), I32), ((slots,), jnp.bool_), ((), I32)])
+
+
+def _latent_prompt_write(S=512, P=16):
+    return (lambda pool, stack, row, n, start: write_prompt_pools(
+        (pool,), (stack,), row, n, start=start, impl="pallas"),
+        [_LATENT_POOL, ((6, S, 1, 576), BF16), ((P,), I32), ((), I32),
+         ((), I32)])
 
 
 def _flash(heads, kv_heads):
@@ -130,6 +162,14 @@ CASES = {
     "sample_t1_top40": (*_sample(1.0, 40), {"apex_fused_sample"}),
     "sample_t1_top40_verify5": (*_sample(1.0, 40, rows=40),
                                 {"apex_fused_sample"}),
+    # 128 rows of a 7,168-wide model over a 16,032-row bf16 head: the
+    # vocabulary tile has to shrink to fit VMEM
+    "sample_greedy_wide128": (*_sample(0.0, 0, rows=128, hidden=7168,
+                                       vocab=16032, embed=BF16),
+                              {"apex_fused_sample"}),
+    "mla_decode_attn": (*_mla_decode(), {"apex_mla_decode_attention"}),
+    "latent_write_decode": (*_latent_write(), {"apex_kv_write"}),
+    "latent_write_prompt": (*_latent_prompt_write(), {"apex_kv_write"}),
     # training, GPT-345M and GPT-124M shapes
     "flash_345m": (*_flash(16, 16),
                    {"apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv"}),
@@ -262,21 +302,82 @@ for name, (fn, args) in programs.items():
 print(json.dumps(out))
 """
 
+# the latent-attention, sparse-expert family as its benchmark cell runs
+# it: published widths, 1 dense + 5 expert layers, 16 of 256 experts
+# held, 128 slots x 2,048 positions at page 128, one pool of 576 values
+_LATENT_POOL_CHILD = _DESCRIBED_V5E + """
+import jax.numpy as jnp
+from apex_tpu.analysis.lowered import large_result_instructions
+from apex_tpu.inference import DecodeConfig, KVCacheConfig
+from apex_tpu.inference.decode import make_decode_step, make_prefill
+from apex_tpu.inference.kv_cache import COUNTERS, alloc_named_pools
+from apex_tpu.models.mla_moe import MLAMoEConfig, init_params
+
+B, PAGE, PPS, S = 128, 128, 16, 512
+cfg = MLAMoEConfig(vocab_size=16032, num_dense_layers=1, num_moe_layers=5,
+                   held_start=0, held_count=16)
+dcfg = DecodeConfig(
+    cache=KVCacheConfig(num_pages=1 + B * PPS, page_size=PAGE,
+                        pages_per_seq=PPS, dtype=jnp.bfloat16),
+    max_batch=B, max_prompt_len=1024, prefill_buckets=(256, 512),
+    temperature=0.0, attn_impl="pallas", sample_impl="pallas")
+sh = SingleDeviceSharding(dev)
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+params = put(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+model = cfg.served_model()
+pools = put(jax.eval_shape(lambda: dict(
+    alloc_named_pools(model.cache_spec(), dcfg.cache),
+    **{COUNTERS: jnp.zeros((len(model.counter_names),), jnp.int32)})))
+I, U = jnp.int32, jnp.uint32
+programs = {
+    "decode_step": (make_decode_step(cfg, dcfg), (
+        params, pools, arg((B,), I), arg((B,), I), arg((B,), jnp.bool_),
+        arg((B, PPS), I), arg((B,), U))),
+    "prefill": (make_prefill(cfg, dcfg), (
+        params, pools, arg((1, S), I), arg((), I), arg((), I),
+        arg((PPS,), I), arg((), U))),
+}
+layer_pool = pools["latent"].size // cfg.num_layers
+out = {"pool_bytes": pools["latent"].size * 2}
+for name, (fn, args) in programs.items():
+    try:
+        c = fn.lower(*args).compile()
+    except Exception as e:
+        out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}
+        continue
+    found = large_result_instructions(
+        c, layer_pool, containing=(dcfg.cache.num_pages, 1, 576))
+    out[name] = {
+        "temp_bytes": c.memory_analysis().temp_size_in_bytes,
+        "instructions": [
+            [i["name"], i["opcode"],
+             "tpu_custom_call" in i["line"]
+             and "output_to_operand_aliasing" in i["line"]]
+            for i in found]}
+print(json.dumps(out))
+"""
+
 #: what may carry a pool through a compiled step without copying it
 _POOL_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while"}
 
 
-def test_no_program_copies_the_kv_pool():
+@pytest.mark.parametrize("child", [_POOL_CHILD, _LATENT_POOL_CHILD],
+                         ids=["gpt2-large-kv", "latent-one-pool"])
+def test_no_program_copies_the_kv_pool(child):
     """The decode step and the prefill, compiled for a v5e at GPT-2
-    large's serving shapes, hold no instruction that PRODUCES a value
-    as large as one layer's KV pool: only parameters, the tuples and
-    the ``while`` that carry the pools, and the aliased Pallas calls
-    that write them in place.  A ``copy``, ``fusion``, ``scatter`` or
-    ``dynamic-update-slice`` of that size is XLA re-laying out, slicing
-    or rebuilding the pool around a write or a read (PERF.md, PR 25:
-    eight such copies were 54% of a decode step and held the pool
-    twice).  Temporaries stay under one pool's bytes."""
-    r = subprocess.run([sys.executable, "-c", _POOL_CHILD], cwd=str(REPO),
+    large's serving shapes and at the latent-attention family's (one
+    pool of 576 values a token a layer, 128 slots), hold no instruction
+    that PRODUCES a value as large as one layer's pool: only
+    parameters, the tuples and the ``while`` that carry the pools, and
+    the aliased Pallas calls that write them in place.  A ``copy``,
+    ``fusion``, ``scatter`` or ``dynamic-update-slice`` of that size is
+    XLA re-laying out, slicing or rebuilding the pool around a write or
+    a read (PERF.md, PR 25: eight such copies were 54% of a decode step
+    and held the pool twice).  Temporaries stay under one pool's
+    bytes."""
+    r = subprocess.run([sys.executable, "-c", child], cwd=str(REPO),
                        capture_output=True, text=True, timeout=600,
                        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-3000:]
