@@ -9,24 +9,47 @@ matmul (PAPERS.md: "LLM Inference Acceleration via Efficient Operation
 Fusion", arxiv 2502.17728), so the whole per-head attention — page
 gather, scores, online softmax, weighted sum — runs as ONE kernel:
 
-- grid ``(batch, kv_heads, pages_per_seq)``, pages sequential;
-- the page table AND the layer ride as **scalar-prefetch** operands
-  (``pltpu.PrefetchScalarGridSpec``), so each k/v BlockSpec index map
-  dereferences ``(layer, page_table[b, p])`` and the DMA fetches
-  exactly that ``(head_dim, page_size)`` tile out of the STACKED pool
-  — neither one layer's pool nor the gathered (B, S_max, H_kv, D) key
+- **the unit of work is a live page of a sequence row with ALL of its
+  kv heads** (or as many as :func:`_plan` fits into VMEM).  The pool is
+  ``(L, num_pages, H_kv, D, page_size)``, so a page's heads are
+  contiguous and an ``(h_blk, D, page_size)`` block is one DMA — 20
+  heads of GPT-2 large are 320 KB of k and 320 KB of v, where one
+  ``(D, page_size)`` tile a grid step paid the grid's and the DMA's
+  overheads 3,200 times a layer (PERF.md, PR 27);
+- the page table, the lengths AND the layer ride as **scalar-prefetch**
+  operands (``pltpu.PrefetchScalarGridSpec``): a block is addressed as
+  ``(layer, page_table[b, p], head block)`` in the STACKED pool —
+  neither one layer's pool nor the gathered (B, S_max, H_kv, D) key
   tensor the XLA reference materializes in HBM ever exists here;
+- **the walk is bounded by the row's length**, in one of two forms that
+  the page size picks (a shape, not an option):
+
+  * a page of whole lane tiles (``page_size % 128 == 0``; the
+    benchmark's cells): grid ``(rows, kv_heads // h_blk)``, the pools
+    stay in HBM (``memory_space=ANY``) and :func:`_walk_kernel` copies
+    the ``ceil(length / page_size)`` live pages itself, double-buffered,
+    the next copy issued before the current one is waited for and the
+    last page of a row issuing the first page of the next live row: a
+    slot without a live position costs one grid step and no copy;
+  * a smaller page (lanes padded; Mosaic cannot slice such a page out
+    of HBM by hand): grid ``(rows, kv_heads // h_blk, pages_per_seq)``
+    with a BlockSpec whose index past the last live page names that
+    page again (:func:`_kv_block_index`) — an unchanged block is not
+    fetched and ``pl.when`` skips the arithmetic, at about 0.2 µs a
+    dead grid step.
+
+  Either way a whole page past a length is never read; the tail of the
+  last live page is masked per position;
 - pages are stored head-dim-major (:mod:`apex_tpu.inference.kv_cache`):
   the page's positions sit in the lanes, which is the device's own
-  layout for the pool, so no consumer re-lays it out.  ``q·k``
-  contracts ``(1),(0)`` and ``p·v`` contracts ``(1),(1)``;
+  layout for the pool, so no consumer re-lays it out;
 - grouped-query attention reads the group-shared kv page ONCE per kv
-  head and scores all ``H // H_kv`` q heads of the group against it
-  (no ``repeat_kv_heads`` materialization, same as the flash kernels);
-- the per-sequence length masks both granularities: whole pages past
-  the length are skipped via ``pl.when`` (no wasted MXU work on a
-  fresh sequence in a long-cache-shaped step), and the tail page is
-  masked per position.
+  head and scores all ``H // H_kv`` q heads of the group against it on
+  the MXU, batched over the block's heads (no ``repeat_kv_heads``
+  materialization, same as the flash kernels).  One query head a kv
+  head (GPT-2) makes each product a one-row pass; measured on the
+  chip, the batched passes still beat the same sums on the VPU, and a
+  full table of pages streams at 80% of HBM bandwidth (PERF.md, PR 27).
 
 The XLA reference :func:`decode_attention_xla` is the numerics
 specification: it mirrors the TRAINING attention expression
@@ -43,17 +66,14 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._pallas_tiling import LANES as _LANES
+from apex_tpu.ops._pallas_tiling import LANES as _LANES, VMEM_BUDGET
 from apex_tpu.transformer.functional.fused_softmax import MASK_FILL_VALUE
 
 NEG_INF = -1e30
-
-_DIM_SEMANTICS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
-
 
 # ---------------------------------------------------------------- reference
 def stacked_pools(pools, layer):
@@ -91,8 +111,8 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
     heads).  ``k_pool``/``v_pool``: the stacked pool (L, num_pages,
     H_kv, D, page_size) with ``layer`` a (traced) scalar, or one
     layer's (num_pages, H_kv, D, page_size) — head-dim-major pages:
-    each (page, kv head) is one contiguous (D, page_size) tile, the
-    block the kernel DMAs.
+    each (page, kv head) is one contiguous (D, page_size) tile, and a
+    page's heads one contiguous block, what the kernel DMAs.
     ``page_table``: (B, P) int32 page ids,
     CLAMPED into the pool before the gather (a stale/garbage entry
     reads the reserved garbage page instead of wrapping).  ``lengths``:
@@ -166,13 +186,201 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
 
 
 # ------------------------------------------------------------------ kernel
+#: VMEM the kernel plans for (half of what a ``pallas_call`` may hold,
+#: as ``apex_kv_write`` plans)
+_VMEM_BUDGET = VMEM_BUDGET // 2
+
+
+def _head_bytes(group, head_dim, page_size, kv_dtype):
+    """VMEM that one kv head of a grid step's block costs: its page
+    tile four times over as a block (k and v, each double-buffered,
+    lanes padded to 128) and twice in f32 (both widened to an f32
+    query), and its group's f32 rows — running max, sum, accumulator,
+    the page's scores and probabilities."""
+    lanes = max(page_size, _LANES)
+    tile = head_dim * lanes * (4 * jnp.dtype(kv_dtype).itemsize + 2 * 4)
+    rows = 4 * max(group, 8) * (2 * _LANES + max(head_dim, _LANES)
+                                + 2 * lanes)
+    return tile + rows
+
+
+def _plan(rows, h_kv, group, head_dim, pages_per_seq, page_size, kv_dtype):
+    """``(h_blk, grid)`` for these shapes: the kv heads a grid step
+    holds — the largest divisor of ``h_kv`` whose block fits the budget
+    — and the grid: ``(rows, h_kv // h_blk)`` where the kernel walks a
+    row's live pages itself (a page of whole lane tiles), with a third
+    dimension ``pages_per_seq`` where the grid does.  At GPT-2 large's
+    shapes (20 heads of 64, page 128, bf16) a block is all 20 heads: 20
+    grid steps a layer where one (row, head, page slot) a step made
+    3,200."""
+    fit = _VMEM_BUDGET // _head_bytes(group, head_dim, page_size, kv_dtype)
+    h_blk = max(d for d in range(1, h_kv + 1)
+                if h_kv % d == 0 and d <= max(fit, 1))
+    grid = (rows, h_kv // h_blk)
+    return h_blk, grid if page_size % _LANES == 0 \
+        else grid + (pages_per_seq,)
+
+
+def _kv_block_index(b, g, p, pt_ref, len_ref, layer_ref, *, width,
+                    pages_per_seq, page_size):
+    """The pool block of grid step ``(row b, head block g, page slot
+    p)``.  A slot past the row's last live page names THAT page again,
+    and the pipeline does not fetch a block whose index did not change:
+    a whole page past a length is never read (a row without a live
+    position names its table's first entry, one block at most)."""
+    last = jnp.maximum((len_ref[b] + page_size - 1) // page_size - 1, 0)
+    slot = (b // width) * pages_per_seq + jnp.minimum(p, last)
+    return (layer_ref[0], pt_ref[slot], g, 0, 0)
+
+
+def _attend(q, k, v, first, length, m_ref, l_ref, acc_ref, *, denom, scale):
+    """One page of a block's kv heads against their queries, batched
+    over the heads on the MXU: ``q`` (h_blk, group, D), ``k``/``v``
+    (h_blk, D, page) whose first position is ``first``.  One step of
+    the online softmax (f32 running max/sum/accumulator in scratch)."""
+    if k.dtype != q.dtype:
+        # bf16 (or narrower) cache with an f32 query: widen the
+        # cache read rather than rounding q down (APX306)
+        k = k.astype(q.dtype)
+        v = v.astype(q.dtype)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    s = s / denom if scale is None else s * scale
+    pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    s = jnp.where(pos < length, s, NEG_INF)
+    m_prev = m_ref[:, :, 0:1]
+    l_prev = l_ref[:, :, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    pexp = jnp.exp(s - m_new)
+    pexp = jnp.where(s > NEG_INF / 2, pexp, 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(pexp, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        pexp.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    acc_ref[:] = acc_ref[:] * corr + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _walk_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+                 k_buf, v_buf, sem, m_ref, l_ref, acc_ref, state_ref, *,
+                 h_blk, n_blk, rows, page_size, pages_per_seq, width, denom,
+                 scale):
+    """One sequence row and one block of kv heads a grid step; the step
+    walks the row's LIVE pages itself.  The pools stay in HBM; a page's
+    k and v blocks are copied into one of two VMEM slots, the copy of
+    the next page issued before this page's is waited for, and the last
+    page of a row issues the first page of the NEXT live row, so the
+    copies run back to back across rows.  ``state_ref`` (SMEM) carries
+    across grid steps the slot the next page goes to and whether this
+    row's first page is already in flight.
+
+    The index arithmetic binds ``lax`` primitives directly: the kernel
+    is traced with every decode program, and each ``jnp`` operator on a
+    traced scalar costs a nested ``jit`` trace (with them the cell's
+    warm-up took 0.35 s longer than the parent's on the chip's host,
+    without 0.2 s; PERF.md, PR 27)."""
+    i32 = np.int32
+    add, sub, mul, lt = lax.add, lax.sub, lax.mul, lax.lt
+    b, g = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
+    length = len_ref[b]
+    n = lax.min(lax.div(add(length, i32(page_size - 1)), i32(page_size)),
+                i32(pages_per_seq))
+
+    def copies(page, blk, slot):
+        """The copies of one pool page's k and v blocks into a slot
+        (to wait for one, any page will do: a wait reads the slot's
+        semaphore and size)."""
+        where = (layer, page) if n_blk == 1 else (
+            layer, page, pl.ds(mul(blk, i32(h_blk)), h_blk))
+        return [pltpu.make_async_copy(hbm.at[where], buf.at[slot],
+                                      sem.at[j, slot])
+                for j, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf)))]
+
+    def page_of(row, i):
+        return pt_ref[add(mul(lax.div(row, i32(width)), i32(pages_per_seq)),
+                          i)]
+
+    @pl.when(lax.eq(add(b, g), i32(0)))
+    def _reset():
+        state_ref[0] = i32(0)
+        state_ref[1] = i32(0)
+
+    @pl.when(lax.eq(n, i32(0)))
+    def _inactive():
+        # nothing is fetched for a row without a live position
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(lax.gt(n, i32(0)))
+    def _active():
+        first_slot = state_ref[0]
+
+        @pl.when(lax.eq(state_ref[1], i32(0)))
+        def _first_of_all():
+            for dma in copies(page_of(b, i32(0)), g, first_slot):
+                dma.start()
+
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        # the work after this one: this row's next head block, or the
+        # first block of the next row with a live position
+        if n_blk > 1:
+            same_row = lt(add(g, i32(1)), i32(n_blk))
+        else:
+            same_row = False
+        nxt = lax.while_loop(
+            lambda r: lax.bitwise_and(
+                lt(r, i32(rows)),
+                lax.le(len_ref[lax.min(r, i32(rows - 1))], i32(0))),
+            lambda r: add(r, i32(1)), add(b, i32(1)))
+        has_next = lt(nxt, i32(rows))
+        next_row, next_blk = lax.min(nxt, i32(rows - 1)), i32(0)
+        if n_blk > 1:
+            has_next = lax.bitwise_or(same_row, has_next)
+            next_row = lax.select(same_row, b, next_row)
+            next_blk = lax.select(same_row, add(g, i32(1)), next_blk)
+        last = sub(n, i32(1))
+
+        def page_step(i, slot):
+            # the next copy goes out BEFORE this page's is waited for:
+            # this row's next page, or after its last the next row's first
+            more = lt(i, last)
+            other = sub(i32(1), slot)
+
+            @pl.when(lax.bitwise_or(more, has_next))
+            def _next():
+                page = page_of(lax.select(more, b, next_row),
+                               lax.select(more, add(i, i32(1)), i32(0)))
+                for dma in copies(page, lax.select(more, g, next_blk),
+                                  other):
+                    dma.start()
+
+            for dma in copies(0, 0, slot):
+                dma.wait()
+            _attend(q_ref[0, 0], k_buf[slot], v_buf[slot],
+                    mul(i, i32(page_size)), length, m_ref, l_ref, acc_ref,
+                    denom=denom, scale=scale)
+            return other
+
+        state_ref[0] = lax.fori_loop(i32(0), n, page_step, first_slot)
+        state_ref[1] = has_next.astype(jnp.int32)
+        o_ref[0, 0] = (acc_ref[:] / l_ref[:, :, 0:1]).astype(o_ref.dtype)
+
+
 def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
                         o_ref, m_ref, l_ref, acc_ref, *,
                         page_size, pages_per_seq, denom, scale):
-    """One (sequence, kv-head) pair; the sequential grid dim walks that
-    sequence's pages through VMEM.  Online softmax exactly as the flash
-    forward: running max/sum/accumulator in f32 scratch, finalize on
-    the last page."""
+    """The form for a page under 128 lanes: one sequence row and one
+    block of kv heads; the sequential grid dim walks that row's page
+    slots through VMEM, all of the block's heads a step.  Online
+    softmax exactly as the flash forward: running max/sum/accumulator
+    in f32 scratch, finalize on the last page."""
     del pt_ref, layer_ref  # consumed by the BlockSpec index maps
     b, p = pl.program_id(0), pl.program_id(2)
 
@@ -184,43 +392,19 @@ def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
 
     length = len_ref[b]
 
-    # whole pages at/after the length hold no valid position: skip the
-    # dots entirely (a freshly-admitted sequence costs page-1 work even
-    # when the step shape is sized for the longest resident cache)
+    # a page slot at/after the length holds no valid position: its block
+    # index was clamped to the last live page, so nothing was fetched
+    # for it, and nothing is computed (a freshly-admitted sequence costs
+    # page-1 work even when the step shape is sized for the longest
+    # resident cache)
     @pl.when(p * page_size < length)
     def _compute():
-        q = q_ref[0, 0]          # (group, D)
-        k = k_ref[0, 0, 0]       # (D, page) — group-shared GQA page
-        v = v_ref[0, 0, 0]
-        if k.dtype != q.dtype:
-            # bf16 (or narrower) cache with an f32 query: widen the
-            # cache read rather than rounding q down (APX306)
-            k = k.astype(q.dtype)
-            v = v.astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = s / denom if scale is None else s * scale
-        pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pexp = jnp.exp(s - m_new)
-        pexp = jnp.where(s > NEG_INF / 2, pexp, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(pexp, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        _attend(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], p * page_size, length,
+                m_ref, l_ref, acc_ref, denom=denom, scale=scale)
 
     @pl.when(p == pages_per_seq - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, 0:1], 1e-30)  # inactive rows: l == 0
+        l = jnp.maximum(l_ref[:, :, 0:1], 1e-30)  # inactive rows: l == 0
         o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
@@ -230,15 +414,17 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
     """The Pallas paged decode-attention launcher (see module doc).
 
     Shapes as :func:`decode_attention_xla`.  The flattened page table,
-    the lengths and the layer ride as scalar-prefetch operands so the
-    k/v BlockSpec index maps can dereference them — each grid step DMAs
-    exactly one (D, page_size) tile of the group-shared kv head out of
-    the stacked pool, which is never sliced, copied or re-laid out.  With ``width`` > 1 (the verify/chunk layout: q rows in
-    groups of ``width`` consecutive positions of one sequence) the
-    index maps fold the row back onto its sequence's table row —
-    ``pt[(b // width) * P + p]`` — so the table is prefetched once per
-    SEQUENCE, not once per query row; ``width`` is static, one compile
-    per verify width.
+    the lengths and the layer ride as scalar-prefetch operands; the
+    kernel reads one page's ``(h_blk, D, page_size)`` block of kv heads
+    at a time out of the stacked pool, which is never sliced, copied or
+    re-laid out, and only pages on which the row has a live position
+    (:func:`_walk_kernel` for a page of whole lane tiles,
+    :func:`_decode_attn_kernel` with :func:`_kv_block_index` for a
+    smaller one).  With ``width`` > 1 (the verify/chunk layout: q rows
+    in groups of ``width`` consecutive positions of one sequence) a row
+    reads its SEQUENCE's table row — ``pt[(b // width) * P + p]`` — so
+    the table is prefetched once per sequence, not once per query row;
+    ``width`` is static, one compile per verify width.
     """
     k_pool, v_pool, layer = as_stacked_pools(k_pool, v_pool, layer)
     B, H, D = q.shape
@@ -251,40 +437,62 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
             f"q rows ({B}) must equal page-table rows ({n_seq}) x width "
             f"({width})")
     group = H // h_kv
-    qg = q.reshape(B, h_kv, group, D)
+    h_blk, grid = _plan(B, h_kv, group, D, P, page_size, k_pool.dtype)
+    qg = q.reshape(B, h_kv // h_blk, h_blk, group, D)
     # clamp BEFORE prefetch: the index map output becomes a DMA source
     # address, where a garbage entry must hit the reserved garbage page,
     # never wrap (APX107's contract for page-table gathers)
     pt = jnp.clip(page_table, 0, num_pages - 1) \
         .reshape(n_seq * P).astype(jnp.int32)
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, 1, D, page_size),
-        lambda b, g, p, pt_ref, len_ref, layer_ref: (
-            layer_ref[0], pt_ref[(b // width) * P + p], g, 0, 0),
-    )
     q_spec = pl.BlockSpec(
-        (1, 1, group, D),
-        lambda b, g, p, pt_ref, len_ref, layer_ref: (b, g, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, h_kv, P),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((group, _LANES), jnp.float32),
-            pltpu.VMEM((group, _LANES), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
+        (1, 1, h_blk, group, D),
+        lambda b, g, *_: (b, g, 0, 0, 0))
+    scratch = [
+        pltpu.VMEM((h_blk, group, _LANES), jnp.float32),
+        pltpu.VMEM((h_blk, group, _LANES), jnp.float32),
+        pltpu.VMEM((h_blk, group, D), jnp.float32),
+    ]
+    if len(grid) == 2:      # the kernel walks the live pages itself
+        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+        tile = (2, h_blk, D, page_size)
+        kernel = functools.partial(
+            _walk_kernel, h_blk=h_blk, n_blk=grid[1], rows=B,
+            page_size=page_size, pages_per_seq=P, width=width,
+            denom=float(np.sqrt(D)), scale=softmax_scale)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[q_spec, pool_spec, pool_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM(tile, k_pool.dtype),
+                pltpu.VMEM(tile, v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ] + scratch + [pltpu.SMEM((2,), jnp.int32)],
+        )
+        semantics = ("arbitrary", "arbitrary")
+    else:
+        kv_spec = pl.BlockSpec(
+            (1, 1, h_blk, D, page_size),
+            functools.partial(_kv_block_index, width=width, pages_per_seq=P,
+                              page_size=page_size))
+        kernel = functools.partial(
             _decode_attn_kernel, page_size=page_size, pages_per_seq=P,
-            denom=float(np.sqrt(D)), scale=softmax_scale,
-        ),
+            denom=float(np.sqrt(D)), scale=softmax_scale)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=scratch,
+        )
+        semantics = ("parallel", "parallel", "arbitrary")
+    out = pl.pallas_call(
+        kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h_kv, group, D), v_pool.dtype),
-        compiler_params=_DIM_SEMANTICS,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, v_pool.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name="apex_decode_attention",
     )(pt, lengths.astype(jnp.int32),
@@ -296,7 +504,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
 def pallas_decode_attn_available(q, k_pool) -> bool:
     """Kernel path: a real TPU and a sublane-aligned head dim.
 
-    A k/v block is a whole (head_dim, page_size) tile, so any page size
+    A k/v block is whole (head_dim, page_size) tiles, so any page size
     lowers; one under 128 pads the lanes.  (No env-var override — thread
     ``attn_impl`` through :class:`apex_tpu.inference.DecodeConfig`
     instead; APX101/102.)"""

@@ -31,6 +31,7 @@ from apex_tpu.inference.decode import (
 from apex_tpu.models.gpt import (
     GPTConfig, forward_decode, gpt_forward, init_params, param_specs,
 )
+from apex_tpu.ops import decode_attention_pallas as dap
 from apex_tpu.ops.decode_attention_pallas import (
     decode_attention_xla, paged_decode_attention_pallas,
 )
@@ -240,6 +241,166 @@ class TestDecodeAttentionKernel:
             a = impl(q, kp, vp, pt_bad, lengths)
             b = impl(q, kp, vp, pt_ok, lengths)
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _blocked_case(rng, H, KVH, lengths, page, P, D=16, width=1,
+                  pool_dtype=jnp.float32, q_dtype=jnp.float32, layers=2):
+    """A stacked pool whose sequences own distinct pages (the
+    allocator's contract), page 0 the garbage page; ``lengths`` has one
+    entry a q row (``width`` rows a sequence)."""
+    n_seq = len(lengths) // width
+    num_pages = n_seq * P + 1
+    shape = (layers, num_pages, KVH, D, page)
+    kp = jnp.asarray(rng.randn(*shape), pool_dtype)
+    vp = jnp.asarray(rng.randn(*shape), pool_dtype)
+    pt = 1 + rng.permutation(n_seq * P).reshape(n_seq, P).astype(np.int32)
+    q = jnp.asarray(rng.randn(len(lengths), H, D), q_dtype)
+    return q, kp, vp, jnp.asarray(pt), jnp.asarray(lengths, jnp.int32)
+
+
+#: name -> (heads, kv heads, lengths a row as multiples of (page, 1),
+#: heads that fit VMEM or None, kwargs).  Every case runs at a page
+#: under 128 (the grid walks the page slots) and at 128 (the kernel
+#: walks the live pages itself); 4 pages a sequence.
+_LENGTHS = ((0, 0), (0, 1), (1, 0), (1, 1), (4, 0))     # 0 1 pg pg+1 4pg
+_MIXED = ((0, 0), (1, -1), (1, 0), (4, -2))
+# width 3: rows pg-1, pg, pg+1 of a sequence straddle pages 0|1; an
+# inactive sequence; a sequence that ends on the table's last position
+_WIDTH3 = ((1, -1), (1, 0), (1, 1), (0, 0), (0, 0), (0, 0),
+           (4, -2), (4, -1), (4, 0))
+BLOCKED_CASES = {
+    # one query row a head (GPT-2), every length that matters
+    "mha_lengths": (4, 4, _LENGTHS, None, {}),
+    "mha_all_inactive": (4, 4, ((0, 0),) * 3, None, {}),
+    "mha_live_rows_apart": (4, 4, ((0, 5), (0, 0), (0, 0), (2, 9), (0, 0),
+                                   (4, 0), (0, 1), (0, 0)), None, {}),
+    # a budget that holds 4 heads of 6: blocks of 3; of 5 (a prime): 1
+    "mha6_two_head_blocks": (6, 6, _MIXED, 4, {}),
+    "mha5_one_head_a_block": (5, 5, ((0, 3), (0, 0), (2, 1), (4, 0)), 2,
+                              {}),
+    "mha_bf16_cache_f32_query": (4, 4, _MIXED, None,
+                                 {"pool_dtype": jnp.bfloat16}),
+    "mha_bf16_cache_bf16_query": (4, 4, _MIXED, None,
+                                  {"pool_dtype": jnp.bfloat16,
+                                   "q_dtype": jnp.bfloat16}),
+    "mha_width3_straddle": (4, 4, _WIDTH3, None, {"width": 3}),
+    "mha6_width3_head_blocks": (6, 6, ((1, 0), (1, 1), (1, 2), (0, 1),
+                                       (0, 2), (0, 3)), 4, {"width": 3}),
+    # grouped queries, one kv head (MQA)
+    "gqa16_4_lengths": (16, 4, _LENGTHS, None, {}),
+    "gqa16_4_all_inactive": (16, 4, ((0, 0),) * 2, None, {}),
+    "gqa16_4_two_head_blocks": (16, 4, _MIXED, 2, {}),
+    "gqa16_4_bf16_cache_f32_query": (16, 4, _MIXED, None,
+                                     {"pool_dtype": jnp.bfloat16}),
+    "gqa16_4_width3_straddle": (16, 4, _WIDTH3, None, {"width": 3}),
+    "mqa_lengths": (12, 1, _LENGTHS, None, {}),
+    "mqa_bf16_cache_f32_query": (12, 1, _MIXED, None,
+                                 {"pool_dtype": jnp.bfloat16}),
+    "mqa_width3_straddle": (12, 1, _WIDTH3[:6], None, {"width": 3}),
+}
+
+
+class TestDecodeAttentionBlocking:
+    """The kernel's unit of work is a live page of a sequence row, all
+    of a block's kv heads (PERF.md, PR 27), in both of its forms:
+    parity with the reference over head blocks, lengths, widths and
+    dtypes, and the promise that a whole page past a length is never
+    read."""
+
+    @pytest.mark.parametrize("page", [8, 128])
+    @pytest.mark.parametrize("name", sorted(BLOCKED_CASES))
+    def test_blocked_kernel_matches_reference(self, name, page,
+                                              monkeypatch):
+        H, KVH, lengths, fit, kw = BLOCKED_CASES[name]
+        lengths = [pages * page + more for pages, more in lengths]
+        width = kw.get("width", 1)
+        q, kp, vp, pt, ln = _blocked_case(
+            np.random.RandomState(len(name)), H, KVH, lengths,
+            **{"page": page, "P": 4, **kw})
+        h_kv_blocks = 1
+        if fit is not None:
+            # the kernel has no argument for its head block, on purpose
+            monkeypatch.setattr(dap, "_VMEM_BUDGET", fit * dap._head_bytes(
+                H // KVH, q.shape[-1], page, kp.dtype))
+            h_kv_blocks = KVH // max(d for d in range(1, fit + 1)
+                                     if KVH % d == 0)
+            assert h_kv_blocks > 1
+        _, grid = dap._plan(len(lengths), KVH, H // KVH, q.shape[-1],
+                            pt.shape[1], page, kp.dtype)
+        assert grid == (len(lengths), h_kv_blocks) + (
+            () if page == 128 else (pt.shape[1],))
+        ref = decode_attention_xla(q, kp, vp, pt, ln, width=width, layer=1)
+        out = paged_decode_attention_pallas(q, kp, vp, pt, ln, width=width,
+                                            interpret=True, layer=1)
+        assert out.shape == ref.shape and out.dtype == ref.dtype
+        tol = 1e-5 if kp.dtype == jnp.float32 else 0.05
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            rtol=0, atol=tol)
+        dead = np.asarray(lengths) == 0
+        assert float(np.abs(np.asarray(out, np.float32)[dead]).sum()) == 0.0
+
+    @pytest.mark.parametrize("page", [8, 128])
+    @pytest.mark.parametrize("H,KVH,width", [
+        (4, 4, 1), (6, 6, 3), (16, 4, 1), (16, 4, 3), (12, 1, 1)],
+        ids=["mha", "mha6_width3", "gqa16_4", "gqa16_4_width3", "mqa"])
+    def test_dead_pages_are_never_read(self, H, KVH, width, page):
+        """Every pool page that holds no live position of any sequence
+        — the garbage page, the table's slots past each length, every
+        page of an inactive sequence, the other layers — is poisoned;
+        the output must not change by a bit.  (Whole pages only: the
+        tail of a live page is masked per position.)"""
+        P = 4
+        first = [0, 1, page - 1, page, 2 * page + 3, P * page - width]
+        lengths = [(f + w + 1 if f else 0)
+                   for f in first for w in range(width)]
+        q, kp, vp, pt, ln = _blocked_case(
+            np.random.RandomState(H + KVH + width), H, KVH, lengths,
+            page=page, P=P, width=width, pool_dtype=jnp.bfloat16, layers=3)
+        live = np.zeros(kp.shape[1], bool)
+        for row, n in zip(np.asarray(pt), np.asarray(ln).reshape(-1, width)
+                          .max(axis=1)):
+            live[row[:-(-n // page)]] = True
+        assert not live[GARBAGE_PAGE] and live.sum() < len(live) - 1
+        poison = np.where(np.arange(kp.size).reshape(kp.shape) % 2,
+                          np.nan, np.inf)
+        mask = np.ones(kp.shape, bool)
+        mask[1, live] = False                # layer 1's live pages stay
+
+        def run(k, v):
+            return np.asarray(paged_decode_attention_pallas(
+                q, k, v, pt, ln, width=width, interpret=True, layer=1),
+                np.float32)
+
+        clean = run(kp, vp)
+        dirty = run(jnp.where(mask, poison, kp).astype(kp.dtype),
+                    jnp.where(mask, poison, vp).astype(vp.dtype))
+        assert np.isfinite(clean).all()
+        np.testing.assert_array_equal(dirty, clean)
+
+    @pytest.mark.parametrize("width", [1, 3])
+    def test_block_index_names_live_pages_only(self, width):
+        """What the pipeline FETCHES where the grid walks the page
+        slots (a page under 128), from the index map itself: a live row
+        names its live pages, each once and in order (a repeated index
+        is not fetched again), and a row without a live position names
+        one block."""
+        page, P = 16, 8
+        lengths = np.array([0, 1, 16, 17, 80, 128, 0, 40] * width)
+        lengths = np.sort(lengths.reshape(width, -1), axis=0).T.reshape(-1)
+        n_seq = len(lengths) // width
+        pt = (1 + np.arange(n_seq * P)).astype(np.int32)
+        for b, n in enumerate(lengths):
+            named = [tuple(int(x) for x in dap._kv_block_index(
+                b, 2, p, pt, lengths, np.array([5]), width=width,
+                pages_per_seq=P, page_size=page)) for p in range(P)]
+            assert all(i[0] == 5 and i[2:] == (2, 0, 0) for i in named)
+            pages = [i[1] for i in named]
+            row = pt[(b // width) * P:][:P]
+            live = -(-int(n) // page)
+            assert pages[:live] == list(row[:live])
+            assert set(pages[live:]) <= {pages[max(live - 1, 0)]}
+            assert len(set(pages)) == max(live, 1)
 
 
 # --------------------------------------------------------- fused sampling

@@ -44,13 +44,25 @@ BF16, F32, I32, U32 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.uint32
 VOCAB = 50304
 
 
-def _decode_attn(heads, kv_heads, width, page=16):
-    B, D, P, pages = 8, 64, 12, 97
+def _decode_attn(heads, kv_heads, width, page=16, B=8, D=64, P=12,
+                 pages=97, layers=None):
+    """One layer's pool, or with ``layers`` the stacked pool and a
+    traced layer."""
     pool = ((pages, kv_heads, D, page), BF16)
-    return (lambda q, k, v, pt, n: paged_decode_attention_pallas(
-        q, k, v, pt, n, width=width),
-        [((B * width, heads, D), BF16), pool, pool, ((B, P), I32),
-         ((B * width,), I32)])
+    avals = [((B * width, heads, D), BF16), pool, pool, ((B, P), I32),
+             ((B * width,), I32)]
+    if layers is None:
+        return (lambda q, k, v, pt, n: paged_decode_attention_pallas(
+            q, k, v, pt, n, width=width), avals)
+    avals[1] = avals[2] = ((layers, *pool[0]), BF16)
+    return (lambda q, k, v, pt, n, layer: paged_decode_attention_pallas(
+        q, k, v, pt, n, width=width, layer=layer), avals + [((), I32)])
+
+
+#: GPT-2 large as the benchmark's serve cells run the kernel: 20 slots,
+#: 20 kv heads of 64, page 128, 8 pages a sequence, the 36-layer
+#: stacked pool (a garbage page and every slot's pages) at a traced layer
+_CELL = dict(B=20, D=64, page=128, P=8, pages=161, layers=36)
 
 
 def _kv_write(kv_heads, width, page=16, dtype=BF16, D=64):
@@ -143,6 +155,12 @@ CASES = {
                             {"apex_decode_attention"}),
     "decode_attn_page128": (*_decode_attn(20, 20, 1, page=128),
                             {"apex_decode_attention"}),
+    "decode_attn_cell": (*_decode_attn(20, 20, 1, **_CELL),
+                         {"apex_decode_attention"}),
+    "decode_attn_gqa16_4_page128": (*_decode_attn(16, 4, 1, page=128),
+                                    {"apex_decode_attention"}),
+    "decode_attn_verify5_page128": (*_decode_attn(12, 12, 5, page=128),
+                                    {"apex_decode_attention"}),
     # the in-place pool writes: decode token, verify rows, prompt;
     # page 16 (chip_smoke) and 128 (the benchmark's cells); an fp32
     # cache at head dim 128 splits a tile over blocks of heads
@@ -194,6 +212,37 @@ def test_kernel_lowers_for_tpu(name):
     exp = jexport.export(jax.jit(fn), platforms=["tpu"])(
         *[jax.ShapeDtypeStruct(s, d) for s, d in avals])
     assert "tpu_custom_call" in exp.mlir_module()
+
+
+def test_decode_attention_grid_at_the_cells_shapes():
+    """A grid step of ``apex_decode_attention`` is a sequence row with
+    ALL of its kv heads, and the kernel walks the row's live pages
+    itself: at the serve cells' shapes the launcher plans 20 steps a
+    layer (3,200 before PR 27), and the lowered kernel has that grid.
+    A page under 128 lanes keeps the page slots in the grid."""
+    from apex_tpu.ops.decode_attention_pallas import _plan
+
+    def lowered_grid(name):
+        fn, avals, _ = CASES[name]
+        jaxpr = jax.make_jaxpr(fn)(
+            *[jax.ShapeDtypeStruct(s, d) for s, d in avals])
+        call, = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        return tuple(call.params["grid_mapping"].grid)
+
+    B, P, page, heads = _CELL["B"], _CELL["P"], _CELL["page"], 20
+    h_blk, grid = _plan(B, heads, 1, _CELL["D"], P, page, BF16)
+    assert h_blk == heads
+    assert grid == (B, 1) == lowered_grid("decode_attn_cell")
+    assert grid[0] * grid[1] <= B * P == 160
+    # chip_smoke's shapes: page 16, 12 heads, 12 page slots a sequence
+    assert _plan(8, 12, 1, 64, 12, 16, BF16) == (12, (8, 1, 12))
+    assert lowered_grid("decode_attn_mha12") == (8, 1, 12)
+    # an fp32 cache at head dim 256 does not fit 20 heads a block: the
+    # plan splits them, it does not overrun VMEM
+    h_blk, grid = _plan(B, heads, 1, 256, P, page, F32)
+    assert 1 <= h_blk < heads and heads % h_blk == 0
+    assert grid == (B, heads // h_blk)
 
 
 #: a compile-only v5e device, or one JSON line {"skip": why} and exit 0
