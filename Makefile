@@ -1,9 +1,13 @@
 # CI surface for apex_tpu — `make ci` is what .github/workflows/ci.yml
-# runs, and what a laptop runs before pushing.  Four gates:
+# runs, and what a laptop runs before pushing.  Two gates:
 #
 #   make test        tier-1 (quick) pytest suite on the 8-virtual-device
-#                    CPU platform — ROADMAP.md's canonical invocation
-#   make analyze     the static analyzer, ONE scan doing both jobs:
+#                    CPU platform, six xdist workers — the command the
+#                    driver judges the suite by, less its junit
+#                    plumbing and the ALLOW_MULTIPLE_LIBTPU_LOAD=1 it
+#                    sets for its own runs
+#   make analyze     the static analyzer over its default operands
+#                    (apex_tpu, examples), ONE scan doing both jobs:
 #                    writes the SARIF document for code scanning
 #                    (analysis.sarif — written before the exit code, so
 #                    the upload has content exactly when there ARE
@@ -12,46 +16,25 @@
 #                    human-readable rule-id summary on stderr; the
 #                    per-rule timing JSON (analysis_timing.json) rides
 #                    along so CI can attribute a slow scan to a rule
-#   make fleet-smoke the serving-resilience gate: bench.py's smoke
-#                    serve_gpt124 section, whose fleet mode runs a
-#                    2-replica frontend, chaos-kills one replica
-#                    mid-run, and asserts dropped_requests == 0 with
-#                    greedy streams bitwise the unkilled single-replica
-#                    run (plus the spec/prefix/chunked serving modes the
-#                    section always covered)
-#   make bench-gate  the perf-regression gate: benchmarks/bench_compare.py
-#                    diffs the two newest BENCH_*.json rounds' headline
-#                    columns (no-op when fewer than two rounds exist —
-#                    chip benches don't run in CPU CI)
 #
-# See docs/static_analysis.md for analyzer details and the baseline
+# Speed is not gated here: it is measured on the chip, one cell at a
+# time (`python -m cellbench`, BENCHMARK.json, PERF.md).  See
+# docs/static_analysis.md for analyzer details and the baseline
 # contract.
 
 PYTHON ?= python
 JOBS   ?= 2
 
-.PHONY: ci test analyze fleet-smoke bench-gate
+.PHONY: ci test analyze
 
-ci: analyze test fleet-smoke bench-gate
+ci: analyze test
 
 test:
-	timeout -k 10 870 env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q \
-	  -m 'not slow' --continue-on-collection-errors \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
+	timeout -k 10 1470 env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q \
+	  -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
+	  -p xdist -n 6 --dist loadfile -p no:randomly
 
 analyze:
-	$(PYTHON) -m apex_tpu.analysis apex_tpu bench.py \
+	$(PYTHON) -m apex_tpu.analysis \
 	  --format sarif --check-baseline --jobs $(JOBS) \
 	  --timing-json analysis_timing.json > analysis.sarif
-
-fleet-smoke:
-	timeout -k 10 600 env JAX_PLATFORMS=cpu \
-	  $(PYTHON) bench.py --smoke --smoke-only serve_gpt124
-
-bench-gate:
-	@n=$$(ls BENCH_r*.json 2>/dev/null | wc -l); \
-	if [ "$$n" -lt 2 ]; then \
-	  echo "bench-gate: $$n BENCH_r*.json round(s) found — need two, skipping"; \
-	else \
-	  $(PYTHON) benchmarks/bench_compare.py; \
-	fi

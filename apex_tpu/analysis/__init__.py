@@ -6,7 +6,7 @@ reviewer in this repo's history — the rules scale those findings into
 machine-checked invariants):
 
 - **APX101/102** trace-time host-state capture and process-global env
-  mutation (``rules_trace``) — the ``bench.py:876`` class.
+  mutation (``rules_trace``) — the trace-time host-state class.
 - **APX103** donated-buffer reuse: a ``donate_argnums`` argument read
   after the donating call without a rebind (``rules_donation``) — a
   no-op on CPU, garbage or a deleted-array error on TPU.

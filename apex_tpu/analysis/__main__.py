@@ -2,8 +2,8 @@
 
 Exit codes: 0 clean (modulo baseline), 1 findings, 2 usage/baseline
 error.  With no paths, scans the repo's default surface (``apex_tpu``,
-``bench.py``, ``examples`` — whichever exist under the current
-directory) against ``analysis_baseline.json`` when present.
+``examples`` — whichever exist under the current directory) against
+``analysis_baseline.json`` when present.
 """
 
 from __future__ import annotations
@@ -18,7 +18,7 @@ from apex_tpu.analysis import (
     discover_axis_registry, load_baseline, sarif, write_baseline,
 )
 
-DEFAULT_PATHS = ("apex_tpu", "bench.py", "examples")
+DEFAULT_PATHS = ("apex_tpu", "examples")
 DEFAULT_BASELINE = "analysis_baseline.json"
 
 

@@ -506,7 +506,7 @@ def _module_name_for(file: str, root: str) -> str:
     parent: a package root (dir with ``__init__.py``) contributes its
     own name (``apex_tpu/ops/x.py`` scanned via root ``apex_tpu`` →
     ``apex_tpu.ops.x``); a bare dir's files are top-level modules; a
-    file root is its own module (``bench.py`` → ``bench``)."""
+    file root is its own module (``chip_smoke.py`` → ``chip_smoke``)."""
     if os.path.isfile(root):
         rel = os.path.basename(file)
     else:

@@ -2,7 +2,7 @@
 
 ``donate_argnums`` tells XLA it may reuse an input buffer's memory for
 the outputs.  That is the difference between fitting and halving the
-batch at 345M scale (see ``bench.py``'s GPT sections) — and it is also
+batch at 345M scale — and it is also
 the one jit option whose misuse is INVISIBLE everywhere but the chip:
 on CPU donation is a no-op, so a test that reads a donated buffer after
 the step passes locally and reads garbage (or crashes with "array has

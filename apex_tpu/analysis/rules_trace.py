@@ -1,4 +1,4 @@
-"""Trace-time host-state rules (the ``bench.py:876`` class).
+"""Trace-time host-state rules.
 
 JAX traces a Python function ONCE per (shape, dtype, static-arg)
 signature; everything the Python body reads from the host — env vars,

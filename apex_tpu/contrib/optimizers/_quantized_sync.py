@@ -224,8 +224,10 @@ def grad_sync_bytes(total: int, sync_dtype, block: int = QBLOCK,
     rank contributes to each hop's collective).  The scale-vector bytes
     of the quantized wires (the fp32 per-block amax psum) are EXPLICIT
     per hop — never folded into a payload approximation — so the
-    bench's ``wire_bytes_per_step`` ratios (≈2x int8 vs bf16, ≈4x vs
-    fp32, the ``1/dp_inner`` cross-slice cut) are exact.
+    ratios of ``ZeroOptimizerBase.wire_bytes_per_step`` (≈2x int8 vs
+    bf16, ≈4x vs fp32, the ``1/dp_inner`` cross-slice cut) are exact
+    (``tests/test_distributed_optimizers.py`` pins them; the time they
+    save is not measured: no cell has a dp axis yet).
 
     - flat (``hier=None``): one hop keyed ``flat_hop`` with the full
       ``total``-element payload in the sync dtype;

@@ -950,8 +950,9 @@ class ZeroOptimizerBase:
                 for b in plan.buckets]
 
     def wire_bytes_per_step(self) -> Dict[str, Any]:
-        """Static per-step wire accounting off the bucket plan — what
-        the ``zero_gpt124`` bench reports per sync mode:
+        """Static per-step wire accounting off the bucket plan, per
+        sync mode (byte counts from shapes; collective time on the chip
+        is not measured until a four-chip cell exists):
 
         - ``grad_payload``: Σ bucket totals × the grad wire itemsize
           (1 B for int8/fp8), summed over every hop;
@@ -965,9 +966,8 @@ class ZeroOptimizerBase:
           grad_scales, grad_sync, param_sync, total}}`` — one entry
           (the flat dp axis) on a flat plan, ``{inner, outer}`` axes on
           a hierarchical one.  The slow (outer/cross-slice) hop's entry
-          is the bench's ``cross_slice_wire_cut`` numerator input:
-          exactly ``1/dp_inner`` of the flat plan's bytes at equal wire
-          dtype, scales included."""
+          is exactly ``1/dp_inner`` of the flat plan's bytes at equal
+          wire dtype, scales included."""
         plan = self._require_plan()
         hier = self._hier_plan
         hops: Dict[str, Dict[str, int]] = {}

@@ -9,7 +9,7 @@ event instead of N dropped streams:
 - :mod:`~apex_tpu.inference.fleet.replica` — replica lifecycle
   (starting → warm → serving → draining → dead) with heartbeats and
   per-replica state gauges; :class:`LocalReplica` is the in-process
-  incarnation the tests and bench drive.
+  incarnation the tests drive.
 - :mod:`~apex_tpu.inference.fleet.journal` — the request journal and
   the splice invariant that makes multi-leg streams gapless and
   duplicate-free (bitwise the unkilled stream under greedy decoding).

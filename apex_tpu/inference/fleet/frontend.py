@@ -193,7 +193,7 @@ class FleetFrontend:
     def run_until_drained(self, max_steps: int = 10_000
                           ) -> List[FleetCompletion]:
         """Drive :meth:`step` until every journaled request finished
-        (the test/bench convenience loop)."""
+        (the tests' convenience loop)."""
         for _ in range(max_steps):
             if not self.journal.unfinished():
                 return self.completed

@@ -21,7 +21,7 @@ State machine (``REPLICA_STATES``, in lifecycle order)::
   a chaos plan keyed on replica steps fires once, not once per life.
 
 :class:`LocalReplica` is the in-process incarnation (one scheduler per
-replica object, same process) that the fleet tests and the bench drive
+replica object, same process) that the fleet tests drive
 — the same frontend logic applies unchanged when each replica is a
 supervised ``serve_gpt.py --replica-id`` child, because every
 interaction goes through the scheduler's public seams (``submit`` /
@@ -210,7 +210,7 @@ class LocalReplica:
         return worked
 
     def kill(self) -> None:
-        """Direct in-process SIGKILL analogue (tests, bench): die hard
+        """Direct in-process SIGKILL analogue (tests): die hard
         right now, no manifest."""
         self.mark_dead("kill")
 

@@ -49,12 +49,11 @@ from apex_tpu.observability.correlation import (
 )
 from apex_tpu.observability.flightrec import FlightRecorder
 from apex_tpu.observability.goodput import (
-    GoodputAccountant, decode_flops_per_token, goodput_report,
-    model_flops_per_step, model_flops_per_token, param_count,
+    GoodputAccountant, goodput_report, model_flops_per_token, param_count,
     session_progress,
 )
 from apex_tpu.observability.metrics import (
-    MetricsRegistry, MetricsScope, append_jsonl, get_metrics,
+    MetricsRegistry, MetricsScope, get_metrics,
 )
 from apex_tpu.observability.stepstats import (
     AsyncFetcher, StepStats, StepTelemetry,
@@ -67,9 +66,8 @@ __all__ = [
     "AnomalyMonitor", "AsyncFetcher", "FlightRecorder",
     "GoodputAccountant", "MetricsRegistry", "MetricsScope",
     "RollingMadDetector", "StepStats", "StepTelemetry", "TracedStep",
-    "Tracer", "TracingScope", "append_jsonl", "clear_step_context",
-    "decode_flops_per_token", "get_metrics", "goodput_report",
-    "model_flops_per_step", "model_flops_per_token", "new_trace_id",
+    "Tracer", "TracingScope", "clear_step_context", "get_metrics",
+    "goodput_report", "model_flops_per_token", "new_trace_id",
     "param_count", "session_progress", "set_step_context", "span",
     "step_context",
 ]

@@ -299,9 +299,9 @@ class AnomalyMonitor:
 
     # ------------------------------------------------------ introspection
     def counts(self) -> Dict[str, int]:
-        """TRUE alert counts per kind (the bench/driver report column;
-        the ``alerts`` deque holds only the most recent records, so
-        counts come from dedicated counters that never saturate)."""
+        """TRUE alert counts per kind (the driver's report column; the
+        ``alerts`` deque holds only the most recent records, so counts
+        come from dedicated counters that never saturate)."""
         with self._lock:
             return dict(self._counts)
 

@@ -18,10 +18,9 @@ breakdown:
   last-progress→death tail to ``wedge`` — so an injected wedged
   collective shows up as a measurable goodput loss, not a log line.
 
-MFU helpers centralize the model-FLOPs formula bench.py has always
-used (6N + 12·L·S·H per trained token, no recompute credit; 2N per
-decoded token) so the trainer, the serving bench, and the report agree
-on the denominator's numerator.
+The MFU helper holds the model-FLOPs formula (6N + 12·L·S·H per trained
+token, no recompute credit) that the trainer's report divides by; the
+benchmark's ``mfu.train`` reads its own copy under ``cellbench/``.
 """
 
 import contextlib
@@ -34,9 +33,8 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 __all__ = [
-    "GoodputAccountant", "decode_flops_per_token", "goodput_report",
-    "model_flops_per_step", "model_flops_per_token", "param_count",
-    "session_progress",
+    "GoodputAccountant", "goodput_report", "model_flops_per_token",
+    "param_count", "session_progress",
 ]
 
 SCHEMA = "apex_tpu_goodput_v1"
@@ -53,21 +51,8 @@ def model_flops_per_token(n_params: int, num_layers: int, seq: int,
                           hidden: int) -> float:
     """Train-step model FLOPs per token: ``6N`` (fwd+bwd matmuls) plus
     the attention term ``12·L·S·H`` — the usual MFU convention (no
-    recompute credit), and exactly bench.py's historical formula."""
+    recompute credit)."""
     return 6.0 * n_params + 12.0 * num_layers * seq * hidden
-
-
-def model_flops_per_step(n_params: int, num_layers: int, seq: int,
-                         hidden: int, batch: int) -> float:
-    return model_flops_per_token(n_params, num_layers, seq, hidden) \
-        * batch * seq
-
-
-def decode_flops_per_token(n_params: int) -> float:
-    """Serving decode FLOPs per generated token: the forward matmuls
-    (``2N``); attention-over-cache is cache-length-dependent and small
-    against the matmuls at the page sizes served here."""
-    return 2.0 * n_params
 
 
 # --------------------------------------------------------------- accountant

@@ -1,9 +1,9 @@
 """Process-local, rank-aware metrics registry.
 
 The unified metrics layer the repo's one-off telemetry primitives
-(``log_structured`` events, bench sidecar records, per-section JSON)
-plug into — TorchTitan's built-in-metrics pillar (PAPERS.md, arxiv
-2410.06511) in apex_tpu shape:
+(``log_structured`` events, sidecar records) plug into — TorchTitan's
+built-in-metrics pillar (PAPERS.md, arxiv 2410.06511) in apex_tpu
+shape:
 
 - **Counters / gauges / histograms with labels**: plain host-side
   Python objects (a dict update under a lock — safe to call from the
@@ -13,11 +13,10 @@ plug into — TorchTitan's built-in-metrics pillar (PAPERS.md, arxiv
   embedded servers can scope their own.
 - **JSONL time-series sidecar** (:meth:`MetricsRegistry.snapshot_jsonl`):
   one line per sample per snapshot, append+flush+fsync — the same
-  greppability contract as ``utils.logging.log_structured`` and
-  bench.py's section sidecar (whose writer now lives here,
-  :func:`append_jsonl`).  Every line carries ``ts``, the process
-  ``rank``, and the :mod:`~apex_tpu.observability.correlation`
-  ``(run_id, step)`` so it joins against logs and xprof ranges.
+  greppability contract as ``utils.logging.log_structured``.  Every
+  line carries ``ts``, the process ``rank``, and the
+  :mod:`~apex_tpu.observability.correlation` ``(run_id, step)`` so it
+  joins against logs and xprof ranges.
 - **Prometheus text exporter** (:meth:`MetricsRegistry.prometheus_text`):
   the 0.0.4 exposition format (``# HELP``/``# TYPE`` + samples;
   histograms expand to cumulative ``_bucket``/``_sum``/``_count``) for
@@ -40,7 +39,7 @@ from apex_tpu.observability.correlation import step_context
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "MetricsScope",
-    "append_jsonl", "get_metrics", "inc", "observe", "set_gauge",
+    "get_metrics", "inc", "observe", "set_gauge",
 ]
 
 #: default latency buckets (seconds): sub-ms decode tokens through
@@ -421,17 +420,6 @@ def _esc_label(v) -> str:
 def _esc_help(v: str) -> str:
     """HELP-text escaping: backslash and LF."""
     return str(v).replace("\\", r"\\").replace("\n", r"\n")
-
-
-def append_jsonl(path, obj: dict) -> None:
-    """THE append-one-JSON-line writer (append + flush + fsync) —
-    shared by the metrics sidecar and bench.py's section sidecar, so a
-    process killed mid-run keeps every line that was written."""
-    line = json.dumps(obj, sort_keys=True, default=str)
-    with open(path, "a") as f:
-        f.write(line + "\n")
-        f.flush()
-        os.fsync(f.fileno())
 
 
 # ------------------------------------------------------- current registry

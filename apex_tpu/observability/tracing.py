@@ -48,7 +48,7 @@ Span naming schema (see docs/observability.md for the full table):
 ``<subsystem>.<phase>`` — ``train.step.dispatch``, ``train.data_wait``,
 ``train.checkpoint_save``, ``serve.admit``, ``serve.prefill``,
 ``serve.decode_step``, ``serve.emit``, ``serve.request``,
-``supervisor.attempt``, ``bench.section.<name>``.
+``supervisor.attempt``.
 
 A span around device work ends when its result is ON THE HOST, or is
 named ``.dispatch`` (``serve.prefill`` and ``serve.decode_step`` end in
@@ -424,9 +424,9 @@ def export_run(dir_path, run_id, tracer: Optional["Tracer"] = None
     """Export the current trace under ``dir_path`` with THE repo-wide
     artifact convention — ``trace_<run_id>_<pid>.json`` (Perfetto/
     Chrome) plus ``spans_<run_id>_<pid>.jsonl`` (sidecar contract) —
-    the one spelling shared by the train/serve drivers, the wedge
-    hook, and bench (the e2e forensics test and the docs table both
-    glob these names).  Creates ``dir_path`` if missing; returns
+    the one spelling shared by the train/serve drivers and the wedge
+    hook (the e2e forensics test and the docs table both glob these
+    names).  Creates ``dir_path`` if missing; returns
     ``{"chrome", "jsonl", "events", "dropped"}``, or None when no
     tracer is installed."""
     t = tracer if tracer is not None else _TRACER

@@ -26,7 +26,6 @@ numerics specification and the universal fallback (CPU, odd shapes).
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -46,15 +45,10 @@ NEG_INF = -1e30
 # where the scratch carry, its init, and its finalize live — is
 # sequential.  Declaring this lets Mosaic software-pipeline the block
 # DMAs across grid steps instead of serializing on the conservative
-# default.  APEX_TPU_FLASH_DIMSEM=0 reverts to the default semantics so
-# the win is measurable A/B on hardware (numerics are identical either
-# way — the arbitrary dim still runs in order).
-_DIM_SEMANTICS = (
-    pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-    if os.environ.get("APEX_TPU_FLASH_DIMSEM", "1") != "0"
-    else pltpu.CompilerParams()
-)
+# default (numerics are identical either way — the arbitrary dim still
+# runs in order).
+_DIM_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # ------------------------------------------------------------ block tuning
@@ -63,9 +57,10 @@ _DIM_SEMANTICS = (
 # different VMEM envelopes — the backward kernels keep ~4 (bq, bk) f32
 # score temporaries live vs the forward's 2 — so one (bq, bk) cannot
 # serve both.  Populated from benchmarks/flash_sweep.py runs on real
-# hardware (each entry's provenance is recorded in benchmarks/
-# RESULTS.md); consulted by the fwd/bwd entry points when the caller
-# passes no explicit blocks, before the _pick_block static heuristic.
+# hardware (benchmarks/install_tuned_blocks.py records the provenance
+# in a comment at the table's head); consulted by the fwd/bwd entry
+# points when the caller passes no explicit blocks, before the
+# _pick_block static heuristic.
 # Legacy 3-tuple (seq_q, head_dim, dtype) keys are read as fwd-only.
 _TUNED_BLOCKS: dict = {}
 
@@ -685,12 +680,9 @@ def flash_attention_pallas(q, k, v, causal=True, softmax_scale=None,
 
 
 def pallas_flash_available(q, k) -> bool:
-    """Kernel path: real TPU, lane-aligned sequence blocks, ≥8 head dim.
-    Disable with APEX_TPU_PALLAS_ATTN=0."""
+    """Kernel path: real TPU, lane-aligned sequence blocks, ≥8 head dim."""
     from apex_tpu.utils.platform import on_tpu
 
-    if os.environ.get("APEX_TPU_PALLAS_ATTN", "1") == "0":
-        return False
     return (
         on_tpu()
         and q.shape[2] % 128 == 0

@@ -3,9 +3,7 @@
 The standard GPT loss head materializes fp32 logits ``(S, B, V)`` twice
 — once forward (LM-head matmul output, read back by the CE) and once
 backward (``d_logits``).  At GPT-124M scale (S1024, B8, V50304) that is
-~3.3 GB of fp32 HBM traffic per step that does no model FLOPs, a prime
-suspect for the unattributed MFU gap (benchmarks/RESULTS.md, VERDICT r4
-item 3).
+~3.3 GB of fp32 HBM traffic per step that does no model FLOPs.
 
 This op computes the same per-token loss without ever materializing the
 full logits:
@@ -85,8 +83,7 @@ def _resolve_mode(impl) -> tuple:
     count as forced (fail-loudly, no registry fallback).  Threading the
     override as an argument is what lets callers A/B the two impls
     without mutating process-global state under an already-traced
-    function (the bench.py:876 class the static analyzer's APX102 rule
-    flags)."""
+    function (what the static analyzer's APX102 rule flags)."""
     if impl is None:
         return _pallas_mode()
     if impl not in ("on", "off", "interpret"):

@@ -50,7 +50,7 @@ def make_master(params: Tree, master_weights: bool) -> Optional[Tree]:
     ``astype`` on an already-fp32 leaf returns the same buffer, and a
     master that aliases its param makes ``donate_argnums`` over
     (params, state) donate one buffer twice — an Execute()-time crash
-    (caught by ``bench.py --smoke`` on the resnet amp-O2 step)."""
+    (first seen on the resnet amp-O2 step)."""
     if not master_weights:
         return None
     return jax.tree.map(lambda p: jnp.array(p, jnp.float32, copy=True),

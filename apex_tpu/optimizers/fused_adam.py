@@ -73,12 +73,6 @@ class FusedAdam(base.OptimizerBase):
 
     _BUCKET_SLOT = "exp_avg"
 
-    #: True restores the pre-fix engine emit (param bucket pack +
-    #: unpack) — kept ONLY so ``bench.py`` can time the BENCH_r05
-    #: 0.679× path against the pack-free emit in the same run (the
-    #: before/after drift evidence); never set in training code.
-    _pack_params_emit = False
-
     def __init__(
         self,
         lr: float = 1e-3,
@@ -162,16 +156,12 @@ class FusedAdam(base.OptimizerBase):
     # --------------------------------------------------------- bucket path
     def _bucket_update_packfree(self, prep: base.PreparedGrads,
                                 state: AdamState, params, pred, lr):
-        """The BENCH_r05 0.679× fix.  Profiling the resident-bucket
-        step against jitted optax ruled OUT the dispute's named
-        suspects — no per-leaf norm reconstruction runs in a plain Adam
-        step, the noop-flag OR only exists under a finite vote, and the
-        tail pad is <0.1% of the bucket — and pinned the gap on the
-        param round-trip: ``pack(params)`` concatenates every leaf into
-        a bucket XLA materializes, and ``unpack`` writes it all back —
-        two whole-model HBM passes per step the optax baseline never
-        pays.  With no fp32 master and decoupled decay (AdamW), the
-        bucket math only needs the GRADS in bucket form: m/v/core are
+        """The emit without a param bucket.  ``pack(params)``
+        concatenates every leaf into a bucket XLA materializes, and
+        ``unpack`` writes it all back — two whole-model HBM passes per
+        step that a whole-tree jitted optax update never pays.  With no
+        fp32 master and decoupled decay (AdamW), the bucket math only
+        needs the GRADS in bucket form: m/v/core are
         computed per bucket (:func:`adam_core`), then each param leaf
         is emitted directly from its static core slice — slice +
         elementwise fuse, and no param bucket exists in the HLO.
@@ -222,8 +212,7 @@ class FusedAdam(base.OptimizerBase):
 
     def _bucket_update(self, prep: base.PreparedGrads, state: AdamState,
                        params, pred, lr=None):
-        if (state.master is None and self.adam_w_mode
-                and not self._pack_params_emit):
+        if state.master is None and self.adam_w_mode:
             return self._bucket_update_packfree(prep, state, params, pred, lr)
         lr = self.lr if lr is None else lr
         wd = self.weight_decay

@@ -24,8 +24,7 @@ surface, so the production code path under test is the real one):
   :class:`~apex_tpu.resilience.preemption.PreemptionHandler` exactly as
   a real SIGTERM would.
 - **wedged/slow sections** — :meth:`ChaosMonkey.maybe_wedge` sleeps at
-  a named site, exercising watchdog/timeout paths (bench.py's `_try`,
-  the subprocess section runner).
+  a named site, exercising watchdog/timeout paths.
 
 Activate with ``with monkey.active(): ...`` — module-global so the
 registry and guards deep inside jitted-step construction see it without

@@ -56,7 +56,7 @@ class PreemptionHandler:
     ``signals``: which signals mean "preempted" (default SIGTERM — the
     Cloud TPU maintenance/reclaim notice).  The previous handler is
     chained, not clobbered, and restored on exit.  ``deadline_sec``:
-    treat the approach of a wall-clock budget (job schedulers, bench
+    treat the approach of a wall-clock budget (job schedulers,
     watchdogs) as a preemption ``grace_sec`` before it lands.
     """
 

@@ -1,6 +1,6 @@
 """The one persistent compilation cache.
 
-Every entry point (the trainer, the server, ``bench.py``,
+Every entry point (the trainer, the server, ``cellbench``,
 ``chip_smoke.py``, ``__graft_entry__`` and the test suite) calls
 :func:`enable_compile_cache` before its first compile, so a program
 compiled by one is found again by the next.

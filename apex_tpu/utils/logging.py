@@ -50,8 +50,7 @@ def log_structured(logger: logging.Logger, level: int, event: str,
 
     The resilience runtime (kernel fallback, step guard, preemption)
     reports through this so a wedged-run postmortem can grep one event
-    name and get every occurrence with its context as JSON — the same
-    greppability contract as bench.py's section sidecar.  When the loop
+    name and get every occurrence with its context as JSON.  When the loop
     set a step-correlation context
     (:func:`apex_tpu.observability.set_step_context`), every record
     additionally carries ``(run_id, step)`` so it joins against metrics

@@ -2,6 +2,15 @@
 MemoryBuffer, syncbn subgroup helper, pipeline next/prev rank, bottleneck
 blocks, Megatron-style arguments/global_vars, DistributedTestBase."""
 
+import ast
+import importlib
+import importlib.util
+import inspect
+import pathlib
+import re
+import sys
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -609,3 +618,125 @@ class TestSplitRankMachinery:
                    parallel_state.new_nccl_socket_group):
             with pytest.raises(RuntimeError, match="mesh axes"):
                 fn([0, 1])
+
+
+# ------------------------------------------- the scripts outside the package
+# Every runnable script beside the package (examples, benchmarks, the chip
+# smoke) must load and name only what apex_tpu still exports: most of their
+# imports are function-local, so loading alone would not see a dangling one.
+_REPO = pathlib.Path(__file__).resolve().parents[1]
+_SCRIPTS = sorted(
+    str(p.relative_to(_REPO))
+    for pat in ("*.py", "examples/*/*.py", "benchmarks/*.py")
+    for p in _REPO.glob(pat)
+    if 'if __name__ == "__main__":' in p.read_text())
+
+
+_GONE = object()
+
+
+def _package_name(module: str, name: str):
+    """What ``from module import name`` would bind — an attribute or a
+    submodule — or ``_GONE``."""
+    mod = importlib.import_module(module)
+    if hasattr(mod, name):
+        return getattr(mod, name)
+    try:
+        return importlib.import_module(f"{module}.{name}")
+    except ImportError:
+        return _GONE
+
+
+def _dangling_package_names(tree: ast.AST) -> list:
+    """``from apex_tpu.x import y`` anywhere in the file whose ``y`` is
+    gone, and ``alias.attr`` on a module imported from the package whose
+    ``attr`` is gone."""
+    missing, aliases = [], {}
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and node.level == 0 \
+                and (node.module or "").split(".")[0] == "apex_tpu":
+            for a in node.names:
+                obj = _package_name(node.module, a.name)
+                if obj is _GONE:
+                    missing.append(f"{node.module}.{a.name}")
+                elif isinstance(obj, types.ModuleType):
+                    aliases[a.asname or a.name] = obj.__name__
+        elif isinstance(node, ast.Import):
+            for a in node.names:
+                if a.name.split(".")[0] == "apex_tpu":
+                    importlib.import_module(a.name)
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Attribute) \
+                and isinstance(node.value, ast.Name) \
+                and node.value.id in aliases \
+                and _package_name(aliases[node.value.id],
+                                  node.attr) is _GONE:
+            missing.append(f"{aliases[node.value.id]}.{node.attr}")
+    return missing
+
+
+@pytest.mark.parametrize("script", _SCRIPTS)
+def test_script_loads_and_names_only_what_the_package_exports(
+        script, monkeypatch, capsys):
+    path = _REPO / script
+    assert not _dangling_package_names(ast.parse(path.read_text()))
+
+    spec = importlib.util.spec_from_file_location(
+        "_script_" + path.stem, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)  # top-level imports; no __main__ block
+
+    # the argument parser builds: --help exits 0 before any work
+    monkeypatch.setattr(sys, "argv", [str(path), "--help"])
+    build = getattr(mod, "parse_args", None) or getattr(mod, "build_args",
+                                                         None)
+    if build is None and "ArgumentParser" in inspect.getsource(mod.main):
+        build = mod.main
+    if build is None:
+        return  # takes no arguments (chip_smoke.py, asp_permutation.py)
+    with pytest.raises(SystemExit) as done:
+        parser = build()
+        parser.parse_args()  # build_args() hands the parser back
+    assert done.value.code == 0
+    assert "usage:" in capsys.readouterr().out
+
+
+# ------------------------------------ the documents point at what exists
+_DOCUMENTS = ["README.md", "Makefile"] + sorted(
+    str(p.relative_to(_REPO)) for p in (_REPO / "docs").glob("*.md")
+    if p.name != "api.md")  # generated from the package by docs/gen_api.py
+#: a repo path is a token under one of these directories ...
+_REPO_PATH = re.compile(
+    r"(?<![\w/.\-~])(?:apex_tpu|tests|examples|benchmarks|cellbench|docs)/"
+    r"[^\s`'\"()\[\]|,;]*")
+#: ... or the script of a `python <script>.py ...` command line
+_PY_OPERAND = re.compile(
+    r"(?:python3?|\$\(PYTHON\))\s+(?:-[^m\s]\S*\s+)*([\w./\-]+\.py)\b")
+#: `make <target>` in inline code, or leading a (comment) line
+_MAKE_TARGET = re.compile(r"(?:`|^[ \t#]*)make\s+([a-z][\w\-]*)", re.M)
+
+
+def _path_resolves(token: str) -> bool:
+    """``dir/file.py``, ``dir/``, ``file.py:12``, ``file.py::test`` and
+    ``dir/module.symbol`` (through ``dir/module.py``, which must name the
+    symbol); ``*`` globs must match something."""
+    token = token.rstrip(".:")
+    path = re.split(r"::|:\d", token)[0]
+    if "*" in path:
+        return any(_REPO.glob(path))
+    if (_REPO / path).exists():
+        return True
+    module, _, symbol = path.rpartition(".")
+    source = _REPO / (module + ".py")
+    return bool(module) and source.is_file() \
+        and re.search(rf"\b{re.escape(symbol)}\b", source.read_text())
+
+
+@pytest.mark.parametrize("document", _DOCUMENTS)
+def test_document_points_at_files_and_targets_that_exist(document):
+    text = (_REPO / document).read_text()
+    paths = set(_REPO_PATH.findall(text)) | set(_PY_OPERAND.findall(text))
+    assert not sorted(p for p in paths if not _path_resolves(p))
+    targets = set(re.findall(r"^([a-z][\w\-]*):", (_REPO / "Makefile")
+                             .read_text(), re.M))
+    assert not sorted(set(_MAKE_TARGET.findall(text)) - targets)

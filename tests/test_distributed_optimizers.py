@@ -1762,3 +1762,86 @@ class TestOverlappedGradSync:
         with pytest.raises(ValueError, match="per-bucket dp grad sync"):
             make_train_step(cfg, FusedAdam(lr=1e-3), mesh,
                             overlap_grad_sync=True)
+
+
+# ------------------------------------------- static wire accounting
+def test_zero_wire_bytes_accounting_ratios():
+    """``wire_bytes_per_step`` validated at the accounting level (pure
+    plan arithmetic, no step compile) — EXACT ratios, scale-vector bytes included per hop
+    (never the old payload approximation): an int8 wire carries
+    ``1 + 4/QBLOCK`` bytes per element (payload + its share of the
+    fp32 per-block scale psum), so the cut vs the 2-byte bf16 default
+    is exactly ``2 / (1 + 4/1024) = 512/257``, and vs a 4-byte fp32
+    wire exactly ``1024/257``."""
+    from fractions import Fraction
+
+    import jax.numpy as jnp
+
+    from apex_tpu.contrib.optimizers import DistributedFusedAdam
+    from apex_tpu.contrib.optimizers._quantized_sync import QBLOCK
+
+    params = {"w": jnp.zeros((512, 256), jnp.bfloat16),
+              "b": jnp.zeros((8192,), jnp.bfloat16)}
+
+    def wire(**kw):
+        opt = DistributedFusedAdam(lr=1e-3, **kw)
+        opt.init(params, world_size=4)
+        return opt.wire_bytes_per_step()
+
+    bf16 = wire()                                  # default: storage dtype
+    i8 = wire(grad_sync_dtype="int8")
+    f8 = wire(grad_sync_dtype=jnp.float8_e5m2)
+    f32 = wire(grad_sync_dtype=jnp.float32)
+    assert i8["grad_scales"] > 0 and bf16["grad_scales"] == 0
+    # i8 bytes/element = 1 payload + 4/QBLOCK scales — exact, no
+    # rounding: bucket totals are QBLOCK multiples by construction
+    assert i8["grad_scales"] * QBLOCK == i8["grad_payload"] * 4
+    per_elt_i8 = Fraction(QBLOCK + 4, QBLOCK)
+    assert Fraction(bf16["grad_sync"], i8["grad_sync"]) \
+        == Fraction(2, 1) / per_elt_i8             # = 512/257
+    assert Fraction(f32["grad_sync"], i8["grad_sync"]) \
+        == Fraction(4, 1) / per_elt_i8             # = 1024/257
+    assert f8["grad_sync"] == i8["grad_sync"]      # both 1-byte wires
+    # param gather is never quantized (no error-feedback channel)
+    assert i8["param_sync"] == bf16["param_sync"]
+    # the flat plan reports its one hop under the dp axis, and the
+    # top-level fields are exactly that hop
+    assert set(i8["hops"]) == {"dp"}
+    assert i8["hops"]["dp"]["grad_sync"] == i8["grad_sync"]
+
+
+def test_hierarchical_wire_bytes_cross_slice_cut_exact():
+    """The ``hier_*_sync`` modes' per-hop accounting: the slow (outer)
+    hop's bytes — payload AND scales — are exactly ``1/dp_in`` of the
+    flat plan's at the same wire dtype (the cross-slice cut); the fast
+    (inner) hop carries the full bucket like the flat plan."""
+    import jax.numpy as jnp
+
+    from apex_tpu.contrib.optimizers import DistributedFusedAdam
+
+    params = {"w": jnp.zeros((512, 256), jnp.bfloat16),
+              "b": jnp.zeros((8192,), jnp.bfloat16)}
+
+    def wire(**kw):
+        sizes = kw.pop("axis_sizes", None)
+        opt = DistributedFusedAdam(lr=1e-3, **kw)
+        opt.init(params, world_size=4, axis_sizes=sizes)
+        return opt.wire_bytes_per_step()
+
+    flat = wire(grad_sync_dtype="int8")
+    hier = wire(grad_sync_dtype="int8", dp_axes=("dp_out", "dp_in"),
+                axis_sizes={"dp_out": 2, "dp_in": 2})
+    inner, outer = hier["hops"]["dp_in"], hier["hops"]["dp_out"]
+    # fast hop == the flat wire (full bucket, same dtype, same scales)
+    assert inner["grad_sync"] == flat["grad_sync"]
+    assert inner["param_sync"] == flat["param_sync"]
+    # slow hop: exactly 1/dp_in of the flat plan, scales included
+    assert outer["grad_payload"] * 2 == flat["grad_payload"]
+    assert outer["grad_scales"] * 2 == flat["grad_scales"]
+    assert outer["grad_sync"] * 2 == flat["grad_sync"]
+    assert outer["param_sync"] * 2 == flat["param_sync"]
+    # top-level fields sum the hops (total wire traffic of the step)
+    assert hier["grad_sync"] == inner["grad_sync"] + outer["grad_sync"]
+    # both hops stay at the compressed dtype: equal bytes/element
+    # implies the slow hop never widened (3/2 = full + half buckets)
+    assert hier["grad_payload"] * 2 == flat["grad_payload"] * 3

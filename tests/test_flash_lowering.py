@@ -4,7 +4,13 @@
 lowering without a device.  The per-shape tuned-block table
 (``flash_attention_pallas._TUNED_BLOCKS``) is installed from sweep
 output by ``benchmarks/install_tuned_blocks.py`` — a bad entry must
-fail HERE, not inside an audited bench section on the chip."""
+fail HERE, not on the chip.  The sweep and the installer are smoke-
+tested below on the CPU (interpret mode, a scratch copy of the kernel)."""
+
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -96,3 +102,133 @@ def test_tuned_blocks_lower_for_tpu():
             _lower(lambda q, k, v, o, lse, do: fap.flash_bwd_pallas(
                 q, k, v, o, lse, do, 1.0 / D ** 0.5, True, 0, 0,
                 block_q=bq, block_k=bk, heads=4), q, q, q, q, r, q)
+
+
+# ------------------------------------------- flash sweep + installer
+_BENCHMARKS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+FLASH_SWEEP = os.path.join(_BENCHMARKS, "flash_sweep.py")
+INSTALL = os.path.join(_BENCHMARKS, "install_tuned_blocks.py")
+
+
+def test_flash_sweep_quick_interpret_smoke(tmp_path):
+    """``flash_sweep.py --quick --interpret`` is the CPU smoke contract:
+    tiny shapes through the Pallas interpreter, one JSON line per
+    config, and a final per-(shape, phase) ``tuned_blocks_table`` line
+    with BOTH phases that ``set_tuned_blocks`` ingests directly."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, FLASH_SWEEP, "--quick", "--interpret"],
+        capture_output=True, text=True, timeout=540, env=env,
+    )
+    assert proc.returncode == 0, (proc.stderr or "")[-2000:]
+    table = None
+    for line in (proc.stdout or "").splitlines():
+        try:
+            rec = json.loads(line)
+        except ValueError:
+            continue
+        if isinstance(rec, dict) and "tuned_blocks_table" in rec:
+            table = rec["tuned_blocks_table"]
+    assert table, "no tuned_blocks_table line on stdout"
+    phases = {tuple(key)[3] for key, _ in table}
+    assert phases == {"fwd", "bwd"}, table
+    # the printed pairs install directly, per phase
+    from apex_tpu.ops import flash_attention_pallas as fap
+
+    saved = dict(fap._TUNED_BLOCKS)
+    try:
+        fap._TUNED_BLOCKS.clear()
+        fap.set_tuned_blocks(table)
+        for key, val in table:
+            s, d, dtype, phase = key
+            assert fap.tuned_blocks(s, d, dtype, phase=phase) == tuple(val)
+    finally:
+        fap._TUNED_BLOCKS.clear()
+        fap._TUNED_BLOCKS.update(saved)
+
+
+def _run_installer(kernel_path, sweep_path, monkeypatch):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("install_tuned_blocks",
+                                                  INSTALL)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    from pathlib import Path
+
+    mod.KERNEL = Path(kernel_path)
+    monkeypatch.setattr(sys, "argv",
+                        ["install_tuned_blocks.py", str(sweep_path),
+                         "--provenance", "cpu-test 2026-08-07"])
+    mod.main()
+
+
+def test_install_tuned_blocks_round_trip(tmp_path, monkeypatch):
+    """Installer contract: per-phase sweep keys land as 4-tuple entries,
+    an old 3-tuple entry already in the source literal migrates to
+    ``"fwd"`` (pre-split sweeps measured the forward path), and a
+    second run with the same sweep output is BYTE-IDENTICAL
+    (idempotent — re-running never churns the kernel source)."""
+    import ast
+    import re
+
+    kernel = tmp_path / "kernel_stub.py"
+    kernel.write_text(
+        "# stub kernel module for the installer test\n"
+        "_TUNED_BLOCKS: dict = {\n"
+        "    (1024, 64, 'bfloat16'): (512, 256),\n"
+        "}\n"
+        "OTHER = 1\n")
+    sweep = tmp_path / "sweep.jsonl"
+    sweep.write_text(
+        json.dumps({"roofline_tflops": 1.0}) + "\n" + json.dumps(
+            {"tuned_blocks_table": [
+                [[256, 64, "bfloat16", "fwd"], [128, 128]],
+                [[256, 64, "bfloat16", "bwd"], [64, 64]],
+                [[512, 64, "bfloat16"], [256, 256]],  # old flat key
+            ]}) + "\n")
+    _run_installer(kernel, sweep, monkeypatch)
+    first = kernel.read_text()
+    m = re.search(r"_TUNED_BLOCKS: dict = \{(.*?)\}", first, re.S)
+    body = "\n".join(ln for ln in m.group(1).splitlines()
+                     if not ln.strip().startswith("#"))
+    entries = ast.literal_eval("{" + body + "}")
+    assert entries == {
+        (256, 64, "bfloat16", "fwd"): (128, 128),
+        (256, 64, "bfloat16", "bwd"): (64, 64),
+        (512, 64, "bfloat16", "fwd"): (256, 256),
+        # the pre-existing flat entry migrated, not dropped
+        (1024, 64, "bfloat16", "fwd"): (512, 256),
+    }
+    assert "OTHER = 1" in first  # the rest of the module is untouched
+    # the installed table round-trips through the runtime setter
+    from apex_tpu.ops import flash_attention_pallas as fap
+
+    saved = dict(fap._TUNED_BLOCKS)
+    try:
+        fap._TUNED_BLOCKS.clear()
+        fap.set_tuned_blocks(entries)
+        import jax.numpy as jnp
+
+        assert fap.tuned_blocks(256, 64, jnp.bfloat16, phase="bwd") == (64, 64)
+        assert fap.tuned_blocks(1024, 64, jnp.bfloat16) == (512, 256)
+    finally:
+        fap._TUNED_BLOCKS.clear()
+        fap._TUNED_BLOCKS.update(saved)
+    # idempotency: same sweep output -> byte-identical file
+    _run_installer(kernel, sweep, monkeypatch)
+    assert kernel.read_text() == first
+
+
+def test_install_tuned_blocks_rejects_bad_phase(tmp_path, monkeypatch):
+    kernel = tmp_path / "kernel_stub.py"
+    kernel.write_text("_TUNED_BLOCKS: dict = {}\n")
+    sweep = tmp_path / "sweep.jsonl"
+    sweep.write_text(json.dumps(
+        {"tuned_blocks_table": [[[256, 64, "bfloat16", "backward"],
+                                 [128, 128]]]}) + "\n")
+    import pytest
+
+    with pytest.raises(SystemExit, match="phase"):
+        _run_installer(kernel, sweep, monkeypatch)

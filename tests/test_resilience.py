@@ -314,13 +314,13 @@ class TestChaosMonkey:
     def test_wedge_sleeps_and_counts(self):
         import time
 
-        m = ChaosMonkey(ChaosPlan.make(wedge_seconds={"bench.x": 0.05}))
+        m = ChaosMonkey(ChaosPlan.make(wedge_seconds={"site.x": 0.05}))
         with m.active():
             t0 = time.monotonic()
-            assert m.maybe_wedge("bench.x") == 0.05
+            assert m.maybe_wedge("site.x") == 0.05
             assert time.monotonic() - t0 >= 0.05
-            assert m.maybe_wedge("bench.y") == 0.0
-        assert m.injected["wedge:bench.x"] == 1
+            assert m.maybe_wedge("site.y") == 0.0
+        assert m.injected["wedge:site.x"] == 1
 
     def test_preemption_delivered_at_planned_step(self):
         m = ChaosMonkey(ChaosPlan.make(preempt_at_step=3))
@@ -488,46 +488,6 @@ class TestPreemptionHandler:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         assert fresh.counts_ == tracker.counts_ == {
             "model-parallel-rng": 2}
-
-
-# ------------------------------------------------- bench.py fault paths
-class TestBenchHarness:
-    """The wedge/timeout and failure seams of bench.py's section
-    runner, driven by chaos."""
-
-    @pytest.fixture(autouse=True)
-    def _bench(self, tmp_path, monkeypatch):
-        import bench
-
-        monkeypatch.setattr(bench, "_SECTIONS_PATH",
-                            str(tmp_path / "sections.jsonl"))
-        monkeypatch.setattr(bench, "_DEVICE_WEDGED", False)
-        import time
-
-        monkeypatch.setattr(bench, "_DEADLINE", time.monotonic() + 120)
-        self.bench = bench
-
-    def test_try_watchdog_catches_injected_wedge(self):
-        m = ChaosMonkey(ChaosPlan.make(wedge_seconds={"bench.stuck": 5.0}))
-        with m.active():
-            r = self.bench._try("stuck", lambda: {"v": 1},
-                                section_budget=0.2)
-        assert "timeout" in r["error"]
-        assert self.bench._DEVICE_WEDGED  # in-process: thread unkillable
-
-    def test_failed_section_is_recorded_and_counted(self, monkeypatch):
-        """A section that raises is recorded as an error AND lands in
-        ``_FAILED`` — what makes bench.py exit non-zero instead of
-        printing a clean-looking JSON around the hole."""
-        monkeypatch.setattr(self.bench, "_FAILED", [])
-
-        def boom():
-            raise ValueError("no such kernel")
-
-        r = self.bench._try("broken", boom, section_budget=5.0)
-        assert r == {"error": "ValueError: no such kernel"}
-        assert self.bench._try("fine", lambda: {"v": 1}) == {"v": 1}
-        assert self.bench._FAILED == ["broken"]
 
 
 # --------------------------------------------------- end-to-end survival

@@ -6,7 +6,7 @@ APX102/302/401 are the literal pre-fix ADVICE r5 snippets from
 bench.py:876, ops/fused_ce_pallas.py:58, and models/gpt.py:447 — the
 findings this subsystem exists to scale), engine unit tests (traced
 index, axis-registry discovery, baseline), and the repo-wide clean
-check ``python -m apex_tpu.analysis apex_tpu bench.py`` rides on.
+check ``python -m apex_tpu.analysis apex_tpu examples`` rides on.
 """
 
 import json
@@ -269,7 +269,7 @@ class TestDonatedBufferReuse:
 
     def test_positive_partial_decorator_spelling(self, tmp_path):
         """@partial(jax.jit, donate_argnums=...) defs are tracked by
-        their function name (the bench.py step idiom)."""
+        their function name (a benchmark harness's step idiom)."""
         got = run("""
             from functools import partial
 
@@ -3066,7 +3066,7 @@ class TestUnseamedDispatchTiming:
 
     def test_negative_host_read_and_local_seam_wrapper(self, tmp_path):
         """float(loss) is a sync; so is calling a local def that wraps
-        block_until_ready (the bench.py `block(tree)` idiom)."""
+        block_until_ready (a harness's `block(tree)` idiom)."""
         got = run("""
             import time
             import jax
@@ -3137,8 +3137,7 @@ class TestRepoIsClean:
     committed baseline, and every baseline entry still bites."""
 
     def _repo_findings(self):
-        paths = [str(REPO / "apex_tpu"), str(REPO / "bench.py"),
-                 str(REPO / "examples")]
+        paths = [str(REPO / "apex_tpu"), str(REPO / "examples")]
         return analyze_paths(paths, DEFAULT_RULES, rel_to=str(REPO))
 
     def test_repo_clean_modulo_baseline(self):
@@ -3151,21 +3150,22 @@ class TestRepoIsClean:
 
     def test_advice_r5_fixes_are_in_the_tree(self):
         """The three ADVICE r5 findings must stay FIXED (their pre-fix
-        shapes are pinned by the fixture tests above): no APX102 left in
-        bench.py, no APX302 in the Pallas ops, no APX401 in gpt.py."""
+        shapes are pinned by the fixture tests above): no APX102 anywhere
+        in the scanned tree (the file that held it is gone), no APX302
+        in the Pallas ops, no APX401 in gpt.py."""
         by_rule = {}
         for f in self._repo_findings():
             by_rule.setdefault(f.rule, []).append(f.path)
-        assert "bench.py" not in by_rule.get("APX102", [])
+        assert not by_rule.get("APX102")
         assert not [p for p in by_rule.get("APX302", [])
                     if p.startswith("apex_tpu/ops/")]
         assert "apex_tpu/models/gpt.py" not in by_rule.get("APX401", [])
 
     def test_cli_acceptance_command(self):
-        """`python -m apex_tpu.analysis apex_tpu bench.py` exits 0."""
+        """`python -m apex_tpu.analysis apex_tpu examples` exits 0."""
         r = subprocess.run(
             [sys.executable, "-m", "apex_tpu.analysis",
-             "apex_tpu", "bench.py"],
+             "apex_tpu", "examples"],
             cwd=str(REPO), capture_output=True, text=True, timeout=600)
         assert r.returncode == 0, r.stdout + r.stderr
 
@@ -3178,7 +3178,7 @@ class TestRepoIsClean:
         env = dict(os.environ, PYTHONPATH=str(REPO))
         r = subprocess.run(
             [sys.executable, "-m", "apex_tpu.analysis",
-             str(REPO / "apex_tpu"), str(REPO / "bench.py")],
+             str(REPO / "apex_tpu"), str(REPO / "examples")],
             cwd=str(tmp_path), env=env, capture_output=True, text=True,
             timeout=600)
         assert r.returncode == 0, r.stdout + r.stderr
@@ -3249,7 +3249,7 @@ class TestCliPerformanceAndHygiene:
         algorithmic, not scheduling."""
         import time
 
-        paths = [str(REPO / "apex_tpu"), str(REPO / "bench.py")]
+        paths = [str(REPO / "apex_tpu"), str(REPO / "examples")]
         t0 = time.process_time()
         analyze_paths(paths, DEFAULT_RULES, rel_to=str(REPO))
         dt = time.process_time() - t0
@@ -3259,7 +3259,8 @@ class TestCliPerformanceAndHygiene:
         """--jobs may change wall time, never findings: the parallel
         parse/index pass over a real subtree must produce byte-equal
         findings to the serial one."""
-        paths = [str(REPO / "apex_tpu" / "ops"), str(REPO / "bench.py")]
+        paths = [str(REPO / "apex_tpu" / "ops"),
+                 str(REPO / "chip_smoke.py")]
         serial = analyze_paths(paths, DEFAULT_RULES, rel_to=str(REPO))
         parallel = analyze_paths(paths, DEFAULT_RULES, rel_to=str(REPO),
                                  jobs=2)
@@ -3328,7 +3329,7 @@ class TestCliPerformanceAndHygiene:
         """The repo-level --check-baseline run the CI target uses."""
         r = subprocess.run(
             [sys.executable, "-m", "apex_tpu.analysis", "apex_tpu",
-             "bench.py", "--check-baseline"],
+             "examples", "--check-baseline"],
             cwd=str(REPO), capture_output=True, text=True, timeout=600)
         assert r.returncode == 0, r.stdout + r.stderr
 
