@@ -3,7 +3,8 @@
 The steps are built for a **served model**: any object with
 ``cache_spec()`` (pool name -> ``(layers, heads, dim)``), ``prefill``,
 ``decode``, ``head(params)`` (the (V, H) matrix the sampling head
-multiplies), ``multi_position``, ``counter_names`` and
+multiplies), ``serving_params(params)`` (the tree the steps should be
+given: :func:`cast_once`), ``multi_position``, ``counter_names`` and
 ``max_positions`` — or a model configuration whose ``served_model()``
 gives one (:func:`served`).  ``models.gpt.GPTServed`` is the first,
 ``models.mla_moe.MLAMoEServed`` (a latent cache, held experts) the
@@ -42,6 +43,7 @@ position's hidden state.
 """
 
 import dataclasses
+from functools import partial
 from typing import Any, Optional, Tuple
 
 import jax
@@ -50,12 +52,13 @@ import jax.numpy as jnp
 from apex_tpu.inference.kv_cache import (
     KVCacheConfig, alloc_named_pools, write_prompt_pools,
 )
+from apex_tpu.observability import tracing as _tracing
 from apex_tpu.ops.decode_sampling_pallas import fused_sample
 
 __all__ = [
-    "DecodeConfig", "decode_logits_tokenwise", "make_decode_step",
-    "make_prefill", "make_prefill_chunk", "make_sample_head",
-    "make_verify_step", "served",
+    "DecodeConfig", "cast_once", "decode_logits_tokenwise",
+    "make_decode_step", "make_prefill", "make_prefill_chunk",
+    "make_sample_head", "make_verify_step", "served",
 ]
 
 
@@ -63,6 +66,51 @@ def served(model):
     """The served model: ``model`` itself, or — given a model
     configuration — what its ``served_model()`` builds."""
     return model.served_model() if hasattr(model, "served_model") else model
+
+
+@partial(jax.jit, static_argnames="dtype")
+def _cast_leaves(leaves, dtype):
+    return [x.astype(dtype) for x in leaves]
+
+
+def cast_once(params, names, dtype):
+    """``params`` (nested dicts) with every leaf whose own key is in
+    ``names`` cast to ``dtype`` — what a served model's
+    ``serving_params`` returns.
+
+    A family names the leaves that EVERY one of its served programs
+    reads only as ``leaf.astype(compute_dtype)``: handed this tree,
+    those casts are no-ops and the programs multiply the very bits they
+    rounded to before, but the rounding is done once and not in every
+    decode step and every prefill (XLA hoists a stacked matrix's cast
+    out of the layer scan and runs it once a PROGRAM: 61% of the device
+    time of GPT-2 large's serving cells; PERF.md, PR 29).
+
+    All casts are ONE jitted program, finished on return.  A picked
+    leaf that already has ``dtype`` is left alone, and with none to
+    cast ``params`` itself comes back and nothing is launched: a tree
+    prepared before, a checkpoint in the compute dtype and
+    ``compute_dtype=float32`` pass through.  The span
+    ``serve.prepare_params`` says what happened: ``cast_leaves``,
+    ``cast_bytes`` (read, in the leaves' old dtype) and ``kept_bytes``
+    (every other leaf)."""
+    dtype = jnp.dtype(dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [leaf for _, leaf in flat]
+    todo = [i for i, (path, leaf) in enumerate(flat)
+            if path[-1].key in names and leaf.dtype != dtype]
+    nbytes = [leaf.size * leaf.dtype.itemsize for leaf in leaves]
+    cast_bytes = sum(nbytes[i] for i in todo)
+    with _tracing.span("serve.prepare_params", cast_leaves=len(todo),
+                       cast_bytes=cast_bytes,
+                       kept_bytes=sum(nbytes) - cast_bytes):
+        if not todo:
+            return params
+        cast = jax.block_until_ready(
+            _cast_leaves([leaves[i] for i in todo], dtype))
+    for i, x in zip(todo, cast):
+        leaves[i] = x
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -219,8 +219,11 @@ class ContinuousBatchingScheduler:
     def __init__(self, params, model, dcfg: DecodeConfig,
                  time_fn=time.monotonic, watchdog=None, anomaly=None):
         """``model``: the served model (docs/inference.md: cache spec,
-        prefill, decode forward, head matrix), or a model configuration
-        whose ``served_model()`` builds it."""
+        prefill, decode forward, head matrix, serving tree), or a model
+        configuration whose ``served_model()`` builds it.  ``params``:
+        the model's parameters, or what its ``serving_params`` made of
+        them before (several schedulers then share one prepared
+        tree)."""
         cache = dcfg.cache
         self.model = served(model)
         config = self.model.config
@@ -235,7 +238,10 @@ class ContinuousBatchingScheduler:
             raise ValueError(
                 f"max_prompt_len ({dcfg.max_prompt_len}) exceeds the "
                 f"learned position table ({limit})")
-        self.params = params
+        # the model's tree for its compiled steps (matrices cast to
+        # the compute dtype ONCE, here); the tree given is not kept, so
+        # a caller that drops it frees what the cast replaced
+        self.params = self.model.serving_params(params)
         self.config = config
         self.dcfg = dcfg
         self._time = time_fn

@@ -777,12 +777,23 @@ def forward_decode(params, tokens, positions, active, kv_pools, page_tables,
 class GPTServed:
     """What :mod:`apex_tpu.inference` needs of this family (the
     served-model interface, docs/inference.md): the cache spec, the
-    prefill, the decode forward and the head matrix."""
+    prefill, the decode forward, the head matrix and the tree to serve
+    from."""
 
     #: the decode forward takes ``verify_width`` > 1 (speculative
     #: verify, prefill chunks)
     multi_position = True
     counter_names = ()
+    #: the leaves of ``params["layers"]`` that :func:`gpt_forward` and
+    #: :func:`forward_decode` (dense: serving is tp = 1) read ONLY as
+    #: ``leaf.astype(compute_dtype)``, and that are worth a program:
+    #: the six stacked matrices (``_col_proj``, ``_attention``,
+    #: ``_mlp``).  Not ``embed`` (the lookup adds ``pos_embed`` in the
+    #: parameters' dtype before the cast, and the head reads it as it
+    #: is), not ``pos_embed``, not a LayerNorm gain or bias (the norm
+    #: reads them as they are); the six projection biases are read the
+    #: same way but are a thousandth of the bytes
+    cast_once_leaves = ("wq", "wk", "wv", "wo", "fc1", "fc2")
 
     def __init__(self, config: GPTConfig):
         if config.moe:
@@ -804,6 +815,15 @@ class GPTServed:
 
     def head(self, params):
         return params["embed"]
+
+    def serving_params(self, params):
+        """The tree to give the served programs: :attr:`cast_once_leaves`
+        in ``compute_dtype``, every other leaf the array it was
+        (:func:`apex_tpu.inference.decode.cast_once`)."""
+        from apex_tpu.inference.decode import cast_once
+
+        return cast_once(params, self.cast_once_leaves,
+                         self.config.compute_dtype)
 
     def prefill(self, params, prompt, prompt_len, attn_impl):
         """(1, S) padded prompt -> hidden (S, 1, H) and the post-RoPE

@@ -35,7 +35,8 @@ and the layer index rides to the kernels as a scalar, as in
 ``gpt.forward_decode``.
 
 Parameters are born in ``param_dtype`` (bf16 for serving: nothing is
-cast per step); matrices are stored ``(in, out)``.  Tensor-parallel
+cast per step; from another dtype, ``MLAMoEServed.serving_params``
+casts the matrices once); matrices are stored ``(in, out)``.  Tensor-parallel
 and training variants do not exist yet (ROADMAP, Queue 2); the
 multi-token-prediction module of the published checkpoints is not
 part of the model's logits and is not built.
@@ -522,6 +523,16 @@ class MLAMoEServed:
     counter_names = COUNTER_NAMES
     #: rotary positions: no learned table bounds a request
     max_positions = None
+    #: the leaves of both stacks that :func:`forward` and
+    #: :func:`forward_decode` read ONLY as ``leaf.astype(compute_dtype)``
+    #: (``mla_project``, the two attention cores, ``_block``,
+    #: ``_gated_ffn``): the attention projections, the dense FFN and the
+    #: shared expert.  Not the router and its bias (float32), not a norm
+    #: gain (float32), not the held experts' ``we_*`` (the grouped
+    #: matmul reads them as they are stored), not ``embed``/``head``
+    cast_once_leaves = ("wq_a", "wq_b", "wkv_a", "wkv_b_k", "wkv_b_v", "wo",
+                        "w_gate", "w_up", "w_down",
+                        "ws_gate", "ws_up", "ws_down")
 
     def __init__(self, config: MLAMoEConfig):
         self.config = config
@@ -532,6 +543,16 @@ class MLAMoEServed:
 
     def head(self, params):
         return params["head"]
+
+    def serving_params(self, params):
+        """The tree to give the served programs: :attr:`cast_once_leaves`
+        in ``compute_dtype``, every other leaf the array it was
+        (:func:`apex_tpu.inference.decode.cast_once`).  Parameters born
+        in the compute dtype (bf16 serving) pass through."""
+        from apex_tpu.inference.decode import cast_once
+
+        return cast_once(params, self.cast_once_leaves,
+                         self.config.compute_dtype)
 
     def prefill(self, params, prompt, prompt_len, attn_impl):
         """(1, S) padded prompt -> final-normed hidden (S, 1, H) and the

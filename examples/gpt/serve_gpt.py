@@ -308,9 +308,15 @@ def build_scheduler(args, watchdog=None, anomaly=None):
     family (:func:`build_model`) behind the one
     ``ContinuousBatchingScheduler``, which asks the model's config for
     its served-model adapter (cache spec, prefill, decode forward, head
-    matrix: docs/inference.md).  Returns ``(scheduler, params,
-    config)``.  ``chip_smoke.py`` drives its own request mixes through
-    the schedulers this builds."""
+    matrix, serving tree: docs/inference.md).  Returns ``(scheduler,
+    params, config)``.  ``params`` is the tree AS BUILT (fp32 for GPT);
+    the scheduler holds its own ``serving_params`` of it — the
+    projection matrices cast once to the compute dtype — and no
+    reference to this one, so the caller of this function is who still
+    holds the fp32 matrices: a server that wants their memory back
+    drops ``params`` (``main`` keeps it for ``--smoke``'s parity
+    check).  ``chip_smoke.py`` drives its own request mixes through the
+    schedulers this builds."""
     total_prompt = args.system_prompt_len + args.prompt_len
     config, params = build_model(
         args, max(total_prompt + args.max_new + args.draft_len + 1, 64))
@@ -420,6 +426,10 @@ def main(argv=None):
 
     sched, params, config = build_scheduler(args, watchdog=watchdog,
                                             anomaly=anomaly)
+    if not args.smoke:
+        # only the smoke's parity check reads the tree as built; the
+        # scheduler serves from its own (build_scheduler)
+        params = None
     reqs, arrivals = make_requests(args, np.random.RandomState(args.seed))
 
     t0 = time.monotonic()

@@ -656,6 +656,190 @@ class TestPoolInPlace:
             decode_attention_xla(kn, kp[None], kp[None], pt, pos + 1)
 
 
+# ------------------------------------------- the tree the scheduler holds
+def _family(name):
+    """``(config, fp32 parameters, pools of the decode step)`` of a
+    served family at a tiny size, bf16 compute."""
+    if name == "latent":
+        from apex_tpu.models import mla_moe
+
+        cfg = mla_moe.MLAMoEConfig(
+            vocab_size=61, hidden_size=32, num_dense_layers=1,
+            num_moe_layers=2, num_attention_heads=2, q_lora_rank=12,
+            kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=8,
+            v_head_dim=16, intermediate_size=48, moe_intermediate_size=16,
+            n_routed_experts=8, held_start=2, held_count=4, n_group=2,
+            topk_group=1, num_experts_per_tok=2,
+            rope_original_max_position=16, param_dtype=jnp.float32,
+            compute_dtype=jnp.bfloat16)
+        return cfg, mla_moe.init_params(cfg, jax.random.PRNGKey(4))
+    cfg = tiny_cfg(compute_dtype=jnp.bfloat16, **{
+        "gpt-learned": dict(position_embedding_type="learned"),
+        "gpt-rope-gqa": dict(num_query_groups=2)}[name])
+    return cfg, init_params(cfg, jax.random.PRNGKey(4))
+
+
+def _paths(tree):
+    return {tuple(k.key for k in path): leaf for path, leaf
+            in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+#: per family: how many leaves the rule names, and leaves it must not
+_RULE = {
+    "gpt-learned": (6, [("embed",), ("pos_embed",), ("final_ln_scale",),
+                        ("layers", "ln1_scale"), ("layers", "ln1_bias"),
+                        ("layers", "ln2_scale"), ("layers", "ln2_bias"),
+                        ("layers", "bq"), ("layers", "bk"), ("layers", "bv"),
+                        ("layers", "bo"), ("layers", "fc1_b"),
+                        ("layers", "fc2_b")]),
+    "gpt-rope-gqa": (6, [("embed",), ("final_ln_bias",),
+                         ("layers", "ln2_scale"), ("layers", "bo")]),
+    "latent": (6 + 3 + 6 + 3, [
+        ("embed",), ("head",), ("final_norm",), ("moe", "router"),
+        ("moe", "router_bias"), ("moe", "attn_norm"), ("moe", "q_norm"),
+        ("moe", "kv_norm"), ("dense", "ffn_norm"), ("moe", "we_gate"),
+        ("moe", "we_up"), ("moe", "we_down")]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_RULE))
+class TestServingParams:
+    """``serving_params``: the leaves every served program reads only
+    through a cast to the compute dtype are cast ONCE; the programs run
+    either tree to the same bits (PERF.md, PR 29)."""
+
+    DCFG = DecodeConfig(
+        cache=KVCacheConfig(num_pages=9, page_size=4, pages_per_seq=4,
+                            dtype=jnp.bfloat16),
+        max_batch=2, max_prompt_len=8, temperature=0.0, attn_impl="xla",
+        sample_impl="xla")
+
+    def _pools(self, model):
+        from apex_tpu.inference.kv_cache import COUNTERS, alloc_named_pools
+
+        pools = alloc_named_pools(model.cache_spec(), self.DCFG.cache)
+        if model.counter_names:
+            pools[COUNTERS] = jnp.zeros((len(model.counter_names),),
+                                        jnp.int32)
+        return pools
+
+    def test_only_what_the_rule_names_is_cast(self, name):
+        from apex_tpu.observability import tracing
+
+        cfg, params = _family(name)
+        n_cast, kept = _RULE[name]
+        with tracing.TracingScope() as tracer:
+            got = cfg.served_model().serving_params(params)
+        before, after = _paths(params), _paths(got)
+        cast = [p for p in after if after[p] is not before[p]]
+        assert len(cast) == n_cast
+        assert all(after[p].dtype == jnp.bfloat16 for p in cast)
+        for p in kept:
+            assert after[p] is before[p] and after[p].dtype == jnp.float32
+        (span,) = [s for s in tracer.spans()
+                   if s["name"] == "serve.prepare_params"]
+        size = lambda ps: sum(before[p].size * 4 for p in ps)  # noqa: E731
+        assert span["attrs"]["cast_leaves"] == n_cast
+        assert span["attrs"]["cast_bytes"] == size(cast)
+        assert span["attrs"]["kept_bytes"] == size(
+            [p for p in before if p not in cast])
+
+    @pytest.mark.parametrize("case", ["again", "born-in-bf16",
+                                      "float32-compute"])
+    def test_nothing_to_cast_gives_the_same_arrays(self, name, case):
+        """No copy and no program: the second call, a tree already in
+        the compute dtype, and ``compute_dtype=float32``."""
+        import dataclasses
+
+        from apex_tpu.inference import decode
+        from apex_tpu.observability import tracing
+
+        cfg, params = _family(name)
+        model = cfg.served_model()
+        if case == "again":
+            params = model.serving_params(params)
+        elif case == "born-in-bf16":
+            params = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+        else:
+            model = dataclasses.replace(
+                cfg, compute_dtype=jnp.float32).served_model()
+        runs = decode._cast_leaves._cache_size()
+        with tracing.TracingScope() as tracer:
+            got = model.serving_params(params)
+        assert got is params
+        assert decode._cast_leaves._cache_size() == runs
+        (span,) = tracer.spans()
+        assert (span["attrs"]["cast_leaves"],
+                span["attrs"]["cast_bytes"]) == (0, 0)
+
+    @pytest.mark.parametrize("program", ["prefill", "decode_step",
+                                         "tokenwise"])
+    def test_programs_give_the_same_bits_from_either_tree(self, name,
+                                                          program):
+        cfg, params = _family(name)
+        model = cfg.served_model()
+        trees = (params, model.serving_params(params))
+        d, rng = self.DCFG, np.random.RandomState(5)
+        prompt = jnp.asarray(rng.randint(0, cfg.vocab_size, size=(1, 8)))
+        row = jnp.asarray([1, 2, 3, 4], jnp.int32)
+        if program == "tokenwise":
+            a, b = (decode_logits_tokenwise(t, model, d, prompt, 3, row)
+                    for t in trees)
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            return
+        outs = []
+        for tree in trees:
+            hidden, _ = jax.jit(lambda p: model.prefill(
+                p, prompt, jnp.int32(6), "xla"))(tree)
+            pools, first = make_prefill(model, d)(
+                tree, self._pools(model), prompt, jnp.int32(6),
+                jnp.int32(0), row, jnp.uint32(0))
+            got = [hidden, first]
+            if program == "decode_step":
+                tables = jnp.zeros((2, 4), jnp.int32).at[0].set(row)
+                args = (jnp.asarray([int(first), 0]),
+                        jnp.asarray([6, 0]), jnp.asarray([True, False]))
+                hidden, _ = jax.jit(lambda p, pl: model.decode(
+                    p, *args, pl, tables, "xla"))(tree, pools)
+                pools, nxt = make_decode_step(model, d)(
+                    tree, pools, *args, tables, jnp.zeros((2,), jnp.uint32))
+                got = [hidden[0], nxt[0]]
+            outs.append(got + jax.tree.leaves(pools))
+        for a, b in zip(*outs):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(
+                np.asarray(a.astype(jnp.float32)),
+                np.asarray(b.astype(jnp.float32)))
+
+    def test_scheduler_holds_the_prepared_tree_only(self, name):
+        """The fp32 matrices are freed once the caller drops its tree,
+        and a tree prepared by the caller is held as it is (several
+        schedulers then share one)."""
+        import gc
+        import weakref
+
+        cfg, params = _family(name)
+        n_cast, kept = _RULE[name]
+        given = _paths(params)
+        gone = [weakref.ref(leaf) for p, leaf in given.items()
+                if p[-1] in cfg.served_model().cast_once_leaves]
+        stays = weakref.ref(given[kept[0]])
+        assert len(gone) == n_cast
+        sched = ContinuousBatchingScheduler(params, cfg, self.DCFG)
+        del params, given
+        gc.collect()
+        assert all(r() is None for r in gone) and stays() is not None
+        held = _paths(sched.params)
+        assert sum(a.dtype == jnp.bfloat16 for a in held.values()) == n_cast
+        other = ContinuousBatchingScheduler(sched.params, cfg, self.DCFG)
+        assert other.params is sched.params
+        sched.submit(Request(rid=0, prompt=[3, 1, 4, 1, 5],
+                             max_new_tokens=4))
+        assert len(sched.run_until_drained()[0].tokens) == 4
+        jax.block_until_ready(sched.params)
+        assert sched.lower_decode_step() is not None
+
+
 # -------------------------------------------------------------- scheduler
 def _sched(params, cfg, *, num_pages=10, page_size=4, pages_per_seq=6,
            max_batch=3, temperature=0.0, top_k=0, attn="xla", sample="xla",

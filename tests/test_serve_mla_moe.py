@@ -455,6 +455,46 @@ def test_prefill_span_says_what_was_padded(model):
             for s in spans] == [(11, 16)]
 
 
+@pytest.mark.parametrize("param_dtype,cast", [("bfloat16", 0),
+                                              ("float32", 18)])
+def test_scheduler_casts_the_adapters_tree_once_or_not_at_all(param_dtype,
+                                                              cast):
+    """The cell's tree is born in bf16: the scheduler holds it as it is
+    and launches nothing.  The same weights in float32 under bf16
+    compute: the projections are cast once (6 attention + 3 FFN leaves
+    a stack); the router, its bias, the norms, the held experts and
+    both vocabulary tables keep float32."""
+    from apex_tpu.observability import tracing
+
+    conf = _conf()
+    conf["cellbench"]["args"] = {"compute_dtype": "bfloat16",
+                                 "param_dtype": param_dtype}
+    cfg = adapter.model_config(conf)
+    params = adapter.program_params(conf, weights.seed_key(SEED),
+                                    jnp.dtype(param_dtype))
+    with tracing.TracingScope() as tracer:
+        sched, _ = _scheduler((conf, None, cfg, params))
+        (span,) = [s for s in tracer.spans()
+                   if s["name"] == "serve.prepare_params"]
+    held = sched.params
+    moved = [(stack, leaf) for stack in ("dense", "moe")
+             for leaf in held[stack]
+             if held[stack][leaf] is not params[stack][leaf]]
+    assert len(moved) == span["attrs"]["cast_leaves"] == cast
+    assert span["attrs"]["cast_bytes"] == sum(
+        params[s][leaf].size * 4 for s, leaf in moved)
+    assert (held is params) == (cast == 0)
+    assert all(held[s][leaf].dtype == jnp.bfloat16 for s, leaf in moved)
+    assert held["moe"]["router_bias"].dtype == jnp.float32
+    for leaf in ("router", "attn_norm", "q_norm", "kv_norm", "ffn_norm",
+                 "we_gate", "we_up", "we_down"):
+        assert held["moe"][leaf] is params["moe"][leaf]
+    assert held["embed"] is params["embed"]
+    assert held["head"] is params["head"]
+    sched.submit(Request(rid=0, prompt=list(range(9)), max_new_tokens=3))
+    assert len(sched.run_until_drained()[0].tokens) == 3
+
+
 def test_what_the_latent_family_does_not_serve_yet_is_refused(model):
     with pytest.raises(NotImplementedError, match="one position"):
         _scheduler(model, draft_len=2)

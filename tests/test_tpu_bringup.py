@@ -319,7 +319,9 @@ sh = SingleDeviceSharding(dev)
 put = lambda tree: jax.tree.map(
     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
 arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
-params = put(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+# the tree the scheduler holds: given fp32, matrices cast once
+params = put(jax.eval_shape(lambda: cfg.served_model().serving_params(
+    init_params(cfg, jax.random.PRNGKey(0)))))
 pools = put(jax.eval_shape(lambda: alloc_pools(
     cfg.num_layers, cfg.kv_heads, cfg.head_dim, dcfg.cache)))
 I, U = jnp.int32, jnp.uint32
@@ -332,6 +334,7 @@ programs = {
         arg((PPS,), I), arg((), U))),
 }
 layer_pool = pools["k"].size // cfg.num_layers
+matrix = params["layers"]["wq"].size     # the smallest stacked matrix
 out = {"pool_bytes": pools["k"].size * 2}
 for name, (fn, args) in programs.items():
     try:
@@ -341,13 +344,15 @@ for name, (fn, args) in programs.items():
         continue
     found = large_result_instructions(
         c, layer_pool, containing=(dcfg.cache.num_pages, cfg.kv_heads))
+    show = lambda found: [
+        [i["name"], i["opcode"],
+         "tpu_custom_call" in i["line"]
+         and "output_to_operand_aliasing" in i["line"]]
+        for i in found]
     out[name] = {
         "temp_bytes": c.memory_analysis().temp_size_in_bytes,
-        "instructions": [
-            [i["name"], i["opcode"],
-             "tpu_custom_call" in i["line"]
-             and "output_to_operand_aliasing" in i["line"]]
-            for i in found]}
+        "instructions": show(found),
+        "matrix_sized": show(large_result_instructions(c, matrix))}
 print(json.dumps(out))
 """
 
@@ -374,8 +379,9 @@ sh = SingleDeviceSharding(dev)
 put = lambda tree: jax.tree.map(
     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
 arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
-params = put(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
 model = cfg.served_model()
+params = put(jax.eval_shape(lambda: model.serving_params(
+    init_params(cfg, jax.random.PRNGKey(0)))))
 pools = put(jax.eval_shape(lambda: dict(
     alloc_named_pools(model.cache_spec(), dcfg.cache),
     **{COUNTERS: jnp.zeros((len(model.counter_names),), jnp.int32)})))
@@ -389,6 +395,10 @@ programs = {
         arg((PPS,), I), arg((), U))),
 }
 layer_pool = pools["latent"].size // cfg.num_layers
+# more than ONE LAYER of the largest leaf (a layer's 16 held experts):
+# here one layer of a leaf is as large as the smaller leaves' stacks, and
+# the layer loop may well slice it; only a whole stack is larger
+matrix = 1 + params["moe"]["we_gate"].size // cfg.num_moe_layers
 out = {"pool_bytes": pools["latent"].size * 2}
 for name, (fn, args) in programs.items():
     try:
@@ -398,13 +408,15 @@ for name, (fn, args) in programs.items():
         continue
     found = large_result_instructions(
         c, layer_pool, containing=(dcfg.cache.num_pages, 1, 576))
+    show = lambda found: [
+        [i["name"], i["opcode"],
+         "tpu_custom_call" in i["line"]
+         and "output_to_operand_aliasing" in i["line"]]
+        for i in found]
     out[name] = {
         "temp_bytes": c.memory_analysis().temp_size_in_bytes,
-        "instructions": [
-            [i["name"], i["opcode"],
-             "tpu_custom_call" in i["line"]
-             and "output_to_operand_aliasing" in i["line"]]
-            for i in found]}
+        "instructions": show(found),
+        "matrix_sized": show(large_result_instructions(c, matrix))}
 print(json.dumps(out))
 """
 
@@ -412,9 +424,34 @@ print(json.dumps(out))
 _POOL_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while"}
 
 
-@pytest.mark.parametrize("child", [_POOL_CHILD, _LATENT_POOL_CHILD],
-                         ids=["gpt2-large-kv", "latent-one-pool"])
-def test_no_program_copies_the_kv_pool(child):
+@pytest.fixture(scope="module", params=[_POOL_CHILD, _LATENT_POOL_CHILD],
+                ids=["gpt2-large-kv", "latent-one-pool"])
+def serving_programs(request):
+    """What the child found in the decode step and the prefill compiled
+    for a v5e, once a family for the tests below."""
+    r = subprocess.run([sys.executable, "-c", request.param],
+                       cwd=str(REPO), capture_output=True, text=True,
+                       timeout=600,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(f"no compile-only TPU client: {out['skip']}")
+    for name in ("decode_step", "prefill"):
+        assert "error" not in out[name], (
+            f"{name} does not compile: {out[name]}")
+    return out
+
+
+def _moved(instructions, plumbing=_POOL_PLUMBING):
+    """Of ``[name, opcode, aliased kernel]`` rows, those that are not
+    plumbing nor a Pallas call writing its operand in place."""
+    return [(n, op) for n, op, aliased_kernel in instructions
+            if op not in plumbing
+            and not (op == "custom-call" and aliased_kernel)]
+
+
+def test_no_program_copies_the_kv_pool(serving_programs):
     """The decode step and the prefill, compiled for a v5e at GPT-2
     large's serving shapes and at the latent-attention family's (one
     pool of 576 values a token a layer, 128 slots), hold no instruction
@@ -426,19 +463,10 @@ def test_no_program_copies_the_kv_pool(child):
     a read (PERF.md, PR 25: eight such copies were 54% of a decode step
     and held the pool twice).  Temporaries stay under one pool's
     bytes."""
-    r = subprocess.run([sys.executable, "-c", child], cwd=str(REPO),
-                       capture_output=True, text=True, timeout=600,
-                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert r.returncode == 0, r.stderr[-3000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    if "skip" in out:
-        pytest.skip(f"no compile-only TPU client: {out['skip']}")
+    out = serving_programs
     for name in ("decode_step", "prefill"):
         got = out[name]
-        assert "error" not in got, f"{name} does not compile: {got}"
-        bad = [(n, op) for n, op, aliased_kernel in got["instructions"]
-               if op not in _POOL_PLUMBING
-               and not (op == "custom-call" and aliased_kernel)]
+        bad = _moved(got["instructions"])
         assert not bad, (
             f"{name}: instructions that produce a pool-sized value: {bad}")
         kernels = [n for n, op, _ in got["instructions"]
@@ -447,6 +475,30 @@ def test_no_program_copies_the_kv_pool(child):
         assert got["temp_bytes"] < out["pool_bytes"], (
             f"{name}: {got['temp_bytes']} B of temporaries, one pool is "
             f"{out['pool_bytes']} B")
+
+
+def test_no_program_casts_or_copies_the_stacked_weights(serving_programs):
+    """Given the tree the scheduler holds (``serving_params``: GPT-2
+    large's fp32 parameters with the six stacked matrices cast once;
+    the latent family's bf16 tree as it is), the compiled decode step
+    and prefill hold no instruction that PRODUCES a value with as many
+    elements as one stacked matrix (GPT: 36 x 1280 x 1280, 118 MB in
+    bf16; latent: more than one layer of its largest leaf, which only a
+    whole stack is) but parameters, their plumbing, bitcasts and the
+    aliased pool writes.  A ``convert`` there is the weights' cast run
+    again in every program (six of them were 61% of the device time of
+    both GPT-2 serving cells and 1.42 GB of temporaries; PERF.md, PR
+    29); a ``copy`` or ``transpose`` is XLA re-laying a stack out under
+    another name (PR 26: the expert weights, 17 ms of a 56 ms step).
+    The decode step's temporaries stay under 0.2 GB."""
+    out = serving_programs
+    for name in ("decode_step", "prefill"):
+        bad = _moved(out[name]["matrix_sized"],
+                     _POOL_PLUMBING | {"bitcast"})
+        assert not bad, (
+            f"{name}: instructions that produce a value as large as a "
+            f"stacked weight matrix: {bad}")
+    assert out["decode_step"]["temp_bytes"] < 0.2e9
 
 
 # ----------------------------------------------------------- compile cache
