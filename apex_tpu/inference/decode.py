@@ -1,7 +1,9 @@
 """Fused single-token decode step and the prefill step.
 
 The steps are built for a **served model**: any object with
-``cache_spec()`` (pool name -> ``(layers, heads, dim)``), ``prefill``,
+``cache_spec()`` (cache entry name -> ``(layers, heads, dim)`` for a
+paged pool or a :class:`~apex_tpu.inference.kv_cache.PerSlot` for
+per-slot recurrent state), ``prefill``,
 ``decode``, ``head(params)`` (the (V, H) matrix the sampling head
 multiplies), ``serving_params(params)`` (the tree the steps should be
 given: :func:`cast_once`), ``multi_position``, ``counter_names`` and
@@ -50,10 +52,11 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.inference.kv_cache import (
-    KVCacheConfig, alloc_named_pools, write_prompt_pools,
+    KVCacheConfig, alloc_named_pools, per_slot_names, write_prompt_pools,
 )
 from apex_tpu.observability import tracing as _tracing
 from apex_tpu.ops.decode_sampling_pallas import fused_sample
+from apex_tpu.ops.kda import install_rows
 
 __all__ = [
     "DecodeConfig", "cast_once", "decode_logits_tokenwise",
@@ -289,34 +292,42 @@ def make_prefill(model, dcfg: DecodeConfig):
     length: ``dcfg.prefill_lengths``).
 
     Returns ``prefill(params, pools, prompt, prompt_len, start,
-    page_table_row, seed) -> (pools, first_token)`` where ``prompt``
-    is (1, S) int32, S one of the padded lengths (zero-padded past
-    ``prompt_len``; the padded tail's cache columns go to the garbage
-    page and its causal rows are never read), ``start`` is the
+    page_table_row, seed, slot) -> (pools, first_token)`` where
+    ``prompt`` is (1, S) int32, S one of the padded lengths (zero-padded
+    past ``prompt_len``; the padded tail's cache columns go to the
+    garbage page and its causal rows are never read), ``start`` is the
     prefix-sharing write window (positions < ``start`` already live in
     shared pool pages and are NOT rewritten; 0 = unshared),
-    ``page_table_row`` is the admitted sequence's (P,) table, and
+    ``page_table_row`` is the admitted sequence's (P,) table, ``slot``
+    its decode slot (read only where the model keeps per-slot state:
+    the prompt's final state is installed into that slot's rows, all of
+    them, so nothing of the slot's last tenant survives) and
     ``first_token`` is sampled from the LAST prompt position's hidden
     state with the same sampling head as decode.  Pools donate, as in
     the decode step.
     """
     m = served(model)
+    per_slot = per_slot_names(m.cache_spec())
 
     def prefill(params, pools, prompt, prompt_len, start, page_table_row,
-                seed):
+                seed, slot=None):
         S = prompt.shape[1]
         hidden, stacks = m.prefill(params, prompt, prompt_len,
                                    dcfg.attn_impl)
-        names = sorted(stacks)
+        names = sorted(n for n in stacks if n not in per_slot)
         written = write_prompt_pools(
             [pools[n] for n in names], [stacks[n] for n in names],
             page_table_row, prompt_len, start=start, impl=dcfg.attn_impl)
+        new = dict(zip(names, written))
+        for n in per_slot:
+            new[n] = install_rows(pools[n], stacks[n], slot,
+                                  impl=dcfg.attn_impl)
         h_last = hidden[jnp.clip(prompt_len - 1, 0, S - 1), 0]  # (H,)
         first = fused_sample(
             h_last[None], m.head(params), seed[None],
             temperature=dcfg.temperature, top_k=dcfg.top_k,
             impl=dcfg.sample_impl, dot_dtype=dcfg.sample_dot_dtype)
-        return dict(pools, **dict(zip(names, written))), first[0]
+        return dict(pools, **new), first[0]
 
     return jax.jit(prefill, donate_argnums=(1,))
 
@@ -396,15 +407,23 @@ def decode_logits_tokenwise(params, model, dcfg: DecodeConfig,
     m = served(model)
     S = tokens.shape[1]
     B = dcfg.max_batch
+    spec = m.cache_spec()
+    per_slot = per_slot_names(spec)
+    # the prompt padded to S: a paged pool's first ``prefix`` columns do
+    # not see the tail (causal), a per-slot state is taken AT ``prefix``
+    head = jnp.where(jnp.arange(S)[None] < prefix, tokens, 0)
     _, stacks = jax.jit(
-        lambda p, t: m.prefill(p, t, jnp.int32(S), dcfg.attn_impl))(
-            params, tokens)
-    names = sorted(stacks)
-    pools = alloc_named_pools(m.cache_spec(), dcfg.cache)
+        lambda p, t: m.prefill(p, t, jnp.int32(prefix), dcfg.attn_impl))(
+            params, head)
+    names = sorted(n for n in stacks if n not in per_slot)
+    pools = alloc_named_pools(spec, dcfg.cache, slots=B)
     written = write_prompt_pools(
         [pools[n] for n in names], [stacks[n][:, :prefix] for n in names],
         page_table_row, jnp.int32(prefix), impl=dcfg.attn_impl)
-    pools = dict(zip(names, written))
+    pools.update(zip(names, written))
+    for n in per_slot:
+        pools[n] = install_rows(pools[n], stacks[n], jnp.int32(0),
+                                impl=dcfg.attn_impl)
     step = make_decode_step(m, dcfg, return_logits=True)
     tables = jnp.zeros((B, page_table_row.shape[0]), jnp.int32) \
         .at[0].set(page_table_row)

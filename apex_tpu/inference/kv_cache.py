@@ -56,6 +56,22 @@ dense static-shape op and can never corrupt a live sequence's page.
 Every page-table read is clamped into the pool (the APX107 contract:
 a stale or corrupt table entry reads/writes garbage, never wraps).
 
+**A second kind of cache entry: per-slot state.**  A recurrent layer
+(a linear-attention state, a short convolution's tail) keeps a FIXED
+amount a sequence, whatever its length: no pages, no page table, one
+row a decode slot.  A cache spec names such an entry with a
+:class:`PerSlot` in place of the paged ``(layers, heads, dim)`` tuple,
+and :func:`alloc_named_pools` makes it ``(layers, slots + 1) + shape``:
+row ``slots`` is the **garbage row**, the page-0 of this kind — the
+destination of every masked write, never read.  The same rule holds
+for it as for a pool: it is the layer loop's carry, written in place
+through aliased kernels (:mod:`apex_tpu.ops.kda`), and no XLA op in a
+step produces a value of its size.  A prefill hands back a slot's
+final values and :func:`apex_tpu.ops.kda.install_rows` puts them into
+the slot's rows; nothing of a predecessor survives, and nothing can be shared
+between sequences (the scheduler refuses ``prefix_sharing`` for a model
+with such an entry: pages can be shared, a recurrence cannot).
+
 Device-side helpers here are pure functions on the pool arrays (jit
 inside the decode/prefill steps); the allocator and page tables are
 host-side bookkeeping owned by the scheduler.
@@ -63,15 +79,14 @@ host-side bookkeeping owned by the scheduler.
 
 import dataclasses
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
 __all__ = [
-    "COUNTERS", "GARBAGE_PAGE", "KVCacheConfig", "PageAllocator",
-    "alloc_named_pools", "alloc_pools", "copy_page", "named_pools",
-    "pages_needed", "write_decode_kv", "write_decode_pools",
-    "write_prompt_kv", "write_prompt_pools",
+    "COUNTERS", "GARBAGE_PAGE", "KVCacheConfig", "PageAllocator", "PerSlot",
+    "alloc_named_pools", "alloc_pools", "copy_page", "named_pools", "pages_needed", "per_slot_names", "write_decode_kv",
+    "write_decode_pools", "write_prompt_kv", "write_prompt_pools",
 ]
 
 #: page id 0 — reserved, never allocated; the destination of every
@@ -83,6 +98,22 @@ GARBAGE_PAGE = 0
 #: in its decode step (read back once, after a window, by
 #: ``ContinuousBatchingScheduler.read_counters``)
 COUNTERS = "counters"
+
+
+class PerSlot(NamedTuple):
+    """A cache spec's entry for per-slot state (module doc): ``layers``
+    rows of ``shape`` a decode slot, in ``dtype`` (its own, not the
+    paged pools' storage dtype: a recurrent state is float32 beside a
+    bf16 cache)."""
+
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: Any
+
+
+def per_slot_names(spec) -> List[str]:
+    """The names of a cache spec's per-slot entries, sorted."""
+    return sorted(n for n, e in spec.items() if isinstance(e, PerSlot))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,15 +158,29 @@ def pages_needed(total_positions: int, page_size: int) -> int:
     return -(-int(total_positions) // int(page_size))
 
 
-def alloc_named_pools(spec, cfg: KVCacheConfig) -> Dict[str, jnp.ndarray]:
-    """Zero-initialized pools from a served model's cache spec, name ->
-    ``(layers, heads, dim)``: ``(layers, num_pages, heads, dim,
-    page_size)`` each, in the storage dtype (dim-major pages: module
-    doc).  Donated through the decode/prefill jits — the pools are
-    updated in place across the whole serve loop."""
-    return {name: jnp.zeros((int(layers), cfg.num_pages, int(heads),
-                             int(dim), cfg.page_size), cfg.dtype)
-            for name, (layers, heads, dim) in spec.items()}
+def alloc_named_pools(spec, cfg: KVCacheConfig,
+                      slots: Optional[int] = None) -> Dict[str, jnp.ndarray]:
+    """Zero-initialized cache state from a served model's cache spec.
+    A paged entry, name -> ``(layers, heads, dim)``, is a pool
+    ``(layers, num_pages, heads, dim, page_size)`` in the storage dtype
+    (dim-major pages: module doc); a :class:`PerSlot` entry is
+    ``(layers, slots + 1) + shape`` in its own dtype (``slots``: the
+    decode slots, ``DecodeConfig.max_batch``).  Donated through the
+    decode/prefill jits — updated in place across the whole serve
+    loop."""
+    out = {}
+    for name, entry in spec.items():
+        if isinstance(entry, PerSlot):
+            if slots is None:
+                raise ValueError(f"cache entry {name!r} is per-slot state: "
+                                 f"alloc_named_pools needs slots")
+            out[name] = jnp.zeros((int(entry.layers), int(slots) + 1)
+                                  + tuple(entry.shape), entry.dtype)
+        else:
+            layers, heads, dim = entry
+            out[name] = jnp.zeros((int(layers), cfg.num_pages, int(heads),
+                                   int(dim), cfg.page_size), cfg.dtype)
+    return out
 
 
 def alloc_pools(num_layers: int, kv_heads: int, head_dim: int,
@@ -239,7 +284,8 @@ class PageAllocator:
 # ----------------------------------------------------------- device writes
 def copy_page(pools, src: int, dst: int):
     """Copy-on-write seam: duplicate pool page ``src`` into ``dst``
-    across every layer of every named pool.
+    across every layer of every named pool (every entry must be a paged
+    pool: a model with per-slot state shares no prefix).
 
     ``src``/``dst`` are HOST ints handed out by :class:`PageAllocator`
     (``dst`` freshly allocated, refcount 1) — the scheduler calls this

@@ -86,7 +86,7 @@ from apex_tpu.inference.decode import (
 )
 from apex_tpu.inference.kv_cache import (
     COUNTERS, GARBAGE_PAGE, PageAllocator, alloc_named_pools, copy_page,
-    pages_needed,
+    pages_needed, per_slot_names,
 )
 from apex_tpu.inference.prefix import PrefixCache, PrefixMatch
 from apex_tpu.inference.spec import NGramProposer, accepted_tokens
@@ -233,6 +233,14 @@ class ContinuousBatchingScheduler:
                 f"{type(self.model).__name__}'s decode forward scores one "
                 "position a slot: speculative verify (draft_len) and "
                 "chunked prefill (prefill_chunk) are not built for it")
+        spec = self.model.cache_spec()
+        if dcfg.prefix_sharing and per_slot_names(spec):
+            raise NotImplementedError(
+                f"{type(self.model).__name__} keeps per-slot recurrent "
+                f"state ({', '.join(per_slot_names(spec))}): a shared "
+                "page holds a prefix's keys, but the state at a prefix's "
+                "end is not kept anywhere to be shared (prefix_sharing "
+                "needs state snapshots: ROADMAP, Queue 2)")
         limit = self.model.max_positions
         if limit is not None and dcfg.max_prompt_len > limit:
             raise ValueError(
@@ -245,9 +253,10 @@ class ContinuousBatchingScheduler:
         self.config = config
         self.dcfg = dcfg
         self._time = time_fn
-        # the carried cache state: the model's named pools and, if it
+        # the carried cache state: the model's named pools, its
+        # per-slot state (a row a decode slot; no pages) and, if it
         # keeps any, its device-side counters (read_counters)
-        self.pools = alloc_named_pools(self.model.cache_spec(), cache)
+        self.pools = alloc_named_pools(spec, cache, slots=dcfg.max_batch)
         if self.model.counter_names:
             self.pools[COUNTERS] = jnp.zeros(
                 (len(self.model.counter_names),), jnp.int32)
@@ -455,6 +464,21 @@ class ContinuousBatchingScheduler:
             return {}
         values = np.asarray(self.pools[COUNTERS])
         return {n: int(v) for n, v in zip(names, values)}
+
+    def slot_state(self, rid: int) -> Optional[Dict[str, jnp.ndarray]]:
+        """The per-slot state of the RESIDENT request ``rid`` as it
+        stands between two steps: for each of the cache spec's
+        :class:`~apex_tpu.inference.kv_cache.PerSlot` entries, its
+        slot's rows ``(layers,) + shape``, sliced out of the carried
+        array (a copy; a few MB).  It has taken in the prompt and every
+        emitted token but the last (``drain_manifest`` has them).  None
+        for a request that is queued, finished or unknown, and for a
+        model that keeps no such state."""
+        names = per_slot_names(self.model.cache_spec())
+        for i, s in enumerate(self._slots):
+            if s is not None and s.request.rid == rid and names:
+                return {n: self.pools[n][:, i] for n in names}
+        return None
 
     def _call(self, attr: str, *args):
         """Run a compiled step; on a deferred kernel-compile failure,
@@ -732,7 +756,7 @@ class ContinuousBatchingScheduler:
                 "_prefill", self.params, self.pools,
                 jnp.asarray(prompt), jnp.int32(plen),
                 jnp.int32(match.shared_len), jnp.asarray(row),
-                jnp.uint32(self._seed(slot)))
+                jnp.uint32(self._seed(slot)), jnp.int32(slot))
             sp.set(dispatch_us=int(sp.elapsed() * 1e6))
             first = int(first)
         self._prefill_done()

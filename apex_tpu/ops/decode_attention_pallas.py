@@ -515,16 +515,12 @@ def pallas_decode_attn_available(q, k_pool) -> bool:
             and q.dtype in (jnp.float32, jnp.bfloat16))
 
 
-def dispatch_pool_kernel(name, impl, q, k_pool, kernel_impl, xla_impl):
-    """Run one of the two kernels that touch the KV pool, or its XLA
-    twin: ``impl`` "xla" is the twin, "pallas"/"interpret" force the
-    kernel (fail loudly), "auto" is the kernel where
-    :func:`pallas_decode_attn_available` and the twin elsewhere — and a
+def dispatch_kernel(name, impl, available, kernel_impl, xla_impl):
+    """Run a serving kernel or its XLA twin: ``impl`` "xla" is the
+    twin, "pallas"/"interpret" force the kernel (fail loudly), "auto"
+    is the kernel where ``available()`` and the twin elsewhere — and a
     chosen (not forced) kernel routes through the fallback registry
-    under ``name``, degrading to the twin once.  Shared by the read
-    (:func:`decode_attention`) and the write
-    (:mod:`apex_tpu.inference.kv_cache`), which follow one ``attn_impl``.
-    """
+    under ``name``, degrading to the twin once."""
     if impl not in ("auto", "pallas", "interpret", "xla"):
         raise ValueError(
             f"impl must be 'auto', 'pallas', 'interpret', or 'xla'; "
@@ -532,7 +528,7 @@ def dispatch_pool_kernel(name, impl, q, k_pool, kernel_impl, xla_impl):
     if impl == "xla":
         return xla_impl()
     forced = impl in ("pallas", "interpret")
-    if not forced and not pallas_decode_attn_available(q, k_pool):
+    if not forced and not available():
         return xla_impl()
 
     from apex_tpu.resilience.fallback import get_registry, registry_engaged
@@ -540,6 +536,17 @@ def dispatch_pool_kernel(name, impl, q, k_pool, kernel_impl, xla_impl):
     if registry_engaged(forced=forced):
         return get_registry().call(name, kernel_impl, xla_impl)
     return kernel_impl()
+
+
+def dispatch_pool_kernel(name, impl, q, k_pool, kernel_impl, xla_impl):
+    """:func:`dispatch_kernel` for the kernels that touch the KV pool,
+    available where :func:`pallas_decode_attn_available`.  Shared by
+    the read (:func:`decode_attention`) and the write
+    (:mod:`apex_tpu.inference.kv_cache`), which follow one
+    ``attn_impl``."""
+    return dispatch_kernel(
+        name, impl, lambda: pallas_decode_attn_available(q, k_pool),
+        kernel_impl, xla_impl)
 
 
 def decode_attention(q, k_pool, v_pool, page_table, lengths,

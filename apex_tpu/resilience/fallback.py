@@ -97,6 +97,11 @@ KERNELS: Dict[str, tuple] = {
                          "_decode_attn_kernel"),
     "kv_write": ("kv_write_pallas", "_kv_write_kernel"),
     "mla_decode_attention": ("mla_decode_pallas", "_mla_decode_kernel"),
+    # the recurrent-state kernels of ops/kda.py
+    "kda_decode": ("_kda_decode_kernel",),
+    "kda_conv_step": ("_kda_conv_step_kernel",),
+    "kda_chunk_scan": ("_kda_chunk_scan_kernel",),
+    "slot_install": ("_slot_install_kernel", "_slot_install_row_kernel"),
     "decode_sampling": ("decode_sampling_pallas", "fused_sample_pallas",
                         "_sample_kernel", "_merge_top_k"),
 }

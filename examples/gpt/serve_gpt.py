@@ -16,6 +16,12 @@ by.
         cellbench/configs/gigachat3.1-702b-a36b-serve-ep16.json \
         --streams 128 --page-size 128 --prompt-len 1024 --max-new 1024 \
         --prefill-buckets 256,512 --temperature 0
+    # the same family with a mixer KIND per layer (kimi_linear: KDA
+    # layers keep a per-slot recurrent state beside the latent pool)
+    python examples/gpt/serve_gpt.py --model-config \
+        cellbench/configs/kimi-linear-48b-a3b-serve-ep8.json \
+        --streams 128 --page-size 128 --prompt-len 4096 --max-new 1024 \
+        --prefill-buckets 512,1024,2048 --temperature 0
     # serving v2: speculative decode + shared system prompt + chunked
     # prefill + a preemptible best-effort lane, one command
     python examples/gpt/serve_gpt.py --draft-len 4 --prefix-sharing \\
@@ -79,12 +85,12 @@ def build_args():
     p.add_argument("--model-config", default=None,
                    help="a published-style config.json of the "
                         "latent-attention, sparse-expert family "
-                        "(model_type deepseek_v3; models/mla_moe.py) "
-                        "instead of the GPT flags above.  Its "
-                        "n_routed_experts is the number of experts HELD "
-                        "here, from --held-start on; the router's width "
-                        "is published.n_routed_experts where the file "
-                        "has it")
+                        "(model_type deepseek_v3, or kimi_linear with its "
+                        "KDA layers; models/mla_moe.py) instead of the "
+                        "GPT flags above.  Where the file states the "
+                        "router's width under 'published', its own "
+                        "experts count is the number HELD here, from "
+                        "--held-start on")
     p.add_argument("--held-start", type=int, default=0,
                    help="first expert id this process holds "
                         "(--model-config)")
@@ -282,12 +288,14 @@ def build_model(args, max_seq_len):
 
         conf = json.loads(Path(args.model_config).read_text())
         dtype = jnp.float32 if args.smoke else jnp.bfloat16
+        # a file cut to one chip's share states the router's width
+        # under "published"; its own count is what this process holds
+        name = ("n_routed_experts" if "n_routed_experts" in conf
+                else "num_experts")
         config = mla_moe.MLAMoEConfig.from_published(
             conf,
-            n_routed_experts=conf.get("published", {}).get(
-                "n_routed_experts", conf["n_routed_experts"]),
-            held_start=args.held_start,
-            held_count=conf["n_routed_experts"],
+            n_routed_experts=conf.get("published", {}).get(name, conf[name]),
+            held_start=args.held_start, held_count=conf[name],
             param_dtype=dtype, compute_dtype=dtype)
         args.vocab = config.vocab_size
         return config, mla_moe.init_params(config, key)

@@ -292,34 +292,62 @@ def test_stacked_expert_weights_are_indexed_not_sliced(model):
 
 
 # ------------------------------------------------------------ the share
-def test_the_shares_add_up_to_the_uncut_layer():
-    """32 experts as 4 shares of 8: the routed parts that the four
-    shares give (the program's layer, told which experts it holds),
-    plus the shared expert counted once, are what the uncut reference
-    gives for the layer."""
-    key = weights.seed_key(SEED)
-    index = 2                                   # the first expert layer
-    whole = _conf(held_start=0, held=32)
+def _kimi_family():
+    """The KDA/MLA family's tiny configuration, weights, adapter and
+    reference (``tests/test_serve_kda_mla_moe.py``)."""
+    from cellbench import weights_kda_mla_moe as w
+    from cellbench.adapters import serve_kda_mla_moe as a
+    from cellbench.reference import kda_mla_moe as r
+    import test_serve_kda_mla_moe as tiny
+
+    return tiny._conf, w, a, r
+
+
+#: family -> (its tiny configuration, weights, adapter and reference;
+#: the shares; the first expert layer, its stack and the published
+#: name of its experts' gate matrices; the router's arguments)
+SHARES = {
+    # 32 experts as 4 shares of 8, 4 groups of which 2 stay
+    "deepseek_v3-4x8": (
+        lambda: (_conf, weights, adapter, reference), (0, 8, 16, 24), 8,
+        2, "moe", "mlp.experts.gate_proj.weight",
+        dict(top_k=4, n_group=4, topk_group=2, scale=2.5)),
+    # 32 experts as 8 shares of 4, one group: the cut of one chip of
+    # eight (kimi-linear-48b-a3b-serve-ep8 holds 32 of 256)
+    "kimi_linear-8x4": (
+        _kimi_family, tuple(range(0, 32, 4)), 4, 1, "kda_moe",
+        "block_sparse_moe.experts.w1.weight",
+        dict(top_k=4, n_group=1, topk_group=1, scale=2.446)),
+}
+
+
+@pytest.mark.parametrize("family", sorted(SHARES))
+def test_the_shares_add_up_to_the_uncut_layer(family):
+    """The routed parts that all the shares give (the program's layer,
+    told which experts it holds), plus the shared expert counted once,
+    are what the uncut reference gives for the layer."""
+    parts, starts, each, index, stack, gate, route = SHARES[family]
+    conf_of, weights_, adapter_, reference_ = parts()
+    key = weights_.seed_key(SEED)
+    whole = conf_of(held_start=0, held=32)
     w_all = {k: v.astype(jnp.float32)
-             for k, v in weights.layer_weights(whole, key, index).items()}
+             for k, v in weights_.layer_weights(whole, key, index).items()}
     x = jnp.asarray(np.random.RandomState(5).randn(40, 64), jnp.float32)
     ident = lambda a: a
-    want = reference.routed_experts(x, w_all, whole, range(32), ident) \
-        + reference.shared_expert(x, w_all, ident)
+    want = reference_.routed_experts(x, w_all, whole, range(32), ident) \
+        + reference_.shared_expert(x, w_all, ident)
     routed = jnp.zeros_like(x)
     held_total = hit = 0
-    for start in (0, 8, 16, 24):
-        conf = _conf(held_start=start)
-        cfg = adapter.model_config(conf)
-        assert cfg.held == range(start, start + 8)
-        p = jax.tree.map(lambda a: a[0], adapter.program_params(
-            conf, key, jnp.float32)["moe"])     # layer 2 is moe[0]
+    for start in starts:
+        conf = conf_of(held_start=start, held=each)
+        cfg = adapter_.model_config(conf)
+        assert cfg.held == range(start, start + each)
+        p = jax.tree.map(lambda a: a[0], adapter_.program_params(
+            conf, key, jnp.float32)[stack])  # the stack's first layer
         # expert e has the same weights whichever share holds it
-        np.testing.assert_array_equal(
-            p["we_gate"][3].T,
-            w_all["mlp.experts.gate_proj.weight"][start + 3])
-        part, counts = held_experts_ffn(
-            x, p, cfg.held, top_k=4, n_group=4, topk_group=2, scale=2.5)
+        np.testing.assert_array_equal(p["we_gate"][3].T,
+                                      w_all[gate][start + 3])
+        part, counts = held_experts_ffn(x, p, cfg.held, **route)
         routed = routed + part
         held_total += int(counts["assignments_held"])
         hit += int(counts["experts_hit"])

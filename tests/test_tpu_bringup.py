@@ -13,6 +13,7 @@ Whether the compiled kernels compute the right thing is ``chip_smoke.py``'s
 job, on the chip.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -34,6 +35,7 @@ from apex_tpu.ops.flash_attention_pallas import flash_attention_pallas
 from apex_tpu.ops.fused_ce_pallas import (
     fused_ce_bwd_pallas, fused_ce_fwd_pallas,
 )
+from apex_tpu.ops import kda
 from apex_tpu.ops.mla_decode_pallas import mla_decode_pallas
 from apex_tpu.ops.layer_norm_pallas import (
     layer_norm_bwd_pallas, layer_norm_fwd_pallas,
@@ -120,6 +122,47 @@ def _latent_prompt_write(S=512, P=16):
          ((), I32)])
 
 
+# the KDA ops at the cell's shapes: 128 slots, 32 heads of 128, 10 KDA
+# layers; float32 state, bfloat16 convolution tails of 3 x 12,288
+_KDA_STATE = ((10, 129, 32, 128, 128), F32)
+_KDA_TAILS = ((10, 129, 3 * 12288), BF16)
+
+
+def _kda_decode(slots=128):
+    vec = ((slots, 32, 128), F32)
+    return (lambda q, k, v, g, beta, state, active, layer: kda.kda_decode(
+        q, k, v, g, beta, state, active, layer, impl="pallas"),
+        [vec, vec, vec, vec, ((slots, 32), F32), _KDA_STATE,
+         ((slots,), jnp.bool_), ((), I32)])
+
+
+def _kda_conv_step(slots=128):
+    return (lambda x, w, tails, active, layer: kda.conv_step(
+        x, w, tails, active, layer, impl="pallas"),
+        [((slots, 12288), BF16), ((4, 12288), BF16), _KDA_TAILS,
+         ((slots,), jnp.bool_), ((), I32)])
+
+
+def _kda_chunked(S):
+    vec = ((S, 32, 128), F32)
+    return (lambda q, k, v, g, beta, state: kda.kda_chunked(
+        q, k, v, g, beta, state, impl="pallas"),
+        [vec, vec, vec, vec, ((S, 32), F32), ((32, 128, 128), F32)])
+
+
+def _slot_install(rows, dtype):
+    return (lambda rows_, new, slot: kda.install_rows(
+        rows_, new, slot, impl="pallas"),
+        [rows, ((rows[0][0],) + rows[0][2:], dtype), ((), I32)])
+
+
+def _flash_qk192_v128(S=1024):
+    """The MLA prefill's flash forward: keys 192 wide, values 128 wide
+    padded to them (``models/mla_moe._attend_full``)."""
+    q = ((1, 32, S, 192), BF16)
+    return (lambda q_, k_, v_: flash_attention_pallas(q_, k_, v_), [q, q, q])
+
+
 def _flash(heads, kv_heads):
     q, kv = ((8, heads, 1024, 64), BF16), ((8, kv_heads, 1024, 64), BF16)
     return (jax.grad(lambda q, k, v: flash_attention_pallas(q, k, v)
@@ -188,6 +231,17 @@ CASES = {
     "mla_decode_attn": (*_mla_decode(), {"apex_mla_decode_attention"}),
     "latent_write_decode": (*_latent_write(), {"apex_kv_write"}),
     "latent_write_prompt": (*_latent_prompt_write(), {"apex_kv_write"}),
+    # the recurrent state beside the pages: one token a slot in place,
+    # the short convolution's step, the chunked prompt, the install of a
+    # prefill's final state (a block of heads) and tails (a row of 16)
+    "kda_decode": (*_kda_decode(), {"apex_kda_decode"}),
+    "kda_conv_step": (*_kda_conv_step(), {"apex_kda_conv_step"}),
+    "kda_chunked_1024": (*_kda_chunked(1024), {"apex_kda_chunk_scan"}),
+    "slot_install_state": (*_slot_install(_KDA_STATE, F32),
+                           {"apex_slot_install"}),
+    "slot_install_tails": (*_slot_install(_KDA_TAILS, BF16),
+                           {"apex_slot_install"}),
+    "flash_fwd_qk192": (*_flash_qk192_v128(), {"apex_flash_fwd"}),
     # training, GPT-345M and GPT-124M shapes
     "flash_345m": (*_flash(16, 16),
                    {"apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv"}),
@@ -420,16 +474,100 @@ for name, (fn, args) in programs.items():
 print(json.dumps(out))
 """
 
+# the KDA/MLA family as its benchmark cell runs it: published widths,
+# 13 layers (10 KDA, 3 MLA), 32 of 256 experts held, 128 slots x 6,144
+# positions at page 128: a latent pool for 3 layers beside 129 rows of
+# float32 state and of convolution tails for 10
+_KDA_POOL_CHILD = _DESCRIBED_V5E + """
+import jax.numpy as jnp
+from pathlib import Path
+import apex_tpu.utils.platform as platform
+platform.on_tpu = lambda: True      # 'auto' impls: as on the chip
+from apex_tpu.analysis.lowered import large_result_instructions
+from apex_tpu.inference import DecodeConfig, KVCacheConfig
+from apex_tpu.inference.decode import make_decode_step, make_prefill
+from apex_tpu.inference.kv_cache import COUNTERS, alloc_named_pools
+from apex_tpu.models.mla_moe import init_params
+from cellbench.adapters.serve_kda_mla_moe import model_config
+
+B, PAGE, PPS, S = 128, 128, 48, 1024
+cfg = model_config(json.loads(Path(
+    "cellbench/configs/kimi-linear-48b-a3b-serve-ep8.json").read_text()))
+dcfg = DecodeConfig(
+    cache=KVCacheConfig(num_pages=1 + B * PPS, page_size=PAGE,
+                        pages_per_seq=PPS, dtype=jnp.bfloat16),
+    max_batch=B, max_prompt_len=4096, prefill_buckets=(512, 1024, 2048),
+    temperature=0.0, attn_impl="pallas", sample_impl="pallas")
+sh = SingleDeviceSharding(dev)
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+model = cfg.served_model()
+params = put(jax.eval_shape(lambda: model.serving_params(
+    init_params(cfg, jax.random.PRNGKey(0)))))
+pools = put(jax.eval_shape(lambda: dict(
+    alloc_named_pools(model.cache_spec(), dcfg.cache, slots=B),
+    **{COUNTERS: jnp.zeros((len(model.counter_names),), jnp.int32)})))
+I, U = jnp.int32, jnp.uint32
+programs = {
+    "decode_step": (make_decode_step(cfg, dcfg), (
+        params, pools, arg((B,), I), arg((B,), I), arg((B,), jnp.bool_),
+        arg((B, PPS), I), arg((B,), U))),
+    "prefill": (make_prefill(cfg, dcfg), (
+        params, pools, arg((1, S), I), arg((), I), arg((), I),
+        arg((PPS,), I), arg((), U), arg((), I))),
+}
+per_layer = lambda name, kind: pools[name].size // cfg.count(kind)
+# more than one layer of the largest leaf (a layer's 32 held experts)
+matrix = 1 + params["kda_moe"]["we_gate"].size // 9
+nbytes = lambda a: a.size * a.dtype.itemsize
+out = {"pool_bytes": nbytes(pools["latent"]),
+       "step_bytes": None, "chip_bytes": 16 * 2 ** 30}
+for name, (fn, args) in programs.items():
+    try:
+        c = fn.lower(*args).compile()
+    except Exception as e:
+        out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}
+        continue
+    show = lambda found: [
+        [i["name"], i["opcode"],
+         "tpu_custom_call" in i["line"]
+         and "output_to_operand_aliasing" in i["line"]]
+        for i in found]
+    mem = c.memory_analysis()
+    out[name] = {
+        "temp_bytes": mem.temp_size_in_bytes,
+        "program_bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes,
+        "instructions": show(large_result_instructions(
+            c, per_layer("latent", "mla"),
+            containing=(dcfg.cache.num_pages, 1, 576))),
+        "state_instructions": show(large_result_instructions(
+            c, per_layer("kda_state", "kda"),
+            containing=(B + 1, 32, 128, 128))),
+        "tails_instructions": show(large_result_instructions(
+            c, per_layer("kda_conv", "kda"), containing=(B + 1, 36864))),
+        "matrix_sized": show(large_result_instructions(c, matrix))}
+print(json.dumps(out))
+"""
+
 #: what may carry a pool through a compiled step without copying it
 _POOL_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while"}
 
 
-@pytest.fixture(scope="module", params=[_POOL_CHILD, _LATENT_POOL_CHILD],
-                ids=["gpt2-large-kv", "latent-one-pool"])
+@pytest.fixture(scope="module",
+                params=[_POOL_CHILD, _LATENT_POOL_CHILD, _KDA_POOL_CHILD],
+                ids=["gpt2-large-kv", "latent-one-pool",
+                     "kda-state-beside-latent"])
 def serving_programs(request):
     """What the child found in the decode step and the prefill compiled
     for a v5e, once a family for the tests below."""
-    r = subprocess.run([sys.executable, "-c", request.param],
+    return _programs_of(request.param)
+
+
+@functools.lru_cache(maxsize=None)
+def _programs_of(child):
+    r = subprocess.run([sys.executable, "-c", child],
                        cwd=str(REPO), capture_output=True, text=True,
                        timeout=600,
                        env={**os.environ, "JAX_PLATFORMS": "cpu"})
@@ -499,6 +637,28 @@ def test_no_program_casts_or_copies_the_stacked_weights(serving_programs):
             f"{name}: instructions that produce a value as large as a "
             f"stacked weight matrix: {bad}")
     assert out["decode_step"]["temp_bytes"] < 0.2e9
+
+
+def test_no_program_copies_the_recurrent_state():
+    """The same for the second kind of cache entry, at the KDA/MLA
+    cell's shapes: no instruction of the decode step or the prefill
+    but parameters, tuple plumbing and aliased kernels
+    (``apex_kda_decode``, ``apex_kda_conv_step``, ``apex_slot_install``)
+    produces a value the size of one layer of ``kda_state`` (270 MB) or
+    of ``kda_conv``; and the compiled decode step holds between 25% and
+    100% of the chip (12.4 GB: weights 6.9, state 2.8, latent pool
+    2.7)."""
+    out = _programs_of(_KDA_POOL_CHILD)
+    for name in ("decode_step", "prefill"):
+        for which in ("state_instructions", "tails_instructions"):
+            bad = _moved(out[name][which])
+            assert not bad, (f"{name}: instructions that produce a value "
+                             f"as large as a layer of {which[:-13]}: {bad}")
+            assert [n for n, op, _ in out[name][which]
+                    if op == "custom-call"], (
+                f"{name}: no aliased kernel writes {which[:-13]}")
+        assert out[name]["program_bytes"] < out["chip_bytes"]
+    assert 0.25 * out["chip_bytes"] < out["decode_step"]["program_bytes"]
 
 
 # ----------------------------------------------------------- compile cache
