@@ -31,8 +31,12 @@ this scheduler fills its slots —
   divergent write, paid from a reserve page allocated at admission —
   COW can never fail mid-generation.
 - **decode**: one fused step advances every active slot; inactive
-  slots ride along masked.  With ``draft_len`` k > 0 the step is the
-  VERIFY step: per slot, an n-gram proposer
+  slots ride along masked.  The plain step is PIPELINED, one step in
+  flight: a call of ``step()`` launches step n+1 and only then reads
+  step n's tokens back and emits them, so the device computes while the
+  host does its bookkeeping (see "The decode loop" below).  With
+  ``draft_len`` k > 0 the step is the VERIFY step (synchronous:
+  acceptance needs the tokens on the host): per slot, an n-gram proposer
   (:class:`~apex_tpu.inference.spec.NGramProposer`) drafts up to k
   tokens, one batched pass scores all k+1 positions, and the host
   accepts the longest matching prefix — the emitted stream is bitwise
@@ -42,6 +46,31 @@ this scheduler fills its slots —
 - **evict**: finished sequences free (decref) their pages back to the
   allocator — the next ``step()`` can admit into them — and register
   their quiesced tail page into the prefix trie.
+
+The decode loop (``draft_len == 0``).  Step n+1 needs nothing of the
+host that step n's tokens decide: its ``tokens`` argument IS step n's
+output and stays on the device (a prefill writes its first token into
+that vector once, at admission); positions, liveness, page tables and
+seeds are known before step n ends.  So ``step()`` runs: LAUNCH step
+n+1, READ step n BACK, EMIT it (tokens, their times, evictions), ADMIT
+(the synchronous prefills queue on the device behind n+1).  Positions,
+seeds and the COW pass advance at launch; tokens and their times at
+emit — a token's time is the moment it is on the host.  A sequence
+that fills its ``max_new_tokens`` with step n is left out of step n+1,
+so length-terminated traffic wastes no slot-step; one that ends on
+``eos_id`` is found a step late: its slot's part of the step in flight
+is dropped (``stats["wasted_slot_steps"]``; the write landed in pages
+the sequence had reserved or in its own per-slot rows, which the next
+prefill installs whole) and its draw is handed back, so every stream is
+bitwise what a lockstep loop serves.  READERS SEE A SETTLED SCHEDULER:
+``drain_manifest``, ``begin_drain``, ``slot_state``, ``read_counters``
+and ``cancel`` first read the step in flight back and emit it
+(``stats["decode_settles"]``), so between two calls a caller sees every
+launched token emitted and the caches holding all emitted tokens but
+the last; a caller that polls one of them every step runs in lockstep.
+Only the watchdog's hook (another thread, the device hung) reads host
+state unsettled.  ``idle()``, ``num_active``, ``completed`` and
+``stats`` are host reads: they show what has been EMITTED.
 
 The scheduler is time-agnostic (drivers decide when to ``submit``;
 tests replay seeded traces step-by-step, the load-generator example
@@ -74,10 +103,11 @@ import dataclasses
 import logging
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from apex_tpu.inference.decode import (
@@ -206,6 +236,23 @@ class _Slot:
     cow_reserve: Optional[int] = None
     chunk_next: Optional[int] = None  # next prompt position to chunk-prefill
     proposer: Optional[NGramProposer] = None
+    launched: int = 0              # tokens emitted or owed by the step in flight
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A plain decode step launched and not read back: its
+    ``next_tokens`` as the device holds them, and the slots it owes a
+    token (a slot that ends on ``eos_id`` meanwhile is struck)."""
+
+    tokens: Any
+    slots: np.ndarray              # (B,) bool
+
+
+@jax.jit
+def _set_token(tokens, slot, token):
+    """A prefill's first token into the device's token vector."""
+    return tokens.at[slot].set(token)
 
 
 class ContinuousBatchingScheduler:
@@ -270,8 +317,13 @@ class ContinuousBatchingScheduler:
         self._slots: List[Optional[_Slot]] = [None] * B
         self._page_tables = np.zeros((B, P), np.int32)
         self._positions = np.zeros((B,), np.int32)
-        self._tokens = np.zeros((B,), np.int32)
+        self._tokens = np.zeros((B,), np.int32)  # the verify step's
         self._active = np.zeros((B,), bool)
+        #: the plain decode loop's one step in flight, and ITS token
+        #: vector, on the device: the next launch's ``tokens`` argument
+        #: (the last launched step's output, with first tokens written in)
+        self._inflight: Optional[_InFlight] = None
+        self._dev_tokens = jnp.zeros((B,), jnp.int32)
         #: per-slot sampling draw counters — MONOTONIC for the life of
         #: the scheduler, across every generation a slot serves (the
         #: determinism contract: no (slot, draw) seed is ever replayed,
@@ -289,6 +341,11 @@ class ContinuousBatchingScheduler:
             # admission passes on which the head of a queue blocked,
             # by what it lacked
             "admit_blocked_slot": 0, "admit_blocked_pages": 0,
+            # the one step in flight: launches that found the previous
+            # step unread, reads a settling reader forced, and slot-steps
+            # dropped because their sequence had ended on eos_id
+            "decode_overlapped": 0, "decode_settles": 0,
+            "wasted_slot_steps": 0,
         }
         #: prefills run since the last decode/verify step ended: above
         #: zero, that step's token gap holds a prefill as well
@@ -328,15 +385,20 @@ class ContinuousBatchingScheduler:
             watchdog.on_wedge = hook
         self._build_steps()
 
-    def drain_manifest(self) -> List["ManifestEntry"]:
+    def drain_manifest(self, settle: bool = True) -> List["ManifestEntry"]:
         """Snapshot of every unfinished request — queued (both lanes)
         then in-flight, each with the tokens already emitted across all
         its legs — structured for a frontend to resubmit elsewhere and
         SPLICE (emit only ``total[len(already_streamed):]``) rather
-        than regenerate.  Non-destructive and lock-free: list() copies
-        of the queues/slots make it racy-but-safe from the watchdog
-        thread (the decode thread is by definition wedged when it runs
-        there), and cheap enough for a frontend to poll per step."""
+        than regenerate.  The step in flight is settled first, so
+        ``emitted`` holds every launched token (a frontend that polls
+        per step runs the decode loop in lockstep).  ``settle=False``
+        is the watchdog thread's: the device is by definition hung, so
+        it reads the host's state as it stands — non-destructive and
+        lock-free, list() copies of the queues/slots make that
+        racy-but-safe."""
+        if settle:
+            self._settle()
         out: List[ManifestEntry] = []
         for req in list(self.queue) + list(self.be_queue):
             c = self._carry.get(req.rid)
@@ -369,8 +431,10 @@ class ContinuousBatchingScheduler:
         can resubmit the unfinished tail and splice the continuation
         instead of regenerating from scratch — plus the wedge counter.
         Runs on the watchdog thread; reads of the slot arrays are
-        racy-but-safe (the decode thread is by definition wedged)."""
-        manifest = self.drain_manifest()
+        racy-but-safe (the decode thread is by definition wedged, in
+        the readback of the step in flight: nothing here waits for
+        it)."""
+        manifest = self.drain_manifest(settle=False)
         queued = [m.rid for m in manifest if m.phase == "queued"]
         inflight = [m.rid for m in manifest if m.phase == "in_flight"]
         # EVERY entry, untruncated: this record IS the requeue manifest
@@ -459,6 +523,7 @@ class ContinuousBatchingScheduler:
         """The model's device-side counters (``counter_names``), summed
         over every decode step so far: ONE readback, for after a window
         — the steps themselves never read them."""
+        self._settle()
         names = self.model.counter_names
         if not names:
             return {}
@@ -475,6 +540,7 @@ class ContinuousBatchingScheduler:
         for a request that is queued, finished or unknown, and for a
         model that keeps no such state."""
         names = per_slot_names(self.model.cache_spec())
+        self._settle()
         for i, s in enumerate(self._slots):
             if s is not None and s.request.rid == rid and names:
                 return {n: self.pools[n][:, i] for n in names}
@@ -562,6 +628,7 @@ class ContinuousBatchingScheduler:
         None when ``rid`` is resident or unknown — a decoding sequence
         is not cancellable mid-step, the caller suppresses its output
         instead (the frontend's hedge-loser path)."""
+        self._settle()
         for q in (self.queue, self.be_queue):
             for req in q:
                 if req.rid == rid:
@@ -582,7 +649,8 @@ class ContinuousBatchingScheduler:
     def drained(self) -> bool:
         """True once a draining scheduler has no residents left — the
         planned-restart point where killing the replica drops nothing."""
-        return self._draining and all(s is None for s in self._slots)
+        return self._draining and self._inflight is None \
+            and all(s is None for s in self._slots)
 
     def begin_drain(self) -> List[ManifestEntry]:
         """Planned-restart entry: stop admitting (``submit`` raises,
@@ -592,7 +660,7 @@ class ContinuousBatchingScheduler:
         caller re-routes the returned entries and polls :meth:`drained`
         before recycling the process."""
         self._draining = True
-        manifest = [m for m in self.drain_manifest()
+        manifest = [m for m in self.drain_manifest()   # settles
                     if m.phase == "queued"]
         for m in manifest:
             self._submit_times.pop(m.rid, None)
@@ -625,7 +693,10 @@ class ContinuousBatchingScheduler:
         return int(self._active.sum())
 
     def idle(self) -> bool:
+        """Nothing queued, resident or in flight (a host read: a slot
+        is released when its last token is emitted)."""
         return (not self.queue and not self.be_queue
+                and self._inflight is None
                 and all(s is None for s in self._slots))
 
     # ------------------------------------------------------------- admit
@@ -785,6 +856,7 @@ class ContinuousBatchingScheduler:
                                   lane=req.lane)
         s.generated.append(first)
         s.token_times.append(t_first)
+        s.launched = 1
         s.chunk_next = None
         if self.prefix is not None:
             # full pages quiesce the moment the prompt is cached; the
@@ -796,11 +868,18 @@ class ContinuousBatchingScheduler:
                                        self.dcfg.ngram_min)
             s.proposer.extend(list(req.prompt) + [first])
         self._positions[slot] = len(req.prompt)  # where `first` caches
-        self._tokens[slot] = first
         self._active[slot] = True
         if (req.max_new_tokens == 1
                 or (req.eos_id is not None and first == req.eos_id)):
             self._evict(slot)
+        elif self._decode is None:
+            self._tokens[slot] = first
+        else:
+            # the next launch reads its tokens off the device: one
+            # small update a prefill, queued behind the step in flight
+            # (in which this slot, free at its launch, rode masked)
+            self._dev_tokens = _set_token(
+                self._dev_tokens, np.int32(slot), np.int32(first))
 
     # --------------------------------------------------------- preemption
     def _preempt_one(self) -> bool:
@@ -812,6 +891,12 @@ class ContinuousBatchingScheduler:
                  if s is not None and s.request.lane == "best_effort"]
         if not cands:
             return False
+        if self._inflight is not None:
+            # a victim is owed its token of the step in flight, and its
+            # continuation starts from caches that hold it: settle, and
+            # let the pass plan again (the step may have freed a slot)
+            self._settle()
+            return True
         victim = max(cands, key=lambda i: self._slots[i].admit_seq)
         s = self._slots[victim]
         req = s.request
@@ -853,8 +938,19 @@ class ContinuousBatchingScheduler:
 
     def _release_slot(self, slot: int) -> None:
         """Return a slot's pages (and unused COW reserve) to the
-        allocator and clear its static-shape arrays."""
+        allocator and clear its static-shape arrays.  A step in flight
+        that still owes the slot a token ran past the sequence's
+        ``eos_id``: the token is dropped and its draw handed back (it
+        was never emitted, so the slot's next tenant draws what a
+        lockstep loop would have given it)."""
         s = self._slots[slot]
+        if self._inflight is not None and self._inflight.slots[slot]:
+            self._inflight.slots[slot] = False
+            self._draws[slot] -= 1
+            self.stats["wasted_slot_steps"] += 1
+            _metrics.inc("apex_serve_wasted_slot_steps_total",
+                         help="slot-steps launched past a sequence's "
+                              "eos_id and dropped")
         self.allocator.free(s.pages)
         if s.cow_reserve is not None:
             self.allocator.free([s.cow_reserve])
@@ -953,18 +1049,18 @@ class ContinuousBatchingScheduler:
         return progressed
 
     # ------------------------------------------------------------- COW
-    def _cow_for_writes(self, width: int) -> None:
-        """Copy-on-write pass before a decode/verify step: any page the
-        step's write window (``positions .. positions + width - 1``)
-        touches with refcount > 1 is copied into the slot's reserve and
-        the table repointed — shared pages are never written through."""
+    def _cow_for_writes(self, writers: np.ndarray, width: int) -> None:
+        """Copy-on-write pass before a decode/verify step is launched:
+        any page the write window (``positions .. positions + width -
+        1``) of a slot in ``writers`` touches with refcount > 1 is
+        copied into the slot's reserve and the table repointed — shared
+        pages are never written through.  (The copy queues on the device
+        behind a step in flight, ahead of the step it is for.)"""
         if self.prefix is None:
             return  # no sharing → no page can ever hold refcount > 1
         ps = self.dcfg.cache.page_size
         P = self.dcfg.cache.pages_per_seq
-        for i in range(self.dcfg.max_batch):
-            if not self._active[i]:
-                continue
+        for i in np.flatnonzero(writers):
             p0 = int(self._positions[i])
             first_ix = p0 // ps
             last_ix = min((p0 + width - 1) // ps, P - 1)
@@ -992,10 +1088,14 @@ class ContinuousBatchingScheduler:
 
     # -------------------------------------------------------------- step
     def step(self) -> bool:
-        """Admit waiting requests (both lanes), advance chunked
-        prefills by one chunk each, then advance every active sequence
-        — one token (plain decode) or up to ``draft_len + 1`` tokens
-        (speculative verify).  Returns True when any work happened."""
+        """One iteration of the serve loop.  Plain decode: launch step
+        n+1, read step n back and emit it, then admit waiting requests
+        (both lanes; their prefills queue behind n+1) and advance
+        chunked prefills by one chunk each — it returns with a step in
+        flight.  Speculative verify (``draft_len`` > 0; up to
+        ``draft_len + 1`` tokens a slot): admit, advance chunks, then
+        the synchronous verify step.  Returns True when any work
+        happened."""
         if self._watchdog is not None:
             # the first interval covers the prefill/decode jit compiles
             # (the trainer loop's compile-grace pattern); steady state
@@ -1011,77 +1111,131 @@ class ContinuousBatchingScheduler:
             # THIS step past the watchdog deadline, exactly how a hung
             # dispatch presents (plan key: decode steps taken so far)
             monkey.maybe_wedge_step(self.stats["decode_steps"])
+        # acceptance needs the tokens on the host, so a verify step
+        # cannot be launched ahead of its predecessor's readback: it
+        # runs whole, after admission
+        speculative = self.dcfg.draft_len > 0
+        stepped = False if speculative else self._step_decode()
         admitted = self._admit()
         progressed = False
         if self.dcfg.prefill_chunk is not None:
             progressed = self._advance_chunks()
-        if not self._active.any():
-            return admitted > 0 or progressed
-        if self.dcfg.draft_len > 0:
+        if speculative and self._active.any():
             self._step_verify()
-        else:
-            self._step_decode()
-        return True
+            stepped = True
+        return stepped or admitted > 0 or progressed
 
-    def _step_decode(self) -> None:
-        """The plain one-token decode step (PR 9 semantics, plus the
-        COW pass and per-lane latency labels)."""
+    def _settle(self) -> None:
+        """Read the step in flight back and emit it, launching nothing:
+        what a reader of device state or of progress calls first."""
+        if self._inflight is None:
+            return
+        self.stats["decode_settles"] += 1
+        _metrics.inc("apex_serve_decode_settles_total",
+                     help="steps in flight read back for a reader, "
+                          "outside the loop's own order")
+        self._step_decode(launch=False)
+
+    def _next_writers(self) -> np.ndarray:
+        """The slots the next plain step advances: the active ones
+        whose budget the tokens emitted or in flight have not filled (a
+        sequence's length is known before its last token is read)."""
+        live = self._active.copy()
+        for i in np.flatnonzero(live):
+            s = self._slots[i]
+            live[i] = s.launched < s.request.max_new_tokens
+        return live
+
+    def _step_decode(self, launch: bool = True) -> bool:
+        """One iteration of the plain decode loop: launch the next
+        step, THEN read the previous one back and emit it.  The span
+        ``serve.decode_step`` runs from the launch to the PREVIOUS
+        step's tokens on the host.  Returns whether it launched or read
+        anything."""
         B = self.dcfg.max_batch
-        self._cow_for_writes(width=1)
+        prev, self._inflight = self._inflight, None
+        live = self._next_writers() if launch else np.zeros((B,), bool)
+        launching = bool(live.any())
+        if prev is None and not launching:
+            return False
         seeds = np.zeros((B,), np.uint32)
-        for i in range(B):
-            if self._active[i]:
+        if launching:
+            # positions, seeds and the COW pass advance at launch
+            self._cow_for_writes(live, width=1)
+            for i in np.flatnonzero(live):
                 seeds[i] = self._seed(i)
+                self._slots[i].launched += 1
+        overlapped = int(launching and prev is not None)
         # attrs (slot scan, active count) are only worth computing when
         # a tracer is installed — this is the highest-frequency span in
         # the serving path and the off case must stay near-zero
         attrs = (dict(decode_step=self.stats["decode_steps"],
-                      active=int(self._active.sum()),
+                      active=int((live if launching else prev.slots).sum()),
                       trace_ids=self._active_trace_ids(),
-                      prefills_before=self._prefills_since_step)
+                      prefills_before=self._prefills_since_step,
+                      in_flight=overlapped)
                  if _tracing.enabled() else {})
+        next_tokens = None
         with _tracing.span("serve.decode_step", **attrs) as sp:
-            self.pools, next_tokens = self._call(
-                "_decode", self.params, self.pools,
-                jnp.asarray(self._tokens), jnp.asarray(self._positions),
-                jnp.asarray(self._active), jnp.asarray(self._page_tables),
-                jnp.asarray(seeds))
+            if launching:
+                # COPIES of the arrays the host goes on changing while
+                # the step is in flight (an upload may alias or still be
+                # reading its numpy buffer after the launch returns)
+                self.pools, self._dev_tokens = self._call(
+                    "_decode", self.params, self.pools, self._dev_tokens,
+                    jnp.asarray(self._positions.copy()), jnp.asarray(live),
+                    jnp.asarray(self._page_tables.copy()),
+                    jnp.asarray(seeds))
+                self._dev_tokens.copy_to_host_async()
+                self._inflight = _InFlight(self._dev_tokens, live.copy())
+                self._positions[live] += 1
             sp.set(dispatch_us=int(sp.elapsed() * 1e6))
-            next_tokens = np.asarray(next_tokens)
+            if prev is not None:
+                next_tokens = np.asarray(prev.tokens)
         self._prefills_since_step = 0
-        # readback -> end of the step: token bookkeeping, histogram
-        # observations, evictions
+        if overlapped:
+            self.stats["decode_overlapped"] += 1
+            _metrics.inc("apex_serve_decode_overlapped_total",
+                         help="decode steps launched before the "
+                              "previous step's tokens were read back")
+        if prev is not None:
+            self._emit(prev, next_tokens)
+        return True
+
+    def _emit(self, step: _InFlight, next_tokens: np.ndarray) -> None:
+        """Readback -> end of the iteration: token bookkeeping,
+        histogram observations, evictions.  A token's time is now, the
+        moment it is on the host."""
         with _tracing.span("serve.emit") as emit_span:
             now = self._time()
             self.stats["decode_steps"] += 1
             self._record_occupancy()
             tokens, evicted = 0, self.stats["evicted"]
-            for i in range(B):
-                if not self._active[i]:
-                    continue
+            for i in np.flatnonzero(step.slots):
                 s = self._slots[i]
                 tok = int(next_tokens[i])
-                _metrics.observe("apex_serve_inter_token_seconds",
-                                 now - s.token_times[-1],
-                                 help="previous token -> this token",
-                                 exemplar={"trace_id": s.request.trace_id,
-                                           "rid": s.request.rid},
-                                 lane=s.request.lane)
-                if self._anomaly is not None:
-                    self._anomaly.observe("inter_token",
-                                          now - s.token_times[-1],
-                                          lane=s.request.lane)
-                s.generated.append(tok)
-                s.token_times.append(now)
+                self._emit_token(s, tok, now)
                 tokens += 1
-                self._tokens[i] = tok
-                self._positions[i] += 1
                 if (len(s.generated) >= s.request.max_new_tokens
                         or (s.request.eos_id is not None
                             and tok == s.request.eos_id)):
                     self._evict(i)
             emit_span.set(tokens=tokens,
                           evicted=self.stats["evicted"] - evicted)
+
+    def _emit_token(self, s: _Slot, tok: int, now: float) -> None:
+        """One emitted token of a decode or verify step: the gap
+        observations, then the slot's stream and times."""
+        gap = now - s.token_times[-1]
+        _metrics.observe("apex_serve_inter_token_seconds", gap,
+                         help="previous token -> this token",
+                         exemplar={"trace_id": s.request.trace_id,
+                                   "rid": s.request.rid},
+                         lane=s.request.lane)
+        if self._anomaly is not None:
+            self._anomaly.observe("inter_token", gap, lane=s.request.lane)
+        s.generated.append(tok)
+        s.token_times.append(now)
 
     def _step_verify(self) -> None:
         """The speculative step: draft, verify all ``draft_len + 1``
@@ -1091,7 +1245,7 @@ class ContinuousBatchingScheduler:
         non-speculative stream, delivered faster."""
         B = self.dcfg.max_batch
         W = self.dcfg.draft_len + 1
-        self._cow_for_writes(width=W)
+        self._cow_for_writes(self._active, width=W)
         tokmat = np.zeros((B, W), np.int32)
         seeds = np.zeros((B, W), np.uint32)
         for i in range(B):
@@ -1147,19 +1301,7 @@ class ContinuousBatchingScheduler:
                         break
                 self._draws[i] += len(out)  # one draw per emission
                 for tok in out:
-                    _metrics.observe(
-                        "apex_serve_inter_token_seconds",
-                        now - s.token_times[-1],
-                        help="previous token -> this token",
-                        exemplar={"trace_id": s.request.trace_id,
-                                  "rid": s.request.rid},
-                        lane=s.request.lane)
-                    if self._anomaly is not None:
-                        self._anomaly.observe("inter_token",
-                                              now - s.token_times[-1],
-                                              lane=s.request.lane)
-                    s.generated.append(tok)
-                    s.token_times.append(now)
+                    self._emit_token(s, tok, now)
                 s.proposer.extend(out)
                 self.stats["spec_emitted"] += len(out)
                 _metrics.inc("apex_serve_spec_emitted_total", len(out),

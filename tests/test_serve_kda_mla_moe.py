@@ -404,6 +404,38 @@ def test_slot_state_is_the_residents_recurrence(model):
     assert sched.slot_state(1) is None          # finished
 
 
+def test_probe_state_reads_the_prompt_and_all_emitted_but_the_last(model):
+    """The benchmark's probe (``adapter.probe_state``: ``step``, then
+    ``drain_manifest``, then ``slot_state``, which settle the step in
+    flight) holds a state that has taken in the prompt and every emitted
+    token but the last — one token more, the step in flight's, reads
+    thirty times the rounding."""
+    conf, key, cfg, params = model
+    dcfg = DecodeConfig(
+        cache=KVCacheConfig(num_pages=13, page_size=8, pages_per_seq=6,
+                            dtype=jnp.float32),
+        max_batch=2, max_prompt_len=32, prefill_buckets=(16,),
+        temperature=0.0, attn_impl="xla", sample_impl="xla",
+        sample_dot_dtype=jnp.float32)
+    sched = ContinuousBatchingScheduler(params, cfg, dcfg)
+    prompt = np.random.RandomState(6).randint(0, 256, size=11).tolist()
+    tokens, state = adapter.probe_state(sched, prompt)
+    # the slot's pages end before PROBE_TOKENS: 6 x 8 - 11 - 1 = 36
+    # emitted tokens, the first by the prefill, each other by a step
+    # that the probe's manifest read back
+    assert tokens[:11] == prompt and len(tokens) == 11 + 35
+    assert sched.stats["decode_settles"] == 35
+    first = lambda toks: reference.first_kda_state(
+        conf, weights.top_weights(conf, key),
+        weights.layer_weights(conf, key, 0), jnp.asarray(toks, jnp.int32))
+    far = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    want = first(tokens)
+    assert far(state, want) < 1e-5
+    (m,) = sched.drain_manifest()
+    assert m.emitted[:-1] == tokens[11:]
+    assert far(first(tokens + m.emitted[-1:]), want) > 3e-4
+
+
 def test_what_a_recurrent_state_cannot_serve_is_refused(model):
     _, _, cfg, params = model
     cache = KVCacheConfig(num_pages=9, page_size=8, pages_per_seq=4,

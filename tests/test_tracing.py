@@ -1202,7 +1202,17 @@ class TestServeSpans:
                 else "serve.decode_step")
         steps = [s for s in spans if s["name"] == step]
         emits = [s for s in spans if s["name"] == "serve.emit"]
-        assert len(steps) == len(emits) == stats["decode_steps"]
+        assert len(emits) == stats["decode_steps"]
+        if name == "chunked_spec":      # synchronous: a span a step
+            assert len(steps) == len(emits)
+        else:
+            # the plain loop keeps one step in flight: a span launches a
+            # step, reads one back, or (in_flight) does both, and every
+            # step is launched once and read once
+            over = stats["decode_overlapped"]
+            assert len(steps) == 2 * stats["decode_steps"] - over
+            assert sum(s["attrs"]["in_flight"] for s in steps) == over > 0
+            assert stats["decode_settles"] == 0
         # every token but each request's first comes out of a step
         assert sum(e["attrs"]["tokens"] for e in emits) \
             == sum(len(c.tokens) - 1 for c in done)
