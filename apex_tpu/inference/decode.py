@@ -52,7 +52,8 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.inference.kv_cache import (
-    KVCacheConfig, alloc_named_pools, per_slot_names, write_prompt_pools,
+    KVCacheConfig, alloc_named_pools, per_slot_names, windowed_entry,
+    write_prompt_pools, write_prompt_windowed,
 )
 from apex_tpu.observability import tracing as _tracing
 from apex_tpu.ops.decode_sampling_pallas import fused_sample
@@ -287,6 +288,25 @@ def make_verify_step(model, dcfg: DecodeConfig):
     return jax.jit(verify, donate_argnums=(1,))
 
 
+def _write_prompt(pools, stacks, names, windowed, dcfg: DecodeConfig,
+                  page_table_row, prompt_len, start, slot):
+    """A prefilled prompt's columns into the pools ``names``, in place:
+    ``stacks[name]`` is a paged pool's columns, or, for a ``windowed``
+    spec, ``(pooled columns, the open window's own columns)`` (the
+    latter go to decode slot ``slot``'s window buffer).  Returns the
+    written pools by name."""
+    if windowed is not None:
+        written = write_prompt_windowed(
+            [pools[n] for n in names], [stacks[n][0] for n in names],
+            [stacks[n][1] for n in names], page_table_row, prompt_len,
+            slot, windowed, dcfg.cache.num_pages, impl=dcfg.attn_impl)
+    else:
+        written = write_prompt_pools(
+            [pools[n] for n in names], [stacks[n] for n in names],
+            page_table_row, prompt_len, start=start, impl=dcfg.attn_impl)
+    return dict(zip(names, written))
+
+
 def make_prefill(model, dcfg: DecodeConfig):
     """Build the jitted prompt-prefill step (one compile a padded
     length: ``dcfg.prefill_lengths``).
@@ -301,13 +321,17 @@ def make_prefill(model, dcfg: DecodeConfig):
     ``page_table_row`` is the admitted sequence's (P,) table, ``slot``
     its decode slot (read only where the model keeps per-slot state:
     the prompt's final state is installed into that slot's rows, all of
-    them, so nothing of the slot's last tenant survives) and
+    them, so nothing of the slot's last tenant survives; or a window a
+    slot, :class:`~apex_tpu.inference.kv_cache.Windowed`: the model's
+    prefill then hands back ``(pooled columns, own columns)`` a pool,
+    and the open window's columns go to that slot's buffer) and
     ``first_token`` is sampled from the LAST prompt position's hidden
     state with the same sampling head as decode.  Pools donate, as in
     the decode step.
     """
     m = served(model)
     per_slot = per_slot_names(m.cache_spec())
+    windowed = windowed_entry(m.cache_spec(), dcfg.cache)
 
     def prefill(params, pools, prompt, prompt_len, start, page_table_row,
                 seed, slot=None):
@@ -315,10 +339,8 @@ def make_prefill(model, dcfg: DecodeConfig):
         hidden, stacks = m.prefill(params, prompt, prompt_len,
                                    dcfg.attn_impl)
         names = sorted(n for n in stacks if n not in per_slot)
-        written = write_prompt_pools(
-            [pools[n] for n in names], [stacks[n] for n in names],
-            page_table_row, prompt_len, start=start, impl=dcfg.attn_impl)
-        new = dict(zip(names, written))
+        new = _write_prompt(pools, stacks, names, windowed, dcfg,
+                            page_table_row, prompt_len, start, slot)
         for n in per_slot:
             new[n] = install_rows(pools[n], stacks[n], slot,
                                   impl=dcfg.attn_impl)
@@ -417,10 +439,12 @@ def decode_logits_tokenwise(params, model, dcfg: DecodeConfig,
             params, head)
     names = sorted(n for n in stacks if n not in per_slot)
     pools = alloc_named_pools(spec, dcfg.cache, slots=B)
-    written = write_prompt_pools(
-        [pools[n] for n in names], [stacks[n][:, :prefix] for n in names],
-        page_table_row, jnp.int32(prefix), impl=dcfg.attn_impl)
-    pools.update(zip(names, written))
+    windowed = windowed_entry(spec, dcfg.cache)
+    if windowed is None:
+        stacks = dict(stacks, **{n: stacks[n][:, :prefix] for n in names})
+    pools.update(_write_prompt(       # a windowed S is whole windows
+        pools, stacks, names, windowed, dcfg, page_table_row,
+        jnp.int32(prefix), 0, jnp.int32(0)))
     for n in per_slot:
         pools[n] = install_rows(pools[n], stacks[n], jnp.int32(0),
                                 impl=dcfg.attn_impl)

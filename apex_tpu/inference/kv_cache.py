@@ -72,6 +72,34 @@ the slot's rows; nothing of a predecessor survives, and nothing can be shared
 between sequences (the scheduler refuses ``prefix_sharing`` for a model
 with such an entry: pages can be shared, a recurrence cannot).
 
+**A third kind: a pool addressed in chunks, with an exact window a
+slot.**  An attention that keeps the last ``window`` positions exactly
+and ONE pooled column for every ``stride`` positions before them (a
+:class:`Windowed` entry) caches neither one column a token nor a fixed
+amount.  Its pool has two regions of the SAME dim-major page tiles:
+
+- pages ``[1, num_pages)`` are handed out by the allocator as ever, but
+  a column stands for ``stride`` positions, so a page covers
+  ``page_size * stride`` of them — exactly one window (the spec is
+  refused otherwise) — and admission reserves ``ceil(positions /
+  window)`` pages (:func:`page_positions`);
+- pages ``num_pages + slot * window_pages + j`` are decode slot
+  ``slot``'s **window buffer**: ``window_pages = window // page_size``
+  lane-tile pages that the allocator never sees, column ``position mod
+  window``.  It starts again from empty every ``window`` positions: a
+  sequence's live length in it falls to zero and nothing is copied or
+  cleared.
+
+A query's cache is then ONE page list (:func:`windowed_view`): the
+pages of the windows that have closed, every column of them, and after
+them the slot's window pages up to its live column.  The walk kernel
+reads it as any other table, one softmax over both, and never fetches a
+dead window page; a reused slot's old columns lie past the length.  A
+prefill puts the prompt's pooled columns into its pages and the columns
+of its last, open window into the slot's buffer
+(:func:`write_prompt_windowed`).  Nothing of a window buffer can be
+shared between sequences, so the scheduler refuses ``prefix_sharing``.
+
 Device-side helpers here are pure functions on the pool arrays (jit
 inside the decode/prefill steps); the allocator and page tables are
 host-side bookkeeping owned by the scheduler.
@@ -82,11 +110,15 @@ from collections import deque
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = [
     "COUNTERS", "GARBAGE_PAGE", "KVCacheConfig", "PageAllocator", "PerSlot",
-    "alloc_named_pools", "alloc_pools", "copy_page", "named_pools", "pages_needed", "per_slot_names", "write_decode_kv",
+    "Windowed", "alloc_named_pools", "alloc_pools", "copy_page",
+    "named_pools", "open_window", "page_positions", "pages_needed", "per_slot_names",
+    "windowed_entry", "windowed_view", "write_decode_kv",
     "write_decode_pools", "write_prompt_kv", "write_prompt_pools",
+    "write_prompt_windowed",
 ]
 
 #: page id 0 — reserved, never allocated; the destination of every
@@ -109,6 +141,50 @@ class PerSlot(NamedTuple):
     layers: int
     shape: Tuple[int, ...]
     dtype: Any
+
+
+class Windowed(NamedTuple):
+    """A cache spec's entry for a pool addressed in chunks with an exact
+    window a slot (module doc): ``layers`` pools of ``heads x dim``
+    pages; an allocated page's column stands for ``stride`` positions,
+    and every decode slot keeps the last ``window`` positions' own
+    columns in ``window // page_size`` pages of its own."""
+
+    layers: int
+    heads: int
+    dim: int
+    stride: int
+    window: int
+
+
+def windowed_entry(spec, cfg: "KVCacheConfig" = None) -> Optional[Windowed]:
+    """The :class:`Windowed` entry that a cache spec's paged pools are
+    (all of them one, since they share a page table), or None for a
+    spec of plain pools.  With ``cfg``, checked against the page size:
+    a page of columns must cover exactly one window."""
+    found = {e for e in spec.values() if isinstance(e, Windowed)}
+    if not found:
+        return None
+    plain = [n for n, e in spec.items()
+             if not isinstance(e, (Windowed, PerSlot))]
+    if len({(e.stride, e.window) for e in found}) > 1 or plain:
+        raise ValueError(
+            f"a cache spec's paged pools share one page table: they are "
+            f"all windowed alike or none is ({spec})")
+    e = next(iter(found))
+    if cfg is not None and cfg.page_size * e.stride != e.window:
+        raise ValueError(
+            f"a windowed cache needs page_size x stride == window (a "
+            f"page of pooled columns is one window): page_size "
+            f"{cfg.page_size}, stride {e.stride}, window {e.window}")
+    return e
+
+
+def page_positions(spec, cfg: "KVCacheConfig") -> int:
+    """Positions of a sequence that one allocated page covers: the page
+    size, times the stride where a column stands for a chunk."""
+    e = windowed_entry(spec, cfg)
+    return cfg.page_size * (e.stride if e is not None else 1)
 
 
 def per_slot_names(spec) -> List[str]:
@@ -163,7 +239,9 @@ def alloc_named_pools(spec, cfg: KVCacheConfig,
     """Zero-initialized cache state from a served model's cache spec.
     A paged entry, name -> ``(layers, heads, dim)``, is a pool
     ``(layers, num_pages, heads, dim, page_size)`` in the storage dtype
-    (dim-major pages: module doc); a :class:`PerSlot` entry is
+    (dim-major pages: module doc); a :class:`Windowed` entry the same
+    with ``slots * window // page_size`` more pages, the slots' window
+    buffers, after the allocator's; a :class:`PerSlot` entry is
     ``(layers, slots + 1) + shape`` in its own dtype (``slots``: the
     decode slots, ``DecodeConfig.max_batch``).  Donated through the
     decode/prefill jits — updated in place across the whole serve
@@ -176,6 +254,16 @@ def alloc_named_pools(spec, cfg: KVCacheConfig,
                                  f"alloc_named_pools needs slots")
             out[name] = jnp.zeros((int(entry.layers), int(slots) + 1)
                                   + tuple(entry.shape), entry.dtype)
+        elif isinstance(entry, Windowed):
+            if slots is None:
+                raise ValueError(f"cache entry {name!r} keeps a window a "
+                                 f"slot: alloc_named_pools needs slots")
+            windowed_entry(spec, cfg)
+            pages = cfg.num_pages + int(slots) * (entry.window
+                                                  // cfg.page_size)
+            out[name] = jnp.zeros((int(entry.layers), pages,
+                                   int(entry.heads), int(entry.dim),
+                                   cfg.page_size), cfg.dtype)
         else:
             layers, heads, dim = entry
             out[name] = jnp.zeros((int(layers), cfg.num_pages, int(heads),
@@ -527,3 +615,72 @@ def write_prompt_kv(k_pool, v_pool, k_stack, v_stack, page_table_row,
     return write_prompt_pools(
         (k_pool, v_pool), (k_stack, v_stack), page_table_row, prompt_len,
         start=start, impl=impl)
+
+
+# ------------------------------------------------------- windowed entries
+def windowed_view(page_tables, positions, active, rows, entry: Windowed,
+                  page_size: int, num_pages: int):
+    """What a :class:`Windowed` cache is to a decode step's rows, as ONE
+    page list a row (module doc).
+
+    ``page_tables``: (B, P) the allocator's pages, entry ``w`` the
+    pooled columns of window ``w``; ``positions``: (B,) the current
+    tokens' positions; ``active``: (B,) bool; ``rows``: (B,) the decode
+    slot of each row (its window buffer); ``num_pages``: the
+    ALLOCATOR's page count (``KVCacheConfig.num_pages``), after which
+    the window buffers lie.  Returns ``(tables, columns, lengths)``:
+    ``tables`` (B, P + window_pages) — the pages of the closed windows
+    ``0 .. w - 1``, then the slot's window pages; ``columns`` (B,) where
+    the current token's own column goes in that list (``w * page_size +
+    position mod window``); ``lengths`` (B,) ``columns + 1``, 0 for an
+    inactive row.  All int32, a few hundred values: nothing pool-sized.
+    """
+    wp = entry.window // page_size
+    B, P = page_tables.shape
+    positions = positions.astype(jnp.int32)
+    w = positions // entry.window
+    j = jnp.arange(P + wp, dtype=jnp.int32)[None, :]
+    closed = jnp.take_along_axis(page_tables, jnp.clip(j, 0, P - 1), axis=1)
+    own = num_pages + rows.astype(jnp.int32)[:, None] * wp \
+        + jnp.clip(j - w[:, None], 0, wp - 1)
+    tables = jnp.where(j < w[:, None], closed,
+                       jnp.where(j < w[:, None] + wp, own, GARBAGE_PAGE))
+    columns = w * page_size + positions % entry.window
+    return tables.astype(jnp.int32), columns, \
+        jnp.where(active, columns + 1, 0).astype(jnp.int32)
+
+
+def open_window(prompt_len, padded: int, entry: Windowed):
+    """Of a prompt of ``prompt_len`` positions padded to ``padded``
+    (whole windows): ``(first, live)``, where its last, open window
+    starts (so that a slice of one window from ``first`` stays inside
+    the padded prompt) and how many of that slice's columns are the
+    prompt's.  A prompt that ends on a window's edge leaves the window
+    empty: the slice then starts a window early and none of it is live.
+    """
+    start = prompt_len // entry.window * entry.window
+    return jnp.minimum(start, padded - entry.window), prompt_len - start
+
+
+def write_prompt_windowed(pools, pooled, own, page_table_row, prompt_len,
+                          slot, entry: Windowed, num_pages: int,
+                          impl="auto"):
+    """A prefilled prompt into a :class:`Windowed` cache, in place.
+
+    ``pools``: a tuple of pools of one shape; ``pooled``: one (L, S //
+    stride, heads, dim) array a pool, the prompt's pooled columns, one a
+    chunk; ``own``: one (L, window, heads, dim) array a pool, the own
+    columns of the prompt's last, open window (the slice
+    :func:`open_window` names).  The pooled columns of the ``prompt_len
+    // stride`` WHOLE chunks go to the sequence's pages
+    (``page_table_row``), the open window's live columns to decode slot
+    ``slot``'s window buffer; a padded position writes neither.
+    Returns the pools, as a tuple in the order given."""
+    pools = tuple(pools)
+    wp = entry.window // pools[0].shape[-1]
+    pools = write_prompt_pools(pools, pooled, page_table_row,
+                               prompt_len // entry.stride, impl=impl)
+    row = num_pages + slot.astype(jnp.int32) * wp \
+        + jnp.arange(wp, dtype=jnp.int32)
+    return write_prompt_pools(pools, own, row,
+                              prompt_len % entry.window, impl=impl)

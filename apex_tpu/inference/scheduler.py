@@ -116,7 +116,7 @@ from apex_tpu.inference.decode import (
 )
 from apex_tpu.inference.kv_cache import (
     COUNTERS, GARBAGE_PAGE, PageAllocator, alloc_named_pools, copy_page,
-    pages_needed, per_slot_names,
+    page_positions, pages_needed, per_slot_names, windowed_entry,
 )
 from apex_tpu.inference.prefix import PrefixCache, PrefixMatch
 from apex_tpu.inference.spec import NGramProposer, accepted_tokens
@@ -288,6 +288,19 @@ class ContinuousBatchingScheduler:
                 "page holds a prefix's keys, but the state at a prefix's "
                 "end is not kept anywhere to be shared (prefix_sharing "
                 "needs state snapshots: ROADMAP, Queue 2)")
+        #: the cache's window a slot, where it keeps one (a
+        #: :class:`~apex_tpu.inference.kv_cache.Windowed` spec), and the
+        #: positions an allocated page covers (a page of pooled columns
+        #: covers ``page_size * stride``)
+        self._windowed = windowed_entry(spec, cache)
+        self._page_positions = page_positions(spec, cache)
+        if dcfg.prefix_sharing and self._windowed is not None:
+            raise NotImplementedError(
+                f"{type(self.model).__name__} keeps the last "
+                f"{self._windowed.window} positions in a window buffer a "
+                "slot: a window buffer cannot be shared between "
+                "sequences, only the closed windows' pooled pages could "
+                "(prefix_sharing over those: ROADMAP, Queue 2)")
         limit = self.model.max_positions
         if limit is not None and dcfg.max_prompt_len > limit:
             raise ValueError(
@@ -346,6 +359,9 @@ class ContinuousBatchingScheduler:
             # dropped because their sequence had ended on eos_id
             "decode_overlapped": 0, "decode_settles": 0,
             "wasted_slot_steps": 0,
+            # launches after which a slot's window buffer starts again
+            # from empty (a windowed cache only)
+            "window_rollovers": 0,
         }
         #: prefills run since the last decode/verify step ended: above
         #: zero, that step's token gap holds a prefill as well
@@ -536,14 +552,32 @@ class ContinuousBatchingScheduler:
         :class:`~apex_tpu.inference.kv_cache.PerSlot` entries, its
         slot's rows ``(layers,) + shape``, sliced out of the carried
         array (a copy; a few MB).  It has taken in the prompt and every
-        emitted token but the last (``drain_manifest`` has them).  None
-        for a request that is queued, finished or unknown, and for a
-        model that keeps no such state."""
-        names = per_slot_names(self.model.cache_spec())
+        emitted token but the last (``drain_manifest`` has them).  Of a
+        windowed cache (:class:`~apex_tpu.inference.kv_cache.Windowed`)
+        it is the request's pages of pooled columns, ``(layers, pages,
+        heads, dim, page_size)`` a pool, page ``w`` window ``w``'s, a
+        column a chunk that has closed, and under ``<name>.window`` its
+        slot's window buffer, ``(layers, window_pages, heads, dim,
+        page_size)``, column ``position mod window``.  None for a
+        request that is queued, finished or unknown, and for a model
+        that keeps neither."""
+        spec = self.model.cache_spec()
+        names = per_slot_names(spec)
+        pooled = sorted(n for n in spec if n not in names) \
+            if self._windowed is not None else []
         self._settle()
         for i, s in enumerate(self._slots):
-            if s is not None and s.request.rid == rid and names:
-                return {n: self.pools[n][:, i] for n in names}
+            if s is not None and s.request.rid == rid and (names or pooled):
+                out = {n: self.pools[n][:, i] for n in names}
+                pages = jnp.asarray(s.pages, jnp.int32)
+                out.update({n: self.pools[n][:, pages] for n in pooled})
+                if pooled:
+                    cache = self.dcfg.cache
+                    wp = self._windowed.window // cache.page_size
+                    own = cache.num_pages + i * wp + jnp.arange(wp)
+                    out.update({f"{n}.window": self.pools[n][:, own]
+                                for n in pooled})
+                return out
         return None
 
     def _call(self, attr: str, *args):
@@ -686,7 +720,7 @@ class ContinuousBatchingScheduler:
         spill into an unreserved — garbage — table entry)."""
         return pages_needed(
             len(req.prompt) + req.max_new_tokens + self.dcfg.draft_len,
-            self.dcfg.cache.page_size)
+            self._page_positions)
 
     @property
     def num_active(self) -> int:
@@ -1189,6 +1223,9 @@ class ContinuousBatchingScheduler:
                 self._dev_tokens.copy_to_host_async()
                 self._inflight = _InFlight(self._dev_tokens, live.copy())
                 self._positions[live] += 1
+                if self._windowed is not None:
+                    self.stats["window_rollovers"] += int(np.sum(
+                        self._positions[live] % self._windowed.window == 0))
             sp.set(dispatch_us=int(sp.elapsed() * 1e6))
             if prev is not None:
                 next_tokens = np.asarray(prev.tokens)

@@ -102,6 +102,10 @@ KERNELS: Dict[str, tuple] = {
     "kda_conv_step": ("_kda_conv_step_kernel",),
     "kda_chunk_scan": ("_kda_chunk_scan_kernel",),
     "slot_install": ("_slot_install_kernel", "_slot_install_row_kernel"),
+    # ops/eva.py: the chunk summary; the prompt's attention rides the
+    # flash forward, under a name of its own
+    "eva_summarise": ("_summarise_kernel", "_summarise_pallas"),
+    "eva_prefill_attention": ("_window_pallas",),
     "decode_sampling": ("decode_sampling_pallas", "fused_sample_pallas",
                         "_sample_kernel", "_merge_top_k"),
 }

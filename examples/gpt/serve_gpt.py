@@ -22,6 +22,12 @@ by.
         cellbench/configs/kimi-linear-48b-a3b-serve-ep8.json \
         --streams 128 --page-size 128 --prompt-len 4096 --max-new 1024 \
         --prefill-buckets 512,1024,2048 --temperature 0
+    # the third family (models/evabyte.py: EVA attention over a windowed
+    # cache; the page size follows the file: window_size / chunk_size)
+    python examples/gpt/serve_gpt.py --model-config \
+        cellbench/configs/evabyte-6.5b-serve-pp4.json \
+        --streams 20 --prompt-len 16384 --max-new 2048 \
+        --prefill-buckets 2048,4096,8192 --temperature 0
     # serving v2: speculative decode + shared system prompt + chunked
     # prefill + a preemptible best-effort lane, one command
     python examples/gpt/serve_gpt.py --draft-len 4 --prefix-sharing \\
@@ -83,11 +89,14 @@ def build_args():
                    help="GQA query groups (None = MHA)")
     p.add_argument("--vocab", type=int, default=50304)
     p.add_argument("--model-config", default=None,
-                   help="a published-style config.json of the "
-                        "latent-attention, sparse-expert family "
-                        "(model_type deepseek_v3, or kimi_linear with its "
-                        "KDA layers; models/mla_moe.py) instead of the "
-                        "GPT flags above.  Where the file states the "
+                   help="a published-style config.json instead of the "
+                        "GPT flags above: the latent-attention, "
+                        "sparse-expert family (model_type deepseek_v3, or "
+                        "kimi_linear with its KDA layers; "
+                        "models/mla_moe.py), or model_type evabyte "
+                        "(models/evabyte.py: --page-size then follows the "
+                        "file, window_size / chunk_size, and prompts pad "
+                        "to whole windows).  Where the file states the "
                         "router's width under 'published', its own "
                         "experts count is the number HELD here, from "
                         "--held-start on")
@@ -264,6 +273,12 @@ def check_greedy_parity(params, config, completions, max_check=3):
             if isinstance(config, GPTConfig):
                 logits = gpt_forward(params, jnp.asarray([seq]), config)
                 pred = int(jnp.argmax(logits[len(seq) - 1, 0]))
+            elif type(config).__name__ == "EvaByteConfig":
+                from apex_tpu.models import evabyte
+
+                logits = evabyte.forward(params, jnp.asarray(seq), config,
+                                         attn_impl="xla")
+                pred = int(jnp.argmax(logits[-1, :config.vocab_size]))
             else:
                 from apex_tpu.models import mla_moe
 
@@ -280,14 +295,21 @@ def check_greedy_parity(params, config, completions, max_check=3):
 def build_model(args, max_seq_len):
     """``(config, params)`` of the family the flags name: GPT from
     ``--layers/--hidden/--heads/...``, or — with ``--model-config`` — the
-    latent-attention, sparse-expert family from a published-style
-    ``config.json`` (weights in bf16, random)."""
+    family a published-style ``config.json`` names (``model_type``
+    ``evabyte``: ``models/evabyte.py``; else the latent-attention,
+    sparse-expert family; weights in bf16, random)."""
     key = jax.random.PRNGKey(args.seed)
     if args.model_config:
-        from apex_tpu.models import mla_moe
+        from apex_tpu.models import evabyte, mla_moe
 
         conf = json.loads(Path(args.model_config).read_text())
         dtype = jnp.float32 if args.smoke else jnp.bfloat16
+        if conf.get("model_type") == "evabyte":
+            config = evabyte.EvaByteConfig.from_published(
+                conf, param_dtype=dtype, compute_dtype=dtype)
+            args.vocab = config.vocab_size
+            return config, evabyte.init_params(
+                config, key, std=float(conf.get("init_std", 0.01275)))
         # a file cut to one chip's share states the router's width
         # under "published"; its own count is what this process holds
         name = ("n_routed_experts" if "n_routed_experts" in conf
@@ -329,10 +351,22 @@ def build_scheduler(args, watchdog=None, anomaly=None):
     config, params = build_model(
         args, max(total_prompt + args.max_new + args.draft_len + 1, 64))
 
+    # a windowed cache (kv_cache.Windowed): a page of pooled columns is
+    # one window, and a prompt is padded to whole windows
+    from apex_tpu.inference.decode import served
+    from apex_tpu.inference.kv_cache import Windowed
+
+    windowed = next((e for e in served(config).cache_spec().values()
+                     if isinstance(e, Windowed)), None)
+    per_page, max_prompt = args.page_size, total_prompt
+    if windowed is not None:
+        args.page_size = windowed.window // windowed.stride
+        per_page = windowed.window
+        max_prompt = -(-total_prompt // per_page) * per_page
     # worst-case footprint: full prompt + generation budget + the
     # speculative write window (draft k/v land past the accepted stream)
     pages_per_seq = -(-(total_prompt + args.max_new + args.draft_len)
-                      // args.page_size)
+                      // per_page)
     num_pages = args.num_pages
     if num_pages is None:
         # pool sized so ~streams worst-case sequences fit (+ garbage
@@ -343,7 +377,7 @@ def build_scheduler(args, watchdog=None, anomaly=None):
             num_pages=num_pages, page_size=args.page_size,
             pages_per_seq=pages_per_seq,
             dtype=jnp.dtype(args.kv_dtype)),
-        max_batch=args.streams, max_prompt_len=total_prompt,
+        max_batch=args.streams, max_prompt_len=max_prompt,
         prefill_buckets=tuple(int(b) for b in
                               args.prefill_buckets.split(",") if b),
         temperature=args.temperature, top_k=args.top_k,

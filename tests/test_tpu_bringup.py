@@ -35,7 +35,7 @@ from apex_tpu.ops.flash_attention_pallas import flash_attention_pallas
 from apex_tpu.ops.fused_ce_pallas import (
     fused_ce_bwd_pallas, fused_ce_fwd_pallas,
 )
-from apex_tpu.ops import kda
+from apex_tpu.ops import eva, kda
 from apex_tpu.ops.mla_decode_pallas import mla_decode_pallas
 from apex_tpu.ops.layer_norm_pallas import (
     layer_norm_bwd_pallas, layer_norm_fwd_pallas,
@@ -89,10 +89,10 @@ def _prompt_write(kv_heads, page=16):
 
 
 def _sample(temperature, top_k, rows=8, hidden=768, vocab=VOCAB,
-            embed=F32):
+            embed=F32, x_dtype=BF16, dot_dtype=None):
     return (lambda x, e, s: fused_sample_pallas(
-        x, e, s, temperature=temperature, top_k=top_k),
-        [((rows, hidden), BF16), ((vocab, hidden), embed),
+        x, e, s, temperature=temperature, top_k=top_k, dot_dtype=dot_dtype),
+        [((rows, hidden), x_dtype), ((vocab, hidden), embed),
          ((rows,), U32)])
 
 
@@ -187,6 +187,44 @@ def _fused_ce(vocab):
                       ((8192,), I32), ((8192,), F32)])
 
 
+#: EvaByte as its cell serves it: 20 slots, 32 heads of 128, page 128,
+#: ONE page list a slot of 9 pages of pooled columns and 16 of its
+#: window buffer, the 8-layer pool of 1 + 20 * 9 + 20 * 16 pages
+_EVA_CELL = dict(B=20, D=128, page=128, P=25, pages=501, layers=8)
+_EVA_POOL = ((8, 501, 32, 128, 128), BF16)
+
+
+def _eva_summarise():
+    return (lambda k, v, phi, mu, pages, first, closing, layer:
+            eva.eva_summarise(k, v, phi, mu, pages, first, closing, layer,
+                              16, impl="pallas"),
+            [_EVA_POOL, _EVA_POOL, ((32, 128), BF16), ((32, 128), BF16),
+             ((20,), I32), ((20,), I32), ((20,), jnp.bool_), ((), I32)])
+
+
+def _eva_window_attention(pooled):
+    row = ((2048, 32, 128), BF16)
+    buf = ((pooled, 32, 128), BF16)
+    return (lambda q, k, v, kt, vt, seen: eva.eva_window_attention(
+        q, k, v, kt, vt, seen, impl="pallas"),
+        [row, row, row, buf, buf, ((), I32)])
+
+
+def _eva_write(width_tiles=None):
+    """The decode step's column into the cell's pool, or (16 tiles) a
+    prompt's open window into a slot's buffer."""
+    if width_tiles is None:
+        new = ((20, 32, 128), BF16)
+        return (lambda k, v, kn, vn, pt, pos, act, layer: write_decode_kv(
+            k, v, kn, vn, pt, pos, act, layer=layer, impl="pallas"),
+            [_EVA_POOL, _EVA_POOL, new, new, ((20, 25), I32), ((20,), I32),
+             ((20,), jnp.bool_), ((), I32)])
+    new = ((8, 128 * width_tiles, 32, 128), BF16)
+    return (lambda k, v, kn, vn, row, n: write_prompt_kv(
+        k, v, kn, vn, row, n, impl="pallas"),
+        [_EVA_POOL, _EVA_POOL, new, new, ((width_tiles,), I32), ((), I32)])
+
+
 #: name -> (fn, [(shape, dtype)...], kernel names the executable must hold)
 CASES = {
     # serving, GPT-124M heads; 345M heads; GQA; MQA; the verify width
@@ -241,6 +279,22 @@ CASES = {
                            {"apex_slot_install"}),
     "slot_install_tails": (*_slot_install(_KDA_TAILS, BF16),
                            {"apex_slot_install"}),
+    # the windowed cache's kernels at the EvaByte cell's shapes: the
+    # walk over one page list of pooled and own pages, the chunk
+    # summary, a window of the prompt (with and without a pooled
+    # buffer), the column and the open window's writes, a head of 320
+    "eva_decode_attn_cell": (*_decode_attn(32, 32, 1, **_EVA_CELL),
+                             {"apex_decode_attention"}),
+    "eva_summarise": (*_eva_summarise(), {"apex_eva_summarise"}),
+    "eva_window_attention_first": (*_eva_window_attention(0),
+                                   {"apex_flash_fwd"}),
+    "eva_window_attention_pooled": (*_eva_window_attention(1024),
+                                    {"apex_flash_fwd"}),
+    "eva_write_decode": (*_eva_write(), {"apex_kv_write"}),
+    "eva_write_open_window": (*_eva_write(16), {"apex_kv_write"}),
+    "sample_greedy_v320": (*_sample(0.0, 0, rows=20, hidden=4096, vocab=320,
+                                    embed=BF16, x_dtype=F32, dot_dtype=F32),
+                           {"apex_fused_sample"}),
     "flash_fwd_qk192": (*_flash_qk192_v128(), {"apex_flash_fwd"}),
     # training, GPT-345M and GPT-124M shapes
     "flash_345m": (*_flash(16, 16),
@@ -658,6 +712,106 @@ def test_no_program_copies_the_recurrent_state():
                     if op == "custom-call"], (
                 f"{name}: no aliased kernel writes {which[:-13]}")
         assert out[name]["program_bytes"] < out["chip_bytes"]
+    assert 0.25 * out["chip_bytes"] < out["decode_step"]["program_bytes"]
+
+
+_EVA_POOL_CHILD = _DESCRIBED_V5E + """
+import jax.numpy as jnp
+from pathlib import Path
+import apex_tpu.utils.platform as platform
+platform.on_tpu = lambda: True      # 'auto' impls: as on the chip
+from apex_tpu.analysis.lowered import (
+    large_result_instructions, pallas_kernels,
+)
+from apex_tpu.inference import DecodeConfig, KVCacheConfig
+from apex_tpu.inference.decode import make_decode_step, make_prefill
+from apex_tpu.inference.kv_cache import COUNTERS, alloc_named_pools
+from apex_tpu.models.evabyte import init_params
+from cellbench.adapters.serve_evabyte import model_config
+
+B, PAGE, PPS = 20, 128, 9
+conf = json.loads(Path(
+    "cellbench/configs/evabyte-6.5b-serve-pp4.json").read_text())
+cfg = model_config(conf)
+dcfg = DecodeConfig(
+    cache=KVCacheConfig(num_pages=1 + B * PPS, page_size=PAGE,
+                        pages_per_seq=PPS, dtype=jnp.bfloat16),
+    max_batch=B, max_prompt_len=16384,
+    prefill_buckets=tuple(conf["cellbench"]["args"]["prefill_buckets"]),
+    temperature=0.0, attn_impl="pallas", sample_impl="pallas",
+    sample_dot_dtype=jnp.float32)
+sh = SingleDeviceSharding(dev)
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+model = cfg.served_model()
+params = put(jax.eval_shape(lambda: model.serving_params(
+    init_params(cfg, jax.random.PRNGKey(0)))))
+pools = put(jax.eval_shape(lambda: dict(
+    alloc_named_pools(model.cache_spec(), dcfg.cache, slots=B),
+    **{COUNTERS: jnp.zeros((len(model.counter_names),), jnp.int32)})))
+I, U = jnp.int32, jnp.uint32
+prefill = lambda S: (make_prefill(cfg, dcfg), (
+    params, pools, arg((1, S), I), arg((), I), arg((), I),
+    arg((PPS,), I), arg((), U), arg((), I)))
+programs = {
+    "decode_step": (make_decode_step(cfg, dcfg), (
+        params, pools, arg((B,), I), arg((B,), I), arg((B,), jnp.bool_),
+        arg((B, PPS), I), arg((B,), U))),
+    "prefill": prefill(16384), "prefill_2048": prefill(2048),
+}
+nbytes = lambda a: a.size * a.dtype.itemsize
+out = {"pool_bytes": nbytes(pools["k"]), "pool_shape": pools["k"].shape,
+       "chip_bytes": 16 * 2 ** 30}
+for name, (fn, args) in programs.items():
+    try:
+        c = fn.lower(*args).compile()
+    except Exception as e:
+        out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}
+        continue
+    mem = c.memory_analysis()
+    out[name] = {
+        "temp_bytes": mem.temp_size_in_bytes,
+        "program_bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes,
+        "kernels": sorted(set(pallas_kernels(c))),
+        "instructions": [
+            [i["name"], i["opcode"], "tpu_custom_call" in i["line"]
+             and "output_to_operand_aliasing" in i["line"]]
+            for i in large_result_instructions(
+                c, pools["k"].size // cfg.num_hidden_layers,
+                containing=(pools["k"].shape[1], 32, 128))]}
+print(json.dumps(out))
+"""
+
+
+def test_no_program_copies_the_windowed_pool():
+    """The third kind of cache entry, at the EvaByte cell's shapes (20
+    slots, 8 layers, 32 heads of 128: ONE pool a name of 181 pages of
+    pooled columns and 320 of window buffers, 4.2 GB each): no
+    instruction of the decode step or of a prefill (the largest bucket
+    and the smallest) but parameters, tuple plumbing and aliased kernels
+    produces a value the size of one layer of it, so no program copies
+    the window buffers or the pooled pages; each program holds the
+    kernels it should; the decode step needs under 50 MB of
+    temporaries and between 25% and 100% of the chip, the largest
+    prefill fits beside it (under 90% of the chip)."""
+    out = _programs_of(_EVA_POOL_CHILD)
+    assert out["pool_shape"] == [8, 1 + 20 * 9 + 20 * 16, 32, 128, 128]
+    for name in ("decode_step", "prefill", "prefill_2048"):
+        assert "error" not in out[name], out[name]
+        bad = _moved(out[name]["instructions"])
+        assert not bad, (f"{name}: instructions that produce a value as "
+                         f"large as a layer of the pool: {bad}")
+        assert [n for n, op, _ in out[name]["instructions"]
+                if op == "custom-call"], f"{name}: no aliased kernel"
+        assert out[name]["program_bytes"] < 0.9 * out["chip_bytes"]
+    assert out["decode_step"]["kernels"] == [
+        "apex_decode_attention", "apex_eva_summarise", "apex_fused_sample",
+        "apex_kv_write"]
+    assert out["prefill"]["kernels"] == out["prefill_2048"]["kernels"] == [
+        "apex_flash_fwd", "apex_fused_sample", "apex_kv_write"]
+    assert out["decode_step"]["temp_bytes"] < 50e6
     assert 0.25 * out["chip_bytes"] < out["decode_step"]["program_bytes"]
 
 
