@@ -96,7 +96,8 @@ KERNELS: Dict[str, tuple] = {
                          "paged_decode_attention_pallas",
                          "_decode_attn_kernel"),
     "kv_write": ("kv_write_pallas", "_kv_write_kernel"),
-    "mla_decode_attention": ("mla_decode_pallas", "_mla_decode_kernel"),
+    "mla_decode_attention": ("mla_decode_pallas", "_mla_decode_kernel",
+                             "_mla_walk_kernel"),
     # the recurrent-state kernels of ops/kda.py
     "kda_decode": ("_kda_decode_kernel",),
     "kda_conv_step": ("_kda_conv_step_kernel",),

@@ -28,7 +28,10 @@ from apex_tpu.inference.kv_cache import (  # noqa: E402
     write_decode_pools, write_prompt_pools,
 )
 from apex_tpu.models import mla_moe  # noqa: E402
-from apex_tpu.ops.mla_decode_pallas import mla_decode_attention  # noqa: E402
+from apex_tpu.ops import mla_decode_pallas as mdp  # noqa: E402
+from apex_tpu.ops.mla_decode_pallas import (  # noqa: E402
+    mla_decode_attention, mla_decode_attention_xla, mla_decode_pallas,
+)
 from apex_tpu.transformer.expert_parallel import (  # noqa: E402
     grouped_gated_ffn, held_experts_ffn, route_group_limited,
 )
@@ -169,6 +172,168 @@ def test_absorbed_decode_is_the_non_absorbed_attention(impl):
         want = np.einsum("ht,thd->hd", p_, v)
         np.testing.assert_allclose(got[b], want, atol=2e-5)
     assert float(jnp.max(jnp.abs(got[2]))) == 0.0     # the empty slot
+
+
+# ------------------------------------------- the walk over live tiles
+# A page of whole lane tiles (the cells' 128): the kernel copies a
+# sequence's live tiles itself, the copies running on across sequences
+# (PERF.md, PR 33).  The published column: 512 latent values + 64 rotary.
+WALK_PAGE, WALK_P, WALK_DC, WALK_DL = 128, 3, 576, 512
+#: one call's ragged lengths: inactive slots first, BETWEEN live ones
+#: and last, one position, a page less one, a page, a page and one, a
+#: full table
+WALK_LENGTHS = [0, 1, 127, 0, 128, 129, WALK_P * WALK_PAGE, 0, 0, 5, 0]
+WALK_CASES = [(64, jnp.bfloat16), (64, jnp.float32), (32, jnp.bfloat16),
+              (32, jnp.float32)]
+WALK_IDS = ["h64_bf16", "h64_f32", "h32_bf16", "h32_f32"]
+
+
+def _walk_case(heads, dtype, seed=0, layers=2):
+    """(q, pool, page table, lengths): every sequence's pages scattered
+    through a stacked pool, page 0 the garbage page."""
+    rng = np.random.RandomState(seed + heads)
+    B = len(WALK_LENGTHS)
+    pool = jnp.asarray(rng.randn(layers, 1 + B * WALK_P, 1, WALK_DC,
+                                 WALK_PAGE), dtype)
+    q = jnp.asarray(rng.randn(B, heads, WALK_DC) * 0.2, dtype)
+    pt = jnp.asarray(1 + rng.permutation(B * WALK_P).reshape(B, WALK_P),
+                     jnp.int32)
+    return q, pool, pt, jnp.asarray(WALK_LENGTHS, jnp.int32)
+
+
+def _walk(q, pool, pt, lengths):
+    return mla_decode_pallas(q, pool, pt, lengths, WALK_DL, 0.0722,
+                             interpret=True, layer=1)
+
+
+def _live_pages(pt, lengths):
+    """The pool pages that hold a live position, sequence by sequence
+    in the order of their positions."""
+    return [int(p) for row, n in zip(np.asarray(pt), np.asarray(lengths))
+            for p in row[:-(-int(n) // WALK_PAGE)]]
+
+
+@pytest.mark.parametrize("heads,dtype", WALK_CASES, ids=WALK_IDS)
+def test_walk_matches_the_reference_over_ragged_lengths(heads, dtype):
+    q, pool, pt, lengths = _walk_case(heads, dtype)
+    assert mdp._plan(len(WALK_LENGTHS), WALK_P, WALK_PAGE)[0] == \
+        (len(WALK_LENGTHS),)
+    out = _walk(q, pool, pt, lengths)
+    ref = mla_decode_attention_xla(q, pool, pt, lengths, WALK_DL, 0.0722,
+                                   layer=1)
+    assert out.shape == ref.shape and out.dtype == ref.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=0,
+        atol=2e-5 if dtype == jnp.float32 else 0.03)
+    dead = np.asarray(lengths) == 0
+    assert float(np.abs(np.asarray(out, np.float32)[dead]).sum()) == 0.0
+    assert np.abs(np.asarray(out, np.float32)[~dead]).max(axis=(1, 2)).all()
+
+
+def test_walk_widens_a_narrower_cache_to_the_query():
+    q, pool, pt, lengths = _walk_case(8, jnp.bfloat16)
+    q = q.astype(jnp.float32)
+    out = _walk(q, pool, pt, lengths)
+    ref = mla_decode_attention_xla(q, pool, pt, lengths, WALK_DL, 0.0722,
+                                   layer=1)
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=0,
+                               atol=0.03)
+
+
+@pytest.mark.parametrize("heads,dtype", WALK_CASES, ids=WALK_IDS)
+def test_walk_never_reads_a_dead_page(heads, dtype):
+    """Every pool page that holds no live position — the garbage page,
+    a table's slots past its length, every page of an inactive slot,
+    the other layer — is poisoned: no bit of the output changes."""
+    q, pool, pt, lengths = _walk_case(heads, dtype)
+    live = np.zeros(pool.shape[1], bool)
+    live[_live_pages(pt, lengths)] = True
+    assert not live[GARBAGE_PAGE] and 0 < live.sum() < len(live) - 1
+    poison = np.where(np.arange(pool.size).reshape(pool.shape) % 2,
+                      np.nan, np.inf)
+    mask = np.ones(pool.shape, bool)
+    mask[1, live] = False                # layer 1's live pages stay
+    clean = np.asarray(_walk(q, pool, pt, lengths), np.float32)
+    dirty = np.asarray(_walk(q, jnp.where(mask, poison, pool)
+                             .astype(pool.dtype), pt, lengths), np.float32)
+    assert np.isfinite(clean).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.parametrize("heads,dtype", WALK_CASES, ids=WALK_IDS)
+def test_walk_clamps_the_page_table_before_it_is_an_address(heads, dtype):
+    """Entries outside the pool, under live positions and past them:
+    what the kernel reads is the table clipped into the pool, as the
+    XLA twin gathers it."""
+    q, pool, pt, lengths = _walk_case(heads, dtype)
+    wild = np.asarray(pt).copy()
+    wild[1, 0], wild[4, 0], wild[6, 1], wild[6, 2] = -7, 10 ** 6, -1, 2 ** 30
+    wild[0, :], wild[9, 1:] = -3, 10 ** 7        # never read
+    clipped = np.clip(wild, 0, pool.shape[1] - 1)
+    out = _walk(q, pool, jnp.asarray(wild, jnp.int32), lengths)
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(_walk(q, pool, jnp.asarray(clipped, jnp.int32), lengths),
+                   np.float32))
+    ref = mla_decode_attention_xla(q, pool, jnp.asarray(wild, jnp.int32),
+                                   lengths, WALK_DL, 0.0722, layer=1)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=0,
+        atol=2e-5 if dtype == jnp.float32 else 0.03)
+
+
+@pytest.mark.parametrize("slots", [2, 3, 4])
+def test_walk_asks_for_each_live_tile_once_and_for_no_other(slots,
+                                                            monkeypatch):
+    """What the kernel COPIES, from the copies it starts: the live
+    tiles of the live sequences, each once, round robin over the VMEM
+    slots in the order of the walk — nothing for an inactive slot,
+    whether it lies before, between or after live ones — however many
+    slots the walk has: with two no copy runs ahead of the arithmetic,
+    with more some do (the launcher's constant is a measured choice,
+    not what makes the cursor right)."""
+    q, pool, pt, lengths = _walk_case(4, jnp.float32)
+    started = []
+    real = mdp._tile_copy
+
+    class Spy:
+        def __init__(self, dma, page, slot):
+            self.dma, self.page, self.slot = dma, page, slot
+
+        def start(self):
+            jax.debug.callback(
+                lambda p, k: started.append((int(p), int(k))), self.page,
+                self.slot)
+            self.dma.start()
+
+        def wait(self):
+            self.dma.wait()
+
+    monkeypatch.setattr(mdp, "WALK_SLOTS", slots)
+    monkeypatch.setattr(
+        mdp, "_tile_copy", lambda pool_hbm, buf, sem, layer, page, slot:
+        Spy(real(pool_hbm, buf, sem, layer, page, slot), page, slot))
+    out = jax.block_until_ready(_walk(q, pool, pt, lengths))
+    jax.effects_barrier()
+    want = _live_pages(pt, lengths)
+    # the callbacks are unordered effects: compare what was asked for,
+    # and the slot of each, not the order they were delivered in
+    assert sorted(started) == sorted(
+        (page, k % slots) for k, page in enumerate(want))
+    ref = mla_decode_attention_xla(q, pool, pt, lengths, WALK_DL, 0.0722,
+                                   layer=1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=0,
+                               atol=2e-5)
+
+
+def test_a_small_page_keeps_the_grid_of_page_slots():
+    """Which form runs is a matter of the page's shape alone."""
+    assert mdp._plan(128, 16, 128) == ((128,), mdp.WALK_SLOTS)
+    assert mdp._plan(128, 48, 256) == ((128,), mdp.WALK_SLOTS)
+    assert mdp._plan(3, 4, 8) == ((3, 1), 4)
+    assert mdp._plan(8, 12, 16) == ((8, 2), 6)
+    assert mdp._plan(8, 12, 64) == ((8, 2), 6)
 
 
 # ------------------------------------------------------------- the router

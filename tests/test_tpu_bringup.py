@@ -101,10 +101,14 @@ def _sample(temperature, top_k, rows=8, hidden=768, vocab=VOCAB,
 _LATENT_POOL = ((6, 257, 1, 576, 128), BF16)
 
 
-def _mla_decode(slots=128, P=16):
+# the KDA/MLA cell's latent pool: 3 MLA layers, 128 slots x 48 pages
+_LATENT_POOL_P48 = ((3, 6145, 1, 576, 128), BF16)
+
+
+def _mla_decode(slots=128, P=16, heads=64, pool=_LATENT_POOL):
     return (lambda q, pool, pt, n, layer: mla_decode_pallas(
         q, pool, pt, n, 512, 0.1447, layer=layer),
-        [((slots, 64, 576), BF16), _LATENT_POOL, ((slots, P), I32),
+        [((slots, heads, 576), BF16), pool, ((slots, P), I32),
          ((slots,), I32), ((), I32)])
 
 
@@ -267,6 +271,13 @@ CASES = {
                                        vocab=16032, embed=BF16),
                               {"apex_fused_sample"}),
     "mla_decode_attn": (*_mla_decode(), {"apex_mla_decode_attention"}),
+    # the second latent cell: 32 heads, 48 page slots a sequence
+    "mla_decode_attn_h32_p48": (*_mla_decode(P=48, heads=32,
+                                             pool=_LATENT_POOL_P48),
+                                {"apex_mla_decode_attention"}),
+    # a page under 128 lanes (chip_smoke's): the grid of page slots
+    "mla_decode_attn_page8": (*_mla_decode(slots=8, P=12, heads=4, pool=(
+        (2, 97, 1, 576, 8), BF16)), {"apex_mla_decode_attention"}),
     "latent_write_decode": (*_latent_write(), {"apex_kv_write"}),
     "latent_write_prompt": (*_latent_prompt_write(), {"apex_kv_write"}),
     # the recurrent state beside the pages: one token a slot in place,
@@ -322,6 +333,16 @@ def test_kernel_lowers_for_tpu(name):
     assert "tpu_custom_call" in exp.mlir_module()
 
 
+def _lowered_grid_mapping(name):
+    """The grid mapping of the one ``pallas_call`` a case lowers to."""
+    fn, avals, _ = CASES[name]
+    jaxpr = jax.make_jaxpr(fn)(
+        *[jax.ShapeDtypeStruct(s, d) for s, d in avals])
+    call, = [e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    return call.params["grid_mapping"]
+
+
 def test_decode_attention_grid_at_the_cells_shapes():
     """A grid step of ``apex_decode_attention`` is a sequence row with
     ALL of its kv heads, and the kernel walks the row's live pages
@@ -331,12 +352,7 @@ def test_decode_attention_grid_at_the_cells_shapes():
     from apex_tpu.ops.decode_attention_pallas import _plan
 
     def lowered_grid(name):
-        fn, avals, _ = CASES[name]
-        jaxpr = jax.make_jaxpr(fn)(
-            *[jax.ShapeDtypeStruct(s, d) for s, d in avals])
-        call, = [e for e in jaxpr.jaxpr.eqns
-                 if e.primitive.name == "pallas_call"]
-        return tuple(call.params["grid_mapping"].grid)
+        return tuple(_lowered_grid_mapping(name).grid)
 
     B, P, page, heads = _CELL["B"], _CELL["P"], _CELL["page"], 20
     h_blk, grid = _plan(B, heads, 1, _CELL["D"], P, page, BF16)
@@ -351,6 +367,31 @@ def test_decode_attention_grid_at_the_cells_shapes():
     h_blk, grid = _plan(B, heads, 1, 256, P, page, F32)
     assert 1 <= h_blk < heads and heads % h_blk == 0
     assert grid == (B, heads // h_blk)
+
+
+def test_mla_decode_grid_at_the_cells_shapes():
+    """A grid step of ``apex_mla_decode_attention`` is a SEQUENCE, and
+    the kernel walks its live tiles itself out of a pool left in HBM:
+    128 steps a layer at both latent cells' shapes (256 and 768 before
+    PR 33, eight BlockSpec tiles a step, dead ones fetched at every
+    change of sequence).  A page under 128 lanes keeps the page slots
+    in the grid and its tiles in BlockSpecs."""
+    from apex_tpu.ops.mla_decode_pallas import _plan
+
+    def lowered(name):
+        mapping = _lowered_grid_mapping(name)
+        pools = [str(getattr(m.transformed_block_aval, "memory_space", None))
+                 for m in mapping.block_mappings
+                 if m.array_aval.shape == CASES[name][1][1][0]]
+        return tuple(mapping.grid), pools
+
+    for name, P in (("mla_decode_attn", 16), ("mla_decode_attn_h32_p48", 48)):
+        grid, pools = lowered(name)
+        assert grid == (128,) == _plan(128, P, 128)[0]
+        assert pools == ["any"]          # one operand, never a VMEM block
+    grid, pools = lowered("mla_decode_attn_page8")
+    assert grid == (8, 2) == _plan(8, 12, 8)[0]
+    assert pools == ["None"] * 6         # six BlockSpec tiles a step
 
 
 #: a compile-only v5e device, or one JSON line {"skip": why} and exit 0
