@@ -72,6 +72,15 @@ Only the watchdog's hook (another thread, the device hung) reads host
 state unsettled.  ``idle()``, ``num_active``, ``completed`` and
 ``stats`` are host reads: they show what has been EMITTED.
 
+What the loop says of its own pace.  Every iteration reads
+``time.perf_counter`` six times, tracer or none: ``stats["device_wait_s"]``
+sums the seconds blocked in a readback, ``stats["loop_host_s"]`` the
+seconds inside ``step()`` less that wait.  With a tracer on,
+``serve.decode_step`` and ``serve.prefill`` carry the same reads as
+attributes (``prep_us``, ``upload_us``, ``enqueue_us``, ``wait_us``:
+docs/observability.md), and a stretch of ``step()`` calls that found
+nothing resident, queued or in flight is ONE span, ``serve.idle``.
+
 The scheduler is time-agnostic (drivers decide when to ``submit``;
 tests replay seeded traces step-by-step, the load-generator example
 submits on wall-clock Poisson arrivals) and deterministic: sampling
@@ -249,6 +258,18 @@ class _InFlight:
     slots: np.ndarray              # (B,) bool
 
 
+def _launch_us(t_open: float, t_up: float, t_enq: float,
+               t_read: float) -> Dict[str, int]:
+    """A device call's host time taken apart (``time.perf_counter``
+    reads, in µs): the host arguments made device arrays, the compiled
+    call returned, their sum (``dispatch_us``), and the wait for the
+    result that is read back."""
+    return {"upload_us": int((t_up - t_open) * 1e6),
+            "enqueue_us": int((t_enq - t_up) * 1e6),
+            "dispatch_us": int((t_enq - t_open) * 1e6),
+            "wait_us": int((t_read - t_enq) * 1e6)}
+
+
 @jax.jit
 def _set_token(tokens, slot, token):
     """A prefill's first token into the device's token vector."""
@@ -345,7 +366,7 @@ class ContinuousBatchingScheduler:
         self._admit_counter = 0
         self.completed: List[Completion] = []
         self._carry: Dict[int, _Carry] = {}
-        self.stats: Dict[str, int] = {
+        self.stats: Dict[str, float] = {
             "admitted": 0, "evicted": 0, "decode_steps": 0,
             "prefills": 0, "step_rebuilds": 0,
             "preemptions": 0, "chunk_steps": 0, "cow_copies": 0,
@@ -362,7 +383,21 @@ class ContinuousBatchingScheduler:
             # launches after which a slot's window buffer starts again
             # from empty (a windowed cache only)
             "window_rollovers": 0,
+            # seconds (``time.perf_counter``) the loop was blocked in a
+            # readback (a decode or verify step's tokens, a prefill's
+            # first), and seconds inside ``step()`` less that wait:
+            # wait / (wait + host) near 0 says the HOST sets the pace
+            "device_wait_s": 0.0, "loop_host_s": 0.0,
         }
+        #: since when ``step()`` has found nothing resident, queued or
+        #: in flight (``self._time``), and its calls since: ONE
+        #: ``serve.idle`` span a stretch, when work arrives
+        self._idle_since: Optional[float] = None
+        self._idle_polls = 0
+        #: the registry the occupancy gauges' children were resolved
+        #: in, and their handles (``_record_occupancy``)
+        self._gauge_registry = None
+        self._gauges: tuple = ()
         #: prefills run since the last decode/verify step ended: above
         #: zero, that step's token gap holds a prefill as well
         self._prefills_since_step = 0
@@ -480,22 +515,39 @@ class ContinuousBatchingScheduler:
 
     def _record_occupancy(self) -> None:
         """Serving gauges on the current registry (the scope seam:
-        ``with MetricsScope(reg):`` around the serve loop routes them)."""
-        _metrics.set_gauge("apex_serve_queue_depth",
-                           len(self.queue) + len(self.be_queue),
-                           help="requests waiting for a slot+pages")
-        _metrics.set_gauge("apex_serve_lane_queue_depth", len(self.queue),
-                           help="waiting requests, by lane",
-                           lane="interactive")
-        _metrics.set_gauge("apex_serve_lane_queue_depth",
-                           len(self.be_queue),
-                           help="waiting requests, by lane",
-                           lane="best_effort")
-        _metrics.set_gauge("apex_serve_active_slots", self.num_active,
-                           help="resident decoding sequences")
-        _metrics.set_gauge("apex_serve_free_pages",
-                           self.allocator.free_pages,
-                           help="allocatable KV pages")
+        ``with MetricsScope(reg):`` around the serve loop routes them).
+        Their children are resolved once a registry, not once a call."""
+        reg = _metrics.get_metrics()
+        if reg is not self._gauge_registry:
+            self._resolve_gauges(reg)
+        for gauge, value in zip(self._gauges, (
+                len(self.queue) + len(self.be_queue), len(self.queue),
+                len(self.be_queue), self.num_active,
+                self.allocator.free_pages)):
+            gauge.set(value)
+
+    def _resolve_gauges(self, reg) -> None:
+        self._gauge_registry = reg
+        try:
+            lanes = reg.gauge("apex_serve_lane_queue_depth",
+                              "waiting requests, by lane", ("lane",))
+            self._gauges = (
+                reg.gauge("apex_serve_queue_depth",
+                          "requests waiting for a slot+pages").labels(),
+                lanes.labels(lane="interactive"),
+                lanes.labels(lane="best_effort"),
+                reg.gauge("apex_serve_active_slots",
+                          "resident decoding sequences").labels(),
+                reg.gauge("apex_serve_free_pages",
+                          "allocatable KV pages").labels())
+        except ValueError as e:
+            # a caller-owned registry holds one of the names as another
+            # kind: telemetry never changes the serve loop's control flow
+            self._gauges = ()
+            log_structured(_logger, logging.WARNING,
+                           "metrics.record_failed",
+                           metric="apex_serve_* occupancy gauges",
+                           error=f"{type(e).__name__}: {e}")
 
     # ------------------------------------------------------------ build
     def _build_steps(self) -> None:
@@ -851,19 +903,29 @@ class ContinuousBatchingScheduler:
         prompt = np.zeros((1, padded), np.int32)
         prompt[0, :plen] = req.prompt
         # the span ends when the first token is ON THE HOST (the
-        # readback waits for the device); dispatch_us is the enqueue
+        # readback waits for the device, and for what is left of a
+        # decode step in flight: behind_step); dispatch_us is the
+        # upload of the arguments and the enqueue
+        behind = int(self._inflight is not None)
         with _tracing.span("serve.prefill", rid=req.rid,
                            trace_id=req.trace_id, lane=req.lane,
                            prompt_len=plen, tokens=plen,
                            padded_tokens=padded,
                            shared_len=match.shared_len) as sp:
+            t_open = time.perf_counter()
+            args = (jnp.asarray(prompt), jnp.int32(plen),
+                    jnp.int32(match.shared_len), jnp.asarray(row),
+                    jnp.uint32(self._seed(slot)), jnp.int32(slot))
+            t_up = time.perf_counter()
             self.pools, first = self._call(
-                "_prefill", self.params, self.pools,
-                jnp.asarray(prompt), jnp.int32(plen),
-                jnp.int32(match.shared_len), jnp.asarray(row),
-                jnp.uint32(self._seed(slot)), jnp.int32(slot))
-            sp.set(dispatch_us=int(sp.elapsed() * 1e6))
+                "_prefill", self.params, self.pools, *args)
+            t_enq = time.perf_counter()
             first = int(first)
+            t_read = time.perf_counter()
+            self.stats["device_wait_s"] += t_read - t_enq
+            if _tracing.enabled():
+                sp.set(**_launch_us(t_open, t_up, t_enq, t_read),
+                       behind_step=behind)
         self._prefill_done()
         self._start_decoding(slot, first)
 
@@ -1071,9 +1133,13 @@ class ContinuousBatchingScheduler:
                     jnp.int32(s.shared_len),
                     jnp.asarray(self._page_tables[i]))
                 if last:
-                    first = int(self._call(
+                    first = self._call(
                         "_sample_head", self.params, h_last,
-                        jnp.uint32(self._seed(i))))
+                        jnp.uint32(self._seed(i)))
+                    t_enq = time.perf_counter()
+                    first = int(first)
+                    self.stats["device_wait_s"] += \
+                        time.perf_counter() - t_enq
             self.stats["chunk_steps"] += 1
             s.chunk_next = start + n_valid
             progressed = True
@@ -1130,6 +1196,8 @@ class ContinuousBatchingScheduler:
         ``draft_len + 1`` tokens a slot): admit, advance chunks, then
         the synchronous verify step.  Returns True when any work
         happened."""
+        t_in = time.perf_counter()
+        waited = self.stats["device_wait_s"]
         if self._watchdog is not None:
             # the first interval covers the prefill/decode jit compiles
             # (the trainer loop's compile-grace pattern); steady state
@@ -1145,6 +1213,13 @@ class ContinuousBatchingScheduler:
             # THIS step past the watchdog deadline, exactly how a hung
             # dispatch presents (plan key: decode steps taken so far)
             monkey.maybe_wedge_step(self.stats["decode_steps"])
+        if self._idle_since is not None:
+            # an empty server: only a submit ends the stretch
+            if not self.queue and not self.be_queue:
+                self._idle_polls += 1
+                self.stats["loop_host_s"] += time.perf_counter() - t_in
+                return False
+            self._end_idle()
         # acceptance needs the tokens on the host, so a verify step
         # cannot be launched ahead of its predecessor's readback: it
         # runs whole, after admission
@@ -1157,7 +1232,23 @@ class ContinuousBatchingScheduler:
         if speculative and self._active.any():
             self._step_verify()
             stepped = True
-        return stepped or admitted > 0 or progressed
+        worked = stepped or admitted > 0 or progressed
+        if not worked and self.idle():
+            self._idle_since, self._idle_polls = self._time(), 1
+        self.stats["loop_host_s"] += (time.perf_counter() - t_in) - (
+            self.stats["device_wait_s"] - waited)
+        return worked
+
+    def _end_idle(self) -> None:
+        """Work has arrived on an empty server: ONE ``serve.idle`` span
+        for the whole stretch, after the fact (a span a ``step()`` call
+        would flood the ring: an empty server polls every
+        millisecond)."""
+        since, self._idle_since = self._idle_since, None
+        tracer = _tracing.get_tracer()
+        if tracer is not None:
+            tracer.emit("serve.idle", self._epoch(since),
+                        self._time() - since, polls=self._idle_polls)
 
     def _settle(self) -> None:
         """Read the step in flight back and emit it, launching nothing:
@@ -1184,8 +1275,11 @@ class ContinuousBatchingScheduler:
         """One iteration of the plain decode loop: launch the next
         step, THEN read the previous one back and emit it.  The span
         ``serve.decode_step`` runs from the launch to the PREVIOUS
-        step's tokens on the host.  Returns whether it launched or read
-        anything."""
+        step's tokens on the host, and says where the host's time went:
+        ``prep_us`` before it opened, then ``upload_us``,
+        ``enqueue_us`` and ``wait_us``.  Returns whether it launched or
+        read anything."""
+        t_in = time.perf_counter()
         B = self.dcfg.max_batch
         prev, self._inflight = self._inflight, None
         live = self._next_writers() if launch else np.zeros((B,), bool)
@@ -1203,32 +1297,42 @@ class ContinuousBatchingScheduler:
         # attrs (slot scan, active count) are only worth computing when
         # a tracer is installed — this is the highest-frequency span in
         # the serving path and the off case must stay near-zero
+        traced = _tracing.enabled()
         attrs = (dict(decode_step=self.stats["decode_steps"],
                       active=int((live if launching else prev.slots).sum()),
                       trace_ids=self._active_trace_ids(),
                       prefills_before=self._prefills_since_step,
                       in_flight=overlapped)
-                 if _tracing.enabled() else {})
+                 if traced else {})
         next_tokens = None
         with _tracing.span("serve.decode_step", **attrs) as sp:
+            t_open = t_up = t_enq = time.perf_counter()
             if launching:
                 # COPIES of the arrays the host goes on changing while
                 # the step is in flight (an upload may alias or still be
                 # reading its numpy buffer after the launch returns)
+                args = (jnp.asarray(self._positions.copy()),
+                        jnp.asarray(live),
+                        jnp.asarray(self._page_tables.copy()),
+                        jnp.asarray(seeds))
+                t_up = time.perf_counter()
                 self.pools, self._dev_tokens = self._call(
                     "_decode", self.params, self.pools, self._dev_tokens,
-                    jnp.asarray(self._positions.copy()), jnp.asarray(live),
-                    jnp.asarray(self._page_tables.copy()),
-                    jnp.asarray(seeds))
+                    *args)
                 self._dev_tokens.copy_to_host_async()
                 self._inflight = _InFlight(self._dev_tokens, live.copy())
                 self._positions[live] += 1
                 if self._windowed is not None:
                     self.stats["window_rollovers"] += int(np.sum(
                         self._positions[live] % self._windowed.window == 0))
-            sp.set(dispatch_us=int(sp.elapsed() * 1e6))
+                t_enq = time.perf_counter()
             if prev is not None:
                 next_tokens = np.asarray(prev.tokens)
+            t_read = time.perf_counter()
+            self.stats["device_wait_s"] += t_read - t_enq
+            if traced:
+                sp.set(prep_us=int((t_open - t_in) * 1e6),
+                       **_launch_us(t_open, t_up, t_enq, t_read))
         self._prefills_since_step = 0
         if overlapped:
             self.stats["decode_overlapped"] += 1
@@ -1248,31 +1352,43 @@ class ContinuousBatchingScheduler:
             self.stats["decode_steps"] += 1
             self._record_occupancy()
             tokens, evicted = 0, self.stats["evicted"]
+            gaps: Dict[str, list] = {}
             for i in np.flatnonzero(step.slots):
                 s = self._slots[i]
                 tok = int(next_tokens[i])
-                self._emit_token(s, tok, now)
+                self._emit_token(s, tok, now, gaps)
                 tokens += 1
                 if (len(s.generated) >= s.request.max_new_tokens
                         or (s.request.eos_id is not None
                             and tok == s.request.eos_id)):
                     self._evict(i)
+            self._observe_gaps(gaps)
             emit_span.set(tokens=tokens,
                           evicted=self.stats["evicted"] - evicted)
 
-    def _emit_token(self, s: _Slot, tok: int, now: float) -> None:
-        """One emitted token of a decode or verify step: the gap
-        observations, then the slot's stream and times."""
+    def _emit_token(self, s: _Slot, tok: int, now: float,
+                    gaps: Dict[str, list]) -> None:
+        """One emitted token of a decode or verify step: its gap into
+        the step's ``gaps`` (a list a lane of ``(gap, request)``), then
+        the slot's stream and times."""
         gap = now - s.token_times[-1]
-        _metrics.observe("apex_serve_inter_token_seconds", gap,
-                         help="previous token -> this token",
-                         exemplar={"trace_id": s.request.trace_id,
-                                   "rid": s.request.rid},
-                         lane=s.request.lane)
+        gaps.setdefault(s.request.lane, []).append((gap, s.request))
         if self._anomaly is not None:
             self._anomaly.observe("inter_token", gap, lane=s.request.lane)
         s.generated.append(tok)
         s.token_times.append(now)
+
+    def _observe_gaps(self, gaps: Dict[str, list]) -> None:
+        """A step's token gaps into the inter-token histogram: ONE call
+        a lane (128 calls a step were half of the host's iteration at
+        128 slots), the exemplar that of the step's largest gap."""
+        for lane, pairs in gaps.items():
+            _, worst = max(pairs, key=lambda p: p[0])
+            _metrics.observe_many(
+                "apex_serve_inter_token_seconds", [g for g, _ in pairs],
+                help="previous token -> this token",
+                exemplar={"trace_id": worst.trace_id, "rid": worst.rid},
+                lane=lane)
 
     def _step_verify(self) -> None:
         """The speculative step: draft, verify all ``draft_len + 1``
@@ -1314,7 +1430,9 @@ class ContinuousBatchingScheduler:
                 jnp.asarray(tokmat), jnp.asarray(self._positions),
                 jnp.asarray(self._active), jnp.asarray(self._page_tables),
                 jnp.asarray(seeds))
+            t_enq = time.perf_counter()
             sampled = np.asarray(sampled)
+            self.stats["device_wait_s"] += time.perf_counter() - t_enq
             self._prefills_since_step = 0
             # the accept loop, under the verify span as its child
             emit_span = _tracing.span("serve.emit")
@@ -1322,6 +1440,7 @@ class ContinuousBatchingScheduler:
             self.stats["decode_steps"] += 1
             self.stats["spec_steps"] += 1
             self._record_occupancy()
+            gaps: Dict[str, list] = {}
             for i in range(B):
                 if not self._active[i]:
                     continue
@@ -1338,7 +1457,7 @@ class ContinuousBatchingScheduler:
                         break
                 self._draws[i] += len(out)  # one draw per emission
                 for tok in out:
-                    self._emit_token(s, tok, now)
+                    self._emit_token(s, tok, now, gaps)
                 s.proposer.extend(out)
                 self.stats["spec_emitted"] += len(out)
                 _metrics.inc("apex_serve_spec_emitted_total", len(out),
@@ -1349,6 +1468,7 @@ class ContinuousBatchingScheduler:
                         or (s.request.eos_id is not None
                             and out[-1] == s.request.eos_id)):
                     self._evict(i)
+            self._observe_gaps(gaps)
         except BaseException:
             verify_span.set(error=True)
             raise

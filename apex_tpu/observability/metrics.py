@@ -39,7 +39,7 @@ from apex_tpu.observability.correlation import step_context
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "MetricsScope",
-    "get_metrics", "inc", "observe", "set_gauge",
+    "get_metrics", "inc", "observe", "observe_many", "set_gauge",
 ]
 
 #: default latency buckets (seconds): sub-ms decode tokens through
@@ -217,6 +217,16 @@ class Histogram(_Metric):
         .prom export carries only the histogram itself."""
         self.labels(**labels).observe(v, exemplar=exemplar)
 
+    def observe_many(self, values: Sequence[float],
+                     exemplar: Optional[dict] = None, **labels) -> None:
+        """Record every value of a batch (a decode step's token gaps)
+        with ONE resolution of the child and one lock: bucket counts,
+        ``sum`` and ``count`` are what ``observe`` a value, in order,
+        gives.  ``exemplar`` is the identity of the batch's LARGEST
+        value, the caller's to pick: an exemplar exists to make an
+        outlier joinable, and ``MAX_EXEMPLARS`` are kept."""
+        self.labels(**labels).observe_many(values, exemplar=exemplar)
+
     def drain_exemplars(self) -> List[Tuple[dict, dict]]:
         """``(labels, exemplar)`` pairs recorded since the last drain
         (the JSONL snapshot's feed); clears the rings."""
@@ -245,12 +255,26 @@ class _BoundHistogram:
         self._m, self._key = metric, key
 
     def observe(self, v: float, exemplar: Optional[dict] = None) -> None:
-        v = float(v)
+        self.observe_many((v,), exemplar=exemplar)
+
+    def observe_many(self, values: Sequence[float],
+                     exemplar: Optional[dict] = None) -> None:
+        values = [float(v) for v in values]
+        if not values:
+            return
         m = self._m
+        buckets = m.buckets
         with m._lock:
             st: _HistState = m._children[self._key]
-            st.sum += v
-            st.count += 1
+            for v in values:
+                st.sum += v
+                for i, le in enumerate(buckets):
+                    if v <= le:
+                        st.counts[i] += 1
+                        break
+                else:
+                    st.counts[-1] += 1
+            st.count += len(values)
             if exemplar is not None:
                 # recency ring, but the window MAX survives eviction:
                 # the p99 outlier is the sample worth joining, and a
@@ -261,13 +285,8 @@ class _BoundHistogram:
                     mx = max(range(len(exs)),
                              key=lambda i: exs[i]["value"])
                     del exs[1 if mx == 0 else 0]
-                exs.append(
-                    {"value": v, "ts": round(time.time(), 3), **exemplar})
-            for i, le in enumerate(m.buckets):
-                if v <= le:
-                    st.counts[i] += 1
-                    return
-            st.counts[-1] += 1
+                exs.append({"value": max(values),
+                            "ts": round(time.time(), 3), **exemplar})
 
 
 def _fmt(v: float) -> str:
@@ -504,4 +523,18 @@ def observe(name: str, value: float, help: str = "",
         lambda: get_metrics().histogram(
             name, help, tuple(sorted(labels)),
             buckets=buckets).observe(value, exemplar=exemplar, **labels),
+        name)
+
+
+def observe_many(name: str, values: Sequence[float], help: str = "",
+                 buckets: Sequence[float] = DEFAULT_BUCKETS,
+                 exemplar: Optional[dict] = None, **labels) -> None:
+    """:meth:`Histogram.observe_many` on histogram ``name`` of the
+    current registry: one call for a batch of values, ``exemplar`` the
+    identity of the largest.  Best-effort — see above."""
+    _best_effort(
+        lambda: get_metrics().histogram(
+            name, help, tuple(sorted(labels)),
+            buckets=buckets).observe_many(values, exemplar=exemplar,
+                                          **labels),
         name)

@@ -17,6 +17,7 @@ state beside it):
 """
 
 import sys
+from contextlib import nullcontext
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -455,3 +456,161 @@ def test_the_watchdogs_hook_waits_for_nothing(fam):
     assert _in_flight(sched) and sched.stats["decode_settles"] == 0
     sched._inflight.tokens = tokens
     fam.check(sched.run_until_drained())
+
+
+# ------------------------------------- the iteration taken apart (PR 35)
+LAUNCH_ATTRS = ("upload_us", "enqueue_us", "dispatch_us", "wait_us")
+
+
+def _drive_traced(fam, traced=True):
+    """The benchmark's driver over six requests, after a warm-up:
+    (scheduler, the window's completions by rid, spans)."""
+    from cellbench.adapters import serve
+
+    prompts = _prompts(13, [5, 9, 3, 7, 6, 4])
+    requests = [SimpleNamespace(rid=i, due=0.002 * i, prompt=p,
+                                max_new_tokens=6 + i)
+                for i, p in enumerate(prompts)]
+    sched = fam.sched()
+    sched.submit(Request(10 ** 9, prompts[0], 2))       # the warm-up
+    sched.run_until_drained()
+    tr = tracing.Tracer(capacity=1 << 12)
+    with tracing.TracingScope(tr) if traced else nullcontext():
+        serve.drive(sched, requests, 0.25, log=lambda *_: None)
+    done = {c.rid: c for c in sched.completed if c.rid < 10 ** 9}
+    return sched, done, tr.spans()
+
+
+@pytest.fixture(scope="module")
+def traced_drive(fam):
+    return _drive_traced(fam)
+
+
+def _end(span):
+    return span["ts"] + span["dur_us"] / 1e6
+
+
+def test_every_decode_step_says_where_the_hosts_time_went(traced_drive):
+    """``prep_us`` (before the span opened), then ``upload_us`` and
+    ``enqueue_us``, whose sum is ``dispatch_us`` to the rounding, then
+    ``wait_us``: on every span of a busy stretch, and they fit into it."""
+    _, _, spans = traced_drive
+    steps = [s for s in spans if s["name"] == "serve.decode_step"]
+    assert len(steps) >= 11            # the longest answer alone
+    for s in steps:
+        at = s["attrs"]
+        assert all(at[k] >= 0 for k in LAUNCH_ATTRS + ("prep_us",))
+        assert abs(at["upload_us"] + at["enqueue_us"]
+                   - at["dispatch_us"]) <= 2
+        assert at["dispatch_us"] + at["wait_us"] <= s["dur_us"] + 2
+    launched = [s["attrs"] for s in steps if s["attrs"]["in_flight"]]
+    assert all(at["upload_us"] > 0 and at["enqueue_us"] > 0
+               for at in launched)
+    # a span that only reads launches nothing
+    last = max(steps, key=lambda s: s["ts"])["attrs"]
+    assert last["in_flight"] == 0 and last["dispatch_us"] <= 2
+
+
+def test_the_spans_tile_a_period_up_to_the_callers_loop(traced_drive):
+    """From one iteration's start to the next: the step's span, its
+    ``serve.emit``, the ``serve.admit`` pass and the next step's
+    ``prep_us``.  They never overlap, so they never exceed the period,
+    and what they leave is the caller's loop."""
+    _, _, spans = traced_drive
+    steps = sorted((s for s in spans if s["name"] == "serve.decode_step"),
+                   key=lambda s: s["ts"])
+    others = [s for s in spans if s["name"] in ("serve.emit", "serve.admit")]
+    periods = parts = 0.0
+    for a, b in zip(steps, steps[1:]):
+        if not (a["attrs"]["in_flight"] and b["attrs"]["in_flight"]):
+            continue
+        period = (b["ts"] - a["ts"]) * 1e6
+        covered = a["dur_us"] + b["attrs"]["prep_us"] + sum(
+            s["dur_us"] for s in others if a["ts"] <= s["ts"] < b["ts"])
+        assert covered <= period + 200      # clocks differ by microseconds
+        periods += period
+        parts += covered
+    assert periods > 0 and parts >= 0.5 * periods
+
+
+def test_every_prefill_says_where_the_hosts_time_went(traced_drive):
+    sched, _, spans = traced_drive
+    prefills = sorted((s for s in spans if s["name"] == "serve.prefill"),
+                      key=lambda s: s["ts"])
+    assert len(prefills) == 6
+    for s in prefills:
+        at = s["attrs"]
+        assert all(at[k] >= 0 for k in LAUNCH_ATTRS)
+        assert abs(at["upload_us"] + at["enqueue_us"]
+                   - at["dispatch_us"]) <= 2
+        assert at["dispatch_us"] + at["wait_us"] <= s["dur_us"] + 2
+        assert at["behind_step"] in (0, 1)
+        assert at["tokens"] <= at["padded_tokens"] in (8, 16)
+    # the first finds an empty server; one admitted mid-flight queues
+    # on the device behind the step that was launched before it
+    assert prefills[0]["attrs"]["behind_step"] == 0
+    assert any(s["attrs"]["behind_step"] for s in prefills)
+
+
+def test_the_stats_say_how_long_the_loop_waited(fam):
+    """``device_wait_s`` and ``loop_host_s`` are kept with no tracer
+    installed, and together they are time spent inside the loop."""
+    import time
+
+    prompts = _prompts(14, [5, 8, 3])
+    sched = fam.sched()
+    t0 = time.perf_counter()
+    for rid, p in enumerate(prompts):
+        sched.submit(Request(rid, p, 7))
+    sched.run_until_drained()
+    wall = time.perf_counter() - t0
+    wait, host = sched.stats["device_wait_s"], sched.stats["loop_host_s"]
+    assert isinstance(wait, float) and isinstance(host, float)
+    assert wait > 0 and host > 0 and wait + host <= wall
+    for _ in range(5):                  # an empty server waits for nothing
+        assert not sched.step()
+    assert sched.stats["device_wait_s"] == wait
+    assert sched.stats["loop_host_s"] > host
+
+
+def test_an_empty_server_is_one_span_a_stretch(fam):
+    """``serve.idle``: one span a stretch of ``step()`` calls that found
+    nothing resident, queued or in flight, recorded when work arrives,
+    with the calls counted; none a call, and none while a request is in
+    the server."""
+    (prompt,) = _prompts(15, [5])
+    sched = fam.sched()
+    with tracing.TracingScope() as tr:
+        for _ in range(10):
+            assert not sched.step()
+        after_10 = len(tr.spans())
+        for _ in range(10_000):
+            sched.step()
+        assert len(tr.spans()) == after_10 == 0
+        sched.submit(Request(0, prompt, 4))
+        assert sched.step()
+        (idle,) = [s for s in tr.spans() if s["name"] == "serve.idle"]
+        assert idle["attrs"]["polls"] == 10_010 and idle["parent"] is None
+        sched.run_until_drained()
+        for _ in range(5):
+            assert not sched.step()
+        sched.submit(Request(1, prompt, 3))
+        sched.run_until_drained()
+    spans = tr.spans()
+    idles = [s for s in spans if s["name"] == "serve.idle"]
+    assert [s["attrs"]["polls"] for s in idles] == [10_010, 5]
+    # nothing the server did lies inside a stretch it called empty
+    work = [s for s in spans if s["name"] in (
+        "serve.admit", "serve.prefill", "serve.decode_step", "serve.emit")]
+    assert work and not any(i["ts"] <= w["ts"] < _end(i)
+                            for i in idles for w in work)
+    fam.check(sched.completed)
+
+
+def test_streams_are_the_same_with_the_tracer_on_and_off(fam, traced_drive):
+    _, on, _ = traced_drive
+    sched, off, spans = _drive_traced(fam, traced=False)
+    assert not spans and sorted(on) == sorted(off) == list(range(6))
+    assert all(on[rid].tokens == off[rid].tokens for rid in on)
+    fam.check(off.values())
+    assert sched.stats["device_wait_s"] > 0

@@ -140,7 +140,9 @@ class TestMetricsRegistry:
         payload = json.loads(records[-1].getMessage().split(" ", 1)[1])
         assert payload == {"a": 1, "run_id": "corr", "step": 5}
 
-    def test_scrape_is_a_consistent_snapshot_under_hammer(self):
+    @pytest.mark.parametrize("batch", [1, 7], ids=["observe",
+                                                    "observe_many"])
+    def test_scrape_is_a_consistent_snapshot_under_hammer(self, batch):
         """Two-thread hammer for the torn-scrape race: a writer thread
         (the watchdog shape) observes a CONSTANT value into a
         histogram and bumps a counter while the main thread scrapes.
@@ -165,7 +167,10 @@ class TestMetricsRegistry:
 
         def writer():
             while not stop.is_set():
-                h.observe(1.0)
+                if batch == 1:
+                    h.observe(1.0)
+                else:
+                    h.observe_many([1.0] * batch)
                 c.inc()
                 reg.gauge(f"apex_g_{threading.get_ident() % 7}").set(1)
 
@@ -182,6 +187,7 @@ class TestMetricsRegistry:
                 count = val(r"apex_hammer_seconds_count")
                 if count is None:
                     continue  # scrape ran before the first observe
+                assert count % batch == 0, txt
                 # torn scrape: the cumulative buckets, the +Inf
                 # bucket, _sum and _count disagree with each other
                 assert val(
@@ -194,6 +200,39 @@ class TestMetricsRegistry:
             stop.set()
             t.join()
             sys.setswitchinterval(prev_switch)
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_observe_many_is_observe_a_value(self, seed):
+        """Bucket counts, ``sum``, ``count`` and the Prometheus text of
+        one ``observe_many`` a batch EQUAL those of ``observe`` a value
+        in the same order (values on a bucket's bound, beyond the last,
+        and a label a series); the batch's one exemplar carries its
+        largest value."""
+        rng = np.random.RandomState(seed)
+        bounds = metrics.DEFAULT_BUCKETS
+        batches = [list(rng.lognormal(-5.0, 2.0, size=n))
+                   + [bounds[int(rng.randint(len(bounds)))], 1e4]
+                   for n in rng.randint(1, 130, size=9)] + [[]]
+        regs = metrics.MetricsRegistry(), metrics.MetricsRegistry()
+        one, many = (r.histogram("apex_serve_inter_token_seconds",
+                                 "previous token -> this token",
+                                 ("lane",)) for r in regs)
+        for k, batch in enumerate(batches):
+            lane = ("interactive", "best_effort")[k % 2]
+            for v in batch:
+                one.observe(v, lane=lane)
+            many.observe_many(batch, exemplar={"rid": k}, lane=lane)
+        assert list(one.samples()) == list(many.samples())
+        assert regs[0].prometheus_text() == regs[1].prometheus_text()
+        assert not one.drain_exemplars()
+        got = {ex["rid"]: ex["value"] for _, ex in many.drain_exemplars()}
+        assert got == {k: max(b) for k, b in enumerate(batches) if b}
+        # the module helper, on the current registry
+        with metrics.MetricsScope() as reg:
+            metrics.observe_many("apex_x_seconds", batches[0], lane="a")
+            h = reg.histogram("apex_x_seconds", labelnames=("lane",))
+            assert dict((n, v) for n, _, v in h.samples())[
+                "apex_x_seconds_count"] == len(batches[0])
 
     def test_nvtx_range_suffix(self):
         from apex_tpu.utils.profiler import nvtx_range
@@ -629,3 +668,93 @@ class TestServingMetrics:
             # drained: gauges read empty
             assert reg.gauge("apex_serve_queue_depth").value() == 0
             assert reg.gauge("apex_serve_active_slots").value() == 0
+
+    def _tiny(self, max_batch=2):
+        from apex_tpu.inference import (
+            ContinuousBatchingScheduler, DecodeConfig, KVCacheConfig,
+        )
+
+        cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
+                        num_attention_heads=4, max_seq_len=64,
+                        position_embedding_type="rope",
+                        compute_dtype=jnp.float32, checkpoint_layers=False)
+        dcfg = DecodeConfig(
+            cache=KVCacheConfig(num_pages=20, page_size=4,
+                                pages_per_seq=4, dtype=jnp.float32),
+            max_batch=max_batch, max_prompt_len=8, temperature=0.0,
+            attn_impl="xla", sample_impl="xla",
+            sample_dot_dtype=jnp.float32)
+        return ContinuousBatchingScheduler(
+            init_params(cfg, jax.random.PRNGKey(0)), cfg, dcfg)
+
+    def test_a_scope_entered_after_the_scheduler_was_built_gets_the_gauges(
+            self):
+        """The occupancy gauges' children are resolved once a REGISTRY:
+        a scope entered later, and the registry behind it once it is
+        left, each receive what is recorded while they are current."""
+        from apex_tpu.inference import Request
+
+        with metrics.MetricsScope() as first:
+            sched = self._tiny()
+            sched.submit(Request(rid=0, prompt=[1, 2, 3],
+                                 max_new_tokens=4))
+            assert first.gauge("apex_serve_queue_depth").value() == 1
+            with metrics.MetricsScope() as second:
+                sched.step()                # admit + prefill
+                sched.step()                # launch: occupancy recorded
+                sched.step()
+                assert second.gauge("apex_serve_active_slots").value() == 1
+                assert second.gauge("apex_serve_queue_depth").value() == 0
+                assert second.gauge("apex_serve_lane_queue_depth",
+                                    labelnames=("lane",)).value(
+                                        lane="interactive") == 0
+                free = second.gauge("apex_serve_free_pages").value()
+                assert 0 < free < 19
+            # the first registry saw none of it ...
+            assert first.gauge("apex_serve_active_slots").value() == 0
+            sched.run_until_drained()
+            # ... and is written again once it is current
+            assert first.gauge("apex_serve_free_pages").value() == 19
+            assert first.gauge("apex_serve_active_slots").value() == 0
+            assert second.gauge("apex_serve_active_slots").value() == 1
+
+    def test_a_clash_in_the_callers_registry_does_not_stop_the_server(self):
+        from apex_tpu.inference import Request
+
+        with metrics.MetricsScope() as reg:
+            reg.counter("apex_serve_queue_depth")   # the name, another kind
+            sched = self._tiny()
+            sched.submit(Request(rid=0, prompt=[1, 2, 3],
+                                 max_new_tokens=3))
+            (done,) = sched.run_until_drained()
+            assert len(done.tokens) == 3
+            assert reg.counter("apex_serve_completions_total").value() == 1
+
+    def test_the_inter_token_exemplar_of_a_step_is_its_largest_gaps(self):
+        """One ``observe_many`` a lane a step: a step's exemplar is the
+        request whose gap was the largest, with that gap as its value."""
+        from apex_tpu.inference import Request
+
+        with metrics.MetricsScope() as reg:
+            sched = self._tiny(max_batch=3)
+            for rid in range(3):
+                sched.submit(Request(rid=rid, prompt=[1 + rid, 2, 3],
+                                     max_new_tokens=6))
+            for _ in range(3):
+                sched.step()
+            hist = reg.histogram("apex_serve_inter_token_seconds",
+                                 labelnames=("lane",))
+            hist.drain_exemplars()
+            steps = sched.stats["decode_steps"]
+            # request 1's last token, as if it had come 5 s earlier
+            slot = next(s for s in sched._slots if s.request.rid == 1)
+            slot.token_times[-1] -= 5.0
+            sched.step()
+            assert sched.stats["decode_steps"] == steps + 1
+            ((labels, ex),) = hist.drain_exemplars()
+            assert labels == {"lane": "interactive"}
+            assert ex["rid"] == 1 and ex["trace_id"] == slot.request.trace_id
+            assert 5.0 < ex["value"] < 6.0
+            count = {n: v for n, _, v in hist.samples()}[
+                "apex_serve_inter_token_seconds_count"]
+            assert count == 3 * sched.stats["decode_steps"]
