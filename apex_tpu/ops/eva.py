@@ -204,9 +204,10 @@ def eva_summarise(k_pool, v_pool, phi, mu, pages, first, closing, layer,
 
 
 # --------------------------------------------------------------- the prompt
-#: the flash forward's key block in :func:`eva_window_attention`; a
-#: prompt's pooled pairs are held in a buffer of whole blocks of it
-#: (:func:`pooled_capacity`)
+#: a prompt's pooled pairs are held in a buffer of whole blocks of this
+#: many rows (:func:`pooled_capacity`): the flash forward's sub-tile at
+#: the window's shape, so that buffer and window are whole sub-tiles of
+#: the ONE key block :func:`eva_window_attention` asks for
 PREFILL_BLOCK_K = 512
 
 
@@ -256,7 +257,10 @@ def _window_pallas(q, k, v, kt, vt, seen, interpret=False):
                          MASKED).astype(jnp.float32)[None, None, :]
     out, _ = flash_fwd_pallas(
         heads_first(q), heads_first(k), heads_first(v), 1.0 / np.sqrt(D),
-        True, 0, -pooled, block_k=min(PREFILL_BLOCK_K, W) if pooled else None,
+        True, 0, -pooled,
+        # ONE key block over buffer and window: no softmax state carried
+        # between grid steps (key blocks of 512 took 2.8 times as long)
+        block_k=pooled + W if pooled else None,
         interpret=interpret, kv_bias=bias, heads=H)
     return heads_first(out)
 

@@ -2,10 +2,8 @@
 
 Reference: ``apex/contrib/fmha`` (CUDA flash-style fused MHA, seqlen
 ≤512) and ``apex/contrib/multihead_attn`` fused attention.  TPU
-redesign: one VMEM-resident online-softmax kernel — the (bq, bk) score
-tile never touches HBM, running max/sum live in VMEM scratch across the
-sequential k-block grid steps, and the causal upper triangle is skipped
-block-wholesale via ``pl.when`` on grid indices.
+redesign: one VMEM-resident online-softmax kernel — a score tile never
+touches HBM, running max/sum live in f32 across the key walk.
 
 Three kernels, the standard flash decomposition:
 
@@ -16,6 +14,48 @@ Three kernels, the standard flash decomposition:
   accumulates ``dq`` in scratch.
 - dk/dv backward: grid ``(batch·heads, k_blocks, q_blocks)`` (k outer),
   accumulates ``dk``/``dv`` in scratch.
+
+**The causal walk is inside the kernels.**  Grid blocks stay large (a
+grid step costs ~0.35 µs, as much as the work a small block would
+skip), and a block is walked in square sub-tiles of ``sub`` rows and
+columns (:data:`~apex_tpu.ops._pallas_tiling.SUBTILE`, or the tuned
+row's): a strip of query rows over its key sub-tiles in ascending
+order (forward, dq), a strip of keys over its query sub-tiles (dkv,
+which holds its tiles transposed, keys by queries, so that neither
+product into dk or dv transposes a tile).  Where the diagonal crosses a
+block is a function of one integer, the block's first query row less
+its first key column, and the values it takes over a call's grid are
+known when the kernel is traced (``q_offset``, ``k_offset`` and the
+sizes are static).  So the walk is straight-line code: one static
+variant a distinct position of the diagonal, chosen on the device by
+``pl.when`` on the grid indices (a grid of one block has one variant
+and no test), and in it three classes of sub-tile
+(:func:`live_subtiles` counts them from the same plans the code is
+built from):
+
+1. wholly above the diagonal: not visited;
+2. wholly under it: computed with no ``iota`` and no ``where``;
+3. crossed by it: one ``where`` against a constant index difference.
+
+**The code the walk emits is budgeted.**  Straight-line code grows with
+what it visits, and a kernel's code is paid for once a compiled PROGRAM
+before anything runs: traced and lowered at every start (no compile
+cache saves that), compiled, serialized, loaded.  So a strip's class-2
+sub-tiles go in *runs* (:func:`_pieces`): one product over the run's
+columns and ONE softmax update, at most ``RUN_COLUMNS`` wide, and a
+strip is a body or two and not a body a sub-tile.  :func:`live_subtiles`
+reports the *bodies* beside the sub-tiles; the tests hold them, and the
+serialized module, under ceilings at the cells' shapes.
+
+A non-causal call is the same code with every sub-tile in class 2, a
+ring chunk wholly under the diagonal likewise, one wholly above it a
+variant with no code.  A key bias hides columns by DATA and never
+removes a sub-tile.  Rows that see no key at all (ring warm-up chunks,
+a batch row whose keys are all padding) keep ``lse = NEG_INF`` and a
+zero output: the forward zeroes them where it finalises, the backward
+kernels turn their ``lse`` to ``+|NEG_INF|`` so every weight is 0.
+Sums run in the order of the pieces, keys (dkv: queries) ascending; a
+run is one term of that sum.
 
 ``delta = rowsum(dout · out)`` is precomputed by XLA (it fuses into the
 preceding op).  ``q_offset``/``k_offset`` place the local blocks in the
@@ -34,7 +74,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops._pallas_tiling import LANES as _LANES
+from apex_tpu.ops._pallas_tiling import SUBTILE as _SUBTILE
 from apex_tpu.ops._pallas_tiling import VMEM_BUDGET as _VMEM_BUDGET
+from apex_tpu.ops._pallas_tiling import flash_run_cap as run_cap
+from apex_tpu.ops._pallas_tiling import flash_subtile as _flash_subtile
 from apex_tpu.ops._pallas_tiling import flash_vmem_bytes as _flash_vmem_bytes
 from apex_tpu.ops._pallas_tiling import sublane as _sublane
 
@@ -53,24 +96,35 @@ _DIM_SEMANTICS = pltpu.CompilerParams(
 
 # ------------------------------------------------------------ block tuning
 # Measured per-shape block targets, keyed (seq_q, head_dim, dtype name,
-# phase) -> (block_q, block_k), phase ∈ {"fwd", "bwd"}.  The phases have
-# different VMEM envelopes — the backward kernels keep ~4 (bq, bk) f32
-# score temporaries live vs the forward's 2 — so one (bq, bk) cannot
-# serve both.  Populated from benchmarks/flash_sweep.py runs on real
-# hardware (benchmarks/install_tuned_blocks.py records the provenance
-# in a comment at the table's head); consulted by the fwd/bwd entry
-# points when the caller passes no explicit blocks, before the
-# _pick_block static heuristic.
+# phase) -> (block_q, block_k[, sub]), phase ∈ {"fwd", "bwd"}; ``sub``
+# is the side of the in-kernel sub-tile (absent: _SUBTILE).  The phases
+# have different VMEM envelopes and different work a sub-tile, so one
+# row cannot serve both.  Populated from benchmarks/flash_sweep.py runs
+# on real hardware (benchmarks/install_tuned_blocks.py records the
+# provenance in a comment at the table's head); consulted by the
+# fwd/bwd entry points when the caller passes no explicit blocks,
+# before the _pick_block static heuristic.
 # Legacy 3-tuple (seq_q, head_dim, dtype) keys are read as fwd-only.
-_TUNED_BLOCKS: dict = {}
+_TUNED_BLOCKS: dict = {
+    # measured: TPU v5 lite (v5e), one chip, 2026-09-30: kernels timed alone, 50 calls chained a program (benchmarks/flash_sweep.py); rows of PR 36's sweep, read again with runs in PR 37's;
+    # (2048, 128): sub-tile 512 (0.299 ms, 8 bodies) over 256 (0.284 ms, 18 bodies): the EVA window's programs pay for code eight times (PERF.md, PR 37)
+    (512, 192, 'bfloat16', 'fwd'): (512, 512, 256),
+    (1024, 64, 'bfloat16', 'bwd'): (1024, 1024, 256),
+    (1024, 64, 'bfloat16', 'fwd'): (1024, 1024, 256),
+    (1024, 192, 'bfloat16', 'fwd'): (1024, 1024, 256),
+    (2048, 128, 'bfloat16', 'fwd'): (2048, 2048, 512),
+    (2048, 192, 'bfloat16', 'fwd'): (1024, 2048, 256),
+    (4096, 192, 'bfloat16', 'fwd'): (2048, 512, 512),
+}
 
 _PHASES = ("fwd", "bwd")
 
+#: block targets of a shape with no measured row (the backward holds
+#: more blocks a grid step; unmeasured shapes keep the grid they had)
+DEFAULT_BLOCK = {"fwd": 1024, "bwd": 512}
 
-def tuned_blocks(seq_q, head_dim, dtype, phase="fwd"):
-    """(block_q, block_k) measured best for this shape and phase, or
-    None.  ``phase="fwd"`` also reads legacy 3-tuple entries (tables
-    installed before the per-phase split are forward measurements)."""
+
+def _tuned_row(seq_q, head_dim, dtype, phase):
     if phase not in _PHASES:
         raise ValueError(f"phase must be one of {_PHASES}, got {phase!r}")
     key = (int(seq_q), int(head_dim), jnp.dtype(dtype).name)
@@ -80,10 +134,25 @@ def tuned_blocks(seq_q, head_dim, dtype, phase="fwd"):
     return hit
 
 
+def tuned_blocks(seq_q, head_dim, dtype, phase="fwd"):
+    """(block_q, block_k) measured best for this shape and phase, or
+    None.  ``phase="fwd"`` also reads legacy 3-tuple entries (tables
+    installed before the per-phase split are forward measurements)."""
+    hit = _tuned_row(seq_q, head_dim, dtype, phase)
+    return None if hit is None else tuple(hit[:2])
+
+
+def tuned_subtile(seq_q, head_dim, dtype, phase="fwd"):
+    """The sub-tile side the shape's tuned row names, or None (no row,
+    or a row of blocks alone: the kernels' ``SUBTILE`` then)."""
+    hit = _tuned_row(seq_q, head_dim, dtype, phase)
+    return hit[2] if hit is not None and len(hit) > 2 else None
+
+
 def set_tuned_blocks(table) -> None:
     """Install sweep-measured block targets: ``{(S, D, dtype[, phase]):
-    (bq, bk)}`` or an iterable of ``[[S, D, dtype[, phase]], [bq, bk]]``
-    pairs (the exact JSON flash_sweep.py prints as
+    (bq, bk[, sub])}`` or an iterable of ``[[S, D, dtype[, phase]],
+    [bq, bk[, sub]]]`` pairs (the exact JSON flash_sweep.py prints as
     ``tuned_blocks_table``).  Three-element keys — the pre-per-phase
     format — install as ``"fwd"`` entries: old sweeps measured the
     forward dispatcher's path.  The dtype key is normalized through
@@ -98,9 +167,8 @@ def set_tuned_blocks(table) -> None:
         if phase not in _PHASES:
             raise ValueError(
                 f"tuned-block phase must be one of {_PHASES}, got {phase!r}")
-        bq, bk = val
         _TUNED_BLOCKS[(int(s), int(d), jnp.dtype(name).name, str(phase))] = (
-            int(bq), int(bk))
+            tuple(int(x) for x in val))
 
 
 def _pick_block(seq, target, align=_LANES, fits=None):
@@ -130,71 +198,302 @@ def _pick_block(seq, target, align=_LANES, fits=None):
     return best
 
 
-def _causal_mask(bq, bk, qi, kj, block_q, block_k, q_offset, k_offset):
-    row = q_offset + qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    col = k_offset + kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return row >= col
+# ------------------------------------------------------- the causal walk
+# Where the diagonal crosses a block is a function of ONE integer, the
+# block's first query row less its first key column, and every value
+# it takes over a call's grid is known when the kernel is traced.  So
+# nothing about the walk is decided on the device but which of a few
+# static variants of the block's code runs (`_block_variants`): the
+# sub-tiles a strip visits, and which of them pay a mask, are Python
+# integers, and the code inside a block is straight-line.
+
+def _clip(x, lo, hi):
+    return min(max(x, lo), hi)
+
+
+def _key_bounds(causal, gap, sub_q, sub_k, n):
+    """For a strip of ``sub_q`` query rows over the ``n`` key sub-tiles
+    of ``sub_k`` columns of a block, ``gap`` = the strip's first global
+    row less the block's first global column: ``(full, seen)``.
+    Sub-tiles ``[0, full)`` lie wholly under the diagonal, ``[full,
+    seen)`` are crossed by it, ``[seen, n)`` lie wholly above it."""
+    if not causal:
+        return n, n
+    full = _clip((gap + 1) // sub_k, 0, n)
+    seen = _clip((gap + sub_q - 1) // sub_k + 1, 0, n)
+    return full, seen
+
+
+def _query_bounds(causal, lead, sub_k, sub_q, n):
+    """For a strip of ``sub_k`` keys over the ``n`` query sub-tiles of
+    ``sub_q`` rows of a block, ``lead`` = the strip's first global
+    column less the block's first global row: ``(first, full)``.
+    Sub-tiles ``[0, first)`` lie wholly above the diagonal, ``[first,
+    full)`` are crossed by it, ``[full, n)`` lie wholly under it."""
+    if not causal:
+        return 0, 0
+    first = _clip(lead // sub_q, 0, n)
+    full = _clip(-((-lead - sub_k + 1) // sub_q), 0, n)
+    return first, full
+
+
+def _strip_plan(phase, causal, d, bq, bk, sub_q, sub_k):
+    """The bounds of every strip of a block whose first query row lies
+    ``d`` past its first key column: by query strip ``(full, seen)``
+    (forward, dq), by key strip ``(first, full)`` (``"dkv"``)."""
+    if phase == "dkv":
+        return tuple(_query_bounds(causal, c * sub_k - d, sub_k, sub_q,
+                                   bq // sub_q) for c in range(bk // sub_k))
+    return tuple(_key_bounds(causal, d + r * sub_q, sub_q, sub_k,
+                             bk // sub_k) for r in range(bq // sub_q))
+
+
+def _block_distances(q_offset, k_offset, bq, bk, nq, nk):
+    """First query row less first key column, of every grid block."""
+    return [q_offset - k_offset + i * bq - j * bk
+            for i in range(nq) for j in range(nk)]
+
+
+def _block_variants(phase, causal, q_offset, k_offset, bq, bk, nq, nk,
+                    sub_q, sub_k):
+    """``[(plan, d_min, d_max)]``: the distinct strip plans among the
+    grid's blocks.  A plan only grows with ``d``, so the blocks that
+    share one are an interval of ``d``; a plan with sub-tiles the
+    diagonal crosses belongs to ONE ``d`` (the mask needs the exact
+    distance), ``d_min == d_max``."""
+    spans = {}
+    for d in _block_distances(q_offset, k_offset, bq, bk, nq, nk):
+        plan = _strip_plan(phase, causal, d, bq, bk, sub_q, sub_k)
+        crossed = _visited(phase, plan, bq // sub_q)[1] > 0
+        key = (plan, d if crossed else None)
+        lo, hi = spans.get(key, (d, d))
+        spans[key] = (min(lo, d), max(hi, d))
+    return sorted(((plan, lo, hi) for (plan, _), (lo, hi) in spans.items()),
+                  key=lambda v: v[1])
+
+
+def _visited(phase, plan, n):
+    """(visited, masked) sub-tiles of a block with this plan."""
+    if phase == "dkv":
+        return (sum(n - first for first, _ in plan),
+                sum(full - first for first, full in plan))
+    return (sum(seen for _, seen in plan),
+            sum(seen - full for full, seen in plan))
+
+
+def _pieces(phase, bounds, n, run):
+    """What a strip with these bounds computes, in the order it sums:
+    ``[(t0, t1, masked)]``, each piece ONE copy of the tile arithmetic
+    (a *body*) over sub-tiles ``[t0, t1)``.  The sub-tiles the diagonal
+    does not touch go in runs of at most ``run``, one product and one
+    update a run whatever its length; a sub-tile it crosses is a piece
+    of its own (the mask is that sub-tile's alone)."""
+    if phase == "dkv":
+        first, full = bounds
+        crossed, plain = range(first, full), (full, n)
+    else:
+        full, seen = bounds
+        crossed, plain = range(full, seen), (0, full)
+    runs = [(t, min(t + run, plain[1]), False)
+            for t in range(plain[0], plain[1], run)]
+    alone = [(t, t + 1, True) for t in crossed]
+    return alone + runs if phase == "dkv" else runs + alone
+
+
+def live_subtiles(phase, Sq, Sk, q_offset, k_offset, bq, bk, sub,
+                  causal=True, run=None):
+    """``(visited, masked, skipped, bodies)``: the sub-tiles one head's
+    call computes, those of them the diagonal crosses (the only ones
+    that pay a mask), and those never visited, summed over the grid
+    blocks; and the *bodies*, the copies of the tile arithmetic the
+    kernel's CODE holds (a run of unmasked sub-tiles is one, a crossed
+    sub-tile one; summed over the static variants, not the blocks: it
+    is what the kernel costs to lower, compile and load, once a
+    compiled program).  All from the strip plans the kernels' code is
+    built from (``"fwd"`` and the dq kernel walk keys by query strip,
+    ``"dkv"`` queries by key strip; ``"bwd"`` counts as dq).
+    ``sub=None``: a block is one tile; ``run``: sub-tiles a run at
+    most (default: :func:`run_cap` of the sub-tile).  A key bias hides
+    columns by data and changes none of the four."""
+    sub_q, sub_k = (sub, sub) if sub else (bq, bk)
+    run = run or run_cap(sub)
+    nq, nk, n = Sq // bq, Sk // bk, bq // sub_q
+    total = (Sq // sub_q) * (Sk // sub_k)
+    visited = masked = 0
+    for d in _block_distances(q_offset, k_offset, bq, bk, nq, nk):
+        plan = _strip_plan(phase, causal, d, bq, bk, sub_q, sub_k)
+        v, m = _visited(phase, plan, n)
+        visited, masked = visited + v, masked + m
+    n_walk = n if phase == "dkv" else bk // sub_k
+    bodies = sum(len(_pieces(phase, bounds, n_walk, run))
+                 for plan, _, _ in _block_variants(
+                     phase, causal, q_offset, k_offset, bq, bk, nq, nk,
+                     sub_q, sub_k)
+                 for bounds in plan)
+    return visited, masked, total - visited, bodies
+
+
+def _for_this_block(phase, causal, q_offset, k_offset, bq, bk, nq, nk,
+                    sub_q, sub_k, i, j, always, body):
+    """``body(plan, d)`` for the block at grid position ``(i, j)``:
+    traced indices choose among the static variants by ``pl.when`` on
+    the one integer that tells them apart (``d`` is exact where the
+    plan has masked sub-tiles); a variant with nothing to visit is no
+    code at all, unless ``always`` (a block that has to write its
+    outputs whatever it sees)."""
+    d = q_offset - k_offset + i * bq - j * bk
+    variants = _block_variants(phase, causal, q_offset, k_offset, bq, bk,
+                               nq, nk, sub_q, sub_k)
+    for plan, lo, hi in variants:
+        if not (always or _visited(phase, plan, bq // sub_q)[0]):
+            continue
+        if len(variants) == 1:
+            body(plan, lo)
+        else:
+            conds = ([d >= lo] if lo > variants[0][1] else []) + (
+                [d <= hi] if hi < variants[-1][2] else [])
+            pl.when(functools.reduce(jnp.logical_and, conds))(
+                functools.partial(body, plan, lo))
+
+
+def _tile(t, size, until=None):
+    """Rows (or columns) of sub-tile ``t``, or of sub-tiles ``[t,
+    until)``."""
+    return slice(t * size, (t + 1 if until is None else until) * size)
+
+
+def _grid_index(axis, n):
+    """The grid index, or the integer 0 where the axis has one block."""
+    return 0 if n == 1 else pl.program_id(axis)
+
+
+def _visible(rows, cols, first_key_less_first_query, transposed=False):
+    """The causal mask of a piece the diagonal crosses: query index ≥
+    key index, as ONE compare of the index difference inside the piece
+    against a constant.  ``transposed``: keys (sublanes) by queries."""
+    along = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    down = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    diff = along - down if transposed else down - along
+    return diff >= first_key_less_first_query
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))   # a · bᵀ
+_NN = ((1,), (0,))   # a · b
+
+
+def _dead_rows_off(lse):
+    """Rows no key reaches have ``lse = NEG_INF``: ``exp(s − lse)``
+    would read 1 where ``s`` is a hidden key's ``NEG_INF`` too.  Turned
+    to ``+|NEG_INF|`` every weight of such a row is exactly 0."""
+    return jnp.where(lse > NEG_INF / 2, lse, -lse)
 
 
 # ------------------------------------------------------------------ forward
 def _fwd_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
-                block_q, block_k, nk):
+                block_q, block_k, sub_q, sub_k, run, nq, nk):
     if has_bias:
         q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
         b_ref = None
-    i, j = pl.program_id(1), pl.program_id(2)
+    i, j = _grid_index(1, nq), _grid_index(2, nk)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # Fully-masked (above-diagonal) blocks contribute nothing.
-    diag_ok = (
-        (q_offset + (i + 1) * block_q - 1) >= (k_offset + j * block_k)
-        if causal
-        else True
-    )
-
-    @pl.when(diag_ok)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if b_ref is not None:
-            s = s + b_ref[0]  # (1, bk) key bias broadcast over rows
-        if causal:
-            mask = _causal_mask(q.shape[0], k.shape[0], i, j, block_q, block_k,
-                                q_offset, k_offset)
-            s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # exp(NEG_INF - NEG_INF) = 1 would give fully-masked rows a
-        # spurious uniform distribution; re-mask after the exp.
-        p = jnp.exp(s - m_new)
+    def finalize(rows, m, l, acc):
+        l = jnp.maximum(l, 1e-30)  # rows that saw no key (ring blocks)
+        out = acc / l
         if causal or has_bias:
-            p = jnp.where(s > NEG_INF / 2, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+            # a row whose keys were all hidden so far weighs them 1
+            # each (exp(NEG_INF - NEG_INF)); the first visible key wipes
+            # that (corr = 0), and a row that never sees one is zero
+            out = jnp.where(m > NEG_INF / 2, out, 0.0)
+        o_ref[0, rows, :] = out.astype(o_ref.dtype)
+        lse_ref[0, rows, :] = m + jnp.log(l)
 
-    @pl.when(j == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, 0:1], 1e-30)  # fully-masked rows (ring blocks)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:, 0:1] + jnp.log(l)
+    if nk > 1:
+        @pl.when(j == 0)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def strip(r, gap, full, seen):
+        """Query strip ``r``, its first row ``gap`` past the block's
+        first key: its key sub-tiles ``[0, full)`` plain, in runs,
+        ``[full, seen)`` masked."""
+        rows = _tile(r, sub_q)
+        q = q_ref[0, rows, :]
+
+        def score(c, until, masked):
+            """The scores of key sub-tiles ``[c, until)``: biased, and
+            masked where the diagonal crosses them."""
+            cols = _tile(c, sub_k, until)
+            s = _dot(q, k_ref[0, cols, :], _NT) * scale
+            if b_ref is not None:
+                s = s + b_ref[0, :, cols]  # (1, columns) key bias over rows
+            if masked:
+                s = jnp.where(_visible(sub_q, sub_k, c * sub_k - gap),
+                              s, NEG_INF)
+            return s
+
+        def update(c, until, s, carry):
+            """One online-softmax update over key sub-tiles ``[c,
+            until)``; a ``carry`` of None is a strip that has seen
+            nothing yet."""
+            m_new = jnp.max(s, axis=-1, keepdims=True)
+            if carry is not None:
+                m_prev, l_prev, acc = carry
+                m_new = jnp.maximum(m_prev, m_new)
+                corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = jnp.sum(p, axis=-1, keepdims=True)
+            pv = _dot(p.astype(v_ref.dtype),
+                      v_ref[0, _tile(c, sub_k, until), :], _NN)
+            if carry is None:
+                return m_new, l_new, pv
+            return m_new, l_prev * corr + l_new, acc * corr + pv
+
+        # one key block: the first tile starts the statistics
+        carry = (None if nk == 1 else
+                 (m_ref[rows, 0:1], l_ref[rows, 0:1], acc_ref[rows, :]))
+        # keys ascending: the plain runs, then the diagonal's sub-tiles.
+        # A piece's scores are written one piece AHEAD of its update, so
+        # that its product has no softmax to wait for (the schedule of a
+        # head at the train cell's shape: 3,791 bundles -> 3,441)
+        pieces = _pieces("fwd", (full, seen), block_k // sub_k, run)
+        ahead = score(*pieces[0]) if pieces else None
+        for n, (c, until, _) in enumerate(pieces):
+            s, ahead = ahead, (score(*pieces[n + 1])
+                               if n + 1 < len(pieces) else None)
+            carry = update(c, until, s, carry)
+        if carry is None:  # a strip no key of the call reaches
+            carry = (jnp.full((sub_q, 1), NEG_INF, jnp.float32),
+                     jnp.zeros((sub_q, 1), jnp.float32),
+                     jnp.zeros((sub_q, acc_ref.shape[1]), jnp.float32))
+        if nk == 1:
+            finalize(rows, *carry)
+        else:
+            m, l, acc = carry
+            m_ref[rows, :] = jnp.broadcast_to(m, (sub_q, m_ref.shape[1]))
+            l_ref[rows, :] = jnp.broadcast_to(l, (sub_q, l_ref.shape[1]))
+            acc_ref[rows, :] = acc
+
+    def block(plan, d):
+        for r, (full, seen) in enumerate(plan):
+            if seen or nk == 1:  # one key block: finalised here, seen or not
+                strip(r, d + r * sub_q, full, seen)
+
+    _for_this_block("fwd", causal, q_offset, k_offset, block_q, block_k,
+                    nq, nk, sub_q, sub_k, i, j, nk == 1, block)
+
+    if nk > 1:
+        @pl.when(j == nk - 1)
+        def _finalize():
+            finalize(slice(None), m_ref[:, 0:1], l_ref[:, 0:1], acc_ref[:])
 
 
 def _kv_row(b, heads, kv_heads):
@@ -207,11 +506,12 @@ def _kv_row(b, heads, kv_heads):
     return (b // heads) * kv_heads + (b % heads) // group
 
 
-def _resolve_targets(sq, sk, d, dtype, block_q, block_k, phase, default):
+def _resolve_targets(sq, sk, d, dtype, block_q, block_k, phase):
     """Per-phase block TARGETS: explicit args win, then the phase's
     tuned entry (self-attention shapes only — a block_k tuned for
-    Sk == Sq must not leak onto cross-attention key lengths), then the
-    static default (fwd 1024 / bwd 512 — the VMEM envelopes differ)."""
+    Sk == Sq must not leak onto cross-attention key lengths), then
+    ``DEFAULT_BLOCK`` (fwd 1024 / bwd 512)."""
+    default = DEFAULT_BLOCK[phase]
     if (block_q is None or block_k is None) and sk == sq:
         tuned = tuned_blocks(sq, d, dtype, phase=phase)
         if tuned is not None:
@@ -220,21 +520,51 @@ def _resolve_targets(sq, sk, d, dtype, block_q, block_k, phase, default):
     return block_q or default, block_k or default
 
 
+def _subtile_target(sq, d, dtype, phase):
+    """The sub-tile side asked for at this shape: its tuned row's, or
+    ``SUBTILE`` (the VMEM clamp and the kernels read the same one)."""
+    return tuned_subtile(sq, d, dtype, phase=phase) or _SUBTILE
+
+
 def _clamped_blocks(sq, sk, d, dtype, block_q, block_k, phase):
     """(bq, bk) divisor blocks for the targets, jointly clamped so the
     APX304-priced footprint of the resulting pallas_call stays inside
     the VMEM budget: pick bq by preference alone, clamp bk against it,
     then re-clamp bq against the chosen bk (a no-op unless the pair
     was over budget)."""
+    target = _subtile_target(sq, d, dtype, phase)
 
     def fits(b_q, b_k):
-        return _flash_vmem_bytes(b_q, b_k, d, phase) <= _VMEM_BUDGET
+        return _flash_vmem_bytes(
+            b_q, b_k, d, phase,
+            sub=_flash_subtile(b_q, b_k, target)) <= _VMEM_BUDGET
 
     bq = _pick_block(sq, block_q, align=_sublane(dtype))
     bk = _pick_block(sk, block_k, fits=lambda b: fits(bq, b))
     bq = _pick_block(sq, block_q, align=_sublane(dtype),
                      fits=lambda b: fits(b, bk))
     return bq, bk
+
+
+def _subtiles(sq, d, dtype, phase, bq, bk):
+    """(sub_q, sub_k, run) the kernels walk a ``(bq, bk)`` block in:
+    squares of the shape's tuned sub-tile (whatever the key length: it
+    only has to divide the blocks) or of ``SUBTILE``, brought down to a
+    lane-tile multiple that divides both, and the sub-tiles a run of
+    them at most (:func:`run_cap`); a block with none is one tile."""
+    sub = _flash_subtile(bq, bk, _subtile_target(sq, d, dtype, phase))
+    return (sub, sub, run_cap(sub)) if sub else (bq, bk, 1)
+
+
+def dispatched(sq, sk, d, dtype, phase, block_q=None, block_k=None):
+    """``(bq, bk, (sub_q, sub_k, run))``: the grid blocks, the
+    sub-tiles and the run cap a call of this shape and phase is built
+    with, given the caller's block targets (both launchers' own
+    resolution; the static tests read the cells' shapes through it)."""
+    bq, bk = _clamped_blocks(
+        sq, sk, d, dtype,
+        *_resolve_targets(sq, sk, d, dtype, block_q, block_k, phase), phase)
+    return bq, bk, _subtiles(sq, d, dtype, phase, bq, bk)
 
 
 def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
@@ -259,14 +589,12 @@ def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
     Sk = k.shape[1]
     kv_heads = kv_heads or heads
     out_dtype = out_dtype or q.dtype
-    block_q, block_k = _resolve_targets(
-        Sq, Sk, D, q.dtype, block_q, block_k, "fwd", 1024)
-    bq, bk = _clamped_blocks(Sq, Sk, D, q.dtype, block_q, block_k, "fwd")
+    bq, bk, sub = dispatched(Sq, Sk, D, q.dtype, "fwd", block_q, block_k)
     has_bias = kv_bias is not None
 
     inputs = (q, k, v) if not has_bias else (q, k, v, kv_bias)
     call = _fwd_call(BH, Sq, Sk, D, heads, kv_heads, float(scale), causal,
-                     q_offset, k_offset, bq, bk, has_bias, interpret,
+                     q_offset, k_offset, bq, bk, sub, has_bias, interpret,
                      jnp.dtype(out_dtype).name)
     # jax.disable_jit(False): pallas_call cannot bind eagerly (its bind
     # params carry a dict), so the kernel stays one jitted op even when a
@@ -278,7 +606,7 @@ def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
 
 @functools.lru_cache(maxsize=512)
 def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
-              q_offset, k_offset, bq, bk, has_bias, interpret,
+              q_offset, k_offset, bq, bk, sub, has_bias, interpret,
               out_dtype_name):
     """The fwd ``pallas_call``, memoized on its static configuration —
     every argument is static by construction (they bake into the kernel
@@ -306,7 +634,7 @@ def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, has_bias=has_bias,
             q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk,
-            nk=nk,
+            sub_q=sub[0], sub_k=sub[1], run=sub[2], nq=nq, nk=nk,
         ),
         grid=(BH, nq, nk),
         in_specs=in_specs,
@@ -333,66 +661,81 @@ def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
 
 # ----------------------------------------------------------------- backward
 def _dq_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
-               block_q, block_k, nk):
+               block_q, block_k, sub_q, sub_k, run, nq, nk):
     if has_bias:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, b_ref, dq_ref, acc_ref = refs
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = refs
         b_ref = None
-    i, j = pl.program_id(1), pl.program_id(2)
+    i, j = _grid_index(1, nq), _grid_index(2, nk)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    if nk > 1:
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    diag_ok = (
-        (q_offset + (i + 1) * block_q - 1) >= (k_offset + j * block_k)
-        if causal
-        else True
-    )
+    def strip(r, gap, full, seen):
+        rows = _tile(r, sub_q)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        lse, delta = lse_ref[0, rows, :], delta_ref[0, rows, :]
+        if has_bias:
+            lse = _dead_rows_off(lse)
 
-    @pl.when(diag_ok)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if b_ref is not None:
-            s = s + b_ref[0]
-        if causal:
-            mask = _causal_mask(q.shape[0], k.shape[0], i, j, block_q, block_k,
-                                q_offset, k_offset)
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0])
-        if causal or has_bias:  # fully-masked rows have lse == NEG_INF: exp(0) = 1
-            p = jnp.where(s > NEG_INF / 2, p, 0.0)
-        do = do_ref[0]
-        # ring passes an f32 cotangent with bf16 k/v: widen the narrower
-        # operand instead of rounding do through bf16
-        v = v_ref[0]
-        if v.dtype != do.dtype:
-            v = v.astype(do.dtype)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0])
-        acc_ref[:] += scale * jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        def tile(c, until, masked, acc):
+            cols = _tile(c, sub_k, until)
+            k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+            s = _dot(q, k, _NT) * scale
+            if b_ref is not None:
+                s = s + b_ref[0, :, cols]
+            p = jnp.exp(s - lse)
+            if masked:
+                p = jnp.where(_visible(sub_q, sub_k, c * sub_k - gap), p, 0.0)
+            # ring passes an f32 cotangent with bf16 k/v: widen the
+            # narrower operand instead of rounding do through bf16
+            if v.dtype != do.dtype:
+                v = v.astype(do.dtype)
+            ds = p * (_dot(do, v, _NT) - delta)
+            dq = _dot(ds.astype(k.dtype), k, _NN)
+            return dq if acc is None else acc + dq
 
-    @pl.when(j == nk - 1)
-    def _finalize():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        acc = None if nk == 1 else acc_ref[rows, :]
+        # keys ascending: the plain runs, then the diagonal's sub-tiles
+        for piece in _pieces("dq", (full, seen), block_k // sub_k, run):
+            acc = tile(*piece, acc)
+        if acc is None:  # a strip no key of the call reaches
+            acc = jnp.zeros((sub_q, acc_ref.shape[1]), jnp.float32)
+        if nk == 1:
+            dq_ref[0, rows, :] = (scale * acc).astype(dq_ref.dtype)
+        else:
+            acc_ref[rows, :] = acc
+
+    def block(plan, d):
+        for r, (full, seen) in enumerate(plan):
+            if seen or nk == 1:
+                strip(r, d + r * sub_q, full, seen)
+
+    _for_this_block("dq", causal, q_offset, k_offset, block_q, block_k,
+                    nq, nk, sub_q, sub_k, i, j, nk == 1, block)
+
+    if nk > 1:
+        @pl.when(j == nk - 1)
+        def _finalize():
+            dq_ref[0] = (scale * acc_ref[:]).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
-                block_q, block_k, nq, nt):
+                block_q, block_k, sub_q, sub_k, run, nq, nk, nt):
     """k-block outer; the inner dimension ``t`` walks ALL nt = g·nq
     q-blocks that attend to this kv head — for grouped-query attention
     the g q-heads of the group accumulate into the same dk/dv block
-    (i = t % nq is the q-block index within the current q head)."""
+    (i = t % nq is the q-block index within the current q head).  Inside
+    a block a strip of keys walks its query sub-tiles, rows ascending:
+    those the diagonal crosses, then those wholly under it, in runs.
+
+    Every tile is held TRANSPOSED, keys down the sublanes and queries
+    along the lanes: both products into dk and dv then contract over a
+    tile's lanes (no transpose of p or ds), and lse and delta come as
+    rows ``(BH, 1, Sq)``, the key bias as a column ``(B, Sk, 1)``."""
     if has_bias:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, b_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -400,74 +743,84 @@ def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         b_ref = None
-    j, t = pl.program_id(1), pl.program_id(2)
-    i = t % nq
+    j, t = _grid_index(1, nk), _grid_index(2, nt)
+    i = 0 if nq == 1 else t % nq
+    n_sub = block_q // sub_q
 
-    @pl.when(t == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    if nt > 1:
+        @pl.when(t == 0)
+        def _init():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    diag_ok = (
-        (q_offset + (i + 1) * block_q - 1) >= (k_offset + j * block_k)
-        if causal
-        else True
-    )
+    def strip(c, lead, first, full):
+        """Key strip ``c``, its first key ``lead`` past the block's
+        first query row: its query sub-tiles ``[first, full)`` masked,
+        ``[full, n_sub)`` plain."""
+        cols = _tile(c, sub_k)
+        k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+        bias = None if b_ref is None else b_ref[0, cols, :]  # (sub_k, 1)
 
-    @pl.when(diag_ok)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if b_ref is not None:
-            s = s + b_ref[0]
-        if causal:
-            mask = _causal_mask(q.shape[0], k.shape[0], i, j, block_q, block_k,
-                                q_offset, k_offset)
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0])
-        if causal or has_bias:  # fully-masked rows have lse == NEG_INF: exp(0) = 1
-            p = jnp.where(s > NEG_INF / 2, p, 0.0)
-        do = do_ref[0]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # widen v rather than rounding an f32 cotangent down (ring path)
-        v = v_ref[0]
-        if v.dtype != do.dtype:
-            v = v.astype(do.dtype)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0])
-        dk_acc[:] += scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        def tile(r, until, masked, carry):
+            rows = _tile(r, sub_q, until)
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            lse = lse_ref[0, :, rows]  # (1, sub_q)
+            if has_bias:
+                lse = _dead_rows_off(lse)
+            s = _dot(k, q, _NT) * scale
+            if bias is not None:
+                s = s + bias
+            p = jnp.exp(s - lse)
+            if masked:
+                p = jnp.where(_visible(sub_k, sub_q, lead - r * sub_q,
+                                       transposed=True), p, 0.0)
+            dv = _dot(p.astype(do.dtype), do, _NN)
+            # widen v rather than rounding an f32 cotangent down (ring path)
+            vw = v if v.dtype == do.dtype else v.astype(do.dtype)
+            ds = p * (_dot(vw, do, _NT) - delta_ref[0, :, rows])
+            dk = _dot(ds.astype(q.dtype), q, _NN)
+            if carry is None:
+                return dk, dv
+            return carry[0] + dk, carry[1] + dv
 
-    @pl.when(t == nt - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        carry = None if nt == 1 else (dk_acc[cols, :], dv_acc[cols, :])
+        # rows ascending: the diagonal's sub-tiles, then the plain runs
+        for piece in _pieces("dkv", (first, full), n_sub, run):
+            carry = tile(*piece, carry)
+        zero = jnp.zeros((sub_k, dk_acc.shape[1]), jnp.float32)
+        dk, dv = carry or (zero, zero)  # a strip no query of the call reaches
+        if nt == 1:
+            dk_ref[0, cols, :] = (scale * dk).astype(dk_ref.dtype)
+            dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+        else:
+            dk_acc[cols, :], dv_acc[cols, :] = dk, dv
+
+    def block(plan, d):
+        for c, (first, full) in enumerate(plan):
+            if first < n_sub or nt == 1:
+                strip(c, c * sub_k - d, first, full)
+
+    _for_this_block("dkv", causal, q_offset, k_offset, block_q, block_k,
+                    nq, nk, sub_q, sub_k, i, j, nt == 1, block)
+
+    if nt > 1:
+        @pl.when(t == nt - 1)
+        def _finalize():
+            dk_ref[0] = (scale * dk_acc[:]).astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, q_offset, k_offset,
                      block_q=None, block_k=None, interpret=False, delta=None,
                      out_dtype=None, kv_bias=None, heads=1, kv_heads=None):
-    # default 512 (not the forward's 1024): the bwd kernels keep ~4
-    # (bq, bk) f32 score-sized temporaries live, so smaller tiles stay
-    # inside VMEM — the same envelope the "bwd" tuned entries and the
-    # footprint clamp price exactly.
     """q/out/do (BH, Sq, D); k/v (B·kv_heads, Sk, D); lse (BH, Sq, 1).
     Returns (dq, dk, dv) with dk/dv shaped like k/v.
 
     ``block_q``/``block_k`` default to the shape's tuned ``"bwd"`` entry
-    (self-attention shapes) else 512 — the backward consults its OWN
-    per-phase table, never a forward measurement — and candidates are
-    clamped against the bwd VMEM footprint formula.
+    (self-attention shapes) else 512 (unmeasured shapes keep the grid
+    they had) — the backward consults its OWN per-phase table, never a
+    forward measurement — and candidates are clamped against the bwd
+    VMEM footprint formula.
     ``delta`` (rowsum of do·out over the FULL row) may be passed in when
     ``out`` covers more keys than this call sees — ring attention's
     backward, where each chunk-pair call sees only the local k/v chunk.
@@ -479,15 +832,11 @@ def flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, q_offset, k_offset,
     BH, Sq, D = q.shape
     Sk = k.shape[1]
     kv_heads = kv_heads or heads
-    group = heads // kv_heads
     BKV = k.shape[0]
     dq_dtype = out_dtype or q.dtype
     dk_dtype = out_dtype or k.dtype
     dv_dtype = out_dtype or v.dtype
-    block_q, block_k = _resolve_targets(
-        Sq, Sk, D, q.dtype, block_q, block_k, "bwd", 512)
-    bq, bk = _clamped_blocks(Sq, Sk, D, q.dtype, block_q, block_k, "bwd")
-    nq, nk = Sq // bq, Sk // bk
+    bq, bk, sub = dispatched(Sq, Sk, D, q.dtype, "bwd", block_q, block_k)
     has_bias = kv_bias is not None
 
     if delta is None:
@@ -498,21 +847,25 @@ def flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, q_offset, k_offset,
     if has_bias:
         inputs = inputs + (kv_bias,)
     static = (BH, BKV, Sq, Sk, D, heads, kv_heads, float(scale), causal,
-              q_offset, k_offset, bq, bk, has_bias, interpret)
+              q_offset, k_offset, bq, bk, sub, has_bias, interpret)
     dq_call = _dq_pallas_call(*static, jnp.dtype(dq_dtype).name)
     dkv_call = _dkv_pallas_call(*static, jnp.dtype(dk_dtype).name,
                                 jnp.dtype(dv_dtype).name)
+    # the dkv kernel reads the per-row statistics as rows and the key
+    # bias as a column (its tiles are keys by queries): the same values
+    rows = (lse.reshape(BH, 1, Sq), delta.reshape(BH, 1, Sq))
+    bias_t = (kv_bias.reshape(-1, Sk, 1),) if has_bias else ()
     # jax.disable_jit(False): see flash_fwd_pallas — pallas_call cannot
     # bind eagerly, so both backward kernels stay jitted ops.
     with jax.disable_jit(False):
         dq = dq_call(*inputs)
-        dk, dv = dkv_call(*inputs)
+        dk, dv = dkv_call(q, k, v, do, *rows, *bias_t)
     return dq, dk, dv
 
 
 @functools.lru_cache(maxsize=512)
 def _dq_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
-                    q_offset, k_offset, bq, bk, has_bias, interpret,
+                    q_offset, k_offset, bq, bk, sub, has_bias, interpret,
                     dq_dtype_name):
     """The dq ``pallas_call``, memoized like :func:`_fwd_call`."""
     nq, nk = Sq // bq, Sk // bk
@@ -534,7 +887,7 @@ def _dq_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
         functools.partial(
             _dq_kernel, scale=scale, causal=causal, has_bias=has_bias,
             q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk,
-            nk=nk,
+            sub_q=sub[0], sub_k=sub[1], run=sub[2], nq=nq, nk=nk,
         ),
         grid=(BH, nq, nk),
         in_specs=in_specs,
@@ -549,7 +902,7 @@ def _dq_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
 
 @functools.lru_cache(maxsize=512)
 def _dkv_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
-                     q_offset, k_offset, bq, bk, has_bias, interpret,
+                     q_offset, k_offset, bq, bk, sub, has_bias, interpret,
                      dk_dtype_name, dv_dtype_name):
     """The dk/dv ``pallas_call``, memoized like :func:`_fwd_call`."""
     nq, nk = Sq // bq, Sk // bk
@@ -568,22 +921,27 @@ def _dkv_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
         memory_space=pltpu.VMEM,
     )
     kT_spec = pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0), memory_space=pltpu.VMEM)
+    # lse and delta as ROWS (BH, 1, Sq): the kernel holds its tiles
+    # keys by queries
     rT_spec = pl.BlockSpec(
-        (1, bq, 1), lambda b, j, t: (_q_row(b, t), t % nq, 0),
+        (1, 1, bq), lambda b, j, t: (_q_row(b, t), 0, t % nq),
         memory_space=pltpu.VMEM,
     )
 
     in_specsT = [qT_spec, kT_spec, kT_spec, qT_spec, rT_spec, rT_spec]
     if has_bias:
         in_specsT.append(
-            pl.BlockSpec((1, 1, bk), lambda b, j, t: (b // kv_heads, 0, j), memory_space=pltpu.VMEM)
+            # the key bias as a COLUMN (B, Sk, 1)
+            pl.BlockSpec((1, bk, 1), lambda b, j, t: (b // kv_heads, j, 0),
+                         memory_space=pltpu.VMEM)
         )
 
     return pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, has_bias=has_bias,
             q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk,
-            nq=nq, nt=group * nq,
+            sub_q=sub[0], sub_k=sub[1], run=sub[2], nq=nq, nk=nk,
+            nt=group * nq,
         ),
         grid=(BKV, nk, group * nq),
         in_specs=in_specsT,
@@ -626,10 +984,10 @@ def _flash_pallas_bwd(scale, causal, q_offset, k_offset, block_q, block_k,
                       interpret, heads, kv_heads, res, g):
     q, k, v, kv_bias, out, lse = res
     # the nondiff blocks are the CALLER's (None = untuned): an explicit
-    # block keeps the documented 512 cap (more score-sized f32
-    # temporaries live in the bwd); None defers to flash_bwd_pallas's
-    # own per-phase tuned entry — a forward measurement never leaks
-    # onto the backward's different VMEM envelope
+    # block keeps the documented 512 cap (the backward holds more blocks
+    # a grid step than the forward it was chosen for); None defers to
+    # flash_bwd_pallas's own per-phase tuned entry — a forward
+    # measurement never leaks onto the backward's different envelope
     dq, dk, dv = flash_bwd_pallas(q, k, v, out, lse, g, scale, causal,
                                   q_offset, k_offset,
                                   block_q=None if block_q is None else min(block_q, 512),

@@ -1,31 +1,49 @@
-"""Flash-attention block-size sweep + absolute-roofline report.
+"""Flash-attention grid-block × sub-tile × run-cap sweep, one kernel at
+a time.
 
-VERDICT r3 item 6: the static ``_pick_block`` heuristic is the only
-tuning, and the wins are reported only RELATIVE to the scan composite.
-This sweep measures, on the real chip:
+The three kernels (``apex_flash_fwd``, ``apex_flash_dq``,
+``apex_flash_dkv``) walk a grid block in square sub-tiles and visit
+only those the causal diagonal leaves alive
+(``flash_attention_pallas.live_subtiles``).  What a grid block and a
+sub-tile should measure is a property of the chip, so this sweep
+measures it, on the real chip, at the shapes the benchmark's cells run:
 
-1. the bf16 matmul roofline (the MFU denominator),
-2. fwd and fwd+bwd TFLOP/s of the Pallas flash kernel per
-   (D, S, block_q, block_k) combination — the fwd-only best feeds the
-   ``"fwd"`` tuned entry, the fwd+bwd best the ``"bwd"`` entry (the
-   phases have different VMEM envelopes, so one (bq, bk) cannot serve
-   both),
-3. the arithmetic-intensity bound for each shape (is it memory-bound?),
+- ``train``: S 1024, D 64, bf16, 128 heads: fwd, dq and dkv, causal
+  (``gpt2-medium.train-b8``);
+- ``eva``: Sq 2048, keys = a pooled buffer of 0 / 512 / 1,024 rows then
+  the window, D 128, bf16, 32 heads: fwd with a key bias and a negative
+  ``k_offset`` (``evabyte-6.5b.serve-bytedoc-over``'s prefill);
+- ``mla``: S 512–4096, D 192, bf16, 32 heads: fwd, causal (the latent
+  family's prefill);
+- ``long`` (``--long``): S 4096 / 8192 at D 64 and their ring-attention
+  chunk shapes (Sq/cp for cp ∈ {2, 4}), fwd and bwd.
 
-over the long-seq shapes (4096/8192) AND their ring-attention chunk
-shapes (Sq/cp for cp ∈ {2, 4} — the per-chunk-pair calls context
-parallelism actually dispatches), and prints one JSON line per config
-with the best blocks and % of roofline, plus a
-per-(shape, phase) ``tuned_blocks_table`` line that
-``install_tuned_blocks.py`` ships into the kernel source.
+Each kernel is timed ALONE (its ``pallas_call`` built with explicit
+blocks, sub-tile and run cap, 50 calls chained inside one program: a
+call takes half a millisecond and the host's dispatch as long, so calls
+timed one by one read the host), and each line carries beside the
+timing the static counter (sub-tiles visited, masked, skipped a head,
+and the bodies the kernel's code holds) and what ONE call costs a
+program that holds it before it runs: the seconds to trace and lower it
+and the bytes of its serialized module (``jax.export``; every start pays
+the first, compile cache or not).  The roofline
+share counts the causal half of the square as the work, at the
+published 197 TFLOP/s of a v5e.  The last line is the per-(shape,
+phase) ``tuned_blocks_table`` that ``install_tuned_blocks.py`` ships
+into the kernel source: rows ``[[S, D, dtype, phase], [bq, bk, sub]]``,
+the ``"bwd"`` row the one that minimises dq + dkv together (both read
+it).
 
-    python benchmarks/flash_sweep.py [--quick]
+    python benchmarks/flash_sweep.py [--quick] [--shapes train eva mla]
+    python benchmarks/flash_sweep.py --table-from a.jsonl b.jsonl
+        # no timing: the table of several kept outputs together
     python benchmarks/flash_sweep.py --quick --interpret   # CPU smoke:
         # tiny shapes through the Pallas interpreter, still emits a
-        # valid tuned_blocks_table line (tests/test_bench_smoke.py)
+        # valid tuned_blocks_table line (tests/test_flash_lowering.py)
 """
 
 import argparse
+import functools
 import itertools
 import json
 import sys
@@ -36,165 +54,325 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from apex_tpu.ops import flash_attention_pallas as fap
+from apex_tpu.ops._pallas_tiling import RUN_COLUMNS
+
+PEAK_TFLOPS = 197.0   # TPU v5e, bf16 (Google Cloud documentation)
+#: matmul-halves of the S x S square a kernel computes (QK^T and PV;
+#: + dP and dQ; + dV, dP and dK), as cellbench/counts/flash_attention.py
+MATMULS = {"fwd": 2, "dq": 3, "dkv": 4}
+BF16 = jnp.bfloat16
 
 
-def measure_roofline(n=8192, iters=32):
-    a = jax.random.normal(jax.random.PRNGKey(0), (n, n), jnp.bfloat16)
-    b = jax.random.normal(jax.random.PRNGKey(1), (n, n), jnp.bfloat16)
+def _case(name, phases, BH, Sq, D, pooled=0, bias=False):
+    return dict(name=name, phases=phases, BH=BH, Sq=Sq, Sk=Sq + pooled,
+                D=D, k_offset=-pooled, bias=bias)
 
-    @jax.jit
-    def chained(a, b):
+
+def cases(groups, quick):
+    out = []
+    if "train" in groups:
+        out.append(_case("train", ("fwd", "dq", "dkv"), 128, 1024, 64))
+    if "eva" in groups:
+        for pooled in ((512,) if quick else (0, 512, 1024)):
+            out.append(_case(f"eva+{pooled}", ("fwd",), 32, 2048, 128,
+                             pooled=pooled, bias=pooled > 0))
+    if "mla" in groups:
+        for S in ((1024,) if quick else (512, 1024, 2048, 4096)):
+            out.append(_case(f"mla{S}", ("fwd",), 32, S, 192))
+    if "long" in groups:
+        long = [(24, 4096, 64), (8, 8192, 64)]
+        ring = [(BH * cp, S // cp, D) for BH, S, D in long for cp in (2, 4)]
+        for BH, S, D in long + [s for s in ring if s not in long]:
+            out.append(_case(f"long{S}", ("fwd", "dq", "dkv"), BH, S, D))
+    return out
+
+
+def useful_tflop(case, phase):
+    """Causal work of one call: the live triangle (and the whole pooled
+    buffer beside it), 2·rows·cols·D a matmul."""
+    live = case["Sq"] * (case["Sq"] + 1) / 2 + case["Sq"] * -case["k_offset"]
+    return case["BH"] * MATMULS[phase] * 2 * live * case["D"] / 1e12
+
+
+@functools.lru_cache(maxsize=1)
+def inputs_of(name, BH, Sq, Sk, D, k_offset, biased, interpret):
+    """A case's operands, made once: q, k, v, the cotangent, the key
+    bias (half of a pooled buffer hidden), and the forward's ``lse`` and
+    ``delta`` the backward kernels read (through the dispatcher's own
+    blocks: their values do not depend on the blocks timed)."""
+    kq, kk, kv, kd = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(kq, (BH, Sq, D), BF16)
+    k = jax.random.normal(kk, (BH, Sk, D), BF16)
+    v = jax.random.normal(kv, (BH, Sk, D), BF16)
+    do = jax.random.normal(kd, (BH, Sq, D), BF16)
+    bias = None
+    if biased:
+        col = jnp.arange(Sk)
+        hidden = (col >= -k_offset // 2) & (col < -k_offset)
+        bias = jnp.where(hidden, fap.NEG_INF, 0.0).astype(
+            jnp.float32)[None, None, :]
+    out, lse = fap.flash_fwd_pallas(
+        q, k, v, float(D) ** -0.5, True, 0, k_offset, kv_bias=bias,
+        heads=BH, interpret=interpret)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    return q, k, v, do, lse, delta, bias
+
+
+def build(case, phase, bq, bk, sub, run, interpret, iters):
+    """``iters`` calls of the kernel in ONE program and its inputs,
+    blocks, sub-tile and run cap (sub-tiles a run) explicit (no table,
+    no clamp: what is asked is what is timed).  Each call's output
+    feeds the next one's input, so the loop runs them one after another
+    and the host's dispatch (most of a millisecond here) is paid once,
+    not a call."""
+    BH, Sq, Sk, D = case["BH"], case["Sq"], case["Sk"], case["D"]
+    q, k, v, do, lse, delta, bias = inputs_of(
+        case["name"], BH, Sq, Sk, D, case["k_offset"], case["bias"],
+        interpret)
+    subs = (sub, sub, run) if sub else (bq, bk, 1)
+    static = (Sq, Sk, D, BH, BH, float(D) ** -0.5, True, 0,
+              case["k_offset"], bq, bk, subs, case["bias"], interpret)
+    bias = () if bias is None else (bias,)
+    if phase == "fwd":
+        return chained(fap._fwd_call(BH, *static, "bfloat16"), 0,
+                       iters), (q, k, v) + bias
+    if phase == "dq":
+        call = fap._dq_pallas_call(BH, BH, *static, "bfloat16")
+        return chained(call, 0, iters), (q, k, v, do, lse, delta) + bias
+    # dkv holds its tiles keys by queries: statistics as rows, the key
+    # bias as a column (flash_bwd_pallas reshapes them so)
+    call = fap._dkv_pallas_call(BH, BH, *static, "bfloat16", "bfloat16")
+    return chained(call, 1, iters), (
+        q, k, v, do, lse.reshape(BH, 1, Sq), delta.reshape(BH, 1, Sq)
+    ) + tuple(b.reshape(-1, Sk, 1) for b in bias)
+
+
+def chained(call, feed, iters):
+    """``iters`` calls, the first output of each (out, dq, dk) put in
+    the place of argument ``feed`` (q, q, k) of the next."""
+    def many(*args):
         def body(_, x):
-            return jnp.matmul(x, b, preferred_element_type=jnp.bfloat16)
-        return jnp.float32(jax.lax.fori_loop(0, iters, body, a)[0, 0])
-
-    float(chained(a, b))
-    best = min(
-        _timed(lambda: float(chained(a, b))) for _ in range(3)
-    ) / iters
-    return 2 * n ** 3 / best / 1e12
+            out = call(*args[:feed], x, *args[feed + 1:])
+            out = out[0] if isinstance(out, (tuple, list)) else out
+            return out.astype(x.dtype)
+        return jax.lax.fori_loop(0, iters, body, args[feed])
+    return jax.jit(many)
 
 
-def _timed(fn):
+def code_size(fn, args):
+    """What one call of the kernel costs a program before it runs:
+    seconds to trace and lower it, and the bytes of the serialized
+    module (for the TPU, whatever the host: nothing is compiled)."""
+    from jax import export
+
     t0 = time.perf_counter()
-    fn()
-    return time.perf_counter() - t0
+    exported = export.export(fn, platforms=["tpu"])(*args)
+    seconds = time.perf_counter() - t0
+    return round(seconds, 3), len(exported.mlir_module_serialized)
 
 
-def attn_flops(B, H, S, D, fwd_only):
-    """Causal attention FLOPs: 2 matmuls (QK^T, PV) of 2·S²·D each,
-    halved by causality; backward re-does ~2.5x the fwd matmul work."""
-    fwd = B * H * (2 * 2 * S * S * D) / 2
-    return fwd if fwd_only else fwd * 3.5
-
-
-def bench_flash(B, H, S, D, bq, bk, fwd_only, iters=8, interpret=False):
-    from apex_tpu.ops.flash_attention_pallas import flash_attention_pallas
-
-    kq = jax.random.PRNGKey(0)
-    q = jax.random.normal(kq, (B, H, S, D), jnp.bfloat16)
-    k = jax.random.normal(jax.random.PRNGKey(1), (B, H, S, D), jnp.bfloat16)
-    v = jax.random.normal(jax.random.PRNGKey(2), (B, H, S, D), jnp.bfloat16)
-
-    if fwd_only:
-        @jax.jit
-        def run(q, k, v):
-            o = flash_attention_pallas(q, k, v, causal=True, block_q=bq,
-                                       block_k=bk, interpret=interpret)
-            return jnp.float32(o[0, 0, 0, 0])
-    else:
-        @jax.jit
-        def run(q, k, v):
-            def f(q):
-                o = flash_attention_pallas(q, k, v, causal=True, block_q=bq,
-                                           block_k=bk, interpret=interpret)
-                return jnp.sum(o.astype(jnp.float32))
-            g = jax.grad(f)(q)
-            return jnp.float32(g[0, 0, 0, 0])
-
-    float(run(q, k, v))  # compile + warm
+def time_kernel(fn, args, iters):
+    """Seconds a call: the best of three runs of the chained program."""
+    jax.block_until_ready(fn(*args))  # compile + warm
     best = float("inf")
     for _ in range(3):
         t0 = time.perf_counter()
-        for _ in range(iters):
-            r = run(q, k, v)
-        float(r)
+        jax.block_until_ready(fn(*args))
         best = min(best, (time.perf_counter() - t0) / iters)
-    return attn_flops(B, H, S, D, fwd_only) / best / 1e12, best * 1e3
+    return best
+
+
+def candidates(case, phase, blocks, subs, runs):
+    """(bq, bk, sub, run, shipped) that divide the shape; a sub-tile
+    divides both blocks (None: the block is one tile, what the kernels
+    did before they walked sub-tiles); ``run``: the sub-tiles a run at
+    most, for each cap of ``runs`` (columns) that gives another number
+    of them; ``shipped``: it is the kernels' own cap for that sub-tile.
+    The whole key length is always a candidate key block: one block
+    along the keys is code with no state carried between grid steps."""
+    Sq, Sk = case["Sq"], case["Sk"]
+    key_blocks = sorted(set(blocks) | {Sk})
+    for bq, bk in itertools.product(blocks, key_blocks):
+        if bq > Sq or Sq % bq or bk > Sk or Sk % bk:
+            continue
+        for sub in subs:
+            if sub is None and bq * bk <= 1024 * 1024:
+                yield bq, bk, None, 1, True
+            elif sub and bq % sub == 0 and bk % sub == 0:
+                walked = max(bq, bk) // sub
+                for run in sorted({min(max(1, c // sub), walked)
+                                   for c in runs}):
+                    yield bq, bk, sub, run, run == min(fap.run_cap(sub),
+                                                       walked)
+
+
+#: the query block the dispatcher gives a call whose key length is not
+#: its query length (the table's blocks are read where Sk == Sq only:
+#: ``flash_attention_pallas._resolve_targets``), forward
+DISPATCH_BQ = fap.DEFAULT_BLOCK["fwd"]
+
+
+#: a sub-tile within this share of the fastest is as good: among those
+#: the table takes the one whose kernels hold the least code
+SLACK = 0.02
+
+
+def tuned_table(records, slack=SLACK):
+    """The ``tuned_blocks_table`` rows ``[[S, D, dtype, phase], [bq,
+    bk, sub]]`` from a sweep's timing records (pure: the last line of a
+    sweep, or ``--table-from`` kept outputs).
+
+    Blocks come from the self-attention case of a shape (the table's
+    blocks are read where Sk == Sq); the ``"bwd"`` row is the
+    combination under which dq and dkv together take least, since both
+    kernels read that one row.  The SUB-TILE of a row is read whatever
+    the key length, so it is the one under which all of the shape's
+    cases together take least: the self-attention case at its best
+    blocks for that sub-tile, a case with more keys at the query block
+    the dispatcher gives it (``DISPATCH_BQ``) and its best key block.
+    A kernel's code is paid for once a compiled program before it runs
+    (module docstring of the kernels), so among the sub-tiles within
+    ``slack`` of the fastest the row takes the one whose kernels hold
+    the fewest bodies."""
+    cost = {}   # (S, D, phase) -> {sub: {case: {(bq, bk): ms}}}
+    for r in records:
+        # the run cap is the kernels' constant, not a column of the
+        # table: only what the dispatcher would run is a candidate
+        if "ms" not in r or not r.get("run_shipped", True):
+            continue
+        _, Sq, Sk, D = r["shape"]
+        if Sk != Sq and r["bq"] != min(DISPATCH_BQ, Sq):
+            continue
+        by_case = cost.setdefault((Sq, D), {}).setdefault(
+            r["sub"], {}).setdefault((r["case"], Sk == Sq), {})
+        by_case.setdefault((r["bq"], r["bk"]), {})[r["phase"]] = (
+            r["ms"], r.get("subtiles", {}).get("bodies", 0))
+    rows = {}
+    for (S, D), by_sub in cost.items():
+        for phase, kernels in (("fwd", ("fwd",)), ("bwd", ("dq", "dkv"))):
+            found = []   # (ms, bodies, row) a sub-tile
+            for sub, by_case in by_sub.items():
+                total, bodies, blocks = 0.0, 0, None
+                for (_, own), by_blocks in by_case.items():
+                    timed = {b: (sum(got[k][0] for k in kernels),
+                                 sum(got[k][1] for k in kernels))
+                             for b, got in by_blocks.items()
+                             if all(k in got for k in kernels)}
+                    if not timed:
+                        continue
+                    b = min(timed, key=timed.get)
+                    total, bodies = total + timed[b][0], bodies + timed[b][1]
+                    if own:
+                        blocks = b
+                if blocks:
+                    found.append((total, bodies,
+                                  blocks + ((sub,) if sub else ())))
+            if found:
+                fastest = min(f[0] for f in found)
+                rows[(S, D, phase)] = min(
+                    (f for f in found if f[0] <= (1 + slack) * fastest),
+                    key=lambda f: (f[1], f[0]))[2]
+    return [[[S, D, "bfloat16", phase], list(row)]
+            for (S, D, phase), row in sorted(rows.items())]
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", help="fewer shapes/blocks")
-    ap.add_argument("--fwd-only", action="store_true")
+    ap.add_argument("--shapes", nargs="+", default=["train", "eva", "mla"],
+                    choices=["train", "eva", "mla", "long"])
+    ap.add_argument("--long", action="store_true",
+                    help="add the 4096/8192 shapes and their ring chunks")
+    ap.add_argument("--blocks", nargs="+", type=int,
+                    default=[512, 1024, 2048])
+    ap.add_argument("--subs", nargs="+", type=int, default=[128, 256, 512],
+                    help="sub-tile sides; 0 = the block as one tile")
+    ap.add_argument("--runs", nargs="+", type=int,
+                    default=[RUN_COLUMNS],
+                    help="run caps in columns (a sub-tile's side: no runs); "
+                         "only the kernels' own feeds the table")
+    ap.add_argument("--iters", type=int, default=50,
+                    help="calls chained in one program a timing")
     ap.add_argument("--interpret", action="store_true",
                     help="Pallas interpreter mode (CPU smoke test only — "
                          "timings are meaningless)")
-    ap.add_argument("--tiny", action="store_true",
-                    help="tiny shapes for the CPU smoke test")
+    ap.add_argument("--table-from", nargs="+", metavar="JSONL",
+                    help="time nothing: print the tuned_blocks_table of "
+                         "these kept sweep outputs together")
     args = ap.parse_args()
+    if args.table_from:
+        records = []
+        for path in args.table_from:
+            with open(path) as f:
+                records += [json.loads(line) for line in f
+                            if line.startswith('{"case"')]
+        print(json.dumps({"tuned_blocks_table": tuned_table(
+            [r for r in records if "best" not in r])}))
+        return
 
-    # interpret mode = CPU: no 8k matmuls, and real shapes through the
-    # interpreter take minutes — the smoke contract is tiny shapes
-    small = args.tiny or args.interpret
-    roof = measure_roofline(n=256, iters=4) if small else measure_roofline()
-    print(json.dumps({"roofline_tflops": round(roof, 1)}), flush=True)
-
-    shapes = [
-        # (B, H, S, D) — the VERDICT targets: D=64/S1024, D=128, S>=4096
-        (8, 12, 1024, 64),
-        (8, 8, 1024, 128),
-        (2, 12, 4096, 64),
-        (1, 8, 8192, 64),
-    ]
-    # ring-attention chunk shapes: context parallelism dispatches the
-    # flash kernels per chunk PAIR at Sq/cp, so those are the shapes a
-    # cp run's tuned lookup actually keys on (batch scaled up to keep
-    # the grid busy, like a real cp rank's B·H)
-    ring = [(B * cp, H, S // cp, D)
-            for (B, H, S, D) in shapes if S >= 4096
-            for cp in (2, 4)]
-    shapes += [s for s in ring if s not in shapes]
-    blocks = [256, 512, 1024, 2048]
+    groups = list(args.shapes) + (["long"] if args.long else [])
+    subs = [s or None for s in args.subs]
+    blocks = args.blocks
+    todo = cases(groups, args.quick)
     if args.quick:
-        shapes = shapes[:2]
-        blocks = [512, 1024]
-    if small:
-        shapes = [(1, 2, 256, 64)]
-        blocks = [128, 256]
+        blocks, subs = blocks[:2], subs[:2]
+    if args.interpret:
+        # interpret mode = CPU: real shapes through the interpreter take
+        # minutes — the smoke contract is a tiny shape, both phases
+        todo = [_case("tiny", ("fwd", "dq", "dkv"), 2, 256, 64)]
+        blocks, subs, args.iters = [128, 256], [128, None], 1
+        args.runs = [128, 256]
+    print(json.dumps({"device": jax.devices()[0].device_kind,
+                      "peak_tflops": PEAK_TFLOPS}),
+          flush=True)
 
-    passes = [True] if args.fwd_only else [True, False]
-    results = []
-    for (B, H, S, D), fwd_only in itertools.product(shapes, passes):
-        per_shape = []
-        # the backward kernels cap tiles at 512 (VMEM), so >512 blocks in
-        # a fwd+bwd sweep would only vary the forward — sweep them fwd-only
-        use_blocks = [b for b in blocks if fwd_only or b <= 512]
-        for bq, bk in itertools.product(use_blocks, use_blocks):
-            if bq > S or bk > S:
-                continue
-            try:
-                tflops, ms = bench_flash(B, H, S, D, bq, bk, fwd_only,
-                                         iters=1 if small else 8,
-                                         interpret=args.interpret)
-            except Exception as e:  # noqa: BLE001 — a block combo can exceed VMEM
-                print(json.dumps({"shape": [B, H, S, D], "fwd_only": fwd_only,
-                                  "bq": bq, "bk": bk,
-                                  "error": f"{type(e).__name__}"}), flush=True)
-                continue
-            rec = {
-                "shape": [B, H, S, D], "fwd_only": fwd_only,
-                "bq": bq, "bk": bk, "tflops": round(tflops, 2),
-                "ms": round(ms, 3), "pct_roofline": round(100 * tflops / roof, 1),
-            }
-            per_shape.append(rec)
-            print(json.dumps(rec), flush=True)
-        if per_shape:
-            best = max(per_shape, key=lambda r: r["tflops"])
-            results.append({**best, "best": True})
-            print(json.dumps({**best, "best": True}), flush=True)
-
-    # arithmetic-intensity note: flash fwd reads ~3·S·D·2B + writes S·D·2B
-    # per (b,h); intensity = flops/bytes — compare against roof/HBM-BW to
-    # call memory-bound honestly
-    print(json.dumps({"summary": results}), flush=True)
-
-    # table-ready per-(shape, phase) defaults in the list-of-pairs
-    # format set_tuned_blocks accepts directly:
+    records, best = [], {}
+    for case in todo:
+        for phase in case["phases"]:
+            for bq, bk, sub, run, shipped in candidates(
+                    case, phase, blocks, subs, args.runs):
+                rec = {"case": case["name"], "phase": phase,
+                       "shape": [case["BH"], case["Sq"], case["Sk"], case["D"]],
+                       "bq": bq, "bk": bk, "sub": sub, "run": run,
+                       "run_shipped": shipped}
+                try:
+                    fn, inputs = build(case, phase, bq, bk, sub, run,
+                                       args.interpret, args.iters)
+                    one, _ = build(case, phase, bq, bk, sub, run,
+                                   args.interpret, 1)
+                    lower_s, module_bytes = code_size(one, inputs)
+                    sec = time_kernel(fn, inputs, args.iters)
+                except Exception as e:  # noqa: BLE001 — a combo can exceed VMEM
+                    print(json.dumps({**rec, "error": type(e).__name__}),
+                          flush=True)
+                    continue
+                visited, masked, skipped, bodies = fap.live_subtiles(
+                    phase, case["Sq"], case["Sk"], 0, case["k_offset"],
+                    bq, bk, sub, run=run)
+                tflops = useful_tflop(case, phase) / sec
+                rec.update(
+                    ms=round(sec * 1e3, 4),
+                    us_per_head=round(sec * 1e6 / case["BH"], 3),
+                    tflops=round(tflops, 2),
+                    pct_peak=round(100 * tflops / PEAK_TFLOPS, 1),
+                    subtiles={"visited": visited, "masked": masked,
+                              "skipped": skipped, "bodies": bodies},
+                    lower_s=lower_s, module_bytes=module_bytes)
+                print(json.dumps(rec), flush=True)
+                records.append(rec)
+                key = (case["name"], phase)
+                if key not in best or rec["ms"] < best[key]["ms"]:
+                    best[key] = rec
+    for rec in best.values():
+        print(json.dumps({**rec, "best": True}), flush=True)
+    # table-ready rows in the list-of-pairs format set_tuned_blocks
+    # accepts directly:
     #   set_tuned_blocks(json.loads(line)["tuned_blocks_table"])
-    # The fwd-only best becomes the "fwd" entry (what the forward
-    # dispatcher keys on); the fwd+bwd best becomes the "bwd" entry —
-    # the backward kernels consult their own phase, so a fast-forward
-    # block choice never drags the backward over its VMEM envelope.
-    table = {}
-    for r in results:
-        B, H, S, D = r["shape"]
-        phase = "fwd" if r["fwd_only"] else "bwd"
-        table[(S, D, phase)] = [r["bq"], r["bk"]]
-    pairs = [[[s, d, "bfloat16", phase], v]
-             for (s, d, phase), v in sorted(table.items())]
-    print(json.dumps({"tuned_blocks_table": pairs}), flush=True)
+    print(json.dumps({"tuned_blocks_table": tuned_table(records)}),
+          flush=True)
 
 
 if __name__ == "__main__":

@@ -10,7 +10,9 @@ only in a runtime ``set_tuned_blocks`` call someone has to remember.
         --provenance "v5e-lite 2026-07-31 flash_sweep"
 
 Keys are per-phase ``(S, D, dtype, phase)`` with phase ∈ {"fwd", "bwd"}
-(the forward and backward kernels consult separate entries).  Old flat
+(the forward and backward kernels consult separate entries); values are
+``(bq, bk)`` or ``(bq, bk, sub)``, ``sub`` the side of the sub-tiles the
+kernels walk a block in (absent: the kernels' own constant).  Old flat
 3-element keys — in the sweep output OR already installed in the
 source literal — migrate as ``"fwd"`` entries: pre-split sweeps
 measured the forward dispatcher's path.
@@ -81,17 +83,22 @@ def main():
             raise SystemExit(f"bad tuned-block phase {phase!r} in {key!r}")
         return (int(s), int(d), str(dtype), str(phase))
 
-    entries = {norm_key(k): (int(bq), int(bk))
-               for k, (bq, bk) in existing.items()}
+    def norm_val(val):
+        """(bq, bk) or (bq, bk, sub)."""
+        if len(val) not in (2, 3):
+            raise SystemExit(f"bad tuned-block row {val!r}: (bq, bk[, sub])")
+        return tuple(int(x) for x in val)
+
+    entries = {norm_key(k): norm_val(v) for k, v in existing.items()}
     for key, val in read_table(args.sweep_output):
-        bq, bk = val
-        entries[norm_key(key)] = (int(bq), int(bk))
+        entries[norm_key(key)] = norm_val(val)
     if not entries:
         raise SystemExit("tuned_blocks_table was empty")
 
     body = "".join(
-        f"    ({s}, {d}, {dtype!r}, {phase!r}): ({bq}, {bk}),\n"
-        for (s, d, dtype, phase), (bq, bk) in sorted(entries.items())
+        f"    ({s}, {d}, {dtype!r}, {phase!r}): "
+        f"({', '.join(str(x) for x in val)}),\n"
+        for (s, d, dtype, phase), val in sorted(entries.items())
     )
     new_literal = (
         f"_TUNED_BLOCKS: dict = {{\n"

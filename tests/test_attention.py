@@ -163,6 +163,30 @@ class TestBiasedFlashAttention:
                                    rtol=1e-4, atol=1e-5)
 
 
+    @pytest.mark.parametrize("pooled,seen,block,block_k", [
+        (128, 128, None, None), (256, 100, None, None), (256, 0, None, None),
+        (256, 100, 128, None), (128, 0, 128, None),
+        (256, 100, 128, 512), (128, 0, 128, 384), (256, 256, 64, 512)])
+    def test_pallas_key_bias_with_negative_k_offset(self, monkeypatch,
+                                                    pooled, seen, block,
+                                                    block_k):
+        """The EVA window's call: keys = a pooled buffer then the
+        window's own, the diagonal starts after the buffer (``k_offset =
+        -pooled``) and a key bias hides the buffer's rows from ``seen``
+        on.  Over one block the buffer's sub-tiles lie wholly under the
+        diagonal: they are walked without a mask, their hidden columns
+        weigh nothing.  Over several query blocks and ONE key block, as
+        ``ops/eva.py`` asks for it, or over a grid of key blocks, the
+        grid indices choose a block's static variant as without a
+        bias."""
+        W = 256
+        col = np.arange(pooled + W)
+        mask = jnp.asarray(((col < seen) | (col >= pooled))[None, :])
+        assert_walk_matches_scan(monkeypatch, Sq=W, Sk=pooled + W,
+                                 k_offset=-pooled, kv_mask=mask, seed=23,
+                                 block=block, block_k=block_k)
+
+
 class TestOpenFoldMHA:
     def test_attention_core_with_mask_and_bias(self):
         from apex_tpu.contrib.openfold_triton import CanSchTriMHA, attention_core
@@ -194,6 +218,102 @@ class TestOpenFoldMHA:
         g = jax.grad(lambda b: jnp.sum(attention_core(q, k, v, bias=b) ** 2))(bias)
         assert float(jnp.abs(g).max()) > 0  # trained pair bias: real cotangent
         assert bool(jnp.all(jnp.isfinite(g)))
+
+
+def _walk_inputs(B, H, Hkv, Sq, Sk, D, seed, dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+    mk = lambda h, n: jnp.asarray(
+        rng.randn(B, h, n, D).astype(np.float32)).astype(dtype)
+    return mk(H, Sq), mk(Hkv, Sk), mk(Hkv, Sk), mk(H, Sq)
+
+
+def assert_walk_matches_scan(monkeypatch, *, Sq, Sk=None, sub=128, block=None,
+                             block_k=None, causal=True, q_offset=0,
+                             k_offset=0, H=2,
+                             Hkv=None, kv_mask=None, B=1, D=64, seed=3,
+                             dead_rows=None, dtype=jnp.float32,
+                             tol=(2e-5, 1e-4), runs=None):
+    """The Pallas kernels (interpret mode), their blocks walked in
+    ``sub`` x ``sub`` sub-tiles (``sub=None``: no tuned row, the
+    dispatcher's own blocks and sub-tile; ``block``: the caller's grid
+    blocks, ``block_k`` the key block where it is another), against the
+    ``lax.scan``
+    composite: forward and all three gradients, within ``tol`` (the
+    tolerances of the Pallas classes here: 2e-5 forward, 1e-4
+    gradients).  ``runs``: the call's plans must then hold a run of two
+    sub-tiles or more in every kernel (fewer bodies than sub-tiles
+    visited), so that a run's one update is held to the sum."""
+    from apex_tpu.ops import flash_attention_pallas as fap
+
+    Sk, block_k = Sk or Sq, block_k or block
+    q, k, v, w = _walk_inputs(B, H, Hkv or H, Sq, Sk, D, seed, dtype)
+    monkeypatch.setattr(fap, "_TUNED_BLOCKS", {})
+    for phase in ("fwd", "bwd") if sub else ():
+        fap.set_tuned_blocks({(Sq, D, jnp.dtype(dtype).name, phase): (
+            block or Sq, block_k or Sk, sub)})
+    if runs:
+        for phase in ("fwd", "bwd", "dkv"):
+            kind = "fwd" if phase == "fwd" else "bwd"
+            bq, bk, (side, _, _) = fap.dispatched(
+                Sq, Sk, D, dtype, kind, block, block_k)
+            visited, masked, _, bodies = fap.live_subtiles(
+                phase, Sq, Sk, q_offset, k_offset, bq, bk,
+                side if side < max(bq, bk) else None, causal=causal)
+            assert bodies < visited, (phase, bq, bk, side, visited, bodies)
+    kw = dict(causal=causal, q_offset=q_offset, k_offset=k_offset,
+              kv_mask=kv_mask)
+
+    def pallas(q, k, v):
+        return fap.flash_attention_pallas(
+            q, k, v, block_q=block, block_k=block_k, interpret=True, **kw)
+
+    def scan(q, k, v):
+        return flash_attention(q, k, v, impl="scan", **kw)
+
+    f32 = lambda x: np.asarray(x, np.float32)
+    out, ref = pallas(q, k, v), scan(q, k, v)
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(f32(out), f32(ref), atol=tol[0], rtol=tol[0])
+    if dead_rows is not None:   # rows no key reaches: zero, not a mean
+        np.testing.assert_array_equal(f32(out)[dead_rows], 0.0)
+    loss = lambda fn: lambda *a: jnp.sum(
+        fn(*a).astype(jnp.float32) * w.astype(jnp.float32))
+    gp = jax.grad(loss(pallas), argnums=(0, 1, 2))(q, k, v)
+    gs = jax.grad(loss(scan), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gs):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(f32(a), f32(b), atol=tol[1], rtol=tol[1])
+
+
+#: name -> arguments of assert_walk_matches_scan; every case cuts a grid
+#: block into sub-tiles of 128 and puts the diagonal somewhere else
+WALK_CASES = {
+    # one block of 4 x 4 sub-tiles: 6 skipped, 4 masked, 6 plain
+    "block_4x4": dict(Sq=512),
+    # 2 x 2 grid blocks of 2 x 2 sub-tiles: three static variants, the
+    # grid indices choose among them
+    "grid_2x2_blocks": dict(Sq=512, block=256),
+    # ring chunks: a call wholly above (every row dead), on, and wholly
+    # under the diagonal, and one the diagonal enters off a tile's edge
+    "chunk_above": dict(Sq=256, q_offset=0, k_offset=256,
+                        dead_rows=np.s_[:]),
+    "chunk_on": dict(Sq=256, q_offset=256, k_offset=256),
+    "chunk_under": dict(Sq=256, q_offset=512, k_offset=0),
+    "chunk_off_edge": dict(Sq=256, Sk=512, q_offset=160, k_offset=64),
+    "keys_start_late": dict(Sq=256, q_offset=0, k_offset=64,
+                            dead_rows=np.s_[:, :, :64]),
+    # Sq != Sk, causal (keys past the last query: never visited) and not
+    "more_keys_causal": dict(Sq=256, Sk=512),
+    "more_keys_full": dict(Sq=256, Sk=512, causal=False),
+    "more_queries": dict(Sq=512, Sk=256, q_offset=0, k_offset=0),
+    # grouped queries: the dkv walk over a group's q heads
+    "gqa_4_2": dict(Sq=256, H=4, Hkv=2),
+    "mqa_grid": dict(Sq=256, block=128, H=2, Hkv=1),
+    # lengths the sub-tile does not divide: 384 falls back to 128 under
+    # a target of 256, 320 has no lane-tile divisor and is one tile
+    "falls_back_to_128": dict(Sq=384, sub=256),
+    "one_tile_320": dict(Sq=320, sub=256),
+}
 
 
 class TestPaddedPallasFlashAttention:
@@ -255,6 +375,21 @@ class TestPaddedPallasFlashAttention:
             np.testing.assert_allclose(np.asarray(out[b, :, :n]),
                                        np.asarray(ref[b, :, :n]),
                                        atol=2e-5, rtol=2e-5)
+
+
+    @pytest.mark.parametrize("lengths,causal", [
+        ([256, 130], False), ([200, 64], False), ([256, 130], True),
+        ([256, 0], False),   # a batch row with no valid key: dead rows
+    ])
+    def test_subtile_walk_with_kv_mask(self, monkeypatch, lengths, causal):
+        """A key mask hides columns by data: every sub-tile is walked,
+        none pays the diagonal's mask when the call is not causal, and a
+        batch row whose keys are all padding stays zero with zero
+        gradients."""
+        mask = padded_mask(2, 256, lengths)
+        dead = np.s_[1] if lengths[1] == 0 else None
+        assert_walk_matches_scan(monkeypatch, Sq=256, B=2, causal=causal,
+                                 kv_mask=mask, dead_rows=dead, seed=21)
 
 
 CP = 4
@@ -550,12 +685,34 @@ class TestPallasFlashAttention:
                     assert S % bq == 0 and S % bk == 0
                     assert flash_vmem_bytes(bq, bk, D, phase) <= VMEM_BUDGET, \
                         (phase, S, D, bq, bk)
-        # an explicitly over-budget request clamps too (2048² fwd at
-        # D=64 prices ~38 MiB — more than double the 16 MiB budget)
-        bq, bk = fap._clamped_blocks(2048, 2048, 64, jnp.bfloat16,
-                                     2048, 2048, "fwd")
-        assert flash_vmem_bytes(bq, bk, 64, "fwd") <= VMEM_BUDGET
-        assert (bq, bk) != (2048, 2048)
+        # an explicitly over-budget request clamps too (8192² fwd at
+        # D=128 prices ~29 MiB of blocks and scratch alone)
+        bq, bk = fap._clamped_blocks(8192, 8192, 128, jnp.bfloat16,
+                                     8192, 8192, "fwd")
+        assert flash_vmem_bytes(bq, bk, 128, "fwd") <= VMEM_BUDGET
+        assert (bq, bk) != (8192, 8192)
+        # the score-sized temporaries are a RUN's since the kernels
+        # walk a block in sub-tiles (a sub-tile's rows by RUN_COLUMNS
+        # columns at most): 3 of them forward, 5 backward, beside the
+        # blocks and the scratch
+        from apex_tpu.ops._pallas_tiling import RUN_COLUMNS
+        sub = 256
+        assert RUN_COLUMNS == 1024
+        assert flash_vmem_bytes(1024, 1024, 64, "fwd") == 4 * (
+            4 * 1024 * 64 + 1024 + 2 * 1024 * 128 + 1024 * 64 + 3 * sub * 1024)
+        assert flash_vmem_bytes(1024, 1024, 64, "bwd") == 4 * (
+            8 * 1024 * 64 + 2 * 1024 + 5 * sub * 1024)
+        assert flash_vmem_bytes(1024, 1024, 64, "bwd", sub=512) == 4 * (
+            8 * 1024 * 64 + 2 * 1024 + 5 * 512 * 1024)
+        assert flash_vmem_bytes(512, 512, 64, "fwd") == 4 * (
+            4 * 512 * 64 + 512 + 2 * 512 * 128 + 512 * 64 + 3 * sub * 512)
+        # so whole-sequence backward blocks are admissible at the train
+        # cell's shape (the old (bq, bk) temporaries priced them at 18 MB)
+        assert fap._clamped_blocks(1024, 1024, 64, jnp.bfloat16,
+                                   1024, 1024, "bwd") == (1024, 1024)
+        # a block no lane-tile multiple divides is one tile, priced whole
+        assert flash_vmem_bytes(320, 320, 64, "fwd") == 4 * (
+            4 * 320 * 64 + 320 + 2 * 320 * 128 + 320 * 64 + 3 * 320 * 320)
 
     @pytest.mark.parametrize("causal", [True, False])
     @pytest.mark.slow
@@ -630,6 +787,139 @@ class TestPallasFlashAttention:
 
         dq = jax.grad(loss)(q)
         np.testing.assert_allclose(np.asarray(dq[:, :, :64]), 0.0, atol=1e-6)
+
+    @pytest.mark.parametrize("row", ["tuned_sub128", "no_row"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("heads", ["mha", "gqa"])
+    @pytest.mark.parametrize("place", ["offsets_0", "ring_hop", "eva_window"])
+    @pytest.mark.parametrize("hidden", ["no_bias", "key_bias", "dead_rows"])
+    def test_runs_match_scan(self, monkeypatch, hidden, place, heads, dtype,
+                             row):
+        """Forward, dq, dk and dv against the ``lax.scan`` composite
+        wherever the diagonal, a key bias, the head grouping, the dtype
+        and the tuned table put the walk, each at a size whose strips
+        hold a run of two sub-tiles or more in all three kernels: the
+        run's ONE product and one softmax update against the sum a
+        sub-tile at a time."""
+        # no row: 768 is one forward block of 3 x 3 sub-tiles of 256 and
+        # backward blocks of 384 in sub-tiles of 128
+        Sq = 512 if row == "tuned_sub128" else 768
+        pooled = 256 if place == "eva_window" else 0
+        Sk, B = Sq + pooled, 2 if hidden == "dead_rows" else 1
+        q_offset, k_offset = {"offsets_0": (0, 0), "ring_hop": (Sq, 0),
+                              "eva_window": (0, -pooled)}[place]
+        col = np.arange(Sk)
+        mask, dead = None, None
+        if hidden == "key_bias":   # the buffer's tail, or keys here and there
+            keep = ((col < pooled // 2) | (col >= pooled)) & (col % 7 != 3)
+            mask = jnp.asarray(keep[None, :])
+        elif hidden == "dead_rows":   # a batch row whose keys are all padding
+            mask, dead = padded_mask(2, Sk, [Sk - 5, 0]), np.s_[1]
+        bf16 = dtype == "bfloat16"
+        assert_walk_matches_scan(
+            monkeypatch, Sq=Sq, Sk=Sk, q_offset=q_offset, k_offset=k_offset,
+            sub=128 if row == "tuned_sub128" else None, kv_mask=mask, B=B,
+            H=4 if heads == "gqa" else 2, Hkv=2, dead_rows=dead, seed=29,
+            dtype=jnp.dtype(dtype), runs=True,
+            tol=(3e-2, 6e-2) if bf16 else (2e-5, 1e-4))
+
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_subtile_walk_matches_scan(self, monkeypatch, case):
+        assert_walk_matches_scan(monkeypatch, **WALK_CASES[case])
+
+    @pytest.mark.parametrize("case", ["chunk_above", "chunk_on",
+                                      "chunk_off_edge", "grid_2x2_blocks"])
+    def test_subtile_walk_keeps_lse(self, monkeypatch, case):
+        """``lse`` keeps its shape and its values, NEG_INF for a row no
+        key reaches (the ring merges chunks by it)."""
+        from apex_tpu.ops import flash_attention_pallas as fap
+
+        c = dict(WALK_CASES[case])
+        c.pop("dead_rows", None)
+        Sq, Sk, block = c["Sq"], c.get("Sk", c["Sq"]), c.get("block")
+        q, k, v, _ = _walk_inputs(1, 2, 2, Sq, Sk, 64, 5)
+        monkeypatch.setattr(fap, "_TUNED_BLOCKS", {})
+        fap.set_tuned_blocks({(Sq, 64, "float32", "fwd"): (Sq, Sk, 128)})
+        qo, ko = c.get("q_offset", 0), c.get("k_offset", 0)
+        out, lse = fap.flash_fwd_pallas(
+            q[0], k[0], v[0], 0.125, True, qo, ko, block_q=block,
+            block_k=block, interpret=True)
+        ref, ref_lse = flash_attention_with_lse(
+            q, k, v, causal=True, softmax_scale=0.125, q_offset=qo,
+            k_offset=ko)
+        assert lse.shape == (2, Sq, 1)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref[0]),
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse[..., 0]),
+                                   np.asarray(ref_lse[0]), rtol=1e-5)
+
+    def test_subtile_falls_back_to_a_divisor(self, monkeypatch):
+        """The sub-tile is brought down to a lane-tile multiple that
+        divides both blocks; where there is none the block is one tile.
+        A tuned row's third column is read whatever the key length."""
+        from apex_tpu.ops import flash_attention_pallas as fap
+
+        monkeypatch.setattr(fap, "_TUNED_BLOCKS", {})
+        f32 = jnp.float32
+        # (sub_q, sub_k, sub-tiles a run at most: RUN_COLUMNS of columns)
+        assert fap._subtiles(1024, 64, f32, "fwd", 1024, 1024) == (256, 256, 4)
+        assert fap._subtiles(384, 64, f32, "fwd", 384, 384) == (128, 128, 8)
+        assert fap._subtiles(320, 64, f32, "fwd", 320, 320) == (320, 320, 1)
+        assert fap._subtiles(2048, 128, f32, "fwd", 1024, 512) == (256, 256, 4)
+        assert fap._subtiles(40, 64, f32, "fwd", 40, 40) == (40, 40, 1)
+        fap.set_tuned_blocks({(2048, 128, "float32", "fwd"): (1024, 512, 512)})
+        assert fap.tuned_blocks(2048, 128, f32) == (1024, 512)
+        assert fap.tuned_subtile(2048, 128, f32) == 512
+        assert fap.tuned_subtile(2048, 128, f32, phase="bwd") is None
+        assert fap._subtiles(2048, 128, f32, "fwd", 1024, 512) == (512, 512, 2)
+        assert fap._subtiles(2048, 128, f32, "fwd", 1024, 640) == (128, 128, 8)
+
+    def test_live_subtiles_counts(self):
+        """The static counter the kernels' code is built from: visited
+        + skipped is the square, the masked ones lie on the diagonal,
+        forward and dkv agree on a square call; the fourth figure, the
+        bodies the kernel's CODE holds: a run of unmasked sub-tiles is
+        one, a crossed sub-tile one, a static variant counted once
+        however many grid blocks run it."""
+        from apex_tpu.ops.flash_attention_pallas import live_subtiles
+
+        for phase in ("fwd", "bwd", "dkv"):
+            # one block, strips of 0 + 1, 1 + 1, 2 + 1, 3 + 1 sub-tiles:
+            # 1 + 2 + 2 + 2 bodies; with runs of one, a body a sub-tile
+            assert live_subtiles(phase, 512, 512, 0, 0, 512, 512, 128) \
+                == (10, 4, 6, 7)
+            assert live_subtiles(phase, 512, 512, 0, 0, 512, 512, 128,
+                                 run=1) == (10, 4, 6, 10)
+            # four blocks, three variants: on the diagonal (1 + 2, run
+            # by two blocks), under it (a run a strip), above it (none)
+            assert live_subtiles(phase, 512, 512, 0, 0, 256, 256, 128) \
+                == (10, 4, 6, 5)
+            # ring chunks: above, on, under the diagonal
+            assert live_subtiles(phase, 256, 256, 0, 256, 256, 256, 128) \
+                == (0, 0, 4, 0)
+            assert live_subtiles(phase, 256, 256, 256, 256, 256, 256, 128) \
+                == (3, 2, 1, 3)
+            assert live_subtiles(phase, 256, 256, 512, 0, 256, 256, 128) \
+                == (4, 0, 0, 2)
+            # not causal: everything, nothing masked, a run a strip (two
+            # query strips of four keys, or four key strips of two)
+            assert live_subtiles(phase, 256, 512, 0, 0, 256, 512, 128,
+                                 causal=False) \
+                == (8, 0, 0, 4 if phase == "dkv" else 2)
+            # the diagonal off a tile's edge crosses two tiles a strip
+            assert live_subtiles(phase, 256, 512, 160, 64, 256, 512, 128) \
+                == (5, 4, 3, 5)
+        # the EVA window over 1,024 pooled rows as ops/eva.py asks for
+        # it: two query blocks of 1,024 over ONE key block of six
+        # sub-tiles of 512: the buffer's two and the triangle's ten
+        # less its diagonal plain, in runs of two; a variant a block
+        assert live_subtiles("fwd", 2048, 3072, 0, -1024, 1024, 3072, 512) \
+            == (18, 4, 6, 12)
+        assert live_subtiles("fwd", 2048, 3072, 0, -1024, 1024, 3072, 512,
+                             run=1) == (18, 4, 6, 18)
+        # a block that is one tile (sub None)
+        assert live_subtiles("fwd", 320, 320, 0, 0, 320, 320, None) \
+            == (1, 1, 0, 1)
 
     def test_impl_validation(self):
         q, k, v = self._inputs(Sq=128, Sk=128)

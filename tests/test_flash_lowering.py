@@ -85,23 +85,57 @@ def test_odd_seq_bf16_lowers_for_tpu():
 def test_tuned_blocks_lower_for_tpu():
     """Whatever the sweep installed must lower for its own shape and
     phase (keys are per-phase ``(S, D, dtype, phase)``; legacy 3-element
-    keys are forward entries)."""
+    keys are forward entries), blocks and sub-tile as the table has
+    them — and the table is not empty: it holds the three cells'
+    shapes (train S 1024 D 64 both phases; the EVA window Sq 2048 D
+    128; the latent prefill D 192)."""
     table = dict(fap._TUNED_BLOCKS)
-    if not table:
-        pytest.skip("no tuned blocks installed yet")
-    for key, (bq, bk) in table.items():
+    assert (1024, 64, "bfloat16", "fwd") in table
+    assert (1024, 64, "bfloat16", "bwd") in table
+    assert any(k[:2] == (2048, 128) for k in table)
+    assert any(k[1] == 192 for k in table)
+    for key, row in table.items():
         S, D, dtype = key[:3]
         phase = key[3] if len(key) == 4 else "fwd"
         q = jax.ShapeDtypeStruct((4, S, D), jnp.dtype(dtype))
+        # no explicit blocks: the call reads its own row, sub-tile too
+        assert fap.tuned_blocks(S, D, dtype, phase=phase) == tuple(row[:2])
         if phase == "fwd":
             _lower(lambda q, k, v: fap.flash_fwd_pallas(
-                q, k, v, 1.0 / D ** 0.5, True, 0, 0,
-                block_q=bq, block_k=bk, heads=4), q, q, q)
+                q, k, v, 1.0 / D ** 0.5, True, 0, 0, heads=4), q, q, q)
         else:
             r = jax.ShapeDtypeStruct((4, S, 1), jnp.float32)
             _lower(lambda q, k, v, o, lse, do: fap.flash_bwd_pallas(
                 q, k, v, o, lse, do, 1.0 / D ** 0.5, True, 0, 0,
-                block_q=bq, block_k=bk, heads=4), q, q, q, q, r, q)
+                heads=4), q, q, q, q, r, q)
+
+
+@pytest.mark.parametrize("pooled", [0, 512, 1024])
+def test_eva_window_call_lowers_for_tpu(pooled):
+    """The EVA window's forward at the cell's shape: a pooled buffer
+    before the window's keys (``k_offset`` < 0), a key bias, ONE key
+    block over buffer and window as the caller asks, and the shape's
+    sub-tile; with no buffer, one block."""
+    H, W, D = 32, 2048, 128
+    q = jax.ShapeDtypeStruct((H, W, D), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((H, W + pooled, D), jnp.bfloat16)
+    if not pooled:
+        _lower(lambda q, k, v: fap.flash_fwd_pallas(
+            q, k, v, D ** -0.5, True, 0, 0, heads=H), q, k, k)
+        return
+    b = jax.ShapeDtypeStruct((1, 1, W + pooled), jnp.float32)
+    _lower(lambda q, k, v, b: fap.flash_fwd_pallas(
+        q, k, v, D ** -0.5, True, 0, -pooled, block_k=W + pooled,
+        kv_bias=b, heads=H), q, k, k, b)
+
+
+@pytest.mark.parametrize("S", [512, 4096])
+def test_latent_prefill_call_lowers_for_tpu(S):
+    """The latent family's prefill forward (keys 192 wide), one block
+    and a grid of blocks."""
+    q = jax.ShapeDtypeStruct((32, S, 192), jnp.bfloat16)
+    _lower(lambda q, k, v: fap.flash_fwd_pallas(
+        q, k, v, 192 ** -0.5, True, 0, 0, heads=32), q, q, q)
 
 
 # ------------------------------------------- flash sweep + installer
@@ -142,7 +176,9 @@ def test_flash_sweep_quick_interpret_smoke(tmp_path):
         fap.set_tuned_blocks(table)
         for key, val in table:
             s, d, dtype, phase = key
-            assert fap.tuned_blocks(s, d, dtype, phase=phase) == tuple(val)
+            assert fap.tuned_blocks(s, d, dtype, phase=phase) == tuple(val[:2])
+            assert fap.tuned_subtile(s, d, dtype, phase=phase) == (
+                val[2] if len(val) > 2 else None)
     finally:
         fap._TUNED_BLOCKS.clear()
         fap._TUNED_BLOCKS.update(saved)

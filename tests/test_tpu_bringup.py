@@ -301,12 +301,18 @@ CASES = {
                                    {"apex_flash_fwd"}),
     "eva_window_attention_pooled": (*_eva_window_attention(1024),
                                     {"apex_flash_fwd"}),
+    "eva_window_attention_pooled512": (*_eva_window_attention(512),
+                                       {"apex_flash_fwd"}),
     "eva_write_decode": (*_eva_write(), {"apex_kv_write"}),
     "eva_write_open_window": (*_eva_write(16), {"apex_kv_write"}),
     "sample_greedy_v320": (*_sample(0.0, 0, rows=20, hidden=4096, vocab=320,
                                     embed=BF16, x_dtype=F32, dot_dtype=F32),
                            {"apex_fused_sample"}),
     "flash_fwd_qk192": (*_flash_qk192_v128(), {"apex_flash_fwd"}),
+    # the latent family's prefill buckets' ends: one block, and a grid
+    # of blocks whose static variants the grid indices choose among
+    "flash_fwd_qk192_s512": (*_flash_qk192_v128(512), {"apex_flash_fwd"}),
+    "flash_fwd_qk192_s4096": (*_flash_qk192_v128(4096), {"apex_flash_fwd"}),
     # training, GPT-345M and GPT-124M shapes
     "flash_345m": (*_flash(16, 16),
                    {"apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv"}),
@@ -333,13 +339,27 @@ def test_kernel_lowers_for_tpu(name):
     assert "tpu_custom_call" in exp.mlir_module()
 
 
+def _pallas_calls(jaxpr, found=None):
+    """Every ``pallas_call`` equation of a jaxpr, sub-jaxprs included."""
+    found = [] if found is None else found
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            found.append(e)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if hasattr(sub, "jaxpr"):
+                    _pallas_calls(sub.jaxpr, found)
+                elif hasattr(sub, "eqns"):
+                    _pallas_calls(sub, found)
+    return found
+
+
 def _lowered_grid_mapping(name):
     """The grid mapping of the one ``pallas_call`` a case lowers to."""
     fn, avals, _ = CASES[name]
     jaxpr = jax.make_jaxpr(fn)(
         *[jax.ShapeDtypeStruct(s, d) for s, d in avals])
-    call, = [e for e in jaxpr.jaxpr.eqns
-             if e.primitive.name == "pallas_call"]
+    call, = _pallas_calls(jaxpr.jaxpr)
     return call.params["grid_mapping"]
 
 
@@ -367,6 +387,138 @@ def test_decode_attention_grid_at_the_cells_shapes():
     h_blk, grid = _plan(B, heads, 1, 256, P, page, F32)
     assert 1 <= h_blk < heads and heads % h_blk == 0
     assert grid == (B, heads // h_blk)
+
+
+def _dispatched(Sq, Sk, D, phase, block_q=None, block_k=None):
+    """(bq, bk, sub) the dispatcher gives a bf16 call of this shape."""
+    from apex_tpu.ops import flash_attention_pallas as fap
+
+    bq, bk, (side, _, _) = fap.dispatched(Sq, Sk, D, BF16, phase,
+                                          block_q=block_q, block_k=block_k)
+    return bq, bk, side if side < max(bq, bk) else None
+
+
+def _eva_blocks(pooled):
+    """The blocks of the EVA window's forward over ``pooled`` buffer
+    rows: ``ops/eva._window_pallas`` asks for ONE key block over buffer
+    and window."""
+    return _dispatched(2048, 2048 + pooled, 128, "fwd",
+                       block_k=2048 + pooled if pooled else None)
+
+
+def test_flash_attention_walk_at_the_cells_shapes():
+    """The flash kernels walk a grid block in sub-tiles and visit the
+    live triangle only.  At the train cell's shape (S 1024, D 64, bf16)
+    the forward, dq and dkv each visit at most 5/8 of the square's
+    sub-tiles, mask only those on the diagonal, and take no more grid
+    steps a head than before PR 36 (1 forward, 4 each backward): the
+    shape's row of the tuned table, as the lowered kernels have it.
+    The latent prefill and the EVA window's first-window call visit no
+    sub-tile wholly above the diagonal either; the EVA window's call
+    over a pooled buffer takes ONE key block over buffer and window, as
+    its caller asks (no state carried between grid steps), and walks
+    the buffer's sub-tiles unmasked beside the window's triangle."""
+    from apex_tpu.ops import flash_attention_pallas as fap
+
+    S, D = 1024, 64
+    fn, avals, _ = CASES["flash_345m"]
+    grids = {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+             for e in _pallas_calls(jax.make_jaxpr(fn)(
+                 *[jax.ShapeDtypeStruct(s, d) for s, d in avals]).jaxpr)}
+    for phase, kernels, steps_before in (
+            ("fwd", ("fwd",), 1), ("bwd", ("dq", "dkv"), 4)):
+        row = fap.tuned_blocks(S, D, BF16, phase=phase)
+        assert row is not None, "the train cell's shape has a measured row"
+        bq, bk, sub = _dispatched(S, S, D, phase)
+        assert (bq, bk) == row and sub and S % sub == 0
+        steps = (S // bq) * (S // bk)
+        assert steps <= steps_before
+        for kernel in kernels:
+            assert grids["apex_flash_" + kernel] == (
+                (128, S // bq, S // bk) if kernel != "dkv"
+                else (128, S // bk, S // bq))
+            visited, masked, skipped, _ = fap.live_subtiles(
+                kernel, S, S, 0, 0, bq, bk, sub)
+            assert 8 * visited <= 5 * (visited + skipped)
+            assert masked == S // sub          # the diagonal's, no other
+            n = S // sub
+            assert visited == n * (n + 1) // 2  # the triangle exactly
+
+    # the EVA window (Sq 2048, D 128): pooled buffer, then the window
+    bq, bk, sub = _dispatched(2048, 2048, 128, "fwd")
+    assert (bq, bk) == (2048, 2048)
+    n = 2048 // sub
+    assert fap.live_subtiles("fwd", 2048, 2048, 0, 0, bq, bk, sub)[:2] == (
+        n * (n + 1) // 2, n)
+    for pooled in (512, 1024):
+        Sk = 2048 + pooled
+        bq, bk, sub = _eva_blocks(pooled)
+        assert (bq, bk, sub) == (1024, Sk, 512)
+        visited, masked, skipped, _ = fap.live_subtiles(
+            "fwd", 2048, Sk, 0, -pooled, bq, bk, sub)
+        n = 2048 // sub
+        # the buffer whole, the window's triangle, its diagonal masked
+        assert (visited, masked) == (
+            n * (pooled // sub) + n * (n + 1) // 2, n)
+        assert visited + skipped == n * (Sk // sub)
+        assert tuple(_lowered_grid_mapping(
+            "eva_window_attention_pooled" + "512" * (pooled == 512)
+        ).grid) == (32, 2, 1)
+    # the latent family's prefill (D 192), every bucket
+    for S in (256, 512, 1024, 2048, 4096):
+        bq, bk, sub = _dispatched(S, S, 192, "fwd")
+        visited, masked, skipped, _ = fap.live_subtiles(
+            "fwd", S, S, 0, 0, bq, bk, sub)
+        n = S // (sub or bq)
+        assert (visited, masked) == (n * (n + 1) // 2, n)
+
+
+#: bodies (copies of the tile arithmetic in a kernel's code) a kernel of
+#: a cell may hold: what PR 37 reads (train 7 each kernel; the EVA
+#: window 8 / 10 / 12 over 0 / 512 / 1,024 pooled rows: a strip is the
+#: buffer and the window's plain sub-tiles in runs of two, then its
+#: diagonal's; the latent prefill 1, 3, 7, 18, 14 over its buckets).
+#: And the bytes of the EVA window call's serialized module, 1.5 x what
+#: PR 37 reads (13,058 / 17,374 / 18,359; the grid of blocks before it
+#: 10,542 / 12,718 / 12,718).  PR 36's walk, a body a sub-tile, held 10
+#: a train kernel and 36-68 an EVA window in 26,382 / 39,790 / 47,654
+#: bytes, and its eight windowed prefill programs cost 32% of warm
+#: set-up (ledger, PR 36: refused)
+BODIES_CEILING = {"train": 7, "eva": 12, "latent": 18}
+EVA_MODULE_BYTES_CEILING = {0: 19_600, 512: 26_000, 1024: 27_500}
+
+
+def test_flash_code_size_at_the_cells_shapes():
+    """What a flash kernel costs a program BEFORE it runs (trace, lower,
+    compile, load; once a compiled program, eight in the windowed cell)
+    grows with the code it emits, and the straight-line walk emits a
+    body a run of sub-tiles: deterministic figures, held here on the
+    CPU.  The counter's fourth figure at the cells' shapes, and the
+    serialized module of the EVA window call as ``jax.export`` lowers
+    it for the TPU."""
+    from jax import export
+
+    from apex_tpu.ops import flash_attention_pallas as fap
+
+    bodies = lambda kernel, Sq, Sk, k_offset, blocks: fap.live_subtiles(
+        kernel, Sq, Sk, 0, k_offset, *blocks)[3]
+    for kernel in ("fwd", "dq", "dkv"):
+        phase = "fwd" if kernel == "fwd" else "bwd"
+        assert bodies(kernel, 1024, 1024, 0, _dispatched(
+            1024, 1024, 64, phase)) <= BODIES_CEILING["train"], kernel
+    for S in (256, 512, 1024, 2048, 4096):
+        assert bodies("fwd", S, S, 0, _dispatched(S, S, 192, "fwd")) \
+            <= BODIES_CEILING["latent"], S
+    for pooled in (0, 512, 1024):
+        Sk = 2048 + pooled
+        blocks = _eva_blocks(pooled)
+        assert fap.live_subtiles("fwd", 2048, Sk, 0, -pooled, *blocks)[3] \
+            <= BODIES_CEILING["eva"], pooled
+        fn, avals = _eva_window_attention(pooled)
+        module = export.export(jax.jit(fn), platforms=["tpu"])(
+            *[jax.ShapeDtypeStruct(s, d) for s, d in avals])
+        size = len(module.mlir_module_serialized)
+        assert size <= EVA_MODULE_BYTES_CEILING[pooled], (pooled, size)
 
 
 def test_mla_decode_grid_at_the_cells_shapes():
