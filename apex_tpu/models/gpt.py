@@ -1069,7 +1069,7 @@ def _telemetry_wrap(fn, n_state, has_scaler, telemetry):
 
 
 def _step_variant(loss_scaler, step_guard, variants, specs, sspec,
-                  data_spec, telemetry=None):
+                  data_spec, telemetry=None, wrap=None):
     """Pick the local-step variant and its shard_map specs for a
     scaler×guard(×telemetry) combination.  ``variants`` maps
     (has_scaler, has_guard) to the local step fn; each enabled feature
@@ -1077,7 +1077,8 @@ def _step_variant(loss_scaler, step_guard, variants, specs, sspec,
     state, then the StepStats window) between the optimizer state and
     the data, and one replicated output before the loss.  Returns
     ``(fn, in_specs, out_specs, stats_argnum)`` — ``stats_argnum`` is
-    the StepStats position (for donation), or None."""
+    the StepStats position (for donation), or None.  ``wrap``: applied
+    to the chosen step last (a family's state, ``make_train_step``)."""
     from jax.sharding import PartitionSpec as P
 
     fn = variants[(loss_scaler is not None, step_guard is not None)]
@@ -1088,6 +1089,8 @@ def _step_variant(loss_scaler, step_guard, variants, specs, sspec,
                              telemetry)
         stats_argnum = 2 + n_state
         n_state += 1
+    if wrap is not None:    # outermost: the telemetry sees what it wraps
+        fn = wrap(fn)
     state_specs = (P(),) * n_state
     in_specs = (specs, sspec, *state_specs, data_spec, data_spec)
     out_specs = (specs, sspec, *state_specs, P())
@@ -1424,9 +1427,45 @@ def make_train_step(
     then the fused optimizer update on local shards.
     Without a scaler, returns
     ``step(params, opt_state, tokens, targets) -> (params, opt_state, loss)``.
+
+    **A second family.**  ``config`` may be another family's
+    (``config.train_family()``, as the scheduler asks a config for its
+    ``served_model()``; today :class:`apex_tpu.models.afmoe.AFMoEConfig`):
+    the family gives the loss with its auxiliary outputs, the parameter
+    specs, which leaves are STATE that no optimizer touches (a router's
+    choice-only bias, device-side counters) and how a step moves them,
+    and which leaves are experts.  The optimizer's tree is then
+    ``family.split(params)[0]`` (init the optimizer on that); the step
+    differentiates and updates it alone, then applies
+    ``family.update_state`` to the rest from the loss's auxiliary
+    outputs (summed over the data axis), AFTER the optimizer.  Optimizer
+    application, donation, scaler, guard and telemetry are the tails
+    below, unchanged; what a family does not have yet (tensor and
+    context parallelism, ZeRO over its experts, quantized or overlapped
+    sync, ``spmd="auto"``) raises.
     """
     if spmd not in ("shard_map", "auto"):
         raise ValueError(f"spmd must be 'shard_map' or 'auto', got {spmd!r}")
+    family = (config.train_family() if hasattr(config, "train_family")
+              else None)
+    if family is not None:
+        for bad, why in (
+            (spmd == "auto", "spmd='auto'"),
+            (mesh.shape.get(tp_axis, 1) > 1, "tensor parallelism"),
+            (cp_axis is not None, "context parallelism"),
+            (isinstance(dp_axis, (tuple, list)), "hierarchical dp"),
+            (hasattr(optimizer, "state_partition_spec"),
+             "a ZeRO optimizer (its experts' leaves are not dp wires)"),
+            (grad_sync_dtype is not None, "grad_sync_dtype"),
+            (overlap_grad_sync, "overlap_grad_sync"),
+        ):
+            if bad:
+                raise NotImplementedError(
+                    f"make_train_step for {type(config).__name__} does "
+                    f"not take {why} yet")
+    # GPT's own switches; another family has neither
+    gpt_moe = bool(getattr(config, "moe", False))
+    sequence_parallel = bool(getattr(config, "sequence_parallel", False))
     if spmd == "auto":
         for arg, name in ((cp_axis, "cp_axis"), (chaos, "chaos"),
                           (grad_sync_dtype, "grad_sync_dtype")):
@@ -1463,12 +1502,12 @@ def make_train_step(
                 f"a hierarchical dp_axis is the (outer, inner) pair — or "
                 f"the (dcn, outer, inner) triple — of mesh axes ordered "
                 f"slow to fast, got {dp_axis!r}")
-        if config.moe:
+        if gpt_moe:
             raise NotImplementedError(
                 "MoE expert parallelism over a hierarchical dp split is "
                 "not wired (EP rides a single dp axis)")
 
-    ep_axis = dp_axis if config.moe else None  # EP rides DP
+    ep_axis = dp_axis if gpt_moe else None  # EP rides DP
     if ep_axis is not None:
         ep = mesh.shape[ep_axis]
         if config.moe_num_experts % ep != 0:
@@ -1477,7 +1516,12 @@ def make_train_step(
                 f"by the '{ep_axis}' mesh axis size ({ep}): experts shard over "
                 "dp (EP rides DP)"
             )
-    specs = param_specs(config, ep_axis=ep_axis)
+    specs = (param_specs(config, ep_axis=ep_axis) if family is None
+             else family.param_specs())
+    #: a family step's state and the loss's auxiliary outputs, within
+    #: one trace of the step: set by ``with_family_state`` below, read
+    #: and filled by ``value_and_grads``
+    cell = {}
 
     qspec = None
     if grad_sync_dtype is not None:
@@ -1495,7 +1539,7 @@ def make_train_step(
                 "a ZeRO optimizer owns the dp grad sync: pass "
                 "grad_sync_dtype to its constructor (where it gains the "
                 "error-feedback residual), not to make_train_step")
-        if config.moe:
+        if gpt_moe:
             raise NotImplementedError(
                 "quantized dp sync + MoE is not wired: expert grads are "
                 "dp-sharded sums, not pmean'd")
@@ -1528,7 +1572,7 @@ def make_train_step(
             # compressed sync, minus the residual — no state channel)
             return _quantized_sync.quantized_pmean(
                 grads, ax, qspec, world=mesh.shape[dp_axis])
-        if not (skip_experts and config.moe):
+        if not (skip_experts and gpt_moe):
             return jax.tree.map(lambda g: jax.lax.pmean(g, ax), grads)
         from apex_tpu.transformer.expert_parallel import EXPERT_PARAM_KEYS
 
@@ -1547,7 +1591,7 @@ def make_train_step(
     # sync via its per-bucket reduce-scatter; grads then stay local
     # over dp and the collectives live inside the optimizer.
     zero_opt = hasattr(optimizer, "state_partition_spec")
-    if zero_opt and config.moe:
+    if zero_opt and gpt_moe:
         raise NotImplementedError(
             "ZeRO + MoE expert sharding both claim the dp axis; not wired"
         )
@@ -1555,9 +1599,9 @@ def make_train_step(
 
     if overlap_grad_sync:
         for bad, why in (
-            (config.moe, "MoE (expert grads are dp-sharded sums, not "
+            (gpt_moe, "MoE (expert grads are dp-sharded sums, not "
              "bucketed pmean wires)"),
-            (config.sequence_parallel, "sequence parallelism "
+            (sequence_parallel, "sequence parallelism "
              "(sp_grad_sync is a whole-tree pass after the backward)"),
             (cp_axis is not None, "context parallelism (cp grads need "
              "a second pmean after the backward)"),
@@ -1583,7 +1627,7 @@ def make_train_step(
         so pmean over cp (and dp) recovers the global-mean-loss grads.
         With ``overlap_grad_sync`` the dp sync already happened inside
         the backward (per bucket), so only the loss pmean remains."""
-        if config.sequence_parallel:
+        if sequence_parallel:
             grads = sp_grad_sync(grads, tp_axis)
         for ax in (cp_axis, dp_axis):
             if ax is not None:
@@ -1730,6 +1774,15 @@ def make_train_step(
         ``(scaled_loss, grads, presynced)``.  Monolithic
         ``value_and_grad`` with ``presynced=None`` normally; the
         segmented overlapped backward when ``overlap_grad_sync``."""
+        if family is not None:
+            def family_loss_fn(p):
+                loss, aux = family.loss(family.merge(p, cell["state"]),
+                                        tokens, targets)
+                return post_loss(loss), aux
+
+            (scaled_loss, cell["aux"]), grads = jax.value_and_grad(
+                family_loss_fn, has_aux=True)(params)
+            return scaled_loss, grads, None
         if not overlap_grad_sync:
             def loss_fn(p):
                 return post_loss(gpt_loss(p, tokens, targets, config,
@@ -1779,7 +1832,7 @@ def make_train_step(
     # ranks can disagree too — every such axis must join the vote
     # (pmean'd axes already agree: a nan poisons every rank's copy)
     sync_axes = [tp_axis]
-    if (zero_opt or config.moe) and dp_axis is not None:
+    if (zero_opt or gpt_moe) and dp_axis is not None:
         sync_axes.extend(dp_axis if dp_hier else (dp_axis,))
 
     def local_step(params, opt_state, tokens, targets):
@@ -1860,12 +1913,29 @@ def make_train_step(
 
         return AdamState(step=P(), exp_avg=params_spec, exp_avg_sq=params_spec, master=None)
 
+    def with_family_state(local):
+        """A local step over the family's whole tree: the optimizer's
+        part goes through ``local`` (any of the variants, telemetry
+        included), the state is moved after it from the loss's
+        auxiliary outputs, summed over the data axis."""
+        def stepped(params, opt_state, *rest):
+            trainable, cell["state"] = family.split(params)
+            out = local(trainable, opt_state, *rest)
+            aux, state = cell.pop("aux"), cell.pop("state")
+            if dp_axis is not None:
+                aux = jax.tree.map(lambda a: jax.lax.psum(a, dp_axis), aux)
+            return (family.merge(out[0], family.update_state(state, aux)),
+                    *out[1:])
+
+        return stepped
+
     if opt_state_spec is not None:
         sspec = opt_state_spec
     elif zero_opt:
         sspec = optimizer.state_partition_spec()
     else:
-        sspec = state_spec_of(specs)
+        sspec = state_spec_of(specs if family is None
+                              else family.split(specs)[0])
     data_spec = P(dp_axis, cp_axis)  # batch over dp, sequence over cp
 
     donate = (0, 1) if donate_state else ()
@@ -1875,7 +1945,8 @@ def make_train_step(
          (True, False): scaled_local_step,
          (False, True): guarded_local_step,
          (False, False): local_step},
-        specs, sspec, data_spec, telemetry=telemetry)
+        specs, sspec, data_spec, telemetry=telemetry,
+        wrap=None if family is None else with_family_state)
     if stats_argnum is not None:
         # the StepStats window is always rebound (fetch swaps in fresh
         # zeros), so its tiny buffers always donate

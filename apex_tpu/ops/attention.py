@@ -66,10 +66,18 @@ def _bias_blocks(kv_bias, B, nblocks, bk):
     return jnp.moveaxis(blocks, 3, 0)  # (nblocks, b0, h0, q0, bk)
 
 
+def _causal_mask(q_pos, k_pos, window):
+    """Key ``j`` visible to query ``i``: ``j <= i``, and under a sliding
+    ``window`` ``i - j < window`` too."""
+    gap = q_pos[:, None] - k_pos[None, :]
+    return gap >= 0 if window is None else (gap >= 0) & (gap < window)
+
+
 def _attend_fwd_scan(q, k, v, scale, causal, q_offset, k_offset, block_k,
-                     kv_bias=None):
+                     kv_bias=None, window=None):
     """Online-softmax forward.  q: (B,H,Sq,D), k/v: (B,H,Sk,D).
     ``kv_bias``: optional (B, Sk) f32 additive key bias (padding masks).
+    ``window``: a sliding window over a causal call (:func:`_causal_mask`).
     Returns (out, lse) with lse = log Σ exp(s·scale) per row."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
@@ -94,8 +102,7 @@ def _attend_fwd_scan(q, k, v, scale, causal, q_offset, k_offset, block_k,
         if bblk is not None:
             s = s + bblk
         if causal:
-            mask = q_pos[:, None] >= k_pos[None, :]
-            s = jnp.where(mask, s, NEG_INF)
+            s = jnp.where(_causal_mask(q_pos, k_pos, window), s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         # exp(NEG_INF - NEG_INF) = 1 would give fully-masked rows (ring
         # warmup blocks, fully-padded batch entries) a spurious uniform
@@ -121,21 +128,23 @@ def _attend_fwd_scan(q, k, v, scale, causal, q_offset, k_offset, block_k,
     return out, lse
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, kv_bias, scale, causal, q_offset, k_offset, block_k):
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, kv_bias, scale, causal, q_offset, k_offset, block_k,
+           window=None):
     out, _ = _attend_fwd_scan(q, k, v, scale, causal, q_offset, k_offset,
-                              block_k, kv_bias=kv_bias)
+                              block_k, kv_bias=kv_bias, window=window)
     return out.astype(q.dtype)
 
 
-def _flash_fwd(q, k, v, kv_bias, scale, causal, q_offset, k_offset, block_k):
+def _flash_fwd(q, k, v, kv_bias, scale, causal, q_offset, k_offset, block_k,
+               window=None):
     out, lse = _attend_fwd_scan(q, k, v, scale, causal, q_offset, k_offset,
-                                block_k, kv_bias=kv_bias)
+                                block_k, kv_bias=kv_bias, window=window)
     return out.astype(q.dtype), (q, k, v, kv_bias, out, lse)
 
 
 def flash_bwd_from_lse(q, k, v, g, lse, delta, scale, causal, q_offset=0,
-                       k_offset=0, block_k=256, kv_bias=None):
+                       k_offset=0, block_k=256, kv_bias=None, window=None):
     """Blockwise flash backward from (lse, delta): dV = PᵀdO;
     dS = P∘(dOVᵀ − Δ); dQ = dS·K·scale; dK = dSᵀ·Q·scale with
     Δ = rowsum(dO∘O) over the FULL row — pass it in when this call sees
@@ -170,8 +179,7 @@ def flash_bwd_from_lse(q, k, v, g, lse, delta, scale, causal, q_offset=0,
         if bblk is not None:
             s = s + bblk
         if causal:
-            mask = q_pos[:, None] >= k_pos[None, :]
-            s = jnp.where(mask, s, NEG_INF)
+            s = jnp.where(_causal_mask(q_pos, k_pos, window), s, NEG_INF)
         p = jnp.exp(s - lse[..., None])  # (B,H,Sq,bk)
         if remask:  # fully-masked rows have lse == NEG_INF: exp(0) = 1
             p = jnp.where(s > NEG_INF / 2, p, 0.0)
@@ -204,12 +212,12 @@ def flash_bwd_from_lse(q, k, v, g, lse, delta, scale, causal, q_offset=0,
     return dq, dk, dv, db
 
 
-def _flash_bwd(scale, causal, q_offset, k_offset, block_k, res, g):
+def _flash_bwd(scale, causal, q_offset, k_offset, block_k, window, res, g):
     q, k, v, kv_bias, out, lse = res
     delta = jnp.sum(g.astype(jnp.float32) * out, axis=-1)  # (B,H,Sq)
     outs = flash_bwd_from_lse(
         q, k, v, g, lse, delta, scale, causal, q_offset, k_offset, block_k,
-        kv_bias=kv_bias,
+        kv_bias=kv_bias, window=window,
     )
     if kv_bias is None:
         dq, dk, dv = outs
@@ -237,8 +245,14 @@ def flash_attention(
     block_q: Optional[int] = None,
     kv_mask: Optional[jnp.ndarray] = None,
     attn_bias: Optional[jnp.ndarray] = None,
+    window: Optional[int] = None,
 ):
     """Memory-efficient attention, (B, H, S, D) layout.
+
+    ``window``: a static sliding window over a causal call: key ``j`` is
+    visible to query ``i`` iff ``0 <= i - j < window`` (the query's own
+    key among the ``window``).  The Pallas kernels (forward, dq, dkv)
+    visit the band's blocks and sub-tiles only; the scan path masks.
 
     ``q_offset``/``k_offset`` give the global sequence positions of the
     local blocks (used by ring attention for cross-device causal masks).
@@ -269,6 +283,10 @@ def flash_attention(
     if impl not in ("auto", "pallas", "scan"):
         raise ValueError(f"impl must be 'auto', 'pallas', or 'scan'; got {impl!r}")
     scale = softmax_scale if softmax_scale is not None else 1.0 / np.sqrt(q.shape[-1])
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError(f"a window ({window}) needs causal=True and at "
+                         f"least one key")
+    window = None if window is None else int(window)
 
     def scan_impl(q=q, k=k, v=v, attn_bias=attn_bias):
         k, v = repeat_kv_heads(q, k, v)
@@ -281,7 +299,7 @@ def flash_attention(
             pad = padding_bias(kv_mask)
             bias = pad if bias is None else bias + pad[:, None, None, :]
         return _flash(q, k, v, bias, scale, causal, q_offset, k_offset,
-                      block_k or 256)
+                      block_k or 256, window)
 
     if impl != "scan" and attn_bias is None:
         from apex_tpu.ops.flash_attention_pallas import (
@@ -307,6 +325,7 @@ def flash_attention(
                     q, k, v, causal=causal, softmax_scale=scale,
                     q_offset=q_offset, k_offset=k_offset,
                     block_q=block_q, block_k=block_k, kv_mask=kv_mask,
+                    window=window,
                 )
 
             if registry_engaged(forced=(impl == "pallas")):
@@ -326,7 +345,8 @@ def flash_attention_with_lse(
     return out, lse
 
 
-def mha_reference(q, k, v, causal=True, softmax_scale=None, kv_mask=None):
+def mha_reference(q, k, v, causal=True, softmax_scale=None, kv_mask=None,
+                  window=None):
     """Naive O(S²)-memory oracle for tests (GQA via head repeat)."""
     k, v = repeat_kv_heads(q, k, v)
     scale = softmax_scale if softmax_scale is not None else 1.0 / np.sqrt(q.shape[-1])
@@ -334,6 +354,8 @@ def mha_reference(q, k, v, causal=True, softmax_scale=None, kv_mask=None):
     if causal:
         Sq, Sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq - window)
         s = jnp.where(mask, s, NEG_INF)
     if kv_mask is not None:
         s = jnp.where(kv_mask[:, None, None, :], s, NEG_INF)

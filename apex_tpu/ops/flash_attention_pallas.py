@@ -66,6 +66,7 @@ numerics specification and the universal fallback (CPU, odd shapes).
 """
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -106,8 +107,7 @@ _DIM_SEMANTICS = pltpu.CompilerParams(
 # before the _pick_block static heuristic.
 # Legacy 3-tuple (seq_q, head_dim, dtype) keys are read as fwd-only.
 _TUNED_BLOCKS: dict = {
-    # measured: TPU v5 lite (v5e), one chip, 2026-09-30: kernels timed alone, 50 calls chained a program (benchmarks/flash_sweep.py); rows of PR 36's sweep, read again with runs in PR 37's;
-    # (2048, 128): sub-tile 512 (0.299 ms, 8 bodies) over 256 (0.284 ms, 18 bodies): the EVA window's programs pay for code eight times (PERF.md, PR 37)
+    # measured: TPU v5 lite (v5e), one chip, kernels timed alone, chained calls a program. Rows of D 64 and 192 and of (2048, 128): 2026-09-30, PR 36's sweep read again with runs in PR 37's; (2048, 128): sub-tile 512 (0.299 ms, 8 bodies) over 256 (0.284 ms, 18 bodies): the EVA window's programs pay for code eight times (PERF.md, PR 37). Rows of (8192, 128): 2026-09-30, PR 38's sweep (--shapes afmoe: GQA 32:4, a band of 2,048 keys and the causal triangle, three band calls weighed to one full call, ms a sequence; bodies summed over the four kernels): fwd (1024, 2048, 512) 15.79 ms, 28 bodies, where no row (1024, 1024, 256) read 17.46 and the fastest (2048, 2048, 512) 15.47 (its full call is refused for VMEM by the compile-only v5e client); bwd (1024, 1024, 256) 29.80 ms, 58 bodies, where no row (512, 512, 256) read 36.08 and the fastest (2048, 2048, 256) 27.77 with 140 bodies (PERF.md, PR 38) (benchmarks/flash_sweep.py)
     (512, 192, 'bfloat16', 'fwd'): (512, 512, 256),
     (1024, 64, 'bfloat16', 'bwd'): (1024, 1024, 256),
     (1024, 64, 'bfloat16', 'fwd'): (1024, 1024, 256),
@@ -115,6 +115,8 @@ _TUNED_BLOCKS: dict = {
     (2048, 128, 'bfloat16', 'fwd'): (2048, 2048, 512),
     (2048, 192, 'bfloat16', 'fwd'): (1024, 2048, 256),
     (4096, 192, 'bfloat16', 'fwd'): (2048, 512, 512),
+    (8192, 128, 'bfloat16', 'bwd'): (1024, 1024, 256),
+    (8192, 128, 'bfloat16', 'fwd'): (1024, 2048, 512),
 }
 
 _PHASES = ("fwd", "bwd")
@@ -211,60 +213,137 @@ def _clip(x, lo, hi):
     return min(max(x, lo), hi)
 
 
-def _key_bounds(causal, gap, sub_q, sub_k, n):
+def _key_bounds(causal, gap, sub_q, sub_k, n, window=None):
     """For a strip of ``sub_q`` query rows over the ``n`` key sub-tiles
     of ``sub_k`` columns of a block, ``gap`` = the strip's first global
-    row less the block's first global column: ``(full, seen)``.
-    Sub-tiles ``[0, full)`` lie wholly under the diagonal, ``[full,
-    seen)`` are crossed by it, ``[seen, n)`` lie wholly above it."""
+    row less the block's first global column: ``(dead, low, full,
+    seen)``.  Sub-tiles ``[dead, seen)`` are visited: ``[full, seen)``
+    are crossed by the diagonal, ``[seen, n)`` lie wholly above it;
+    under a ``window`` (key ``j`` visible to query ``i`` iff ``0 <= i -
+    j < window``) ``[0, dead)`` lie wholly under the band and ``[dead,
+    low)`` are crossed by its lower edge (no window: both 0)."""
     if not causal:
-        return n, n
+        return 0, 0, n, n
     full = _clip((gap + 1) // sub_k, 0, n)
     seen = _clip((gap + sub_q - 1) // sub_k + 1, 0, n)
-    return full, seen
+    if window is None:
+        return 0, 0, full, seen
+    dead = _clip((gap - window + 1) // sub_k, 0, n)
+    if dead >= seen:        # nothing to visit: above the diagonal, or under the band
+        return (0, 0, 0, 0) if seen == 0 else (n, n, n, n)
+    low = _clip(-((window - gap - sub_q) // sub_k), dead, seen)
+    return dead, low, _clip(full, dead, seen), seen
 
 
-def _query_bounds(causal, lead, sub_k, sub_q, n):
+def _query_bounds(causal, lead, sub_k, sub_q, n, window=None):
     """For a strip of ``sub_k`` keys over the ``n`` query sub-tiles of
     ``sub_q`` rows of a block, ``lead`` = the strip's first global
-    column less the block's first global row: ``(first, full)``.
-    Sub-tiles ``[0, first)`` lie wholly above the diagonal, ``[first,
-    full)`` are crossed by it, ``[full, n)`` lie wholly under it."""
+    column less the block's first global row: ``(first, full, high,
+    end)``.  Sub-tiles ``[first, end)`` are visited: ``[0, first)`` lie
+    wholly above the diagonal, ``[first, full)`` are crossed by it;
+    under a ``window`` ``[high, end)`` are crossed by the band's lower
+    edge and ``[end, n)`` lie wholly under it (no window: both ``n``)."""
     if not causal:
-        return 0, 0
+        return 0, 0, n, n
     first = _clip(lead // sub_q, 0, n)
     full = _clip(-((-lead - sub_k + 1) // sub_q), 0, n)
-    return first, full
+    if window is None:
+        return first, full, n, n
+    end = _clip(-((-(window + lead + sub_k - 1)) // sub_q), 0, n)
+    if first >= end:
+        return (n, n, n, n) if first == n else (0, 0, 0, 0)
+    high = _clip((window + lead) // sub_q, first, end)
+    return first, _clip(full, first, end), high, end
 
 
-def _strip_plan(phase, causal, d, bq, bk, sub_q, sub_k):
+def _strip_plan(phase, causal, d, bq, bk, sub_q, sub_k, window=None):
     """The bounds of every strip of a block whose first query row lies
-    ``d`` past its first key column: by query strip ``(full, seen)``
-    (forward, dq), by key strip ``(first, full)`` (``"dkv"``)."""
+    ``d`` past its first key column: by query strip ``(dead, low, full,
+    seen)`` (forward, dq), by key strip ``(first, full, high, end)``
+    (``"dkv"``).  Either way a strip visits sub-tiles ``[a, d)`` of its
+    four numbers ``(a, b, c, d)``, masks those under ``b`` and those
+    from ``c`` on, and computes ``[b, c)`` plain."""
     if phase == "dkv":
         return tuple(_query_bounds(causal, c * sub_k - d, sub_k, sub_q,
-                                   bq // sub_q) for c in range(bk // sub_k))
+                                   bq // sub_q, window)
+                     for c in range(bk // sub_k))
     return tuple(_key_bounds(causal, d + r * sub_q, sub_q, sub_k,
-                             bk // sub_k) for r in range(bq // sub_q))
+                             bk // sub_k, window) for r in range(bq // sub_q))
 
 
-def _block_distances(q_offset, k_offset, bq, bk, nq, nk):
+class _Band(NamedTuple):
+    """A sliding window's static geometry over one kernel's grid: a
+    block of the outer axis (query blocks in the forward and dq, key
+    blocks in dkv) walks ``n_live`` blocks of the other axis, from
+    :meth:`first` on.  The walked blocks all exist; those of them that
+    the band does not reach are dead by their strips' plans."""
+
+    window: int
+    n_live: int
+    base: int
+    step: int
+    div: int
+    last: int
+
+    def first(self, x):
+        """First walked block of outer block ``x`` (an integer, or a
+        traced grid index)."""
+        f = (self.base + x * self.step) // self.div
+        if isinstance(x, int):
+            return _clip(f, 0, self.last)
+        return jnp.clip(f, 0, self.last)
+
+
+def _band(phase, window, q_offset, k_offset, bq, bk, nq, nk):
+    """The :class:`_Band` of a call, or None without a window."""
+    if window is None:
+        return None
+    off = q_offset - k_offset
+    if phase == "dkv":
+        # key block j: queries from its first key's row to its last
+        # key's row + window - 1
+        lo = [_clip((j * bk - off) // bq, 0, nq - 1) for j in range(nk)]
+        hi = [_clip(((j + 1) * bk + window - 2 - off) // bq, 0, nq - 1)
+              for j in range(nk)]
+        base, step, div, n = -off, bk, bq, nq
+    else:
+        # query block i: keys from its first row - (window - 1) to its
+        # last row
+        lo = [_clip((off + i * bq - window + 1) // bk, 0, nk - 1)
+              for i in range(nq)]
+        hi = [_clip((off + (i + 1) * bq - 1) // bk, 0, nk - 1)
+              for i in range(nq)]
+        base, step, div, n = off - window + 1, bq, bk, nk
+    n_live = max(1, max(h - l + 1 for l, h in zip(lo, hi)))
+    return _Band(window, n_live, base, step, div, n - n_live)
+
+
+def _block_distances(q_offset, k_offset, bq, bk, nq, nk, phase="fwd",
+                     band=None):
     """First query row less first key column, of every grid block."""
-    return [q_offset - k_offset + i * bq - j * bk
-            for i in range(nq) for j in range(nk)]
+    off = q_offset - k_offset
+    if band is None:
+        return [off + i * bq - j * bk for i in range(nq) for j in range(nk)]
+    if phase == "dkv":
+        return [off + (band.first(j) + ii) * bq - j * bk
+                for j in range(nk) for ii in range(band.n_live)]
+    return [off + i * bq - (band.first(i) + jj) * bk
+            for i in range(nq) for jj in range(band.n_live)]
 
 
 def _block_variants(phase, causal, q_offset, k_offset, bq, bk, nq, nk,
-                    sub_q, sub_k):
+                    sub_q, sub_k, band=None):
     """``[(plan, d_min, d_max)]``: the distinct strip plans among the
-    grid's blocks.  A plan only grows with ``d``, so the blocks that
-    share one are an interval of ``d``; a plan with sub-tiles the
-    diagonal crosses belongs to ONE ``d`` (the mask needs the exact
+    grid's blocks.  A plan's numbers only grow with ``d``, so the blocks
+    that share one are an interval of ``d``; a plan with sub-tiles an
+    edge crosses belongs to ONE ``d`` (the mask needs the exact
     distance), ``d_min == d_max``."""
+    window = band.window if band else None
     spans = {}
-    for d in _block_distances(q_offset, k_offset, bq, bk, nq, nk):
-        plan = _strip_plan(phase, causal, d, bq, bk, sub_q, sub_k)
-        crossed = _visited(phase, plan, bq // sub_q)[1] > 0
+    for d in _block_distances(q_offset, k_offset, bq, bk, nq, nk, phase,
+                              band):
+        plan = _strip_plan(phase, causal, d, bq, bk, sub_q, sub_k, window)
+        crossed = _visited(plan)[1] > 0
         key = (plan, d if crossed else None)
         lo, hi = spans.get(key, (d, d))
         spans[key] = (min(lo, d), max(hi, d))
@@ -272,69 +351,70 @@ def _block_variants(phase, causal, q_offset, k_offset, bq, bk, nq, nk,
                   key=lambda v: v[1])
 
 
-def _visited(phase, plan, n):
+def _visited(plan):
     """(visited, masked) sub-tiles of a block with this plan."""
-    if phase == "dkv":
-        return (sum(n - first for first, _ in plan),
-                sum(full - first for first, full in plan))
-    return (sum(seen for _, seen in plan),
-            sum(seen - full for full, seen in plan))
+    return (sum(d - a for a, _, _, d in plan),
+            sum((d - a) - max(0, c - b) for a, b, c, d in plan))
 
 
-def _pieces(phase, bounds, n, run):
-    """What a strip with these bounds computes, in the order it sums:
-    ``[(t0, t1, masked)]``, each piece ONE copy of the tile arithmetic
-    (a *body*) over sub-tiles ``[t0, t1)``.  The sub-tiles the diagonal
-    does not touch go in runs of at most ``run``, one product and one
-    update a run whatever its length; a sub-tile it crosses is a piece
-    of its own (the mask is that sub-tile's alone)."""
-    if phase == "dkv":
-        first, full = bounds
-        crossed, plain = range(first, full), (full, n)
-    else:
-        full, seen = bounds
-        crossed, plain = range(full, seen), (0, full)
-    runs = [(t, min(t + run, plain[1]), False)
-            for t in range(plain[0], plain[1], run)]
-    alone = [(t, t + 1, True) for t in crossed]
-    return alone + runs if phase == "dkv" else runs + alone
+def _pieces(phase, bounds, run):
+    """What a strip with these bounds computes, in the order it sums
+    (ascending): ``[(t0, t1, masked)]``, each piece ONE copy of the
+    tile arithmetic (a *body*) over sub-tiles ``[t0, t1)``.  The
+    sub-tiles no edge touches go in runs of at most ``run``, one product
+    and one update a run whatever its length (``masked`` False); a
+    sub-tile an edge crosses is a piece of its own, ``masked`` the pair
+    ``(lower, upper)`` of the edges that cross it: the band's lower
+    edge, the diagonal."""
+    a, b, c, d = bounds
+    head_is = (False, True) if phase == "dkv" else (True, False)
+    tail_is = head_is[::-1]
+    head = [(t, t + 1, (True, True) if t >= c else head_is)
+            for t in range(a, b)]
+    runs = [(t, min(t + run, c), False) for t in range(b, c, run)]
+    tail = [(t, t + 1, tail_is) for t in range(max(b, c), d)]
+    return head + runs + tail
 
 
 def live_subtiles(phase, Sq, Sk, q_offset, k_offset, bq, bk, sub,
-                  causal=True, run=None):
+                  causal=True, run=None, window=None):
     """``(visited, masked, skipped, bodies)``: the sub-tiles one head's
-    call computes, those of them the diagonal crosses (the only ones
-    that pay a mask), and those never visited, summed over the grid
-    blocks; and the *bodies*, the copies of the tile arithmetic the
-    kernel's CODE holds (a run of unmasked sub-tiles is one, a crossed
-    sub-tile one; summed over the static variants, not the blocks: it
-    is what the kernel costs to lower, compile and load, once a
-    compiled program).  All from the strip plans the kernels' code is
-    built from (``"fwd"`` and the dq kernel walk keys by query strip,
-    ``"dkv"`` queries by key strip; ``"bwd"`` counts as dq).
-    ``sub=None``: a block is one tile; ``run``: sub-tiles a run at
-    most (default: :func:`run_cap` of the sub-tile).  A key bias hides
-    columns by data and changes none of the four."""
+    call computes, those of them an edge crosses (the diagonal, or a
+    ``window``'s lower edge: the only ones that pay a mask), and those
+    never visited, summed over the grid blocks; and the *bodies*, the
+    copies of the tile arithmetic the kernel's CODE holds (a run of
+    unmasked sub-tiles is one, a crossed sub-tile one; summed over the
+    static variants, not the blocks: it is what the kernel costs to
+    lower, compile and load, once a compiled program).  All from the
+    strip plans the kernels' code is built from (``"fwd"`` and the dq
+    kernel walk keys by query strip, ``"dkv"`` queries by key strip;
+    ``"bwd"`` counts as dq).  ``sub=None``: a block is one tile;
+    ``run``: sub-tiles a run at most (default: :func:`run_cap` of the
+    sub-tile).  A key bias hides columns by data and changes none of
+    the four.  Under a ``window`` the grid blocks the band does not
+    reach are not in the grid at all (:class:`_Band`): their sub-tiles
+    count as skipped."""
     sub_q, sub_k = (sub, sub) if sub else (bq, bk)
     run = run or run_cap(sub)
-    nq, nk, n = Sq // bq, Sk // bk, bq // sub_q
+    nq, nk = Sq // bq, Sk // bk
+    band = _band(phase, window, q_offset, k_offset, bq, bk, nq, nk)
     total = (Sq // sub_q) * (Sk // sub_k)
     visited = masked = 0
-    for d in _block_distances(q_offset, k_offset, bq, bk, nq, nk):
-        plan = _strip_plan(phase, causal, d, bq, bk, sub_q, sub_k)
-        v, m = _visited(phase, plan, n)
+    for d in _block_distances(q_offset, k_offset, bq, bk, nq, nk, phase,
+                              band):
+        v, m = _visited(_strip_plan(phase, causal, d, bq, bk, sub_q, sub_k,
+                                    window))
         visited, masked = visited + v, masked + m
-    n_walk = n if phase == "dkv" else bk // sub_k
-    bodies = sum(len(_pieces(phase, bounds, n_walk, run))
+    bodies = sum(len(_pieces(phase, bounds, run))
                  for plan, _, _ in _block_variants(
                      phase, causal, q_offset, k_offset, bq, bk, nq, nk,
-                     sub_q, sub_k)
+                     sub_q, sub_k, band)
                  for bounds in plan)
     return visited, masked, total - visited, bodies
 
 
 def _for_this_block(phase, causal, q_offset, k_offset, bq, bk, nq, nk,
-                    sub_q, sub_k, i, j, always, body):
+                    sub_q, sub_k, i, j, always, body, band=None):
     """``body(plan, d)`` for the block at grid position ``(i, j)``:
     traced indices choose among the static variants by ``pl.when`` on
     the one integer that tells them apart (``d`` is exact where the
@@ -343,9 +423,9 @@ def _for_this_block(phase, causal, q_offset, k_offset, bq, bk, nq, nk,
     outputs whatever it sees)."""
     d = q_offset - k_offset + i * bq - j * bk
     variants = _block_variants(phase, causal, q_offset, k_offset, bq, bk,
-                               nq, nk, sub_q, sub_k)
+                               nq, nk, sub_q, sub_k, band)
     for plan, lo, hi in variants:
-        if not (always or _visited(phase, plan, bq // sub_q)[0]):
+        if not (always or _visited(plan)[0]):
             continue
         if len(variants) == 1:
             body(plan, lo)
@@ -367,14 +447,21 @@ def _grid_index(axis, n):
     return 0 if n == 1 else pl.program_id(axis)
 
 
-def _visible(rows, cols, first_key_less_first_query, transposed=False):
-    """The causal mask of a piece the diagonal crosses: query index ≥
-    key index, as ONE compare of the index difference inside the piece
-    against a constant.  ``transposed``: keys (sublanes) by queries."""
+def _visible(rows, cols, first_key_less_first_query, transposed=False,
+             edges=(False, True), window=None):
+    """The mask of a piece an edge crosses: query index ≥ key index
+    (the diagonal, ``edges[1]``) and query index − key index < ``window``
+    (the band's lower edge, ``edges[0]``), each ONE compare of the index
+    difference inside the piece against a constant.  ``transposed``:
+    keys (sublanes) by queries."""
     along = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     down = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
     diff = along - down if transposed else down - along
-    return diff >= first_key_less_first_query
+    lower, upper = edges
+    if not lower:
+        return diff >= first_key_less_first_query
+    inside = diff < first_key_less_first_query + window
+    return inside & (diff >= first_key_less_first_query) if upper else inside
 
 
 def _dot(a, b, contract):
@@ -395,13 +482,17 @@ def _dead_rows_off(lse):
 
 # ------------------------------------------------------------------ forward
 def _fwd_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
-                block_q, block_k, sub_q, sub_k, run, nq, nk):
+                block_q, block_k, sub_q, sub_k, run, nq, nk, band=None):
+    """``nk``: key blocks a query block walks (under a window, the
+    band's ``n_live``: grid index ``j`` is then the walk's, and the key
+    block is ``band.first(i) + j``)."""
     if has_bias:
         q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
         b_ref = None
     i, j = _grid_index(1, nq), _grid_index(2, nk)
+    window = band.window if band else None
 
     def finalize(rows, m, l, acc):
         l = jnp.maximum(l, 1e-30)  # rows that saw no key (ring blocks)
@@ -421,10 +512,11 @@ def _fwd_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
             l_ref[:] = jnp.zeros_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def strip(r, gap, full, seen):
+    def strip(r, gap, bounds):
         """Query strip ``r``, its first row ``gap`` past the block's
-        first key: its key sub-tiles ``[0, full)`` plain, in runs,
-        ``[full, seen)`` masked."""
+        first key, over the key sub-tiles its ``bounds`` name
+        (:func:`_key_bounds`): plain ones in runs, crossed ones
+        masked."""
         rows = _tile(r, sub_q)
         q = q_ref[0, rows, :]
 
@@ -436,7 +528,8 @@ def _fwd_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
             if b_ref is not None:
                 s = s + b_ref[0, :, cols]  # (1, columns) key bias over rows
             if masked:
-                s = jnp.where(_visible(sub_q, sub_k, c * sub_k - gap),
+                s = jnp.where(_visible(sub_q, sub_k, c * sub_k - gap,
+                                       edges=masked, window=window),
                               s, NEG_INF)
             return s
 
@@ -464,7 +557,7 @@ def _fwd_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
         # A piece's scores are written one piece AHEAD of its update, so
         # that its product has no softmax to wait for (the schedule of a
         # head at the train cell's shape: 3,791 bundles -> 3,441)
-        pieces = _pieces("fwd", (full, seen), block_k // sub_k, run)
+        pieces = _pieces("fwd", bounds, run)
         ahead = score(*pieces[0]) if pieces else None
         for n, (c, until, _) in enumerate(pieces):
             s, ahead = ahead, (score(*pieces[n + 1])
@@ -483,17 +576,41 @@ def _fwd_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
             acc_ref[rows, :] = acc
 
     def block(plan, d):
-        for r, (full, seen) in enumerate(plan):
-            if seen or nk == 1:  # one key block: finalised here, seen or not
-                strip(r, d + r * sub_q, full, seen)
+        for r, bounds in enumerate(plan):
+            # one key block: finalised here, seen or not
+            if bounds[3] > bounds[0] or nk == 1:
+                strip(r, d + r * sub_q, bounds)
 
     _for_this_block("fwd", causal, q_offset, k_offset, block_q, block_k,
-                    nq, nk, sub_q, sub_k, i, j, nk == 1, block)
+                    nq, nk, sub_q, sub_k, i,
+                    j if band is None else band.first(i) + j,
+                    nk == 1, block, band)
 
     if nk > 1:
         @pl.when(j == nk - 1)
         def _finalize():
             finalize(slice(None), m_ref[:, 0:1], l_ref[:, 0:1], acc_ref[:])
+
+
+def _check_window(window, causal):
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError(f"a window ({window}) needs causal=True and at "
+                         f"least one key")
+
+
+def _walked(band, n):
+    """``(block, extent)`` of a grid's walked axis: ``block(x, y)`` is
+    the block that step ``y`` of outer block ``x``'s walk names.  No
+    window: ``y`` itself over all ``n`` blocks (the index maps, and the
+    code they lower to, are then what they were before windows)."""
+    if band is None:
+        return (lambda x, y: y), n
+    return (lambda x, y: band.first(x) + y), band.n_live
+
+
+def _band_kw(band):
+    """The kernels' ``band`` argument, absent without a window."""
+    return {} if band is None else {"band": band}
 
 
 def _kv_row(b, heads, kv_heads):
@@ -569,9 +686,16 @@ def dispatched(sq, sk, d, dtype, phase, block_q=None, block_k=None):
 
 def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
                      block_q=None, block_k=None, interpret=False,
-                     out_dtype=None, kv_bias=None, heads=1, kv_heads=None):
+                     out_dtype=None, kv_bias=None, heads=1, kv_heads=None,
+                     window=None):
     """q: (BH, Sq, D); k/v: (B·kv_heads, Sk, D).  Returns
     (out, lse (BH, Sq, 1)).
+
+    ``window``: a static sliding window over a causal call: key ``j`` is
+    visible to query ``i`` iff ``0 <= i - j < window`` (global
+    positions).  Key blocks the band does not reach are not in the grid
+    (the index maps name live blocks only), sub-tiles under it are not
+    visited, and its lower edge is one ``where`` as the diagonal is.
 
     ``kv_bias``: optional (B, 1, Sk) f32 additive key bias (0 valid /
     NEG_INF padded; the middle singleton keeps the block sublane-legal);
@@ -591,11 +715,12 @@ def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
     out_dtype = out_dtype or q.dtype
     bq, bk, sub = dispatched(Sq, Sk, D, q.dtype, "fwd", block_q, block_k)
     has_bias = kv_bias is not None
+    _check_window(window, causal)
 
     inputs = (q, k, v) if not has_bias else (q, k, v, kv_bias)
     call = _fwd_call(BH, Sq, Sk, D, heads, kv_heads, float(scale), causal,
                      q_offset, k_offset, bq, bk, sub, has_bias, interpret,
-                     jnp.dtype(out_dtype).name)
+                     jnp.dtype(out_dtype).name, window)
     # jax.disable_jit(False): pallas_call cannot bind eagerly (its bind
     # params carry a dict), so the kernel stays one jitted op even when a
     # caller runs the surrounding program op-by-op under disable_jit().
@@ -607,17 +732,19 @@ def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
 @functools.lru_cache(maxsize=512)
 def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
               q_offset, k_offset, bq, bk, sub, has_bias, interpret,
-              out_dtype_name):
+              out_dtype_name, window=None):
     """The fwd ``pallas_call``, memoized on its static configuration —
     every argument is static by construction (they bake into the kernel
     closure), so eager callers (a ring chunk per hop, interpret-mode
     tests) reuse one traced kernel instead of rebuilding fresh index-map
     closures — and with them the whole compile — per invocation."""
     nq, nk = Sq // bq, Sk // bk
+    band = _band("fwd", window, q_offset, k_offset, bq, bk, nq, nk)
+    key_block, nk = _walked(band, nk)
 
     kv_spec = pl.BlockSpec(
         (1, bk, D),
-        lambda b, i, j: (_kv_row(b, heads, kv_heads), j, 0),
+        lambda b, i, j: (_kv_row(b, heads, kv_heads), key_block(i, j), 0),
         memory_space=pltpu.VMEM,
     )
     in_specs = [
@@ -627,7 +754,9 @@ def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
     ]
     if has_bias:
         in_specs.append(
-            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // heads, 0, j), memory_space=pltpu.VMEM)
+            pl.BlockSpec((1, 1, bk),
+                         lambda b, i, j: (b // heads, 0, key_block(i, j)),
+                         memory_space=pltpu.VMEM)
         )
 
     return pl.pallas_call(
@@ -635,6 +764,7 @@ def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
             _fwd_kernel, scale=scale, causal=causal, has_bias=has_bias,
             q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk,
             sub_q=sub[0], sub_k=sub[1], run=sub[2], nq=nq, nk=nk,
+            **_band_kw(band),
         ),
         grid=(BH, nq, nk),
         in_specs=in_specs,
@@ -661,20 +791,21 @@ def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
 
 # ----------------------------------------------------------------- backward
 def _dq_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
-               block_q, block_k, sub_q, sub_k, run, nq, nk):
+               block_q, block_k, sub_q, sub_k, run, nq, nk, band=None):
     if has_bias:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, b_ref, dq_ref, acc_ref = refs
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = refs
         b_ref = None
     i, j = _grid_index(1, nq), _grid_index(2, nk)
+    window = band.window if band else None
 
     if nk > 1:
         @pl.when(j == 0)
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def strip(r, gap, full, seen):
+    def strip(r, gap, bounds):
         rows = _tile(r, sub_q)
         q, do = q_ref[0, rows, :], do_ref[0, rows, :]
         lse, delta = lse_ref[0, rows, :], delta_ref[0, rows, :]
@@ -689,7 +820,8 @@ def _dq_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
                 s = s + b_ref[0, :, cols]
             p = jnp.exp(s - lse)
             if masked:
-                p = jnp.where(_visible(sub_q, sub_k, c * sub_k - gap), p, 0.0)
+                p = jnp.where(_visible(sub_q, sub_k, c * sub_k - gap,
+                                       edges=masked, window=window), p, 0.0)
             # ring passes an f32 cotangent with bf16 k/v: widen the
             # narrower operand instead of rounding do through bf16
             if v.dtype != do.dtype:
@@ -700,7 +832,7 @@ def _dq_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
 
         acc = None if nk == 1 else acc_ref[rows, :]
         # keys ascending: the plain runs, then the diagonal's sub-tiles
-        for piece in _pieces("dq", (full, seen), block_k // sub_k, run):
+        for piece in _pieces("dq", bounds, run):
             acc = tile(*piece, acc)
         if acc is None:  # a strip no key of the call reaches
             acc = jnp.zeros((sub_q, acc_ref.shape[1]), jnp.float32)
@@ -710,12 +842,14 @@ def _dq_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
             acc_ref[rows, :] = acc
 
     def block(plan, d):
-        for r, (full, seen) in enumerate(plan):
-            if seen or nk == 1:
-                strip(r, d + r * sub_q, full, seen)
+        for r, bounds in enumerate(plan):
+            if bounds[3] > bounds[0] or nk == 1:
+                strip(r, d + r * sub_q, bounds)
 
     _for_this_block("dq", causal, q_offset, k_offset, block_q, block_k,
-                    nq, nk, sub_q, sub_k, i, j, nk == 1, block)
+                    nq, nk, sub_q, sub_k, i,
+                    j if band is None else band.first(i) + j,
+                    nk == 1, block, band)
 
     if nk > 1:
         @pl.when(j == nk - 1)
@@ -724,11 +858,13 @@ def _dq_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
 
 
 def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
-                block_q, block_k, sub_q, sub_k, run, nq, nk, nt):
+                block_q, block_k, sub_q, sub_k, run, nq, nk, nt, band=None):
     """k-block outer; the inner dimension ``t`` walks ALL nt = g·nq
     q-blocks that attend to this kv head — for grouped-query attention
     the g q-heads of the group accumulate into the same dk/dv block
-    (i = t % nq is the q-block index within the current q head).  Inside
+    (i = t % nq is the q-block index within the current q head; ``nq``
+    is the q-blocks a key block walks: under a window the band's
+    ``n_live``, from ``band.first(j)`` on).  Inside
     a block a strip of keys walks its query sub-tiles, rows ascending:
     those the diagonal crosses, then those wholly under it, in runs.
 
@@ -745,7 +881,9 @@ def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
         b_ref = None
     j, t = _grid_index(1, nk), _grid_index(2, nt)
     i = 0 if nq == 1 else t % nq
-    n_sub = block_q // sub_q
+    if band is not None:
+        i = band.first(j) + i
+    window = band.window if band else None
 
     if nt > 1:
         @pl.when(t == 0)
@@ -753,10 +891,11 @@ def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def strip(c, lead, first, full):
+    def strip(c, lead, bounds):
         """Key strip ``c``, its first key ``lead`` past the block's
-        first query row: its query sub-tiles ``[first, full)`` masked,
-        ``[full, n_sub)`` plain."""
+        first query row, over the query sub-tiles its ``bounds`` name
+        (:func:`_query_bounds`): crossed ones masked, plain ones in
+        runs."""
         cols = _tile(c, sub_k)
         k, v = k_ref[0, cols, :], v_ref[0, cols, :]
         bias = None if b_ref is None else b_ref[0, cols, :]  # (sub_k, 1)
@@ -773,7 +912,8 @@ def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
             p = jnp.exp(s - lse)
             if masked:
                 p = jnp.where(_visible(sub_k, sub_q, lead - r * sub_q,
-                                       transposed=True), p, 0.0)
+                                       transposed=True, edges=masked,
+                                       window=window), p, 0.0)
             dv = _dot(p.astype(do.dtype), do, _NN)
             # widen v rather than rounding an f32 cotangent down (ring path)
             vw = v if v.dtype == do.dtype else v.astype(do.dtype)
@@ -785,7 +925,7 @@ def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
 
         carry = None if nt == 1 else (dk_acc[cols, :], dv_acc[cols, :])
         # rows ascending: the diagonal's sub-tiles, then the plain runs
-        for piece in _pieces("dkv", (first, full), n_sub, run):
+        for piece in _pieces("dkv", bounds, run):
             carry = tile(*piece, carry)
         zero = jnp.zeros((sub_k, dk_acc.shape[1]), jnp.float32)
         dk, dv = carry or (zero, zero)  # a strip no query of the call reaches
@@ -796,12 +936,12 @@ def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
             dk_acc[cols, :], dv_acc[cols, :] = dk, dv
 
     def block(plan, d):
-        for c, (first, full) in enumerate(plan):
-            if first < n_sub or nt == 1:
-                strip(c, c * sub_k - d, first, full)
+        for c, bounds in enumerate(plan):
+            if bounds[3] > bounds[0] or nt == 1:
+                strip(c, c * sub_k - d, bounds)
 
     _for_this_block("dkv", causal, q_offset, k_offset, block_q, block_k,
-                    nq, nk, sub_q, sub_k, i, j, nt == 1, block)
+                    nq, nk, sub_q, sub_k, i, j, nt == 1, block, band)
 
     if nt > 1:
         @pl.when(t == nt - 1)
@@ -812,7 +952,8 @@ def _dkv_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
 
 def flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, q_offset, k_offset,
                      block_q=None, block_k=None, interpret=False, delta=None,
-                     out_dtype=None, kv_bias=None, heads=1, kv_heads=None):
+                     out_dtype=None, kv_bias=None, heads=1, kv_heads=None,
+                     window=None):
     """q/out/do (BH, Sq, D); k/v (B·kv_heads, Sk, D); lse (BH, Sq, 1).
     Returns (dq, dk, dv) with dk/dv shaped like k/v.
 
@@ -828,6 +969,8 @@ def flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, q_offset, k_offset,
     ``kv_bias``/``heads``/``kv_heads`` as in :func:`flash_fwd_pallas`;
     with grouped-query attention the dk/dv grid walks every q head of
     the group before finalizing, so the group sum happens in VMEM.
+    ``window`` as in :func:`flash_fwd_pallas`: dq walks a query block's
+    live key blocks, dkv a key block's live query blocks.
     """
     BH, Sq, D = q.shape
     Sk = k.shape[1]
@@ -846,11 +989,12 @@ def flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, q_offset, k_offset,
     inputs = (q, k, v, do, lse, delta)
     if has_bias:
         inputs = inputs + (kv_bias,)
+    _check_window(window, causal)
     static = (BH, BKV, Sq, Sk, D, heads, kv_heads, float(scale), causal,
               q_offset, k_offset, bq, bk, sub, has_bias, interpret)
-    dq_call = _dq_pallas_call(*static, jnp.dtype(dq_dtype).name)
+    dq_call = _dq_pallas_call(*static, jnp.dtype(dq_dtype).name, window)
     dkv_call = _dkv_pallas_call(*static, jnp.dtype(dk_dtype).name,
-                                jnp.dtype(dv_dtype).name)
+                                jnp.dtype(dv_dtype).name, window)
     # the dkv kernel reads the per-row statistics as rows and the key
     # bias as a column (its tiles are keys by queries): the same values
     rows = (lse.reshape(BH, 1, Sq), delta.reshape(BH, 1, Sq))
@@ -866,13 +1010,15 @@ def flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, q_offset, k_offset,
 @functools.lru_cache(maxsize=512)
 def _dq_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
                     q_offset, k_offset, bq, bk, sub, has_bias, interpret,
-                    dq_dtype_name):
+                    dq_dtype_name, window=None):
     """The dq ``pallas_call``, memoized like :func:`_fwd_call`."""
     nq, nk = Sq // bq, Sk // bk
+    band = _band("dq", window, q_offset, k_offset, bq, bk, nq, nk)
+    key_block, nk = _walked(band, nk)
     q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM)
     k_spec = pl.BlockSpec(
         (1, bk, D),
-        lambda b, i, j: (_kv_row(b, heads, kv_heads), j, 0),
+        lambda b, i, j: (_kv_row(b, heads, kv_heads), key_block(i, j), 0),
         memory_space=pltpu.VMEM,
     )
     r_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM)
@@ -880,7 +1026,9 @@ def _dq_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
     in_specs = [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
     if has_bias:
         in_specs.append(
-            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // heads, 0, j), memory_space=pltpu.VMEM)
+            pl.BlockSpec((1, 1, bk),
+                         lambda b, i, j: (b // heads, 0, key_block(i, j)),
+                         memory_space=pltpu.VMEM)
         )
 
     return pl.pallas_call(
@@ -888,6 +1036,7 @@ def _dq_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
             _dq_kernel, scale=scale, causal=causal, has_bias=has_bias,
             q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk,
             sub_q=sub[0], sub_k=sub[1], run=sub[2], nq=nq, nk=nk,
+            **_band_kw(band),
         ),
         grid=(BH, nq, nk),
         in_specs=in_specs,
@@ -903,9 +1052,11 @@ def _dq_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
 @functools.lru_cache(maxsize=512)
 def _dkv_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
                      q_offset, k_offset, bq, bk, sub, has_bias, interpret,
-                     dk_dtype_name, dv_dtype_name):
+                     dk_dtype_name, dv_dtype_name, window=None):
     """The dk/dv ``pallas_call``, memoized like :func:`_fwd_call`."""
     nq, nk = Sq // bq, Sk // bk
+    band = _band("dkv", window, q_offset, k_offset, bq, bk, nq, nk)
+    query_block, nq = _walked(band, nq)
     group = heads // kv_heads
 
     # k-outer grid over the KV rows: index maps see (b, j, t) with
@@ -917,14 +1068,14 @@ def _dkv_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
         return (b // kv_heads) * heads + (b % kv_heads) * group + t // nq
 
     qT_spec = pl.BlockSpec(
-        (1, bq, D), lambda b, j, t: (_q_row(b, t), t % nq, 0),
+        (1, bq, D), lambda b, j, t: (_q_row(b, t), query_block(j, t % nq), 0),
         memory_space=pltpu.VMEM,
     )
     kT_spec = pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0), memory_space=pltpu.VMEM)
     # lse and delta as ROWS (BH, 1, Sq): the kernel holds its tiles
     # keys by queries
     rT_spec = pl.BlockSpec(
-        (1, 1, bq), lambda b, j, t: (_q_row(b, t), 0, t % nq),
+        (1, 1, bq), lambda b, j, t: (_q_row(b, t), 0, query_block(j, t % nq)),
         memory_space=pltpu.VMEM,
     )
 
@@ -941,7 +1092,7 @@ def _dkv_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
             _dkv_kernel, scale=scale, causal=causal, has_bias=has_bias,
             q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk,
             sub_q=sub[0], sub_k=sub[1], run=sub[2], nq=nq, nk=nk,
-            nt=group * nq,
+            nt=group * nq, **_band_kw(band),
         ),
         grid=(BKV, nk, group * nq),
         in_specs=in_specsT,
@@ -961,27 +1112,28 @@ def _dkv_pallas_call(BH, BKV, Sq, Sk, D, heads, kv_heads, scale, causal,
 
 
 # ---------------------------------------------------------------- dispatch
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
 def _flash_pallas(q, k, v, kv_bias, scale, causal, q_offset, k_offset,
-                  block_q, block_k, interpret, heads, kv_heads):
+                  block_q, block_k, interpret, heads, kv_heads, window):
     out, _ = flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
                               block_q=block_q, block_k=block_k,
                               interpret=interpret, kv_bias=kv_bias, heads=heads,
-                              kv_heads=kv_heads)
+                              kv_heads=kv_heads, window=window)
     return out
 
 
 def _flash_pallas_fwd(q, k, v, kv_bias, scale, causal, q_offset, k_offset,
-                      block_q, block_k, interpret, heads, kv_heads):
+                      block_q, block_k, interpret, heads, kv_heads, window):
     out, lse = flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
                                 block_q=block_q, block_k=block_k,
                                 interpret=interpret, kv_bias=kv_bias, heads=heads,
-                                kv_heads=kv_heads)
+                                kv_heads=kv_heads, window=window)
     return out, (q, k, v, kv_bias, out, lse)
 
 
 def _flash_pallas_bwd(scale, causal, q_offset, k_offset, block_q, block_k,
-                      interpret, heads, kv_heads, res, g):
+                      interpret, heads, kv_heads, window, res, g):
     q, k, v, kv_bias, out, lse = res
     # the nondiff blocks are the CALLER's (None = untuned): an explicit
     # block keeps the documented 512 cap (the backward holds more blocks
@@ -993,7 +1145,8 @@ def _flash_pallas_bwd(scale, causal, q_offset, k_offset, block_q, block_k,
                                   block_q=None if block_q is None else min(block_q, 512),
                                   block_k=None if block_k is None else min(block_k, 512),
                                   interpret=interpret, kv_bias=kv_bias,
-                                  heads=heads, kv_heads=kv_heads)
+                                  heads=heads, kv_heads=kv_heads,
+                                  window=window)
     # the mask bias is data, not a trainable input: zero cotangent
     return (dq, dk, dv, None if kv_bias is None else jnp.zeros_like(kv_bias))
 
@@ -1003,8 +1156,12 @@ _flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 def flash_attention_pallas(q, k, v, causal=True, softmax_scale=None,
                            q_offset=0, k_offset=0, block_q=None, block_k=None,
-                           interpret=False, kv_mask=None):
+                           interpret=False, kv_mask=None, window=None):
     """(B, H, S, D) flash attention via the Pallas kernels.
+
+    ``window``: a static sliding window (needs ``causal``): key ``j`` is
+    visible to query ``i`` iff ``0 <= i - j < window``; forward, dq and
+    dkv all walk the band's blocks and sub-tiles only.
 
     ``kv_mask``: optional (B, Sk) bool key-validity mask (True = valid) —
     the fmha varlen/padding semantics (``apex/contrib/fmha/fmha.py:33-60``)
@@ -1033,7 +1190,8 @@ def flash_attention_pallas(q, k, v, causal=True, softmax_scale=None,
     # entry point, so a forward-tuned (bq, bk) never leaks onto the
     # backward kernels' different VMEM envelope
     out = _flash_pallas(qf, kf, vf, bias, scale, causal, q_offset, k_offset,
-                        block_q, block_k, interpret, H, Hkv)
+                        block_q, block_k, interpret, H, Hkv,
+                        None if window is None else int(window))
     return out.reshape(B, H, Sq, D)
 
 

@@ -38,6 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops._pallas_tiling import LANES as _LANES
+from apex_tpu.ops._pallas_tiling import VMEM_BUDGET as _VMEM_BUDGET
 from apex_tpu.ops._pallas_tiling import sublane as _sublane
 
 NEG_INF = -1e30
@@ -74,6 +75,25 @@ def _ceil_block(n, target, align):
 
 def _grid(n, block):
     return -(-n // block)
+
+
+def _fit_vocab_block(bv, bn, H, x_dtype, embed_dtype, resident):
+    """``bv`` halved (never under a lane tile) until a kernel's VMEM
+    fits: the double-buffered x and embed tiles, three score-sized
+    float32 temporaries, and what the kernel keeps ``resident`` besides
+    (``resident(bv)`` bytes: scratch and output blocks), under 0.8 of
+    the budget (the compiler's own stack is not priced).  Every shape
+    the kernels had met (hidden <= 1280 in float32) keeps its 512; a
+    2,048-wide head against a (512, 2048) float32 dembed block does
+    not."""
+    def need(b):
+        return (2 * bn * H * jnp.dtype(x_dtype).itemsize
+                + 2 * b * H * jnp.dtype(embed_dtype).itemsize
+                + 3 * bn * b * 4 + resident(b))
+
+    while bv > _LANES and need(bv) > 0.8 * _VMEM_BUDGET:
+        bv //= 2
+    return bv
 
 
 # ------------------------------------------------------------------ forward
@@ -149,7 +169,8 @@ def fused_ce_fwd_pallas(x2, embed, t, dot_dtype=None,
     N, H = x2.shape
     V = embed.shape[0]
     bn = _ceil_block(N, block_n, align=_sublane(x2.dtype))
-    bv = _ceil_block(V, block_v, align=_LANES)
+    bv = _fit_vocab_block(_ceil_block(V, block_v, align=_LANES), bn, H,
+                          x2.dtype, embed.dtype, lambda b: 0)
     nn, nv = _grid(N, bn), _grid(V, bv)
 
     kernel = functools.partial(_fwd_kernel, bv=bv, nv=nv, V=V,
@@ -249,7 +270,12 @@ def fused_ce_bwd_pallas(x2, embed, t, lse, g, dot_dtype=None,
     N, H = x2.shape
     V = embed.shape[0]
     bn = _ceil_block(N, block_n, align=_sublane(x2.dtype))
-    bv = _ceil_block(V, block_v, align=_LANES)
+    bv_target = _ceil_block(V, block_v, align=_LANES)
+    # dx keeps a float32 accumulator and a double-buffered output block
+    # of x's shape
+    bv = _fit_vocab_block(
+        bv_target, bn, H, x2.dtype, embed.dtype,
+        lambda b: bn * H * (4 + 2 * jnp.dtype(x2.dtype).itemsize))
     nn, nv = _grid(N, bn), _grid(V, bv)
     t2 = t.reshape(N, 1).astype(jnp.int32)
     lse2 = lse.reshape(N, 1).astype(jnp.float32)
@@ -279,6 +305,11 @@ def fused_ce_bwd_pallas(x2, embed, t, lse, g, dot_dtype=None,
 
     vrow_spec = pl.BlockSpec((bn, 1), lambda i, j: (j, 0),
                              memory_space=pltpu.VMEM)
+    # dembed keeps a float32 accumulator and a double-buffered float32
+    # output block of the embed tile's shape: its own vocabulary block
+    bv = _fit_vocab_block(bv_target, bn, H, x2.dtype, embed.dtype,
+                          lambda b: 3 * b * H * 4)
+    nv = _grid(V, bv)
     dembed = pl.pallas_call(
         functools.partial(_dembed_kernel, bn=bn, bv=bv, nn=nn, N=N, V=V,
                           dot_dtype=dot_dtype),

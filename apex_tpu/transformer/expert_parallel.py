@@ -30,9 +30,26 @@ Pallas grouped GEMM that ships with JAX on a TPU, ``jax.lax.ragged_dot``
 elsewhere: :func:`_grouped_matmul`) over static buffers sized for the
 worst case, every assignment held.  With ``held`` = all experts it is the whole layer; what absent
 experts would add is simply not there (no exchange, no stand-in).
-:func:`moe_ffn` above stays the training layer (capacity factor,
-dropping, ``all_to_all``); this one has no backward-tuned path and no
-exchange yet (ROADMAP, Queue 2).
+
+**The same layer trains** (``buffer_rows=``): the sort compacts the
+held assignments first, and the grouped matmuls walk them in static
+chunks of ``buffer_rows`` rows, as many chunks as hold a live row (one,
+while the routing is even; a dynamic trip count, so nothing is ever
+dropped and a skewed step pays for its spill in time, not in memory).
+The walk is a ``custom_vjp`` (:func:`_held_chunks`): its backward walks
+the same chunks, recomputes each chunk's rows and activations and
+differentiates the chunk's three grouped matmuls (megablox's ``gmm`` /
+``tgmm`` pair on a TPU, ``ragged_dot``'s own derivative elsewhere), so
+neither pass holds a ``T x top_k``-row buffer: tokens reach a chunk by
+a gather, and a token sums its assignments by ``top_k`` gathers out of
+the chunk (no scatter).  Rows past the live ones are zeroed where they
+are read and where they are written, so whatever a grouped matmul
+leaves there reaches no output and no gradient.  The router's bias is
+choice-only STATE: :func:`balance_bias_update` moves it from the
+per-expert load the layer returns, outside every gradient and every
+optimizer.  :func:`moe_ffn` above stays the capacity-factor layer
+(dropping, ``all_to_all``); the exchange between the chips of an
+expert group does not exist yet (ROADMAP, Queue 2).
 """
 
 from functools import partial
@@ -225,12 +242,16 @@ def route_group_limited(x, router_w, bias, *, top_k: int, n_group: int,
 GROUPED_TILING = (128, 1024, 1024)
 
 
-def _grouped_matmul(rows, w, group_sizes, impl):
+def _grouped_matmul(rows, w, group_sizes, impl, trainable=False):
     """``rows[sizes[:g].sum() : sizes[:g+1].sum()] @ w[g]`` for every
     group ``g``: ``jax.lax.ragged_dot`` ("xla"), or the Pallas grouped
     GEMM that ships with JAX (megablox ``gmm``; "pallas", "interpret",
     and "auto" on a TPU), which visits only the row tiles that hold a
-    live row.  Rows past the live ones come out undefined."""
+    live row.  Rows past the live ones come out undefined.
+    ``trainable``: megablox's ``ops.gmm``, the same kernel under a
+    ``custom_vjp`` (``gmm`` with the weights transposed for the rows'
+    cotangent, ``tgmm`` for the weights'); the bare ``pallas_call`` has
+    no derivative."""
     from apex_tpu.utils.platform import on_tpu
 
     if impl not in ("auto", "pallas", "interpret", "xla"):
@@ -240,12 +261,18 @@ def _grouped_matmul(rows, w, group_sizes, impl):
                if rows.shape[0] % t == 0), None)
     if impl == "xla" or (impl == "auto" and not on_tpu()) or tm is None:
         return jax.lax.ragged_dot(rows, w, group_sizes)
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    if trainable:
+        from jax.experimental.pallas.ops.tpu.megablox.ops import gmm
+    else:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     _, tk, tn = GROUPED_TILING
+    tiling = (tm, min(tk, w.shape[1]), min(tn, w.shape[2]))
+    if trainable:       # custom_vjp: the static arguments by position
+        return gmm(rows, w, group_sizes, rows.dtype, tiling, None, None,
+                   False, impl == "interpret")
     return gmm(rows, w, group_sizes, preferred_element_type=rows.dtype,
-               tiling=(tm, min(tk, w.shape[1]), min(tn, w.shape[2])),
-               interpret=(impl == "interpret"))
+               tiling=tiling, interpret=(impl == "interpret"))
 
 
 def grouped_gated_ffn(rows, w_gate, w_up, w_down, group_sizes,
@@ -263,9 +290,156 @@ def grouped_gated_ffn(rows, w_gate, w_up, w_down, group_sizes,
                            impl)
 
 
+# ------------------------------------------- held experts (training)
+def _chunk(c, rows_per_chunk, order, offsets, n_live):
+    """Chunk ``c`` of the sorted held assignments: the assignment (token
+    * top_k + its slot) of each of its rows, which rows are live, and
+    how many rows each held expert has inside it."""
+    start = c * rows_per_chunk
+    pos = start + jnp.arange(rows_per_chunk, dtype=jnp.int32)
+    assignment = jax.lax.dynamic_slice(order, (start,), (rows_per_chunk,))
+    inside = jnp.clip(offsets - start, 0, rows_per_chunk)
+    return assignment, pos < n_live, inside[1:] - inside[:-1]
+
+
+def _chunk_ffn(rows, valid, sizes, w_gate, w_up, w_down, impl):
+    """One chunk's experts: the gated FFN over its rows, with every row
+    past the live ones zero where it is read and where it is written."""
+    rows = jnp.where(valid[:, None], rows, 0)
+    gate = _grouped_matmul(rows, w_gate, sizes, impl, True)
+    up = _grouped_matmul(rows, w_up, sizes, impl, True)
+    act = jnp.where(valid[:, None], jax.nn.silu(gate) * up, 0)
+    y = _grouped_matmul(act, w_down, sizes, impl, True)
+    return jnp.where(valid[:, None], y, 0)
+
+
+def _rows_of(slot, first_row, n_rows):
+    """Where each assignment sits in a chunk of ``n_rows`` rows that
+    starts at rank ``first_row`` (``slot``: (T, top_k) ranks in the
+    sorted order), or ``n_rows``, past the end, where it does not."""
+    local = slot - first_row
+    return jnp.where((local >= 0) & (local < n_rows), local, n_rows)
+
+
+def _own(values, slot, first_row):
+    """``values`` (rows of a chunk) laid back out by assignment: (T,
+    top_k), 0 where the assignment is outside the chunk."""
+    return jnp.take(values, _rows_of(slot, first_row, values.shape[0]),
+                    axis=0, mode="fill", fill_value=0)
+
+
+def _sum_own(y, slot, first_row):
+    """Each token's sum over its ``top_k`` assignments of the rows of
+    chunk-local ``y`` they sit in (one outside the chunk adds nothing):
+    ``top_k`` gathers of (T, H), one after the other (all at once would
+    be the ``T x top_k``-row buffer again), no scatter."""
+    local = _rows_of(slot, first_row, y.shape[0])
+    out = 0.0
+    for k in range(slot.shape[1]):
+        out = out + jnp.take(y, local[:, k], axis=0, mode="fill",
+                             fill_value=0)
+    return out
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _held_chunks(x, weights, w_gate, w_up, w_down, order, slot, offsets,
+                 n_live, rows_per_chunk, impl):
+    return _held_chunks_fwd(x, weights, w_gate, w_up, w_down, order, slot,
+                            offsets, n_live, rows_per_chunk, impl)[0]
+
+
+def _held_chunks_fwd(x, weights, w_gate, w_up, w_down, order, slot, offsets,
+                     n_live, rows_per_chunk, impl):
+    """``x`` (T, H); ``weights`` (T, top_k) float32, 0 where an
+    assignment is not held; ``order`` (T*top_k,) the assignments sorted
+    held-first by expert; ``slot`` (T, top_k) each assignment's rank in
+    that order (``>= n_live``: not held); ``offsets`` (n_held + 1,) the
+    experts' first ranks.  Returns ``(T, H)`` float32."""
+    top_k = weights.shape[1]
+    flat_w = weights.reshape(-1)
+
+    def body(c, out):
+        assignment, valid, sizes = _chunk(c, rows_per_chunk, order, offsets,
+                                          n_live)
+        y = _chunk_ffn(jnp.take(x, assignment // top_k, axis=0), valid,
+                       sizes, w_gate, w_up, w_down, impl)
+        w = jnp.take(flat_w, assignment)
+        y = y.astype(jnp.float32) * jnp.where(valid, w, 0.0)[:, None]
+        return out + _sum_own(y, slot, c * rows_per_chunk)
+
+    n_chunks = -(-n_live // rows_per_chunk)
+    out = jax.lax.fori_loop(0, n_chunks, body,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out, (x, weights, w_gate, w_up, w_down, order, slot, offsets,
+                 n_live)
+
+
+def _held_chunks_bwd(rows_per_chunk, impl, res, dout):
+    x, weights, w_gate, w_up, w_down, order, slot, offsets, n_live = res
+    top_k = weights.shape[1]
+    flat_w = weights.reshape(-1)
+    dout = dout.astype(jnp.float32)
+
+    def body(c, carry):
+        dx, dweights, dgate, dup, ddown = carry
+        assignment, valid, sizes = _chunk(c, rows_per_chunk, order, offsets,
+                                          n_live)
+        token = assignment // top_k
+        y, vjp = jax.vjp(
+            lambda r, wg, wu, wd: _chunk_ffn(r, valid, sizes, wg, wu, wd,
+                                             impl),
+            jnp.take(x, token, axis=0), w_gate, w_up, w_down)
+        g = jnp.where(valid[:, None], jnp.take(dout, token, axis=0), 0.0)
+        w = jnp.where(valid, jnp.take(flat_w, assignment), 0.0)
+        # the routing weight's cotangent: its row of y against the
+        # token's cotangent
+        dw_rows = jnp.sum(y.astype(jnp.float32) * g, axis=-1)
+        drows, dg, du, dd = vjp((g * w[:, None]).astype(y.dtype))
+        drows = jnp.where(valid[:, None], drows.astype(jnp.float32), 0.0)
+        first = c * rows_per_chunk
+        return (dx + _sum_own(drows, slot, first),
+                dweights + _own(dw_rows, slot, first),
+                dgate + dg.astype(jnp.float32), dup + du.astype(jnp.float32),
+                ddown + dd.astype(jnp.float32))
+
+    zeros32 = lambda a: jnp.zeros(a.shape, jnp.float32)
+    n_chunks = -(-n_live // rows_per_chunk)
+    dx, dweights, dgate, dup, ddown = jax.lax.fori_loop(
+        0, n_chunks, body, (zeros32(x), zeros32(weights), zeros32(w_gate),
+                            zeros32(w_up), zeros32(w_down)))
+    return (dx.astype(x.dtype), dweights.astype(weights.dtype),
+            dgate.astype(w_gate.dtype), dup.astype(w_up.dtype),
+            ddown.astype(w_down.dtype), None, None, None, None)
+
+
+_held_chunks.defvjp(_held_chunks_fwd, _held_chunks_bwd)
+
+
+def expert_buffer_rows(tokens: int, top_k: int, n_held: int, n_experts: int,
+                       factor: float = 1.25, multiple: int = 512) -> int:
+    """Rows of the static chunk a training step's expert layer walks:
+    the held share of ``tokens * top_k`` assignments under even routing
+    times ``factor``, up to a multiple the grouped matmul tiles, and
+    never more than every assignment."""
+    share = tokens * top_k * n_held / n_experts
+    rows = -(-int(share * factor) // multiple) * multiple
+    return max(multiple, min(rows, -(-tokens * top_k // multiple) * multiple))
+
+
+def balance_bias_update(bias, load, coeff: float):
+    """Auxiliary-loss-free balancing (Wang et al., arXiv:2408.15664):
+    ``b_e <- b_e + coeff * sign(mean(load) - load_e)`` from the
+    assignments each of ALL the router's experts got in the step.  The
+    bias only corrects the choice, carries no gradient and belongs to
+    no optimizer's tree; the step applies this after the optimizer."""
+    load = load.astype(jnp.float32)
+    return bias + coeff * jnp.sign(
+        jnp.mean(load, axis=-1, keepdims=True) - load).astype(bias.dtype)
+
+
 def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
                      topk_group: int, scale: float, token_mask=None,
-                     layer=None, impl="auto"):
+                     layer=None, impl="auto", buffer_rows=None):
     """The routed part of an expert layer that the experts ``held``
     give, for every token, with no assignment dropped.
 
@@ -284,6 +458,17 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
     the int32 scalars ``assignments_held`` (assignments computed
     here), ``assignments_all`` (``top_k`` a live token) and
     ``experts_hit`` (held experts with at least one).
+
+    ``buffer_rows`` (a static int; :func:`expert_buffer_rows`): the
+    trainable form.  The held assignments, compacted by the sort, are
+    walked in chunks of ``buffer_rows`` rows, as many as hold a live
+    row, under a ``custom_vjp`` whose backward walks them again
+    (:func:`_held_chunks`): nothing is dropped whatever the routing and
+    no buffer has ``T * top_k`` rows.  ``counts`` then also holds
+    ``load`` (E,) int32, the assignments every expert of the router got
+    (what :func:`balance_bias_update` reads), ``spill_chunks`` (chunks
+    walked beyond the first) and ``buffer_rows`` (rows of the chunks
+    walked, the first always).
     """
     T, H = x.shape
     n_held = len(held)
@@ -308,6 +493,48 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
     group_sizes = jnp.sum(
         local[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None],
         axis=0, dtype=jnp.int32)
+
+    def counted():
+        n_tokens = T if token_mask is None else jnp.sum(token_mask)
+        return {
+            "assignments_held": jnp.sum(group_sizes),
+            "assignments_all": jnp.asarray(n_tokens * top_k, jnp.int32),
+            "experts_hit": jnp.sum(group_sizes > 0, dtype=jnp.int32),
+        }
+
+    if buffer_rows is not None:
+        if n_layers != 1:
+            raise ValueError("buffer_rows takes one layer's experts, "
+                             "not a stack and an index")
+        # every expert's load, token by token (no T * top_k-row
+        # one-hot); the held experts' group sizes are its slice
+        chosen = ids if token_mask is None else jnp.where(
+            token_mask[:, None], ids, -1)
+        every = jnp.arange(params["router"].shape[-1], dtype=jnp.int32)
+        load = jnp.sum(
+            jnp.sum(chosen[:, :, None] == every[None, None], axis=1,
+                    dtype=jnp.int32), axis=0, dtype=jnp.int32)
+        group_sizes = load[held.start:held.stop]
+        counts = counted()
+        # each assignment's rank in the sorted order
+        slot = jnp.zeros((A,), jnp.int32).at[order].set(
+            jnp.arange(A, dtype=jnp.int32)).reshape(T, top_k)
+        offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                   jnp.cumsum(group_sizes)])
+        # the buffer walks whole chunks: pad the order to one
+        rows_per_chunk = int(buffer_rows)
+        padded = -(-A // rows_per_chunk) * rows_per_chunk
+        out = _held_chunks(
+            x, jnp.where(live, weights, 0.0),
+            *(experts[k][0] for k in ("we_gate", "we_up", "we_down")),
+            jnp.pad(order, (0, padded - A)), slot, offsets,
+            counts["assignments_held"], rows_per_chunk, impl)
+        counts.update(
+            load=load,
+            spill_chunks=jnp.maximum(
+                -(-counts["assignments_held"] // rows_per_chunk) - 1, 0))
+        counts["buffer_rows"] = rows_per_chunk * (1 + counts["spill_chunks"])
+        return out.astype(x.dtype), counts
     rows = jnp.take(x, order // top_k, axis=0)        # (A, H)
     # the layers' experts side by side as groups; only this layer's
     # have rows
@@ -326,10 +553,4 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
     back = jnp.zeros((A,), jnp.int32).at[order].set(
         jnp.arange(A, dtype=jnp.int32))
     out = jnp.take(y, back, axis=0).reshape(T, top_k, H).sum(1)
-    n_tokens = T if token_mask is None else jnp.sum(token_mask)
-    counts = {
-        "assignments_held": jnp.sum(group_sizes),
-        "assignments_all": jnp.asarray(n_tokens * top_k, jnp.int32),
-        "experts_hit": jnp.sum(group_sizes > 0, dtype=jnp.int32),
-    }
-    return out.astype(x.dtype), counts
+    return out.astype(x.dtype), counted()

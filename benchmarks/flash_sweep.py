@@ -15,6 +15,12 @@ measures it, on the real chip, at the shapes the benchmark's cells run:
   ``k_offset`` (``evabyte-6.5b.serve-bytedoc-over``'s prefill);
 - ``mla``: S 512–4096, D 192, bf16, 32 heads: fwd, causal (the latent
   family's prefill);
+- ``afmoe``: S 8192, D 128, bf16, GQA 32:4, one sequence: fwd, dq and
+  dkv, once under a sliding window of 2,048 keys (the band) and once
+  causal (the triangle): the two kinds of layer of
+  ``trinity-mini.train-8k``.  Both read ONE row a phase (the table is
+  keyed by shape, not by window), so read the two cases' lines
+  together: a step runs three band calls to one full call;
 - ``long`` (``--long``): S 4096 / 8192 at D 64 and their ring-attention
   chunk shapes (Sq/cp for cp ∈ {2, 4}), fwd and bwd.
 
@@ -65,9 +71,13 @@ MATMULS = {"fwd": 2, "dq": 3, "dkv": 4}
 BF16 = jnp.bfloat16
 
 
-def _case(name, phases, BH, Sq, D, pooled=0, bias=False):
+def _case(name, phases, BH, Sq, D, pooled=0, bias=False, kv=None,
+          window=None):
+    """``kv``: key/value heads (grouped-query attention; default one a
+    query head); ``window``: a sliding window of that many keys."""
     return dict(name=name, phases=phases, BH=BH, Sq=Sq, Sk=Sq + pooled,
-                D=D, k_offset=-pooled, bias=bias)
+                D=D, k_offset=-pooled, bias=bias, kv=kv or BH,
+                window=window)
 
 
 def cases(groups, quick):
@@ -81,6 +91,10 @@ def cases(groups, quick):
     if "mla" in groups:
         for S in ((1024,) if quick else (512, 1024, 2048, 4096)):
             out.append(_case(f"mla{S}", ("fwd",), 32, S, 192))
+    if "afmoe" in groups:
+        for name, window in (("afmoe8k-band", 2048), ("afmoe8k-full", None)):
+            out.append(_case(name, ("fwd", "dq", "dkv"), 32, 8192, 128,
+                             kv=4, window=window))
     if "long" in groups:
         long = [(24, 4096, 64), (8, 8192, 64)]
         ring = [(BH * cp, S // cp, D) for BH, S, D in long for cp in (2, 4)]
@@ -91,21 +105,27 @@ def cases(groups, quick):
 
 def useful_tflop(case, phase):
     """Causal work of one call: the live triangle (and the whole pooled
-    buffer beside it), 2·rows·cols·D a matmul."""
-    live = case["Sq"] * (case["Sq"] + 1) / 2 + case["Sq"] * -case["k_offset"]
+    buffer beside it) or, under a window, the band; 2·rows·cols·D a
+    matmul."""
+    S, W = case["Sq"], case["window"]
+    if W is not None and W < S:
+        live = W * (W + 1) / 2 + (S - W) * W
+    else:
+        live = S * (S + 1) / 2 + S * -case["k_offset"]
     return case["BH"] * MATMULS[phase] * 2 * live * case["D"] / 1e12
 
 
 @functools.lru_cache(maxsize=1)
-def inputs_of(name, BH, Sq, Sk, D, k_offset, biased, interpret):
+def inputs_of(name, BH, Sq, Sk, D, k_offset, biased, interpret, kv=None,
+              window=None):
     """A case's operands, made once: q, k, v, the cotangent, the key
     bias (half of a pooled buffer hidden), and the forward's ``lse`` and
     ``delta`` the backward kernels read (through the dispatcher's own
     blocks: their values do not depend on the blocks timed)."""
-    kq, kk, kv, kd = jax.random.split(jax.random.PRNGKey(0), 4)
+    kq, kk, kv_key, kd = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(kq, (BH, Sq, D), BF16)
-    k = jax.random.normal(kk, (BH, Sk, D), BF16)
-    v = jax.random.normal(kv, (BH, Sk, D), BF16)
+    k = jax.random.normal(kk, (kv or BH, Sk, D), BF16)
+    v = jax.random.normal(kv_key, (kv or BH, Sk, D), BF16)
     do = jax.random.normal(kd, (BH, Sq, D), BF16)
     bias = None
     if biased:
@@ -115,7 +135,7 @@ def inputs_of(name, BH, Sq, Sk, D, k_offset, biased, interpret):
             jnp.float32)[None, None, :]
     out, lse = fap.flash_fwd_pallas(
         q, k, v, float(D) ** -0.5, True, 0, k_offset, kv_bias=bias,
-        heads=BH, interpret=interpret)
+        heads=BH, kv_heads=kv or BH, interpret=interpret, window=window)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
     return q, k, v, do, lse, delta, bias
@@ -129,22 +149,24 @@ def build(case, phase, bq, bk, sub, run, interpret, iters):
     and the host's dispatch (most of a millisecond here) is paid once,
     not a call."""
     BH, Sq, Sk, D = case["BH"], case["Sq"], case["Sk"], case["D"]
+    KV, window = case["kv"], case["window"]
     q, k, v, do, lse, delta, bias = inputs_of(
         case["name"], BH, Sq, Sk, D, case["k_offset"], case["bias"],
-        interpret)
+        interpret, KV, window)
     subs = (sub, sub, run) if sub else (bq, bk, 1)
-    static = (Sq, Sk, D, BH, BH, float(D) ** -0.5, True, 0,
+    static = (Sq, Sk, D, BH, KV, float(D) ** -0.5, True, 0,
               case["k_offset"], bq, bk, subs, case["bias"], interpret)
     bias = () if bias is None else (bias,)
     if phase == "fwd":
-        return chained(fap._fwd_call(BH, *static, "bfloat16"), 0,
+        return chained(fap._fwd_call(BH, *static, "bfloat16", window), 0,
                        iters), (q, k, v) + bias
     if phase == "dq":
-        call = fap._dq_pallas_call(BH, BH, *static, "bfloat16")
+        call = fap._dq_pallas_call(BH, KV, *static, "bfloat16", window)
         return chained(call, 0, iters), (q, k, v, do, lse, delta) + bias
     # dkv holds its tiles keys by queries: statistics as rows, the key
     # bias as a column (flash_bwd_pallas reshapes them so)
-    call = fap._dkv_pallas_call(BH, BH, *static, "bfloat16", "bfloat16")
+    call = fap._dkv_pallas_call(BH, KV, *static, "bfloat16", "bfloat16",
+                                window)
     return chained(call, 1, iters), (
         q, k, v, do, lse.reshape(BH, 1, Sq), delta.reshape(BH, 1, Sq)
     ) + tuple(b.reshape(-1, Sk, 1) for b in bias)
@@ -194,7 +216,8 @@ def candidates(case, phase, blocks, subs, runs):
     The whole key length is always a candidate key block: one block
     along the keys is code with no state carried between grid steps."""
     Sq, Sk = case["Sq"], case["Sk"]
-    key_blocks = sorted(set(blocks) | {Sk})
+    # (8,192 keys of 128 in one block do not fit VMEM: not a candidate)
+    key_blocks = sorted(set(blocks) | ({Sk} if Sk <= 4096 else set()))
     for bq, bk in itertools.product(blocks, key_blocks):
         if bq > Sq or Sq % bq or bk > Sk or Sk % bk:
             continue
@@ -283,7 +306,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", help="fewer shapes/blocks")
     ap.add_argument("--shapes", nargs="+", default=["train", "eva", "mla"],
-                    choices=["train", "eva", "mla", "long"])
+                    choices=["train", "eva", "mla", "afmoe", "long"])
     ap.add_argument("--long", action="store_true",
                     help="add the 4096/8192 shapes and their ring chunks")
     ap.add_argument("--blocks", nargs="+", type=int,
@@ -351,7 +374,7 @@ def main():
                     continue
                 visited, masked, skipped, bodies = fap.live_subtiles(
                     phase, case["Sq"], case["Sk"], 0, case["k_offset"],
-                    bq, bk, sub, run=run)
+                    bq, bk, sub, run=run, window=case["window"])
                 tflops = useful_tflop(case, phase) / sec
                 rec.update(
                     ms=round(sec * 1e3, 4),

@@ -9,6 +9,11 @@ weights random from a seed), on whatever TPU JAX finds:
   training kernel on (flash attention, Pallas LayerNorm, Pallas fused
   LM-head+CE) for 8 steps; then one forward+backward at the same shapes
   with the kernels on against the same call on the reference paths;
+- what the third family adds to the training path: the flash kernels
+  under a sliding window (forward, dq, dkv; GQA 16:2, head 128) against
+  the scan twin, and the trainable expert layer's chunk walk (megablox
+  ``gmm``/``tgmm``, 16 of 128 experts held) against ``ragged_dot``'s own
+  derivative;
 - the server: GPT-124M (L12 H768 heads12 V50304, rope, bf16 KV, page 16)
   through ``ContinuousBatchingScheduler`` as ``serve_gpt.main`` builds
   it, kernels forced (``attn_impl="pallas"``, ``sample_impl="pallas"``),
@@ -250,6 +255,108 @@ def parity_phase():
     return out
 
 
+# ------------------------------------- window kernels, trainable experts
+# The flash kernels under a sliding window against the scan twin, and the
+# trainable expert layer's chunk walk (megablox gmm / tgmm under their
+# custom_vjp) against ``ragged_dot``'s own derivative: bf16 inputs, the
+# same arithmetic in another order on both sides.  Bounds as the parity
+# phase's (a wrong mask or a wrong group is off by O(1) in a leaf);
+# measured on a v5e (my chip run, PR 38): window 1.05e-3, experts 2.0e-5.
+WINDOW_REL_L2 = 5e-2
+EXPERTS_REL_L2 = 5e-2
+
+
+def window_experts_phase():
+    """Forward and all three gradients of (a) windowed GQA flash
+    attention, kernels against the scan twin, at 2 x 16:2 heads of 128
+    over 2,048 positions with a window of 512, and (b)
+    ``held_experts_ffn(buffer_rows=...)`` over 2,048 tokens, 16 of 128
+    experts held, the Pallas grouped matmuls against ``ragged_dot``."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.analysis.lowered import pallas_kernels
+    from apex_tpu.ops.attention import flash_attention
+    from apex_tpu.transformer.expert_parallel import (
+        expert_buffer_rows, held_experts_ffn,
+    )
+
+    bf16 = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(0), 12)
+    t0 = time.time()
+
+    def rel_l2(got, want):
+        f32 = lambda x: np.asarray(x, np.float32)
+        num = sum(float(np.sum((f32(a) - f32(b)) ** 2)) for a, b in zip(
+            jax.tree.leaves(got), jax.tree.leaves(want)))
+        den = sum(float(np.sum(f32(b) ** 2)) for b in jax.tree.leaves(want))
+        return math.sqrt(num / den)
+
+    # (a) the band in fwd, dq and dkv
+    q = jax.random.normal(ks[0], (2, 16, 2048, 128), bf16)
+    k = jax.random.normal(ks[1], (2, 2, 2048, 128), bf16)
+    v = jax.random.normal(ks[2], (2, 2, 2048, 128), bf16)
+    g = jax.random.normal(ks[3], (2, 16, 2048, 128), bf16)
+
+    def attend(impl):
+        fn = jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, window=512, impl=impl).astype(jnp.float32)
+                * g.astype(jnp.float32)), (0, 1, 2)))
+        return fn.lower(q, k, v).compile()
+
+    kern, twin = attend("pallas"), attend("scan")
+    check({"apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv"}
+          <= set(pallas_kernels(kern)), "window: a flash kernel is missing")
+    check(not pallas_kernels(twin), "window: the twin holds kernels")
+    window_err = rel_l2(kern(q, k, v), twin(q, k, v))
+    check(window_err <= WINDOW_REL_L2,
+          f"window: kernels against the scan twin, rel L2 {window_err} "
+          f"> {WINDOW_REL_L2}")
+
+    # (b) the trainable expert layer
+    T, H, F, E, held = 2048, 1024, 512, 128, range(16, 32)
+    x = jax.random.normal(ks[4], (T, H), bf16)
+    gx = jax.random.normal(ks[5], (T, H), bf16)
+    params = {
+        "router": jax.random.normal(ks[6], (H, E), jnp.float32) * 0.05,
+        "router_bias": jnp.zeros((E,), jnp.float32),
+        "we_gate": jax.random.normal(ks[7], (16, H, F), bf16) * 0.05,
+        "we_up": jax.random.normal(ks[8], (16, H, F), bf16) * 0.05,
+        "we_down": jax.random.normal(ks[9], (16, F, H), bf16) * 0.05}
+    rows = expert_buffer_rows(T, 8, 16, E)
+
+    def experts(impl):
+        def loss(x, params):
+            out, counts = held_experts_ffn(
+                x, params, held, top_k=8, n_group=1, topk_group=1,
+                scale=2.826, impl=impl, buffer_rows=rows)
+            return jnp.sum(out.astype(jnp.float32)
+                           * gx.astype(jnp.float32)), counts
+
+        return jax.jit(jax.value_and_grad(
+            loss, (0, 1), has_aux=True)).lower(x, params).compile()
+
+    kern, twin = experts("pallas"), experts("xla")
+    check({"gmm", "tgmm"} <= set(pallas_kernels(kern)),
+          "experts: a grouped matmul kernel is missing")
+    (loss_k, counts), grads_k = kern(x, params)
+    (loss_t, _), grads_t = twin(x, params)
+    experts_err = rel_l2((loss_k, grads_k), (loss_t, grads_t))
+    check(experts_err <= EXPERTS_REL_L2,
+          f"experts: Pallas grouped matmuls against ragged_dot, rel L2 "
+          f"{experts_err} > {EXPERTS_REL_L2}")
+    check(int(counts["assignments_held"]) == int(
+        counts["load"][held.start:held.stop].sum()),
+        "experts: an assignment was dropped")
+    out = {"window_rel_l2": window_err, "experts_rel_l2": experts_err,
+           "assignments_held": int(counts["assignments_held"]),
+           "buffer_rows": int(counts["buffer_rows"]),
+           "setup_s": round(time.time() - t0, 1), "ok": True}
+    print("window_experts: " + json.dumps(out), flush=True)
+    return out
+
+
 # ------------------------------------------------------------------- server
 def server_phase(temperature, top_k):
     import jax
@@ -451,6 +558,8 @@ def main():
     phases["trainer"] = trainer_phase()
     gc.collect()
     phases["parity"] = parity_phase()
+    gc.collect()
+    phases["window_experts"] = window_experts_phase()
     gc.collect()
     phases["server_greedy"] = server_phase(temperature=0.0, top_k=0)
     phases["server_sampled"] = server_phase(temperature=1.0, top_k=40)

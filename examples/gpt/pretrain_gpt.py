@@ -90,6 +90,24 @@ def parse_args(argv=None):
                    help="flash attention core (ops/attention.py): the "
                         "Pallas kernels on TPU, the scan composite "
                         "elsewhere")
+    p.add_argument("--family", default="gpt", choices=["gpt", "afmoe"],
+                   help="the model family: 'gpt' (the flags above size "
+                        "it), or 'afmoe' (models/afmoe.py: window and "
+                        "full attention mixed, sandwich norms, gated "
+                        "GQA, held sparse experts), sized by "
+                        "--model-config; its recipe is AdamW betas "
+                        "0.9/0.95, weight decay 0.1 on matrices and "
+                        "none on gains, the routers' biases outside the "
+                        "optimizer")
+    p.add_argument("--model-config", default=None,
+                   help="a published-style config.json of the family "
+                        "(model_type afmoe).  A file that describes one "
+                        "chip's share of an expert group gives the "
+                        "router's width under 'published' and its own "
+                        "num_experts is what this process holds, from "
+                        "--held-start on")
+    p.add_argument("--held-start", type=int, default=0,
+                   help="first expert id this process holds")
     p.add_argument("--checkpoint", default=None, help="save dir (async)")
     p.add_argument("--save-every", type=int, default=4)
     p.add_argument("--keep", type=int, default=3,
@@ -171,6 +189,34 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
+def _afmoe_config(args):
+    """``--family afmoe``: the configuration ``--model-config`` names,
+    with this process's share of its experts and the trainer's flags."""
+    from apex_tpu.models.afmoe import AFMoEConfig
+
+    for flag, bad in (("--tp", args.tp > 1), ("--pp", args.pp > 1),
+                      ("--zero", args.zero), ("--rope", args.rope),
+                      ("--sequence-parallel", args.sequence_parallel),
+                      ("--num-query-groups",
+                       args.num_query_groups is not None)):
+        if bad:
+            raise SystemExit(f"--family afmoe does not take {flag}")
+    if not args.model_config:
+        raise SystemExit("--family afmoe needs --model-config")
+    with open(args.model_config) as f:
+        conf = json.load(f)
+    return AFMoEConfig.from_published(
+        conf,
+        num_experts=conf.get("published", {}).get("num_experts",
+                                                  conf["num_experts"]),
+        held_start=args.held_start, held_count=conf["num_experts"],
+        compute_dtype=jnp.float16 if args.fp16 else jnp.bfloat16,
+        remat_policy=args.remat_policy,
+        use_flash_attention=args.flash_attention, fused_ce=args.fused_ce,
+        fused_ce_chunk=next(c for c in range(min(128, args.seq), 0, -1)
+                            if args.seq % c == 0))
+
+
 def main(argv=None):
     """Run the trainer; returns what the run is judged by — per-step
     losses, the device, per-device memory, the kernel-fallback registry,
@@ -225,24 +271,32 @@ def main(argv=None):
     print(f"mesh: dp={dp} pp={args.pp} tp={args.tp} "
           f"({len(jax.devices())} devices)")
 
-    config = GPTConfig(
-        vocab_size=args.vocab, hidden_size=args.hidden,
-        num_layers=args.layers, num_attention_heads=args.heads,
-        max_seq_len=args.seq,
-        compute_dtype=jnp.float16 if args.fp16 else jnp.bfloat16,
-        checkpoint_layers=True,
-        remat_policy=args.remat_policy,
-        sequence_parallel=args.sequence_parallel,
-        position_embedding_type="rope" if args.rope else "learned",
-        num_query_groups=args.num_query_groups,
-        use_flash_attention=args.flash_attention,
-        fused_ce=args.fused_ce,
-        # largest divisor of seq <= 128, so the flag always engages
-        # (the gpt_loss guard silently falls back on indivisibility)
-        fused_ce_chunk=next(c for c in range(min(128, args.seq), 0, -1)
-                            if args.seq % c == 0),
-    )
-    params = init_params(config, jax.random.PRNGKey(0))
+    family = None
+    if args.family == "afmoe":
+        config = _afmoe_config(args)
+        family = config.train_family()
+        args.vocab, args.layers = config.vocab_size, config.num_hidden_layers
+        args.hidden = config.hidden_size
+    else:
+        config = GPTConfig(
+            vocab_size=args.vocab, hidden_size=args.hidden,
+            num_layers=args.layers, num_attention_heads=args.heads,
+            max_seq_len=args.seq,
+            compute_dtype=jnp.float16 if args.fp16 else jnp.bfloat16,
+            checkpoint_layers=True,
+            remat_policy=args.remat_policy,
+            sequence_parallel=args.sequence_parallel,
+            position_embedding_type="rope" if args.rope else "learned",
+            num_query_groups=args.num_query_groups,
+            use_flash_attention=args.flash_attention,
+            fused_ce=args.fused_ce,
+            # largest divisor of seq <= 128, so the flag always engages
+            # (the gpt_loss guard silently falls back on indivisibility)
+            fused_ce_chunk=next(c for c in range(min(128, args.seq), 0, -1)
+                                if args.seq % c == 0),
+        )
+    params = (init_params(config, jax.random.PRNGKey(0)) if family is None
+              else family.init_params(jax.random.PRNGKey(0)))
 
     def train_param_specs():
         """PartitionSpec tree for the params as the train step shards
@@ -251,6 +305,8 @@ def main(argv=None):
         both consume it."""
         from jax.sharding import PartitionSpec as P
 
+        if family is not None:
+            return family.param_specs()
         specs = dict(param_specs(config))
         if args.pp > 1:
             specs["layers"] = {
@@ -278,6 +334,15 @@ def main(argv=None):
             axis_sizes["pp"] = args.pp
         state = optimizer.init(params, world_size=dp, param_specs=zspecs,
                                axis_sizes=axis_sizes)
+    elif family is not None:
+        # the family's recipe; its state (the routers' biases, the
+        # counters) is in no optimizer's tree.  Per leaf: the bucket
+        # engine's flat copies of 705M parameters do not fit beside them
+        optimizer = FusedAdam(
+            lr=args.lr, betas=(0.9, 0.95), weight_decay=0.1,
+            param_group_fn=family.weight_decay_group,
+            group_hypers={"gain": {"weight_decay": 0.0}}, use_buckets=False)
+        state = optimizer.init(family.split(params)[0])
     else:
         optimizer = FusedAdam(lr=args.lr, weight_decay=0.01)
         state = optimizer.init(params)
@@ -462,9 +527,11 @@ def main(argv=None):
         if args.zero:
             sspec = optimizer.state_partition_spec()
         else:
+            # a family's optimizer holds its trainable part alone
+            ospecs = pspecs if family is None else family.split(pspecs)[0]
             sspec = type(state)(
-                step=P(), exp_avg=pspecs, exp_avg_sq=pspecs,
-                master=pspecs if state.master is not None else None,
+                step=P(), exp_avg=ospecs, exp_avg_sq=ospecs,
+                master=ospecs if state.master is not None else None,
             )
         scaler_spec = (
             jax.tree.map(lambda _: P(), scaler.state_dict(scaler_state))

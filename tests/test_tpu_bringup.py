@@ -173,6 +173,32 @@ def _flash(heads, kv_heads):
                      .astype(F32).sum(), argnums=(0, 1, 2)), [q, kv, kv])
 
 
+def _flash_8k(window):
+    """The ``afmoe`` train cell's attention: GQA 32:4, head 128, 8,192
+    positions; a sliding window of 2,048 keys, or the causal triangle."""
+    q, kv = ((1, 32, 8192, 128), BF16), ((1, 4, 8192, 128), BF16)
+    return (jax.grad(lambda q, k, v: flash_attention_pallas(
+        q, k, v, window=window).astype(F32).sum(), argnums=(0, 1, 2)),
+        [q, kv, kv])
+
+
+def _grouped_matmul_train(rows=20480, hidden=2048, width=1024, held=16):
+    """The held experts' gated FFN over one static chunk of the train
+    cell, forward and backward (megablox gmm / tgmm under their
+    custom_vjp)."""
+    from apex_tpu.transformer.expert_parallel import _chunk_ffn
+
+    def loss(x, wg, wu, wd, sizes):
+        live = jnp.arange(rows) < jnp.sum(sizes)
+        return _chunk_ffn(x, live, sizes, wg, wu, wd,
+                          "pallas").astype(F32).sum()
+
+    return (jax.grad(loss, argnums=(0, 1, 2, 3)),
+            [((rows, hidden), BF16), ((held, hidden, width), BF16),
+             ((held, hidden, width), BF16), ((held, width, hidden), BF16),
+             ((held,), I32)])
+
+
 def _layer_norm(rows, hidden):
     def fwd_bwd(x, w, b, dy):
         y, mean, rstd = layer_norm_fwd_pallas(x, w, b, 1e-5)
@@ -182,13 +208,23 @@ def _layer_norm(rows, hidden):
                       ((hidden,), F32), ((rows, hidden), BF16)])
 
 
-def _fused_ce(vocab):
+def _fused_ce(vocab, rows=8192, hidden=1024, embed=F32):
     def fwd_bwd(x, e, t, g):
         m, l, tgt = fused_ce_fwd_pallas(x, e, t)
         return tgt, fused_ce_bwd_pallas(x, e, t, m + jnp.log(l), g)
 
-    return (fwd_bwd, [((8192, 1024), BF16), ((vocab, 1024), F32),
-                      ((8192,), I32), ((8192,), F32)])
+    return (fwd_bwd, [((rows, hidden), BF16), ((vocab, hidden), embed),
+                      ((rows,), I32), ((rows,), F32)])
+
+
+def _rms_norm(rows, hidden):
+    def fwd_bwd(x, w, dy):
+        y, mean, rstd = layer_norm_fwd_pallas(x, w, None, 1e-5, rms=True)
+        return y, layer_norm_bwd_pallas(x, w, dy, mean, rstd, rms=True,
+                                        with_bias=False)
+
+    return (fwd_bwd, [((rows, hidden), BF16), ((hidden,), F32),
+                      ((rows, hidden), BF16)])
 
 
 #: EvaByte as its cell serves it: 20 slots, 32 heads of 128, page 128,
@@ -328,6 +364,20 @@ CASES = {
     "fused_ce_tp2_shard": (*_fused_ce(VOCAB // 2),
                            {"apex_fused_ce_fwd", "apex_fused_ce_dx",
                             "apex_fused_ce_dembed"}),
+    # the afmoe train cell: a band of 2,048 keys and the causal triangle
+    # at 8,192 positions under GQA 32:4; the fused cross entropy over an
+    # eighth of a 200,192-row vocabulary at hidden 2,048 (a bf16 head:
+    # its vocabulary block is clamped to VMEM); RMSNorm over 16,384
+    # rows; one chunk of the held experts, forward and backward
+    "flash_window_8k": (*_flash_8k(2048), {"apex_flash_fwd", "apex_flash_dq",
+                                           "apex_flash_dkv"}),
+    "flash_full_8k": (*_flash_8k(None), {"apex_flash_fwd", "apex_flash_dq",
+                                         "apex_flash_dkv"}),
+    "fused_ce_h2048_v25024": (*_fused_ce(25024, 16384, 2048, BF16),
+                              {"apex_fused_ce_fwd", "apex_fused_ce_dx",
+                               "apex_fused_ce_dembed"}),
+    "rms_norm_8k": (*_rms_norm(16384, 2048), {"apex_ln_fwd", "apex_ln_bwd"}),
+    "grouped_matmul_train": (*_grouped_matmul_train(), {"gmm", "tgmm"}),
 }
 
 
@@ -519,6 +569,42 @@ def test_flash_code_size_at_the_cells_shapes():
             *[jax.ShapeDtypeStruct(s, d) for s, d in avals])
         size = len(module.mlir_module_serialized)
         assert size <= EVA_MODULE_BYTES_CEILING[pooled], (pooled, size)
+
+
+def test_flash_window_walk_at_the_train_cells_shape():
+    """A band of 2,048 keys at 8,192 positions: each kernel visits the
+    sub-tiles the band touches and no other (by the same plans its code
+    is built from), masks the two edges' only, and its grid walks the
+    band's blocks, not the square."""
+    from apex_tpu.ops import flash_attention_pallas as fap
+
+    S, W, D = 8192, 2048, 128
+    for kernel in ("fwd", "dq", "dkv"):
+        phase = "fwd" if kernel == "fwd" else "bwd"
+        bq, bk, sub = _dispatched(S, S, D, phase)
+        side = sub or bq
+        n, w = S // side, W // side
+        visited, masked, skipped, bodies = fap.live_subtiles(
+            kernel, S, S, 0, 0, bq, bk, sub, window=W)
+        # per strip: the diagonal's tile, w - 1 whole ones under it and
+        # the lower edge's (W is a multiple of the tile)
+        want = sum(min(r, w) + 1 for r in range(n))
+        assert visited == want and visited + skipped == n * n, kernel
+        assert masked == n + (n - w), kernel
+        causal = fap.live_subtiles(kernel, S, S, 0, 0, bq, bk, sub)
+        assert visited < 0.55 * causal[0], kernel
+        assert bodies <= 24, (kernel, bodies)
+        band = fap._band(kernel, W, 0, 0, bq, bk, S // bq, S // bk)
+        assert band.n_live == W // (bq if kernel == "dkv" else bk) + 1
+    # the lowered grid of the forward: 32 heads x query blocks x the
+    # band's key blocks
+    _, avals, _ = CASES["flash_window_8k"]
+    call, = _pallas_calls(jax.make_jaxpr(
+        lambda q, k, v: flash_attention_pallas(q, k, v, window=W))(
+            *[jax.ShapeDtypeStruct(s, d) for s, d in avals]).jaxpr)
+    grid = tuple(call.params["grid_mapping"].grid)
+    bq, bk, _ = _dispatched(S, S, D, "fwd")
+    assert grid == (32, S // bq, W // bk + 1), grid
 
 
 def test_mla_decode_grid_at_the_cells_shapes():
@@ -797,6 +883,90 @@ for name, (fn, args) in programs.items():
         "matrix_sized": show(large_result_instructions(c, matrix))}
 print(json.dumps(out))
 """
+
+_AFMOE_STEP_CHILD = _DESCRIBED_V5E + """
+import re
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+import apex_tpu.utils.platform as platform
+platform.on_tpu = lambda: True      # the impls that ask choose the kernels
+from apex_tpu.analysis.lowered import pallas_kernels
+from apex_tpu.models.gpt import make_train_step
+from apex_tpu.optimizers import FusedAdam
+from apex_tpu.transformer import parallel_state as ps
+from cellbench.adapters.train_afmoe import program_config
+
+# the train cell as cellbench/adapters/train_afmoe.py builds it: the
+# committed configuration, 2 x 8,192 tokens a step
+conf = json.load(open("cellbench/configs/trinity-mini-26b-a3b-train-ep8.json"))
+args = conf["cellbench"]["args"]
+config = program_config(conf, args)
+family = config.train_family()
+mesh = ps.initialize_model_parallel(
+    tensor_model_parallel_size_=1, pipeline_model_parallel_size_=1,
+    devices=[dev])
+optimizer = FusedAdam(lr=3e-4, betas=tuple(args["betas"]), eps=args["eps"],
+                      weight_decay=args["weight_decay"],
+                      param_group_fn=family.weight_decay_group,
+                      group_hypers={"gain": {"weight_decay": 0.0}},
+                      use_buckets=args["use_buckets"])
+sh = NamedSharding(mesh, P())
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0)))
+state = jax.eval_shape(lambda p: optimizer.init(family.split(p)[0]), params)
+B, S = 2, int(args["seq"])
+tokens = jax.ShapeDtypeStruct((B, S), jnp.int32,
+                              sharding=NamedSharding(mesh, P("dp", None)))
+step = make_train_step(config, optimizer, mesh, donate_state=True)
+try:
+    compiled = step.lower(put(params), put(state), tokens, tokens).compile()
+    mem = compiled.memory_analysis()
+    assignments = B * S * config.num_experts_per_tok
+    # every array with T * top_k rows and a second dimension
+    wide = sorted(set(re.findall(r"[a-z0-9]+\\[%d,[0-9,]+\\]" % assignments,
+                                 compiled.as_text())))
+    out = {"bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
+           + mem.output_size_in_bytes - mem.alias_size_in_bytes,
+           "arguments": mem.argument_size_in_bytes,
+           "kernels": sorted(set(pallas_kernels(compiled))),
+           "assignment_rows": assignments, "wide": wide,
+           "buffer_rows": config.buffer_rows(B * S),
+           "parameters": sum(x.size for x in jax.tree.leaves(
+               family.split(params)[0]))}
+except Exception as e:
+    out = {"error": f"{type(e).__name__}: {e}"[:1500]}
+print(json.dumps(out))
+"""
+
+
+def test_the_afmoe_train_step_fits_a_v5e_and_holds_no_assignment_wide_buffer():
+    """The family's train step at the cell's sizes (705.5M parameters in
+    float32 with Adam's moments, 2 x 8,192 tokens, full remat, every
+    kernel), compiled for a v5e without a chip: it fits the chip's
+    15.75 GB with the state filling over 70% of it, every kernel of the
+    path is in it, and no array of it has ``T x top_k`` rows and a
+    second dimension (the expert layer walks a static chunk of the held
+    share)."""
+    r = subprocess.run([sys.executable, "-c", _AFMOE_STEP_CHILD],
+                       cwd=str(REPO), capture_output=True, text=True,
+                       timeout=900,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(f"no compile-only TPU client: {out['skip']}")
+    assert "error" not in out, out
+    assert 705.4e6 < out["parameters"] < 705.6e6
+    assert 11.3e9 < out["bytes"] < 15.75e9, out["bytes"]
+    assert out["arguments"] >= 12 * out["parameters"]
+    assert set(out["kernels"]) >= {
+        "apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv", "apex_ln_fwd",
+        "apex_ln_bwd", "apex_fused_ce_fwd", "apex_fused_ce_dx",
+        "apex_fused_ce_dembed", "gmm", "tgmm"}
+    assert out["assignment_rows"] == 131072 and out["wide"] == [], out["wide"]
+    assert out["buffer_rows"] == 20480
+
 
 #: what may carry a pool through a compiled step without copying it
 _POOL_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while"}
