@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import math
 import re
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 
@@ -49,7 +49,8 @@ __all__ = [
     "host_transfer_sites",
     "arg_shardings", "sharding_of", "assert_sharding",
     "spmd_collective_sites", "assert_spmd_collectives",
-    "pallas_kernels", "large_result_instructions",
+    "pallas_kernels", "large_result_instructions", "entry_instructions",
+    "entry_users",
 ]
 
 #: collective ops that carry a reduction REGION in StableHLO — their
@@ -983,6 +984,44 @@ def large_result_instructions(artifact, min_elements: int,
             out.append({"name": m.group(1), "opcode": opcode,
                         "elements": max(sizes), "line": line.strip()})
     return out
+
+
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def entry_instructions(artifact) -> Dict[str, dict]:
+    """The ENTRY computation of a COMPILED module, by instruction name:
+    ``{"type", "opcode", "operands", "line"}`` each, in program order.
+    ``operands`` are the names inside the opcode's own parentheses;
+    ``type`` is the result type as printed (array or tuple)."""
+    txt = _compiled_text(artifact)
+    out = {}
+    for line in txt[txt.index("ENTRY "):].splitlines()[1:]:
+        if line.startswith("}"):
+            break
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        rtype, opcode = _split_result_type(m.group(2))
+        args = m.group(2)[len(rtype) + 1 + len(opcode):]
+        depth, end = 0, len(args)
+        for i, c in enumerate(args):
+            depth += c == "("
+            depth -= c == ")"
+            if depth == 0:
+                end = i
+                break
+        out[m.group(1)] = {"type": rtype, "opcode": opcode, "line": line,
+                           "operands": _HLO_OPERAND.findall(args[:end])}
+    return out
+
+
+def entry_users(instructions: Dict[str, dict], name: str) -> List[str]:
+    """Names of the ENTRY instructions (:func:`entry_instructions`) that
+    take ``name`` as an operand: how many fusions READ a value.  A
+    value that a step should read once and finds two users for is a
+    second pass over it through memory."""
+    return [n for n, i in instructions.items() if name in i["operands"]]
 
 
 def donated_buffer_count(artifact) -> int:

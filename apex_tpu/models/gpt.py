@@ -1174,20 +1174,15 @@ def _make_gspmd_train_step(
             "clip_grad_norm needs an engine optimizer (OptimizerBase "
             "subclass) — the clip folds into its fused grad pass")
 
-    if getattr(optimizer, "use_buckets", False):
-        # Engine optimizers run their PER-LEAF path here, not the fused
-        # bucket engine: packing differently-sharded leaves into one
-        # flat bucket both defeats the sharding (the concat forces
-        # all-gathers) and mis-partitions outright — XLA's SPMD pass
-        # was observed returning zeroed pack segments for the stacked
-        # tp-sharded leaves on the CPU backend (params came back as
-        # ``-lr*g``).  Under GSPMD the per-leaf spelling IS the fused
-        # one: XLA fuses the elementwise update chains itself.  The
-        # caller's optimizer is not mutated.
-        import copy
-
-        optimizer = copy.copy(optimizer)
-        optimizer.use_buckets = False
+    # An optimizer's tree state updates a leaf at a time (optimizers/
+    # base.py:_dispatch), which is the spelling GSPMD needs: packing
+    # differently-sharded leaves into one flat bucket both defeats the
+    # sharding (the concat forces all-gathers) and mis-partitions
+    # outright — XLA's SPMD pass was observed returning zeroed pack
+    # segments for the stacked tp-sharded leaves on the CPU backend
+    # (params came back as ``-lr*g``).  ``opt_state_spec`` describes
+    # per-leaf slots for that reason; bucket-resident state is not
+    # wired here.
 
     specs = param_specs(config)
     sspec = opt_state_spec

@@ -4,12 +4,15 @@ Each optimizer is a pure pytree transform with exact reference numerics
 (fp32 math regardless of storage dtype), device-side predicated updates
 (the capturable/noop_flag design), and optional fp32 master weights.
 
-All five run on the bucketed **multi-tensor engine** by default (the
-TPU form of ``multi_tensor_apply``): params flatten into a few
-dtype-homogeneous 1-D buckets and each step is one fused elementwise
-pass per bucket, with loss-scale unscale, global-norm grad clip, and
-the all-finite vote folded into the same pass via ``update_scaled``.
-See :mod:`apex_tpu.optimizers.bucketing` and ``docs/optimizers.md``.
+All five choose their route by the layout of the state: a tree of
+per-leaf slots (``init(params)``) updates a leaf at a time, in place
+under donation; bucket-resident slots (``init(params, bucketed=True)``)
+run the bucketed **multi-tensor engine** (the TPU form of
+``multi_tensor_apply``): a few dtype-homogeneous 1-D buckets and one
+fused elementwise pass per bucket.  On both, ``update_scaled`` folds the
+loss-scale unscale, the global-norm grad clip and the all-finite vote
+into the update's own read of the gradients.  See
+:mod:`apex_tpu.optimizers.base` and ``docs/optimizers.md``.
 """
 
 from apex_tpu.optimizers.bucketing import BucketPlan, Buckets, plan_of

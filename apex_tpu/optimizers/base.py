@@ -15,19 +15,36 @@ math reads/writes the master and the returned params are the master cast
 back to storage dtype (reference: ``AdamCapturableMasterFunctor``,
 ``multi_tensor_adam.cu:243``; ``fp16_utils/fp16_optimizer.py``).
 
-Multi-tensor engine: each optimizer's ``update`` dispatches through
-:meth:`OptimizerBase._dispatch` — by default onto the **bucketed
-engine** (``use_buckets=True``): the param pytree flattens into a few
-dtype-homogeneous 1-D buckets (:mod:`apex_tpu.optimizers.bucketing`)
-and the whole step is one fused elementwise pass per bucket, with the
-loss-scale unscale, the global-l2-norm grad clip, and the all-finite
-vote folded into the same pass (``update_scaled``) so grads are read
-once instead of once per sweep.  The per-leaf path remains as the
-numerics specification and the fallback: the engine routes through the
-``resilience.fallback`` registry, so an engine surprise degrades once
-to per-leaf instead of crashing a run.  Both paths are bit-exact in
-fp32 (same elementwise expression trees; ``tests/test_bucketed_engine``
-pins it).
+Two layouts of state, one route each: ``update`` dispatches through
+:meth:`OptimizerBase._dispatch`, which looks at the state it is handed.
+
+- **Tree state** (``init(params)``, the default): the update runs a
+  leaf at a time (``_leaf_update``, the numerics specification).  The
+  unscale, the all-finite vote, the clip's Σx² and the update are
+  elementwise chains and reductions over the same leaf, so inside one
+  jitted step XLA fuses them per leaf: each gradient is read once by
+  the vote/clip reductions where there are any and once by the update,
+  ``p``/``m``/``v`` are read once and written once, and with donation
+  the new leaves alias the old ones.  No whole-model flat copy exists.
+- **Bucket-resident state** (``init(params, bucketed=True)``): the
+  slots ARE a few dtype-homogeneous 1-D buckets
+  (:mod:`apex_tpu.optimizers.bucketing`) and the step is one fused
+  elementwise pass per bucket (``_bucket_update``), with the unscale,
+  the clip and the vote folded into the gradients' pack
+  (:func:`prepare_grads_bucketed`).  This is the layout the ZeRO
+  engines shard (an equal-size 1-D bucket is what a ``psum_scatter``
+  splits cleanly); here it is kept for state that already lives flat.
+
+Until PR 39 tree state ran on the bucket engine too (a port of the
+reference's one-launch-for-many-tensors idea): every step packed the
+gradients and each state slot into whole-model flat copies, ran the
+fused pass, and sliced the results back into leaves.  On a TPU a leaf
+in its tiled layout is not a row-major run of memory, so each pack was
+two physical copies and each unpack one: at GPT-2 medium 85 ms of a
+298.6 ms step against the per-leaf update's 14.8 (9.9 GB at the HBM
+roofline), and 10.6 GB of temporaries (PERF.md, PR 39).  Inside one
+XLA program there are no launches to save.  Both routes are bit-exact in fp32 (same elementwise expression
+trees; ``tests/test_bucketed_engine`` pins it).
 """
 
 from typing import Any, NamedTuple, Optional, Tuple
@@ -245,9 +262,10 @@ def prepare_grads_bucketed(params, grads, scale=None, clip_norm=None,
 
 
 class OptimizerBase:
-    """Common constructor plumbing + the engine dispatch.  Subclasses
-    implement ``init``, ``_leaf_update`` (the per-leaf numerics
-    specification), and ``_bucket_update`` (the fused engine)."""
+    """Common constructor plumbing + the dispatch.  Subclasses
+    implement ``init``, ``_leaf_update`` (the per-leaf update: the
+    numerics specification, and the route of tree state), and
+    ``_bucket_update`` (the fused pass over bucket-resident state)."""
 
     #: state field holding the slot that is a :class:`bucketing.Buckets`
     #: when the state is bucket-resident (subclasses override)
@@ -263,10 +281,15 @@ class OptimizerBase:
 
     def __init__(self, lr: float, weight_decay: float = 0.0,
                  master_weights: bool = False, use_buckets: bool = True):
+        """``use_buckets`` is accepted and means nothing since PR 39:
+        the route follows the layout of the state (:meth:`_dispatch`),
+        so there is nothing left for it to choose.  It stays in the
+        signatures because benchmark code passes it
+        (``cellbench/adapters/train_afmoe.py``); ROADMAP D4 lists it
+        for a ``simplicity`` PR to delete together with that keyword."""
         self.lr = lr
         self.weight_decay = weight_decay
         self.master_weights = master_weights
-        self.use_buckets = use_buckets
 
     # ------------------------------------------------------------ engine
     def _state_is_bucketed(self, state) -> bool:
@@ -287,11 +310,11 @@ class OptimizerBase:
                   scale=None, clip_norm=None, finite_sync=None,
                   want_finite=False, prescale=None, sumsq_reduce=None,
                   **kw):
-        """Route one step: bucket-resident state → engine (no fallback
-        possible: the per-leaf path cannot read flat slots); tree state
-        → engine through the resilience fallback registry (an engine
-        failure degrades once to per-leaf); ``use_buckets=False`` →
-        per-leaf.  Returns ``(new_params, new_state, finite)``."""
+        """Route one step by the layout of ``state``: bucket-resident
+        slots (``init(..., bucketed=True)``) → the bucket engine, which
+        alone can read flat slots; a tree of per-leaf slots → the
+        per-leaf update, in place under donation.  Returns
+        ``(new_params, new_state, finite)``."""
 
         def leaf_path():
             g, finite = grads, grads_finite
@@ -333,20 +356,6 @@ class OptimizerBase:
             return p, s, pred
 
         if self._state_is_bucketed(state):
-            return bucket_path()
-        if self.use_buckets and self._BUCKET_SLOT is not None:
-            from apex_tpu.resilience.fallback import (
-                get_registry,
-                registry_engaged,
-            )
-
-            if registry_engaged(forced=False):
-                return get_registry().call(
-                    "multi_tensor_engine", bucket_path, leaf_path)
-            # multi-process runs never engage the registry (fallback.py:
-            # a per-process degrade-once would lower DIVERGENT programs
-            # of one SPMD step — with the clip psums and the finite-vote
-            # collectives inside): run the engine directly, fail fast
             return bucket_path()
         return leaf_path()
 
@@ -429,22 +438,6 @@ class OptimizerBase:
             return lr
         per = [leaf_lr(h, lr) for h in hyper_leaves]
         return bucketing.seg_broadcast(bucket, per)
-
-    @staticmethod
-    def _slot_buckets(plan, slot):
-        """A state slot as bucket arrays: pass-through when resident,
-        packed (f32) when tree-shaped."""
-        if isinstance(slot, bucketing.Buckets):
-            return slot.arrays, True
-        return tuple(bucketing.pack(plan, slot)), False
-
-    @staticmethod
-    def _emit_slot(plan, arrays, resident):
-        """A new state slot: stays flat when resident (the donated
-        buffers), unpacks to the fp32 per-leaf tree otherwise."""
-        if resident:
-            return bucketing.Buckets(plan, arrays)
-        return bucketing.unpack(plan, arrays, dtype=jnp.float32)
 
 
 def bucket_select(pred, new_arrays, old_arrays):

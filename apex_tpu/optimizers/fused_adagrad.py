@@ -9,8 +9,8 @@ Elementwise (fp32):
 - adagrad_w mode (ADAGRAD_MODE_1): ``h += g²``;
   ``p -= lr·(g/(√h+eps) + wd·p)``.
 
-Runs on the bucketed multi-tensor engine by default (see
-:mod:`apex_tpu.optimizers.base`).
+Tree state updates a leaf at a time, bucket-resident state on the
+bucketed multi-tensor engine (see :mod:`apex_tpu.optimizers.base`).
 """
 
 from typing import Any, NamedTuple, Optional
@@ -105,10 +105,10 @@ class FusedAdagrad(base.OptimizerBase):
         plan = prep.plan
 
         step = base.predicate_step(pred, state.step)
-        h_b, resident = self._slot_buckets(plan, state.sum)
+        h_b = state.sum.arrays
         has_master = state.master is not None
         if has_master:
-            p_b, _ = self._slot_buckets(plan, state.master)
+            p_b = state.master.arrays
         else:
             p_b = bucketing.pack(plan, params)
         hl = self._hyper_leaves(
@@ -127,7 +127,7 @@ class FusedAdagrad(base.OptimizerBase):
         new_p = base.bucket_select(pred, new_p, p_b)
         new_h = base.bucket_select(pred, new_h, h_b)
         new_params = bucketing.unpack(plan, new_p)
-        new_master = (self._emit_slot(plan, new_p, resident)
+        new_master = (bucketing.Buckets(plan, new_p)
                       if has_master else None)
         return new_params, AdagradState(
-            step, self._emit_slot(plan, new_h, resident), new_master)
+            step, bucketing.Buckets(plan, new_h), new_master)

@@ -16,14 +16,16 @@ The capturable behavior is default here: pass ``grads_finite`` (from
 including the step counter — commits only when grads are finite, exactly
 like the reference's device-side noop_flag path.
 
-The update runs on the bucketed multi-tensor engine by default
-(``use_buckets=True``; see :mod:`apex_tpu.optimizers.base`): one fused
-elementwise pass per dtype bucket, bit-exact in fp32 with both the
-per-leaf path and ``optax.adamw`` (the second-moment update is
-``(1-β2)·(g·g)``, optax's association).  ``init(params, bucketed=True)``
-stores m/v (and the fp32 master) as flat bucket buffers that ride the
-jit boundary directly — ``donate_argnums`` then donates the bucket
-buffers themselves.
+The route follows the state's layout (see
+:mod:`apex_tpu.optimizers.base`).  ``init(params)`` makes per-leaf
+m/v and the step updates a leaf at a time: one fusion a leaf reads
+``g``, ``p``, ``m``, ``v`` once and writes ``p``, ``m``, ``v`` in place
+under donation.  ``init(params, bucketed=True)`` stores m/v (and the
+fp32 master) as flat bucket buffers that ride the jit boundary
+directly — ``donate_argnums`` then donates the bucket buffers
+themselves — and the step is one fused pass per dtype bucket.  Both are
+bit-exact in fp32 with each other and with ``optax.adamw`` (the
+second-moment update is ``(1-β2)·(g·g)``, optax's association).
 """
 
 from typing import Any, NamedTuple, Optional, Tuple
@@ -90,7 +92,9 @@ class FusedAdam(base.OptimizerBase):
         """``param_group_fn(path, leaf) -> group_name`` +
         ``group_hypers={name: {"lr": ..., "weight_decay": ...}}`` is the
         functional form of the reference's ``param_groups`` (per-group
-        hyperparameters, e.g. no weight decay on norms/biases)."""
+        hyperparameters, e.g. no weight decay on norms/biases).
+        ``use_buckets`` is accepted and ignored
+        (:class:`~apex_tpu.optimizers.base.OptimizerBase`)."""
         if amsgrad:
             raise RuntimeError("FusedAdam does not support the AMSGrad variant.")
         super().__init__(lr, weight_decay, master_weights,
@@ -156,24 +160,24 @@ class FusedAdam(base.OptimizerBase):
     # --------------------------------------------------------- bucket path
     def _bucket_update_packfree(self, prep: base.PreparedGrads,
                                 state: AdamState, params, pred, lr):
-        """The emit without a param bucket.  ``pack(params)``
-        concatenates every leaf into a bucket XLA materializes, and
-        ``unpack`` writes it all back — two whole-model HBM passes per
-        step that a whole-tree jitted optax update never pays.  With no
-        fp32 master and decoupled decay (AdamW), the bucket math only
-        needs the GRADS in bucket form: m/v/core are
-        computed per bucket (:func:`adam_core`), then each param leaf
-        is emitted directly from its static core slice — slice +
-        elementwise fuse, and no param bucket exists in the HLO.
-        Bit-exact with the packed path (identical expressions per
-        element; only the layout of the param read changed)."""
+        """The emit without a param bucket, for bucket-resident m/v.
+        ``pack(params)`` concatenates every leaf into a bucket XLA
+        materializes, and ``unpack`` writes it all back — two
+        whole-model HBM passes per step.  With no fp32 master and
+        decoupled decay (AdamW), the bucket math only needs the GRADS
+        in bucket form: m/v/core are computed per bucket
+        (:func:`adam_core`), then each param leaf is emitted directly
+        from its static core slice — slice + elementwise fuse, and no
+        param bucket exists in the HLO.  Bit-exact with the packed path
+        (identical expressions per element; only the layout of the
+        param read changed)."""
         lr = self.lr if lr is None else lr
         wd = self.weight_decay
         plan = prep.plan
         step = base.predicate_step(pred, state.step)
         bc1, bc2 = self._bias_corrections(step)
-        m_b, resident = self._slot_buckets(plan, state.exp_avg)
-        v_b, _ = self._slot_buckets(plan, state.exp_avg_sq)
+        m_b = state.exp_avg.arrays
+        v_b = state.exp_avg_sq.arrays
         hl = self._hyper_leaves(
             base.leaf_hypers(params, self.param_group_fn, self.group_hypers))
 
@@ -205,8 +209,8 @@ class FusedAdam(base.OptimizerBase):
         new_params = jax.tree.unflatten(plan.treedef, new_leaves)
         return new_params, AdamState(
             step,
-            self._emit_slot(plan, new_m, resident),
-            self._emit_slot(plan, new_v, resident),
+            bucketing.Buckets(plan, new_m),
+            bucketing.Buckets(plan, new_v),
             None,
         )
 
@@ -221,11 +225,11 @@ class FusedAdam(base.OptimizerBase):
         step = base.predicate_step(pred, state.step)
         bc1, bc2 = self._bias_corrections(step)
 
-        m_b, resident = self._slot_buckets(plan, state.exp_avg)
-        v_b, _ = self._slot_buckets(plan, state.exp_avg_sq)
+        m_b = state.exp_avg.arrays
+        v_b = state.exp_avg_sq.arrays
         has_master = state.master is not None
         if has_master:
-            p_b, _ = self._slot_buckets(plan, state.master)
+            p_b = state.master.arrays
         else:
             p_b = bucketing.pack(plan, params)
         hl = self._hyper_leaves(
@@ -247,11 +251,11 @@ class FusedAdam(base.OptimizerBase):
         new_v = base.bucket_select(pred, new_v, v_b)
 
         new_params = bucketing.unpack(plan, new_p)
-        new_master = (self._emit_slot(plan, new_p, resident)
+        new_master = (bucketing.Buckets(plan, new_p)
                       if has_master else None)
         return new_params, AdamState(
             step,
-            self._emit_slot(plan, new_m, resident),
-            self._emit_slot(plan, new_v, resident),
+            bucketing.Buckets(plan, new_m),
+            bucketing.Buckets(plan, new_v),
             new_master,
         )

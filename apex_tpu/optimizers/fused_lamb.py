@@ -15,11 +15,11 @@ Two-phase semantics reproduced exactly:
    ``use_nvlamb or wd != 0`` and both norms are nonzero;
    ``p -= lr·r·u``.
 
-Runs on the bucketed multi-tensor engine by default (see
-:mod:`apex_tpu.optimizers.base`): stage 1 is one fused pass per dtype
-bucket; the per-tensor norms of stage 2 read the buckets through the
-plan's static offset table, and the trust ratios broadcast back as one
-per-element gather.
+Tree state updates a leaf at a time, bucket-resident state on the
+bucketed multi-tensor engine (see :mod:`apex_tpu.optimizers.base`):
+there stage 1 is one fused pass per dtype bucket; the per-tensor norms
+of stage 2 read the buckets through the plan's static offset table, and
+the trust ratios broadcast back as one per-element gather.
 """
 
 from typing import Any, NamedTuple, Optional, Tuple
@@ -208,11 +208,11 @@ class FusedLAMB(base.OptimizerBase):
             plan, prep.g, lambda x: jnp.sum(jnp.square(x)))
         clip = self._grad_clip(jnp.sqrt(jnp.stack(sq).sum()))
 
-        m_b, resident = self._slot_buckets(plan, state.exp_avg)
-        v_b, _ = self._slot_buckets(plan, state.exp_avg_sq)
+        m_b = state.exp_avg.arrays
+        v_b = state.exp_avg_sq.arrays
         has_master = state.master is not None
         if has_master:
-            p_b, _ = self._slot_buckets(plan, state.master)
+            p_b = state.master.arrays
         else:
             p_b = bucketing.pack(plan, params)
         hl = self._hyper_leaves(base.leaf_hypers(
@@ -251,11 +251,11 @@ class FusedLAMB(base.OptimizerBase):
         new_v = base.bucket_select(pred, new_v, v_b)
 
         new_params = bucketing.unpack(plan, new_p)
-        new_master = (self._emit_slot(plan, new_p, resident)
+        new_master = (bucketing.Buckets(plan, new_p)
                       if has_master else None)
         return new_params, LambState(
             step,
-            self._emit_slot(plan, new_m, resident),
-            self._emit_slot(plan, new_v, resident),
+            bucketing.Buckets(plan, new_m),
+            bucketing.Buckets(plan, new_v),
             new_master,
         )

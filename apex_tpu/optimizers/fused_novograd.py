@@ -19,10 +19,11 @@ Elementwise (fp32), with ``denom = gn/√(1-β2^t) + eps``:
 Note ``bias_correction2 = sqrt(1-β2^t)`` here (unlike Adam) —
 ``multi_tensor_novograd.cu:150-152``.
 
-Runs on the bucketed multi-tensor engine by default (see
-:mod:`apex_tpu.optimizers.base`): the per-tensor norms read the grad
-bucket through the plan's offset table; ``exp_avg_sq`` stays a tree of
-per-leaf scalars in both layouts (it is one float per tensor).
+Tree state updates a leaf at a time, bucket-resident state on the
+bucketed multi-tensor engine (see :mod:`apex_tpu.optimizers.base`):
+there the per-tensor norms read the grad bucket through the plan's
+offset table; ``exp_avg_sq`` stays a tree of per-leaf scalars in both
+layouts (it is one float per tensor).
 """
 
 from typing import Any, NamedTuple, Optional, Tuple
@@ -166,10 +167,10 @@ class FusedNovoGrad(base.OptimizerBase):
         step = base.predicate_step(pred, state.step)
         bc1, bc2 = self._bias_corrections(step)
 
-        m_b, resident = self._slot_buckets(plan, state.exp_avg)
+        m_b = state.exp_avg.arrays
         has_master = state.master is not None
         if has_master:
-            p_b, _ = self._slot_buckets(plan, state.master)
+            p_b = state.master.arrays
         else:
             p_b = bucketing.pack(plan, params)
 
@@ -208,7 +209,7 @@ class FusedNovoGrad(base.OptimizerBase):
             jax.tree.structure(state.exp_avg_sq), gn_new_leaves)
 
         new_params = bucketing.unpack(plan, new_p)
-        new_master = (self._emit_slot(plan, new_p, resident)
+        new_master = (bucketing.Buckets(plan, new_p)
                       if has_master else None)
         return new_params, NovoGradState(
-            step, self._emit_slot(plan, new_m, resident), gn_new, new_master)
+            step, bucketing.Buckets(plan, new_m), gn_new, new_master)

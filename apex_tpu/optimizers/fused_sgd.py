@@ -13,11 +13,12 @@ Per-element semantics (fp32 math):
 - ``p -= lr·g``.
 
 The first-run distinction is handled branch-free with the step counter
-(step==0 ⇒ buf := g), keeping the whole step jit-compatible.  Runs on
-the bucketed multi-tensor engine by default (see
-:mod:`apex_tpu.optimizers.base`); per-group ``momentum`` overrides
-become a per-element select on the bucket, reproducing the per-leaf
-"momentum-free group" semantics exactly.
+(step==0 ⇒ buf := g), keeping the whole step jit-compatible.  Tree
+state updates a leaf at a time, bucket-resident state on the bucketed
+multi-tensor engine (see :mod:`apex_tpu.optimizers.base`); there
+per-group ``momentum`` overrides become a per-element select on the
+bucket, reproducing the per-leaf "momentum-free group" semantics
+exactly.
 """
 
 from typing import Any, NamedTuple, Optional
@@ -145,10 +146,10 @@ class FusedSGD(base.OptimizerBase):
         first_run = state.step == 0
 
         step = base.predicate_step(pred, state.step)
-        buf_b, resident = self._slot_buckets(plan, state.momentum_buffer)
+        buf_b = state.momentum_buffer.arrays
         has_master = state.master is not None
         if has_master:
-            p_b, _ = self._slot_buckets(plan, state.master)
+            p_b = state.master.arrays
         else:
             p_b = bucketing.pack(plan, params)
         hl = self._hyper_leaves(base.leaf_hypers(
@@ -190,7 +191,7 @@ class FusedSGD(base.OptimizerBase):
         new_p = base.bucket_select(pred, new_p, p_b)
         new_buf = base.bucket_select(pred, new_buf, buf_b)
         new_params = bucketing.unpack(plan, new_p)
-        new_master = (self._emit_slot(plan, new_p, resident)
+        new_master = (bucketing.Buckets(plan, new_p)
                       if has_master else None)
         return new_params, SGDState(
-            step, self._emit_slot(plan, new_buf, resident), new_master)
+            step, bucketing.Buckets(plan, new_buf), new_master)

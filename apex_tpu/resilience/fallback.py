@@ -41,10 +41,11 @@ compiled step; catch it there, feed it to :func:`trip_from_exception`,
 and rebuild the step — the new trace consults the registry and lowers
 the fallback.  ``examples/gpt/pretrain_gpt.py`` wires this.
 
-Collective-bearing engines NEVER register here.  The multi-tensor
-bucket engine routes through ``"multi_tensor_engine"`` only because its
-fallback (the per-leaf path) lowers the SAME collective-free program
-shape; the ZeRO bucket engine
+Collective-bearing engines NEVER register here.  (The optimizers'
+``"multi_tensor_engine"`` site went with PR 39: tree state IS the
+per-leaf path now, so there is no engine left to degrade from on that
+route, and bucket-resident state never could fall back.)  The ZeRO
+bucket engine
 (:mod:`apex_tpu.contrib.optimizers._zero_engine`) has per-bucket
 reduce-scatters and all-gathers INSIDE the optimizer, so a per-process
 degrade-once would lower divergent SPMD programs across the pod —

@@ -336,12 +336,11 @@ def main(argv=None):
                                axis_sizes=axis_sizes)
     elif family is not None:
         # the family's recipe; its state (the routers' biases, the
-        # counters) is in no optimizer's tree.  Per leaf: the bucket
-        # engine's flat copies of 705M parameters do not fit beside them
+        # counters) is in no optimizer's tree
         optimizer = FusedAdam(
             lr=args.lr, betas=(0.9, 0.95), weight_decay=0.1,
             param_group_fn=family.weight_decay_group,
-            group_hypers={"gain": {"weight_decay": 0.0}}, use_buckets=False)
+            group_hypers={"gain": {"weight_decay": 0.0}})
         state = optimizer.init(family.split(params)[0])
     else:
         optimizer = FusedAdam(lr=args.lr, weight_decay=0.01)

@@ -2,21 +2,26 @@
 
 The engine (``optimizers/bucketing.py`` + the ``_bucket_update`` paths)
 is the TPU form of the reference's ``multi_tensor_apply`` chunk tables:
-one fused elementwise pass per dtype bucket.  Its correctness contract:
+one fused elementwise pass per dtype bucket.  Since PR 39 it runs only
+where the state LIVES in buckets (``init(params, bucketed=True)``); a
+tree of per-leaf slots updates a leaf at a time.  So every parity case
+here holds the engine on bucket-resident state against the per-leaf
+update on tree state (the resident slots unpacked for the comparison).
+Its correctness contract:
 
 - **bit-exact vs per-leaf in fp32** — both paths evaluate the same
   elementwise expression tree per element and share one per-leaf-Σx²
   reduction shape for the clip norm, so the bucket layout may not
   change a single ulp on elementwise-only steps;
-- **bit-exact vs optax.adamw in fp32** for FusedAdam (the audited
-  bench baseline — the ≥1.0× claim is only meaningful if the two
-  compute the same function);
+- **bit-exact vs optax.adamw in fp32** for FusedAdam, on both routes
+  (the audited bench baseline — the ≥1.0× claim is only meaningful if
+  the two compute the same function);
 - the amp path (``update_scaled``) folds unscale/clip/finite-vote into
   the same grad read with identical results to the separate sweeps;
 - a non-finite step is a device-side NO-OP (params, state, step
   counter all unchanged);
-- resident bucket state (``init(params, bucketed=True)``) is actually
-  donated through a jitted step (the HLO aliases the buffers).
+- resident bucket state is actually donated through a jitted step (the
+  HLO aliases the buffers).
 """
 
 import functools
@@ -105,6 +110,15 @@ def assert_trees(a, b, exact=True, err=""):
                                        err_msg=err)
 
 
+def as_tree_state(state):
+    """A bucket-resident state with every flat slot unpacked to the
+    fp32 per-leaf tree the tree-state route keeps."""
+    return type(state)(*[
+        slot.unpack(dtype=jnp.float32)
+        if isinstance(slot, bucketing.Buckets) else slot
+        for slot in state])
+
+
 # --------------------------------------------------------------- the plan
 class TestBucketPlan:
     def test_layout(self):
@@ -156,16 +170,17 @@ class TestBucketLeafParity:
     def test_update_parity(self, name, mixed):
         params = make_mixed_tree() if mixed else make_tree()
         grads = grads_like(params)
-        ob = OPTS[name]()
-        ol = OPTS[name](use_buckets=False)
+        opt = OPTS[name]()
         pb, pl = params, params
-        sb, sl = ob.init(params), ol.init(params)
+        sb, sl = opt.init(params, bucketed=True), opt.init(params)
         for _ in range(3):
-            pb, sb = ob.update(grads, sb, pb)
-            pl, sl = ol.update(grads, sl, pl)
+            pb, sb = opt.update(grads, sb, pb)
+            pl, sl = opt.update(grads, sl, pl)
+        assert opt._state_is_bucketed(sb) and not opt._state_is_bucketed(sl)
         assert_trees(pb, pl, exact=(name in BITEXACT and not mixed),
                      err=f"{name} bucket vs leaf params")
-        # state parity: same structure (transparent mode keeps trees)
+        # state parity: the resident slots unpack to the leaf route's trees
+        sb = as_tree_state(sb)
         assert jax.tree.structure(sb) == jax.tree.structure(sl)
         assert_trees(sb, sl, exact=(name in BITEXACT and not mixed),
                      err=f"{name} bucket vs leaf state")
@@ -174,9 +189,10 @@ class TestBucketLeafParity:
     def test_clip_parity(self, name):
         params = make_tree()
         grads = grads_like(params)
-        ob, ol = OPTS[name](), OPTS[name](use_buckets=False)
-        pb, sb = ob.update(grads, ob.init(params), params, clip_norm=0.5)
-        pl, sl = ol.update(grads, ol.init(params), params, clip_norm=0.5)
+        opt = OPTS[name]()
+        pb, sb = opt.update(grads, opt.init(params, bucketed=True), params,
+                            clip_norm=0.5)
+        pl, sl = opt.update(grads, opt.init(params), params, clip_norm=0.5)
         assert_trees(pb, pl, exact=False,
                      err=f"{name} clip_norm bucket vs leaf")
 
@@ -184,21 +200,25 @@ class TestBucketLeafParity:
     def test_master_weights_parity(self, name):
         params = make_tree(dtype=jnp.bfloat16)
         grads = grads_like(params)
-        ob = OPTS[name](master_weights=True)
-        ol = OPTS[name](master_weights=True, use_buckets=False)
-        pb, sb = ob.update(grads, ob.init(params), params)
-        pl, sl = ol.update(grads, ol.init(params), params)
+        opt = OPTS[name](master_weights=True)
+        pb, sb = opt.update(grads, opt.init(params, bucketed=True), params)
+        pl, sl = opt.update(grads, opt.init(params), params)
         assert_trees(pb, pl, exact=name in BITEXACT,
                      err=f"{name} master bucket vs leaf")
-        assert_trees(sb.master, sl.master, exact=name in BITEXACT)
+        assert isinstance(sb.master, bucketing.Buckets)
+        assert_trees(as_tree_state(sb).master, sl.master,
+                     exact=name in BITEXACT)
 
 
 # -------------------------------------------------------- optax parity
 class TestOptaxParity:
+    @pytest.mark.parametrize("bucketed", [False, True],
+                             ids=["leaf", "resident"])
     @pytest.mark.parametrize("wd", [0.0, 0.01])
-    def test_adamw_bit_exact_fp32(self, wd):
-        """The bench A/B's correctness leg: FusedAdam (bucketed, the
-        default) computes bit-for-bit the same fp32 function as
+    def test_adamw_bit_exact_fp32(self, wd, bucketed):
+        """The bench A/B's correctness leg: FusedAdam, a leaf at a time
+        (tree state, the default) and on the engine (resident state),
+        computes bit-for-bit the same fp32 function as
         ``optax.adamw`` — so any measured speed gap is implementation,
         not numerics.  Run op-by-op (unjitted): each primitive compiles
         alone, so XLA cannot form different FMA groupings in the two
@@ -209,7 +229,7 @@ class TestOptaxParity:
         opt = FusedAdam(lr=1e-2, weight_decay=wd)
         ox = optax.adamw(1e-2, b1=0.9, b2=0.999, eps=1e-8, weight_decay=wd)
 
-        p_f, s_f = params, opt.init(params)
+        p_f, s_f = params, opt.init(params, bucketed=bucketed)
         p_o, s_o = params, ox.init(params)
         for _ in range(4):
             p_f, s_f = opt.update(grads, s_f, p_f)
@@ -273,14 +293,14 @@ class TestScaledPath:
         grads16 = jax.tree.map(
             lambda g: (g * scale).astype(jnp.float16), grads_like(params))
         opt = OPTS[name]()
-        leaf = OPTS[name](use_buckets=False)
         p1, s1, fin = opt.update_scaled(
-            grads16, opt.init(params), params, scale=scale, clip_norm=1.0)
+            grads16, opt.init(params, bucketed=True), params, scale=scale,
+            clip_norm=1.0)
         assert bool(fin)
         # reference composition on the per-leaf path
         g = jax.tree.map(lambda x: x.astype(jnp.float32) / scale, grads16)
-        p2, s2, fin2 = leaf.update_scaled(
-            g, leaf.init(params), params, clip_norm=1.0)
+        p2, s2, fin2 = opt.update_scaled(
+            g, opt.init(params), params, clip_norm=1.0)
         assert_trees(p1, p2, exact=name in BITEXACT,
                      err=f"{name} fused vs composed amp tail")
 

@@ -90,8 +90,7 @@ class TestDistributedFusedAdam:
         dist = DistributedFusedAdam(lr=1e-2, weight_decay=0.01, axis_name="dp")
         state = dist.init(params, world_size=DP)
 
-        ref = FusedAdam(lr=1e-2, weight_decay=0.01, master_weights=True,
-                        use_buckets=False)
+        ref = FusedAdam(lr=1e-2, weight_decay=0.01, master_weights=True)
         ref_state = ref.init(params)
         ref_params = params
         rng = np.random.RandomState(50)
@@ -231,8 +230,7 @@ class TestDistributedFusedAdam:
         dist = DistributedFusedAdam(lr=1e-2, weight_decay=0.01, axis_name="dp")
         state = dist.init(params, world_size=DP)
         sspec = dist.state_partition_spec()
-        ref = FusedAdam(lr=1e-2, weight_decay=0.01, master_weights=True,
-                        use_buckets=False)
+        ref = FusedAdam(lr=1e-2, weight_decay=0.01, master_weights=True)
         ref_state = ref.init(params)
 
         rng = np.random.RandomState(3)
@@ -368,8 +366,7 @@ class TestSyncDtypeValidation:
                                     axis_name="dp",
                                     grad_sync_dtype=jnp.float16)
         state = dist.init(params, world_size=DP)
-        ref = FusedAdam(lr=1e-2, weight_decay=0.01, master_weights=True,
-                        use_buckets=False)
+        ref = FusedAdam(lr=1e-2, weight_decay=0.01, master_weights=True)
         ref_state = ref.init(params)
         ref_params = params
         rng = np.random.RandomState(31)
@@ -531,8 +528,7 @@ class TestShardedStateDict:
         sspec = dist.state_partition_spec()
         assert sspec.exp_avg[0] == P(("tp", "dp"))
 
-        ref = FusedAdam(lr=1e-2, weight_decay=0.01, master_weights=True,
-                        use_buckets=False)
+        ref = FusedAdam(lr=1e-2, weight_decay=0.01, master_weights=True)
         ref_state = ref.init(params)
         ref_params = params
 
@@ -636,8 +632,7 @@ class TestDistributedFusedLAMB:
         dist = DistributedFusedLAMB(lr=1e-2, weight_decay=0.01,
                                     max_grad_norm=1.0, axis_name="dp")
         state = dist.init(params, world_size=DP)
-        ref = FusedLAMB(lr=1e-2, weight_decay=0.01, max_grad_norm=1.0,
-                        use_buckets=False)
+        ref = FusedLAMB(lr=1e-2, weight_decay=0.01, max_grad_norm=1.0)
         ref_state = ref.init(params)
         ref_params = params
         rng = np.random.RandomState(23)
@@ -673,8 +668,7 @@ class TestDistributedFusedLAMB:
         sspec = dist.state_partition_spec()
         assert sspec.exp_avg[0] == P(("tp", "dp"))
 
-        ref = FusedLAMB(lr=1e-2, weight_decay=0.01, max_grad_norm=1.0,
-                        use_buckets=False)
+        ref = FusedLAMB(lr=1e-2, weight_decay=0.01, max_grad_norm=1.0)
         ref_state = ref.init(params)
         ref_params = params
 

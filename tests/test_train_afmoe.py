@@ -98,8 +98,7 @@ def test_the_train_step_follows_three_reference_steps():
     w, params = _params(config, jax.random.PRNGKey(5))
     hyper = dict(lr=1e-3, betas=(0.9, 0.95), eps=1e-8, weight_decay=0.1)
     optimizer = FusedAdam(**hyper, param_group_fn=family.weight_decay_group,
-                          group_hypers={"gain": {"weight_decay": 0.0}},
-                          use_buckets=False)
+                          group_hypers={"gain": {"weight_decay": 0.0}})
     state = optimizer.init(family.split(params)[0])
     assert "state" not in state.exp_avg         # in no optimizer's tree
     mesh = ps.initialize_model_parallel(
