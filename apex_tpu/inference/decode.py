@@ -10,8 +10,9 @@ given: :func:`cast_once`), ``multi_position``, ``counter_names`` and
 ``max_positions`` — or a model configuration whose ``served_model()``
 gives one (:func:`served`).  ``models.gpt.GPTServed`` is the first,
 ``models.mla_moe.MLAMoEServed`` (a latent cache, held experts) the
-second; nothing here imports a model (docs/inference.md has the
-interface).
+second, ``models.falcon_h1.FalconH1Served`` (paged K/V AND per-slot
+state in every layer) the fifth; nothing here imports a model
+(docs/inference.md has the interface).
 
 ``make_decode_step`` builds ONE jitted function that advances every
 resident sequence by one token: embedding lookup, all transformer
@@ -57,6 +58,8 @@ from apex_tpu.inference.kv_cache import (
 )
 from apex_tpu.observability import tracing as _tracing
 from apex_tpu.ops.decode_sampling_pallas import fused_sample
+# the install of a slot's rows: every per-slot entry's, whichever
+# recurrence keeps it (ops/kda.py's KDA, ops/ssd.py's Mamba-2)
 from apex_tpu.ops.kda import install_rows
 
 __all__ = [

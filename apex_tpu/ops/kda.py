@@ -30,7 +30,11 @@ Three forms of the one recurrence, all float32:
   the same for the short convolution in front of it: one output a slot
   from the slot's cached tail, and the tail shifted, in place.
   :func:`install_rows` (``apex_slot_install``) puts a prefill's final
-  values into one slot's rows of such an array, in place.
+  values into one slot's rows of such an array, in place.  These two
+  serve BOTH recurrences of the package: the Mamba-2 mixer
+  (:mod:`apex_tpu.ops.ssd`, ``models/falcon_h1.py``) calls them with its
+  own channel count and filter, and adds its convolution's bias to the
+  sum it gets back (the kernels keep their names).
 
 **Decays without overflow.**  With ``G`` the running sum of ``g``
 inside a chunk, the chunk's matrices hold ``exp(G_t - G_s)`` for ``s <=
@@ -463,7 +467,9 @@ def _conv_step_pallas(x, w, tails, active, layer, interpret=False):
 
 def conv_step(x, w, tails, active, layer, impl="auto"):
     """One step of the short convolution a slot on the stacked per-slot
-    tails, IN PLACE: the one dispatch between ``apex_kda_conv_step``
+    tails, IN PLACE (a KDA layer's three convolutions side by side, or a
+    Mamba-2 mixer's one; a bias is the caller's to add): the one
+    dispatch between ``apex_kda_conv_step``
     (:data:`CONV_SLOTS` slots a grid step, each slot's row read, an
     active one's shifted, written back) and :func:`conv_step_xla`
     (shapes there).  A chosen kernel degrades once through the fallback
@@ -551,7 +557,8 @@ def _install_pallas(rows, new, slot, interpret=False):
 
 def install_rows(rows, new, slot, impl="auto"):
     """``rows[:, slot] = new``, in place: ``rows`` (L, slots + 1, ...)
-    a stacked per-slot state, ``new`` (L, ...) one slot's values for
+    a stacked per-slot state (a KDA or a Mamba-2 state, a convolution's
+    tails), ``new`` (L, ...) one slot's values for
     every layer, ``slot`` a (traced) scalar.  The kernel
     (``apex_slot_install``) writes the slot's blocks through
     input/output aliasing and touches nothing else; the XLA form is a

@@ -104,6 +104,8 @@ KERNELS: Dict[str, tuple] = {
     "kda_conv_step": ("_kda_conv_step_kernel",),
     "kda_chunk_scan": ("_kda_chunk_scan_kernel",),
     "slot_install": ("_slot_install_kernel", "_slot_install_row_kernel"),
+    # ops/ssd.py: the Mamba-2 state update (its chunked scan is XLA)
+    "ssd_decode": ("_ssd_decode_kernel",),
     # ops/eva.py: the chunk summary; the prompt's attention rides the
     # flash forward, under a name of its own
     "eva_summarise": ("_summarise_kernel", "_summarise_pallas"),

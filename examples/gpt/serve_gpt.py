@@ -28,6 +28,12 @@ by.
         cellbench/configs/evabyte-6.5b-serve-pp4.json \
         --streams 20 --prompt-len 16384 --max-new 2048 \
         --prefill-buckets 2048,4096,8192 --temperature 0
+    # the fifth family (models/falcon_h1.py: attention and a Mamba-2
+    # mixer in every layer; paged K/V and a per-slot state side by side)
+    python examples/gpt/serve_gpt.py --model-config \
+        cellbench/configs/falcon-h1-34b-serve-pp9.json \
+        --streams 96 --page-size 128 --prompt-len 1536 --max-new 1024 \
+        --prefill-buckets 128,256,512,1024 --temperature 0
     # serving v2: speculative decode + shared system prompt + chunked
     # prefill + a preemptible best-effort lane, one command
     python examples/gpt/serve_gpt.py --draft-len 4 --prefix-sharing \\
@@ -93,10 +99,11 @@ def build_args():
                         "GPT flags above: the latent-attention, "
                         "sparse-expert family (model_type deepseek_v3, or "
                         "kimi_linear with its KDA layers; "
-                        "models/mla_moe.py), or model_type evabyte "
+                        "models/mla_moe.py), model_type evabyte "
                         "(models/evabyte.py: --page-size then follows the "
                         "file, window_size / chunk_size, and prompts pad "
-                        "to whole windows).  Where the file states the "
+                        "to whole windows), or model_type falcon_h1 "
+                        "(models/falcon_h1.py).  Where the file states the "
                         "router's width under 'published', its own "
                         "experts count is the number HELD here, from "
                         "--held-start on")
@@ -279,6 +286,12 @@ def check_greedy_parity(params, config, completions, max_check=3):
                 logits = evabyte.forward(params, jnp.asarray(seq), config,
                                          attn_impl="xla")
                 pred = int(jnp.argmax(logits[-1, :config.vocab_size]))
+            elif type(config).__name__ == "FalconH1Config":
+                from apex_tpu.models import falcon_h1
+
+                logits = falcon_h1.forward(params, jnp.asarray([seq]),
+                                           config, attn_impl="xla")
+                pred = int(jnp.argmax(logits[0, len(seq) - 1]))
             else:
                 from apex_tpu.models import mla_moe
 
@@ -296,14 +309,20 @@ def build_model(args, max_seq_len):
     """``(config, params)`` of the family the flags name: GPT from
     ``--layers/--hidden/--heads/...``, or — with ``--model-config`` — the
     family a published-style ``config.json`` names (``model_type``
-    ``evabyte``: ``models/evabyte.py``; else the latent-attention,
-    sparse-expert family; weights in bf16, random)."""
+    ``evabyte``: ``models/evabyte.py``; ``falcon_h1``:
+    ``models/falcon_h1.py``; else the latent-attention, sparse-expert
+    family; weights in bf16, random)."""
     key = jax.random.PRNGKey(args.seed)
     if args.model_config:
-        from apex_tpu.models import evabyte, mla_moe
+        from apex_tpu.models import evabyte, falcon_h1, mla_moe
 
         conf = json.loads(Path(args.model_config).read_text())
         dtype = jnp.float32 if args.smoke else jnp.bfloat16
+        if conf.get("model_type") == "falcon_h1":
+            config = falcon_h1.FalconH1Config.from_published(
+                conf, param_dtype=dtype, compute_dtype=dtype)
+            args.vocab = config.vocab_size
+            return config, falcon_h1.init_params(config, key)
         if conf.get("model_type") == "evabyte":
             config = evabyte.EvaByteConfig.from_published(
                 conf, param_dtype=dtype, compute_dtype=dtype)

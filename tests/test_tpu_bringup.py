@@ -35,7 +35,7 @@ from apex_tpu.ops.flash_attention_pallas import flash_attention_pallas
 from apex_tpu.ops.fused_ce_pallas import (
     fused_ce_bwd_pallas, fused_ce_fwd_pallas,
 )
-from apex_tpu.ops import eva, kda
+from apex_tpu.ops import eva, kda, ssd
 from apex_tpu.ops.mla_decode_pallas import mla_decode_pallas
 from apex_tpu.ops.layer_norm_pallas import (
     layer_norm_bwd_pallas, layer_norm_fwd_pallas,
@@ -152,6 +152,25 @@ def _kda_chunked(S):
     return (lambda q, k, v, g, beta, state: kda.kda_chunked(
         q, k, v, g, beta, state, impl="pallas"),
         [vec, vec, vec, vec, ((S, 32), F32), ((32, 128, 128), F32)])
+
+
+_SSM_STATE = ((8, 97, 32, 128, 256), F32)
+_SSM_TAILS = ((8, 97, 3 * 5120), BF16)
+
+
+def _ssd_decode(slots=96):
+    return (lambda x, dt, A, B, C, D, state, active, layer: ssd.ssd_decode(
+        x, dt, A, B, C, D, state, active, layer, impl="pallas"),
+        [((slots, 32, 128), F32), ((slots, 32), F32), ((32,), F32),
+         ((slots, 2, 256), F32), ((slots, 2, 256), F32), ((32,), F32),
+         _SSM_STATE, ((slots,), jnp.bool_), ((), I32)])
+
+
+def _ssm_conv_step(slots=96):
+    return (lambda x, w, tails, active, layer: kda.conv_step(
+        x, w, tails, active, layer, impl="pallas"),
+        [((slots, 5120), BF16), ((4, 5120), F32), _SSM_TAILS,
+         ((slots,), jnp.bool_), ((), I32)])
 
 
 def _slot_install(rows, dtype):
@@ -326,6 +345,15 @@ CASES = {
                            {"apex_slot_install"}),
     "slot_install_tails": (*_slot_install(_KDA_TAILS, BF16),
                            {"apex_slot_install"}),
+    # the second recurrence at the Falcon-H1 cell's shapes: 96 slots of
+    # 32 heads x (128 x 256), the convolution's step over 5,120 channels
+    # with a float32 filter, the installs of a 4 MB state and its tail
+    "ssd_decode": (*_ssd_decode(), {"apex_ssd_decode"}),
+    "ssm_conv_step": (*_ssm_conv_step(), {"apex_kda_conv_step"}),
+    "slot_install_ssm_state": (*_slot_install(_SSM_STATE, F32),
+                               {"apex_slot_install"}),
+    "slot_install_ssm_tails": (*_slot_install(_SSM_TAILS, BF16),
+                               {"apex_slot_install"}),
     # the windowed cache's kernels at the EvaByte cell's shapes: the
     # walk over one page list of pooled and own pages, the chunk
     # summary, a window of the prompt (with and without a pooled
@@ -884,6 +912,81 @@ for name, (fn, args) in programs.items():
 print(json.dumps(out))
 """
 
+# Falcon-H1 as its benchmark cell runs it: published widths, layers 1-8,
+# a quarter of the vocabulary, 96 slots, 1,024 pages of 128, the longest
+# prefill (1,536 tokens): every layer writes K/V pages AND a slot's rows
+_H1_POOL_CHILD = _DESCRIBED_V5E + """
+import jax.numpy as jnp
+from pathlib import Path
+import apex_tpu.utils.platform as platform
+platform.on_tpu = lambda: True      # 'auto' impls: as on the chip
+from apex_tpu.analysis.lowered import (
+    large_result_instructions, pallas_kernels,
+)
+from apex_tpu.inference.decode import make_decode_step, make_prefill
+from apex_tpu.inference.kv_cache import COUNTERS, alloc_named_pools
+from apex_tpu.models.falcon_h1 import init_params
+from cellbench.adapters.serve_falcon_h1 import decode_config, model_config
+
+conf = json.loads(Path(
+    "cellbench/configs/falcon-h1-34b-serve-pp9.json").read_text())
+cfg, dcfg = model_config(conf), decode_config(conf, 0)
+B, PPS, S = dcfg.max_batch, dcfg.cache.pages_per_seq, dcfg.max_prompt_len
+sh = SingleDeviceSharding(dev)
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+model = cfg.served_model()
+params = put(jax.eval_shape(lambda: model.serving_params(
+    init_params(cfg, jax.random.PRNGKey(0)))))
+pools = put(jax.eval_shape(lambda: dict(
+    alloc_named_pools(model.cache_spec(), dcfg.cache, slots=B),
+    **{COUNTERS: jnp.zeros((len(model.counter_names),), jnp.int32)})))
+I, U = jnp.int32, jnp.uint32
+programs = {
+    "decode_step": (make_decode_step(cfg, dcfg), (
+        params, pools, arg((B,), I), arg((B,), I), arg((B,), jnp.bool_),
+        arg((B, PPS), I), arg((B,), U))),
+    "prefill": (make_prefill(cfg, dcfg), (
+        params, pools, arg((1, S), I), arg((), I), arg((), I),
+        arg((PPS,), I), arg((), U), arg((), I))),
+}
+L = cfg.num_hidden_layers
+# more than one layer of the largest leaf: only a whole stack is larger
+matrix = 1 + params["layers"]["w_gate"].size // L
+nbytes = lambda a: a.size * a.dtype.itemsize
+out = {"pool_bytes": nbytes(pools["k"]), "chip_bytes": 16 * 2 ** 30,
+       "weight_bytes": sum(nbytes(a) for a in jax.tree.leaves(params)),
+       "state_bytes": nbytes(pools["ssm_state"])}
+for name, (fn, args) in programs.items():
+    try:
+        c = fn.lower(*args).compile()
+    except Exception as e:
+        out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}
+        continue
+    show = lambda found: [
+        [i["name"], i["opcode"],
+         "tpu_custom_call" in i["line"]
+         and "output_to_operand_aliasing" in i["line"]]
+        for i in found]
+    mem = c.memory_analysis()
+    out[name] = {
+        "kernels": sorted(set(pallas_kernels(c))),
+        "temp_bytes": mem.temp_size_in_bytes,
+        "program_bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes,
+        "instructions": show(large_result_instructions(
+            c, pools["k"].size // L,
+            containing=(dcfg.cache.num_pages, 4, 128))),
+        "state_instructions": show(large_result_instructions(
+            c, pools["ssm_state"].size // L,
+            containing=(B + 1, 32, 128, 256))),
+        "tails_instructions": show(large_result_instructions(
+            c, pools["ssm_conv"].size // L, containing=(B + 1, 15360))),
+        "matrix_sized": show(large_result_instructions(c, matrix))}
+print(json.dumps(out))
+"""
+
 _AFMOE_STEP_CHILD = _DESCRIBED_V5E + """
 import re
 import jax.numpy as jnp
@@ -979,9 +1082,10 @@ _POOL_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while"}
 
 
 @pytest.fixture(scope="module",
-                params=[_POOL_CHILD, _LATENT_POOL_CHILD, _KDA_POOL_CHILD],
+                params=[_POOL_CHILD, _LATENT_POOL_CHILD, _KDA_POOL_CHILD,
+                        _H1_POOL_CHILD],
                 ids=["gpt2-large-kv", "latent-one-pool",
-                     "kda-state-beside-latent"])
+                     "kda-state-beside-latent", "h1-state-and-kv-a-layer"])
 def serving_programs(request):
     """What the child found in the decode step and the prefill compiled
     for a v5e, once a family for the tests below."""
@@ -1216,6 +1320,41 @@ def test_no_program_copies_the_recurrent_state():
                 f"{name}: no aliased kernel writes {which[:-13]}")
         assert out[name]["program_bytes"] < out["chip_bytes"]
     assert 0.25 * out["chip_bytes"] < out["decode_step"]["program_bytes"]
+
+
+def test_a_layer_that_holds_both_kinds_of_entry_copies_neither():
+    """The Falcon-H1 cell's decode step and its longest prefill (1,536
+    tokens), compiled for a v5e at the committed configuration: every
+    layer writes K/V pages AND a slot's recurrent state, and no
+    instruction but parameters, tuple plumbing and aliased kernels
+    (``apex_kv_write``, ``apex_ssd_decode``, ``apex_slot_install``)
+    produces a value the size of one layer of ``ssm_state`` (407 MB) or
+    of ``k``/``v``; the step's kernels are the family's; and the step
+    holds between 25% and 100% of the chip (13.65 GB: weights 8.22,
+    state 3.25, K/V pool 2.15).  The 24 MB of convolution tails are
+    NOT held to this: the device's own layout for ``bf16[8, 97,
+    15360]`` puts the 8 layers in the sublanes, and XLA copies them to
+    the kernel's at the layer loop's entry and exit (PERF.md, Open
+    questions)."""
+    out = _programs_of(_H1_POOL_CHILD)
+    for name in ("decode_step", "prefill"):
+        for which in ("state_instructions", "instructions"):
+            bad = _moved(out[name][which])
+            assert not bad, (f"{name}: instructions that produce a value "
+                             f"as large as a layer of the "
+                             f"{which[:-13] or 'pool'}: {bad}")
+            assert [n for n, op, _ in out[name][which]
+                    if op == "custom-call"], (
+                f"{name}: no aliased kernel writes it")
+        assert out[name]["program_bytes"] < out["chip_bytes"]
+    assert {"apex_ssd_decode", "apex_kda_conv_step", "apex_kv_write",
+            "apex_decode_attention", "apex_fused_sample"} \
+        <= set(out["decode_step"]["kernels"])
+    assert {"apex_flash_fwd", "apex_slot_install", "apex_kv_write"} \
+        <= set(out["prefill"]["kernels"])
+    assert 0.25 * out["chip_bytes"] < out["decode_step"]["program_bytes"]
+    assert 8.2e9 < out["weight_bytes"] < 8.25e9
+    assert out["state_bytes"] == 97 * 8 * 32 * 128 * 256 * 4
 
 
 _EVA_POOL_CHILD = _DESCRIBED_V5E + """
