@@ -196,6 +196,19 @@ def _local_targets(targets, partition, axis_name):
     return targets - jax.lax.axis_index(axis_name) * partition
 
 
+def _narrow_table(embed):
+    """The table as the Pallas kernels read it: cast ONCE a call to the
+    dot's dtype where the master is wider (float32 against bf16 dots),
+    so they stream half the bytes from HBM and their per-tile cast
+    costs nothing; the MXU sees the values the tile cast produced, bit
+    for bit.  A table already that narrow, or float32 dots, cast
+    nothing.  Forward and backward each ask: inside one program XLA
+    keeps one copy."""
+    from apex_tpu.ops.fused_ce_pallas import table_dtype
+
+    return embed.astype(table_dtype(embed.dtype))
+
+
 def _fwd(x, embed, targets, chunk_size, axis_name, impl=None):
     S, B = targets.shape
     mode, forced = _resolve_mode(impl)
@@ -206,8 +219,8 @@ def _fwd(x, embed, targets, chunk_size, axis_name, impl=None):
         H = x.shape[-1]
         local_t = _local_targets(targets, embed.shape[0], axis_name)
         m, l, tgt = fused_ce_fwd_pallas(
-            x.reshape(S * B, H), embed, local_t.reshape(S * B),
-            interpret=(mode == "interpret"))
+            x.reshape(S * B, H), _narrow_table(embed),
+            local_t.reshape(S * B), interpret=(mode == "interpret"))
         if axis_name is not None:
             m_g = jax.lax.pmax(m, axis_name)
             l_g = jax.lax.psum(l * jnp.exp(m - m_g), axis_name)
@@ -263,8 +276,8 @@ def _bwd(chunk_size, axis_name, impl, res, g):
         B, H = targets.shape[1], x.shape[-1]
         local_t = _local_targets(targets, embed.shape[0], axis_name)
         dx2, dembed = fused_ce_bwd_pallas(
-            x.reshape(S * B, H), embed, local_t.reshape(S * B),
-            lse.reshape(S * B), g.reshape(S * B),
+            x.reshape(S * B, H), _narrow_table(embed),
+            local_t.reshape(S * B), lse.reshape(S * B), g.reshape(S * B),
             interpret=(mode == "interpret"))
         return dx2.reshape(x.shape), dembed.astype(embed.dtype), dt
 

@@ -9,14 +9,28 @@ These kernels keep every logits tile in VMEM, flash-attention-style:
 - **forward** (grid rows × vocab-tiles, vocab sequential): per tile,
   ``s = x_blk @ e_blkᵀ`` on the MXU, online max/sum-exp update in f32
   scratch, target logit picked up by an in-tile one-hot reduction.
-  HBM traffic ≈ one read of x + one read of embed + O(N) outputs —
-  the (N, V) logits never exist.
+  The (N, V) logits never exist; HBM traffic is one read of x, O(N)
+  outputs and ``ceil(N / bn)`` reads of the table — the whole table
+  once a row block.
 - **backward**: two kernels, mirroring the flash dq/dkv split (one
   output dim must own the sequential revisit, so dx and dembed cannot
   share a grid): each recomputes its tiles' logits, forms
   ``(softmax − onehot)·g`` in-register, and contracts immediately —
-  ``dx`` accumulating over vocab tiles in scratch, ``dembed`` over row
-  tiles.
+  ``dx`` accumulating over vocab tiles in scratch (``ceil(N / bn)``
+  reads of the table again), ``dembed`` over row tiles (the table
+  once, x ``ceil(V / bv)`` times).
+
+:func:`plan_blocks` picks each kernel's ``(bn, bv)`` from the shapes
+and dtypes under a VMEM price, by what each kernel pays for (measured
+alone on a v5e, ``benchmarks/fused_ce_sweep.py``): the forward pays a
+row's softmax bookkeeping once a VOCABULARY block whatever its width,
+so it takes the widest; dx pays for the table's re-reads, so it takes
+the tallest row block (GPT-2 medium's 8,192 × 1,024 against 50,304:
+256 × 2,048 forward, 25 vocabulary blocks where 512 columns made 99;
+512 × 512 in dx and dembed).  The table should arrive in the dot's
+dtype (:func:`table_dtype`; ``ops/fused_ce.py`` casts a float32 master
+once a call): the tiles' ``.astype(dot_dtype)`` then costs nothing and
+the reads are half as wide.
 
 MXU dots run with inputs cast to ``dot_dtype`` (bf16 by default) and
 f32 accumulation — the same arithmetic XLA's default-precision f32
@@ -31,6 +45,7 @@ accelerated: ``apex/transformer/tensor_parallel/cross_entropy.py``
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -77,23 +92,99 @@ def _grid(n, block):
     return -(-n // block)
 
 
-def _fit_vocab_block(bv, bn, H, x_dtype, embed_dtype, resident):
-    """``bv`` halved (never under a lane tile) until a kernel's VMEM
-    fits: the double-buffered x and embed tiles, three score-sized
-    float32 temporaries, and what the kernel keeps ``resident`` besides
-    (``resident(bv)`` bytes: scratch and output blocks), under 0.8 of
-    the budget (the compiler's own stack is not priced).  Every shape
-    the kernels had met (hidden <= 1280 in float32) keeps its 512; a
-    2,048-wide head against a (512, 2048) float32 dembed block does
-    not."""
-    def need(b):
-        return (2 * bn * H * jnp.dtype(x_dtype).itemsize
-                + 2 * b * H * jnp.dtype(embed_dtype).itemsize
-                + 3 * bn * b * 4 + resident(b))
+class CEPlan(NamedTuple):
+    """One kernel's blocks, and what they make of the table's traffic."""
+    bn: int
+    bv: int
+    grid: tuple         #: (row blocks, vocabulary blocks)
+    table_dtype: jnp.dtype
+    table_bytes: int    #: the table's bytes pulled from HBM a call
+    vmem_bytes: int     #: the blocks' VMEM, by :func:`_vmem_bytes`
 
-    while bv > _LANES and need(bv) > 0.8 * _VMEM_BUDGET:
-        bv //= 2
-    return bv
+
+def table_dtype(embed_dtype, dot_dtype=None):
+    """The dtype the kernels want their table in: the dot's where the
+    table is wider (the float32 master against bf16 dots: the caller
+    casts it ONCE a call, and the per-tile ``.astype(dot_dtype)`` then
+    costs nothing), else the table's own."""
+    dot_dtype = jnp.dtype(dot_dtype or _default_dot_dtype())
+    embed_dtype = jnp.dtype(embed_dtype)
+    return dot_dtype if dot_dtype.itemsize < embed_dtype.itemsize \
+        else embed_dtype
+
+
+def _vmem_bytes(kernel, bn, bv, H, x_dtype, embed_dtype):
+    """A kernel's VMEM at ``(bn, bv)``: the double-buffered x and table
+    tiles, the ``(bn, 1)`` columns (a lane-padded tile each: the
+    targets and, forward, the three statistics; backward, lse and g)
+    and what the kernel keeps besides.  Held against what Mosaic
+    allocates for a described v5e (the least ``vmem_limit_bytes`` a
+    call compiles under at hidden 1,024 and 2,048, PR 41):
+
+    - forward: the statistics' scratch, ONE score-sized float32
+      temporary (the scores are streamed) and half an x tile: 0 to
+      1.5 MiB over what 20 blocks needed;
+    - dembed: a float32 accumulator and a double-buffered float32
+      output of the table tile's shape, the columns twice, a masked
+      copy of the x tile and no score temporary: within 0.5 MiB of 12
+      blocks;
+    - dx: a float32 accumulator and a double-buffered output of x's
+      shape and three score temporaries, the older, rounder guess: 0.4
+      under to 1.4 over at hidden 1,024, up to 1.4 under at 2,048.
+
+    :func:`plan_blocks` leaves 0.2 of the budget for that."""
+    xb, eb = jnp.dtype(x_dtype).itemsize, jnp.dtype(embed_dtype).itemsize
+    column, scores = bn * _LANES * 4, bn * bv * 4
+    tiles = 2 * bn * H * xb + 2 * bv * H * eb
+    if kernel == "fwd":
+        return tiles + 7 * column + scores + bn * H * xb // 2
+    if kernel == "dx":
+        return tiles + 3 * column + 3 * scores + bn * H * (4 + 2 * xb)
+    return tiles + 6 * column + 3 * bv * H * 4 + bn * H * xb
+
+
+def plan_blocks(kernel, N, H, V, x_dtype, embed_dtype,
+                block_n=None, block_v=None) -> CEPlan:
+    """``(bn, bv)`` for ``kernel`` ("fwd", "dx" or "dembed") from the
+    shapes and dtypes alone: of the row blocks 1,024, 512, 256 and the
+    vocabulary blocks 2,048 down to 256 that :func:`_vmem_bytes` prices
+    under 0.8 of the VMEM budget, the pair that costs the kernel least
+    by what the chip showed (``benchmarks/fused_ce_sweep.py``, PR 41):
+
+    - the forward pays each row's softmax bookkeeping (three lane
+      reductions, the rescale) once a VOCABULARY block, 3.3 ns a row a
+      block on a v5e whatever the block's width, as much as 300 columns
+      of the product: the fewest vocabulary blocks first, then the
+      fewest row blocks (the table is streamed once a row block);
+    - dx has no such cost and streams the table as often: the fewest
+      row blocks first, then the fewest grid steps;
+    - dembed walks rows innermost, reads the table once and x once a
+      vocabulary block: the fewest vocabulary blocks, then row blocks.
+
+    Ties go to the smaller block (less padding).  ``block_n`` /
+    ``block_v`` override the candidates (the tests'); ``bv`` is still
+    halved to fit VMEM.  Where nothing fits (a 2,048-wide head against
+    a float32 table in dembed): 256 rows by one lane tile."""
+    align, embed_dtype = _sublane(x_dtype), jnp.dtype(embed_dtype)
+    heights = [_ceil_block(N, b, align)
+               for b in ((block_n,) if block_n else (1024, 512, 256))]
+    widths = [_ceil_block(V, block_v or 2048, _LANES)]
+    while widths[-1] > (_LANES if block_v else 256):
+        widths.append(max(_LANES, widths[-1] // 2 // _LANES * _LANES))
+
+    def cost(pair):
+        nn, nv = _grid(N, pair[0]), _grid(V, pair[1])
+        return ((nn, nv) if kernel == "dx" else (nv, nn)) + pair
+
+    fit = [(bn, bv) for bn in heights for bv in widths
+           if _vmem_bytes(kernel, bn, bv, H, x_dtype,
+                          embed_dtype) <= 0.8 * _VMEM_BUDGET]
+    bn, bv = min(fit or [(heights[-1], _LANES)], key=cost)
+    grid = (_grid(N, bn), _grid(V, bv))
+    reads = 1 if kernel == "dembed" else grid[0]
+    return CEPlan(bn, bv, grid, embed_dtype,
+                  reads * V * H * embed_dtype.itemsize,
+                  _vmem_bytes(kernel, bn, bv, H, x_dtype, embed_dtype))
 
 
 # ------------------------------------------------------------------ forward
@@ -158,7 +249,7 @@ def _fwd_kernel(x_ref, e_ref, t_ref, m_out, l_out, tgt_out,
 
 
 def fused_ce_fwd_pallas(x2, embed, t, dot_dtype=None,
-                        block_n=256, block_v=512, interpret=False):
+                        block_n=None, block_v=None, interpret=False):
     """x2 (N, H), embed (V, H), t (N,) int32 (shard-LOCAL ids in tp).
 
     Returns (m, l, tgt) each (N,): running max, sum-exp at that max,
@@ -168,10 +259,8 @@ def fused_ce_fwd_pallas(x2, embed, t, dot_dtype=None,
     dot_dtype = dot_dtype or _default_dot_dtype()
     N, H = x2.shape
     V = embed.shape[0]
-    bn = _ceil_block(N, block_n, align=_sublane(x2.dtype))
-    bv = _fit_vocab_block(_ceil_block(V, block_v, align=_LANES), bn, H,
-                          x2.dtype, embed.dtype, lambda b: 0)
-    nn, nv = _grid(N, bn), _grid(V, bv)
+    bn, bv, (nn, nv), *_ = plan_blocks("fwd", N, H, V, x2.dtype, embed.dtype,
+                                       block_n, block_v)
 
     kernel = functools.partial(_fwd_kernel, bv=bv, nv=nv, V=V,
                                dot_dtype=dot_dtype)
@@ -259,7 +348,7 @@ def _dembed_kernel(x_ref, e_ref, t_ref, lse_ref, g_ref, de_out,
 
 
 def fused_ce_bwd_pallas(x2, embed, t, lse, g, dot_dtype=None,
-                        block_n=256, block_v=512, interpret=False):
+                        block_n=None, block_v=None, interpret=False):
     """Gradients of ``sum(g * (lse - tgt))`` wrt x2 and embed.
 
     ``lse`` must be the GLOBAL logsumexp (already pmax/psum-combined in
@@ -269,14 +358,8 @@ def fused_ce_bwd_pallas(x2, embed, t, lse, g, dot_dtype=None,
     dot_dtype = dot_dtype or _default_dot_dtype()
     N, H = x2.shape
     V = embed.shape[0]
-    bn = _ceil_block(N, block_n, align=_sublane(x2.dtype))
-    bv_target = _ceil_block(V, block_v, align=_LANES)
-    # dx keeps a float32 accumulator and a double-buffered output block
-    # of x's shape
-    bv = _fit_vocab_block(
-        bv_target, bn, H, x2.dtype, embed.dtype,
-        lambda b: bn * H * (4 + 2 * jnp.dtype(x2.dtype).itemsize))
-    nn, nv = _grid(N, bn), _grid(V, bv)
+    bn, bv, (nn, nv), *_ = plan_blocks("dx", N, H, V, x2.dtype, embed.dtype,
+                                       block_n, block_v)
     t2 = t.reshape(N, 1).astype(jnp.int32)
     lse2 = lse.reshape(N, 1).astype(jnp.float32)
     g2 = g.reshape(N, 1).astype(jnp.float32)
@@ -303,13 +386,11 @@ def fused_ce_bwd_pallas(x2, embed, t, lse, g, dot_dtype=None,
         name="apex_fused_ce_dx",
     )(x2, embed, t2, lse2, g2)
 
+    # dembed has its own blocks: rows inside, the table read once
+    bn, bv, (nn, nv), *_ = plan_blocks("dembed", N, H, V, x2.dtype,
+                                       embed.dtype, block_n, block_v)
     vrow_spec = pl.BlockSpec((bn, 1), lambda i, j: (j, 0),
                              memory_space=pltpu.VMEM)
-    # dembed keeps a float32 accumulator and a double-buffered float32
-    # output block of the embed tile's shape: its own vocabulary block
-    bv = _fit_vocab_block(bv_target, bn, H, x2.dtype, embed.dtype,
-                          lambda b: 3 * b * H * 4)
-    nv = _grid(V, bv)
     dembed = pl.pallas_call(
         functools.partial(_dembed_kernel, bn=bn, bv=bv, nn=nn, N=N, V=V,
                           dot_dtype=dot_dtype),

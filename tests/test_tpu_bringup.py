@@ -392,6 +392,15 @@ CASES = {
     "fused_ce_tp2_shard": (*_fused_ce(VOCAB // 2),
                            {"apex_fused_ce_fwd", "apex_fused_ce_dx",
                             "apex_fused_ce_dembed"}),
+    # the table as the wrapper hands it over since PR 41, cast once to
+    # the dot's dtype: the planner's blocks (256 x 2,048 forward, 512 x
+    # 512 in dx and dembed), and a tp8 shard with no lane-aligned divisor
+    "fused_ce_narrow_table": (*_fused_ce(VOCAB, embed=BF16),
+                              {"apex_fused_ce_fwd", "apex_fused_ce_dx",
+                               "apex_fused_ce_dembed"}),
+    "fused_ce_narrow_tp8_shard": (*_fused_ce(VOCAB // 8, embed=BF16),
+                                  {"apex_fused_ce_fwd", "apex_fused_ce_dx",
+                                   "apex_fused_ce_dembed"}),
     # the afmoe train cell: a band of 2,048 keys and the causal triangle
     # at 8,192 positions under GQA 32:4; the fused cross entropy over an
     # eighth of a 200,192-row vocabulary at hidden 2,048 (a bf16 head:
@@ -1075,6 +1084,93 @@ def test_the_afmoe_train_step_fits_a_v5e_and_holds_no_assignment_wide_buffer():
         "apex_fused_ce_dembed", "gmm", "tgmm"}
     assert out["assignment_rows"] == 131072 and out["wide"] == [], out["wide"]
     assert out["buffer_rows"] == 20480
+
+
+_GPT_STEP_CHILD = _DESCRIBED_V5E + """
+import re
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+import apex_tpu.utils.platform as platform
+platform.on_tpu = lambda: True      # the impls that ask choose the kernels
+from apex_tpu.analysis.lowered import hlo_text, pallas_kernels
+from apex_tpu.models.gpt import GPTConfig, init_params, make_train_step
+from apex_tpu.optimizers import FusedAdam
+from apex_tpu.transformer import parallel_state as ps
+
+# the train cell as cellbench/adapters/train.py builds it: the committed
+# configuration, 8 x 1,024 tokens a step
+conf = json.load(open("cellbench/configs/gpt2-medium-train.json"))
+args = conf["cellbench"]["args"]
+seq = int(args["seq"])
+config = GPTConfig(
+    vocab_size=conf["vocab_size"], hidden_size=conf["n_embd"],
+    num_layers=conf["n_layer"], num_attention_heads=conf["n_head"],
+    max_seq_len=seq, layernorm_eps=conf["layer_norm_epsilon"],
+    compute_dtype=jnp.dtype(args["compute_dtype"]), checkpoint_layers=True,
+    remat_policy=args["remat_policy"], position_embedding_type="learned",
+    use_flash_attention=True, fused_ce=True, fused_ce_chunk=128)
+mesh = ps.initialize_model_parallel(
+    tensor_model_parallel_size_=1, pipeline_model_parallel_size_=1,
+    devices=[dev])
+optimizer = FusedAdam(lr=3e-4, betas=tuple(args["betas"]), eps=args["eps"],
+                      weight_decay=args["weight_decay"])
+sh = NamedSharding(mesh, P())
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+params = jax.eval_shape(lambda: init_params(config, jax.random.PRNGKey(0)))
+state = jax.eval_shape(optimizer.init, params)
+tokens = jax.ShapeDtypeStruct((8, seq), jnp.int32,
+                              sharding=NamedSharding(mesh, P("dp", None)))
+step = make_train_step(config, optimizer, mesh, donate_state=True)
+try:
+    compiled = step.lower(put(params), put(state), tokens, tokens).compile()
+    mem = compiled.memory_analysis()
+    txt = hlo_text(compiled)
+    # each fused-CE kernel's custom call: the types of what it is handed
+    calls = {}
+    for line in txt.splitlines():
+        name = re.search(r"\\((apex_fused_ce_[a-z]+)\\)", line)
+        if name and "operand_layout_constraints=" in line:
+            calls[name.group(1)] = re.findall(
+                r"(?:bf16|f32|s32)\\[[0-9,]+\\]",
+                line.split("operand_layout_constraints=")[1]
+                .split("frontend_attributes")[0])
+    V, H = conf["vocab_size"], conf["n_embd"]
+    out = {"bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
+           + mem.output_size_in_bytes - mem.alias_size_in_bytes,
+           "kernels": sorted(set(pallas_kernels(compiled))),
+           "calls": calls,
+           "casts": len(re.findall(r"= bf16\\[%d,%d\\]\\S* (?:convert|fusion)\\("
+                                   % (V, H), txt))}
+except Exception as e:
+    out = {"error": f"{type(e).__name__}: {e}"[:1500]}
+print(json.dumps(out))
+"""
+
+
+def test_the_gpt_train_step_hands_the_ce_kernels_a_bf16_table():
+    """The GPT-2 medium step at the train cell's sizes, compiled for a
+    v5e without a chip: all three fused-CE kernels are handed the tied
+    embedding as ``bf16[50304,1024]`` (the float32 master, 206 MB,
+    streamed 32 times a call until PR 41), cast no more than once a
+    pass, and the step still fits what it did (7.92 GB before, a
+    103 MB copy more)."""
+    r = subprocess.run([sys.executable, "-c", _GPT_STEP_CHILD],
+                       cwd=str(REPO), capture_output=True, text=True,
+                       timeout=900,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(f"no compile-only TPU client: {out['skip']}")
+    assert "error" not in out, out
+    assert set(out["calls"]) == {"apex_fused_ce_fwd", "apex_fused_ce_dx",
+                                 "apex_fused_ce_dembed"}, out
+    for kernel, operands in out["calls"].items():
+        assert "bf16[50304,1024]" in operands, (kernel, operands)
+        assert "f32[50304,1024]" not in operands, (kernel, operands)
+    assert 1 <= out["casts"] <= 2, out
+    assert out["bytes"] < 8.03e9, out["bytes"]
 
 
 #: what may carry a pool through a compiled step without copying it
