@@ -41,8 +41,12 @@ the same chunks, recomputes each chunk's rows and activations and
 differentiates the chunk's three grouped matmuls (megablox's ``gmm`` /
 ``tgmm`` pair on a TPU, ``ragged_dot``'s own derivative elsewhere), so
 neither pass holds a ``T x top_k``-row buffer: tokens reach a chunk by
-a gather, and a token sums its assignments by ``top_k`` gathers out of
-the chunk (no scatter).  Rows past the live ones are zeroed where they
+a gather, and a token sums the rows it holds in the chunk in ONE pass
+over the chunk (:func:`_sum_own`: on a TPU the rows are gathered once
+into token order and ``apex_moe_combine`` adds each token's run into
+the carry in place, so the cost follows the held rows; elsewhere
+``top_k`` gathers out of the chunk; no scatter, no ``(T, H)`` array a
+slot).  Rows past the live ones are zeroed where they
 are read and where they are written, so whatever a grouped matmul
 leaves there reaches no output and no gradient.  The router's bias is
 choice-only STATE: :func:`balance_bias_update` moves it from the
@@ -242,6 +246,17 @@ def route_group_limited(x, router_w, bias, *, top_k: int, n_group: int,
 GROUPED_TILING = (128, 1024, 1024)
 
 
+def _plain(impl) -> bool:
+    """Whether ``impl`` asks for the plain XLA form here: "xla", or
+    "auto" off a TPU ("pallas" and "interpret" force the kernels)."""
+    from apex_tpu.utils.platform import on_tpu
+
+    if impl not in ("auto", "pallas", "interpret", "xla"):
+        raise ValueError(f"impl must be 'auto', 'pallas', 'interpret' or "
+                         f"'xla'; got {impl!r}")
+    return impl == "xla" or (impl == "auto" and not on_tpu())
+
+
 def _grouped_matmul(rows, w, group_sizes, impl, trainable=False):
     """``rows[sizes[:g].sum() : sizes[:g+1].sum()] @ w[g]`` for every
     group ``g``: ``jax.lax.ragged_dot`` ("xla"), or the Pallas grouped
@@ -252,14 +267,9 @@ def _grouped_matmul(rows, w, group_sizes, impl, trainable=False):
     ``custom_vjp`` (``gmm`` with the weights transposed for the rows'
     cotangent, ``tgmm`` for the weights'); the bare ``pallas_call`` has
     no derivative."""
-    from apex_tpu.utils.platform import on_tpu
-
-    if impl not in ("auto", "pallas", "interpret", "xla"):
-        raise ValueError(f"impl must be 'auto', 'pallas', 'interpret' or "
-                         f"'xla'; got {impl!r}")
     tm = next((t for t in (GROUPED_TILING[0], 64, 32, 16, 8)
                if rows.shape[0] % t == 0), None)
-    if impl == "xla" or (impl == "auto" and not on_tpu()) or tm is None:
+    if _plain(impl) or tm is None:
         return jax.lax.ragged_dot(rows, w, group_sizes)
     if trainable:
         from jax.experimental.pallas.ops.tpu.megablox.ops import gmm
@@ -328,17 +338,41 @@ def _own(values, slot, first_row):
                     axis=0, mode="fill", fill_value=0)
 
 
-def _sum_own(y, slot, first_row):
-    """Each token's sum over its ``top_k`` assignments of the rows of
-    chunk-local ``y`` they sit in (one outside the chunk adds nothing):
-    ``top_k`` gathers of (T, H), one after the other (all at once would
-    be the ``T x top_k``-row buffer again), no scatter."""
-    local = _rows_of(slot, first_row, y.shape[0])
-    out = 0.0
+def _sum_own(out, rows, w, token, valid, slot, first_row, impl):
+    """``out`` (T, H) float32 plus each token's sum, in float32, over
+    the rows of the chunk it holds, each ``float32(rows[r]) * w[r]``
+    (``w`` None: 1); an assignment outside the chunk adds nothing and a
+    dead row (``valid`` false) is never read, whatever it holds.
+    ``rows`` (R, H) as the grouped matmul left them, ``token`` (R,) the
+    token of each, ``slot`` (T, top_k) each assignment's rank in the
+    sorted order, ``first_row`` the chunk's first rank.
+
+    On a TPU (``impl`` as :func:`_grouped_matmul` reads it) ONE pass
+    over the chunk: the rows gathered once into token order and each
+    token's run added into ``out`` in place
+    (:func:`apex_tpu.ops.moe_combine_pallas.moe_combine_pallas`), so a
+    call costs what the held rows cost.  Elsewhere ``top_k`` gathers of
+    (T, H) out of the chunk, added in slot order (all at once would be
+    the ``T x top_k``-row buffer again); no scatter either way."""
+    from apex_tpu.ops import moe_combine_pallas as kernel
+
+    n_tokens, n_rows = out.shape[0], rows.shape[0]
+    if not _plain(impl) and kernel.token_block(n_tokens) \
+            and rows.dtype in (jnp.bfloat16, jnp.float32):
+        return kernel.moe_combine_pallas(
+            out, rows, w, jnp.where(valid, token, n_tokens),
+            interpret=(impl == "interpret"))
+    local = _rows_of(slot, first_row, n_rows)
+    local = jnp.where(local < jnp.sum(valid), local, n_rows)
+    total = 0.0
     for k in range(slot.shape[1]):
-        out = out + jnp.take(y, local[:, k], axis=0, mode="fill",
-                             fill_value=0)
-    return out
+        own = jnp.take(rows, local[:, k], axis=0, mode="fill",
+                       fill_value=0).astype(jnp.float32)
+        if w is not None:
+            own = own * jnp.take(w, local[:, k], mode="fill",
+                                 fill_value=0)[:, None]
+        total = total + own
+    return out + total
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(9, 10))
@@ -363,9 +397,9 @@ def _held_chunks_fwd(x, weights, w_gate, w_up, w_down, order, slot, offsets,
                                           n_live)
         y = _chunk_ffn(jnp.take(x, assignment // top_k, axis=0), valid,
                        sizes, w_gate, w_up, w_down, impl)
-        w = jnp.take(flat_w, assignment)
-        y = y.astype(jnp.float32) * jnp.where(valid, w, 0.0)[:, None]
-        return out + _sum_own(y, slot, c * rows_per_chunk)
+        w = jnp.where(valid, jnp.take(flat_w, assignment), 0.0)
+        return _sum_own(out, y, w, assignment // top_k, valid, slot,
+                        c * rows_per_chunk, impl)
 
     n_chunks = -(-n_live // rows_per_chunk)
     out = jax.lax.fori_loop(0, n_chunks, body,
@@ -395,9 +429,8 @@ def _held_chunks_bwd(rows_per_chunk, impl, res, dout):
         # token's cotangent
         dw_rows = jnp.sum(y.astype(jnp.float32) * g, axis=-1)
         drows, dg, du, dd = vjp((g * w[:, None]).astype(y.dtype))
-        drows = jnp.where(valid[:, None], drows.astype(jnp.float32), 0.0)
         first = c * rows_per_chunk
-        return (dx + _sum_own(drows, slot, first),
+        return (_sum_own(dx, drows, None, token, valid, slot, first, impl),
                 dweights + _own(dw_rows, slot, first),
                 dgate + dg.astype(jnp.float32), dup + du.astype(jnp.float32),
                 ddown + dd.astype(jnp.float32))

@@ -218,6 +218,24 @@ def _grouped_matmul_train(rows=20480, hidden=2048, width=1024, held=16):
              ((held,), I32)])
 
 
+def _moe_combine(weighted, rows=20480, hidden=2048, tokens=16384, top_k=8):
+    """The combine of one chunk of the train cell's expert layer as
+    ``_held_chunks`` calls it: the grouped matmul's bf16 rows and their
+    float32 routing weights in the forward, the bare bf16 cotangent in
+    the backward, summed by token into the float32 carry in place.  No
+    VMEM limit is asked for: the kernel compiles under the default
+    scoped one or not at all."""
+    from apex_tpu.transformer.expert_parallel import _sum_own
+
+    def combine(out, y, w, token, valid, slot):
+        return _sum_own(out, y, w if weighted else None, token, valid, slot,
+                        0, "pallas")
+
+    return (combine,
+            [((tokens, hidden), F32), ((rows, hidden), BF16), ((rows,), F32),
+             ((rows,), I32), ((rows,), jnp.bool_), ((tokens, top_k), I32)])
+
+
 def _layer_norm(rows, hidden):
     def fwd_bwd(x, w, b, dy):
         y, mean, rstd = layer_norm_fwd_pallas(x, w, b, 1e-5)
@@ -415,6 +433,10 @@ CASES = {
                                "apex_fused_ce_dembed"}),
     "rms_norm_8k": (*_rms_norm(16384, 2048), {"apex_ln_fwd", "apex_ln_bwd"}),
     "grouped_matmul_train": (*_grouped_matmul_train(), {"gmm", "tgmm"}),
+    # ... and the combine of its rows: 20,480 rows of 2,048 into 16,384
+    # tokens, weighted (forward) and bare (backward)
+    "moe_combine_weighted": (*_moe_combine(True), {"apex_moe_combine"}),
+    "moe_combine_bare": (*_moe_combine(False), {"apex_moe_combine"}),
 }
 
 
@@ -1081,7 +1103,7 @@ def test_the_afmoe_train_step_fits_a_v5e_and_holds_no_assignment_wide_buffer():
     assert set(out["kernels"]) >= {
         "apex_flash_fwd", "apex_flash_dq", "apex_flash_dkv", "apex_ln_fwd",
         "apex_ln_bwd", "apex_fused_ce_fwd", "apex_fused_ce_dx",
-        "apex_fused_ce_dembed", "gmm", "tgmm"}
+        "apex_fused_ce_dembed", "gmm", "tgmm", "apex_moe_combine"}
     assert out["assignment_rows"] == 131072 and out["wide"] == [], out["wide"]
     assert out["buffer_rows"] == 20480
 

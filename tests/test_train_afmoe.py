@@ -7,7 +7,9 @@ balance rule and the counters among them; and the trainable expert
 layer (``held_experts_ffn(buffer_rows=...)``): the shares of an expert
 group add up in the backward too, poisoned dead rows change no bit of
 any gradient, a routing that sends every assignment to held experts
-drops none, whatever the buffer."""
+drops none, whatever the buffer, and the one-pass combine of a chunk's
+rows (``_sum_own``, plain and through ``apex_moe_combine``) against the
+``top_k`` gathers it replaced."""
 
 import jax
 import jax.numpy as jnp
@@ -267,6 +269,106 @@ def test_a_routing_that_sends_everything_to_held_experts_drops_none(
     for a, b in zip(jax.tree.leaves((want[0], want[2])),
                     jax.tree.leaves((got[0], got[2]))):
         np.testing.assert_allclose(a, b, atol=3e-5)
+
+
+def _combine_case(case, rows_per_chunk=128):
+    """A chunk of a routing as ``_held_chunks`` hands it to the combine:
+    ``(token, valid, slot, first_row)`` of chunk ``c`` of the held
+    assignments of ``T`` tokens, sorted by expert as the layer sorts
+    them."""
+    expert = jax.random.randint(jax.random.PRNGKey(4), (T, K), 0, E)
+    live = (expert >= 16) & (expert < 32)
+    chunk = 0
+    if case in ("all_held", "spill"):
+        live = jnp.ones((T, K), bool)
+        chunk = 1 if case == "spill" else 0
+    elif case == "none_held":
+        live = jnp.zeros((T, K), bool)
+    elif case == "token_mask":
+        live = live & (jax.random.uniform(jax.random.PRNGKey(5), (T,))
+                       < 0.6)[:, None]
+    A = T * K
+    order = jnp.argsort(jnp.where(live, expert % 16, 16).reshape(A),
+                        stable=True)
+    slot = jnp.zeros((A,), jnp.int32).at[order].set(
+        jnp.arange(A, dtype=jnp.int32)).reshape(T, K)
+    first_row = chunk * rows_per_chunk
+    pos = first_row + jnp.arange(rows_per_chunk)
+    assignment = jnp.pad(order, (0, rows_per_chunk))[pos]
+    return assignment // K, pos < jnp.sum(live), slot, first_row
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "unweighted"])
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+@pytest.mark.parametrize("case", ["eighth_held", "all_held", "none_held",
+                                  "spill", "token_mask", "poisoned_dead"])
+def test_the_one_pass_combine_is_the_sum_of_top_k_gathers(case, impl,
+                                                          weighted):
+    """``_sum_own`` against the combine it replaced, written out: a
+    gather of (T, H) a slot out of the weighted float32 rows, added in
+    slot order.  The plain form adds the same products in the same
+    order: bit for bit.  The kernel adds them by expert inside the
+    MXU's float32 accumulator: to the rounding of a ``top_k``-term
+    sum.  Rows in both dtypes the layer computes in."""
+    token, valid, slot, first_row = _combine_case(case)
+    n_rows = token.shape[0]
+    if case in ("all_held", "spill"):
+        assert bool(jnp.all(valid))
+        # a token's rows lie on both sides of the chunk's boundaries
+        inside = (slot >= first_row) & (slot < first_row + n_rows)
+        assert bool(jnp.any(jnp.any(inside, 1) & ~jnp.all(inside, 1)))
+    elif case == "none_held":
+        assert not bool(jnp.any(valid))
+    else:
+        assert 0 < int(jnp.sum(valid)) < n_rows     # there ARE dead rows
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    out = jax.random.normal(keys[0], (T, H))
+    w = jnp.where(valid, jax.random.uniform(keys[1], (n_rows,)), 0.0) \
+        if weighted else None
+    local = ep._rows_of(slot, first_row, n_rows)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        clean = jnp.where(valid[:, None], jax.random.normal(
+            keys[2], (n_rows, H)).astype(dtype), 0)
+        rows = jnp.where(valid[:, None], clean, jnp.nan) \
+            if case == "poisoned_dead" else clean
+        terms = clean.astype(jnp.float32) * (1.0 if w is None
+                                             else w[:, None])
+        total, size = 0.0, jnp.abs(out)
+        for k in range(K):
+            own = jnp.take(terms, local[:, k], axis=0, mode="fill",
+                           fill_value=0)
+            total, size = total + own, size + jnp.abs(own)
+        got = ep._sum_own(out, rows, w, token, valid, slot, first_row, impl)
+        assert got.dtype == jnp.float32
+        if impl == "xla":
+            np.testing.assert_array_equal(got, out + total)
+        else:
+            assert bool(jnp.all(jnp.abs(got - (out + total))
+                                <= 4 * 2.0 ** -23 * size)), dtype
+
+
+def test_the_combine_sweep_rehearses_on_the_cpu(capsys):
+    """``benchmarks/moe_combine_sweep.py --interpret``: a line a
+    candidate and routing, each with a timing and within float32
+    rounding of the gathers that ran until PR 42."""
+    import importlib.util
+    import json
+    from pathlib import Path
+
+    spec = importlib.util.spec_from_file_location(
+        "moe_combine_sweep", Path(__file__).resolve().parents[1]
+        / "benchmarks" / "moe_combine_sweep.py")
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    sweep.main(["--interpret", "--reps", "1"])
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
+    assert [(l["routing"], l["candidate"]) for l in lines] == [
+        (r, c) for r in ("eighth", "all") for c in sweep.candidates(True)]
+    assert all("ms" in l and "error" not in l for l in lines), lines
+    assert all(l.get("max_err", 0) < 4 * 2.0 ** -23 for l in lines), lines
+    eighth, full = lines[0], lines[len(lines) // 2]
+    assert eighth["held_rows"] < eighth["rows"] == full["held_rows"]
 
 
 def test_the_balance_rule_against_the_reference():
