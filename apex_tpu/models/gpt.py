@@ -1181,8 +1181,8 @@ def _make_gspmd_train_step(
     # outright — XLA's SPMD pass was observed returning zeroed pack
     # segments for the stacked tp-sharded leaves on the CPU backend
     # (params came back as ``-lr*g``).  ``opt_state_spec`` describes
-    # per-leaf slots for that reason; bucket-resident state is not
-    # wired here.
+    # per-leaf slots for that reason: an optimizer's state has no
+    # other layout.
 
     specs = param_specs(config)
     sspec = opt_state_spec

@@ -15,8 +15,8 @@ These functions are the building blocks for :mod:`apex_tpu.optimizers`
 and :mod:`apex_tpu.amp`.
 
 Bucket views: every op here also accepts a
-:class:`apex_tpu.optimizers.bucketing.Buckets` (the multi-tensor
-engine's flat dtype-bucket form) anywhere a pytree is accepted —
+:class:`apex_tpu.optimizers.bucketing.Buckets` (a bucket
+plan's flat dtype-bucket form) anywhere a pytree is accepted —
 ``Buckets`` is a registered pytree whose leaves are the 1-D bucket
 buffers, so the elementwise ops (``scale``/``axpby``) map over the
 buffers directly and return ``Buckets`` of the same plan, and the

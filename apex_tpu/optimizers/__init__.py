@@ -4,14 +4,12 @@ Each optimizer is a pure pytree transform with exact reference numerics
 (fp32 math regardless of storage dtype), device-side predicated updates
 (the capturable/noop_flag design), and optional fp32 master weights.
 
-All five choose their route by the layout of the state: a tree of
-per-leaf slots (``init(params)``) updates a leaf at a time, in place
-under donation; bucket-resident slots (``init(params, bucketed=True)``)
-run the bucketed **multi-tensor engine** (the TPU form of
-``multi_tensor_apply``): a few dtype-homogeneous 1-D buckets and one
-fused elementwise pass per bucket.  On both, ``update_scaled`` folds the
-loss-scale unscale, the global-norm grad clip and the all-finite vote
-into the update's own read of the gradients.  See
+State is a tree of per-leaf slots (``init(params)``) and all five
+update it a leaf at a time, in place under donation; ``update_scaled``
+folds the loss-scale unscale, the global-norm grad clip and the
+all-finite vote into the update's own read of the gradients.  The
+bucket plans exported here are the layout of the ZeRO optimizers and
+the bucketed gradient syncs (``contrib.optimizers``).  See
 :mod:`apex_tpu.optimizers.base` and ``docs/optimizers.md``.
 """
 

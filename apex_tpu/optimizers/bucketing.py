@@ -7,25 +7,18 @@ launch sweeps many tensors.  The XLA analogue is a **bucket plan**:
 at optimizer init (or at first trace) the param pytree is flattened in
 stable ``tree_flatten`` order into a few dtype-homogeneous 1-D buckets
 — per-leaf offset table, tail padded to the dtype's (sublane × 128)
-tile (``ops/_pallas_tiling``) — and every optimizer sweep becomes one
-fused elementwise pass per bucket instead of one op chain per leaf.
-The layout is also the prerequisite for cross-replica sharded weight
+tile (``ops/_pallas_tiling``) — and a sweep becomes one fused
+elementwise pass per bucket instead of one op chain per leaf.
+The layout is the prerequisite for cross-replica sharded weight
 updates (PAPERS: arXiv 2004.13336): an equal-size 1-D bucket is what a
 ``psum_scatter`` shards cleanly.
 
-The fused optimizers use a plan only for state that lives in it (tree
-state updates a leaf at a time and never packs: PR 39 measured the
-per-step pack and unpack of whole-model copies at a quarter of a GPT-2
-medium step):
-
-- **resident** (``opt.init(params, bucketed=True)``): the optimizer
-  state slots are stored as :class:`Buckets` — the flat buffers ride
-  the jit boundary directly, so ``donate_argnums`` donates the bucket
-  buffers themselves (m/v never leave bucket form between steps).
-  Requires an unsharded (single-replica or pure-dp) step: a bucket of
-  concatenated *global* leaves does not slice into per-rank buckets of
-  the leaf *shards*, so ``make_train_step``-style shard_map states stay
-  per-leaf.
+The layout belongs to what SHARDS or SYNCS a whole tree at once: the
+ZeRO engine (``contrib.optimizers._zero_engine``), the quantized and
+hierarchical gradient syncs beside it, ``make_train_step``'s
+backward-overlapped sync and the elastic resharder; ``Buckets`` is also
+the view the ``multi_tensor_*`` ops accept.  The fused optimizers of
+this package keep per-leaf state and never build a plan.
 
 The ZeRO optimizers (``contrib.optimizers``) build their plans with two
 extra knobs: ``shard_pad`` pads every bucket so it splits evenly into
@@ -53,7 +46,7 @@ Tree = Any
 __all__ = [
     "BucketLeaf", "BucketSpec", "BucketPlan", "Buckets", "plan_of",
     "plan_of_shapes", "padded_total", "pack", "pack_bucket", "unpack",
-    "per_leaf_reduce", "seg_values", "seg_broadcast", "seg_ids",
+    "per_leaf_reduce", "seg_broadcast", "seg_ids",
     "buckets_by_stage",
 ]
 
@@ -204,10 +197,6 @@ class Buckets:
         shapes = [getattr(a, "shape", ()) for a in self.arrays]
         return f"Buckets({[b.dtype for b in self.plan.buckets]}, {shapes})"
 
-    def unpack(self, dtype=None) -> Tree:
-        """Back to the per-leaf tree (storage dtypes, or ``dtype``)."""
-        return unpack(self.plan, self.arrays, dtype=dtype)
-
 
 jax.tree_util.register_pytree_node(
     Buckets,
@@ -264,30 +253,15 @@ def unpack(plan: BucketPlan, arrays: Sequence, dtype=None) -> Tree:
 def per_leaf_reduce(plan: BucketPlan, arrays: Sequence,
                     fn: Callable) -> List[jnp.ndarray]:
     """``fn`` over each leaf's flat slice, returned in tree_flatten
-    order.  This is how per-tensor reductions (LAMB trust ratios,
-    NovoGrad norms, per-leaf l2) read a bucket: static slices, so the
-    reduction order per leaf matches the per-leaf code path."""
+    order.  This is how per-tensor reductions (the ``multi_tensor_*``
+    ops' per-leaf l2 norms) read a bucket: static slices, so the
+    reduction order per leaf matches a reduction over the leaf."""
     out: List[Optional[jnp.ndarray]] = [None] * plan.n_leaves
     for b, arr in zip(plan.buckets, arrays):
         for bl in b.leaves:
             out[bl.leaf_id] = fn(
                 jax.lax.slice(arr, (bl.offset,), (bl.offset + bl.size,)))
     return out
-
-
-def seg_values(bucket: BucketSpec, per_leaf: Sequence[float]):
-    """Per-element hyperparameter operand for one bucket: a python
-    scalar when every leaf agrees (the common case — no per-element
-    memory traffic), else an np.float32 constant vector (pad region 0).
-    ``per_leaf`` is indexed by ``leaf_id``."""
-    vals = [float(per_leaf[bl.leaf_id]) for bl in bucket.leaves]
-    if all(v == vals[0] for v in vals):
-        return vals[0]
-    parts = [np.full(bl.size, v, np.float32)
-             for bl, v in zip(bucket.leaves, vals)]
-    if bucket.pad:
-        parts.append(np.zeros(bucket.pad, np.float32))
-    return jnp.asarray(np.concatenate(parts))
 
 
 def seg_ids(plan: BucketPlan, bucket: BucketSpec) -> np.ndarray:

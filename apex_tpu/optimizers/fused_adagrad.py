@@ -9,8 +9,7 @@ Elementwise (fp32):
 - adagrad_w mode (ADAGRAD_MODE_1): ``h += g²``;
   ``p -= lr·(g/(√h+eps) + wd·p)``.
 
-Tree state updates a leaf at a time, bucket-resident state on the
-bucketed multi-tensor engine (see :mod:`apex_tpu.optimizers.base`).
+The update runs a leaf at a time (see :mod:`apex_tpu.optimizers.base`).
 """
 
 from typing import Any, NamedTuple, Optional
@@ -18,18 +17,16 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.optimizers import base, bucketing
+from apex_tpu.optimizers import base
 
 
 class AdagradState(NamedTuple):
     step: jnp.ndarray
-    sum: Any  # h accumulator, fp32 (tree or Buckets)
+    sum: Any  # h accumulator, fp32
     master: Optional[Any] = None
 
 
 class FusedAdagrad(base.OptimizerBase):
-
-    _BUCKET_SLOT = "sum"
 
     def __init__(
         self,
@@ -40,19 +37,14 @@ class FusedAdagrad(base.OptimizerBase):
         master_weights: bool = False,
         param_group_fn=None,
         group_hypers=None,
-        use_buckets: bool = True,
     ):
-        super().__init__(lr, weight_decay, master_weights,
-                         use_buckets=use_buckets)
+        super().__init__(lr, weight_decay, master_weights)
         self.eps = eps
         self.adagrad_w_mode = adagrad_w_mode
         self.param_group_fn = param_group_fn
         self.group_hypers = group_hypers
 
-    def init(self, params, bucketed: bool = False) -> AdagradState:
-        if bucketed:
-            (h,), master = self._init_bucket_slots(params, 1)
-            return AdagradState(jnp.int32(0), h, master)
+    def init(self, params) -> AdagradState:
         return AdagradState(
             step=jnp.int32(0),
             sum=jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
@@ -60,7 +52,7 @@ class FusedAdagrad(base.OptimizerBase):
         )
 
     def _adagrad_math(self, g, p32, h, wd_i, lr_i):
-        """The shared Adagrad expression tree (per-leaf == bucket)."""
+        """One Adagrad step per element (AdagradFunctor)."""
         eps = self.eps
         if not self.adagrad_w_mode:
             g = g + wd_i * p32
@@ -71,7 +63,6 @@ class FusedAdagrad(base.OptimizerBase):
             p_out = p32 - lr_i * (g / (jnp.sqrt(h_new) + eps) + wd_i * p32)
         return p_out, h_new
 
-    # ------------------------------------------------------- per-leaf path
     def _leaf_update(self, grads, state: AdagradState, params,
                      grads_finite=None, lr=None):
         lr = self.lr if lr is None else lr
@@ -96,38 +87,3 @@ class FusedAdagrad(base.OptimizerBase):
         h_new = base.select(grads_finite, h_new, state.sum)
         new_params, new_master = base.emit_params(p_new, params, state.master)
         return new_params, AdagradState(step, h_new, new_master)
-
-    # --------------------------------------------------------- bucket path
-    def _bucket_update(self, prep: base.PreparedGrads, state: AdagradState,
-                       params, pred, lr=None):
-        lr = self.lr if lr is None else lr
-        wd = self.weight_decay
-        plan = prep.plan
-
-        step = base.predicate_step(pred, state.step)
-        h_b = state.sum.arrays
-        has_master = state.master is not None
-        if has_master:
-            p_b = state.master.arrays
-        else:
-            p_b = bucketing.pack(plan, params)
-        hl = self._hyper_leaves(
-            base.leaf_hypers(params, self.param_group_fn, self.group_hypers))
-        wd_leaf = [h.get("weight_decay", wd) for h in hl]
-
-        new_p, new_h = [], []
-        for bi, b in enumerate(plan.buckets):
-            p_out, h_out = self._adagrad_math(
-                prep.g[bi], p_b[bi], h_b[bi],
-                bucketing.seg_values(b, wd_leaf),
-                self._bucket_lr(b, hl, lr))
-            new_p.append(p_out)
-            new_h.append(h_out)
-
-        new_p = base.bucket_select(pred, new_p, p_b)
-        new_h = base.bucket_select(pred, new_h, h_b)
-        new_params = bucketing.unpack(plan, new_p)
-        new_master = (bucketing.Buckets(plan, new_p)
-                      if has_master else None)
-        return new_params, AdagradState(
-            step, bucketing.Buckets(plan, new_h), new_master)

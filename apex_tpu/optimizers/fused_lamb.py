@@ -15,11 +15,8 @@ Two-phase semantics reproduced exactly:
    ``use_nvlamb or wd != 0`` and both norms are nonzero;
    ``p -= lr·r·u``.
 
-Tree state updates a leaf at a time, bucket-resident state on the
-bucketed multi-tensor engine (see :mod:`apex_tpu.optimizers.base`):
-there stage 1 is one fused pass per dtype bucket; the per-tensor norms
-of stage 2 read the buckets through the plan's static offset table, and
-the trust ratios broadcast back as one per-element gather.
+The update runs a leaf at a time (see :mod:`apex_tpu.optimizers.base`):
+stage 1, the two norms of stage 2 and the step fuse per leaf.
 """
 
 from typing import Any, NamedTuple, Optional, Tuple
@@ -27,7 +24,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.optimizers import base, bucketing
+from apex_tpu.optimizers import base
 
 
 class LambState(NamedTuple):
@@ -74,8 +71,6 @@ class FusedLAMB(base.OptimizerBase):
     #: group-override keys beyond the base lr/lr_scale/weight_decay set
     _HYPER_KEYS = ("use_trust_ratio",)
 
-    _BUCKET_SLOT = "exp_avg"
-
     def __init__(
         self,
         lr: float = 1e-3,
@@ -91,7 +86,6 @@ class FusedLAMB(base.OptimizerBase):
         master_weights: bool = False,
         param_group_fn=None,
         group_hypers=None,
-        use_buckets: bool = True,
     ):
         """``param_group_fn``/``group_hypers``: functional param_groups
         (see :class:`~apex_tpu.optimizers.FusedAdam`).  LAMB additionally
@@ -100,8 +94,7 @@ class FusedLAMB(base.OptimizerBase):
         norms/biases)."""
         if amsgrad:
             raise RuntimeError("FusedLAMB does not support the AMSGrad variant.")
-        super().__init__(lr, weight_decay, master_weights,
-                         use_buckets=use_buckets)
+        super().__init__(lr, weight_decay, master_weights)
         self.bias_correction = bias_correction
         self.beta1, self.beta2 = betas
         self.eps = eps
@@ -112,10 +105,7 @@ class FusedLAMB(base.OptimizerBase):
         self.param_group_fn = param_group_fn
         self.group_hypers = group_hypers
 
-    def init(self, params, bucketed: bool = False) -> LambState:
-        if bucketed:
-            (m, v), master = self._init_bucket_slots(params, 2)
-            return LambState(jnp.int32(0), m, v, master)
+    def init(self, params) -> LambState:
         zeros = lambda t: jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), t)
         return LambState(
             step=jnp.int32(0),
@@ -124,25 +114,12 @@ class FusedLAMB(base.OptimizerBase):
             master=base.make_master(params, self.master_weights),
         )
 
-    def _grad_clip(self, global_grad_norm):
-        """fused_lamb.py:121-136: divide every grad by
-        ``gn/max_grad_norm`` when the global norm exceeds the max."""
-        return lamb_grad_clip(global_grad_norm, self.max_grad_norm)
-
-    def _stage1_math(self, g, p32, m, v, wd_i, bc1, bc2):
-        """Shared stage-1 expression tree (per-leaf == bucket)."""
-        return lamb_stage1_math(
-            g, p32, m, v, wd_i, bc1, bc2, beta1=self.beta1,
-            beta2=self.beta2, eps=self.eps, adam_w_mode=self.adam_w_mode,
-            grad_averaging=self.grad_averaging)
-
     def _trust_ratio(self, h, wd_i, lr_i, p_norm, u_norm):
         """Stage-2 per-tensor ratio (multi_tensor_lamb.cu:255-262)."""
         apply = h.get("use_trust_ratio", True) and (
             self.use_nvlamb or wd_i != 0.0)
         return lamb_trust_ratio(lr_i, p_norm, u_norm, apply_ratio=apply)
 
-    # ------------------------------------------------------- per-leaf path
     def _leaf_update(self, grads, state: LambState, params,
                      grads_finite=None, lr=None):
         lr = self.lr if lr is None else lr
@@ -154,7 +131,8 @@ class FusedLAMB(base.OptimizerBase):
         # Global grad norm over every param (fused_lamb.py:121-136).
         g32 = base.f32(grads)
         sq = [jnp.sum(jnp.square(g)) for g in jax.tree.leaves(g32)]
-        clip = self._grad_clip(jnp.sqrt(jnp.stack(sq).sum()))
+        clip = lamb_grad_clip(jnp.sqrt(jnp.stack(sq).sum()),
+                              self.max_grad_norm)
 
         p_math = base.math_params(params, state.master)
         hypers = base.leaf_hypers(params, self.param_group_fn, self.group_hypers,
@@ -162,9 +140,11 @@ class FusedLAMB(base.OptimizerBase):
         treedef = jax.tree.structure(grads)
 
         def stage1(g, p, m, v, h):
-            return self._stage1_math(
+            return lamb_stage1_math(
                 g.astype(jnp.float32) / clip, p.astype(jnp.float32), m, v,
-                h.get("weight_decay", wd), bc1, bc2)
+                h.get("weight_decay", wd), bc1, bc2, beta1=self.beta1,
+                beta2=self.beta2, eps=self.eps, adam_w_mode=self.adam_w_mode,
+                grad_averaging=self.grad_averaging)
 
         out = jax.tree.map(stage1, grads, p_math, state.exp_avg, state.exp_avg_sq, hypers)
         flat = jax.tree.leaves(out, is_leaf=lambda x: isinstance(x, tuple))
@@ -191,71 +171,3 @@ class FusedLAMB(base.OptimizerBase):
 
         new_params, new_master = base.emit_params(p_new, params, state.master)
         return new_params, LambState(step, m_new, v_new, new_master)
-
-    # --------------------------------------------------------- bucket path
-    def _bucket_update(self, prep: base.PreparedGrads, state: LambState,
-                       params, pred, lr=None):
-        lr = self.lr if lr is None else lr
-        wd = self.weight_decay
-        plan = prep.plan
-
-        step = base.predicate_step(pred, state.step)
-        bc1, bc2 = self._bias_corrections(step)
-
-        # global grad norm through the offset table: per-leaf Σg² in
-        # flat order, combined exactly like the per-leaf path
-        sq = bucketing.per_leaf_reduce(
-            plan, prep.g, lambda x: jnp.sum(jnp.square(x)))
-        clip = self._grad_clip(jnp.sqrt(jnp.stack(sq).sum()))
-
-        m_b = state.exp_avg.arrays
-        v_b = state.exp_avg_sq.arrays
-        has_master = state.master is not None
-        if has_master:
-            p_b = state.master.arrays
-        else:
-            p_b = bucketing.pack(plan, params)
-        hl = self._hyper_leaves(base.leaf_hypers(
-            params, self.param_group_fn, self.group_hypers,
-            extra_keys=self._HYPER_KEYS))
-        wd_leaf = [h.get("weight_decay", wd) for h in hl]
-
-        # stage 1: one fused pass per bucket
-        u_b, new_m, new_v = [], [], []
-        for bi, b in enumerate(plan.buckets):
-            u, m_out, v_out = self._stage1_math(
-                prep.g[bi] / clip, p_b[bi], m_b[bi], v_b[bi],
-                bucketing.seg_values(b, wd_leaf), bc1, bc2)
-            u_b.append(u)
-            new_m.append(m_out)
-            new_v.append(v_out)
-
-        # stage 2: per-tensor trust ratios from the offset table
-        p_sq = bucketing.per_leaf_reduce(
-            plan, p_b, lambda x: jnp.sum(jnp.square(x)))
-        u_sq = bucketing.per_leaf_reduce(
-            plan, u_b, lambda x: jnp.sum(jnp.square(x)))
-        ratios = [
-            self._trust_ratio(
-                h, h.get("weight_decay", wd), base.leaf_lr(h, lr),
-                jnp.sqrt(p_sq[i]), jnp.sqrt(u_sq[i]))
-            for i, h in enumerate(hl)
-        ]
-        new_p = [
-            p_b[bi] - bucketing.seg_broadcast(b, ratios) * u_b[bi]
-            for bi, b in enumerate(plan.buckets)
-        ]
-
-        new_p = base.bucket_select(pred, new_p, p_b)
-        new_m = base.bucket_select(pred, new_m, m_b)
-        new_v = base.bucket_select(pred, new_v, v_b)
-
-        new_params = bucketing.unpack(plan, new_p)
-        new_master = (bucketing.Buckets(plan, new_p)
-                      if has_master else None)
-        return new_params, LambState(
-            step,
-            bucketing.Buckets(plan, new_m),
-            bucketing.Buckets(plan, new_v),
-            new_master,
-        )

@@ -19,11 +19,8 @@ Elementwise (fp32), with ``denom = gn/√(1-β2^t) + eps``:
 Note ``bias_correction2 = sqrt(1-β2^t)`` here (unlike Adam) —
 ``multi_tensor_novograd.cu:150-152``.
 
-Tree state updates a leaf at a time, bucket-resident state on the
-bucketed multi-tensor engine (see :mod:`apex_tpu.optimizers.base`):
-there the per-tensor norms read the grad bucket through the plan's
-offset table; ``exp_avg_sq`` stays a tree of per-leaf scalars in both
-layouts (it is one float per tensor).
+The update runs a leaf at a time (see :mod:`apex_tpu.optimizers.base`);
+``exp_avg_sq`` is a tree of per-leaf scalars (one float per tensor).
 """
 
 from typing import Any, NamedTuple, Optional, Tuple
@@ -31,7 +28,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.optimizers import base, bucketing
+from apex_tpu.optimizers import base
 
 
 class NovoGradState(NamedTuple):
@@ -42,8 +39,6 @@ class NovoGradState(NamedTuple):
 
 
 class FusedNovoGrad(base.OptimizerBase):
-
-    _BUCKET_SLOT = "exp_avg"
 
     def __init__(
         self,
@@ -58,14 +53,12 @@ class FusedNovoGrad(base.OptimizerBase):
         norm_type: int = 2,
         init_zero: bool = False,
         master_weights: bool = False,
-        use_buckets: bool = True,
     ):
         if amsgrad:
             raise RuntimeError("FusedNovoGrad does not support the AMSGrad variant.")
         if norm_type not in (0, 2):
             raise RuntimeError("FusedNovoGrad only supports l2/inf norm.")
-        super().__init__(lr, weight_decay, master_weights,
-                         use_buckets=use_buckets)
+        super().__init__(lr, weight_decay, master_weights)
         self.bias_correction = bias_correction
         self.beta1, self.beta2 = betas
         self.eps = eps
@@ -75,15 +68,12 @@ class FusedNovoGrad(base.OptimizerBase):
         self.norm_type = norm_type
         self.init_zero = init_zero
 
-    def init(self, params, bucketed: bool = False) -> NovoGradState:
+    def init(self, params) -> NovoGradState:
         # -1 sentinel: "not yet initialized"; replaced by the first
         # grad norm unless init_zero (fused_novograd.py:160-180).
         gn0 = jax.tree.map(
             lambda p: jnp.float32(0.0 if self.init_zero else -1.0), params
         )
-        if bucketed:
-            (m,), master = self._init_bucket_slots(params, 1)
-            return NovoGradState(jnp.int32(0), m, gn0, master)
         return NovoGradState(
             step=jnp.int32(0),
             exp_avg=jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
@@ -113,8 +103,8 @@ class FusedNovoGrad(base.OptimizerBase):
         return jnp.float32(1.0), jnp.float32(1.0)
 
     def _moment_math(self, g, p32, m, denom, lr, bc1):
-        """Shared elementwise tail (per-leaf == bucket); ``denom`` is a
-        per-element operand (broadcast per-tensor norm)."""
+        """The elementwise tail; ``denom`` is the leaf's blended norm
+        over its bias correction, plus eps (a scalar)."""
         b1, wd = self.beta1, self.weight_decay
         b3 = (1.0 - b1) if self.grad_averaging else 1.0
         if self.moment_mode == 0:
@@ -127,7 +117,6 @@ class FusedNovoGrad(base.OptimizerBase):
             p_out = p32 - lr * update
         return p_out, m_new
 
-    # ------------------------------------------------------- per-leaf path
     def _leaf_update(self, grads, state: NovoGradState, params,
                      grads_finite=None, lr=None):
         lr = self.lr if lr is None else lr
@@ -157,59 +146,3 @@ class FusedNovoGrad(base.OptimizerBase):
 
         new_params, new_master = base.emit_params(p_new, params, state.master)
         return new_params, NovoGradState(step, m_new, gn_new, new_master)
-
-    # --------------------------------------------------------- bucket path
-    def _bucket_update(self, prep: base.PreparedGrads, state: NovoGradState,
-                       params, pred, lr=None):
-        lr = self.lr if lr is None else lr
-        plan = prep.plan
-
-        step = base.predicate_step(pred, state.step)
-        bc1, bc2 = self._bias_corrections(step)
-
-        m_b = state.exp_avg.arrays
-        has_master = state.master is not None
-        if has_master:
-            p_b = state.master.arrays
-        else:
-            p_b = bucketing.pack(plan, params)
-
-        # per-tensor fresh norms + blend: one read of the grad bucket
-        # through the offset table, exactly the per-leaf reduction order
-        fresh = bucketing.per_leaf_reduce(plan, prep.g, self._norm)
-        gn_leaves = jax.tree.leaves(state.exp_avg_sq)
-        gn_new_leaves = [self._blend(gn, f)
-                         for gn, f in zip(gn_leaves, fresh)]
-        denoms = [gn / bc2 + self.eps for gn in gn_new_leaves]
-
-        new_p, new_m = [], []
-        for bi, b in enumerate(plan.buckets):
-            denom = bucketing.seg_broadcast(b, denoms)
-            # pad elements would divide by the pad's 0-denominator;
-            # keep them finite so a bucket-level isfinite stays usable.
-            # Mask by PAD POSITION, not by value: a real leaf can have
-            # denom 0 too (eps=0 + zero grads) and must keep the
-            # per-leaf path's NaN there — the two paths may not
-            # silently disagree.
-            if b.pad:
-                is_pad = jnp.arange(b.total) >= b.size
-                denom = jnp.where(is_pad, jnp.float32(1.0), denom)
-            p_out, m_out = self._moment_math(
-                prep.g[bi], p_b[bi], m_b[bi], denom, lr, bc1)
-            new_p.append(p_out)
-            new_m.append(m_out)
-
-        new_p = base.bucket_select(pred, new_p, p_b)
-        new_m = base.bucket_select(pred, new_m, m_b)
-        if pred is not None:
-            w = jnp.asarray(pred)
-            gn_new_leaves = [jnp.where(w, n, o)
-                             for n, o in zip(gn_new_leaves, gn_leaves)]
-        gn_new = jax.tree.unflatten(
-            jax.tree.structure(state.exp_avg_sq), gn_new_leaves)
-
-        new_params = bucketing.unpack(plan, new_p)
-        new_master = (bucketing.Buckets(plan, new_p)
-                      if has_master else None)
-        return new_params, NovoGradState(
-            step, bucketing.Buckets(plan, new_m), gn_new, new_master)

@@ -13,12 +13,10 @@ Per-element semantics (fp32 math):
 - ``p -= lr·g``.
 
 The first-run distinction is handled branch-free with the step counter
-(step==0 ⇒ buf := g), keeping the whole step jit-compatible.  Tree
-state updates a leaf at a time, bucket-resident state on the bucketed
-multi-tensor engine (see :mod:`apex_tpu.optimizers.base`); there
-per-group ``momentum`` overrides become a per-element select on the
-bucket, reproducing the per-leaf "momentum-free group" semantics
-exactly.
+(step==0 ⇒ buf := g), keeping the whole step jit-compatible.  The
+update runs a leaf at a time (see :mod:`apex_tpu.optimizers.base`); a
+group whose ``momentum`` override is 0 keeps its buffer untouched and
+steps on the raw grad.
 """
 
 from typing import Any, NamedTuple, Optional
@@ -26,7 +24,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.optimizers import base, bucketing
+from apex_tpu.optimizers import base
 
 
 class SGDState(NamedTuple):
@@ -40,8 +38,6 @@ class FusedSGD(base.OptimizerBase):
     #: group-override keys beyond the base lr/lr_scale/weight_decay set
     _HYPER_KEYS = ("momentum",)
 
-    _BUCKET_SLOT = "momentum_buffer"
-
     def __init__(
         self,
         lr: float,
@@ -53,15 +49,13 @@ class FusedSGD(base.OptimizerBase):
         master_weights: bool = False,
         param_group_fn=None,
         group_hypers=None,
-        use_buckets: bool = True,
     ):
         """``param_group_fn``/``group_hypers``: functional param_groups
         (see :class:`~apex_tpu.optimizers.FusedAdam`); per-group keys
         here: ``lr``/``lr_scale``, ``weight_decay``, ``momentum``."""
         if nesterov and (momentum <= 0 or dampening != 0):
             raise ValueError("Nesterov momentum requires a momentum and zero dampening")
-        super().__init__(lr, weight_decay, master_weights,
-                         use_buckets=use_buckets)
+        super().__init__(lr, weight_decay, master_weights)
         self.momentum = momentum
         self.dampening = dampening
         self.nesterov = nesterov
@@ -69,10 +63,7 @@ class FusedSGD(base.OptimizerBase):
         self.param_group_fn = param_group_fn
         self.group_hypers = group_hypers
 
-    def init(self, params, bucketed: bool = False) -> SGDState:
-        if bucketed:
-            (buf,), master = self._init_bucket_slots(params, 1)
-            return SGDState(jnp.int32(0), buf, master)
+    def init(self, params) -> SGDState:
         return SGDState(
             step=jnp.int32(0),
             momentum_buffer=jax.tree.map(
@@ -92,7 +83,6 @@ class FusedSGD(base.OptimizerBase):
                                  prescale=1.0 / scale, **kw)
         return p, s
 
-    # ------------------------------------------------------- per-leaf path
     def _leaf_update(self, grads, state: SGDState, params,
                      grads_finite=None, lr=None):
         # grads arrive f32 with the prescale already applied (_dispatch)
@@ -136,62 +126,3 @@ class FusedSGD(base.OptimizerBase):
         buf_new = base.select(grads_finite, buf_new, state.momentum_buffer)
         new_params, new_master = base.emit_params(p_new, params, state.master)
         return new_params, SGDState(step, buf_new, new_master)
-
-    # --------------------------------------------------------- bucket path
-    def _bucket_update(self, prep: base.PreparedGrads, state: SGDState,
-                       params, pred, lr=None):
-        lr = self.lr if lr is None else lr
-        wd, mu, damp = self.weight_decay, self.momentum, self.dampening
-        plan = prep.plan
-        first_run = state.step == 0
-
-        step = base.predicate_step(pred, state.step)
-        buf_b = state.momentum_buffer.arrays
-        has_master = state.master is not None
-        if has_master:
-            p_b = state.master.arrays
-        else:
-            p_b = bucketing.pack(plan, params)
-        hl = self._hyper_leaves(base.leaf_hypers(
-            params, self.param_group_fn, self.group_hypers,
-            extra_keys=self._HYPER_KEYS))
-        wd_leaf = [h.get("weight_decay", wd) for h in hl]
-        mu_leaf = [h.get("momentum", mu) for h in hl]
-
-        new_p, new_buf = [], []
-        for bi, b in enumerate(plan.buckets):
-            g, p32, buf = prep.g[bi], p_b[bi], buf_b[bi]
-            wd_i = bucketing.seg_values(b, wd_leaf)
-            mu_i = bucketing.seg_values(b, mu_leaf)
-            lr_i = self._bucket_lr(b, hl, lr)
-            mu_scalar = isinstance(mu_i, float)
-            wd_scalar = isinstance(wd_i, float)
-            if not self.wd_after_momentum and not (wd_scalar and wd_i == 0.0):
-                g = g + wd_i * p32
-            if mu_scalar and mu_i == 0.0:
-                buf_new = buf
-            else:
-                steady = mu_i * buf + (1.0 - damp) * g
-                mom_buf = jnp.where(first_run, g, steady)
-                g_mom = g + mu_i * mom_buf if self.nesterov else mom_buf
-                if mu_scalar:
-                    buf_new, g = mom_buf, g_mom
-                else:
-                    # per-group momentum: μ=0 leaves keep their buffer
-                    # untouched and step on the raw grad, exactly like
-                    # the per-leaf momentum-free branch
-                    live = mu_i != 0.0
-                    buf_new = jnp.where(live, mom_buf, buf)
-                    g = jnp.where(live, g_mom, g)
-            if self.wd_after_momentum and not (wd_scalar and wd_i == 0.0):
-                g = g + wd_i * p32
-            new_p.append(p32 - lr_i * g)
-            new_buf.append(buf_new)
-
-        new_p = base.bucket_select(pred, new_p, p_b)
-        new_buf = base.bucket_select(pred, new_buf, buf_b)
-        new_params = bucketing.unpack(plan, new_p)
-        new_master = (bucketing.Buckets(plan, new_p)
-                      if has_master else None)
-        return new_params, SGDState(
-            step, bucketing.Buckets(plan, new_buf), new_master)

@@ -41,11 +41,11 @@ compiled step; catch it there, feed it to :func:`trip_from_exception`,
 and rebuild the step — the new trace consults the registry and lowers
 the fallback.  ``examples/gpt/pretrain_gpt.py`` wires this.
 
-Collective-bearing engines NEVER register here.  (The optimizers'
-``"multi_tensor_engine"`` site went with PR 39: tree state IS the
-per-leaf path now, so there is no engine left to degrade from on that
-route, and bucket-resident state never could fall back.)  The ZeRO
-bucket engine
+Collective-bearing engines NEVER register here.  (Nor do the fused
+optimizers of :mod:`apex_tpu.optimizers`, for another reason: their
+one route, the per-leaf update, is plain XLA ops, so there is nothing
+to degrade from.)  The case the rule is for is the ZeRO bucket
+engine: it
 (:mod:`apex_tpu.contrib.optimizers._zero_engine`) has per-bucket
 reduce-scatters and all-gathers INSIDE the optimizer, so a per-process
 degrade-once would lower divergent SPMD programs across the pod —

@@ -6,7 +6,7 @@ fp32 trees with exactly-representable grads the resident-sharded bucket
 engine must match them **bit for bit** (elementwise expression trees are
 shared; the dp reduce adds no rounding when every addend is exactly
 representable).  LAMB (reduction-fed trust ratios) gets a tight
-allclose, same convention as ``tests/test_bucketed_engine.py``.
+allclose, same convention as ``tests/test_fused_optimizers.py``.
 """
 
 import functools
@@ -185,8 +185,7 @@ class TestDistributedFusedAdam:
         to an output in the compiled module's ``input_output_alias``
         table — the ZeRO state updates in place.  (Under shard_map jax
         marks the inputs ``jax.buffer_donor`` and the ALIASING shows up
-        at compile time, unlike the plain-jit ``tf.aliasing_output``
-        path the bucketed-engine test pins.)"""
+        at compile time, unlike a plain jit's ``tf.aliasing_output``.)"""
         params = make_tree()
         mesh = Mesh(np.array(devices8), ("dp",))
         dist = DistributedFusedAdam(lr=1e-2, axis_name="dp")
@@ -626,7 +625,7 @@ class TestDistributedFusedLAMB:
     @pytest.mark.slow
     def test_matches_fused_lamb(self, devices8):
         """Trust ratios are reduction-fed, so LAMB gets the tight
-        allclose band (the bucket-engine convention), not bitwise."""
+        allclose band (``tests/test_fused_optimizers.py``'s), not bitwise."""
         params = make_tree()
         mesh = Mesh(np.array(devices8), ("dp",))
         dist = DistributedFusedLAMB(lr=1e-2, weight_decay=0.01,

@@ -1,14 +1,18 @@
 """Optimizer parity tests — mirrors tests/L0/run_optimizers of the
 reference, which checks fused optimizers against torch.optim references
 (``test_adam.py:52-63``, ``test_fused_optimizer.py``, ``test_lamb.py``).
-Here torch (CPU) is the oracle for Adam/AdamW/SGD/Adagrad, and a NumPy
-reference implements LAMB (as the reference's test_lamb.py does)."""
+Here torch (CPU) is the oracle for Adam/AdamW/SGD/Adagrad, and the
+float64 NumPy references of ``tests/optimizer_oracles.py`` are the
+oracle for LAMB (as the reference's test_lamb.py does), for NovoGrad
+and for ``clip_norm`` under parameter groups."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 import torch
+from optimizer_oracles import assert_matches_oracle, stepped
+from optimizer_oracles import run as oracle_run
 
 from apex_tpu.optimizers import (
     FusedAdagrad,
@@ -138,57 +142,53 @@ class TestFusedAdagrad:
         assert_tree_close(params, tparams, rtol=1e-4, atol=1e-5)
 
 
-def numpy_lamb_reference(params, grads_seq, lr, betas, eps, wd, max_grad_norm=1.0, use_nvlamb=False, grad_averaging=True):
-    """Independent NumPy LAMB implementing multi_tensor_lamb.cu semantics."""
-    b1, b2 = betas
-    b3 = 1 - b1 if grad_averaging else 1.0
-    leaves, treedef = jax.tree.flatten(params)
-    ms = [np.zeros_like(x) for x in leaves]
-    vs = [np.zeros_like(x) for x in leaves]
-    ps = [np.array(x) for x in leaves]
-    step = 0
-    for gtree in grads_seq:
-        gs = [np.array(x) for x in jax.tree.leaves(gtree)]
-        step += 1
-        bc1 = 1 - b1 ** step
-        bc2 = 1 - b2 ** step
-        gn = np.sqrt(sum((g.astype(np.float64) ** 2).sum() for g in gs))
-        clip = gn / max_grad_norm if gn > max_grad_norm else 1.0
-        for i in range(len(ps)):
-            g = gs[i] / clip
-            m = ms[i] = b1 * ms[i] + b3 * g
-            v = vs[i] = b2 * vs[i] + (1 - b2) * g * g
-            u = (m / bc1) / (np.sqrt(v / bc2) + eps) + wd * ps[i]
-            if use_nvlamb or wd != 0:
-                pn = np.sqrt((ps[i] ** 2).sum())
-                un = np.sqrt((u ** 2).sum())
-                ratio = lr * (pn / un) if (pn != 0 and un != 0) else lr
-            else:
-                ratio = lr
-            ps[i] = ps[i] - ratio * u
-    return jax.tree.unflatten(treedef, ps)
+def random_grads(params, nsteps=NSTEPS, seed=3, scale=1.0):
+    rng = np.random.RandomState(seed)
+    return [jax.tree.map(
+        lambda x: jnp.asarray(rng.randn(*x.shape).astype(np.float32) * scale),
+        params) for _ in range(nsteps)]
 
 
 class TestFusedLAMB:
     @pytest.mark.parametrize("wd,use_nvlamb", [(0.01, False), (0.0, False), (0.0, True)])
     def test_lamb_vs_numpy(self, wd, use_nvlamb):
-        lr, betas, eps = 1e-2, (0.9, 0.999), 1e-6
+        hp = dict(lr=1e-2, betas=(0.9, 0.999), eps=1e-6, weight_decay=wd,
+                  use_nvlamb=use_nvlamb)
         params = jax.tree.map(jnp.asarray, make_tree())
-        opt = FusedLAMB(lr=lr, betas=betas, eps=eps, weight_decay=wd, use_nvlamb=use_nvlamb)
-        state = opt.init(params)
-        rng = np.random.RandomState(3)
-        grads_seq = []
-        p = params
-        for _ in range(NSTEPS):
-            g = jax.tree.map(lambda x: rng.randn(*x.shape).astype(np.float32) * 5, params)
-            grads_seq.append(g)
-            p, state = opt.update(jax.tree.map(jnp.asarray, g), state, p)
-        ref = numpy_lamb_reference(params, grads_seq, lr, betas, eps, wd, use_nvlamb=use_nvlamb)
-        for a, b in zip(jax.tree.leaves(p), jax.tree.leaves(ref)):
-            np.testing.assert_allclose(np.asarray(a), b, rtol=2e-4, atol=2e-5)
+        grads_seq = random_grads(params, scale=5.0)
+        p, _ = stepped(FusedLAMB(**hp), params, grads_seq)
+        ref = oracle_run("lamb", params, grads_seq, **hp)
+        assert_matches_oracle(p, ref["params"], rtol=2e-4, atol=2e-5)
 
 
 class TestFusedNovoGrad:
+    @staticmethod
+    def _against_oracle(**hp):
+        params = jax.tree.map(jnp.asarray, make_tree())
+        grads_seq = random_grads(params)
+        p, state = stepped(FusedNovoGrad(**hp), params, grads_seq)
+        ref = oracle_run("novograd", params, grads_seq, **hp)
+        assert_matches_oracle(p, ref["params"])
+        assert_matches_oracle(state.exp_avg, ref["exp_avg"])
+        # the second moment is one blended norm a tensor
+        assert_matches_oracle(state.exp_avg_sq, ref["exp_avg_sq"])
+
+    @pytest.mark.parametrize("init_zero", [False, True])
+    @pytest.mark.parametrize("grad_averaging", [True, False])
+    @pytest.mark.parametrize("wd", [0.0, 0.01])
+    def test_novograd_vs_numpy(self, wd, grad_averaging, init_zero):
+        self._against_oracle(lr=1e-2, weight_decay=wd,
+                             grad_averaging=grad_averaging,
+                             init_zero=init_zero)
+
+    @pytest.mark.parametrize("mode", [dict(norm_type=0),
+                                      dict(reg_inside_moment=True)],
+                             ids=["inf-norm", "reg-inside-moment"])
+    def test_novograd_modes_vs_numpy(self, mode):
+        """The L-inf blend (``β2·gn + (1-β2)·max|g|``) and
+        MOMENT_MODE_0 (decay and normalisation inside the moment)."""
+        self._against_oracle(lr=1e-2, weight_decay=0.01, **mode)
+
     def test_novograd_runs_and_descends(self):
         # quadratic bowl: params should move toward zero
         opt = FusedNovoGrad(lr=0.05, weight_decay=0.0)
@@ -216,6 +216,33 @@ class TestParamGroups:
 
     def _groups(self, path, leaf):
         return "no_decay" if ("bias" in path or "norm" in path) else "default"
+
+    @pytest.mark.parametrize("name,cls,hp", [
+        ("adam", FusedAdam, dict(weight_decay=0.1)),
+        ("lamb", FusedLAMB, dict(weight_decay=0.1, max_grad_norm=1e9)),
+        ("sgd", FusedSGD, dict(lr=0.1, momentum=0.9, weight_decay=0.1)),
+        ("adagrad", FusedAdagrad, dict(weight_decay=0.1)),
+    ], ids=["adam", "lamb", "sgd", "adagrad"])
+    def test_clip_norm_with_groups_vs_oracle(self, name, cls, hp):
+        """``clip_norm`` is ONE global norm over every group's leaves
+        (torch's ``clip_grad_norm_`` rule), and each group then steps at
+        its own rate: ``head`` at its absolute ``lr`` whatever the
+        schedule says, ``bias`` at half the runtime lr without decay,
+        the rest at the runtime lr."""
+        params = {"w": jnp.asarray(make_tree()["a"]),
+                  "head": jnp.asarray(make_tree(1)["a"]),
+                  "bias": jnp.asarray(make_tree(2)["b"]["w"])}
+        groups = {"head": {"lr": 0.05},
+                  "bias": {"lr_scale": 0.5, "weight_decay": 0.0}}
+        opt = cls(param_group_fn=lambda path, leaf: path[2:-2],
+                  group_hypers=groups, **hp)
+        grads_seq = random_grads(params, nsteps=3)   # norm ~9: 1.0 clips
+        p, _ = stepped(opt, params, grads_seq, lr=0.02, clip_norm=1.0)
+        ref = oracle_run(
+            name, params, grads_seq, clip_norm=1.0,
+            leaf_hypers=[groups.get(k, {}) for k in sorted(params)],
+            **dict(hp, lr=0.02))
+        assert_matches_oracle(p, ref["params"])
 
     def test_adam_no_decay_group(self):
         from apex_tpu.optimizers import FusedAdam

@@ -1263,6 +1263,9 @@ def test_no_program_copies_the_kv_pool(serving_programs):
 # ------------------------------------- the optimizer updates a tree in place
 _TREE_OPTIMIZERS = {"FusedAdam": {}, "FusedLAMB": {},
                     "FusedSGD": dict(lr=0.1, momentum=0.9)}
+#: the state slot whose leaves stand beside the gradients in the update
+_TREE_SLOTS = {"FusedAdam": "exp_avg", "FusedLAMB": "exp_avg",
+               "FusedSGD": "momentum_buffer"}
 
 
 def _tree_optimizer(name):
@@ -1272,7 +1275,7 @@ def _tree_optimizer(name):
     return getattr(optimizers, name), _TREE_OPTIMIZERS[name]
 
 
-def _small_gpt_step(optimizer, bucketed=False):
+def _small_gpt_step(optimizer):
     """``make_train_step`` at the tests' small GPT on one (CPU) device,
     state donated: the compiled step, its parameters and its state."""
     from jax.sharding import PartitionSpec as P
@@ -1287,7 +1290,7 @@ def _small_gpt_step(optimizer, bucketed=False):
         tensor_model_parallel_size_=1, pipeline_model_parallel_size_=1,
         devices=jax.devices()[:1])
     params = init_params(cfg, jax.random.PRNGKey(0))
-    state = optimizer.init(params, bucketed=bucketed)
+    state = optimizer.init(params)
     step = make_train_step(cfg, optimizer, mesh, donate_state=True,
                            opt_state_spec=jax.tree.map(lambda _: P(), state))
     tokens = jnp.zeros((2, 16), I32)
@@ -1342,13 +1345,14 @@ def test_the_train_step_updates_a_tree_in_place(name):
     """The one-chip GPT step with a default optimizer and per-leaf
     state: the compiled step holds no 1-D f32 value of the tree's size
     (no whole-tree concatenate of the gradients, no flat moment, no
-    flat update term: until PR 39 the bucket engine made seven and a
-    half such copies a step, 10.6 GB of temporaries at GPT-2 medium);
+    flat update term: packing the tree into flat buckets made seven and
+    a half such copies a step, 10.6 GB of temporaries at GPT-2 medium;
+    PERF.md, PR 39);
     ``input_output_alias`` covers every leaf of the parameters and of
     every state slot, so the update is in place; and the gradients are
     read by no more instructions than the per-leaf numerics
-    specification (``_leaf_update``, the parent's ``use_buckets=False``
-    path) reads them by: the dispatch's tail adds no pass of its own,
+    specification (``_leaf_update`` alone) reads them by: the
+    dispatch's tail adds no pass of its own,
     and ``offer_local_grad_norm`` traces to nothing with no telemetry
     attached."""
     from apex_tpu.analysis.lowered import assert_donation_covers
@@ -1366,32 +1370,13 @@ def test_the_train_step_updates_a_tree_in_place(name):
                                        grads_finite=grads_finite, lr=lr),
                     None)
 
-    slot = cls._BUCKET_SLOT
+    slot = _TREE_SLOTS[name]
     want = _gradient_reads(_small_gpt_step(Specification(**kw))[1], slot)
     got = _gradient_reads(compiled, slot)
     assert got.keys() == want.keys() and len(got) == len(
         jax.tree.leaves(params))
     more = {k: (got[k], want[k]) for k in got if got[k] > want[k]}
     assert not more, f"gradients read more often than specified: {more}"
-
-
-@pytest.mark.parametrize("name", sorted(_TREE_OPTIMIZERS))
-def test_bucket_resident_state_still_reaches_the_engine(name):
-    """State that LIVES in buckets (``init(params, bucketed=True)``)
-    takes the bucket engine: the step packs the gradients into a flat
-    f32 bucket of the tree's size, and the flat slots are donated."""
-    from apex_tpu.analysis.lowered import assert_donation_covers
-    from apex_tpu.optimizers import bucketing
-
-    cls, kw = _tree_optimizer(name)
-    optimizer = cls(**kw)
-    lowered, compiled, params, state = _small_gpt_step(optimizer,
-                                                       bucketed=True)
-    assert optimizer._state_is_bucketed(state)
-    (bucket,) = bucketing.plan_of(params).buckets
-    flat = _flat_f32(compiled, bucket.total)
-    assert flat, "no flat bucket in the step: the engine did not run"
-    assert_donation_covers(lowered, params, state, compiled=True)
 
 
 def test_no_program_casts_or_copies_the_stacked_weights(serving_programs):
