@@ -1,4 +1,6 @@
-"""Fused single-token decode step and the prefill step.
+"""The compiled steps of the serve loop: the fused single-token decode
+step, the speculative verify step, the block step of a model that
+generates by diffusion over blocks, and the prefill step.
 
 The steps are built for a **served model**: any object with
 ``cache_spec()`` (cache entry name -> ``(layers, heads, dim)`` for a
@@ -11,8 +13,18 @@ given: :func:`cast_once`), ``multi_position``, ``counter_names`` and
 gives one (:func:`served`).  ``models.gpt.GPTServed`` is the first,
 ``models.mla_moe.MLAMoEServed`` (a latent cache, held experts) the
 second, ``models.falcon_h1.FalconH1Served`` (paged K/V AND per-slot
-state in every layer) the fifth; nothing here imports a model
+state in every layer) the fifth, ``models.sdar_moe.SDARMoEServed`` (a
+block a slot a step: it declares ``block_length`` and ``mask_id`` and
+brings ``decode_block``) the sixth; nothing here imports a model
 (docs/inference.md has the interface).
+
+There are THREE kinds of step.  The plain decode step advances every
+slot by one position and yields one token a slot; the verify step
+scores ``draft_len + 1`` consecutive positions a slot, each under its
+own causal length, and yields one to ``draft_len + 1`` tokens; the
+block step (:func:`make_block_step`) forwards a slot's current BLOCK of
+``block_length`` positions, which all see the same columns, and yields
+no token (a denoising pass), or the block's tokens (its commit pass).
 
 ``make_decode_step`` builds ONE jitted function that advances every
 resident sequence by one token: embedding lookup, all transformer
@@ -57,15 +69,19 @@ from apex_tpu.inference.kv_cache import (
     write_prompt_pools, write_prompt_windowed,
 )
 from apex_tpu.observability import tracing as _tracing
-from apex_tpu.ops.decode_sampling_pallas import fused_sample
+from apex_tpu.ops.decode_sampling_pallas import (
+    fused_sample, fused_sample_confidence,
+)
 # the install of a slot's rows: every per-slot entry's, whichever
 # recurrence keeps it (ops/kda.py's KDA, ops/ssd.py's Mamba-2)
 from apex_tpu.ops.kda import install_rows
 
 __all__ = [
-    "DecodeConfig", "cast_once", "decode_logits_tokenwise",
+    "BLOCK_COMMIT", "BLOCK_DENOISE", "BLOCK_IDLE", "DecodeConfig",
+    "block_passes", "cast_once", "decode_logits_tokenwise",
+    "init_block_state", "make_block_prefill", "make_block_step",
     "make_decode_step", "make_prefill", "make_prefill_chunk",
-    "make_sample_head", "make_verify_step", "served",
+    "make_sample_head", "make_verify_step", "served", "unmask_counts",
 ]
 
 
@@ -464,3 +480,188 @@ def decode_logits_tokenwise(params, model, dcfg: DecodeConfig,
                              tables, seeds)
         out.append(logits[0])
     return jnp.stack(out)
+
+
+# ------------------------------------------------- generation by blocks
+#: what a slot's pass of a block step was (column ``W`` of the step's
+#: readback): nothing (an inactive slot, or one past its last block), a
+#: denoising pass, a commit pass
+BLOCK_IDLE, BLOCK_DENOISE, BLOCK_COMMIT = -1, 0, 1
+
+
+def unmask_counts(block_length: int, denoising_steps: int):
+    """``n_t``, the masked positions denoising pass ``t`` of a block
+    unmasks (at least; a pass never unmasks more than are masked):
+    ``block_length // T + [t < block_length mod T]``."""
+    W, T = int(block_length), int(denoising_steps)
+    return [W // T + (t < W % T) for t in range(T)]
+
+
+def block_passes(block_length: int, denoising_steps: int, masked: int):
+    """Passes a block of ``masked`` masked positions takes under the
+    static schedule: the denoising passes until none is left, and the
+    commit pass."""
+    left, passes = int(masked), 0
+    for n in unmask_counts(block_length, denoising_steps):
+        if left <= 0:
+            break
+        left -= n
+        passes += 1
+    return passes + 1
+
+
+def init_block_state(max_batch: int, block_length: int, mask_id: int):
+    """The block step's carried per-slot state, on the device: ``ids``
+    (B, W) the block's current ids (``mask_id`` where a position is
+    still to be generated), ``passes`` (B,) the denoising passes the
+    block has had, ``pos`` (B,) the block's first position."""
+    return {"ids": jnp.full((max_batch, block_length), mask_id, jnp.int32),
+            "passes": jnp.zeros((max_batch,), jnp.int32),
+            "pos": jnp.zeros((max_batch,), jnp.int32)}
+
+
+def _chosen(masked, conf, n_t, remasking: str, threshold: float):
+    """The masked positions a denoising pass unmasks, (B, W) bool.
+    ``low_confidence_static``: the ``n_t`` of highest confidence (ties:
+    the lowest index); ``low_confidence_dynamic``: every one whose
+    confidence passes ``threshold``, or the ``n_t`` best where fewer
+    pass; ``sequential``: the leftmost ``n_t``."""
+    if remasking == "sequential":
+        rank = jnp.cumsum(masked, axis=1, dtype=jnp.int32) - 1
+        return masked & (rank < n_t[:, None])
+    c = jnp.where(masked, conf, -jnp.inf)
+    index = jnp.arange(c.shape[1], dtype=jnp.int32)
+    ahead = (c[:, None, :] > c[:, :, None]) | (
+        (c[:, None, :] == c[:, :, None])
+        & (index[None, None, :] < index[None, :, None]))
+    best = masked & (jnp.sum(ahead, axis=2, dtype=jnp.int32) < n_t[:, None])
+    if remasking == "low_confidence_static":
+        return best
+    over = masked & (conf > threshold)
+    enough = jnp.sum(over, axis=1, dtype=jnp.int32) >= n_t
+    return jnp.where(enough[:, None], over, best)
+
+
+def make_block_step(model, dcfg: DecodeConfig, return_logits: bool = False):
+    """Build the jitted BLOCK step of a model that generates by
+    diffusion over blocks (it declares ``block_length`` W, ``mask_id``,
+    ``remasking`` and ``confidence_threshold``, and brings
+    ``decode_block``).
+
+    Returns ``step(params, pools, blocks, steps, ends, active,
+    page_tables, seeds) -> (pools, blocks, out)`` with ``pools`` as in
+    :func:`make_decode_step` and
+
+    - ``blocks``: the carried per-slot block state
+      (:func:`init_block_state`; DONATED, like the pools).  It lives on
+      the device so that the host can launch step n+1 before it has
+      read step n: what a pass unmasked, and whether a block is done,
+      are the device's to know;
+    - ``steps`` (B,) int32: each slot's ``denoising_steps`` T (1..W);
+      ``ends`` (B,): the position a slot's sequence ends at, rounded up
+      to a block: a slot whose block starts there is done, and its pass
+      is no pass (``BLOCK_IDLE``) whatever ``active`` says;
+    - ``active`` (B,) bool, ``page_tables`` (B, P), ``seeds`` (B,)
+      uint32 (a slot's draw; row ``w`` of its block draws from ``seed +
+      w``).
+
+    A live slot's pass: the block's W ids are forwarded at ``pos ..
+    pos + W - 1`` against the cached columns before them and their own
+    (the pass's keys and values are written over the block's columns in
+    place).  Where the block still holds a mask (a DENOISING pass), the
+    fused head gives every row its token and that token's confidence
+    (:func:`~apex_tpu.ops.decode_sampling_pallas.fused_sample_confidence`;
+    no (rows, V) logits in HBM; the mask's own row is left out of the
+    draw and of the softmax, so a position is never unmasked into a
+    mask), and :func:`_chosen` positions take their tokens.  Where it holds none (the COMMIT pass: the keys and
+    values just written are those of the clean tokens) the slot moves on
+    to its next block: all masks, ``pos + W``.  ``out`` (B, W + 2)
+    int32 is what the host reads back: the block's ids after the pass
+    (a commit's: the clean block), the pass's kind
+    (``BLOCK_IDLE``/``BLOCK_DENOISE``/``BLOCK_COMMIT``) and how many
+    positions it unmasked.
+
+    With ``return_logits=True`` the step returns ``(pools, blocks,
+    logits (B, W, V))`` float32 and leaves the block state as it was:
+    the parity probe."""
+    from apex_tpu.inference.kv_cache import COUNTERS
+
+    m = served(model)
+    W, mask_id = int(m.block_length), int(m.mask_id)
+    if dcfg.top_k:
+        raise NotImplementedError(
+            "the block step's head gives a token and its confidence over "
+            "the whole vocabulary: top_k is not built for it")
+    names = tuple(m.counter_names)
+    own = [names.index(n) for n in ("blk_denoise_passes",
+                                    "blk_commit_passes",
+                                    "blk_tokens_unmasked")]
+
+    def step(params, pools, blocks, steps, ends, active, page_tables, seeds):
+        ids, passes, pos = blocks["ids"], blocks["passes"], blocks["pos"]
+        B = ids.shape[0]
+        live = active & (pos < ends)
+        hidden, pools = m.decode_block(
+            params, ids.reshape(B * W), pos, live, pools, page_tables,
+            dcfg.attn_impl)
+        if return_logits:
+            logits = jnp.matmul(hidden.astype(jnp.float32),
+                                m.head(params).T.astype(jnp.float32))
+            return pools, blocks, logits.reshape(B, W, -1)
+        row_seeds = (seeds.astype(jnp.uint32)[:, None]
+                     + jnp.arange(W, dtype=jnp.uint32)[None]).reshape(B * W)
+        x0, conf = fused_sample_confidence(
+            hidden, m.head(params), row_seeds,
+            temperature=dcfg.temperature, exclude=mask_id,
+            impl=dcfg.sample_impl,
+            dot_dtype=dcfg.sample_dot_dtype)
+        masked = ids == mask_id
+        denoise = live & jnp.any(masked, axis=1)
+        commit = live & ~denoise
+        T = jnp.clip(steps.astype(jnp.int32), 1, W)
+        n_t = W // T + (passes < W % T).astype(jnp.int32)
+        chosen = _chosen(masked, conf.reshape(B, W), n_t, m.remasking,
+                         float(m.confidence_threshold)) & denoise[:, None]
+        after = jnp.where(chosen, x0.reshape(B, W), ids)
+        unmasked = jnp.sum(chosen, axis=1, dtype=jnp.int32)
+        kind = jnp.where(commit, BLOCK_COMMIT,
+                         jnp.where(denoise, BLOCK_DENOISE, BLOCK_IDLE))
+        out = jnp.concatenate(
+            [after, kind[:, None].astype(jnp.int32), unmasked[:, None]],
+            axis=1)
+        blocks = {
+            "ids": jnp.where(commit[:, None], mask_id, after),
+            "passes": jnp.where(commit, 0, passes + denoise),
+            "pos": pos + W * commit.astype(jnp.int32)}
+        if COUNTERS in pools:
+            add = jnp.zeros((len(names),), jnp.int32).at[jnp.asarray(own)] \
+                .set(jnp.stack([jnp.sum(denoise, dtype=jnp.int32),
+                                jnp.sum(commit, dtype=jnp.int32),
+                                jnp.sum(unmasked)]))
+            pools = dict(pools, **{COUNTERS: pools[COUNTERS] + add})
+        return pools, blocks, out
+
+    return jax.jit(step, donate_argnums=(1,) if return_logits else (1, 2))
+
+
+def make_block_prefill(model, dcfg: DecodeConfig):
+    """Build the jitted prompt prefill of a block-generating model (one
+    compile a padded length, as :func:`make_prefill`).
+
+    Returns ``prefill(params, pools, prompt, keep, page_table_row) ->
+    pools``: ``prompt`` (1, S) runs through the model's full forward
+    under its block-causal mask and the first ``keep`` positions' cache
+    columns, the WHOLE BLOCKS of the prompt, are written into the
+    sequence's pages.  It samples nothing and reads nothing back: what
+    is left of the prompt past ``keep`` opens the first block beside
+    masks, and the first tokens come from that block's commit."""
+    m = served(model)
+
+    def prefill(params, pools, prompt, keep, page_table_row):
+        _, stacks = m.prefill(params, prompt, keep, dcfg.attn_impl)
+        names = sorted(stacks)
+        new = _write_prompt(pools, stacks, names, None, dcfg,
+                            page_table_row, keep, 0, None)
+        return dict(pools, **new)
+
+    return jax.jit(prefill, donate_argnums=(1,))

@@ -116,7 +116,7 @@ __all__ = [
     "COUNTERS", "GARBAGE_PAGE", "KVCacheConfig", "PageAllocator", "PerSlot",
     "Windowed", "alloc_named_pools", "alloc_pools", "copy_page",
     "named_pools", "open_window", "page_positions", "pages_needed", "per_slot_names",
-    "windowed_entry", "windowed_view", "write_decode_kv",
+    "windowed_entry", "windowed_view", "write_block_pools", "write_decode_kv",
     "write_decode_pools", "write_prompt_kv", "write_prompt_pools",
     "write_prompt_windowed",
 ]
@@ -684,3 +684,55 @@ def write_prompt_windowed(pools, pooled, own, page_table_row, prompt_len,
         + jnp.arange(wp, dtype=jnp.int32)
     return write_prompt_pools(pools, own, row,
                               prompt_len % entry.window, impl=impl)
+
+
+def write_block_pools(pools, news, page_tables, positions, active,
+                      width: int, layer=None, impl="auto"):
+    """Write a BLOCK of ``width`` consecutive columns a slot into ONE
+    layer of the pools, in place: what a block-generating step does
+    every pass (it REWRITES its block's columns: a denoising pass's keys
+    and values are overwritten by the next pass's and at last by the
+    commit's, which are those of the clean tokens).
+
+    As :func:`write_decode_pools` with ``width``, for blocks that start
+    on a multiple of ``width``, ``width`` a divisor of the page: a block
+    then lies in one page, and the kernel path reads and writes that
+    page's tile ONCE a slot (``pool_write_block_pallas``).  ``news``:
+    one (B * width, H_kv, D) array a pool, a slot's rows consecutive;
+    ``page_tables``: (B, P); ``positions``: (B,) each slot's BLOCK
+    START; ``active``: (B,) bool.  Returns the pools, as a tuple in the
+    order given."""
+    from apex_tpu.ops.decode_attention_pallas import stacked_pools
+
+    one_layer = pools[0].ndim == 4
+    pools, layer = stacked_pools(tuple(pools), layer)
+    news = tuple(news)
+    _, num_pages, h_kv, D, page_size = pools[0].shape
+    B = page_tables.shape[0]
+    if news[0].shape[0] != B * width or page_size % width:
+        raise ValueError(
+            f"rows ({news[0].shape[0]}) must equal page-table rows ({B}) x "
+            f"width ({width}), and width divide the page ({page_size})")
+    positions = positions.astype(jnp.int32)
+
+    def xla_impl():
+        rows = (positions[:, None]
+                + jnp.arange(width, dtype=jnp.int32)[None]).reshape(-1)
+        return write_decode_pools(
+            pools, news, page_tables, rows, jnp.repeat(active, width),
+            layer=layer, width=width, impl="xla")
+
+    def kernel_impl():
+        from apex_tpu.ops.kv_write_pallas import pool_write_block_pallas
+
+        dest, first = _row_targets(page_tables, positions, active,
+                                   page_size, num_pages)
+        return pool_write_block_pallas(
+            pools, [x.reshape(1, B, width, h_kv, D) for x in news], dest,
+            first, active & (dest != GARBAGE_PAGE), layer,
+            interpret=(impl == "interpret"))
+
+    pools = _write(impl, news[0], pools[0], kernel_impl, xla_impl)
+    if one_layer:
+        return tuple(p[0] for p in pools)
+    return tuple(pools)

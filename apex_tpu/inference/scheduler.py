@@ -43,6 +43,26 @@ this scheduler fills its slots —
   the non-speculative stream (greedy AND sampled: each emission spends
   its own (slot, draw) seed), it just arrives up to k+1 tokens per
   step.
+- **block generation** (a served model that declares ``block_length``
+  W: generation by diffusion over blocks): the third kind of step.  A
+  slot stays at one BLOCK of W positions for several steps: each step
+  forwards the block's W ids (some of them the model's mask id) and is
+  a DENOISING pass, which unmasks some positions and yields no token,
+  or, once the block holds no mask, its COMMIT pass, which stores the
+  clean block's keys and values and yields the block's tokens at once
+  (``W``, or ``W - r`` for the first block of a prompt that ends ``r``
+  positions into a block); the slot then moves to the next block.  The
+  block's state lives on the device, so the one step in flight
+  survives: under the static strategies the host knows every slot's
+  passes in advance, under ``low_confidence_dynamic`` it learns of a
+  block's end at the readback and may have launched one slot-step too
+  many (``stats["wasted_slot_steps"]``; the device drops it).
+  Admission reserves ``prompt + max_new_tokens`` rounded up to a block,
+  the prefill stores the prompt's whole blocks and samples nothing, and
+  a request is evicted at the commit of its last block, having emitted
+  exactly ``max_new_tokens`` tokens.  ``Request.denoising_steps`` (T,
+  default the model's) is the request's own: fewer passes a block,
+  faster and worse.
 - **evict**: finished sequences free (decref) their pages back to the
   allocator — the next ``step()`` can admit into them — and register
   their quiesced tail page into the prefix trie.
@@ -112,6 +132,7 @@ import dataclasses
 import logging
 import time
 from collections import deque
+from functools import partial
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -120,8 +141,9 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.inference.decode import (
-    DecodeConfig, make_decode_step, make_prefill, make_prefill_chunk,
-    make_sample_head, make_verify_step, served,
+    BLOCK_COMMIT, BLOCK_IDLE, DecodeConfig, block_passes, init_block_state,
+    make_block_prefill, make_block_step, make_decode_step, make_prefill,
+    make_prefill_chunk, make_sample_head, make_verify_step, served,
 )
 from apex_tpu.inference.kv_cache import (
     COUNTERS, GARBAGE_PAGE, PageAllocator, alloc_named_pools, copy_page,
@@ -159,7 +181,13 @@ class Request:
     request's admission-wait/prefill/decode spans.  ``blocked_on`` is
     the scheduler's: why this request last blocked at the head of its
     queue (``"slot"``, ``"pages"``, or None when it never waited as
-    head)."""
+    head).  ``denoising_steps`` is for a block-generating model only
+    (refused elsewhere): the denoising passes a block of this request
+    gets, 1 to the model's ``block_length``; None: the model's own.
+    ``record_passes`` asks such a model to keep every pass of this
+    request's slot for ``Completion.block_trace`` (a caller that checks
+    what each pass did; nothing is kept for a request that does not
+    ask)."""
 
     rid: int
     prompt: List[int]
@@ -168,6 +196,8 @@ class Request:
     lane: str = "interactive"
     trace_id: Optional[str] = None
     blocked_on: Optional[str] = None
+    denoising_steps: Optional[int] = None
+    record_passes: bool = False
 
 
 @dataclasses.dataclass
@@ -181,7 +211,18 @@ class Completion:
     So ``admit_time - submit_time`` is the queue, ``token_times[0] -
     admit_time`` the prefill, and ``token_times[0] - submit_time`` the
     time to first token.  ``preemptions`` counts how often a
-    best-effort generation was evicted-and-requeued on the way."""
+    best-effort generation was evicted-and-requeued on the way.
+
+    Of a block-generating model the tokens of one block come out
+    together, at the block's commit pass: they SHARE one stamp in
+    ``token_times`` (so the gaps inside a block are 0 and the gap
+    between blocks is the block's passes), ``token_times[0]`` is the
+    first block's commit (its prefill samples nothing).  Where the
+    request asked (``Request.record_passes``), ``block_trace`` holds
+    every pass the request's slot made, in order: ``(block start,
+    row)``, ``row`` the W ids after the pass, then the pass's kind
+    (``inference.decode.BLOCK_DENOISE``/``BLOCK_COMMIT``) and how many
+    positions it unmasked.  None otherwise, and for any other model."""
 
     rid: int
     prompt: List[int]
@@ -193,6 +234,7 @@ class Completion:
     lane: str = "interactive"
     preemptions: int = 0
     trace_id: Optional[str] = None
+    block_trace: Optional[List[tuple]] = None
 
 
 @dataclasses.dataclass
@@ -216,6 +258,7 @@ class ManifestEntry:
     remaining: int                 # new tokens still owed
     eos_id: Optional[int] = None
     trace_id: Optional[str] = None
+    denoising_steps: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -230,6 +273,25 @@ class _Carry:
     submit_time: float
     admit_time: float
     preemptions: int = 0
+    block_trace: Optional[List[tuple]] = None
+
+
+@dataclasses.dataclass
+class _BlockPlan:
+    """A block-generating slot's progress, as the host knows it:
+    ``start`` the first block's first position, ``blocks`` how many the
+    request has, ``committed`` how many of them a readback has shown
+    committed, ``to_launch`` the passes the whole request takes where
+    the strategy fixes them in advance (None: learnt at the readback;
+    ``_Slot.launched`` counts the passes launched), ``trace`` every pass
+    read back of a request that asked (``Request.record_passes``, for
+    ``Completion.block_trace``), else None."""
+
+    start: int
+    blocks: int
+    to_launch: Optional[int]
+    committed: int = 0
+    trace: Optional[List[tuple]] = None
 
 
 @dataclasses.dataclass
@@ -245,14 +307,18 @@ class _Slot:
     cow_reserve: Optional[int] = None
     chunk_next: Optional[int] = None  # next prompt position to chunk-prefill
     proposer: Optional[NGramProposer] = None
-    launched: int = 0              # tokens emitted or owed by the step in flight
+    launched: int = 0              # tokens emitted or owed by the step in
+                                   # flight (a block slot: passes launched)
+    block: Optional[_BlockPlan] = None  # a block-generating model's slot
 
 
 @dataclasses.dataclass
 class _InFlight:
     """A plain decode step launched and not read back: its
     ``next_tokens`` as the device holds them, and the slots it owes a
-    token (a slot that ends on ``eos_id`` meanwhile is struck)."""
+    token (a slot that ends on ``eos_id`` meanwhile is struck).  Of a
+    block step ``tokens`` is its (B, W + 2) readback and ``slots`` the
+    slots it forwarded a block for."""
 
     tokens: Any
     slots: np.ndarray              # (B,) bool
@@ -276,13 +342,28 @@ def _set_token(tokens, slot, token):
     return tokens.at[slot].set(token)
 
 
+@partial(jax.jit, donate_argnums=(0,))
+def _set_block(blocks, slot, ids, pos):
+    """An admitted request's first block into the device's block state:
+    what is left of the prompt past its whole blocks, then masks."""
+    return {"ids": blocks["ids"].at[slot].set(ids),
+            "passes": blocks["passes"].at[slot].set(0),
+            "pos": blocks["pos"].at[slot].set(pos)}
+
+
 class ContinuousBatchingScheduler:
     """The serve loop's control plane: lane-aware admission into freed
-    KV pages between decode steps, static-shape slot management,
-    chunked prefill, speculative verify, prefix sharing with COW,
-    eviction with refcounted page recycling, deterministic per-slot
-    sampling seeds, and degrade-once step rebuild on deferred kernel
-    failures (see the module docstring for the full semantics)."""
+    KV pages between steps, static-shape slot management, chunked
+    prefill, prefix sharing with COW, eviction with refcounted page
+    recycling, deterministic per-slot sampling seeds, degrade-once step
+    rebuild on deferred kernel failures, and ONE of three kinds of step
+    by what the model and the configuration say: the plain decode step
+    (a token a slot, one step in flight), the speculative verify step
+    (``draft_len`` > 0: up to ``draft_len + 1`` tokens a slot,
+    synchronous), or the block step of a model that generates by
+    diffusion over blocks (it declares ``block_length``: no token or a
+    whole block a slot, one step in flight).  See the module docstring
+    for the full semantics."""
 
     def __init__(self, params, model, dcfg: DecodeConfig,
                  time_fn=time.monotonic, watchdog=None, anomaly=None):
@@ -301,6 +382,12 @@ class ContinuousBatchingScheduler:
                 f"{type(self.model).__name__}'s decode forward scores one "
                 "position a slot: speculative verify (draft_len) and "
                 "chunked prefill (prefill_chunk) are not built for it")
+        #: the block length of a model that generates by diffusion over
+        #: blocks (it declares it), else None
+        self._block: Optional[int] = getattr(self.model, "block_length",
+                                             None)
+        if self._block is not None:
+            self._check_block_config(dcfg)
         spec = self.model.cache_spec()
         if dcfg.prefix_sharing and per_slot_names(spec):
             raise NotImplementedError(
@@ -358,6 +445,16 @@ class ContinuousBatchingScheduler:
         #: (the last launched step's output, with first tokens written in)
         self._inflight: Optional[_InFlight] = None
         self._dev_tokens = jnp.zeros((B,), jnp.int32)
+        #: a block-generating model's per-slot block state, on the
+        #: device (``inference.decode.init_block_state``), and what the
+        #: host uploads with every launch: each slot's denoising steps
+        #: and the position its sequence ends at, rounded up to a block
+        self._blocks = None
+        if self._block is not None:
+            self._blocks = init_block_state(B, self._block,
+                                            self.model.mask_id)
+        self._block_steps = np.ones((B,), np.int32)
+        self._block_ends = np.zeros((B,), np.int32)
         #: per-slot sampling draw counters — MONOTONIC for the life of
         #: the scheduler, across every generation a slot serves (the
         #: determinism contract: no (slot, draw) seed is ever replayed,
@@ -380,6 +477,9 @@ class ContinuousBatchingScheduler:
             # dropped because their sequence had ended on eos_id
             "decode_overlapped": 0, "decode_settles": 0,
             "wasted_slot_steps": 0,
+            # a block-generating model's slot-steps read back, and how
+            # many of them were commit passes
+            "block_passes": 0, "block_commits": 0,
             # launches after which a slot's window buffer starts again
             # from empty (a windowed cache only)
             "window_rollovers": 0,
@@ -459,7 +559,8 @@ class ContinuousBatchingScheduler:
                 else list(req.prompt),
                 emitted=list(c.tokens) if c is not None else [],
                 remaining=req.max_new_tokens, eos_id=req.eos_id,
-                trace_id=req.trace_id))
+                trace_id=req.trace_id,
+                denoising_steps=req.denoising_steps))
         for s in list(self._slots):
             if s is None:
                 continue
@@ -472,7 +573,8 @@ class ContinuousBatchingScheduler:
                 else list(req.prompt),
                 emitted=(list(c.tokens) if c is not None else []) + gen,
                 remaining=req.max_new_tokens - len(gen),
-                eos_id=req.eos_id, trace_id=req.trace_id))
+                eos_id=req.eos_id, trace_id=req.trace_id,
+                denoising_steps=req.denoising_steps))
         return out
 
     def _on_wedge(self, info) -> None:
@@ -550,8 +652,43 @@ class ContinuousBatchingScheduler:
                            error=f"{type(e).__name__}: {e}")
 
     # ------------------------------------------------------------ build
+    def _check_block_config(self, dcfg: DecodeConfig) -> None:
+        """What a block-generating model cannot be served with yet,
+        each with its reason."""
+        name, W = type(self.model).__name__, self._block
+        if dcfg.draft_len > 0:
+            raise NotImplementedError(
+                f"{name} generates by blocks of {W}: a step already "
+                "yields up to a block a slot, and a drafted token would "
+                "have to be verified against a block that is still being "
+                "denoised (draft_len is not built for it)")
+        if dcfg.prefill_chunk is not None:
+            raise NotImplementedError(
+                f"{name} generates by blocks of {W}: its block step "
+                "forwards one block a slot under one shared length, not a "
+                "chunk of consecutive positions under causal lengths "
+                "(prefill_chunk is not built for it)")
+        if dcfg.prefix_sharing:
+            raise NotImplementedError(
+                f"{name} generates by blocks of {W}: a shared tail page "
+                "would be rewritten by every pass of the block that opens "
+                "in it, and the trie indexes pages by tokens that a block "
+                "in progress does not have yet (prefix_sharing is not "
+                "built for it)")
+        if dcfg.cache.page_size % W or any(
+                b % W for b in dcfg.prefill_lengths):
+            raise ValueError(
+                f"page_size ({dcfg.cache.page_size}) and every prefill "
+                f"length {dcfg.prefill_lengths} must be multiples of the "
+                f"block length ({W}): a block never straddles a page")
+
     def _build_steps(self) -> None:
         d = self.dcfg
+        if self._block is not None:
+            self._decode = make_block_step(self.model, d)
+            self._prefill = make_block_prefill(self.model, d)
+            self._verify = self._chunk = self._sample_head = None
+            return
         if d.draft_len > 0:
             self._verify = make_verify_step(self.model, d)
             self._decode = None
@@ -582,6 +719,12 @@ class ContinuousBatchingScheduler:
             raise ValueError("speculative serving runs the verify step; "
                              "there is no plain decode step to lower")
         B = self.dcfg.max_batch
+        if self._block is not None:
+            return self._decode.lower(
+                self.params, self.pools, self._blocks,
+                jnp.asarray(self._block_steps), jnp.asarray(self._block_ends),
+                jnp.asarray(self._active), jnp.asarray(self._page_tables),
+                jnp.zeros((B,), jnp.uint32))
         return self._decode.lower(
             self.params, self.pools, jnp.asarray(self._tokens),
             jnp.asarray(self._positions), jnp.asarray(self._active),
@@ -685,6 +828,21 @@ class ContinuousBatchingScheduler:
                 f"admit long prompts as chunks")
         if request.max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        if self._block is None and request.denoising_steps is not None:
+            raise ValueError(
+                f"denoising_steps ({request.denoising_steps}) is a "
+                f"block-generating model's: {type(self.model).__name__} "
+                "yields one token a step")
+        if self._block is not None:
+            steps = request.denoising_steps
+            if steps is not None and not 1 <= steps <= self._block:
+                raise ValueError(
+                    f"denoising_steps ({steps}) must lie in [1, "
+                    f"block_length = {self._block}]")
+            if request.eos_id is not None:
+                raise ValueError(
+                    "a block-generating model emits exactly "
+                    "max_new_tokens tokens: eos_id is not built for it")
         limit = self.model.max_positions
         if limit is not None and plen + request.max_new_tokens > limit:
             raise ValueError(
@@ -770,9 +928,10 @@ class ContinuousBatchingScheduler:
         plus the speculative write window (draft k/v land up to
         ``draft_len`` positions past the accepted stream and must never
         spill into an unreserved — garbage — table entry)."""
-        return pages_needed(
-            len(req.prompt) + req.max_new_tokens + self.dcfg.draft_len,
-            self._page_positions)
+        total = len(req.prompt) + req.max_new_tokens + self.dcfg.draft_len
+        if self._block is not None:     # whole blocks: the last one's
+            total = -(-total // self._block) * self._block  # surplus too
+        return pages_needed(total, self._page_positions)
 
     @property
     def num_active(self) -> int:
@@ -898,6 +1057,9 @@ class ContinuousBatchingScheduler:
             s.chunk_next = (match.shared_len if match.shared_len < plen
                             else plen - 1)
             return
+        if self._block is not None:
+            self._admit_block(slot, row)
+            return
         # padded to the shortest compiled length that holds the prompt
         padded = next(b for b in self.dcfg.prefill_lengths if b >= plen)
         prompt = np.zeros((1, padded), np.int32)
@@ -929,6 +1091,55 @@ class ContinuousBatchingScheduler:
         self._prefill_done()
         self._start_decoding(slot, first)
 
+    def _admit_block(self, slot: int, row: np.ndarray) -> None:
+        """A block-generating model's admission: the prompt's WHOLE
+        blocks are prefilled (one launch, nothing sampled and nothing
+        read back: the span times the enqueue), what is left of it
+        opens the first block beside masks in the device's block state,
+        and the slot is armed for block steps.  Its first tokens come
+        with that block's commit."""
+        s = self._slots[slot]
+        req, W = s.request, self._block
+        plen = len(req.prompt)
+        keep = plen // W * W
+        steps = req.denoising_steps or self.model.denoising_steps
+        end = -(-(plen + req.max_new_tokens) // W) * W
+        blocks = (end - keep) // W
+        fixed = self.model.remasking != "low_confidence_dynamic"
+        s.block = _BlockPlan(
+            start=keep, blocks=blocks,
+            to_launch=(block_passes(W, steps, W - (plen - keep))
+                       + (blocks - 1) * block_passes(W, steps, W))
+            if fixed else None,
+            trace=[] if req.record_passes else None)
+        if keep:
+            padded = next(b for b in self.dcfg.prefill_lengths if b >= keep)
+            prompt = np.zeros((1, padded), np.int32)
+            prompt[0, :min(plen, padded)] = req.prompt[:padded]
+            with _tracing.span("serve.prefill", rid=req.rid,
+                               trace_id=req.trace_id, lane=req.lane,
+                               prompt_len=plen, tokens=keep,
+                               padded_tokens=padded, shared_len=0) as sp:
+                t_open = time.perf_counter()
+                args = (jnp.asarray(prompt), jnp.int32(keep),
+                        jnp.asarray(row))
+                t_up = time.perf_counter()
+                self.pools = self._call("_prefill", self.params, self.pools,
+                                        *args)
+                t_enq = time.perf_counter()
+                if _tracing.enabled():
+                    sp.set(**_launch_us(t_open, t_up, t_enq, t_enq),
+                           behind_step=int(self._inflight is not None))
+            self._prefill_done()
+        first = np.full((W,), self.model.mask_id, np.int32)
+        first[:plen - keep] = req.prompt[keep:]
+        self._blocks = _set_block(self._blocks, np.int32(slot),
+                                  jnp.asarray(first), np.int32(keep))
+        self._block_steps[slot] = steps
+        self._block_ends[slot] = end
+        self._positions[slot] = keep
+        self._active[slot] = True
+
     def _prefill_done(self) -> None:
         self.stats["prefills"] += 1
         self._prefills_since_step += 1
@@ -941,15 +1152,7 @@ class ContinuousBatchingScheduler:
         s = self._slots[slot]
         req = s.request
         t_first = self._time()
-        submitted = s.submit_time
-        _metrics.observe("apex_serve_ttft_seconds", t_first - submitted,
-                         help="submit -> first token (prefill incl. queue)",
-                         exemplar={"trace_id": req.trace_id,
-                                   "rid": req.rid},
-                         lane=req.lane)
-        if self._anomaly is not None:
-            self._anomaly.observe("ttft", t_first - submitted,
-                                  lane=req.lane)
+        self._observe_first_token(s, t_first)
         s.generated.append(first)
         s.token_times.append(t_first)
         s.launched = 1
@@ -977,6 +1180,17 @@ class ContinuousBatchingScheduler:
             self._dev_tokens = _set_token(
                 self._dev_tokens, np.int32(slot), np.int32(first))
 
+    def _observe_first_token(self, s: _Slot, t_first: float) -> None:
+        req = s.request
+        _metrics.observe("apex_serve_ttft_seconds", t_first - s.submit_time,
+                         help="submit -> first token (prefill incl. queue)",
+                         exemplar={"trace_id": req.trace_id,
+                                   "rid": req.rid},
+                         lane=req.lane)
+        if self._anomaly is not None:
+            self._anomaly.observe("ttft", t_first - s.submit_time,
+                                  lane=req.lane)
+
     # --------------------------------------------------------- preemption
     def _preempt_one(self) -> bool:
         """Evict the YOUNGEST best-effort resident (decoding or still
@@ -1003,6 +1217,11 @@ class ContinuousBatchingScheduler:
                        admit_time=s.admit_time)
             self._carry[req.rid] = c
         c.preemptions += 1
+        if s.block is not None and s.block.trace is not None:
+            # the committed blocks' passes
+            c.block_trace = (c.block_trace or []) + [
+                t for t in s.block.trace
+                if t[0] < len(req.prompt) + len(s.generated)]
         remaining = req.max_new_tokens - len(s.generated)
         cont_prompt = list(req.prompt) + list(s.generated)
         can_continue = (
@@ -1014,12 +1233,16 @@ class ContinuousBatchingScheduler:
             c.times.extend(s.token_times)
             cont = Request(rid=req.rid, prompt=cont_prompt,
                            max_new_tokens=remaining, eos_id=req.eos_id,
-                           lane=req.lane, trace_id=req.trace_id)
+                           lane=req.lane, trace_id=req.trace_id,
+                           denoising_steps=req.denoising_steps,
+                           record_passes=req.record_passes)
         else:  # restart this leg (its partial work is dropped)
             cont = Request(rid=req.rid, prompt=list(req.prompt),
                            max_new_tokens=req.max_new_tokens,
                            eos_id=req.eos_id, lane=req.lane,
-                           trace_id=req.trace_id)
+                           trace_id=req.trace_id,
+                           denoising_steps=req.denoising_steps,
+                           record_passes=req.record_passes)
         self._release_slot(victim)
         self.stats["preemptions"] += 1
         _metrics.inc("apex_serve_preemptions_total",
@@ -1055,6 +1278,7 @@ class ContinuousBatchingScheduler:
         self._page_tables[slot] = 0
         self._positions[slot] = 0
         self._tokens[slot] = 0
+        self._block_ends[slot] = 0
 
     # ------------------------------------------------------------- evict
     def _evict(self, slot: int) -> None:
@@ -1073,6 +1297,18 @@ class ContinuousBatchingScheduler:
             + list(s.token_times)
         first_leg = c if c is not None else s
         submit, admit = first_leg.submit_time, first_leg.admit_time
+        trace, block_attrs = None, {}
+        if s.block is not None:
+            if s.block.trace is not None:
+                trace = ((c.block_trace or []) if c is not None else []) \
+                    + s.block.trace
+            # every leg's committed blocks: the last one's end, from the
+            # ORIGINAL prompt's last whole block
+            block_attrs = dict(
+                denoising_steps=int(self._block_steps[slot]),
+                blocks=(s.block.start + s.block.committed * self._block
+                        - len(prompt) // self._block * self._block)
+                // self._block)
         self._release_slot(slot)
         finish = self._time()
         self.completed.append(Completion(
@@ -1080,7 +1316,7 @@ class ContinuousBatchingScheduler:
             submit_time=submit, admit_time=admit, finish_time=finish,
             token_times=times, lane=s.request.lane,
             preemptions=c.preemptions if c is not None else 0,
-            trace_id=s.request.trace_id))
+            trace_id=s.request.trace_id, block_trace=trace))
         tracer = _tracing.get_tracer()
         if tracer is not None:
             # the whole-lifetime span (submit -> eviction), what the
@@ -1094,7 +1330,8 @@ class ContinuousBatchingScheduler:
                 prefill_s=round(times[0] - admit, 6) if times else None,
                 ttft_s=round(times[0] - submit, 6) if times else None,
                 blocked_on=s.request.blocked_on,
-                preemptions=c.preemptions if c is not None else 0)
+                preemptions=c.preemptions if c is not None else 0,
+                **block_attrs)
         self.stats["evicted"] += 1
         _metrics.inc("apex_serve_completions_total",
                      help="finished generations")
@@ -1268,7 +1505,12 @@ class ContinuousBatchingScheduler:
         live = self._active.copy()
         for i in np.flatnonzero(live):
             s = self._slots[i]
-            live[i] = s.launched < s.request.max_new_tokens
+            if s.block is None:
+                live[i] = s.launched < s.request.max_new_tokens
+            elif s.block.to_launch is not None:
+                live[i] = s.launched < s.block.to_launch
+            else:   # the readback says when the last block committed
+                live[i] = s.block.committed < s.block.blocks
         return live
 
     def _step_decode(self, launch: bool = True) -> bool:
@@ -1287,6 +1529,7 @@ class ContinuousBatchingScheduler:
         if prev is None and not launching:
             return False
         seeds = np.zeros((B,), np.uint32)
+        W = self._block
         if launching:
             # positions, seeds and the COW pass advance at launch
             self._cow_for_writes(live, width=1)
@@ -1304,10 +1547,28 @@ class ContinuousBatchingScheduler:
                       prefills_before=self._prefills_since_step,
                       in_flight=overlapped)
                  if traced else {})
+        if traced and W is not None:
+            # rows the launched step forwards
+            attrs["block_rows"] = W * int(live.sum()) if launching else 0
         next_tokens = None
         with _tracing.span("serve.decode_step", **attrs) as sp:
             t_open = t_up = t_enq = time.perf_counter()
-            if launching:
+            if launching and W is not None:
+                # the block's ids, pass count and position are the
+                # device's; the host says who is live, each slot's
+                # denoising steps and where its sequence ends
+                args = (jnp.asarray(self._block_steps.copy()),
+                        jnp.asarray(self._block_ends.copy()),
+                        jnp.asarray(live),
+                        jnp.asarray(self._page_tables.copy()),
+                        jnp.asarray(seeds))
+                t_up = time.perf_counter()
+                self.pools, self._blocks, out = self._call(
+                    "_decode", self.params, self.pools, self._blocks, *args)
+                out.copy_to_host_async()
+                self._inflight = _InFlight(out, live.copy())
+                t_enq = time.perf_counter()
+            elif launching:
                 # COPIES of the arrays the host goes on changing while
                 # the step is in flight (an upload may alias or still be
                 # reading its numpy buffer after the launch returns)
@@ -1333,15 +1594,86 @@ class ContinuousBatchingScheduler:
             if traced:
                 sp.set(prep_us=int((t_open - t_in) * 1e6),
                        **_launch_us(t_open, t_up, t_enq, t_read))
+                if W is not None and prev is not None:
+                    sp.set(**self._block_attrs(prev, next_tokens))
         self._prefills_since_step = 0
         if overlapped:
             self.stats["decode_overlapped"] += 1
             _metrics.inc("apex_serve_decode_overlapped_total",
                          help="decode steps launched before the "
                               "previous step's tokens were read back")
-        if prev is not None:
+        if prev is not None and W is not None:
+            self._emit_blocks(prev, next_tokens)
+        elif prev is not None:
             self._emit(prev, next_tokens)
         return True
+
+    def _block_tokens(self, s: _Slot, row: np.ndarray) -> List[int]:
+        """The tokens the commit of ``s``'s current block emits: the
+        block's positions from the prompt's end to the request's (the
+        first block holds what was left of the prompt, the last one a
+        surplus that is generated and dropped)."""
+        start = s.block.start + s.block.committed * self._block
+        plen = len(s.request.prompt)
+        lo = max(plen - start, 0)
+        hi = min(plen + s.request.max_new_tokens - start, self._block)
+        return [int(t) for t in row[lo:hi]]
+
+    def _block_attrs(self, step: _InFlight, out: np.ndarray) -> Dict:
+        """Of the block step read back, for its span: the slots whose
+        pass was a commit, the positions unmasked and the tokens it
+        emits."""
+        W = self._block
+        rows = out[step.slots]
+        return dict(
+            commits=int((rows[:, W] == BLOCK_COMMIT).sum()),
+            unmasked=int(rows[:, W + 1].sum()),
+            emitted=sum(len(self._block_tokens(self._slots[i], out[i]))
+                        for i in np.flatnonzero(step.slots)
+                        if out[i, W] == BLOCK_COMMIT))
+
+    def _emit_blocks(self, step: _InFlight, out: np.ndarray) -> None:
+        """A block step's readback -> end of the iteration: a pass
+        into its slot's trace where the request keeps one; a commit's
+        tokens emitted under ONE stamp (now, the moment they are on the
+        host), the slot moved to its next block, or evicted at the
+        commit of its last."""
+        W = self._block
+        with _tracing.span("serve.emit") as emit_span:
+            now = self._time()
+            self.stats["decode_steps"] += 1
+            self._record_occupancy()
+            tokens, evicted = 0, self.stats["evicted"]
+            passes = commits = 0
+            gaps: Dict[str, list] = {}
+            for i in np.flatnonzero(step.slots):
+                s, row = self._slots[i], out[i]
+                if row[W] == BLOCK_IDLE:    # past its last block: the
+                    continue                # device dropped the pass
+                plan = s.block
+                if plan.trace is not None:
+                    plan.trace.append((plan.start + plan.committed * W, row))
+                passes += 1
+                if row[W] != BLOCK_COMMIT:
+                    continue
+                commits += 1
+                for tok in self._block_tokens(s, row):
+                    if not s.token_times:
+                        self._observe_first_token(s, now)
+                        s.generated.append(tok)
+                        s.token_times.append(now)
+                    else:
+                        self._emit_token(s, tok, now, gaps)
+                    tokens += 1
+                plan.committed += 1
+                self._positions[i] = plan.start + plan.committed * W
+                if plan.committed >= plan.blocks:
+                    self._evict(i)
+            self.stats["block_passes"] += passes
+            self.stats["block_commits"] += commits
+            self._observe_gaps(gaps)
+            emit_span.set(tokens=tokens,
+                          evicted=self.stats["evicted"] - evicted)
 
     def _emit(self, step: _InFlight, next_tokens: np.ndarray) -> None:
         """Readback -> end of the iteration: token bookkeeping,

@@ -361,3 +361,51 @@ def mha_reference(q, k, v, causal=True, softmax_scale=None, kv_mask=None,
         s = jnp.where(kv_mask[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def block_causal_attention(q, k, v, block: int, softmax_scale=None,
+                           impl: str = "auto"):
+    """Attention that is causal by BLOCK, forward only, (B, H, S, D):
+    key ``j`` is visible to query ``i`` iff ``j // block <= i // block``
+    (bidirectional inside a block of ``block`` positions, causal across
+    blocks): what a model that generates by diffusion over blocks
+    prefills its prompt with.  k/v may carry fewer heads than q.
+
+    ``impl``: "auto" (the flash forward with its ``block`` mask on a TPU
+    with kernel-friendly shapes, through the fallback registry as
+    :func:`flash_attention`), "pallas" / "interpret" (the kernel,
+    forced), "xla" the dense softmax in float32 (the numerics
+    specification; the CPU path)."""
+    scale = softmax_scale if softmax_scale is not None \
+        else 1.0 / np.sqrt(q.shape[-1])
+    B, H, S, D = q.shape
+    Hkv = k.shape[1]
+
+    def dense_impl():
+        kk, vv = k, v
+        if Hkv != H:
+            kk = jnp.repeat(k, H // Hkv, axis=1)
+            vv = jnp.repeat(v, H // Hkv, axis=1)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                       kk.astype(jnp.float32)) * scale
+        i = jnp.arange(S, dtype=jnp.int32)
+        seen = (i[None, :] // block) <= (i[:, None] // block)
+        p = jax.nn.softmax(jnp.where(seen, s, NEG_INF), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), vv)
+
+    def kernel_impl():
+        from apex_tpu.ops.flash_attention_pallas import flash_fwd_pallas
+
+        out, _ = flash_fwd_pallas(
+            q.reshape(B * H, S, D), k.reshape(B * Hkv, S, D),
+            v.reshape(B * Hkv, S, D), scale, True, 0, 0,
+            interpret=(impl == "interpret"), heads=H, kv_heads=Hkv,
+            block=block)
+        return out.reshape(B, H, S, D)
+
+    from apex_tpu.ops.decode_attention_pallas import dispatch_kernel
+    from apex_tpu.ops.flash_attention_pallas import pallas_flash_available
+
+    return dispatch_kernel("flash_attention", impl,
+                           lambda: pallas_flash_available(q, k),
+                           kernel_impl, dense_impl)

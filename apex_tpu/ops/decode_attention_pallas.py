@@ -584,3 +584,39 @@ def decode_attention(q, k_pool, v_pool, page_table, lengths,
 
     return dispatch_pool_kernel("decode_attention", impl, q, k_pool,
                                 kernel_impl, xla_impl)
+
+
+def block_decode_attention(q, k_pool, v_pool, page_table, lengths, width,
+                           impl="auto", softmax_scale=None, layer=None):
+    """Paged attention for a BLOCK of ``width`` query positions a
+    sequence that all see the same columns (generation by diffusion over
+    blocks: inside a block attention is bidirectional, so the block's
+    rows share one length, the block's end).
+
+    ``q``: (B * width, H, D), a sequence's ``width`` rows consecutive;
+    ``page_table``: (B, P); ``lengths``: (B,) the columns every row of
+    the sequence's block sees (0: an inactive slot).  The verify layout
+    (``decode_attention(width=...)``) is right in value and walks a
+    sequence's pages once a ROW; because the rows share their length,
+    they ride ONE walk here: the block is folded into the group axis, a
+    key/value head scored against ``width * H / H_kv`` query rows, so
+    the live pages are read once a sequence a layer.  Kernel and XLA
+    twin are :func:`decode_attention`'s, at that wider group
+    (:func:`_plan` prices the group's rows of scratch).  Returns
+    (B * width, H, D)."""
+    Bw, H, D = q.shape
+    h_kv = k_pool.shape[-3]
+    B = page_table.shape[0]
+    if B * width != Bw or H % h_kv:
+        raise ValueError(
+            f"q rows ({Bw}) must equal page-table rows ({B}) x width "
+            f"({width}), and q heads ({H}) divide by kv heads ({h_kv})")
+    group = H // h_kv
+    # (B, width, h_kv, group, D) -> (B, h_kv, width * group, D)
+    folded = q.reshape(B, width, h_kv, group, D).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, h_kv * width * group, D)
+    out = decode_attention(folded, k_pool, v_pool, page_table, lengths,
+                           impl=impl, softmax_scale=softmax_scale,
+                           layer=layer)
+    return out.reshape(B, h_kv, width, group, D).transpose(0, 2, 1, 3, 4) \
+        .reshape(Bw, H, D)

@@ -181,6 +181,20 @@ def _sample_kernel(x_ref, e_ref, seed_ref, tok_out,
         tok_out[:] = best_i[:, 0:1]
 
 
+def _operand_blocks(x2, embed, block_n, block_v):
+    """``(bn, bv)``: the row and vocabulary blocks of a head's launch.
+    Both operand blocks are double-buffered: a wide model (H = 7168)
+    halves the vocab tile until they fit the scoped VMEM."""
+    (N, H), V = x2.shape, embed.shape[0]
+    bn = _ceil_block(N, block_n, align=_sublane(x2.dtype))
+    bv = _ceil_block(V, block_v, align=_LANES)
+    while bv > _LANES and 2 * H * (
+            bn * x2.dtype.itemsize + bv * embed.dtype.itemsize) \
+            > _OPERAND_VMEM:
+        bv //= 2
+    return bn, bv
+
+
 def fused_sample_pallas(x2, embed, seeds, temperature=1.0, top_k=0,
                         dot_dtype=None, block_n=256, block_v=512,
                         interpret=False):
@@ -199,14 +213,7 @@ def fused_sample_pallas(x2, embed, seeds, temperature=1.0, top_k=0,
         raise ValueError(
             f"the kernel's running top-k scratch holds one lane tile "
             f"({MAX_KERNEL_TOP_K}); top_k={top_k} must take the XLA path")
-    bn = _ceil_block(N, block_n, align=_sublane(x2.dtype))
-    bv = _ceil_block(V, block_v, align=_LANES)
-    # both operand blocks are double-buffered: a wide model (H = 7168)
-    # halves the vocab tile until they fit the scoped VMEM
-    while bv > _LANES and 2 * H * (
-            bn * x2.dtype.itemsize + bv * embed.dtype.itemsize) \
-            > _OPERAND_VMEM:
-        bv //= 2
+    bn, bv = _operand_blocks(x2, embed, block_n, block_v)
     nn, nv = _grid(N, bn), _grid(V, bv)
 
     tok = pl.pallas_call(
@@ -283,3 +290,149 @@ def fused_sample(x2, embed, seeds, temperature=1.0, top_k=0,
     if registry_engaged(forced=forced):
         return get_registry().call("decode_sampling", kernel_impl, xla_impl)
     return kernel_impl()
+
+
+# ------------------------------------------- the head with its confidence
+def fused_sample_confidence_xla(x2, embed, seeds, temperature=1.0,
+                                exclude=None):
+    """The token of :func:`fused_sample_xla` (no top-k) AND its
+    confidence: the value at that token of ``softmax(logits)`` (greedy)
+    or of ``softmax(logits / temperature)``, the distribution it was
+    drawn from, in float32.  ``exclude``: a static row of the table
+    that is left out of the draw and of the softmax (a block-generating
+    model's mask id: a position is never unmasked INTO a mask).
+    Returns ``((N,) int32, (N,) float32)``.  What a block-generating
+    step ranks its masked positions by."""
+    logits = jnp.matmul(x2.astype(jnp.float32),
+                        embed.T.astype(jnp.float32))
+    if exclude is not None:
+        logits = logits.at[:, exclude].set(NEG_INF)
+    z = logits if temperature <= 0.0 else logits / jnp.float32(temperature)
+    if temperature <= 0.0:
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    else:
+        cols = jnp.arange(logits.shape[1], dtype=jnp.int32)
+        tok = jnp.argmax(z + gumbel_from_seed(seeds[:, None], cols[None, :]),
+                         axis=-1).astype(jnp.int32)
+    conf = jnp.take_along_axis(jax.nn.softmax(z, axis=-1), tok[:, None],
+                               axis=-1, mode="clip")[:, 0]
+    return tok, conf
+
+
+def _sample_conf_kernel(x_ref, e_ref, seed_ref, tok_out, conf_out,
+                        best_v, best_i, best_z, run_m, run_l, *,
+                        bv, nv, V, dot_dtype, temperature, exclude):
+    """:func:`_sample_kernel`'s sampling sweep with two more running
+    rows a sequence row: the online logsumexp of the scaled logits and
+    the scaled logit of the candidate that leads so far; the confidence
+    is ``exp(z[token] - logsumexp(z))``."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        best_v[:] = jnp.full_like(best_v, NEG_INF)
+        best_i[:] = jnp.zeros_like(best_i)
+        best_z[:] = jnp.full_like(best_z, NEG_INF)
+        run_m[:] = jnp.full_like(run_m, NEG_INF)
+        run_l[:] = jnp.zeros_like(run_l)
+
+    s, cols, valid, _ = _masked_scores(x_ref, e_ref, j, bv, V, dot_dtype)
+    if exclude is not None:
+        valid = valid & (j * bv + cols != exclude)
+    z = s / jnp.float32(temperature) if temperature > 0.0 else s
+    z = jnp.where(valid, z, NEG_INF)
+    if temperature > 0.0:
+        g = gumbel_from_seed(seed_ref[:, 0:1].astype(jnp.uint32),
+                             j * bv + cols)
+        cand = jnp.where(valid, z + g, NEG_INF)
+    else:
+        cand = z
+    m = jnp.max(cand, axis=1, keepdims=True)
+    am = jnp.argmax(cand, axis=1).astype(jnp.int32)[:, None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 1)
+    z_at = jnp.max(jnp.where(lane == am, z, NEG_INF), axis=1, keepdims=True)
+    better = m > best_v[:, 0:1]      # strict: the earlier tile wins a tie
+    best_i[:] = jnp.broadcast_to(
+        jnp.where(better, am + j * bv, best_i[:, 0:1]), best_i.shape)
+    best_z[:] = jnp.broadcast_to(
+        jnp.where(better, z_at, best_z[:, 0:1]), best_z.shape)
+    best_v[:] = jnp.broadcast_to(
+        jnp.where(better, m, best_v[:, 0:1]), best_v.shape)
+    m_prev = run_m[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(z, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(z - m_new), 0.0)
+    run_l[:] = jnp.broadcast_to(
+        run_l[:, 0:1] * jnp.exp(m_prev - m_new)
+        + jnp.sum(p, axis=1, keepdims=True), run_l.shape)
+    run_m[:] = jnp.broadcast_to(m_new, run_m.shape)
+
+    @pl.when(j == nv - 1)
+    def _finalize():
+        tok_out[:] = best_i[:, 0:1]
+        conf_out[:] = jnp.exp(best_z[:, 0:1] - run_m[:, 0:1]) \
+            / run_l[:, 0:1]
+
+
+def fused_sample_confidence_pallas(x2, embed, seeds, temperature=1.0,
+                                   exclude=None, dot_dtype=None,
+                                   block_n=256, block_v=512,
+                                   interpret=False):
+    """The launcher of :func:`_sample_conf_kernel`: shapes, blocks and
+    ``dot_dtype`` as :func:`fused_sample_pallas`; no ``(N, V)`` logits
+    reach HBM."""
+    from apex_tpu.ops.fused_ce_pallas import _default_dot_dtype
+
+    dot_dtype = dot_dtype or _default_dot_dtype()
+    N, H = x2.shape
+    V = embed.shape[0]
+    bn, bv = _operand_blocks(x2, embed, block_n, block_v)
+    nn, nv = _grid(N, bn), _grid(V, bv)
+    row = pl.BlockSpec((bn, 1), lambda i, j: (i, 0),
+                       memory_space=pltpu.VMEM)
+    tok, conf = pl.pallas_call(
+        functools.partial(
+            _sample_conf_kernel, bv=bv, nv=nv, V=V, dot_dtype=dot_dtype,
+            temperature=float(temperature),
+            exclude=None if exclude is None else int(exclude)),
+        grid=(nn, nv),
+        in_specs=[
+            pl.BlockSpec((bn, H), lambda i, j: (i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((bv, H), lambda i, j: (j, 0),
+                         memory_space=pltpu.VMEM),
+            row,
+        ],
+        out_specs=[row, row],
+        out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((N, 1), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((bn, _LANES), jnp.float32),
+            pltpu.VMEM((bn, _LANES), jnp.int32),
+            pltpu.VMEM((bn, _LANES), jnp.float32),
+            pltpu.VMEM((bn, _LANES), jnp.float32),
+            pltpu.VMEM((bn, _LANES), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="apex_fused_sample",
+    )(x2, embed, seeds.reshape(N, 1).astype(jnp.uint32))
+    return tok[:, 0], conf[:, 0]
+
+
+def fused_sample_confidence(x2, embed, seeds, temperature=1.0,
+                            exclude=None, impl="auto", dot_dtype=None):
+    """hidden (N, H) → ``(token ids (N,), confidences (N,) float32)``:
+    :func:`fused_sample` (without top-k) with the softmax value of each
+    sampled token beside it (:func:`fused_sample_confidence_xla` is the
+    specification).  ``impl`` and the degrade path as there."""
+    from apex_tpu.ops.decode_attention_pallas import dispatch_kernel
+
+    return dispatch_kernel(
+        "decode_sampling", impl,
+        lambda: pallas_sample_available(x2, embed, 0),
+        lambda: fused_sample_confidence_pallas(
+            x2, embed, seeds, temperature=temperature, exclude=exclude,
+            dot_dtype=dot_dtype, interpret=(impl == "interpret")),
+        lambda: fused_sample_confidence_xla(
+            x2, embed, seeds, temperature=temperature, exclude=exclude))

@@ -448,14 +448,19 @@ def _grid_index(axis, n):
 
 
 def _visible(rows, cols, first_key_less_first_query, transposed=False,
-             edges=(False, True), window=None):
+             edges=(False, True), window=None, block=None):
     """The mask of a piece an edge crosses: query index ≥ key index
     (the diagonal, ``edges[1]``) and query index − key index < ``window``
     (the band's lower edge, ``edges[0]``), each ONE compare of the index
     difference inside the piece against a constant.  ``transposed``:
-    keys (sublanes) by queries."""
+    keys (sublanes) by queries.  ``block`` (the forward's; a power of
+    two that every piece starts on a multiple of): a query sees to the
+    END of its block of ``block`` positions, so its index counts as its
+    block's last."""
     along = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     down = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    if block:
+        down = down | (block - 1)
     diff = along - down if transposed else down - along
     lower, upper = edges
     if not lower:
@@ -482,10 +487,13 @@ def _dead_rows_off(lse):
 
 # ------------------------------------------------------------------ forward
 def _fwd_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
-                block_q, block_k, sub_q, sub_k, run, nq, nk, band=None):
+                block_q, block_k, sub_q, sub_k, run, nq, nk, band=None,
+                causal_block=None):
     """``nk``: key blocks a query block walks (under a window, the
     band's ``n_live``: grid index ``j`` is then the walk's, and the key
-    block is ``band.first(i) + j``)."""
+    block is ``band.first(i) + j``).  ``causal_block``: causal by block
+    (:func:`flash_fwd_pallas`); only the sub-tiles the diagonal crosses
+    differ, in their mask."""
     if has_bias:
         q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -529,7 +537,8 @@ def _fwd_kernel(*refs, scale, causal, has_bias, q_offset, k_offset,
                 s = s + b_ref[0, :, cols]  # (1, columns) key bias over rows
             if masked:
                 s = jnp.where(_visible(sub_q, sub_k, c * sub_k - gap,
-                                       edges=masked, window=window),
+                                       edges=masked, window=window,
+                                       block=causal_block),
                               s, NEG_INF)
             return s
 
@@ -687,9 +696,18 @@ def dispatched(sq, sk, d, dtype, phase, block_q=None, block_k=None):
 def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
                      block_q=None, block_k=None, interpret=False,
                      out_dtype=None, kv_bias=None, heads=1, kv_heads=None,
-                     window=None):
+                     window=None, block=None):
     """q: (BH, Sq, D); k/v: (B·kv_heads, Sk, D).  Returns
     (out, lse (BH, Sq, 1)).
+
+    ``block``: a static block length ``W`` (a power of two) over a
+    causal call without a window: visibility is causal by BLOCK, key
+    ``j`` visible to query ``i`` iff ``j // W <= i // W`` (bidirectional
+    inside a block; what a block-generating model's prefill attends by).
+    Offsets and sub-tiles are multiples of ``W``, so no sub-tile above
+    the diagonal becomes live: the walk is the causal one and only the
+    crossed sub-tiles' mask changes (``col <= row | (W - 1)``).  The
+    forward alone takes it; there is no backward.
 
     ``window``: a static sliding window over a causal call: key ``j`` is
     visible to query ``i`` iff ``0 <= i - j < window`` (global
@@ -716,11 +734,20 @@ def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
     bq, bk, sub = dispatched(Sq, Sk, D, q.dtype, "fwd", block_q, block_k)
     has_bias = kv_bias is not None
     _check_window(window, causal)
+    if block is not None:
+        block = int(block)
+        if not causal or window is not None or block < 1 \
+                or block & (block - 1) or q_offset % block \
+                or k_offset % block or sub[0] % block or sub[1] % block:
+            raise ValueError(
+                f"block ({block}) needs causal=True, no window, a power of "
+                f"two, and offsets ({q_offset}, {k_offset}) and sub-tiles "
+                f"{sub[:2]} that are multiples of it")
 
     inputs = (q, k, v) if not has_bias else (q, k, v, kv_bias)
     call = _fwd_call(BH, Sq, Sk, D, heads, kv_heads, float(scale), causal,
                      q_offset, k_offset, bq, bk, sub, has_bias, interpret,
-                     jnp.dtype(out_dtype).name, window)
+                     jnp.dtype(out_dtype).name, window, block)
     # jax.disable_jit(False): pallas_call cannot bind eagerly (its bind
     # params carry a dict), so the kernel stays one jitted op even when a
     # caller runs the surrounding program op-by-op under disable_jit().
@@ -732,7 +759,7 @@ def flash_fwd_pallas(q, k, v, scale, causal, q_offset, k_offset,
 @functools.lru_cache(maxsize=512)
 def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
               q_offset, k_offset, bq, bk, sub, has_bias, interpret,
-              out_dtype_name, window=None):
+              out_dtype_name, window=None, block=None):
     """The fwd ``pallas_call``, memoized on its static configuration —
     every argument is static by construction (they bake into the kernel
     closure), so eager callers (a ring chunk per hop, interpret-mode
@@ -764,7 +791,7 @@ def _fwd_call(BH, Sq, Sk, D, heads, kv_heads, scale, causal,
             _fwd_kernel, scale=scale, causal=causal, has_bias=has_bias,
             q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk,
             sub_q=sub[0], sub_k=sub[1], run=sub[2], nq=nq, nk=nk,
-            **_band_kw(band),
+            **_band_kw(band), **({"causal_block": block} if block else {}),
         ),
         grid=(BH, nq, nk),
         in_specs=in_specs,

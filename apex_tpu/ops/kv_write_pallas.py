@@ -49,7 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops._pallas_tiling import LANES, VMEM_BUDGET
 
-__all__ = ["kv_write_pallas", "pool_write_pallas"]
+__all__ = ["kv_write_pallas", "pool_write_block_pallas", "pool_write_pallas"]
 
 
 def _heads_per_block(h_kv, head_dim, page_size, dtype, n_pools=2) -> int:
@@ -118,7 +118,18 @@ def pool_write_pallas(pools, srcs, dest, mask, layer, interpret=False):
         raise ValueError(
             f"dest {dest.shape} / mask {mask.shape} do not match {T} "
             f"tiles of {page_size} columns")
+    return _launch(_kv_write_kernel, pools, srcs, dest, mask, layer,
+                   interpret)
 
+
+def _launch(kernel, pools, srcs, dest, mask, layer, interpret):
+    """The one ``apex_kv_write`` call: ``kernel(..., heads=, cols=)``
+    over the grid (source layers, tiles, blocks of heads), the pools
+    aliased input-to-output.  ``mask``: (T, page_size), what the kernel
+    makes of a lane."""
+    n = len(pools)
+    _, _, h_kv, D, page_size = pools[0].shape
+    Ls, T, C = srcs[0].shape[:3]
     dtype = pools[0].dtype
     hb = _heads_per_block(h_kv, D, page_size, dtype, n)
     src_spec = pl.BlockSpec(
@@ -140,7 +151,7 @@ def pool_write_pallas(pools, srcs, dest, mask, layer, interpret=False):
     pool_t = jax.ShapeDtypeStruct(pools[0].shape, dtype)
     # operand numbering counts the two prefetched scalars and the mask
     out = pl.pallas_call(
-        functools.partial(_kv_write_kernel, heads=hb, cols=C),
+        functools.partial(kernel, heads=hb, cols=C),
         grid_spec=grid_spec,
         out_shape=[pool_t] * n,
         input_output_aliases={3 + n + i: i for i in range(n)},
@@ -160,3 +171,58 @@ def kv_write_pallas(k_pool, v_pool, k_src, v_src, dest, mask, layer,
     ``v``.  Returns the two pools."""
     return pool_write_pallas((k_pool, v_pool), (k_src, v_src), dest, mask,
                              layer, interpret=interpret)
+
+
+def _kv_block_write_kernel(dest_ref, layer_ref, mask_ref, *refs, heads, cols):
+    """The tile write for a block of ``cols`` columns: ``mask`` names,
+    a lane, the source column it takes plus one (0: the lane keeps the
+    pool's)."""
+    del dest_ref, layer_ref  # consumed by the BlockSpec index maps
+    n = len(refs) // 3       # sources, pool tiles in, pool tiles out
+    which = mask_ref[0]                  # (1, page): broadcasts over D
+    for src_ref, pool_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                          refs[2 * n:]):
+        src = src_ref[0, 0, 0]           # (D, heads * cols)
+        for h in range(heads):
+            tile = pool_ref[0, 0, h]
+            for j in range(cols):
+                # the block's column j of head h, one value a head-dim
+                # element, into the one lane that takes it
+                new = src[:, h * cols + j:h * cols + j + 1]
+                tile = jnp.where(which == j + 1, new, tile)
+            out_ref[0, 0, h] = tile
+
+
+def pool_write_block_pallas(pools, srcs, dest, first, live, layer,
+                            interpret=False):
+    """Write a block of ``W`` consecutive columns a tile into pool
+    pages, in place: :func:`pool_write_pallas` for sources of ``W``
+    columns, ``W`` small and a divisor of the page, so that a block
+    lies in ONE page and a tile is read and written once for all of
+    them (the ``width`` layout of :func:`pool_write_pallas` would hand
+    the kernel two page-wide source tiles a block, the second to the
+    garbage page).
+
+    ``pools`` as there.  ``srcs``: one (1, T, W, H_kv, D) source a pool;
+    ``dest``: (T,) int32 page ids, clamped and garbage-routed by the
+    caller, live ones pairwise distinct; ``first``: (T,) int32 the lane
+    the block's first column takes; ``live``: (T,) bool (a dead tile
+    keeps the pool's columns).  ``layer``: scalar int32.  Returns the
+    pools, as a tuple."""
+    pools, srcs = tuple(pools), tuple(srcs)
+    n = len(pools)
+    _, _, h_kv, D, page_size = pools[0].shape
+    Ls, T, W = srcs[0].shape[:3]
+    if Ls != 1 or page_size % W or len(srcs) != n \
+            or any(x.shape != (1, T, W, h_kv, D) for x in srcs) \
+            or any(p.shape != pools[0].shape or p.dtype != pools[0].dtype
+                   for p in pools):
+        raise ValueError(
+            f"sources {[x.shape for x in srcs]} do not fit pools "
+            f"{[p.shape for p in pools]} as blocks that divide a page")
+    lane = jnp.arange(page_size, dtype=jnp.int32)[None, :]
+    offset = lane - first.astype(jnp.int32)[:, None]
+    which = jnp.where(live[:, None] & (offset >= 0) & (offset < W),
+                      offset + 1, 0)
+    return _launch(_kv_block_write_kernel, pools, srcs, dest, which, layer,
+                   interpret)

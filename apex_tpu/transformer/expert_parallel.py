@@ -472,7 +472,8 @@ def balance_bias_update(bias, load, coeff: float):
 
 def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
                      topk_group: int, scale: float, token_mask=None,
-                     layer=None, impl="auto", buffer_rows=None):
+                     layer=None, impl="auto", buffer_rows=None,
+                     softmax=False):
     """The routed part of an expert layer that the experts ``held``
     give, for every token, with no assignment dropped.
 
@@ -483,9 +484,9 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
     (``range(E)``: the whole layer).  ``token_mask``: (T,) bool —
     tokens that are padding or an empty slot route nowhere.
 
-    Each token's ``top_k`` assignments are chosen over all experts and
-    weighted over all ``top_k`` (:func:`route_group_limited`); those to
-    a held expert are sorted by expert and run through
+    Each token's ``top_k`` assignments are chosen and weighted over all
+    experts (:func:`route_group_limited`; ``softmax``: :func:`route_softmax`,
+    no bias); those to a held expert are sorted by expert and run through
     :func:`grouped_gated_ffn` in a static buffer of ``T * top_k`` rows,
     the worst case.  Returns ``(out (T, H), counts)`` with ``counts``
     the int32 scalars ``assignments_held`` (assignments computed
@@ -514,9 +515,13 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
         raise ValueError(
             f"held {held} must be a contiguous range matching the "
             f"{experts['we_gate'].shape[1]} experts' weights given")
-    ids, weights = route_group_limited(
-        x, params["router"], params["router_bias"], top_k=top_k,
-        n_group=n_group, topk_group=topk_group, scale=scale)
+    if softmax:
+        ids, weights = route_softmax(x, params["router"], top_k=top_k,
+                                     scale=scale)
+    else:
+        ids, weights = route_group_limited(
+            x, params["router"], params["router_bias"], top_k=top_k,
+            n_group=n_group, topk_group=topk_group, scale=scale)
     live = (ids >= held.start) & (ids < held.stop)
     if token_mask is not None:
         live = live & token_mask[:, None]
@@ -587,3 +592,19 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
         jnp.arange(A, dtype=jnp.int32))
     out = jnp.take(y, back, axis=0).reshape(T, top_k, H).sum(1)
     return out.astype(x.dtype), counted()
+
+
+def route_softmax(x, router_w, *, top_k: int, scale: float = 1.0):
+    """Softmax top-k routing over ALL experts, no bias and no groups.
+
+    ``x``: (T, H); ``router_w``: (H, E).  In float32 whatever the
+    inputs' dtype: ``p = softmax(x W)`` over the ``E`` experts, the
+    ``top_k`` largest chosen (ties: the lowest id), their weights ``p``
+    divided by the chosen ones' sum (``norm_topk_prob``) and multiplied
+    by ``scale``.  Returns ``(ids (T, top_k) int32, weights (T, top_k)
+    float32)``, as :func:`route_group_limited`."""
+    p = jax.nn.softmax(jnp.matmul(x.astype(jnp.float32),
+                                  router_w.astype(jnp.float32)), axis=-1)
+    picked, ids = jax.lax.top_k(p, top_k)
+    weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+    return ids.astype(jnp.int32), weights

@@ -34,6 +34,13 @@ by.
         cellbench/configs/falcon-h1-34b-serve-pp9.json \
         --streams 96 --page-size 128 --prompt-len 1536 --max-new 1024 \
         --prefill-buckets 128,256,512,1024 --temperature 0
+    # the sixth family (models/sdar_moe.py: generation by diffusion over
+    # blocks of 4: a step forwards a block a slot and yields no token or
+    # a whole block; --denoising-steps trades quality for speed)
+    python examples/gpt/serve_gpt.py --model-config \
+        cellbench/configs/sdar-30b-a3b-serve-ep8.json \
+        --streams 64 --page-size 128 --prompt-len 768 --max-new 512 \
+        --prefill-buckets 128,256,512 --temperature 0 --denoising-steps 2
     # serving v2: speculative decode + shared system prompt + chunked
     # prefill + a preemptible best-effort lane, one command
     python examples/gpt/serve_gpt.py --draft-len 4 --prefix-sharing \\
@@ -102,14 +109,21 @@ def build_args():
                         "models/mla_moe.py), model_type evabyte "
                         "(models/evabyte.py: --page-size then follows the "
                         "file, window_size / chunk_size, and prompts pad "
-                        "to whole windows), or model_type falcon_h1 "
-                        "(models/falcon_h1.py).  Where the file states the "
+                        "to whole windows), model_type falcon_h1 "
+                        "(models/falcon_h1.py), or model_type sdar_moe "
+                        "(models/sdar_moe.py: generation by blocks; "
+                        "--held-start picks the share of the experts).  "
+                        "Where the file states the "
                         "router's width under 'published', its own "
                         "experts count is the number HELD here, from "
                         "--held-start on")
     p.add_argument("--held-start", type=int, default=0,
                    help="first expert id this process holds "
                         "(--model-config)")
+    p.add_argument("--denoising-steps", type=int, default=None,
+                   help="a block-generating model's denoising passes a "
+                        "block, every request's (1 to its block length; "
+                        "default: the model's): fewer is faster and worse")
     p.add_argument("--prefill-buckets", default="",
                    help="comma-separated prompt pad lengths below "
                         "--prompt-len: one prefill compile each, a "
@@ -214,7 +228,11 @@ def make_requests(args, rng):
         lane = ("best_effort"
                 if rng.uniform() < args.best_effort_frac else "interactive")
         reqs.append(Request(rid=rid, prompt=prompt,
-                            max_new_tokens=args.max_new, lane=lane))
+                            max_new_tokens=args.max_new, lane=lane,
+                            denoising_steps=args.denoising_steps,
+                            # the smoke checks every pass of a block-
+                            # generating model (check_block_parity)
+                            record_passes=args.smoke))
         if args.arrival_rate > 0:
             t += float(rng.exponential(1.0 / args.arrival_rate))
         arrivals.append(t)
@@ -270,10 +288,44 @@ def report(completions, wall_secs):
     return out
 
 
+def check_block_parity(params, config, completions, max_check=3):
+    """The smoke contract for a block-generating model: every position
+    a pass unmasked took the full forward's greedy token (the mask's row
+    apart) given the block's state before that pass, and a block was
+    committed only once it held no mask."""
+    from apex_tpu.models import sdar_moe
+
+    W, mask = config.block_length, config.mask_id
+    for c in completions[:max_check]:
+        seq, state, at = list(c.prompt[:len(c.prompt) // W * W]), None, None
+        for start, row in c.block_trace:
+            if start != at:     # a new block: prompt remainder, then masks
+                left = list(c.prompt[start:start + W])
+                state, at = left + [mask] * (W - len(left)), start
+            after = [int(t) for t in row[:W]]
+            if row[W] == 1:     # the commit pass
+                assert after == state and mask not in state, (c.rid, start)
+                seq += state
+                continue
+            logits = sdar_moe.forward(params, jnp.asarray([seq + state]),
+                                      config, attn_impl="xla")[0, start:]
+            pred = jnp.argmax(logits.at[:, mask].set(-jnp.inf), axis=-1)
+            for i in range(W):
+                if after[i] != state[i]:
+                    assert state[i] == mask and after[i] == int(pred[i]), (
+                        f"rid={c.rid}: block {start} unmasked {after[i]} at "
+                        f"{i} where the full forward's greedy token is "
+                        f"{int(pred[i])}")
+            state = after
+        assert seq[len(c.prompt):len(c.prompt) + len(c.tokens)] == c.tokens
+
+
 def check_greedy_parity(params, config, completions, max_check=3):
     """Every generated token must be the model's full forward's argmax
     continuation — the decision-level decode↔forward parity the smoke
     contract promises."""
+    if type(config).__name__ == "SDARMoEConfig":
+        return check_block_parity(params, config, completions, max_check)
     for c in completions[:max_check]:
         seq = list(c.prompt)
         for tok in c.tokens:
@@ -310,14 +362,24 @@ def build_model(args, max_seq_len):
     ``--layers/--hidden/--heads/...``, or — with ``--model-config`` — the
     family a published-style ``config.json`` names (``model_type``
     ``evabyte``: ``models/evabyte.py``; ``falcon_h1``:
-    ``models/falcon_h1.py``; else the latent-attention, sparse-expert
-    family; weights in bf16, random)."""
+    ``models/falcon_h1.py``; ``sdar_moe``: ``models/sdar_moe.py``; else
+    the latent-attention, sparse-expert family; weights in bf16,
+    random)."""
     key = jax.random.PRNGKey(args.seed)
     if args.model_config:
-        from apex_tpu.models import evabyte, falcon_h1, mla_moe
+        from apex_tpu.models import evabyte, falcon_h1, mla_moe, sdar_moe
 
         conf = json.loads(Path(args.model_config).read_text())
         dtype = jnp.float32 if args.smoke else jnp.bfloat16
+        if conf.get("model_type") == "sdar_moe":
+            # as below: the file's own count is what this process holds
+            config = sdar_moe.SDARMoEConfig.from_published(
+                conf, num_experts=conf.get("published", {}).get(
+                    "num_experts", conf["num_experts"]),
+                held_start=args.held_start, held_count=conf["num_experts"],
+                param_dtype=dtype, compute_dtype=dtype)
+            args.vocab = config.mask_id     # prompts hold no mask
+            return config, sdar_moe.init_params(config, key)
         if conf.get("model_type") == "falcon_h1":
             config = falcon_h1.FalconH1Config.from_published(
                 conf, param_dtype=dtype, compute_dtype=dtype)

@@ -302,8 +302,67 @@ def _eva_write(width_tiles=None):
         [_EVA_POOL, _EVA_POOL, new, new, ((width_tiles,), I32), ((), I32)])
 
 
+#: the block-generating cell (``sdar-30b-a3b.serve-blockgen-over``): 64
+#: slots x a block of 4 rows, 32 query heads over 4 key/value heads of
+#: 128, page 128, 10 pages a sequence, the 48-layer stacked pool of 320
+#: pages at a traced layer
+_BLOCK_CELL = dict(B=64, W=4, heads=32, kv=4, D=128, page=128, P=10,
+                   pages=320, layers=48)
+_BLOCK_POOL = ((48, 320, 4, 128, 128), BF16)
+
+
+def _block_attn():
+    from apex_tpu.ops.decode_attention_pallas import block_decode_attention
+
+    c = _BLOCK_CELL
+    return (lambda q, k, v, pt, n, layer: block_decode_attention(
+        q, k, v, pt, n, c["W"], impl="pallas", layer=layer),
+        [((c["B"] * c["W"], c["heads"], c["D"]), BF16), _BLOCK_POOL,
+         _BLOCK_POOL, ((c["B"], c["P"]), I32), ((c["B"],), I32), ((), I32)])
+
+
+def _block_write():
+    from apex_tpu.inference.kv_cache import write_block_pools
+
+    c = _BLOCK_CELL
+    new = ((c["B"] * c["W"], c["kv"], c["D"]), BF16)
+    return (lambda k, v, kn, vn, pt, pos, act, layer: write_block_pools(
+        (k, v), (kn, vn), pt, pos, act, c["W"], layer=layer, impl="pallas"),
+        [_BLOCK_POOL, _BLOCK_POOL, new, new, ((c["B"], c["P"]), I32),
+         ((c["B"],), I32), ((c["B"],), jnp.bool_), ((), I32)])
+
+
+def _sample_confidence(temperature, rows=256, hidden=2048, vocab=18992):
+    from apex_tpu.ops.decode_sampling_pallas import (
+        fused_sample_confidence_pallas,
+    )
+
+    return (lambda x, e, s: fused_sample_confidence_pallas(
+        x, e, s, temperature=temperature, exclude=vocab - 1),
+        [((rows, hidden), BF16), ((vocab, hidden), BF16), ((rows,), U32)])
+
+
+def _flash_block_causal(S=768):
+    from apex_tpu.ops.attention import block_causal_attention
+
+    return (lambda q, k, v: block_causal_attention(q, k, v, 4, impl="pallas"),
+            [((1, 32, S, 128), BF16), ((1, 4, S, 128), BF16),
+             ((1, 4, S, 128), BF16)])
+
+
 #: name -> (fn, [(shape, dtype)...], kernel names the executable must hold)
 CASES = {
+    # generation by blocks, at its cell's shapes: a slot's 4 rows x 8
+    # query heads ride one walk; the block's 4 columns into one tile;
+    # the head with its confidence, greedy and drawn; the prefill's
+    # block-causal flash forward at its longest bucket
+    "block_attn_cell": (*_block_attn(), {"apex_decode_attention"}),
+    "kv_write_block_cell": (*_block_write(), {"apex_kv_write"}),
+    "sample_confidence_greedy": (*_sample_confidence(0.0),
+                                 {"apex_fused_sample"}),
+    "sample_confidence_drawn": (*_sample_confidence(0.8),
+                                {"apex_fused_sample"}),
+    "flash_fwd_block4_s768": (*_flash_block_causal(), {"apex_flash_fwd"}),
     # serving, GPT-124M heads; 345M heads; GQA; MQA; the verify width
     "decode_attn_mha12": (*_decode_attn(12, 12, 1), {"apex_decode_attention"}),
     "decode_attn_mha16": (*_decode_attn(16, 16, 1), {"apex_decode_attention"}),
@@ -496,6 +555,14 @@ def test_decode_attention_grid_at_the_cells_shapes():
     h_blk, grid = _plan(B, heads, 1, 256, P, page, F32)
     assert 1 <= h_blk < heads and heads % h_blk == 0
     assert grid == (B, heads // h_blk)
+    # a block step: a slot's 4 rows share their length, so they are one
+    # grid step (32 query rows a key/value head, priced by the plan) and
+    # the slot's pages are walked ONCE, not once a row
+    c = _BLOCK_CELL
+    h_blk, grid = _plan(c["B"], c["kv"], c["W"] * c["heads"] // c["kv"],
+                        c["D"], c["P"], c["page"], BF16)
+    assert (h_blk, grid) == (c["kv"], (c["B"], 1))
+    assert lowered_grid("block_attn_cell") == (c["B"], 1)
 
 
 def _dispatched(Sq, Sk, D, phase, block_q=None, block_k=None):
@@ -1018,6 +1085,75 @@ for name, (fn, args) in programs.items():
 print(json.dumps(out))
 """
 
+_BLOCK_POOL_CHILD = _DESCRIBED_V5E + """
+import jax.numpy as jnp
+from pathlib import Path
+import apex_tpu.utils.platform as platform
+platform.on_tpu = lambda: True      # 'auto' impls: as on the chip
+from apex_tpu.analysis.lowered import (
+    large_result_instructions, pallas_kernels,
+)
+from apex_tpu.inference.decode import (
+    init_block_state, make_block_prefill, make_block_step,
+)
+from apex_tpu.inference.kv_cache import COUNTERS, alloc_named_pools
+from apex_tpu.models.sdar_moe import init_params
+from cellbench.adapters.serve_sdar_moe import decode_config, model_config
+
+conf = json.loads(Path(
+    "cellbench/configs/sdar-30b-a3b-serve-ep8.json").read_text())
+cfg, dcfg = model_config(conf), decode_config(conf, 0)
+B, PPS, S = dcfg.max_batch, dcfg.cache.pages_per_seq, dcfg.max_prompt_len
+sh = SingleDeviceSharding(dev)
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+model = cfg.served_model()
+params = put(jax.eval_shape(lambda: model.serving_params(
+    init_params(cfg, jax.random.PRNGKey(0)))))
+pools = put(jax.eval_shape(lambda: dict(
+    alloc_named_pools(model.cache_spec(), dcfg.cache, slots=B),
+    **{COUNTERS: jnp.zeros((len(model.counter_names),), jnp.int32)})))
+blocks = put(jax.eval_shape(
+    lambda: init_block_state(B, cfg.block_length, cfg.mask_id)))
+I, U = jnp.int32, jnp.uint32
+programs = {
+    "decode_step": (make_block_step(cfg, dcfg), (
+        params, pools, blocks, arg((B,), I), arg((B,), I),
+        arg((B,), jnp.bool_), arg((B, PPS), I), arg((B,), U))),
+    "prefill": (make_block_prefill(cfg, dcfg), (
+        params, pools, arg((1, S), I), arg((), I), arg((PPS,), I))),
+}
+L = cfg.num_hidden_layers
+# more than one layer of the largest leaf: only a whole stack is larger
+matrix = 1 + params["layers"]["we_gate"].size // L
+nbytes = lambda a: a.size * a.dtype.itemsize
+out = {"pool_bytes": nbytes(pools["k"]), "chip_bytes": 16 * 2 ** 30,
+       "weight_bytes": sum(nbytes(a) for a in jax.tree.leaves(params))}
+for name, (fn, args) in programs.items():
+    try:
+        c = fn.lower(*args).compile()
+    except Exception as e:
+        out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}
+        continue
+    show = lambda found: [
+        [i["name"], i["opcode"],
+         "tpu_custom_call" in i["line"]
+         and "output_to_operand_aliasing" in i["line"]]
+        for i in found]
+    mem = c.memory_analysis()
+    out[name] = {
+        "kernels": sorted(set(pallas_kernels(c))),
+        "temp_bytes": mem.temp_size_in_bytes,
+        "program_bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes,
+        "instructions": show(large_result_instructions(
+            c, pools["k"].size // L,
+            containing=(dcfg.cache.num_pages, 4, 128))),
+        "matrix_sized": show(large_result_instructions(c, matrix))}
+print(json.dumps(out))
+"""
+
 _AFMOE_STEP_CHILD = _DESCRIBED_V5E + """
 import re
 import jax.numpy as jnp
@@ -1458,6 +1594,47 @@ def test_a_layer_that_holds_both_kinds_of_entry_copies_neither():
     assert 0.25 * out["chip_bytes"] < out["decode_step"]["program_bytes"]
     assert 8.2e9 < out["weight_bytes"] < 8.25e9
     assert out["state_bytes"] == 97 * 8 * 32 * 128 * 256 * 4
+
+
+def test_the_block_step_copies_no_pool_and_casts_no_stack():
+    """The block-generating cell's block step and its longest prefill
+    (768 tokens), compiled for a v5e at the committed configuration: no
+    instruction but parameters, tuple plumbing and the aliased
+    ``apex_kv_write`` produces a value the size of one layer of ``k`` or
+    ``v`` (the block's columns are rewritten IN PLACE every pass), none
+    produces one as large as a stacked weight matrix (the held experts'
+    stacks reach the grouped matmul whole, with the layer's index; the
+    embedding table's prefetch apart); the
+    step's kernels are the block attention, the block's write, the
+    grouped matmul and the head with its confidence, the prefill's the
+    block-causal flash forward; and both hold between 25% and 93% of
+    the chip (13.3 GB: weights 9.24, K/V pool 4.03)."""
+    out = _programs_of(_BLOCK_POOL_CHILD)
+    for name in ("decode_step", "prefill"):
+        bad = _moved(out[name]["instructions"])
+        assert not bad, (f"{name}: instructions that produce a value as "
+                         f"large as a layer of the pool: {bad}")
+        assert [n for n, op, _ in out[name]["instructions"]
+                if op == "custom-call"], f"{name}: no aliased kernel writes it"
+        # but for the 78 MB embedding table, which XLA prefetches into
+        # fast memory for the step's 256-row gather (twice: 1% of the
+        # step's bytes; PERF.md, Open questions)
+        bad = _moved(out[name]["matrix_sized"],
+                     _POOL_PLUMBING | {"bitcast", "copy-start", "copy-done"})
+        assert not bad, (f"{name}: instructions that produce a value as "
+                         f"large as a stacked weight matrix: {bad}")
+        assert sum(op == "copy-start"
+                   for _, op, _ in out[name]["matrix_sized"]) <= 2
+        assert 0.25 * out["chip_bytes"] < out[name]["program_bytes"] \
+            < 0.93 * out["chip_bytes"]
+    assert {"apex_kv_write", "apex_decode_attention", "apex_fused_sample",
+            "gmm"} <= set(out["decode_step"]["kernels"])
+    assert {"apex_flash_fwd", "apex_kv_write", "gmm"} \
+        <= set(out["prefill"]["kernels"])
+    assert out["decode_step"]["temp_bytes"] < 0.2e9
+    # 4,620,431,360 parameters in bf16, the router and the gains in float32
+    assert 9.26e9 < out["weight_bytes"] < 9.27e9
+    assert out["pool_bytes"] == 48 * 320 * 4 * 128 * 128 * 2
 
 
 _EVA_POOL_CHILD = _DESCRIBED_V5E + """
