@@ -22,9 +22,10 @@ There are THREE kinds of step.  The plain decode step advances every
 slot by one position and yields one token a slot; the verify step
 scores ``draft_len + 1`` consecutive positions a slot, each under its
 own causal length, and yields one to ``draft_len + 1`` tokens; the
-block step (:func:`make_block_step`) forwards a slot's current BLOCK of
-``block_length`` positions, which all see the same columns, and yields
-no token (a denoising pass), or the block's tokens (its commit pass).
+block step (:func:`make_block_step`) forwards a slot's open BLOCK of
+``block_length`` positions, which all see the same columns (a denoising
+pass: no token), and beside it the clean block before it, whose commit
+rides that pass and yields the block's tokens.
 
 ``make_decode_step`` builds ONE jitted function that advances every
 resident sequence by one token: embedding lookup, all transformer
@@ -483,9 +484,11 @@ def decode_logits_tokenwise(params, model, dcfg: DecodeConfig,
 
 
 # ------------------------------------------------- generation by blocks
-#: what a slot's pass of a block step was (column ``W`` of the step's
-#: readback): nothing (an inactive slot, or one past its last block), a
-#: denoising pass, a commit pass
+#: the kind of a block-forward.  Column ``W`` of the block step's
+#: readback says what the slot's OPEN half did: nothing (an inactive
+#: slot, or one past its last block) or a denoising pass; a commit rides
+#: the step's held half and has a column of its own (``W + 2``), and the
+#: scheduler writes it into a request's trace as a ``BLOCK_COMMIT`` row
 BLOCK_IDLE, BLOCK_DENOISE, BLOCK_COMMIT = -1, 0, 1
 
 
@@ -498,9 +501,13 @@ def unmask_counts(block_length: int, denoising_steps: int):
 
 
 def block_passes(block_length: int, denoising_steps: int, masked: int):
-    """Passes a block of ``masked`` masked positions takes under the
-    static schedule: the denoising passes until none is left, and the
-    commit pass."""
+    """Forwards a block of ``masked`` masked positions takes under the
+    static schedule, which is also the rows it leaves in a request's
+    ``block_trace``: the denoising passes until none is left, and the
+    commit.  Every commit but a request's last rides the next block's
+    first denoising pass (:func:`make_block_step`), so a request of
+    ``n`` blocks holds its slot for the sum of these less ``n - 1``
+    steps."""
     left, passes = int(masked), 0
     for n in unmask_counts(block_length, denoising_steps):
         if left <= 0:
@@ -512,12 +519,19 @@ def block_passes(block_length: int, denoising_steps: int, masked: int):
 
 def init_block_state(max_batch: int, block_length: int, mask_id: int):
     """The block step's carried per-slot state, on the device: ``ids``
-    (B, W) the block's current ids (``mask_id`` where a position is
-    still to be generated), ``passes`` (B,) the denoising passes the
-    block has had, ``pos`` (B,) the block's first position."""
+    (B, W) the OPEN block's current ids (``mask_id`` where a position is
+    still to be generated), ``passes`` (B,) the denoising passes it has
+    had, ``pos`` (B,) its first position; and the HELD block: ``held``
+    (B, W) the ids of the clean block before it, at ``pos - W``, and
+    ``held_live`` (B,) bool, whether its keys and values are still to
+    be stored (set by the pass that left the block clean, cleared by the
+    next pass of the slot, which commits it, and by an admission into
+    the slot)."""
     return {"ids": jnp.full((max_batch, block_length), mask_id, jnp.int32),
             "passes": jnp.zeros((max_batch,), jnp.int32),
-            "pos": jnp.zeros((max_batch,), jnp.int32)}
+            "pos": jnp.zeros((max_batch,), jnp.int32),
+            "held": jnp.zeros((max_batch, block_length), jnp.int32),
+            "held_live": jnp.zeros((max_batch,), bool)}
 
 
 def _chosen(masked, conf, n_t, remasking: str, threshold: float):
@@ -559,31 +573,45 @@ def make_block_step(model, dcfg: DecodeConfig, return_logits: bool = False):
       are the device's to know;
     - ``steps`` (B,) int32: each slot's ``denoising_steps`` T (1..W);
       ``ends`` (B,): the position a slot's sequence ends at, rounded up
-      to a block: a slot whose block starts there is done, and its pass
-      is no pass (``BLOCK_IDLE``) whatever ``active`` says;
+      to a block: a slot whose open block starts there has no open
+      block, whatever ``active`` says;
     - ``active`` (B,) bool, ``page_tables`` (B, P), ``seeds`` (B,)
       uint32 (a slot's draw; row ``w`` of its block draws from ``seed +
       w``).
 
-    A live slot's pass: the block's W ids are forwarded at ``pos ..
-    pos + W - 1`` against the cached columns before them and their own
-    (the pass's keys and values are written over the block's columns in
-    place).  Where the block still holds a mask (a DENOISING pass), the
-    fused head gives every row its token and that token's confidence
+    A step forwards ``2W`` rows a slot, two halves that are live or
+    dead each on its own.  **The open half** (live iff ``active & pos <
+    ends``) is a DENOISING pass: the open block's W ids, a mask among
+    them, are forwarded at ``pos .. pos + W - 1`` against the cached
+    columns before them and their own (the pass's keys and values are
+    written over the block's columns in place); the fused head gives
+    every row its token and that token's confidence
     (:func:`~apex_tpu.ops.decode_sampling_pallas.fused_sample_confidence`;
     no (rows, V) logits in HBM; the mask's own row is left out of the
     draw and of the softmax, so a position is never unmasked into a
-    mask), and :func:`_chosen` positions take their tokens.  Where it holds none (the COMMIT pass: the keys and
-    values just written are those of the clean tokens) the slot moves on
-    to its next block: all masks, ``pos + W``.  ``out`` (B, W + 2)
-    int32 is what the host reads back: the block's ids after the pass
-    (a commit's: the clean block), the pass's kind
-    (``BLOCK_IDLE``/``BLOCK_DENOISE``/``BLOCK_COMMIT``) and how many
-    positions it unmasked.
+    mask), and :func:`_chosen` positions take their tokens.  A pass that
+    leaves the block without a mask moves the slot on AT ONCE: the clean
+    block becomes the HELD block, the open block all masks at ``pos +
+    W``.  **The held half** (live iff ``active & held_live``) is the
+    held block's COMMIT: its clean ids forwarded at ``pos - W .. pos -
+    1`` so that the keys and values that stay in the cache are those of
+    the clean tokens, in the same forward as the open block's first
+    denoising pass, which sees them (``decode_block``: both blocks'
+    columns are written before any row attends).  A commit needs no
+    logits: only the open rows go to the head.  So a block costs its
+    slot T steps, not T + 1; a request's last block has no block after
+    it (``pos == ends``: the open half dead) and commits in a step of
+    its own.
+
+    ``out`` (B, 2W + 3) int32 is what the host reads back: the open
+    block's ids after the pass, the pass's kind (``BLOCK_DENOISE``, or
+    ``BLOCK_IDLE`` where the open half was dead), how many positions it
+    unmasked; then whether a commit rode the step (0/1) and the ids it
+    committed.
 
     With ``return_logits=True`` the step returns ``(pools, blocks,
-    logits (B, W, V))`` float32 and leaves the block state as it was:
-    the parity probe."""
+    logits (B, W, V))`` float32, the open rows', and leaves the block
+    state as it was: the parity probe."""
     from apex_tpu.inference.kv_cache import COUNTERS
 
     m = served(model)
@@ -595,14 +623,18 @@ def make_block_step(model, dcfg: DecodeConfig, return_logits: bool = False):
     names = tuple(m.counter_names)
     own = [names.index(n) for n in ("blk_denoise_passes",
                                     "blk_commit_passes",
-                                    "blk_tokens_unmasked")]
+                                    "blk_tokens_unmasked",
+                                    "blk_commits_fused")]
 
     def step(params, pools, blocks, steps, ends, active, page_tables, seeds):
         ids, passes, pos = blocks["ids"], blocks["passes"], blocks["pos"]
+        held = blocks["held"]
         B = ids.shape[0]
-        live = active & (pos < ends)
+        denoise = active & (pos < ends)
+        commit = active & blocks["held_live"]
         hidden, pools = m.decode_block(
-            params, ids.reshape(B * W), pos, live, pools, page_tables,
+            params, jnp.concatenate([held, ids], axis=1).reshape(2 * B * W),
+            pos, jnp.stack([commit, denoise], axis=1), pools, page_tables,
             dcfg.attn_impl)
         if return_logits:
             logits = jnp.matmul(hidden.astype(jnp.float32),
@@ -616,28 +648,30 @@ def make_block_step(model, dcfg: DecodeConfig, return_logits: bool = False):
             impl=dcfg.sample_impl,
             dot_dtype=dcfg.sample_dot_dtype)
         masked = ids == mask_id
-        denoise = live & jnp.any(masked, axis=1)
-        commit = live & ~denoise
         T = jnp.clip(steps.astype(jnp.int32), 1, W)
         n_t = W // T + (passes < W % T).astype(jnp.int32)
         chosen = _chosen(masked, conf.reshape(B, W), n_t, m.remasking,
                          float(m.confidence_threshold)) & denoise[:, None]
         after = jnp.where(chosen, x0.reshape(B, W), ids)
         unmasked = jnp.sum(chosen, axis=1, dtype=jnp.int32)
-        kind = jnp.where(commit, BLOCK_COMMIT,
-                         jnp.where(denoise, BLOCK_DENOISE, BLOCK_IDLE))
+        clean = denoise & ~jnp.any(after == mask_id, axis=1)
+        kind = jnp.where(denoise, BLOCK_DENOISE, BLOCK_IDLE)
         out = jnp.concatenate(
-            [after, kind[:, None].astype(jnp.int32), unmasked[:, None]],
-            axis=1)
+            [after, kind[:, None].astype(jnp.int32), unmasked[:, None],
+             commit[:, None].astype(jnp.int32), held], axis=1)
         blocks = {
-            "ids": jnp.where(commit[:, None], mask_id, after),
-            "passes": jnp.where(commit, 0, passes + denoise),
-            "pos": pos + W * commit.astype(jnp.int32)}
+            "ids": jnp.where(clean[:, None], mask_id, after),
+            "passes": jnp.where(clean, 0, passes + denoise),
+            "pos": pos + W * clean.astype(jnp.int32),
+            "held": jnp.where(clean[:, None], after, held),
+            # committed by this pass, unless the pass left a new one
+            "held_live": clean | (blocks["held_live"] & ~active)}
         if COUNTERS in pools:
             add = jnp.zeros((len(names),), jnp.int32).at[jnp.asarray(own)] \
                 .set(jnp.stack([jnp.sum(denoise, dtype=jnp.int32),
                                 jnp.sum(commit, dtype=jnp.int32),
-                                jnp.sum(unmasked)]))
+                                jnp.sum(unmasked),
+                                jnp.sum(commit & denoise, dtype=jnp.int32)]))
             pools = dict(pools, **{COUNTERS: pools[COUNTERS] + add})
         return pools, blocks, out
 

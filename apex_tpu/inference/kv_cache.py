@@ -688,20 +688,34 @@ def write_prompt_windowed(pools, pooled, own, page_table_row, prompt_len,
 
 def write_block_pools(pools, news, page_tables, positions, active,
                       width: int, layer=None, impl="auto"):
-    """Write a BLOCK of ``width`` consecutive columns a slot into ONE
-    layer of the pools, in place: what a block-generating step does
-    every pass (it REWRITES its block's columns: a denoising pass's keys
-    and values are overwritten by the next pass's and at last by the
-    commit's, which are those of the clean tokens).
+    """Write a BLOCK of ``width`` consecutive columns a slot, or TWO
+    blocks side by side, into ONE layer of the pools, in place: what a
+    block-generating step does every pass.  It REWRITES its open
+    block's columns (a denoising pass's keys and values are overwritten
+    by the next pass's) and, beside them, stores those of the HELD
+    block, the clean block before it, which are the ones that stay
+    (``inference.decode.make_block_step``).
 
     As :func:`write_decode_pools` with ``width``, for blocks that start
-    on a multiple of ``width``, ``width`` a divisor of the page: a block
-    then lies in one page, and the kernel path reads and writes that
-    page's tile ONCE a slot (``pool_write_block_pallas``).  ``news``:
-    one (B * width, H_kv, D) array a pool, a slot's rows consecutive;
-    ``page_tables``: (B, P); ``positions``: (B,) each slot's BLOCK
-    START; ``active``: (B,) bool.  Returns the pools, as a tuple in the
-    order given."""
+    on a multiple of ``width``, ``width`` a divisor of the page, so that
+    a block lies in one page.  ``active``: (B,) bool, one block a slot
+    from ``positions``, or **(B, n)**: ``n`` consecutive blocks a slot
+    from ``positions`` (the block step: ``n = 2``, the held block at
+    ``positions``, live iff ``active[:, 0]``, the open one at
+    ``positions + width``; ``positions`` may be ``-width`` where a slot
+    has no block before its first, which is then dead).  ``news``: one
+    (B * n * width, H_kv, D) array a pool, a slot's rows consecutive;
+    ``page_tables``: (B, P).
+
+    The kernel path keeps ``apex_kv_write``'s rule, one grid step a
+    tile and never two: a slot's blocks lie in ONE page unless the last
+    of them starts a page, so the slot hands the kernel the page of its
+    LAST column with the columns of every block that lies in it
+    (``pool_write_block_pallas``: a source of ``n * width`` columns, a
+    lane naming the column it takes), and for ``n > 1`` the page of its
+    FIRST column as a second tile only where that is another page, else
+    the garbage page (a dead step).  A dead block writes nothing.
+    Returns the pools, as a tuple in the order given."""
     from apex_tpu.ops.decode_attention_pallas import stacked_pools
 
     one_layer = pools[0].ndim == 4
@@ -709,27 +723,52 @@ def write_block_pools(pools, news, page_tables, positions, active,
     news = tuple(news)
     _, num_pages, h_kv, D, page_size = pools[0].shape
     B = page_tables.shape[0]
-    if news[0].shape[0] != B * width or page_size % width:
+    blocks = active.reshape(B, -1)
+    C = blocks.shape[1] * width
+    if news[0].shape[0] != B * C or page_size % width \
+            or C > page_size + width:
         raise ValueError(
             f"rows ({news[0].shape[0]}) must equal page-table rows ({B}) x "
-            f"width ({width}), and width divide the page ({page_size})")
+            f"blocks ({blocks.shape[1]}) x width ({width}), width divide "
+            f"the page ({page_size}) and a slot's blocks lie in two pages "
+            f"at most")
     positions = positions.astype(jnp.int32)
+    column = jnp.arange(C, dtype=jnp.int32)
+    live = jnp.repeat(blocks, width, axis=1)                    # (B, C)
 
     def xla_impl():
-        rows = (positions[:, None]
-                + jnp.arange(width, dtype=jnp.int32)[None]).reshape(-1)
         return write_decode_pools(
-            pools, news, page_tables, rows, jnp.repeat(active, width),
-            layer=layer, width=width, impl="xla")
+            pools, news, page_tables,
+            (positions[:, None] + column[None]).reshape(-1),
+            live.reshape(-1), layer=layer, width=C, impl="xla")
 
     def kernel_impl():
         from apex_tpu.ops.kv_write_pallas import pool_write_block_pallas
 
-        dest, first = _row_targets(page_tables, positions, active,
-                                   page_size, num_pages)
+        # tile 0: the page of the slot's last column; tile 1 (two blocks
+        # or more): the page of its first, where that is another
+        pages = (positions + C - 1)[:, None] // page_size
+        if C > width:
+            pages = jnp.concatenate(
+                [pages, positions[:, None] // page_size], axis=1)
+        tiles = pages.shape[1]
+        first = positions[:, None] - pages * page_size   # column 0's lane
+        lane = first[:, :, None] + column[None, None]    # (B, tiles, C)
+        writes = live[:, None] & (lane >= 0) & (lane < page_size)
+        if tiles > 1:
+            writes = writes.at[:, 1].set(
+                writes[:, 1] & (pages[:, 1] != pages[:, 0])[:, None])
+        dest, writes = _tile_targets(page_tables, pages, writes, num_pages)
+        # every slot's tile 0, then every slot's tile 1: the dead second
+        # tiles, all at the garbage page, follow one another, and a
+        # block whose index does not change is neither fetched nor
+        # written back
+        by_tile = lambda a: jnp.swapaxes(a, 0, 1).reshape(
+            (B * tiles,) + a.shape[2:])
         return pool_write_block_pallas(
-            pools, [x.reshape(1, B, width, h_kv, D) for x in news], dest,
-            first, active & (dest != GARBAGE_PAGE), layer,
+            pools, [jnp.tile(x.reshape(1, B, C, h_kv, D),
+                             (1, tiles, 1, 1, 1)) for x in news],
+            by_tile(dest), by_tile(first), by_tile(writes), layer,
             interpret=(impl == "interpret"))
 
     pools = _write(impl, news[0], pools[0], kernel_impl, xla_impl)

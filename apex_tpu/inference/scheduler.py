@@ -46,17 +46,22 @@ this scheduler fills its slots —
 - **block generation** (a served model that declares ``block_length``
   W: generation by diffusion over blocks): the third kind of step.  A
   slot stays at one BLOCK of W positions for several steps: each step
-  forwards the block's W ids (some of them the model's mask id) and is
-  a DENOISING pass, which unmasks some positions and yields no token,
-  or, once the block holds no mask, its COMMIT pass, which stores the
-  clean block's keys and values and yields the block's tokens at once
-  (``W``, or ``W - r`` for the first block of a prompt that ends ``r``
-  positions into a block); the slot then moves to the next block.  The
-  block's state lives on the device, so the one step in flight
-  survives: under the static strategies the host knows every slot's
-  passes in advance, under ``low_confidence_dynamic`` it learns of a
-  block's end at the readback and may have launched one slot-step too
-  many (``stats["wasted_slot_steps"]``; the device drops it).
+  forwards the block's W ids (some of them the model's mask id) as a
+  DENOISING pass, which unmasks some positions and yields no token.
+  The pass that leaves the block without a mask moves the slot on to
+  its next block on the device, and the clean block's COMMIT, the
+  forward that stores its clean tokens' keys and values, rides the next
+  step beside the new block's first denoising pass (``2W`` rows a slot
+  a step; a request's last block commits in a step of its own).  The
+  commit's readback yields the block's tokens at once (``W``, or ``W -
+  r`` for the first block of a prompt that ends ``r`` positions into a
+  block): a token is emitted only once its keys and values are the
+  cache's.  The block's state lives on the device, so the one step in
+  flight survives: under the static strategies the host knows every
+  slot's steps in advance (its blocks' denoising passes and ONE more),
+  under ``low_confidence_dynamic`` it learns of a block's end at the
+  readback and may have launched one slot-step too many
+  (``stats["wasted_slot_steps"]``; the device drops it).
   Admission reserves ``prompt + max_new_tokens`` rounded up to a block,
   the prefill stores the prompt's whole blocks and samples nothing, and
   a request is evicted at the commit of its last block, having emitted
@@ -141,7 +146,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.inference.decode import (
-    BLOCK_COMMIT, BLOCK_IDLE, DecodeConfig, block_passes, init_block_state,
+    BLOCK_COMMIT, BLOCK_DENOISE, DecodeConfig, block_passes, init_block_state,
     make_block_prefill, make_block_step, make_decode_step, make_prefill,
     make_prefill_chunk, make_sample_head, make_verify_step, served,
 )
@@ -214,13 +219,15 @@ class Completion:
     best-effort generation was evicted-and-requeued on the way.
 
     Of a block-generating model the tokens of one block come out
-    together, at the block's commit pass: they SHARE one stamp in
-    ``token_times`` (so the gaps inside a block are 0 and the gap
-    between blocks is the block's passes), ``token_times[0]`` is the
-    first block's commit (its prefill samples nothing).  Where the
-    request asked (``Request.record_passes``), ``block_trace`` holds
-    every pass the request's slot made, in order: ``(block start,
-    row)``, ``row`` the W ids after the pass, then the pass's kind
+    together, at the readback of the block's commit: they SHARE one
+    stamp in ``token_times`` (so the gaps inside a block are 0 and the
+    gap between blocks is the next block's denoising passes),
+    ``token_times[0]`` is the first block's commit (its prefill samples
+    nothing).  Where the request asked (``Request.record_passes``),
+    ``block_trace`` holds every block-forward of the request, in order
+    (a commit that rode the next block's pass comes before that pass):
+    ``(block start, row)``, ``row`` the W ids after the pass, then
+    the pass's kind
     (``inference.decode.BLOCK_DENOISE``/``BLOCK_COMMIT``) and how many
     positions it unmasked.  None otherwise, and for any other model."""
 
@@ -280,16 +287,19 @@ class _Carry:
 class _BlockPlan:
     """A block-generating slot's progress, as the host knows it:
     ``start`` the first block's first position, ``blocks`` how many the
-    request has, ``committed`` how many of them a readback has shown
-    committed, ``to_launch`` the passes the whole request takes where
-    the strategy fixes them in advance (None: learnt at the readback;
-    ``_Slot.launched`` counts the passes launched), ``trace`` every pass
-    read back of a request that asked (``Request.record_passes``, for
-    ``Completion.block_trace``), else None."""
+    request has, ``opened`` how many of them a readback has shown clean
+    (the open block is the next), ``committed`` how many committed,
+    ``to_launch`` the steps the whole request takes where the strategy
+    fixes them in advance (None: learnt at the readback;
+    ``_Slot.launched`` counts the steps launched), ``trace`` every
+    block-forward read back of a request that asked
+    (``Request.record_passes``, for ``Completion.block_trace``), else
+    None."""
 
     start: int
     blocks: int
     to_launch: Optional[int]
+    opened: int = 0
     committed: int = 0
     trace: Optional[List[tuple]] = None
 
@@ -317,8 +327,8 @@ class _InFlight:
     """A plain decode step launched and not read back: its
     ``next_tokens`` as the device holds them, and the slots it owes a
     token (a slot that ends on ``eos_id`` meanwhile is struck).  Of a
-    block step ``tokens`` is its (B, W + 2) readback and ``slots`` the
-    slots it forwarded a block for."""
+    block step ``tokens`` is its (B, 2W + 3) readback and ``slots`` the
+    slots it was launched for."""
 
     tokens: Any
     slots: np.ndarray              # (B,) bool
@@ -345,10 +355,13 @@ def _set_token(tokens, slot, token):
 @partial(jax.jit, donate_argnums=(0,))
 def _set_block(blocks, slot, ids, pos):
     """An admitted request's first block into the device's block state:
-    what is left of the prompt past its whole blocks, then masks."""
-    return {"ids": blocks["ids"].at[slot].set(ids),
-            "passes": blocks["passes"].at[slot].set(0),
-            "pos": blocks["pos"].at[slot].set(pos)}
+    what is left of the prompt past its whole blocks, then masks; no
+    block is held (what the slot's last tenant left is dropped)."""
+    return dict(blocks,
+                ids=blocks["ids"].at[slot].set(ids),
+                passes=blocks["passes"].at[slot].set(0),
+                pos=blocks["pos"].at[slot].set(pos),
+                held_live=blocks["held_live"].at[slot].set(False))
 
 
 class ContinuousBatchingScheduler:
@@ -477,8 +490,8 @@ class ContinuousBatchingScheduler:
             # dropped because their sequence had ended on eos_id
             "decode_overlapped": 0, "decode_settles": 0,
             "wasted_slot_steps": 0,
-            # a block-generating model's slot-steps read back, and how
-            # many of them were commit passes
+            # a block-generating model's block-forwards read back (a
+            # slot's step may hold two), and how many of them were commits
             "block_passes": 0, "block_commits": 0,
             # launches after which a slot's window buffer starts again
             # from empty (a windowed cache only)
@@ -1097,7 +1110,10 @@ class ContinuousBatchingScheduler:
         read back: the span times the enqueue), what is left of it
         opens the first block beside masks in the device's block state,
         and the slot is armed for block steps.  Its first tokens come
-        with that block's commit."""
+        with that block's commit.  Where the strategy fixes the passes,
+        the steps to launch are the blocks' denoising passes and ONE
+        more: every commit but the last rides the next block's first
+        pass (``block_passes`` counts a commit a block)."""
         s = self._slots[slot]
         req, W = s.request, self._block
         plen = len(req.prompt)
@@ -1109,7 +1125,7 @@ class ContinuousBatchingScheduler:
         s.block = _BlockPlan(
             start=keep, blocks=blocks,
             to_launch=(block_passes(W, steps, W - (plen - keep))
-                       + (blocks - 1) * block_passes(W, steps, W))
+                       + (blocks - 1) * (block_passes(W, steps, W) - 1))
             if fixed else None,
             trace=[] if req.record_passes else None)
         if keep:
@@ -1548,8 +1564,8 @@ class ContinuousBatchingScheduler:
                       in_flight=overlapped)
                  if traced else {})
         if traced and W is not None:
-            # rows the launched step forwards
-            attrs["block_rows"] = W * int(live.sum()) if launching else 0
+            # rows the launched step carries: two blocks a live slot
+            attrs["block_rows"] = 2 * W * int(live.sum()) if launching else 0
         next_tokens = None
         with _tracing.span("serve.decode_step", **attrs) as sp:
             t_open = t_up = t_enq = time.perf_counter()
@@ -1608,68 +1624,82 @@ class ContinuousBatchingScheduler:
             self._emit(prev, next_tokens)
         return True
 
-    def _block_tokens(self, s: _Slot, row: np.ndarray) -> List[int]:
-        """The tokens the commit of ``s``'s current block emits: the
-        block's positions from the prompt's end to the request's (the
-        first block holds what was left of the prompt, the last one a
-        surplus that is generated and dropped)."""
+    def _block_tokens(self, s: _Slot, ids: np.ndarray) -> List[int]:
+        """The tokens the commit of ``s``'s next block to commit emits,
+        ``ids`` the block as committed: its positions from the prompt's
+        end to the request's (the first block holds what was left of the
+        prompt, the last one a surplus that is generated and dropped)."""
         start = s.block.start + s.block.committed * self._block
         plen = len(s.request.prompt)
         lo = max(plen - start, 0)
         hi = min(plen + s.request.max_new_tokens - start, self._block)
-        return [int(t) for t in row[lo:hi]]
+        return [int(t) for t in ids[lo:hi]]
 
     def _block_attrs(self, step: _InFlight, out: np.ndarray) -> Dict:
-        """Of the block step read back, for its span: the slots whose
-        pass was a commit, the positions unmasked and the tokens it
-        emits."""
+        """Of the block step read back, for its span: the commits it
+        held, those of them that rode a denoising pass of their slot,
+        the positions unmasked and the tokens it emits."""
         W = self._block
         rows = out[step.slots]
+        commit = rows[:, W + 2] == 1
         return dict(
-            commits=int((rows[:, W] == BLOCK_COMMIT).sum()),
+            commits=int(commit.sum()),
+            fused_commits=int((commit & (rows[:, W] == BLOCK_DENOISE)).sum()),
             unmasked=int(rows[:, W + 1].sum()),
-            emitted=sum(len(self._block_tokens(self._slots[i], out[i]))
+            emitted=sum(len(self._block_tokens(self._slots[i],
+                                               out[i, W + 3:]))
                         for i in np.flatnonzero(step.slots)
-                        if out[i, W] == BLOCK_COMMIT))
+                        if out[i, W + 2] == 1))
 
     def _emit_blocks(self, step: _InFlight, out: np.ndarray) -> None:
-        """A block step's readback -> end of the iteration: a pass
-        into its slot's trace where the request keeps one; a commit's
-        tokens emitted under ONE stamp (now, the moment they are on the
-        host), the slot moved to its next block, or evicted at the
-        commit of its last."""
+        """A block step's readback -> end of the iteration.  A slot's
+        row holds up to two block-forwards, counted and kept (where the
+        request keeps a trace) one by one: the COMMIT of the block it
+        held, whose tokens are emitted under ONE stamp (now, the moment
+        they are on the host) and whose request is evicted if the block
+        was its last; then the open block's denoising pass, which emits
+        nothing (the pass that leaves a block clean makes it the held
+        one: its commit is in the next step's row)."""
         W = self._block
         with _tracing.span("serve.emit") as emit_span:
             now = self._time()
             self.stats["decode_steps"] += 1
             self._record_occupancy()
             tokens, evicted = 0, self.stats["evicted"]
-            passes = commits = 0
+            denoised = commits = 0
             gaps: Dict[str, list] = {}
             for i in np.flatnonzero(step.slots):
                 s, row = self._slots[i], out[i]
-                if row[W] == BLOCK_IDLE:    # past its last block: the
-                    continue                # device dropped the pass
                 plan = s.block
+                if row[W + 2] == 1:
+                    held = row[W + 3:]
+                    if plan.trace is not None:
+                        plan.trace.append((
+                            plan.start + plan.committed * W,
+                            np.concatenate([held, [BLOCK_COMMIT, 0]])))
+                    for tok in self._block_tokens(s, held):
+                        if not s.token_times:
+                            self._observe_first_token(s, now)
+                            s.generated.append(tok)
+                            s.token_times.append(now)
+                        else:
+                            self._emit_token(s, tok, now, gaps)
+                        tokens += 1
+                    commits += 1
+                    plan.committed += 1
+                    self._positions[i] = plan.start + plan.committed * W
+                    if plan.committed >= plan.blocks:
+                        self._evict(i)
+                        continue
+                if row[W] != BLOCK_DENOISE:     # no open block, or the
+                    continue                    # device dropped the pass
+                denoised += 1
                 if plan.trace is not None:
-                    plan.trace.append((plan.start + plan.committed * W, row))
-                passes += 1
-                if row[W] != BLOCK_COMMIT:
-                    continue
-                commits += 1
-                for tok in self._block_tokens(s, row):
-                    if not s.token_times:
-                        self._observe_first_token(s, now)
-                        s.generated.append(tok)
-                        s.token_times.append(now)
-                    else:
-                        self._emit_token(s, tok, now, gaps)
-                    tokens += 1
-                plan.committed += 1
-                self._positions[i] = plan.start + plan.committed * W
-                if plan.committed >= plan.blocks:
-                    self._evict(i)
-            self.stats["block_passes"] += passes
+                    plan.trace.append((plan.start + plan.opened * W,
+                                       row[:W + 2]))
+                if self.model.mask_id not in row[:W]:
+                    plan.opened += 1
+            self.stats["block_passes"] += denoised + commits
             self.stats["block_commits"] += commits
             self._observe_gaps(gaps)
             emit_span.set(tokens=tokens,

@@ -10,17 +10,22 @@ of them:
 - **a step forwards a BLOCK of ``block_length`` positions a slot**, some
   of them the mask token, and yields no token, some tokens or a whole
   block (:func:`apex_tpu.inference.decode.make_block_step` has the
-  procedure; this file has the forward it runs);
+  procedure; this file has the forward it runs), and beside it the
+  block BEFORE it, clean, whose commit rides the same forward;
 - **visibility is causal by block, not by token**: key ``j`` is visible
   to query ``i`` iff ``j // W <= i // W``.  Inside a block attention is
-  bidirectional, so a step's ``W`` rows of a slot see the SAME columns,
-  the cached ones and their own (``block_decode_attention``: the live
-  pages read once a slot a layer), and the prompt is prefilled under
-  the same mask (``block_causal_attention``);
+  bidirectional, so a block's ``W`` rows see the SAME columns, the
+  cached ones and their own (``block_decode_attention``: a slot's two
+  blocks ride one walk of its live pages a layer, a length a block),
+  and the prompt is prefilled under the same mask
+  (``block_causal_attention``);
 - **every pass rewrites its block's keys and values in place**
   (``write_block_pools``): a denoising pass's are overwritten by the
-  next pass's and at last by the commit pass's, those of the clean
-  tokens, which is what later blocks attend to;
+  next pass's and at last by the COMMIT's, those of the clean tokens,
+  which is what later blocks attend to.  The commit is no pass of its
+  own: the clean block is HELD and forwarded beside the block that
+  opens after it, whose rows see its clean columns (written in that
+  layer before any row attends);
 - **the logits at position ``i`` predict the token AT ``i``** (a mask
   predicts itself; no shift by one);
 - **a softmax router**: ``p = softmax(x Wr)`` in float32 over all
@@ -54,21 +59,26 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops.rope import apply_rope, apply_rope_at
-from apex_tpu.transformer.expert_parallel import held_experts_ffn
+from apex_tpu.transformer.expert_parallel import (
+    expert_buffer_rows, held_experts_ffn,
+)
 
 __all__ = ["COUNTER_NAMES", "EXPERT_LEAVES", "FLOAT32_LEAVES", "REMASKING",
            "SDARMoEConfig", "SDARMoEServed", "forward", "forward_block",
            "init_params", "param_shapes"]
 
-#: the device-side counters, in the order of the carried vector: rows
-#: forwarded by block steps (``block_length`` a live slot a step),
-#: slot-steps that were denoising passes and commit passes, tokens
-#: unmasked (those three are the step's: ``make_block_step``), the
-#: columns the block attention had to read summed over live slots (every
-#: layer reads as many), and the expert layer's three, summed over the
+#: the device-side counters, in the order of the carried vector: live
+#: rows forwarded by block steps (``block_length`` a live half of a
+#: slot), block-forwards that were denoising passes and commits (a step
+#: of a slot may be one of each), tokens unmasked (those three are the
+#: step's: ``make_block_step``), the columns a slot's one walk had to
+#: read summed over live slots (the longer of its two lengths; every
+#: layer reads as many), the commits that rode a denoising pass of their
+#: slot (the step's too), and the expert layer's three, summed over the
 #: layers, as the latent family keeps them
 COUNTER_NAMES = ("blk_rows_forwarded", "blk_denoise_passes",
                  "blk_commit_passes", "blk_tokens_unmasked", "blk_kv_cols",
+                 "blk_commits_fused",
                  "moe_assignments_held", "moe_assignments_all",
                  "moe_experts_hit")
 #: leaves kept in float32 whatever ``param_dtype``
@@ -259,20 +269,32 @@ def _qkv(x, p, c: SDARMoEConfig):
     return q, k, y[:, nq + nk:].reshape(-1, c.num_key_value_heads, d)
 
 
-def _rest(h, attn, p, experts, index, c: SDARMoEConfig, token_mask, impl):
+def _rest(h, attn, p, experts, index, c: SDARMoEConfig, token_mask, impl,
+          compact=False):
     """The block after its attention: ``attn`` (T, heads, d) through
     ``wo`` into the stream, then the held experts' routed part.
     ``experts``: the STACKED expert leaves, ``index`` this layer's place
-    in them.  Returns ``(h, counts)``."""
+    in them.  ``compact``: the held share of the rows' assignments
+    compacted and walked in chunks (``held_experts_ffn(buffer_rows=)``),
+    so that the layer's glue costs what the held assignments cost (an
+    eighth of them at 16 experts of 128), not what all ``top_k`` a row
+    would: the block step's form, 48 times a step.  The prefill keeps
+    the whole buffer: a second form in its four programs costs every
+    start their tracing (0.9 s of a 12 s set-up: my chip runs, PR 45)
+    for a pass that runs once a request.  Returns ``(h, counts)``."""
     cd = c.compute_dtype
     h = h + jnp.matmul(attn.reshape(attn.shape[0], -1).astype(cd),
                        p["wo"].astype(cd))
     x = _rms_norm(h, p["ffn_norm"], c.rms_norm_eps)
+    A = x.shape[0] * c.num_experts_per_tok
     routed, counts = held_experts_ffn(
         x, dict(experts, router=p["router"]), c.held,
         top_k=c.num_experts_per_tok, n_group=1, topk_group=1, scale=1.0,
         token_mask=token_mask, layer=index, softmax=True,
-        impl={"auto": "auto", "pallas": "pallas"}.get(impl, "xla"))
+        impl={"auto": "auto", "pallas": "pallas"}.get(impl, "xla"),
+        buffer_rows=expert_buffer_rows(
+            x.shape[0], c.num_experts_per_tok, len(c.held), c.num_experts,
+            multiple=min(512, A)) if compact else None)
     return h + routed, counts
 
 
@@ -336,28 +358,42 @@ def forward(params, tokens, config: SDARMoEConfig, attn_impl: str = "auto",
 
 def forward_block(params, tokens, positions, active, pools, page_tables,
                   config: SDARMoEConfig, attn_impl: str = "auto"):
-    """One BLOCK a slot over the paged cache: ``tokens`` (B * W,) the
-    slots' block states side by side (a slot's ``W`` ids consecutive,
-    masks among them), ``positions`` (B,) each slot's block START (a
-    multiple of ``W``), ``active`` (B,) bool.
+    """TWO BLOCKS a slot over the paged cache, the HELD block and the
+    OPEN one after it: ``tokens`` (B * 2W,) the slots' rows side by
+    side, a slot's ``W`` held ids (a clean block whose keys and values
+    are still to be stored) then its ``W`` open ids (masks among them);
+    ``positions`` (B,) each slot's OPEN block's start (a multiple of
+    ``W``; the held block sits at ``positions - W``); ``active`` (B, 2)
+    bool: whether the held half and the open half are live.
 
     ``pools``: ``"k"`` and ``"v"``, (layers, pages, kv heads, d,
-    page_size), and optionally ``"counters"``.  A layer writes the
-    block's ``W`` keys and values into its page over whatever an earlier
-    pass left there (``apex_kv_write``) and every row attends over the
-    ``position + W`` columns: the cached blocks and its own
-    (``apex_decode_attention``, the block folded into the group).
-    Returns ``(hidden (B * W, H), pools)``, hidden final-normed."""
+    page_size), and optionally ``"counters"``.  A layer first writes
+    both blocks' keys and values into their page or two pages
+    (``apex_kv_write``: the open block's over whatever an earlier pass
+    left there, the held block's for good), then all ``2W`` rows attend
+    in one walk of the slot's pages (``apex_decode_attention``, the rows
+    folded into the group): the held rows over ``position`` columns, the
+    cached blocks and their own, the open rows over ``position + W``,
+    those and the held block's CLEAN columns written in this very
+    layer, and their own.  So the held block's columns are what a
+    forward of its clean tokens alone would have stored, and the open
+    block sees them as if that forward had run a step before.  Returns
+    ``(hidden (B * W, H), pools)``: the OPEN rows, final-normed (a held
+    row needs no logits)."""
     from apex_tpu.inference.kv_cache import COUNTERS, write_block_pools
     from apex_tpu.ops.decode_attention_pallas import block_decode_attention
 
     c = config
     W = c.block_length
+    B = positions.shape[0]
     positions = positions.astype(jnp.int32)
-    rows = (positions[:, None] + jnp.arange(W, dtype=jnp.int32)[None]) \
+    start = positions - W                   # the held block's
+    rows = jnp.maximum(start[:, None]
+                       + jnp.arange(2 * W, dtype=jnp.int32)[None], 0) \
         .reshape(-1)
-    lengths = jnp.where(active, positions + W, 0).astype(jnp.int32)
-    live_rows = jnp.repeat(active, W)
+    lengths = jnp.where(active, jnp.stack([positions, positions + W], 1),
+                        0).astype(jnp.int32)
+    live_rows = jnp.repeat(active, W, axis=1).reshape(-1)
     rest, experts = _layers(params)
 
     def body(carry, inp):
@@ -368,13 +404,13 @@ def forward_block(params, tokens, positions, active, pools, page_tables,
         q = apply_rope_at(q, rows, c.rope_theta)
         k = apply_rope_at(k, rows, c.rope_theta)
         k_pool, v_pool = write_block_pools(
-            (k_pool, v_pool), (k, v), page_tables, positions, active, W,
+            (k_pool, v_pool), (k, v), page_tables, start, active, W,
             layer=index, impl=attn_impl)
         attn = block_decode_attention(q, k_pool, v_pool, page_tables,
-                                      lengths, W, impl=attn_impl,
+                                      lengths, 2 * W, impl=attn_impl,
                                       layer=index)
         h, counts = _rest(h, attn, p, experts, index, c, live_rows,
-                          attn_impl)
+                          attn_impl, compact=True)
         return (h, k_pool, v_pool, counted + _count(counts)), None
 
     (h, k_pool, v_pool, counted), _ = jax.lax.scan(
@@ -383,13 +419,16 @@ def forward_block(params, tokens, positions, active, pools, page_tables,
         (rest, jnp.arange(c.num_hidden_layers, dtype=jnp.int32)))
     out = dict(pools, k=k_pool, v=v_pool)
     if COUNTERS in pools:
-        n_live = jnp.sum(active, dtype=jnp.int32)
+        # a slot's ONE walk reads its longer length, once a step
         add = jnp.zeros((len(COUNTER_NAMES),), jnp.int32) \
-            .at[COUNTER_NAMES.index("blk_rows_forwarded")].set(W * n_live) \
-            .at[COUNTER_NAMES.index("blk_kv_cols")].set(jnp.sum(lengths)) \
+            .at[COUNTER_NAMES.index("blk_rows_forwarded")] \
+            .set(W * jnp.sum(active, dtype=jnp.int32)) \
+            .at[COUNTER_NAMES.index("blk_kv_cols")] \
+            .set(jnp.sum(jnp.max(lengths, axis=1))) \
             .at[COUNTER_NAMES.index("moe_assignments_held"):].set(counted)
         out[COUNTERS] = pools[COUNTERS] + add
-    return _rms_norm(h, params["final_norm"], c.rms_norm_eps), out
+    open_rows = h.reshape(B, 2, W, -1)[:, 1].reshape(B * W, -1)
+    return _rms_norm(open_rows, params["final_norm"], c.rms_norm_eps), out
 
 
 # ----------------------------------------------------------- served model

@@ -97,6 +97,19 @@ def as_stacked_pools(k_pool, v_pool, layer):
     return k_pool, v_pool, layer
 
 
+def _lengths_per_row(lengths, group, width) -> int:
+    """How many lengths a sequence row brings: 1 (``lengths`` (B,)) or
+    2 ((B, 2): a length a half of the group)."""
+    if lengths.ndim == 1:
+        return 1
+    if lengths.ndim != 2 or lengths.shape[1] != 2 or width != 1 \
+            or group % 2:
+        raise ValueError(
+            f"lengths {lengths.shape} must be (B,) or, for an even group "
+            f"({group}) and width 1 ({width}), (B, 2)")
+    return 2
+
+
 def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
                          softmax_scale=None, width=1, layer=None):
     """Single-query attention over a paged KV cache, in XLA.
@@ -117,7 +130,10 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
     CLAMPED into the pool before the gather (a stale/garbage entry
     reads the reserved garbage page instead of wrapping).  ``lengths``:
     (B,) int32 valid cache positions per sequence (0 = inactive slot —
-    every position masks out and the output row is 0).
+    every position masks out and the output row is 0); or (B, 2): a
+    length a HALF of each kv head's group of query heads (the first
+    ``H // H_kv // 2`` of them the first length: two blocks of one
+    sequence folded into the group, :func:`block_decode_attention`).
 
     ``width`` > 1 is the verify/chunk layout: q rows come in groups of
     ``width`` CONSECUTIVE positions of one sequence (speculative
@@ -141,6 +157,7 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
         raise ValueError(
             f"q rows ({Bq}) must equal page-table rows ({B}) x width "
             f"({width})")
+    _lengths_per_row(lengths, group, width)
     pt = jnp.clip(page_table, 0, num_pages - 1)
     # (B, P, H_kv, D, page) -> (B, H_kv, S_max, D)
     k = k_pool[layer, pt].transpose(0, 2, 1, 4, 3) \
@@ -173,7 +190,10 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
         scores = jnp.einsum("bhd,bhtd->bht", qf, kf) / np.sqrt(D)
     else:
         scores = jnp.einsum("bhd,bhtd->bht", qf, kf) * softmax_scale
-    valid = t[None, None, :] < lengths[:, None, None]
+    # a length a sequence, or one a half of a group: then one a q head
+    lengths = lengths[:, None, None] if lengths.ndim == 1 else jnp.take(
+        lengths, np.arange(H) % group * 2 // group, axis=1)[:, :, None]
+    valid = t[None, None, :] < lengths
     scores = jnp.where(valid, scores, MASK_FILL_VALUE)
     probs = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bht,bhtd->bhd", probs.astype(v.dtype), v)
@@ -181,8 +201,7 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
     # uniform distribution over garbage pages; pin it to the kernel's
     # semantic (zero output).  Active rows always have >= 1 valid
     # position, so the training-parity expression above is untouched.
-    return jnp.where(lengths[:, None, None] > 0, ctx,
-                     jnp.zeros_like(ctx))
+    return jnp.where(lengths > 0, ctx, jnp.zeros_like(ctx))
 
 
 # ------------------------------------------------------------------ kernel
@@ -221,14 +240,41 @@ def _plan(rows, h_kv, group, head_dim, pages_per_seq, page_size, kv_dtype):
         else grid + (pages_per_seq,)
 
 
+def _half_lengths(len_ref, row):
+    """A row's two lengths, which ``len_ref`` holds side by side."""
+    two = lax.mul(row, np.int32(2))
+    return len_ref[two], len_ref[lax.add(two, np.int32(1))]
+
+
+def _longest(len_ref, row, halves):
+    """The columns a row's walk has to read: its length, or the longer
+    of its two halves'."""
+    if halves == 1:
+        return len_ref[row]
+    return lax.max(*_half_lengths(len_ref, row))
+
+
+def _row_lengths(len_ref, row, halves, group):
+    """``(longest, lengths)`` of a row: what the walk runs to, and what
+    :func:`_attend` masks by, a scalar or with two halves (1, group, 1),
+    the first half of the group's rows under the first length."""
+    if halves == 1:
+        length = len_ref[row]
+        return length, length
+    lo, hi = _half_lengths(len_ref, row)
+    rows = lax.broadcasted_iota(jnp.int32, (1, group, 1), 1)
+    return lax.max(lo, hi), jnp.where(rows < group // 2, lo, hi)
+
+
 def _kv_block_index(b, g, p, pt_ref, len_ref, layer_ref, *, width,
-                    pages_per_seq, page_size):
+                    pages_per_seq, page_size, halves=1):
     """The pool block of grid step ``(row b, head block g, page slot
     p)``.  A slot past the row's last live page names THAT page again,
     and the pipeline does not fetch a block whose index did not change:
     a whole page past a length is never read (a row without a live
     position names its table's first entry, one block at most)."""
-    last = jnp.maximum((len_ref[b] + page_size - 1) // page_size - 1, 0)
+    last = jnp.maximum(
+        (_longest(len_ref, b, halves) + page_size - 1) // page_size - 1, 0)
     slot = (b // width) * pages_per_seq + jnp.minimum(p, last)
     return (layer_ref[0], pt_ref[slot], g, 0, 0)
 
@@ -236,8 +282,9 @@ def _kv_block_index(b, g, p, pt_ref, len_ref, layer_ref, *, width,
 def _attend(q, k, v, first, length, m_ref, l_ref, acc_ref, *, denom, scale):
     """One page of a block's kv heads against their queries, batched
     over the heads on the MXU: ``q`` (h_blk, group, D), ``k``/``v``
-    (h_blk, D, page) whose first position is ``first``.  One step of
-    the online softmax (f32 running max/sum/accumulator in scratch)."""
+    (h_blk, D, page) whose first position is ``first``; ``length``
+    a scalar, or a length a group row (1, group, 1).  One step of the
+    online softmax (f32 running max/sum/accumulator in scratch)."""
     if k.dtype != q.dtype:
         # bf16 (or narrower) cache with an f32 query: widen the
         # cache read rather than rounding q down (APX306)
@@ -267,7 +314,7 @@ def _attend(q, k, v, first, length, m_ref, l_ref, acc_ref, *, denom, scale):
 def _walk_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
                  k_buf, v_buf, sem, m_ref, l_ref, acc_ref, state_ref, *,
                  h_blk, n_blk, rows, page_size, pages_per_seq, width, denom,
-                 scale):
+                 scale, halves):
     """One sequence row and one block of kv heads a grid step; the step
     walks the row's LIVE pages itself.  The pools stay in HBM; a page's
     k and v blocks are copied into one of two VMEM slots, the copy of
@@ -286,7 +333,9 @@ def _walk_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
     add, sub, mul, lt = lax.add, lax.sub, lax.mul, lax.lt
     b, g = pl.program_id(0), pl.program_id(1)
     layer = layer_ref[0]
-    length = len_ref[b]
+    # with two halves the walk runs to the longer length and each half
+    # of the group's rows masks by its own
+    length, lengths = _row_lengths(len_ref, b, halves, q_ref.shape[3])
     n = lax.min(lax.div(add(length, i32(page_size - 1)), i32(page_size)),
                 i32(pages_per_seq))
 
@@ -337,7 +386,8 @@ def _walk_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         nxt = lax.while_loop(
             lambda r: lax.bitwise_and(
                 lt(r, i32(rows)),
-                lax.le(len_ref[lax.min(r, i32(rows - 1))], i32(0))),
+                lax.le(_longest(len_ref, lax.min(r, i32(rows - 1)), halves),
+                       i32(0))),
             lambda r: add(r, i32(1)), add(b, i32(1)))
         has_next = lt(nxt, i32(rows))
         next_row, next_blk = lax.min(nxt, i32(rows - 1)), i32(0)
@@ -364,18 +414,21 @@ def _walk_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
             for dma in copies(0, 0, slot):
                 dma.wait()
             _attend(q_ref[0, 0], k_buf[slot], v_buf[slot],
-                    mul(i, i32(page_size)), length, m_ref, l_ref, acc_ref,
+                    mul(i, i32(page_size)), lengths, m_ref, l_ref, acc_ref,
                     denom=denom, scale=scale)
             return other
 
         state_ref[0] = lax.fori_loop(i32(0), n, page_step, first_slot)
         state_ref[1] = has_next.astype(jnp.int32)
-        o_ref[0, 0] = (acc_ref[:] / l_ref[:, :, 0:1]).astype(o_ref.dtype)
+        acc, l = acc_ref[:], l_ref[:, :, 0:1]
+        if halves > 1:      # a dead half beside a live one: l == 0
+            l = jnp.maximum(l, 1e-30)
+        o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
 
 
 def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
                         o_ref, m_ref, l_ref, acc_ref, *,
-                        page_size, pages_per_seq, denom, scale):
+                        page_size, pages_per_seq, denom, scale, halves):
     """The form for a page under 128 lanes: one sequence row and one
     block of kv heads; the sequential grid dim walks that row's page
     slots through VMEM, all of the block's heads a step.  Online
@@ -390,7 +443,7 @@ def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[b]
+    length, lengths = _row_lengths(len_ref, b, halves, q_ref.shape[3])
 
     # a page slot at/after the length holds no valid position: its block
     # index was clamped to the last live page, so nothing was fetched
@@ -399,7 +452,7 @@ def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
     # resident cache)
     @pl.when(p * page_size < length)
     def _compute():
-        _attend(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], p * page_size, length,
+        _attend(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], p * page_size, lengths,
                 m_ref, l_ref, acc_ref, denom=denom, scale=scale)
 
     @pl.when(p == pages_per_seq - 1)
@@ -413,7 +466,10 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
                                   interpret=False, layer=None):
     """The Pallas paged decode-attention launcher (see module doc).
 
-    Shapes as :func:`decode_attention_xla`.  The flattened page table,
+    Shapes as :func:`decode_attention_xla`; with ``lengths`` (B, 2)
+    the same kernels take two lengths a row: the walk runs to the longer
+    and each half of a group's query rows masks by its own (a half of
+    length 0 beside a live one gives zeros).  The flattened page table,
     the lengths and the layer ride as scalar-prefetch operands; the
     kernel reads one page's ``(h_blk, D, page_size)`` block of kv heads
     at a time out of the stacked pool, which is never sliced, copied or
@@ -437,6 +493,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
             f"q rows ({B}) must equal page-table rows ({n_seq}) x width "
             f"({width})")
     group = H // h_kv
+    halves = _lengths_per_row(lengths, group, width)
     h_blk, grid = _plan(B, h_kv, group, D, P, page_size, k_pool.dtype)
     qg = q.reshape(B, h_kv // h_blk, h_blk, group, D)
     # clamp BEFORE prefetch: the index map output becomes a DMA source
@@ -459,7 +516,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
         kernel = functools.partial(
             _walk_kernel, h_blk=h_blk, n_blk=grid[1], rows=B,
             page_size=page_size, pages_per_seq=P, width=width,
-            denom=float(np.sqrt(D)), scale=softmax_scale)
+            denom=float(np.sqrt(D)), scale=softmax_scale, halves=halves)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
@@ -476,10 +533,10 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
         kv_spec = pl.BlockSpec(
             (1, 1, h_blk, D, page_size),
             functools.partial(_kv_block_index, width=width, pages_per_seq=P,
-                              page_size=page_size))
+                              page_size=page_size, halves=halves))
         kernel = functools.partial(
             _decode_attn_kernel, page_size=page_size, pages_per_seq=P,
-            denom=float(np.sqrt(D)), scale=softmax_scale)
+            denom=float(np.sqrt(D)), scale=softmax_scale, halves=halves)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
@@ -495,7 +552,8 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name="apex_decode_attention",
-    )(pt, lengths.astype(jnp.int32),
+    )(pt, lengths.astype(jnp.int32) if halves == 1
+      else lengths.astype(jnp.int32).reshape(B * halves),
       jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool, v_pool)
     return out.reshape(B, H, D)
 
@@ -588,29 +646,37 @@ def decode_attention(q, k_pool, v_pool, page_table, lengths,
 
 def block_decode_attention(q, k_pool, v_pool, page_table, lengths, width,
                            impl="auto", softmax_scale=None, layer=None):
-    """Paged attention for a BLOCK of ``width`` query positions a
-    sequence that all see the same columns (generation by diffusion over
-    blocks: inside a block attention is bidirectional, so the block's
-    rows share one length, the block's end).
+    """Paged attention for ``width`` query positions a sequence that
+    see the same columns, or two such runs side by side (generation by
+    diffusion over blocks: inside a block attention is bidirectional, so
+    a block's rows share one length, the block's end).
 
     ``q``: (B * width, H, D), a sequence's ``width`` rows consecutive;
     ``page_table``: (B, P); ``lengths``: (B,) the columns every row of
-    the sequence's block sees (0: an inactive slot).  The verify layout
+    the sequence sees (0: an inactive slot), or **(B, 2)**: the first
+    ``width // 2`` rows see ``lengths[:, 0]`` columns and the rest
+    ``lengths[:, 1]`` (the block step's held block, which sees the
+    cache up to its own end, beside the block that opens after it,
+    which sees the held block's columns too; a half of length 0 is dead
+    and reads zeros).  The verify layout
     (``decode_attention(width=...)``) is right in value and walks a
-    sequence's pages once a ROW; because the rows share their length,
-    they ride ONE walk here: the block is folded into the group axis, a
-    key/value head scored against ``width * H / H_kv`` query rows, so
-    the live pages are read once a sequence a layer.  Kernel and XLA
-    twin are :func:`decode_attention`'s, at that wider group
-    (:func:`_plan` prices the group's rows of scratch).  Returns
-    (B * width, H, D)."""
+    sequence's pages once a ROW; here the rows ride ONE walk: they are
+    folded into the group axis, a key/value head scored against
+    ``width * H / H_kv`` query rows, so the live pages are read once a
+    sequence a layer, up to the LONGER length, and each half of the
+    folded rows masks by its own.  Kernel and XLA twin are
+    :func:`decode_attention`'s, at that wider group (:func:`_plan`
+    prices the group's rows of scratch); what tells the two cases apart
+    is the shape of ``lengths``, and for (B,) the kernel traced is the
+    one every other family runs.  Returns (B * width, H, D)."""
     Bw, H, D = q.shape
     h_kv = k_pool.shape[-3]
     B = page_table.shape[0]
-    if B * width != Bw or H % h_kv:
+    if B * width != Bw or H % h_kv or (lengths.ndim == 2 and width % 2):
         raise ValueError(
             f"q rows ({Bw}) must equal page-table rows ({B}) x width "
-            f"({width}), and q heads ({H}) divide by kv heads ({h_kv})")
+            f"({width}), q heads ({H}) divide by kv heads ({h_kv}), and "
+            f"two lengths a sequence {lengths.shape} halve the width")
     group = H // h_kv
     # (B, width, h_kv, group, D) -> (B, h_kv, width * group, D)
     folded = q.reshape(B, width, h_kv, group, D).transpose(0, 2, 1, 3, 4) \

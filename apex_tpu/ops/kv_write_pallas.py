@@ -174,55 +174,69 @@ def kv_write_pallas(k_pool, v_pool, k_src, v_src, dest, mask, layer,
 
 
 def _kv_block_write_kernel(dest_ref, layer_ref, mask_ref, *refs, heads, cols):
-    """The tile write for a block of ``cols`` columns: ``mask`` names,
+    """The tile write for a source of ``cols`` columns: ``mask`` names,
     a lane, the source column it takes plus one (0: the lane keeps the
-    pool's)."""
+    pool's).  The columns are PLACED by the MXU, a head's ``(D, cols)``
+    source times the one-hot ``(cols, page)`` of the lanes' choices
+    (exact: one term a lane), and one select a head merges them into the
+    tile: a select a column over the whole tile, as this kernel ran
+    until PR 45, bound it by the VPU at 29 ns a select a tile (1.2 us a
+    tile at 4 columns, 2.2 at 8, where its bytes take 0.64)."""
     del dest_ref, layer_ref  # consumed by the BlockSpec index maps
     n = len(refs) // 3       # sources, pool tiles in, pool tiles out
     which = mask_ref[0]                  # (1, page): broadcasts over D
+    column = jax.lax.broadcasted_iota(jnp.int32, (cols, which.shape[-1]), 0)
+    takes = which > 0
     for src_ref, pool_ref, out_ref in zip(refs[:n], refs[n:2 * n],
                                           refs[2 * n:]):
         src = src_ref[0, 0, 0]           # (D, heads * cols)
+        onehot = (which == column + 1).astype(src.dtype)
+        # a float32 cache: every bit of the value through the MXU
+        exact = jax.lax.Precision.HIGHEST if src.dtype == jnp.float32 \
+            else None
         for h in range(heads):
-            tile = pool_ref[0, 0, h]
-            for j in range(cols):
-                # the block's column j of head h, one value a head-dim
-                # element, into the one lane that takes it
-                new = src[:, h * cols + j:h * cols + j + 1]
-                tile = jnp.where(which == j + 1, new, tile)
-            out_ref[0, 0, h] = tile
+            placed = jax.lax.dot_general(
+                src[:, h * cols:(h + 1) * cols], onehot,
+                (((1,), (0,)), ((), ())), precision=exact,
+                preferred_element_type=jnp.float32)
+            out_ref[0, 0, h] = jnp.where(
+                takes, placed.astype(src.dtype), pool_ref[0, 0, h])
 
 
 def pool_write_block_pallas(pools, srcs, dest, first, live, layer,
                             interpret=False):
-    """Write a block of ``W`` consecutive columns a tile into pool
-    pages, in place: :func:`pool_write_pallas` for sources of ``W``
-    columns, ``W`` small and a divisor of the page, so that a block
-    lies in ONE page and a tile is read and written once for all of
-    them (the ``width`` layout of :func:`pool_write_pallas` would hand
-    the kernel two page-wide source tiles a block, the second to the
-    garbage page).
+    """Write a few consecutive columns a tile into pool pages, in
+    place: :func:`pool_write_pallas` for sources of ``C`` columns, ``C``
+    small (a block of ``W`` positions, or two blocks side by side), so
+    that a tile is read and written once for all of them (the ``width``
+    layout of :func:`pool_write_pallas` would hand the kernel two
+    page-wide source tiles a block, the second to the garbage page).
 
-    ``pools`` as there.  ``srcs``: one (1, T, W, H_kv, D) source a pool;
+    ``pools`` as there.  ``srcs``: one (1, T, C, H_kv, D) source a pool;
     ``dest``: (T,) int32 page ids, clamped and garbage-routed by the
     caller, live ones pairwise distinct; ``first``: (T,) int32 the lane
-    the block's first column takes; ``live``: (T,) bool (a dead tile
-    keeps the pool's columns).  ``layer``: scalar int32.  Returns the
-    pools, as a tuple."""
+    the source's column 0 takes, column ``c`` then ``first + c``: a
+    column whose lane falls outside the tile (``first`` may be negative)
+    is not written, which is how a run of columns that crosses a page
+    is handed over as two tiles with one source; ``live``: (T,) or
+    (T, C) bool, the columns to write (a dead tile keeps the pool's).
+    ``layer``: scalar int32.  Returns the pools, as a tuple."""
     pools, srcs = tuple(pools), tuple(srcs)
     n = len(pools)
     _, _, h_kv, D, page_size = pools[0].shape
-    Ls, T, W = srcs[0].shape[:3]
-    if Ls != 1 or page_size % W or len(srcs) != n \
-            or any(x.shape != (1, T, W, h_kv, D) for x in srcs) \
+    Ls, T, C = srcs[0].shape[:3]
+    if Ls != 1 or len(srcs) != n \
+            or any(x.shape != (1, T, C, h_kv, D) for x in srcs) \
             or any(p.shape != pools[0].shape or p.dtype != pools[0].dtype
                    for p in pools):
         raise ValueError(
             f"sources {[x.shape for x in srcs]} do not fit pools "
-            f"{[p.shape for p in pools]} as blocks that divide a page")
+            f"{[p.shape for p in pools]} as columns of one layer")
     lane = jnp.arange(page_size, dtype=jnp.int32)[None, :]
     offset = lane - first.astype(jnp.int32)[:, None]
-    which = jnp.where(live[:, None] & (offset >= 0) & (offset < W),
-                      offset + 1, 0)
+    inside = (offset >= 0) & (offset < C)
+    live = jnp.broadcast_to(live.reshape(T, -1), (T, C))
+    taken = jnp.take_along_axis(live, jnp.clip(offset, 0, C - 1), axis=1)
+    which = jnp.where(inside & taken, offset + 1, 0)
     return _launch(_kv_block_write_kernel, pools, srcs, dest, which, layer,
                    interpret)

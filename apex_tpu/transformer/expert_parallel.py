@@ -312,14 +312,15 @@ def _chunk(c, rows_per_chunk, order, offsets, n_live):
     return assignment, pos < n_live, inside[1:] - inside[:-1]
 
 
-def _chunk_ffn(rows, valid, sizes, w_gate, w_up, w_down, impl):
+def _chunk_ffn(rows, valid, sizes, w_gate, w_up, w_down, impl,
+               trainable=True):
     """One chunk's experts: the gated FFN over its rows, with every row
     past the live ones zero where it is read and where it is written."""
     rows = jnp.where(valid[:, None], rows, 0)
-    gate = _grouped_matmul(rows, w_gate, sizes, impl, True)
-    up = _grouped_matmul(rows, w_up, sizes, impl, True)
+    gate = _grouped_matmul(rows, w_gate, sizes, impl, trainable)
+    up = _grouped_matmul(rows, w_up, sizes, impl, trainable)
     act = jnp.where(valid[:, None], jax.nn.silu(gate) * up, 0)
-    y = _grouped_matmul(act, w_down, sizes, impl, True)
+    y = _grouped_matmul(act, w_down, sizes, impl, trainable)
     return jnp.where(valid[:, None], y, 0)
 
 
@@ -383,20 +384,31 @@ def _held_chunks(x, weights, w_gate, w_up, w_down, order, slot, offsets,
 
 
 def _held_chunks_fwd(x, weights, w_gate, w_up, w_down, order, slot, offsets,
-                     n_live, rows_per_chunk, impl):
+                     n_live, rows_per_chunk, impl, layer=None):
     """``x`` (T, H); ``weights`` (T, top_k) float32, 0 where an
     assignment is not held; ``order`` (T*top_k,) the assignments sorted
     held-first by expert; ``slot`` (T, top_k) each assignment's rank in
     that order (``>= n_live``: not held); ``offsets`` (n_held + 1,) the
-    experts' first ranks.  Returns ``(T, H)`` float32."""
+    experts' first ranks.  Returns ``(T, H)`` float32.
+
+    ``layer`` (a traced index): the serving form, forward only.  The
+    weights are then the STACKED layers' experts side by side as groups
+    (``(layers * n_held, ...)``, never sliced), of which only this
+    layer's get rows."""
     top_k = weights.shape[1]
     flat_w = weights.reshape(-1)
+    n_held = offsets.shape[0] - 1
 
     def body(c, out):
         assignment, valid, sizes = _chunk(c, rows_per_chunk, order, offsets,
                                           n_live)
+        if layer is not None:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((w_gate.shape[0],), jnp.int32), sizes,
+                (jnp.asarray(layer, jnp.int32) * n_held,))
         y = _chunk_ffn(jnp.take(x, assignment // top_k, axis=0), valid,
-                       sizes, w_gate, w_up, w_down, impl)
+                       sizes, w_gate, w_up, w_down, impl,
+                       trainable=layer is None)
         w = jnp.where(valid, jnp.take(flat_w, assignment), 0.0)
         return _sum_own(out, y, w, assignment // top_k, valid, slot,
                         c * rows_per_chunk, impl)
@@ -450,7 +462,7 @@ _held_chunks.defvjp(_held_chunks_fwd, _held_chunks_bwd)
 
 def expert_buffer_rows(tokens: int, top_k: int, n_held: int, n_experts: int,
                        factor: float = 1.25, multiple: int = 512) -> int:
-    """Rows of the static chunk a training step's expert layer walks:
+    """Rows of the static chunk an expert layer walks (``buffer_rows``):
     the held share of ``tokens * top_k`` assignments under even routing
     times ``factor``, up to a multiple the grouped matmul tiles, and
     never more than every assignment."""
@@ -494,11 +506,15 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
     ``experts_hit`` (held experts with at least one).
 
     ``buffer_rows`` (a static int; :func:`expert_buffer_rows`): the
-    trainable form.  The held assignments, compacted by the sort, are
-    walked in chunks of ``buffer_rows`` rows, as many as hold a live
-    row, under a ``custom_vjp`` whose backward walks them again
-    (:func:`_held_chunks`): nothing is dropped whatever the routing and
-    no buffer has ``T * top_k`` rows.  ``counts`` then also holds
+    held assignments, compacted by the sort, are walked in chunks of
+    ``buffer_rows`` rows, as many as hold a live row: nothing is
+    dropped whatever the routing and no buffer has ``T * top_k`` rows,
+    so a call costs what the held share of the assignments costs (an
+    eighth of them where 16 of 128 experts are held), not what all of
+    them would.  With one layer's experts it is the trainable form,
+    under a ``custom_vjp`` whose backward walks the chunks again
+    (:func:`_held_chunks`); with a stack and its ``layer`` the serving
+    form, forward only.  ``counts`` then also holds
     ``load`` (E,) int32, the assignments every expert of the router got
     (what :func:`balance_bias_update` reads), ``spill_chunks`` (chunks
     walked beyond the first) and ``buffer_rows`` (rows of the chunks
@@ -507,7 +523,8 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
     T, H = x.shape
     n_held = len(held)
     experts = {k: params[k] for k in ("we_gate", "we_up", "we_down")}
-    if layer is None:
+    stacked = layer is not None
+    if not stacked:
         experts = {k: w[None] for k, w in experts.items()}
         layer = 0
     n_layers = experts["we_gate"].shape[0]
@@ -540,10 +557,11 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
             "experts_hit": jnp.sum(group_sizes > 0, dtype=jnp.int32),
         }
 
+    # the layers' experts side by side as groups; only this layer's
+    # have rows
+    flat = lambda k: experts[k].reshape(
+        (n_layers * n_held,) + experts[k].shape[2:])
     if buffer_rows is not None:
-        if n_layers != 1:
-            raise ValueError("buffer_rows takes one layer's experts, "
-                             "not a stack and an index")
         # every expert's load, token by token (no T * top_k-row
         # one-hot); the held experts' group sizes are its slice
         chosen = ids if token_mask is None else jnp.where(
@@ -562,11 +580,13 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
         # the buffer walks whole chunks: pad the order to one
         rows_per_chunk = int(buffer_rows)
         padded = -(-A // rows_per_chunk) * rows_per_chunk
-        out = _held_chunks(
-            x, jnp.where(live, weights, 0.0),
-            *(experts[k][0] for k in ("we_gate", "we_up", "we_down")),
-            jnp.pad(order, (0, padded - A)), slot, offsets,
-            counts["assignments_held"], rows_per_chunk, impl)
+        walk = (x, jnp.where(live, weights, 0.0),
+                *(flat(k) if stacked else experts[k][0]
+                  for k in ("we_gate", "we_up", "we_down")),
+                jnp.pad(order, (0, padded - A)), slot, offsets,
+                counts["assignments_held"], rows_per_chunk, impl)
+        out = _held_chunks_fwd(*walk, layer=layer)[0] if stacked \
+            else _held_chunks(*walk)
         counts.update(
             load=load,
             spill_chunks=jnp.maximum(
@@ -574,15 +594,11 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
         counts["buffer_rows"] = rows_per_chunk * (1 + counts["spill_chunks"])
         return out.astype(x.dtype), counts
     rows = jnp.take(x, order // top_k, axis=0)        # (A, H)
-    # the layers' experts side by side as groups; only this layer's
-    # have rows
     all_sizes = jax.lax.dynamic_update_slice(
         jnp.zeros((n_layers * n_held,), jnp.int32), group_sizes,
         (jnp.asarray(layer, jnp.int32) * n_held,))
-    flat = {k: w.reshape((n_layers * n_held,) + w.shape[2:])
-            for k, w in experts.items()}
-    y = grouped_gated_ffn(rows, flat["we_gate"], flat["we_up"],
-                          flat["we_down"], all_sizes, impl=impl)
+    y = grouped_gated_ffn(rows, flat("we_gate"), flat("we_up"),
+                          flat("we_down"), all_sizes, impl=impl)
     # a row past the live ones belongs to no group: whatever the grouped
     # matmul left there is replaced, not multiplied away
     w = jnp.take(jnp.where(live, weights, 0.0).reshape(A), order)

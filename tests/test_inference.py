@@ -300,6 +300,47 @@ BLOCKED_CASES = {
 }
 
 
+#: name: ((slots, q heads, kv heads, head dim, page, pages a sequence,
+#: pool pages, verify width), sha256 of the traced jaxpr's text, the
+#: Pallas kernel's inside it), taken at the commit BEFORE the kernels
+#: learnt to take two lengths a row (PR 45; a row's two lengths are a
+#: shape of ``lengths``, (B, 2), and these callers pass (B,)).  The
+#: shapes are the cells': GPT-2 large (20 slots, 20 heads of 64),
+#: Falcon-H1 (96 slots, 20 query heads over 4 of 128), EvaByte (20 slots,
+#: 32 heads of 128), page 128, bf16; the verify layout; a page under 128
+#: lanes (the grid of page slots).  A PR that means to change what these
+#: callers run replaces the pins; one that does not may not move them.
+_ONE_LENGTH_JAXPRS = {
+    "gpt2-large": ((20, 20, 20, 64, 128, 8, 161, 1), "296502af29ff92ab"),
+    "falcon-h1": ((96, 20, 4, 128, 128, 20, 1024, 1), "89b4364b93b5f5e6"),
+    "evabyte": ((20, 32, 32, 128, 128, 4, 501, 1), "75f334fec120d60c"),
+    "verify4": ((8, 20, 20, 64, 128, 8, 161, 4), "c086c196e32f95ed"),
+    "small-page": ((4, 8, 2, 16, 16, 6, 40, 1), "1ef830047ca28e45"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_ONE_LENGTH_JAXPRS))
+def test_one_length_callers_trace_the_kernel_they_traced(name):
+    """``decode_attention`` with one length a row, what ``gpt.py``,
+    ``falcon_h1.py``, ``evabyte.py`` and the verify layout pass: the
+    traced program, the Pallas kernel's jaxpr inside it, is to the
+    letter the one traced before ``lengths`` could be (B, 2).  The block
+    step's second length costs the other families' programs nothing, not
+    an operation and not a second of tracing."""
+    import hashlib
+
+    (B, H, kvh, D, page, P, pages, width), want = _ONE_LENGTH_JAXPRS[name]
+    S = jax.ShapeDtypeStruct
+    pool = S((8, pages, kvh, D, page), jnp.bfloat16)
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, pt, n, layer: dap.decode_attention(
+            q, k, v, pt, n, impl="pallas", width=width, layer=layer))(
+        S((B * width, H, D), jnp.bfloat16), pool, pool,
+        S((B, P), jnp.int32), S((B * width,), jnp.int32), S((), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want, (
+        f"{name}: the one-length program changed")
+
+
 class TestDecodeAttentionBlocking:
     """The kernel's unit of work is a live page of a sequence row, all
     of a block's kv heads (PERF.md, PR 27), in both of its forms:

@@ -7,9 +7,12 @@ cache (logits, confidences, tokens; both remainders of the prompt;
 phases in one batch; greedy and seeded sampling), the shares of the
 experts adding up to the uncut layer (four of two here, eight of sixteen
 in the cell), the kernels of the block step against plain softmaxes, and
-the scheduler's invariants."""
+the scheduler's invariants; and the FUSED block step (a block's commit
+rides the next block's first denoising pass) against the unfused
+procedure written out here, a commit pass of its own a block."""
 
 import json
+from dataclasses import replace as dataclass_replace
 
 import jax
 import jax.numpy as jnp
@@ -210,9 +213,9 @@ def test_a_block_step_through_the_cache_gives_the_references_logits(tiny):
         padded[0, :len(prompt)] = prompt
         pools = prefill(params, pools, jnp.asarray(padded), jnp.int32(keep),
                         jnp.asarray(tables[slot]))
-        blocks = {"ids": blocks["ids"].at[slot].set(jnp.asarray(states[slot])),
-                  "passes": blocks["passes"],
-                  "pos": blocks["pos"].at[slot].set(keep)}
+        blocks = dict(
+            blocks, pos=blocks["pos"].at[slot].set(keep),
+            ids=blocks["ids"].at[slot].set(jnp.asarray(states[slot])))
     active = jnp.asarray([True, False, True])
     _, _, logits = step(params, pools, blocks, jnp.full((3,), 4, jnp.int32),
                         jnp.full((3,), 48, jnp.int32), active,
@@ -332,6 +335,46 @@ def test_the_shares_of_the_experts_sum_to_the_uncut_layer(tiny):
     assert float(jnp.max(jnp.abs(summed))) > 0.1
 
 
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_the_stacked_chunk_walk_gives_what_the_whole_buffer_gives(impl):
+    """``held_experts_ffn(layer=, buffer_rows=)``, the form the block
+    step and the prefill run: the held assignments compacted and walked
+    in chunks over the STACKED experts (only this layer's groups get
+    rows) give what the ``T * top_k``-row buffer gives, with one chunk
+    (all held assignments fit), with spill chunks (a chunk of 8 rows
+    for some 30 held assignments) and with dead tokens; the counts
+    agree."""
+    from apex_tpu.transformer.expert_parallel import held_experts_ffn
+
+    rng = np.random.RandomState(14)
+    T, H, F, E, layers = 32, 16, 8, 8, 3
+    held = range(2, 6)
+    x = jnp.asarray(rng.randn(T, H), jnp.float32)
+    params = {"router": jnp.asarray(rng.randn(H, E), jnp.float32),
+              "we_gate": jnp.asarray(rng.randn(layers, 4, H, F) * .3,
+                                     jnp.float32),
+              "we_up": jnp.asarray(rng.randn(layers, 4, H, F) * .3,
+                                   jnp.float32),
+              "we_down": jnp.asarray(rng.randn(layers, 4, F, H) * .3,
+                                     jnp.float32)}
+    mask = jnp.asarray(rng.rand(T) > 0.2)
+    kw = dict(top_k=2, n_group=1, topk_group=1, scale=1.0, token_mask=mask,
+              layer=jnp.int32(1), softmax=True, impl=impl)
+    whole, counts = held_experts_ffn(x, params, held, **kw)
+    assert int(counts["assignments_held"]) > 16
+    for rows in (64, 8):
+        got, chunked = held_experts_ffn(x, params, held, buffer_rows=rows,
+                                        **kw)
+        np.testing.assert_allclose(got, whole, atol=1e-5, rtol=0)
+        for name in ("assignments_held", "assignments_all", "experts_hit"):
+            assert int(chunked[name]) == int(counts[name])
+        assert (int(chunked["spill_chunks"]) > 0) == (rows == 8)
+    # and another layer's experts give another result
+    other, _ = held_experts_ffn(x, params, held, buffer_rows=64,
+                                **dict(kw, layer=jnp.int32(2)))
+    assert float(jnp.max(jnp.abs(other - whole))) > 0.1
+
+
 # ----------------------------------------------------- (3) the kernels
 def _dense_block_attention(q, k_pool, v_pool, tables, lengths, W):
     """(B * W, H, D) against a paged pool, dense: each of a slot's rows
@@ -422,6 +465,147 @@ def test_a_blocks_columns_are_rewritten_in_place(impl):
             want[1, pg, :, :, lane + w] = new[b * W + w]
     got = np.asarray(got)
     np.testing.assert_array_equal(got[:, 1:], want[:, 1:])  # page 0: garbage
+
+
+@pytest.mark.parametrize("page", [128, 16])
+@pytest.mark.parametrize("impl", ["interpret", "xla"])
+def test_block_attention_takes_a_length_a_half_of_the_rows(impl, page):
+    """``block_decode_attention`` with ``lengths`` (B, 2), the block
+    step's call: 2W = 8 rows a slot x 8 query heads a key/value head are
+    ONE group of 64, the first 4 rows (the held block) under the first
+    length and the last 4 (the open block) under the second, W more.
+    Both kernel forms (a page of whole lane tiles: the walk; a smaller
+    one: the grid) and the twin against a dense softmax a row: both
+    halves live in one page and across two, a dead held half (a first
+    block), a dead open half (a last block's commit), a dead slot; what
+    lies past a half's length is poison the other half may read."""
+    from apex_tpu.ops.decode_attention_pallas import block_decode_attention
+
+    rng = np.random.RandomState(9)
+    B, W, H, h_kv, D, P = 5, 4, 16, 2, 16, 3
+    pos = np.asarray([page - 4, page, 8, 2 * page + 4, 0], np.int32)
+    lengths = np.stack([pos, pos + W], axis=1)
+    lengths[2, 0] = 0           # nothing held: a request's first block
+    lengths[3, 1] = 0           # no open block: its last block's commit
+    lengths[4] = 0              # an idle slot
+    tables = np.zeros((B, P), np.int32)
+    k_pool = np.full((1 + B * P, h_kv, D, page), np.nan, np.float32)
+    v_pool = np.full_like(k_pool, np.nan)
+    page_id = 1
+    for b in range(B):
+        longest = int(lengths[b].max())
+        for p in range(-(-longest // page)):
+            tables[b, p] = page_id
+            k_pool[page_id] = rng.randn(h_kv, D, page)
+            v_pool[page_id] = rng.randn(h_kv, D, page)
+            live = longest - p * page
+            if live < page:
+                k_pool[page_id, :, :, live:] = 1e4
+                v_pool[page_id, :, :, live:] = -1e4
+            page_id += 1
+    q = rng.randn(B * 2 * W, H, D).astype(np.float32)
+    # dense, a half at a time: rows (b, half, w) against lengths[b, half]
+    halves = q.reshape(B, 2, W, H, D)
+    want = np.stack([_dense_block_attention(
+        halves[:, i].reshape(B * W, H, D), k_pool, v_pool, tables,
+        lengths[:, i], W).reshape(B, W, H, D) for i in range(2)], axis=1)
+    pools = (k_pool, v_pool) if impl == "interpret" else (
+        np.nan_to_num(k_pool), np.nan_to_num(v_pool))
+    got = block_decode_attention(
+        jnp.asarray(q), jnp.asarray(pools[0]), jnp.asarray(pools[1]),
+        jnp.asarray(tables), jnp.asarray(lengths), 2 * W, impl=impl)
+    got = np.asarray(got).reshape(B, 2, W, H, D)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+    for b, half in ((2, 0), (3, 1), (4, 0), (4, 1)):    # dead: zeros, no NaN
+        assert not np.any(got[b, half])
+    # the two halves of a slot differ: the open rows saw W columns more
+    assert np.max(np.abs(got[0, 0] - block_decode_attention(
+        jnp.asarray(halves[:1, 0].reshape(W, H, D)), jnp.asarray(pools[0]),
+        jnp.asarray(pools[1]), jnp.asarray(tables[:1]),
+        jnp.asarray(lengths[:1, 1]), W, impl=impl))) > 1e-3
+    with pytest.raises(ValueError, match="lengths"):
+        block_decode_attention(
+            jnp.asarray(q), jnp.asarray(pools[0]), jnp.asarray(pools[1]),
+            jnp.asarray(tables), jnp.asarray(np.repeat(lengths, 2, 1)[:, :3]),
+            2 * W, impl=impl)
+
+
+@pytest.mark.parametrize("page", [16, 4])
+@pytest.mark.parametrize("impl", ["interpret", "xla"])
+def test_two_blocks_a_slot_are_written_tile_by_tile(impl, page):
+    """``write_block_pools`` with ``active`` (B, 2), the block step's
+    call: the held block at ``positions`` and the open one W after it.
+    Where both lie in one page the kernel path writes them in ONE grid
+    step (two steps on a tile would lose the first's columns: every
+    column is checked), where the open block starts a page in two
+    tiles (always, where a page is one block: ``page`` 4); a dead half,
+    a dead slot, a first block (nothing before position 0) and a block
+    outside the table write nothing; every other column of the pool
+    stays as it was."""
+    from apex_tpu.inference.kv_cache import write_block_pools
+
+    rng = np.random.RandomState(12)
+    B, W, h_kv, D, L, P = 6, 4, 2, 8, 2, 32 // page
+    pool = rng.randn(L, 1 + B * P, h_kv, D, page).astype(np.float32)
+    tables = 1 + np.arange(B * P, dtype=np.int32).reshape(B, P)
+    # the HELD block's start: one page; held ends its page, open starts
+    # the next; held dead; open dead and past the table; no block before
+    # the first; idle
+    positions = np.asarray([4, 12, 8, 28, -4, 4], np.int32)
+    active = np.asarray([[1, 1], [1, 1], [0, 1], [1, 0], [0, 1], [0, 0]],
+                        bool)
+    new = rng.randn(B * 2 * W, h_kv, D).astype(np.float32)
+    (got,) = write_block_pools(
+        (jnp.asarray(pool),), (jnp.asarray(new),), jnp.asarray(tables),
+        jnp.asarray(positions), jnp.asarray(active), W, layer=jnp.int32(1),
+        impl=impl)
+    want = pool.copy()
+    for b in range(B):
+        for c in range(2 * W):
+            at = int(positions[b]) + c
+            if active[b, c // W] and 0 <= at < P * page:
+                want[1, tables[b, at // page], :, :, at % page] \
+                    = new[b * 2 * W + c]
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got[:, 1:], want[:, 1:])  # page 0: garbage
+    for b, at in ((0, 4), (0, 8), (1, 12), (1, 16), (3, 28)):   # written
+        assert np.any(got[1, tables[b, at // page]]
+                      != pool[1, tables[b, at // page]])
+
+
+def test_the_block_write_kernel_routes_its_tiles():
+    """``pool_write_block_pallas`` alone (the interpreter): a source of
+    2W columns whose column 0 takes a lane that may lie LEFT of the
+    tile (the run crosses a page: the same source serves two tiles, each
+    the columns that fall inside it), a column mask, dead tiles at the
+    garbage page."""
+    from apex_tpu.ops.kv_write_pallas import pool_write_block_pallas
+
+    rng = np.random.RandomState(13)
+    h_kv, D, page, C = 2, 8, 16, 8
+    pool = rng.randn(1, 5, h_kv, D, page).astype(np.float32)
+    src = rng.randn(1, 4, C, h_kv, D).astype(np.float32)
+    dest = np.asarray([1, 2, 3, 0], np.int32)
+    first = np.asarray([6, 12, -4, 0], np.int32)
+    live = np.ones((4, C), bool)
+    live[0, :4] = False             # tile 0: its first block dead
+    live[3] = False                 # tile 3: dead, at the garbage page
+    (got,) = pool_write_block_pallas(
+        (jnp.asarray(pool),), (jnp.asarray(src),), jnp.asarray(dest),
+        jnp.asarray(first), jnp.asarray(live), jnp.int32(0), interpret=True)
+    want = pool.copy()
+    for t in range(4):
+        for c in range(C):
+            lane = int(first[t]) + c
+            if live[t, c] and 0 <= lane < page:
+                want[0, dest[t], :, :, lane] = src[0, t, c]
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # tile 1 took columns 0..3 (lanes 12..15), tile 2 columns 4..7 (0..3)
+    assert np.all(np.asarray(got)[0, 2, :, :, 12:] == np.moveaxis(
+        src[0, 1, :4], 0, -1))
+    assert np.all(np.asarray(got)[0, 3, :, :, :4] == np.moveaxis(
+        src[0, 2, 4:], 0, -1))
+    assert np.all(np.asarray(got)[0, 3, :, :, 4:] == pool[0, 3, :, :, 4:])
 
 
 def test_the_block_causal_flash_forward_at_a_size_with_many_subtiles():
@@ -577,8 +761,20 @@ def test_passes_are_kept_only_for_a_request_that_asks(tiny):
         spans = {s["attrs"]["rid"]: s["attrs"]
                  for s in tracing.get_tracer().spans()
                  if s["name"] == "serve.request"}
+        steps = [s["attrs"] for s in tracing.get_tracer().spans()
+                 if s["name"] == "serve.decode_step"]
     finally:
         tracing.disable()
+    # the block step's span says how many commits it held and how many of
+    # them rode a denoising pass of their slot: all but a request's last
+    read = [a for a in steps if "commits" in a]
+    assert sum(a["commits"] for a in read) == sched.stats["block_commits"]
+    assert sum(a["fused_commits"] for a in read) \
+        == sched.read_counters()["blk_commits_fused"] \
+        == sched.stats["block_commits"] - len(reqs)
+    assert sum(a["emitted"] for a in read) \
+        == sum(r.max_new_tokens for r in reqs)
+    assert max(a["block_rows"] for a in steps) == 2 * 4 * 3
     for r in reqs:
         assert done[r.rid].block_trace is None
         assert done[r.rid].tokens == kept[r.rid].tokens
@@ -667,3 +863,201 @@ def test_what_a_block_generating_model_cannot_be_served_with(tiny):
                              denoising_steps=2))
     with pytest.raises(ValueError, match="block_length"):
         M.SDARMoEConfig(block_length=4, denoising_steps=5)
+
+
+# ------------------- (5) the fused step against the unfused procedure
+def _unfused(params, cfg, dcfg, prompt, answer, steps, row):
+    """Generation by blocks as it ran before a commit rode a pass, one
+    request in slot 0: every forward is ONE block through the cache
+    (``decode_block`` with the held half dead), a denoising pass while
+    the block holds a mask (plain float32 logits, the mask's row out,
+    the reference's choice), then a COMMIT pass of its own over the
+    clean block.  Returns ``(tokens, trace rows, forwards, pools)``."""
+    from apex_tpu.inference.kv_cache import alloc_named_pools
+
+    model, W = cfg.served_model(), 4
+    B, P = dcfg.max_batch, len(prompt)
+    tables = np.zeros((B, dcfg.cache.pages_per_seq), np.int32)
+    tables[0] = row
+    pools = alloc_named_pools(model.cache_spec(), dcfg.cache, slots=B)
+    keep = P // W * W
+    if keep:
+        padded = np.zeros((1, next(b for b in dcfg.prefill_lengths
+                                   if b >= keep)), np.int32)
+        padded[0, :min(P, padded.shape[1])] = prompt[:padded.shape[1]]
+        pools = make_block_prefill(model, dcfg)(
+            params, pools, jnp.asarray(padded), jnp.int32(keep),
+            jnp.asarray(row))
+
+    @jax.jit
+    def forward(pools, ids, pos):
+        tokens = jnp.zeros((B, 2, W), jnp.int32).at[0, 1].set(ids)
+        hidden, pools = model.decode_block(
+            params, tokens.reshape(-1),
+            jnp.zeros((B,), jnp.int32).at[0].set(pos),
+            jnp.zeros((B, 2), bool).at[0, 1].set(True), pools,
+            jnp.asarray(tables), "xla")
+        return hidden[:W].astype(jnp.float32) \
+            @ params["head"].T.astype(jnp.float32), pools
+
+    seq, trace, forwards = list(prompt[:keep]), [], 0
+    for start in range(keep, -(-(P + answer) // W) * W, W):
+        ids = list(prompt[start:start + W])
+        ids += [MASK] * (W - len(ids))
+        for n_t in unmask_counts(W, steps) + [0]:
+            logits, pools = forward(pools, jnp.asarray(ids), start)
+            forwards += 1
+            if MASK not in ids:     # the commit: the clean block's columns
+                trace.append((start, ids + [BLOCK_COMMIT, 0]))
+                break
+            x0, conf = reference.token_confidence(logits, MASK)
+            chosen = reference.choose([i == MASK for i in ids], conf, n_t,
+                                      "low_confidence_static")
+            ids = [int(x0[i]) if i in chosen else t
+                   for i, t in enumerate(ids)]
+            trace.append((start, ids + [BLOCK_DENOISE, len(chosen)]))
+        seq += ids
+    return seq[P:P + answer], trace, forwards, pools
+
+
+def _columns(pools, row, page_size, upto):
+    """A sequence's cached columns ``[0, upto)`` of every layer, keys
+    and values: (2, L, upto, kv heads, d)."""
+    at = np.arange(upto)
+    return np.stack([np.asarray(pools[n])[:, row[at // page_size], :, :,
+                                          at % page_size]
+                     for n in ("k", "v")]).swapaxes(1, 2)
+
+
+#: name: (page size, slots, impl, [(prompt, answer, denoising steps)])
+FUSED_CASES = {
+    "steps_1": (8, 3, "xla", [(8, 12, 1)]),
+    "steps_2": (8, 3, "xla", [(8, 12, 2)]),
+    "steps_4": (8, 3, "xla", [(8, 12, 4)]),
+    # the first block opens on two prompt tokens and takes fewer passes
+    "prompt_remainder": (8, 3, "xla", [(6, 9, 4)]),
+    "one_block": (8, 3, "xla", [(8, 3, 2)]),
+    # blocks at 8 and 12 of a page of 16: the held block and the open
+    # one share their page, ONE grid step writes both
+    "held_and_open_share_a_page": (16, 2, "interpret", [(8, 8, 2)]),
+    # blocks at 12 and 16: the open block starts a page, two tiles
+    "open_block_starts_a_page": (16, 2, "interpret", [(12, 8, 2)]),
+    # one slot: the second request takes it when the first has left
+    "slot_taken_again": (8, 1, "xla", [(8, 8, 1), (5, 6, 4), (4, 4, 2)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_the_fused_step_is_the_unfused_procedure_in_fewer_steps(tiny, case):
+    """A block's commit rides the next block's first denoising pass:
+    against the unfused procedure (:func:`_unfused`, a commit pass of
+    its own) the scheduler serves the same tokens and the same trace
+    block by block (a ``BLOCK_COMMIT`` row a block, its ids unchanged),
+    leaves the same columns in the pool after every commit (a committed
+    block's columns are never written again: the pool at the end holds
+    them all), and takes a step fewer a block but the last: the
+    denoising passes and ONE more, not one more a block."""
+    conf, key, params, _, _ = tiny
+    page, slots, impl, requests = FUSED_CASES[case]
+    dcfg = DecodeConfig(
+        cache=KVCacheConfig(num_pages=12, page_size=page, pages_per_seq=4,
+                            dtype=jnp.float32),
+        max_batch=slots, max_prompt_len=16, prefill_buckets=(8,),
+        temperature=0.0, attn_impl=impl, sample_impl=impl,
+        sample_dot_dtype=jnp.float32)
+    cfg = adapter.model_config(conf)
+    sched = ContinuousBatchingScheduler(params, cfg, dcfg)
+    rng = np.random.RandomState(21)
+    reqs = [Request(rid=i, prompt=rng.randint(0, MASK, size=p).tolist(),
+                    max_new_tokens=g, denoising_steps=t, record_passes=True)
+            for i, (p, g, t) in enumerate(requests)]
+    rows = {}
+    for r in reqs:
+        sched.submit(r)
+    while not sched.idle():
+        sched.step()
+        for i, s in enumerate(sched._slots):
+            if s is not None:
+                rows.setdefault(s.request.rid, sched._page_tables[i].copy())
+    done = {c.rid: c for c in sched.completed}
+    steps = blocks = 0
+    twin = dataclass_replace(dcfg, attn_impl="xla", sample_impl="xla")
+    for r in reqs:
+        tokens, trace, forwards, pools = _unfused(
+            params, cfg, twin, r.prompt, r.max_new_tokens,
+            r.denoising_steps, rows[r.rid])
+        got = done[r.rid]
+        assert got.tokens == tokens and len(tokens) == r.max_new_tokens
+        assert [(a, list(map(int, row))) for a, row in got.block_trace] \
+            == trace
+        n = sum(row[4] == BLOCK_COMMIT for _, row in trace)
+        assert n == -(-(len(r.prompt) + len(tokens)) // 4) \
+            - len(r.prompt) // 4
+        blocks, steps = blocks + n, steps + forwards - (n - 1)
+        if r is reqs[-1]:   # its pages were not taken again
+            upto = -(-(len(r.prompt) + len(tokens)) // 4) * 4
+            np.testing.assert_allclose(
+                _columns(sched.pools, rows[r.rid], page, upto),
+                _columns(pools, rows[r.rid], page, upto), atol=1e-5, rtol=0)
+    # one slot or one request: the steps are the requests' own, added up
+    assert sched.stats["decode_steps"] == steps
+    assert sched.stats["block_commits"] == blocks
+    counters = sched.read_counters()
+    assert counters["blk_commit_passes"] == blocks
+    assert counters["blk_commits_fused"] == blocks - len(reqs)
+    assert counters["blk_denoise_passes"] + blocks \
+        == sched.stats["block_passes"] == steps + blocks - len(reqs)
+    assert counters["blk_rows_forwarded"] == 4 * sched.stats["block_passes"]
+    assert sched.stats["wasted_slot_steps"] == 0
+    assert sched.decode_cache_size() == 1
+
+
+def test_a_held_block_does_not_outlive_its_tenant(tiny):
+    """One slot.  A best-effort request of one denoising step a block
+    (every pass leaves a clean block HELD for the next step) is
+    preempted between two steps; the interactive request that takes the
+    slot starts with no held block (``_set_block`` clears the flag: a
+    leaked one would be committed at ``pos - W``, over the last block of
+    the newcomer's prompt) and is served what it is served alone; the
+    preempted request loses the block it held (its tokens had not been
+    emitted: a token is emitted at its block's commit) and serves in
+    all what an unpreempted run serves."""
+    conf, key, params, _, _ = tiny
+    dcfg = DecodeConfig(
+        cache=KVCacheConfig(num_pages=12, page_size=8, pages_per_seq=5,
+                            dtype=jnp.float32),
+        max_batch=1, max_prompt_len=32, prefill_buckets=(8, 16, 32),
+        temperature=0.0, attn_impl="xla", sample_impl="xla",
+        sample_dot_dtype=jnp.float32)
+    cfg = adapter.model_config(conf)
+    rng = np.random.RandomState(22)
+    slow = Request(rid=0, prompt=rng.randint(0, MASK, size=8).tolist(),
+                   max_new_tokens=20, lane="best_effort", denoising_steps=1,
+                   record_passes=True)
+    fast = Request(rid=1, prompt=rng.randint(0, MASK, size=8).tolist(),
+                   max_new_tokens=8, denoising_steps=2, record_passes=True)
+    alone = {}
+    for r in (slow, fast):
+        one = ContinuousBatchingScheduler(params, cfg, dcfg)
+        one.submit(Request(**{**r.__dict__, "trace_id": None}))
+        (alone[r.rid],) = one.run_until_drained()
+    sched = ContinuousBatchingScheduler(params, cfg, dcfg)
+    sched.submit(slow)
+    for _ in range(4):
+        sched.step()
+    sched.drain_manifest()      # settles the step in flight
+    assert bool(sched._blocks["held_live"][0])      # a clean block waits
+    emitted = len(sched._slots[0].generated)
+    assert 0 < emitted < 20 and emitted % 4 == 0
+    sched.submit(fast)
+    sched.step()                # preempts, admits: the flag is cleared
+    assert sched._slots[0].request.rid == 1
+    assert not bool(sched._blocks["held_live"][0])
+    done = {c.rid: c for c in sched.run_until_drained()}
+    assert sched.stats["preemptions"] == 1
+    for rid in (0, 1):
+        assert done[rid].tokens == alone[rid].tokens
+    assert [(a, list(map(int, row))) for a, row in done[1].block_trace] \
+        == [(a, list(map(int, row))) for a, row in alone[1].block_trace]
+    commits = [a for a, row in done[0].block_trace if row[4] == BLOCK_COMMIT]
+    assert commits == list(range(8, 28, 4))

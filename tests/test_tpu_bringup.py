@@ -321,15 +321,31 @@ def _block_attn():
          _BLOCK_POOL, ((c["B"], c["P"]), I32), ((c["B"],), I32), ((), I32)])
 
 
-def _block_write():
+def _block_attn_step():
+    """The block STEP's call: the held block and the open one, 2W rows a
+    slot as one group of 64 a key/value head, a length a half."""
+    from apex_tpu.ops.decode_attention_pallas import block_decode_attention
+
+    c = _BLOCK_CELL
+    return (lambda q, k, v, pt, n, layer: block_decode_attention(
+        q, k, v, pt, n, 2 * c["W"], impl="pallas", layer=layer),
+        [((c["B"] * 2 * c["W"], c["heads"], c["D"]), BF16), _BLOCK_POOL,
+         _BLOCK_POOL, ((c["B"], c["P"]), I32), ((c["B"], 2), I32), ((), I32)])
+
+
+def _block_write(blocks=None, dtype=BF16):
+    """One block a slot (``active`` (B,)), or with ``blocks`` the block
+    step's call: that many side by side, ``active`` (B, blocks)."""
     from apex_tpu.inference.kv_cache import write_block_pools
 
     c = _BLOCK_CELL
-    new = ((c["B"] * c["W"], c["kv"], c["D"]), BF16)
+    new = ((c["B"] * (blocks or 1) * c["W"], c["kv"], c["D"]), dtype)
+    act = (c["B"], blocks) if blocks else (c["B"],)
+    pool = (_BLOCK_POOL[0], dtype)
     return (lambda k, v, kn, vn, pt, pos, act, layer: write_block_pools(
         (k, v), (kn, vn), pt, pos, act, c["W"], layer=layer, impl="pallas"),
-        [_BLOCK_POOL, _BLOCK_POOL, new, new, ((c["B"], c["P"]), I32),
-         ((c["B"],), I32), ((c["B"],), jnp.bool_), ((), I32)])
+        [pool, pool, new, new, ((c["B"], c["P"]), I32),
+         ((c["B"],), I32), (act, jnp.bool_), ((), I32)])
 
 
 def _sample_confidence(temperature, rows=256, hidden=2048, vocab=18992):
@@ -358,6 +374,12 @@ CASES = {
     # block-causal flash forward at its longest bucket
     "block_attn_cell": (*_block_attn(), {"apex_decode_attention"}),
     "kv_write_block_cell": (*_block_write(), {"apex_kv_write"}),
+    # the block STEP's calls: the held block beside the open one
+    "block_attn_two_lengths_cell": (*_block_attn_step(),
+                                    {"apex_decode_attention"}),
+    "kv_write_two_blocks_cell": (*_block_write(2), {"apex_kv_write"}),
+    # a float32 cache: the columns are placed by a matmul at HIGHEST
+    "kv_write_two_blocks_fp32": (*_block_write(2, F32), {"apex_kv_write"}),
     "sample_confidence_greedy": (*_sample_confidence(0.0),
                                  {"apex_fused_sample"}),
     "sample_confidence_drawn": (*_sample_confidence(0.8),

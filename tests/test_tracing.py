@@ -1274,3 +1274,37 @@ class TestServeSpans:
                   if s["name"] == "serve.admit"]
         assert passes.count(reason) == stats["admit_blocked_" + reason]
         assert set(passes) <= {reason, None}
+
+
+def test_a_steps_ops_are_summed_by_name_inside_the_step_program():
+    """``benchmarks/cell_step_ops.by_name`` over a loaded trace: the
+    most frequent program is the step, its first and last executions
+    (cut by the trace's edges) are left out, an op's SELF time counts
+    (a fusion less the kernel inside it), names lose their numbering,
+    and what runs inside another program (a prefill) is not the
+    step's."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parents[1] \
+        / "benchmarks" / "cell_step_ops.py"
+    spec = importlib.util.spec_from_file_location("cell_step_ops", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    ms = 1_000_000
+    steps = [["jit_step(1)", i * 10 * ms, 8 * ms] for i in range(5)]
+    ops = []
+    for i in range(5):
+        t = i * 10 * ms
+        ops += [["%fusion.12", t, 4 * ms], ["%apex_kv_write.3", t + ms, ms],
+                ["%fusion.7", t + 5 * ms, 2 * ms]]
+    ops.append(["%apex_flash_fwd.1", 48 * ms + 500, ms])    # a prefill's
+    loaded = {"devices": {"/device:TPU:0": ops},
+              "modules": {"/device:TPU:0": steps + [
+                  ["jit_prefill(2)", 48 * ms, 2 * ms]]},
+              "sync_ns": None}
+    table = mod.by_name(loaded)
+    assert table["program"] == "jit_step(1)" and table["steps"] == 3
+    assert table["step_ms"] == 8.0
+    assert dict(table["ops_ms"]) == {"fusion": 5.0, "apex_kv_write": 1.0}
+    assert table["programs"]["jit_prefill(2)"] == [1, 2.0]
