@@ -239,11 +239,79 @@ def route_group_limited(x, router_w, bias, *, top_k: int, n_group: int,
     return ids, weights
 
 
-#: (rows, contraction, columns) tile of the Pallas grouped matmul: 88%
-#: of the HBM roofline at 64 live rows over 16 experts of 7168 x 2048 on
-#: a v5e, where XLA's own ``ragged_dot`` kernel reached 40% (PERF.md,
-#: PR 26)
+#: (rows, contraction, columns) tile of the Pallas grouped matmul where
+#: a group is a fraction of a row tile or a few of them, the serving
+#: forms (:func:`grouped_tiling`): 88% of the HBM roofline at 64 live
+#: rows over 16 experts of 7168 x 2048 on a v5e, where XLA's own
+#: ``ragged_dot`` kernel reached 40% (PERF.md, PR 26)
 GROUPED_TILING = (128, 1024, 1024)
+#: rows over groups (both static in a call) from which a group spans
+#: several row tiles and the tiles follow the rows it has: 1.3-102 in
+#: the serving programs, 1,280 in the train chunk (PERF.md, PR 46)
+RESIDENT_ROWS_A_GROUP = 512
+#: what Mosaic lets a kernel's blocks take of VMEM when, as megablox,
+#: it asks for no other limit (v5e)
+SCOPED_VMEM_BYTES = 16 * 2 ** 20
+
+
+def grouped_vmem_bytes(product, tiling, itemsize):
+    """VMEM a megablox kernel's blocks take at ``tiling``: both operands'
+    and the output's blocks twice (the pipeline's two buffers), and the
+    float32 accumulator of an output block."""
+    tm, tk, tn = tiling
+    out = tk * tn if product == "tgmm" else tm * tn
+    return 2 * itemsize * (tm * tk + tm * tn + tk * tn) + 4 * out
+
+
+def grouped_tiling(product, rows, groups, k, n, dtype):
+    """``(tm, tk, tn)`` of one grouped matmul, from what the call can
+    see; None where no row tile divides ``rows``.  ``product``: "gmm"
+    (``rows x w``), "gmm_t" (``rows x w^T``: the rows' cotangent) or
+    "tgmm" (``rows^T x cotangent`` a group: the weights' gradient, whose
+    ``tm`` tiles its contraction and ``(tk, tn)`` an expert's matrix);
+    ``k``, ``n``: the product's contraction and output widths (of
+    "tgmm": the matrix's two); ``dtype``: the weights'.
+
+    A Pallas pipeline fetches a block again only when its index moves,
+    and ``gmm``'s weight block has the index ``(group, k_i, n_i)`` under
+    a grid ``(n tiles, row tiles, k tiles)``.  Where a group is a row
+    tile or less (decode, the block step) or a few (prefill), an
+    expert's matrix is read once whatever ``tk`` is, and the call gets
+    :data:`GROUPED_TILING` clipped to its widths, as every call did
+    until PR 46.  From :data:`RESIDENT_ROWS_A_GROUP` rows a group (the
+    training chunk) a ``tk`` short of ``k`` turns the index at every
+    grid step and streams the matrix once a ROW TILE, so ``gmm`` and
+    ``gmm_t`` then
+
+    - contract in ONE tile (``tk = k``): the block stands for all of a
+      group's row tiles (0.93 -> 0.51 ms a call at 2,048 deep);
+    - take the widest columns of ``n``, 1,024, 512 that fit
+      :data:`SCOPED_VMEM_BYTES` by :func:`grouped_vmem_bytes`: with
+      ``tn = n`` the rows are read once, not once a column tile (0.55 ->
+      0.51 ms at 1,024 deep into 2,048);
+    - and a row tile of 256 where it divides ``rows`` (another 3%).
+
+    ``tgmm`` keeps the plain tiles: a taller row tile read SLOWER (0.56
+    -> 0.61 ms at 512 rows), and the one block that read faster, an
+    expert's whole matrix, is past the limit by this arithmetic.  The
+    readings: ``benchmarks/grouped_matmul_sweep.py`` on a v5e (PERF.md,
+    PR 46); which of the tilings that fit is fastest is the chip's to
+    say, and the sweep says it again for another shape."""
+    tm = next((t for t in (GROUPED_TILING[0], 64, 32, 16, 8)
+               if rows % t == 0), None)
+    if tm is None:
+        return None
+    plain = (tm, min(GROUPED_TILING[1], k), min(GROUPED_TILING[2], n))
+    if rows // groups < RESIDENT_ROWS_A_GROUP or tm < GROUPED_TILING[0] \
+            or product == "tgmm":
+        return plain
+    tm = 256 if rows % 256 == 0 else tm
+    # a sixteenth left for what the compiler keeps beside the blocks
+    # (0.45 MiB beside a float32 tgmm's 16.0 on the described v5e)
+    return next((t for t in ((tm, k, n), (tm, k, 1024), (tm, k, 512))
+                 if t[2] <= n and grouped_vmem_bytes(
+                     product, t, jnp.dtype(dtype).itemsize)
+                 <= SCOPED_VMEM_BYTES * 15 // 16), plain)
 
 
 def _plain(impl) -> bool:
@@ -262,27 +330,54 @@ def _grouped_matmul(rows, w, group_sizes, impl, trainable=False):
     group ``g``: ``jax.lax.ragged_dot`` ("xla"), or the Pallas grouped
     GEMM that ships with JAX (megablox ``gmm``; "pallas", "interpret",
     and "auto" on a TPU), which visits only the row tiles that hold a
-    live row.  Rows past the live ones come out undefined.
-    ``trainable``: megablox's ``ops.gmm``, the same kernel under a
-    ``custom_vjp`` (``gmm`` with the weights transposed for the rows'
-    cotangent, ``tgmm`` for the weights'); the bare ``pallas_call`` has
-    no derivative."""
-    tm = next((t for t in (GROUPED_TILING[0], 64, 32, 16, 8)
-               if rows.shape[0] % t == 0), None)
-    if _plain(impl) or tm is None:
+    live row, at the tiles :func:`grouped_tiling` gives the call.  Rows
+    past the live ones come out undefined.
+    ``trainable``: the same kernel under a ``custom_vjp``
+    (:func:`_gmm_trainable`); the bare ``pallas_call`` has no
+    derivative."""
+    if _plain(impl) or grouped_tiling("gmm", rows.shape[0], *w.shape,
+                                      w.dtype) is None:
         return jax.lax.ragged_dot(rows, w, group_sizes)
-    if trainable:
-        from jax.experimental.pallas.ops.tpu.megablox.ops import gmm
-    else:
-        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    return (_gmm_trainable if trainable else _gmm)(
+        rows, w, group_sizes, impl == "interpret")
 
-    _, tk, tn = GROUPED_TILING
-    tiling = (tm, min(tk, w.shape[1]), min(tn, w.shape[2]))
-    if trainable:       # custom_vjp: the static arguments by position
-        return gmm(rows, w, group_sizes, rows.dtype, tiling, None, None,
-                   False, impl == "interpret")
+
+def _gmm(rows, w, group_sizes, interpret, transposed=False):
+    """megablox's ``gmm`` at the call's own tiles: ``rows x w[g]`` a
+    group, or (``transposed``) ``rows x w[g]^T``."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    G, K, N = w.shape
+    tiling = grouped_tiling("gmm_t" if transposed else "gmm", rows.shape[0],
+                            G, *((N, K) if transposed else (K, N)), w.dtype)
     return gmm(rows, w, group_sizes, preferred_element_type=rows.dtype,
-               tiling=tiling, interpret=(impl == "interpret"))
+               tiling=tiling, transpose_rhs=transposed, interpret=interpret)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_trainable(rows, w, group_sizes, interpret):
+    """:func:`_gmm` with a derivative, as megablox's own ``ops.gmm`` has
+    one: ``gmm`` against the weights transposed for the rows' cotangent
+    and ``tgmm`` for the weights'.  ``ops.gmm`` hands ONE tiling to all
+    three kernels; here each gets its own (:func:`grouped_tiling`)."""
+    return _gmm(rows, w, group_sizes, interpret)
+
+
+def _gmm_trainable_fwd(rows, w, group_sizes, interpret):
+    return _gmm(rows, w, group_sizes, interpret), (rows, w, group_sizes)
+
+
+def _gmm_trainable_bwd(interpret, res, dout):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    rows, w, group_sizes = res
+    dw = tgmm(rows.swapaxes(0, 1), dout, group_sizes, w.dtype,
+              grouped_tiling("tgmm", rows.shape[0], *w.shape, w.dtype),
+              num_actual_groups=w.shape[0], interpret=interpret)
+    return _gmm(dout, w, group_sizes, interpret, transposed=True), dw, None
+
+
+_gmm_trainable.defvjp(_gmm_trainable_fwd, _gmm_trainable_bwd)
 
 
 def grouped_gated_ffn(rows, w_gate, w_up, w_down, group_sizes,

@@ -830,6 +830,63 @@ def test_kernels_compile_for_v5e_without_a_chip():
                      f"compile, or compiled without their name: {bad}")
 
 
+_TRAIN_CHUNK_CHILD = _DESCRIBED_V5E + """
+sys.path.insert(0, "tests")
+from test_tpu_bringup import CASES
+from apex_tpu.analysis.lowered import pallas_kernels
+fn, avals, _ = CASES["grouped_matmul_train"]
+args = [jax.ShapeDtypeStruct(s, d, sharding=SingleDeviceSharding(dev))
+        for s, d in avals]
+try:
+    out = {"kernels": pallas_kernels(jax.jit(fn).lower(*args).compile())}
+except Exception as e:
+    out = {"error": f"{type(e).__name__}: {e}"[-1500:]}
+print(json.dumps(out))
+"""
+
+
+def test_the_train_chunks_grouped_matmuls_compile_at_their_own_tiles():
+    """One chunk of the train cell's experts, forward and backward: nine
+    megablox kernels, each at the tiles ``grouped_tiling`` plans for ITS
+    product (an expert's whole 2,048 x 1,024 block resident under 256
+    rows in the gate, the up, the down and both cotangents; ``tgmm`` at
+    the tiles every call had), and Mosaic takes every one under the
+    default scoped VMEM limit on a described v5e."""
+    from apex_tpu.transformer.expert_parallel import grouped_tiling
+
+    fn, avals, _ = CASES["grouped_matmul_train"]
+    (M, H), (G, _, F) = avals[0][0], avals[1][0]
+    calls = _pallas_calls(jax.make_jaxpr(fn)(
+        *[jax.ShapeDtypeStruct(s, d) for s, d in avals]).jaxpr)
+    # the blocks of both operands and of the output, as lowered
+    tiles = sorted(tuple(
+        tuple(d.block_size for d in m.block_shape if hasattr(d, "block_size"))
+        for m in call.params["grid_mapping"].block_mappings)
+        for call in calls)
+    want = []
+    for product, k, n, times in (("gmm", H, F, 2), ("gmm", F, H, 1),
+                                 ("gmm_t", H, F, 1), ("gmm_t", F, H, 2),
+                                 ("tgmm", H, F, 2), ("tgmm", F, H, 1)):
+        tm, tk, tn = grouped_tiling(product, M, G, k, n, BF16)
+        want += [{"gmm": ((tm, tk), (tk, tn), (tm, tn)),
+                  "gmm_t": ((tm, tk), (tn, tk), (tm, tn)),
+                  "tgmm": ((tm, tk), (tm, tn), (tk, tn))}[product]] * times
+    assert tiles == sorted(want)
+    assert ((256, 2048), (2048, 1024), (256, 1024)) in tiles
+    assert ((256, 1024), (1024, 2048), (256, 2048)) in tiles
+    r = subprocess.run([sys.executable, "-c", _TRAIN_CHUNK_CHILD],
+                       cwd=str(REPO), capture_output=True, text=True,
+                       timeout=600,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(f"no compile-only TPU client: {out['skip']}")
+    assert "error" not in out, out
+    # the down projection's forward feeds nothing a sum's gradient needs
+    assert sorted(out["kernels"]) == ["gmm"] * 5 + ["tgmm"] * 3
+
+
 # --------------------------------------------- the pool stays where it is
 _POOL_CHILD = _DESCRIBED_V5E + """
 import jax.numpy as jnp
