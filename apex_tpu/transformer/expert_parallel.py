@@ -208,7 +208,7 @@ def moe_param_specs(ep_axis: Optional[str] = "dp", layers: bool = True):
 
 # ------------------------------------------------ held experts (serving)
 def route_group_limited(x, router_w, bias, *, top_k: int, n_group: int,
-                        topk_group: int, scale: float):
+                        topk_group: int, scale: float, eps: float = 1e-20):
     """Bias-corrected, group-limited top-k routing over ALL experts.
 
     ``x``: (T, H); ``router_w``: (H, E); ``bias``: (E,) — added to the
@@ -217,9 +217,9 @@ def route_group_limited(x, router_w, bias, *, top_k: int, n_group: int,
     consecutive experts are each scored by the sum of their 2 best
     ``s + bias``, the best ``topk_group`` groups stay, and the
     ``top_k`` best ``s + bias`` among them pick the experts.  Their
-    weights are the ORIGINAL ``s``, divided by their sum and multiplied
-    by ``scale``.  Returns ``(ids (T, top_k) int32, weights (T, top_k)
-    float32)``.
+    weights are the ORIGINAL ``s``, divided by their sum plus ``eps`` and
+    multiplied by ``scale``.  Returns ``(ids (T, top_k) int32, weights
+    (T, top_k) float32)``.
     """
     T = x.shape[0]
     E = router_w.shape[1]
@@ -235,7 +235,7 @@ def route_group_limited(x, router_w, bias, *, top_k: int, n_group: int,
                        choice, -jnp.inf)
     ids = jax.lax.top_k(masked, top_k)[1].astype(jnp.int32)    # (T, k)
     picked = jnp.take_along_axis(s, ids, axis=1, mode="clip")  # top_k's
-    weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+    weights = picked / (picked.sum(-1, keepdims=True) + eps) * scale
     return ids, weights
 
 
@@ -580,7 +580,7 @@ def balance_bias_update(bias, load, coeff: float):
 def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
                      topk_group: int, scale: float, token_mask=None,
                      layer=None, impl="auto", buffer_rows=None,
-                     softmax=False):
+                     softmax=False, eps=1e-20):
     """The routed part of an expert layer that the experts ``held``
     give, for every token, with no assignment dropped.
 
@@ -592,8 +592,8 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
     tokens that are padding or an empty slot route nowhere.
 
     Each token's ``top_k`` assignments are chosen and weighted over all
-    experts (:func:`route_group_limited`; ``softmax``: :func:`route_softmax`,
-    no bias); those to a held expert are sorted by expert and run through
+    experts (:func:`route_group_limited`, its ``eps``; ``softmax``:
+    :func:`route_softmax`); those to a held expert are sorted and run through
     :func:`grouped_gated_ffn` in a static buffer of ``T * top_k`` rows,
     the worst case.  Returns ``(out (T, H), counts)`` with ``counts``
     the int32 scalars ``assignments_held`` (assignments computed
@@ -633,7 +633,7 @@ def held_experts_ffn(x, params, held: range, *, top_k: int, n_group: int,
     else:
         ids, weights = route_group_limited(
             x, params["router"], params["router_bias"], top_k=top_k,
-            n_group=n_group, topk_group=topk_group, scale=scale)
+            n_group=n_group, topk_group=topk_group, scale=scale, eps=eps)
     live = (ids >= held.start) & (ids < held.stop)
     if token_mask is not None:
         live = live & token_mask[:, None]

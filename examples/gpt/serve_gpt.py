@@ -41,6 +41,13 @@ by.
         cellbench/configs/sdar-30b-a3b-serve-ep8.json \
         --streams 64 --page-size 128 --prompt-len 768 --max-new 512 \
         --prefill-buckets 128,256,512 --temperature 0 --denoising-steps 2
+    # the seventh family (models/lfm2_moe.py: layers that mix by a gated
+    # short convolution OR by grouped attention, every expert held; paged
+    # K/V over the attention layers, a per-slot tail over the others)
+    python examples/gpt/serve_gpt.py --model-config \
+        cellbench/configs/lfm2-8b-a1b-serve-pp2.json \
+        --streams 256 --page-size 128 --prompt-len 1024 --max-new 2048 \
+        --prefill-buckets 128,256,512 --temperature 0
     # serving v2: speculative decode + shared system prompt + chunked
     # prefill + a preemptible best-effort lane, one command
     python examples/gpt/serve_gpt.py --draft-len 4 --prefix-sharing \\
@@ -110,7 +117,8 @@ def build_args():
                         "(models/evabyte.py: --page-size then follows the "
                         "file, window_size / chunk_size, and prompts pad "
                         "to whole windows), model_type falcon_h1 "
-                        "(models/falcon_h1.py), or model_type sdar_moe "
+                        "(models/falcon_h1.py), model_type lfm2_moe "
+                        "(models/lfm2_moe.py), or model_type sdar_moe "
                         "(models/sdar_moe.py: generation by blocks; "
                         "--held-start picks the share of the experts).  "
                         "Where the file states the "
@@ -344,6 +352,17 @@ def check_greedy_parity(params, config, completions, max_check=3):
                 logits = falcon_h1.forward(params, jnp.asarray([seq]),
                                            config, attn_impl="xla")
                 pred = int(jnp.argmax(logits[0, len(seq) - 1]))
+            elif type(config).__name__ == "LFM2MoEConfig":
+                from apex_tpu.models import lfm2_moe
+
+                # causal: padding after the sequence moves nothing before
+                # it, and one padded length is one compile of the scan
+                padded = seq + [0] * (-len(seq) % 16)
+                logits = jax.jit(
+                    lfm2_moe.forward, static_argnames=("config", "attn_impl")
+                )(params, jnp.asarray([padded]), config=config,
+                  attn_impl="xla")
+                pred = int(jnp.argmax(logits[0, len(seq) - 1]))
             else:
                 from apex_tpu.models import mla_moe
 
@@ -362,12 +381,14 @@ def build_model(args, max_seq_len):
     ``--layers/--hidden/--heads/...``, or — with ``--model-config`` — the
     family a published-style ``config.json`` names (``model_type``
     ``evabyte``: ``models/evabyte.py``; ``falcon_h1``:
-    ``models/falcon_h1.py``; ``sdar_moe``: ``models/sdar_moe.py``; else
-    the latent-attention, sparse-expert family; weights in bf16,
-    random)."""
+    ``models/falcon_h1.py``; ``lfm2_moe``: ``models/lfm2_moe.py``;
+    ``sdar_moe``: ``models/sdar_moe.py``; else the latent-attention,
+    sparse-expert family; weights in bf16, random)."""
     key = jax.random.PRNGKey(args.seed)
     if args.model_config:
-        from apex_tpu.models import evabyte, falcon_h1, mla_moe, sdar_moe
+        from apex_tpu.models import (
+            evabyte, falcon_h1, lfm2_moe, mla_moe, sdar_moe,
+        )
 
         conf = json.loads(Path(args.model_config).read_text())
         dtype = jnp.float32 if args.smoke else jnp.bfloat16
@@ -385,6 +406,11 @@ def build_model(args, max_seq_len):
                 conf, param_dtype=dtype, compute_dtype=dtype)
             args.vocab = config.vocab_size
             return config, falcon_h1.init_params(config, key)
+        if conf.get("model_type") == "lfm2_moe":
+            config = lfm2_moe.LFM2MoEConfig.from_published(
+                conf, param_dtype=dtype, compute_dtype=dtype)
+            args.vocab = config.vocab_size
+            return config, lfm2_moe.init_params(config, key)
         if conf.get("model_type") == "evabyte":
             config = evabyte.EvaByteConfig.from_published(
                 conf, param_dtype=dtype, compute_dtype=dtype)

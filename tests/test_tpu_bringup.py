@@ -1716,6 +1716,125 @@ def test_the_block_step_copies_no_pool_and_casts_no_stack():
     assert out["pool_bytes"] == 48 * 320 * 4 * 128 * 128 * 2
 
 
+# LFM2-8B-A1B as its benchmark cell runs it: published widths, layers
+# 1-13, all 32 experts, the whole vocabulary, 256 slots, 3,840 pages of
+# 128 over the 3 attention layers, the longest prefill (1,024 tokens)
+_LFM2_POOL_CHILD = _DESCRIBED_V5E + """
+import jax.numpy as jnp
+from pathlib import Path
+import apex_tpu.utils.platform as platform
+platform.on_tpu = lambda: True      # 'auto' impls: as on the chip
+from apex_tpu.analysis.lowered import (
+    large_result_instructions, pallas_kernels,
+)
+from apex_tpu.inference.decode import make_decode_step, make_prefill
+from apex_tpu.inference.kv_cache import COUNTERS, alloc_named_pools
+from apex_tpu.models.lfm2_moe import init_params
+from cellbench.adapters.serve_lfm2_moe import decode_config, model_config
+
+conf = json.loads(Path(
+    "cellbench/configs/lfm2-8b-a1b-serve-pp2.json").read_text())
+cfg, dcfg = model_config(conf), decode_config(conf, 0)
+B, PPS, S = dcfg.max_batch, dcfg.cache.pages_per_seq, dcfg.max_prompt_len
+sh = SingleDeviceSharding(dev)
+put = lambda tree: jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+model = cfg.served_model()
+params = put(jax.eval_shape(lambda: model.serving_params(
+    init_params(cfg, jax.random.PRNGKey(0)))))
+pools = put(jax.eval_shape(lambda: dict(
+    alloc_named_pools(model.cache_spec(), dcfg.cache, slots=B),
+    **{COUNTERS: jnp.zeros((len(model.counter_names),), jnp.int32)})))
+I, U = jnp.int32, jnp.uint32
+programs = {
+    "decode_step": (make_decode_step(cfg, dcfg), (
+        params, pools, arg((B,), I), arg((B,), I), arg((B,), jnp.bool_),
+        arg((B, PPS), I), arg((B,), U))),
+    "prefill": (make_prefill(cfg, dcfg), (
+        params, pools, arg((1, S), I), arg((), I), arg((), I),
+        arg((PPS,), I), arg((), U), arg((), I))),
+}
+La, Lc = cfg.count("attn"), cfg.count("conv")
+# more than one repeat of a period position's expert stack
+matrix = 1 + params["period"][0]["we_gate"].size // cfg.plan[2]
+nbytes = lambda a: a.size * a.dtype.itemsize
+out = {"pool_bytes": nbytes(pools["k"]), "chip_bytes": 16 * 2 ** 30,
+       "weight_bytes": sum(nbytes(a) for a in jax.tree.leaves(params)),
+       "tail_bytes": nbytes(pools["conv_tail"]),
+       "plan": [len(cfg.plan[0]), len(cfg.plan[1]), cfg.plan[2],
+                len(cfg.plan[3])]}
+for name, (fn, args) in programs.items():
+    try:
+        c = fn.lower(*args).compile()
+    except Exception as e:
+        out[name] = {"error": f"{type(e).__name__}: {e}"[:1500]}
+        continue
+    show = lambda found: [
+        [i["name"], i["opcode"],
+         "tpu_custom_call" in i["line"]
+         and "output_to_operand_aliasing" in i["line"]]
+        for i in found]
+    mem = c.memory_analysis()
+    out[name] = {
+        "kernels": sorted(set(pallas_kernels(c))),
+        "temp_bytes": mem.temp_size_in_bytes,
+        "program_bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes,
+        "instructions": show(large_result_instructions(
+            c, pools["k"].size // La,
+            containing=(dcfg.cache.num_pages, 8, 64))),
+        "tails_instructions": show(large_result_instructions(
+            c, pools["conv_tail"].size // Lc, containing=(B + 1, 4096))),
+        "matrix_sized": show(large_result_instructions(c, matrix))}
+print(json.dumps(out))
+"""
+
+
+def test_the_hybrid_stage_copies_no_pool_and_no_expert_stack():
+    """The LFM2 cell's decode step and its longest prefill (1,024
+    tokens), compiled for a v5e at the committed configuration (a dense
+    layer unrolled, three periods of four layers under one scan): no
+    instruction but parameters, tuple plumbing and the aliased
+    ``apex_kv_write`` produces a value the size of one layer of ``k`` or
+    ``v`` (the pools have the THREE attention layers only); none
+    produces one as large as a period position's expert stack (the
+    stacks reach the grouped matmul whole, with the repeat's index); the
+    step's kernels are the convolution's step, the grouped attention and
+    its write, the grouped matmul and the head over 65,536 rows, the
+    prefill's the flash forward and the tails' install; and both hold
+    between 25% and 100% of the chip (12.3 GB: weights 9.21, K/V pool
+    3.02).  The 21 MB of convolution tails are NOT held to this: XLA
+    moves them between HBM and its fast memory space around the ten
+    in-place steps (``copy-start`` / ``slice-start`` of ``bf16[10, 257,
+    4096]``: 26 us each at the chip's bandwidth)."""
+    out = _programs_of(_LFM2_POOL_CHILD)
+    assert out["plan"] == [1, 4, 3, 0]
+    for name in ("decode_step", "prefill"):
+        bad = _moved(out[name]["instructions"])
+        assert not bad, (f"{name}: instructions that produce a value as "
+                         f"large as a layer of the pool: {bad}")
+        assert [n for n, op, _ in out[name]["instructions"]
+                if op == "custom-call"], f"{name}: no aliased kernel writes it"
+        bad = _moved(out[name]["matrix_sized"], _POOL_PLUMBING | {"bitcast"})
+        assert not bad, (f"{name}: instructions that produce a value as "
+                         f"large as an expert stack: {bad}")
+        assert [n for n, op, aliased in out[name]["tails_instructions"]
+                if op == "custom-call" and aliased], (
+            f"{name}: no aliased kernel writes the tails")
+        assert 0.25 * out["chip_bytes"] < out[name]["program_bytes"] \
+            < out["chip_bytes"]
+    assert {"apex_kda_conv_step", "apex_kv_write", "apex_decode_attention",
+            "apex_fused_sample", "gmm"} <= set(out["decode_step"]["kernels"])
+    assert {"apex_flash_fwd", "apex_slot_install", "apex_kv_write", "gmm"} \
+        <= set(out["prefill"]["kernels"])
+    assert out["decode_step"]["temp_bytes"] < 0.2e9
+    # 4,606,249,728 parameters in bf16, routers and gains in float32
+    assert 9.21e9 < out["weight_bytes"] < 9.22e9
+    assert out["pool_bytes"] == 3 * 3840 * 8 * 64 * 128 * 2
+    assert out["tail_bytes"] == 10 * 257 * 4096 * 2
+
+
 _EVA_POOL_CHILD = _DESCRIBED_V5E + """
 import jax.numpy as jnp
 from pathlib import Path
