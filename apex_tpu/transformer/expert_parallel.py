@@ -240,14 +240,19 @@ def route_group_limited(x, router_w, bias, *, top_k: int, n_group: int,
 
 
 #: (rows, contraction, columns) tile of the Pallas grouped matmul where
-#: a group is a fraction of a row tile or a few of them, the serving
-#: forms (:func:`grouped_tiling`): 88% of the HBM roofline at 64 live
-#: rows over 16 experts of 7168 x 2048 on a v5e, where XLA's own
-#: ``ragged_dot`` kernel reached 40% (PERF.md, PR 26)
+#: the plan (:func:`grouped_tiling`) has no better: ``tgmm`` and a row
+#: tile under 128 take it whole, a contraction too deep for one tile
+#: its ``tk``.  Until PR 48 every serving call had it (88% of the HBM
+#: roofline at 64 live rows over 16 experts of 7168 x 2048 on a v5e,
+#: where XLA's own ``ragged_dot`` kernel reached 40%: PERF.md, PR 26).
+#: With ``tk`` short of ``k`` the grid's innermost axis turns the rows'
+#: and the weights' block index at EVERY grid step: the rows are
+#: fetched again a step and a group's matrix again a VISIT (a (group,
+#: row tile) pair), twice where its rows straddle a row tile's edge
 GROUPED_TILING = (128, 1024, 1024)
 #: rows over groups (both static in a call) from which a group spans
-#: several row tiles and the tiles follow the rows it has: 1.3-102 in
-#: the serving programs, 1,280 in the train chunk (PERF.md, PR 46)
+#: several row tiles and the row tile is 256: 1.3-102 in the serving
+#: programs, 1,280 in the train chunk (PERF.md, PR 46)
 RESIDENT_ROWS_A_GROUP = 512
 #: what Mosaic lets a kernel's blocks take of VMEM when, as megablox,
 #: it asks for no other limit (v5e)
@@ -272,46 +277,64 @@ def grouped_tiling(product, rows, groups, k, n, dtype):
     ``k``, ``n``: the product's contraction and output widths (of
     "tgmm": the matrix's two); ``dtype``: the weights'.
 
-    A Pallas pipeline fetches a block again only when its index moves,
-    and ``gmm``'s weight block has the index ``(group, k_i, n_i)`` under
-    a grid ``(n tiles, row tiles, k tiles)``.  Where a group is a row
-    tile or less (decode, the block step) or a few (prefill), an
-    expert's matrix is read once whatever ``tk`` is, and the call gets
-    :data:`GROUPED_TILING` clipped to its widths, as every call did
-    until PR 46.  From :data:`RESIDENT_ROWS_A_GROUP` rows a group (the
-    training chunk) a ``tk`` short of ``k`` turns the index at every
-    grid step and streams the matrix once a ROW TILE, so ``gmm`` and
-    ``gmm_t`` then
+    A Pallas pipeline fetches a block again only when its index moves.
+    ``gmm``'s grid is ``(column tiles, visits, contraction tiles)``, a
+    VISIT one (group, row tile) pair that shares a row: as many as row
+    tiles with a live row plus live groups less one, unless an edge
+    falls on a tile's.  Its blocks' indices: rows ``(row tile, k_i)``,
+    weights ``(group, k_i, n_i)``, output ``(row tile, n_i)``.  With
+    ``tk`` short of ``k`` the innermost axis turns ``k_i`` at every grid
+    step, so the rows are fetched again at EVERY grid step and a
+    group's matrix streams once a VISIT: once a row tile in the
+    training chunk (ten of them a group), twice in a serving call
+    wherever a group's rows straddle a row tile's edge, and where
+    ``tk`` does not divide ``k`` every visit's last contraction tile
+    masks both operands through float32.  So ``gmm`` and ``gmm_t``
 
-    - contract in ONE tile (``tk = k``): the block stands for all of a
-      group's row tiles (0.93 -> 0.51 ms a call at 2,048 deep);
-    - take the widest columns of ``n``, 1,024, 512 that fit
+    - contract in ONE tile (``tk = k``): the weights' index moves once
+      a group (a straddling group's visits follow one another) and the
+      rows' once a row tile.  Alone on a v5e, a call: 0.93 -> 0.51 ms in
+      the train chunk at 2,048 deep (PR 46); 0.488 -> 0.409 ms at the
+      LFM2 decode step (32 groups of 31 rows, 2,048 x 1,792, seven
+      groups over an edge: 331 MB moved where 243 are owed), 0.319 ->
+      0.257 at Kimi's (2,304 deep: no remainder tile left), 0.592 ->
+      0.479 and 0.464 -> 0.340 at their prefills (PR 48);
+    - in the fewest EVEN column tiles of at least 512 that fit
       :data:`SCOPED_VMEM_BYTES` by :func:`grouped_vmem_bytes`: with
       ``tn = n`` the rows are read once, not once a column tile (0.55 ->
-      0.51 ms at 1,024 deep into 2,048);
-    - and a row tile of 256 where it divides ``rows`` (another 3%).
+      0.51 ms at 1,024 deep into 2,048), and two tiles of 896 read
+      faster than 1,024 and 768 (0.409 against 0.416 ms);
+    - a contraction too deep for that (7,168 beside 512 columns is 17.8
+      MiB) stays in tiles of :data:`GROUPED_TILING`'s ``tk`` under the
+      columns chosen the same way, all 2,048 at once: 0.714 -> 0.674 ms
+      a call, where one tile of 7,168 over 256 columns read 0.679 at
+      the decode step and no faster than the parent at a prefill;
+    - and from :data:`RESIDENT_ROWS_A_GROUP` rows a group a row tile of
+      256 where it divides ``rows`` (another 3% in the train chunk).
 
     ``tgmm`` keeps the plain tiles: a taller row tile read SLOWER (0.56
     -> 0.61 ms at 512 rows), and the one block that read faster, an
     expert's whole matrix, is past the limit by this arithmetic.  The
     readings: ``benchmarks/grouped_matmul_sweep.py`` on a v5e (PERF.md,
-    PR 46); which of the tilings that fit is fastest is the chip's to
-    say, and the sweep says it again for another shape."""
+    PRs 46 and 48); which of the tilings that fit is fastest is the
+    chip's to say, and the sweep says it again for another shape."""
     tm = next((t for t in (GROUPED_TILING[0], 64, 32, 16, 8)
                if rows % t == 0), None)
     if tm is None:
         return None
     plain = (tm, min(GROUPED_TILING[1], k), min(GROUPED_TILING[2], n))
-    if rows // groups < RESIDENT_ROWS_A_GROUP or tm < GROUPED_TILING[0] \
-            or product == "tgmm":
+    if tm < GROUPED_TILING[0] or product == "tgmm":
         return plain
-    tm = 256 if rows % 256 == 0 else tm
+    if rows // groups >= RESIDENT_ROWS_A_GROUP and rows % 256 == 0:
+        tm = 256
     # a sixteenth left for what the compiler keeps beside the blocks
     # (0.45 MiB beside a float32 tgmm's 16.0 on the described v5e)
-    return next((t for t in ((tm, k, n), (tm, k, 1024), (tm, k, 512))
-                 if t[2] <= n and grouped_vmem_bytes(
-                     product, t, jnp.dtype(dtype).itemsize)
-                 <= SCOPED_VMEM_BYTES * 15 // 16), plain)
+    fits = lambda t: grouped_vmem_bytes(
+        product, t, jnp.dtype(dtype).itemsize) <= SCOPED_VMEM_BYTES * 15 // 16
+    even = [n // c for c in range(1, max(n // 512, 1) + 1)
+            if n % (128 * c) == 0 or c == 1]
+    return next((t for tk in (k, plain[1]) for tn in even
+                 if fits(t := (tm, tk, tn))), plain)
 
 
 def _plain(impl) -> bool:

@@ -1,5 +1,7 @@
-"""The grouped matmuls of the trainable expert layer's chunk alone, over
-row tile x contraction tile x column tile, at the train cell's shape.
+"""The grouped matmuls of the expert layer alone, over row tile x
+contraction tile x column tile: the trainable layer's chunk at the train
+cell's shape, or (``--shape``, ``--draws serving``) a serving program's
+call.
 
 ``transformer/expert_parallel._chunk_ffn`` runs three grouped matmuls
 over a chunk's rows sorted by expert and its backward six more;
@@ -34,6 +36,30 @@ the compiler refused (a block past the scoped VMEM limit).  A
 ``metadata`` line a row tile is the XLA ops before the kernel alone
 (``make_group_metadata``), which every timing includes.
 
+**A serving program's call** (``--shape ROWS GROUPS HIDDEN WIDTH
+--draws serving --live-groups L --rows-a-group R``): the static buffer
+of a decode step, a block step or a prefill bucket over the STACKED
+layers' experts as groups, of which one layer's ``L`` get rows:
+``round(L * R)`` rows drawn as a router draws them (multinomial over the
+live groups, the other groups empty, edges wherever they fall, the
+buffer's tail dead).  A ``gmm`` line then also says what the draw costs
+at the candidate by megablox's own block indices (:func:`traffic`): the
+``visits`` ((group, row tile) pairs: the grid's middle axis), how many
+of them are a group's second or later (``straddling``), ``mb_owed``
+(the live groups' matrices, the live row tiles in and out, each once)
+and ``mb_moved`` (what the pipeline fetches, a block again whenever its
+index moves).  The calls of the four MoE serving cells (PERF.md, PR 48):
+
+    cell, program        --shape                 --live-groups --rows-a-group
+    lfm2 decode step     1024  96 2048 1792      32            31.4
+    lfm2 prefill 512     2048  96 2048 1792      32            64
+    sdar block step      1024 768 2048  768      16            20
+    sdar prefill 512     4096 768 2048  768      16            32
+    kimi decode step     1024 384 2304 1024      32            3.8
+    kimi prefill 2048   16384 384 2304 1024      32            64
+    gigachat decode step 1024  80 7168 2048      16            4
+    gigachat prefill 512 4096  80 7168 2048      16            16
+
     python benchmarks/grouped_matmul_sweep.py > chiprun_out/gmm_sweep.jsonl
     python benchmarks/grouped_matmul_sweep.py --compile-only   # no chip:
         # every candidate through the compile-only v5e client (libtpu
@@ -62,6 +88,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import (
 from apex_tpu.transformer import expert_parallel as ep
 
 PEAK_TFLOPS = 197.0   # TPU v5e, bf16 (Google Cloud documentation)
+PEAK_GBPS = 819.0     # its HBM (the same page)
 BF16, I32 = jnp.bfloat16, jnp.int32
 FILL = 0.791    # ``moe_buffer_fill.moe8k``: 1,012 rows an expert
 #: product: (kernel as ``grouped_tiling`` names it, whether the
@@ -73,9 +100,18 @@ PRODUCTS = {
 }
 
 
-def draw(name, rows, groups, seed):
-    """Group sizes that fill ``FILL`` of ``rows``, the tail dead."""
+def draw(name, rows, groups, seed, live_groups=None, rows_a_group=None):
+    """Group sizes that fill ``FILL`` of ``rows``, the tail dead; or
+    ("serving") ``live_groups * rows_a_group`` rows thrown at the live
+    groups alone, one layer's run of them in the middle of the stack."""
     rng = np.random.default_rng(seed)
+    if name == "serving":
+        live = min(rows, round(live_groups * rows_a_group))
+        sizes = np.zeros(groups, np.int64)
+        first = groups // live_groups // 2 * live_groups
+        sizes[first:first + live_groups] = rng.multinomial(
+            live, np.full(live_groups, 1.0 / live_groups))
+        return jnp.asarray(sizes, I32)
     near = lambda mean, n: mean + rng.integers(
         -(mean // 128), mean // 128 + 1, n)
     mean = int(rows * FILL) // groups
@@ -85,6 +121,38 @@ def draw(name, rows, groups, seed):
         sizes[2:] = near((mean * groups - sizes[1]) // (groups - 2),
                          groups - 2)
     return jnp.asarray(sizes, I32)
+
+
+def traffic(sizes, tiling, K, N, itemsize=2):
+    """What one ``gmm`` over ``sizes`` costs at ``tiling`` by megablox's
+    block indices, walked as its grid ``(column tiles, visits,
+    contraction tiles)`` walks them: rows ``(row tile, k_i)``, weights
+    ``(group, k_i, n_i)``, output ``(row tile, n_i)``; a block is
+    fetched (the output: written back) once a run of equal indices."""
+    tm, tk, tn = tiling
+    sizes = np.asarray(sizes)
+    ends = np.cumsum(sizes)
+    visit = [(g, t) for g, (a, b) in enumerate(zip(ends - sizes, ends))
+             if b > a for t in range(a // tm, -(-b // tm))]
+    tiles_k, tiles_n = -(-K // tk), -(-N // tn)
+    moved, last = dict.fromkeys(("lhs", "rhs", "out"), 0), {}
+    for n_i in range(tiles_n):
+        cols = min(tn, N - n_i * tn)        # a short last tile: what is there
+        for g, t in visit:
+            for k_i in range(tiles_k):
+                deep = min(tk, K - k_i * tk)
+                at = {"lhs": ((t, k_i), tm * deep),
+                      "rhs": ((g, k_i, n_i), deep * cols),
+                      "out": ((t, n_i), tm * cols)}
+                for name, (index, elements) in at.items():
+                    moved[name] += (index != last.get(name)) * elements
+                last = {name: index for name, (index, _) in at.items()}
+    live_groups = int(np.sum(sizes > 0))
+    row_tiles = len({t for _, t in visit})
+    mb = lambda elements: round(elements * itemsize / 1e6, 2)
+    return {"visits": len(visit), "straddling": len(visit) - live_groups,
+            "mb_owed": mb(live_groups * K * N + row_tiles * tm * (K + N)),
+            "mb_moved": mb(sum(moved.values()))}
 
 
 def widths(product, shape):
@@ -147,7 +215,9 @@ def candidates(product, shape, tms, parent_only):
     if parent_only:
         return [parent_tiling(tms[0], K, N)]
     tks = sorted({min(1024, K), K})
-    tns = sorted({min(512, N), min(1024, N), N})
+    # a half of the columns too where it tiles: two even column tiles
+    tns = sorted({min(256, N), min(512, N), min(1024, N), N}
+                 | ({N // 2} if N % 256 == 0 and N >= 1024 else set()))
     return list(itertools.product(tms, tks, tns))
 
 
@@ -177,7 +247,17 @@ def timed(fn, args, reps):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--products", nargs="+", default=list(PRODUCTS))
-    ap.add_argument("--draws", nargs="+", default=["even", "skewed"])
+    ap.add_argument("--draws", nargs="+", default=["even", "skewed"],
+                    choices=["even", "skewed", "serving"])
+    ap.add_argument("--shape", nargs=4, type=int, default=[20480, 16, 2048,
+                                                           1024],
+                    metavar=("ROWS", "GROUPS", "HIDDEN", "WIDTH"),
+                    help="rows of the buffer, stacked groups, the layer's "
+                    "hidden width, an expert's width")
+    ap.add_argument("--live-groups", type=int, help="serving: the groups "
+                    "of one layer, which alone get rows")
+    ap.add_argument("--rows-a-group", type=float, help="serving: the mean "
+                    "rows of a live group")
     ap.add_argument("--tm", nargs="+", type=int, default=[128, 256, 512])
     ap.add_argument("--reps", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
@@ -186,14 +266,17 @@ def main(argv=None):
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--interpret", action="store_true")
     args = ap.parse_args(argv)
-    # rows of a chunk, groups, hidden, an expert's width
-    shape = (512, 4, 256, 128) if args.interpret else (20480, 16, 2048, 1024)
+    if "serving" in args.draws and not (args.live_groups
+                                        and args.rows_a_group):
+        ap.error("--draws serving needs --live-groups and --rows-a-group")
+    shape = (512, 4, 256, 128) if args.interpret else tuple(args.shape)
     tms = [8, 32] if args.interpret else args.tm
     reps = 1 if args.interpret or args.compile_only else args.reps
     M, G = shape[:2]
     device = described_v5e() if args.compile_only else jax.devices()[0]
     sharding = jax.sharding.SingleDeviceSharding(device)
-    sizes = {name: draw(name, M, G, args.seed) for name in args.draws}
+    sizes = {name: draw(name, M, G, args.seed, args.live_groups,
+                        args.rows_a_group) for name in args.draws}
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 2)
     if not args.compile_only:
         for tm in tms:
@@ -216,6 +299,10 @@ def main(argv=None):
                     "plan": tiling == ep.grouped_tiling(kind, M, G, K, N,
                                                         BF16),
                     "device": device.device_kind}
+            if kind != "tgmm":
+                for name, s in sizes.items():
+                    line.update({f"{key}_{name}": value for key, value in
+                                 traffic(s, tiling, K, N).items()})
             try:
                 if args.compile_only:
                     jax.jit(many).lower(*[
@@ -232,6 +319,9 @@ def main(argv=None):
                         line[f"ms_{name}"] = round(ms, 4)
                         line[f"peak_share_{name}"] = round(
                             2.0 * live * K * N / (PEAK_TFLOPS * 1e9) / ms, 4)
+                        if f"mb_owed_{name}" in line:
+                            line[f"hbm_share_{name}"] = round(
+                                line[f"mb_owed_{name}"] / PEAK_GBPS / ms, 4)
             except Exception as err:    # what the compiler refuses
                 line["error"] = f"{type(err).__name__}: {err}"[-400:]
             print(json.dumps(line), flush=True)

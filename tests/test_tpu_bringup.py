@@ -218,6 +218,20 @@ def _grouped_matmul_train(rows=20480, hidden=2048, width=1024, held=16):
              ((held,), I32)])
 
 
+def _grouped_matmul_serve(rows, groups=96, hidden=2048, width=1792):
+    """A serving cell's expert layer over one static buffer, the
+    stacked layers' experts side by side as groups, forward only; by
+    default the LFM2 cell's (a decode step's 256 slots x top-4 or a
+    prompt bucket x top-4 over three repeats' 32 experts)."""
+    from apex_tpu.transformer.expert_parallel import grouped_gated_ffn
+
+    return (lambda x, wg, wu, wd, sizes: grouped_gated_ffn(
+                x, wg, wu, wd, sizes, impl="pallas"),
+            [((rows, hidden), BF16), ((groups, hidden, width), BF16),
+             ((groups, hidden, width), BF16), ((groups, width, hidden), BF16),
+             ((groups,), I32)])
+
+
 def _moe_combine(weighted, rows=20480, hidden=2048, tokens=16384, top_k=8):
     """The combine of one chunk of the train cell's expert layer as
     ``_held_chunks`` calls it: the grouped matmul's bf16 rows and their
@@ -514,6 +528,14 @@ CASES = {
                                "apex_fused_ce_dembed"}),
     "rms_norm_8k": (*_rms_norm(16384, 2048), {"apex_ln_fwd", "apex_ln_bwd"}),
     "grouped_matmul_train": (*_grouped_matmul_train(), {"gmm", "tgmm"}),
+    # the LFM2 serve cell's expert layer: the decode step's buffer and
+    # the 1,024-token bucket's
+    "grouped_matmul_serve_step": (*_grouped_matmul_serve(1024), {"gmm"}),
+    "grouped_matmul_serve_1024": (*_grouped_matmul_serve(4096), {"gmm"}),
+    # ... and the latent cell's: a contraction of 7,168, too deep for
+    # one tile, over five layers' 16 held experts
+    "grouped_matmul_serve_deep": (*_grouped_matmul_serve(
+        1024, groups=80, hidden=7168, width=2048), {"gmm"}),
     # ... and the combine of its rows: 20,480 rows of 2,048 into 16,384
     # tokens, weighted (forward) and bare (backward)
     "moe_combine_weighted": (*_moe_combine(True), {"apex_moe_combine"}),
@@ -845,6 +867,17 @@ print(json.dumps(out))
 """
 
 
+def _lowered_blocks(fn, avals):
+    """The blocks of both operands and of the output of every
+    ``pallas_call`` ``fn`` lowers to, sorted."""
+    calls = _pallas_calls(jax.make_jaxpr(fn)(
+        *[jax.ShapeDtypeStruct(s, d) for s, d in avals]).jaxpr)
+    return sorted(tuple(
+        tuple(d.block_size for d in m.block_shape if hasattr(d, "block_size"))
+        for m in call.params["grid_mapping"].block_mappings)
+        for call in calls)
+
+
 def test_the_train_chunks_grouped_matmuls_compile_at_their_own_tiles():
     """One chunk of the train cell's experts, forward and backward: nine
     megablox kernels, each at the tiles ``grouped_tiling`` plans for ITS
@@ -856,13 +889,7 @@ def test_the_train_chunks_grouped_matmuls_compile_at_their_own_tiles():
 
     fn, avals, _ = CASES["grouped_matmul_train"]
     (M, H), (G, _, F) = avals[0][0], avals[1][0]
-    calls = _pallas_calls(jax.make_jaxpr(fn)(
-        *[jax.ShapeDtypeStruct(s, d) for s, d in avals]).jaxpr)
-    # the blocks of both operands and of the output, as lowered
-    tiles = sorted(tuple(
-        tuple(d.block_size for d in m.block_shape if hasattr(d, "block_size"))
-        for m in call.params["grid_mapping"].block_mappings)
-        for call in calls)
+    tiles = _lowered_blocks(fn, avals)
     want = []
     for product, k, n, times in (("gmm", H, F, 2), ("gmm", F, H, 1),
                                  ("gmm_t", H, F, 1), ("gmm_t", F, H, 2),
@@ -885,6 +912,31 @@ def test_the_train_chunks_grouped_matmuls_compile_at_their_own_tiles():
     assert "error" not in out, out
     # the down projection's forward feeds nothing a sum's gradient needs
     assert sorted(out["kernels"]) == ["gmm"] * 5 + ["tgmm"] * 3
+
+
+@pytest.mark.parametrize("name,gate", [
+    ("grouped_matmul_serve_step", (128, 2048, 896)),
+    ("grouped_matmul_serve_1024", (128, 2048, 896)),
+    ("grouped_matmul_serve_deep", (128, 1024, 2048))])
+def test_a_serving_buffers_grouped_matmuls_lower_at_the_plans_tiles(name,
+                                                                    gate):
+    """A serving cell's three projections as lowered: each kernel's
+    blocks are what ``grouped_tiling`` plans for the call, the whole
+    contraction in one tile unless it is 7,168 deep
+    (``test_kernels_compile_for_v5e_without_a_chip`` hands the same
+    cases to Mosaic, which refuses a block set past the scoped VMEM
+    limit)."""
+    from apex_tpu.transformer.expert_parallel import grouped_tiling
+
+    fn, avals, _ = CASES[name]
+    (M, H), (G, _, F) = avals[0][0], avals[1][0]
+    tiles = _lowered_blocks(fn, avals)
+    assert grouped_tiling("gmm", M, G, H, F, BF16) == gate
+    want = []
+    for k, n, times in ((H, F, 2), (F, H, 1)):
+        tm, tk, tn = grouped_tiling("gmm", M, G, k, n, BF16)
+        want += [((tm, tk), (tk, tn), (tm, tn))] * times
+    assert tiles == sorted(want)
 
 
 # --------------------------------------------- the pool stays where it is
