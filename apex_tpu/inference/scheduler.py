@@ -138,7 +138,7 @@ import logging
 import time
 from collections import deque
 from functools import partial
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -453,6 +453,10 @@ class ContinuousBatchingScheduler:
         self._positions = np.zeros((B,), np.int32)
         self._tokens = np.zeros((B,), np.int32)  # the verify step's
         self._active = np.zeros((B,), bool)
+        #: the resident requests' trace ids, slot order: what the step
+        #: spans carry as ``trace_ids``; None where the resident set
+        #: changed since a traced step last asked
+        self._trace_ids: Optional[Tuple[str, ...]] = None
         #: the plain decode loop's one step in flight, and ITS token
         #: vector, on the device: the next launch's ``tokens`` argument
         #: (the last launched step's output, with first tokens written in)
@@ -618,15 +622,33 @@ class ContinuousBatchingScheduler:
         _metrics.inc("apex_serve_wedges_total",
                      help="decode steps the watchdog declared wedged")
 
-    def _active_trace_ids(self) -> List[str]:
+    def _active_trace_ids(self) -> Tuple[str, ...]:
         """Trace ids of the resident requests, slot order — stamped on
         the batch-level decode/verify spans so a per-request exemplar's
         ``trace_id`` joins to the specific steps that served it, not
-        just the whole-lifetime ``serve.request`` span."""
-        return [self._slots[i].request.trace_id
-                for i in range(self.dcfg.max_batch)
-                if self._active[i] and self._slots[i] is not None
-                and self._slots[i].request.trace_id is not None]
+        just the whole-lifetime ``serve.request`` span.  A scan of the
+        slots: ``_resident_trace_ids`` makes it once a change of the
+        resident set, not once a step."""
+        return tuple(
+            self._slots[i].request.trace_id
+            for i in np.flatnonzero(self._active).tolist()
+            if self._slots[i] is not None
+            and self._slots[i].request.trace_id is not None)
+
+    def _resident_trace_ids(self) -> Tuple[str, ...]:
+        """What a traced step span carries as ``trace_ids``: the tuple
+        held since the last traced step, scanned anew only after the
+        resident set changed (``_set_active``).  An untraced step never
+        asks, so with tracing off nothing is built."""
+        if self._trace_ids is None:
+            self._trace_ids = self._active_trace_ids()
+        return self._trace_ids
+
+    def _set_active(self, slot: int, active: bool) -> None:
+        """A slot joins or leaves the decode batch (admission's end,
+        eviction, preemption)."""
+        self._active[slot] = active
+        self._trace_ids = None
 
     def _record_occupancy(self) -> None:
         """Serving gauges on the current registry (the scope seam:
@@ -1154,7 +1176,7 @@ class ContinuousBatchingScheduler:
         self._block_steps[slot] = steps
         self._block_ends[slot] = end
         self._positions[slot] = keep
-        self._active[slot] = True
+        self._set_active(slot, True)
 
     def _prefill_done(self) -> None:
         self.stats["prefills"] += 1
@@ -1183,7 +1205,7 @@ class ContinuousBatchingScheduler:
                                        self.dcfg.ngram_min)
             s.proposer.extend(list(req.prompt) + [first])
         self._positions[slot] = len(req.prompt)  # where `first` caches
-        self._active[slot] = True
+        self._set_active(slot, True)
         if (req.max_new_tokens == 1
                 or (req.eos_id is not None and first == req.eos_id)):
             self._evict(slot)
@@ -1290,7 +1312,7 @@ class ContinuousBatchingScheduler:
         if s.cow_reserve is not None:
             self.allocator.free([s.cow_reserve])
         self._slots[slot] = None
-        self._active[slot] = False
+        self._set_active(slot, False)
         self._page_tables[slot] = 0
         self._positions[slot] = 0
         self._tokens[slot] = 0
@@ -1559,7 +1581,7 @@ class ContinuousBatchingScheduler:
         traced = _tracing.enabled()
         attrs = (dict(decode_step=self.stats["decode_steps"],
                       active=int((live if launching else prev.slots).sum()),
-                      trace_ids=self._active_trace_ids(),
+                      trace_ids=self._resident_trace_ids(),
                       prefills_before=self._prefills_since_step,
                       in_flight=overlapped)
                  if traced else {})
@@ -1779,7 +1801,7 @@ class ContinuousBatchingScheduler:
         verify_attrs = (dict(decode_step=self.stats["decode_steps"],
                              active=int(self._active.sum()),
                              draft_len=W - 1,
-                             trace_ids=self._active_trace_ids(),
+                             trace_ids=self._resident_trace_ids(),
                              prefills_before=self._prefills_since_step)
                         if _tracing.enabled() else {})
         verify_span = _tracing.span("serve.verify_step", **verify_attrs)

@@ -57,6 +57,15 @@ a readback; ``train.step.dispatch`` times the enqueue and says so).
 Every span has an ``id`` (process-monotonic) and a ``parent``: the id
 of the span that was open on the same thread when it started, or None.
 A retro-emitted span (:meth:`Tracer.emit`) names its parent itself.
+
+**Compilation is in the trace too.**  While a tracer is installed, every
+trace, lowering and backend compile (or cache read) JAX makes becomes a
+``compile.trace`` / ``compile.lower`` / ``compile.backend`` span with
+JAX's own endpoints, a child of the span open on the compiling thread
+(:class:`_CompileSpans`): the first ``serve.prefill`` of a bucket reads
+as its compile children plus the rest, and a stall in service names its
+cause.  With no tracer installed nothing is registered with
+``jax.monitoring``.
 """
 
 import itertools
@@ -198,6 +207,9 @@ class Tracer:
         self.started = 0
         self.finished = 0
         self.dropped = 0
+        #: compile events whose span could not be recorded (an error in
+        #: the listener, swallowed): a reader of the compile spans says so
+        self.compile_errors = 0
 
     # ----------------------------------------------------------- record
     def span(self, name: str, **attrs) -> _Span:
@@ -232,6 +244,15 @@ class Tracer:
             "id": next(_SPAN_IDS), "parent": parent,
             "attrs": {**step_context(), **attrs},
         })
+
+    def _compiled(self, name: str, start: float, end: float,
+                  attrs: Dict[str, Any]) -> None:
+        """One of JAX's compile events as a span (:class:`_CompileSpans`
+        calls this on the compiling thread): a child of the innermost
+        span open there that started before it."""
+        parent = next((s.id for s in reversed(self._open_stack())
+                       if s.ts <= start), None)
+        self.emit(name, start, end - start, parent=parent, **attrs)
 
     def add_listener(self, fn: Callable[[dict], None]) -> None:
         """Call ``fn(span_dict)`` on every finished span — the flight
@@ -359,19 +380,130 @@ def _rank() -> int:
     return metrics_rank()
 
 
+# ---------------------------------------------------------- compile spans
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
+class _CompileSpans:
+    """JAX's compile events, retro-recorded as spans of the installed
+    tracer through :meth:`Tracer.emit`.
+
+    JAX announces each trace, lowering and backend compile twice, on
+    the thread that makes it and on ``time.time()``, the tracer's
+    clock: a scalar at its start and a time span at its end, both with
+    the program's ``fun_name``.  The end becomes a span:
+
+    - ``compile.trace``: Python tracing of a jitted function to a jaxpr;
+    - ``compile.lower``: jaxpr to StableHLO, Pallas kernels included;
+    - ``compile.backend``: the backend compile, or the read of the
+      persistent cache in its place.  ``cache`` says which, from the
+      cache's own events on that thread since the last backend span:
+      ``"hit"`` (read), ``"miss"`` (compiled and written) or ``"off"``
+      (neither: no cache directory, or a compile JAX found too quick
+      or too small to keep).
+
+    ``parent`` is the innermost span open on the compiling thread that
+    started before the event did (``serve.prefill``,
+    ``train.step.dispatch``, ... or None), so a program is named by its
+    caller's attributes (a prefill bucket by ``padded_tokens``), and
+    after warm-up ANY compile child of a serving span is a program the
+    warm-up did not build: the cause of that span's stall.
+
+    **Whole programs only.**  JAX also announces the trace of every
+    jitted ``jnp`` function called inside a program's trace or inside a
+    lowering rule (thousands a warm-up).  Only an event that started
+    with no other compile event open on its thread is recorded; what it
+    contains is part of its time.  So the spans of one thread never
+    overlap, and there are at most three a program.
+
+    The listeners run only while something compiles; a steady step
+    never reaches them."""
+
+    def __init__(self):
+        self._local = threading.local()     # .depth, .cache
+        self._listening = False
+
+    def listen(self, on: bool) -> None:
+        """Register with ``jax.monitoring``, or take the listeners away
+        again."""
+        if on == self._listening:
+            return
+        from jax import monitoring
+
+        if on:
+            # a thread that was inside a compile when the last tracer
+            # left saw a start and no end: its depth does not carry over
+            self._local = threading.local()
+            monitoring.register_scalar_listener(self._on_start)
+            monitoring.register_event_time_span_listener(self._on_end)
+            monitoring.register_event_listener(self._on_cache)
+        else:
+            monitoring.unregister_scalar_listener(self._on_start)
+            monitoring.unregister_event_time_span_listener(self._on_end)
+            monitoring.unregister_event_listener(self._on_cache)
+        self._listening = on
+
+    def _on_start(self, event: str, value, **kw) -> None:
+        if event in _COMPILE_SPANS:
+            self._local.depth = getattr(self._local, "depth", 0) + 1
+
+    def _on_cache(self, event: str, **kw) -> None:
+        verdict = _CACHE_EVENTS.get(event)
+        if verdict is not None:
+            self._local.cache = verdict
+
+    def _on_end(self, event: str, start: float, end: float, **kw) -> None:
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        local = self._local
+        # listeners installed in the middle of a compile see an end
+        # without its start
+        depth = local.depth = max(getattr(local, "depth", 0) - 1, 0)
+        attrs = {}
+        if name == "compile.backend":   # the verdict is this compile's
+            attrs["cache"] = getattr(local, "cache", None) or "off"
+            local.cache = None
+        tracer = _TRACER
+        if depth or tracer is None:
+            return
+        attrs["fun_name"] = str(kw.get("fun_name"))
+        try:
+            tracer._compiled(name, start, end, attrs)
+        except Exception:  # noqa: BLE001 — observers never participate
+            tracer.compile_errors += 1
+
+
+_COMPILES = _CompileSpans()
+
+
+def _install(tracer: Optional[Tracer]) -> None:
+    """The one place the process tracer changes: JAX's compile events
+    are listened to exactly while there is one."""
+    global _TRACER
+    _TRACER = tracer
+    _COMPILES.listen(tracer is not None)
+
+
 # ------------------------------------------------------- global configure
 def configure(capacity: int = 4096,
               tracer: Optional[Tracer] = None) -> Tracer:
     """Install (and return) the process tracer; until this is called
     every :func:`span`/:func:`instant` is a no-op."""
-    global _TRACER
-    _TRACER = tracer if tracer is not None else Tracer(capacity=capacity)
+    _install(tracer if tracer is not None else Tracer(capacity=capacity))
     return _TRACER
 
 
 def disable() -> None:
-    global _TRACER
-    _TRACER = None
+    _install(None)
 
 
 def get_tracer() -> Optional[Tracer]:
@@ -394,14 +526,12 @@ class TracingScope:
         self._prev: Optional[Tracer] = None
 
     def __enter__(self) -> Tracer:
-        global _TRACER
         self._prev = _TRACER
-        _TRACER = self.tracer
+        _install(self.tracer)
         return self.tracer
 
     def __exit__(self, *exc):
-        global _TRACER
-        _TRACER = self._prev
+        _install(self._prev)
         return False
 
 
@@ -463,10 +593,14 @@ class TracedStep:
         self._attrs = dict(attrs or {})
 
     def __call__(self, *args, **kw):
+        # ONE call site, tracer or none: a Mosaic kernel's bytecode
+        # holds the lines of the Python frames that traced it, this one
+        # among them, and the persistent compile cache's key holds the
+        # bytecode, so a second call site was a second executable
+        # (PR 49: a traced run compiled the train step anew, 64 s)
         t = _TRACER
-        if t is None:
-            return self._fn(*args, **kw)
-        with t.span(self._name, dispatch=True, **self._attrs):
+        with (t.span(self._name, dispatch=True, **self._attrs)
+              if t is not None else _NOOP):
             return self._fn(*args, **kw)
 
     def __getattr__(self, name):

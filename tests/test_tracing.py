@@ -397,6 +397,20 @@ class TestTracedStep:
         assert rec["name"] == "train.step.dispatch"
         assert rec["attrs"]["dispatch"] is True
 
+    def test_the_wrapped_callable_has_one_call_site(self):
+        """Tracing on or off, the step is called from the same line: the
+        line is in every Mosaic kernel's bytecode, and with it in the
+        compile cache's key."""
+        import sys
+
+        lines = []
+        step = tracing.TracedStep(
+            lambda: lines.append(sys._getframe(1).f_lineno))
+        step()
+        with tracing.TracingScope():
+            step()
+        assert len(lines) == 2 and lines[0] == lines[1]
+
     def test_delegates_attributes_to_the_wrapped_callable(self):
         class FakeStep:
             def __call__(self, x):
@@ -1097,6 +1111,316 @@ class TestServeTraceJoin:
 # ------------------------------------------------ scheduler span tiling
 LEAVES = ("serve.admit", "serve.prefill", "serve.prefill_chunk",
           "serve.decode_step", "serve.verify_step", "serve.emit")
+
+# ------------------------------------------------------- compile spans
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_NAMES = ("compile.trace", "compile.lower", "compile.backend")
+
+
+def _compiles(spans, fun=None):
+    return [s for s in spans if s["name"] in COMPILE_NAMES
+            and (fun is None or fun in s["attrs"]["fun_name"])]
+
+
+def _our_listeners():
+    """What ``jax.monitoring`` holds of the tracer's."""
+    from jax._src import monitoring
+
+    held = (monitoring.get_event_listeners()
+            + monitoring.get_scalar_listeners()
+            + monitoring.get_event_time_span_listeners()
+            + monitoring.get_event_duration_listeners())
+    return [fn for fn in held
+            if getattr(fn, "__self__", None) is tracing._COMPILES]
+
+
+def _inside(child, parent, slack_s=1e-3):
+    end = lambda s: s["ts"] + s["dur_us"] / 1e6
+    return (parent["ts"] - slack_s <= child["ts"]
+            and end(child) <= end(parent) + slack_s)
+
+
+class TestCompileSpans:
+    """ISSUE 49: every trace, lowering and backend compile is a child
+    span of the call that caused it, with JAX's own endpoints; whole
+    programs only; nothing is registered while no tracer is."""
+
+    def test_a_first_call_leaves_three_children_of_its_span(self):
+        def cause_a(x):
+            return jnp.tanh(x) * 3.0
+
+        x = jnp.ones((4,))
+        with tracing.TracingScope() as tr:
+            with tracing.span("outer"):
+                with tracing.span("x", bucket=4) as sp:
+                    jax.jit(cause_a)(x).block_until_ready()
+        spans = tr.spans()
+        mine = _compiles(spans, "cause_a")
+        assert [s["name"] for s in mine] == list(COMPILE_NAMES)
+        caller = next(s for s in spans if s["name"] == "x")
+        for s in mine:
+            assert s["parent"] == sp.id == caller["id"]
+            assert s["ph"] == "X" and _inside(s, caller)
+            assert s["tid"] == caller["tid"]
+        # one after the other, as JAX made them
+        for a, b in zip(mine, mine[1:]):
+            assert a["ts"] + a["dur_us"] / 1e6 <= b["ts"] + 1e-3
+        backend = mine[-1]["attrs"]
+        assert sorted(backend) == ["cache", "fun_name"]
+        assert backend["cache"] in ("hit", "miss", "off")
+        assert "cache" not in mine[0]["attrs"]
+
+    def test_a_second_call_compiles_nothing_and_a_new_shape_recompiles(self):
+        def cause_b(x):
+            return jnp.tanh(x) - 1.0
+
+        f = jax.jit(cause_b)
+        small, large = jnp.ones((4,)), jnp.ones((8,))
+        with tracing.TracingScope() as tr:
+            with tracing.span("first"):
+                f(small).block_until_ready()
+            with tracing.span("second") as second:
+                f(small).block_until_ready()
+            with tracing.span("third") as third:
+                f(large).block_until_ready()
+        mine = _compiles(tr.spans(), "cause_b")
+        assert not [s for s in mine if s["parent"] == second.id]
+        # after warm-up any compile child of a span is a recompile, and
+        # the span it hangs under names the call that caused it
+        again = [s for s in mine if s["parent"] == third.id]
+        assert [s["name"] for s in again] == list(COMPILE_NAMES)
+        assert len(mine) == 6
+
+    def test_a_compile_outside_every_span_has_no_parent(self):
+        def cause_c(x):
+            return x + 2.0
+
+        x = jnp.ones((4,))
+        with tracing.TracingScope() as tr:
+            jax.jit(cause_c)(x).block_until_ready()
+        mine = _compiles(tr.spans(), "cause_c")
+        assert len(mine) == 3 and all(s["parent"] is None for s in mine)
+
+    def test_hundreds_of_jitted_primitives_are_one_program(self):
+        """JAX announces a trace for every jitted ``jnp`` function a
+        program's trace calls; only the program reaches the ring."""
+        announced = []
+
+        def count(event, value, **kw):
+            if event == TRACE_EVENT:
+                announced.append(kw.get("fun_name"))
+
+        def many(x):
+            for i in range(150):
+                x = jnp.where(x > i, jnp.sinc(x), jnp.logaddexp(x, 1.0 * i))
+            return x
+
+        x = jnp.ones((4,))
+        jax.monitoring.register_scalar_listener(count)
+        try:
+            with tracing.TracingScope(capacity=64) as tr:
+                jax.jit(many)(x).block_until_ready()
+        finally:
+            jax.monitoring.unregister_scalar_listener(count)
+        assert len(announced) > 100
+        found = _compiles(tr.spans())
+        assert [s["name"] for s in found] == list(COMPILE_NAMES)
+        assert tr.dropped == 0
+
+    def test_nothing_is_registered_without_a_tracer(self):
+        def cause_d(x):
+            return x * 5.0
+
+        x = jnp.ones((4,))
+        assert not _our_listeners()
+        jax.jit(cause_d)(x).block_until_ready()       # records nowhere
+        with tracing.TracingScope() as outer:
+            assert len(_our_listeners()) == 3
+            with tracing.TracingScope() as inner:
+                assert len(_our_listeners()) == 3
+            assert len(_our_listeners()) == 3
+            assert not inner.spans()
+        assert not _our_listeners()
+        assert not _compiles(outer.spans(), "cause_d")
+        tracing.configure()
+        tracing.configure()                    # a second tracer, one set
+        assert len(_our_listeners()) == 3
+        tracing.disable()
+        assert not _our_listeners()
+        tracing.disable()
+
+    @pytest.mark.parametrize("events, verdict", [
+        (("cache_hits",), "hit"), (("cache_misses",), "miss"), ((), "off")])
+    def test_backend_span_says_what_the_cache_did(self, events, verdict):
+        """The listener on JAX's own events, fired by hand: the cache's
+        verdict is the one since the last backend span."""
+        with tracing.TracingScope() as tr:
+            with tracing.span("caller") as sp:
+                t0 = time.time()
+                for name in events:
+                    jax.monitoring.record_event(
+                        f"/jax/compilation_cache/{name}")
+                jax.monitoring.record_scalar(BACKEND_EVENT, t0,
+                                             fun_name="jit(fired)")
+                jax.monitoring.record_event_time_span(
+                    BACKEND_EVENT, t0, t0 + 0.25, fun_name="jit(fired)")
+                jax.monitoring.record_scalar(BACKEND_EVENT, t0 + 0.25,
+                                             fun_name="jit(fired)")
+                jax.monitoring.record_event_time_span(
+                    BACKEND_EVENT, t0 + 0.25, t0 + 0.5,
+                    fun_name="jit(fired)")
+        first, second = _compiles(tr.spans(), "fired")
+        assert first["attrs"]["cache"] == verdict
+        assert second["attrs"]["cache"] == "off"      # used up
+        assert first["parent"] == sp.id
+        assert first["dur_us"] == pytest.approx(250000, abs=2)
+        assert first["ts"] == t0
+
+    def test_a_span_opened_after_the_compile_began_is_not_its_parent(self):
+        """The parent is the innermost span that was open when the
+        event STARTED."""
+        with tracing.TracingScope() as tr:
+            with tracing.span("caller") as caller:
+                t0 = time.time()
+                jax.monitoring.record_scalar(TRACE_EVENT, t0, fun_name="g")
+                time.sleep(0.002)
+                with tracing.span("late"):
+                    jax.monitoring.record_event_time_span(
+                        TRACE_EVENT, t0, time.time(), fun_name="g")
+        (g,) = _compiles(tr.spans(), "g")
+        assert g["parent"] == caller.id
+
+    def test_an_end_without_its_start_is_recorded_once(self):
+        """Listeners installed in the middle of a compile."""
+        with tracing.TracingScope() as tr:
+            t0 = time.time()
+            jax.monitoring.record_event_time_span(
+                TRACE_EVENT, t0, t0 + 0.1, fun_name="half")
+            jax.monitoring.record_event_time_span(
+                "/jax/some/other_duration", t0, t0 + 0.1)
+        assert [s["name"] for s in tr.spans()] == ["compile.trace"]
+
+    def test_a_start_the_last_tracer_left_open_hides_nothing_of_the_next(self):
+        """A tracer that leaves while a thread is inside a compile saw
+        the start and not the end; the next tracer starts from depth 0."""
+        t0 = time.time()
+        with tracing.TracingScope():
+            jax.monitoring.record_scalar(TRACE_EVENT, t0, fun_name="cut")
+        with tracing.TracingScope() as tr:
+            jax.monitoring.record_scalar(TRACE_EVENT, t0, fun_name="whole")
+            jax.monitoring.record_event_time_span(
+                TRACE_EVENT, t0, t0 + 0.1, fun_name="whole")
+        assert [s["attrs"]["fun_name"] for s in _compiles(tr.spans())] \
+            == ["whole"]
+
+    def test_an_error_in_the_listener_is_counted_not_raised(
+            self, monkeypatch):
+        def broken(self, *a, **kw):
+            raise RuntimeError("a bug in the recorder")
+
+        with tracing.TracingScope() as tr:
+            assert tr.compile_errors == 0
+            monkeypatch.setattr(tracing.Tracer, "_compiled", broken)
+            t0 = time.time()
+            jax.monitoring.record_event_time_span(
+                TRACE_EVENT, t0, t0 + 0.1, fun_name="lost")
+        assert tr.compile_errors == 1 and not _compiles(tr.spans())
+
+    def test_a_compile_on_another_thread_is_that_threads(self):
+        def cause_e(x):
+            return x - 7.0
+
+        x = jnp.ones((4,))
+
+        def work():
+            with tracing.span("worker.call"):
+                jax.jit(cause_e)(x).block_until_ready()
+
+        with tracing.TracingScope() as tr:
+            with tracing.span("main.call"):
+                t = threading.Thread(target=work, name="compiler")
+                t.start()
+                t.join(60)
+                assert not t.is_alive()
+        spans = tr.spans()
+        worker = next(s for s in spans if s["name"] == "worker.call")
+        mine = _compiles(spans, "cause_e")
+        assert len(mine) == 3
+        assert all(s["parent"] == worker["id"]
+                   and s["thread"] == "compiler" for s in mine)
+
+    def test_a_served_first_prefill_reads_as_its_compiles_and_the_rest(self):
+        """Every compile span of a traced serve run is a child of a
+        device call's span (or of none) and lies inside it; the first
+        prefill of the bucket has all three."""
+        done, spans, stats = _serve("slots")
+        assert len(done) == 5
+        by_id = {s["id"]: s for s in spans}
+        found = _compiles(spans)
+        assert found
+        callers = ("serve.prefill", "serve.decode_step",
+                   "serve.prepare_params", "serve.verify_step",
+                   "serve.prefill_chunk")
+        for s in found:
+            if s["parent"] is not None:
+                parent = by_id[s["parent"]]
+                # the admission pass itself owns one small program: the
+                # first token written into the device's vector, after
+                # its prefill's span has closed
+                assert parent["name"] in callers or (
+                    parent["name"] == "serve.admit"
+                    and "_set_token" in s["attrs"]["fun_name"]), parent
+                assert _inside(s, parent)
+        prefills = sorted((s for s in spans if s["name"] == "serve.prefill"),
+                          key=lambda s: s["ts"])
+        children = lambda p: sorted(
+            s["name"] for s in found if s["parent"] == p["id"]
+            and "prefill" in s["attrs"]["fun_name"])
+        assert children(prefills[0]) == sorted(COMPILE_NAMES)
+        assert prefills[0]["attrs"]["padded_tokens"] == 16
+        assert all(not children(p) for p in prefills[1:])
+        first = sum(s["dur_us"] for s in found
+                    if s["parent"] == prefills[0]["id"])
+        assert 0 < first <= prefills[0]["dur_us"]
+
+    def test_trace_ids_are_built_where_the_resident_set_changes(
+            self, monkeypatch):
+        """``serve.decode_step`` carries the resident ids without
+        scanning the slots every step: a scan after a change of the
+        resident set, and none at all with tracing off."""
+        from apex_tpu.inference import ContinuousBatchingScheduler
+
+        calls = []
+        scan = ContinuousBatchingScheduler._active_trace_ids
+
+        def counted(self):
+            calls.append(self.stats["decode_steps"])
+            return scan(self)
+
+        monkeypatch.setattr(ContinuousBatchingScheduler,
+                            "_active_trace_ids", counted)
+        with tracing.TracingScope() as tr:
+            sched = _tiny_scheduler(max_batch=2, num_pages=40)
+            done = _submit_and_drain(sched, n=3, plen=6, new=12)
+        assert len(done) == 3
+        steps = [s for s in tr.spans() if s["name"] == "serve.decode_step"
+                 and s["attrs"]["active"] > 0]
+        assert len(steps) >= 20
+        # at most one scan an admission and one an eviction, however
+        # many steps lie between them
+        assert 0 < len(calls) <= 2 * 3
+        ids = {c.trace_id for c in done}
+        for s in steps:
+            assert len(s["attrs"]["trace_ids"]) == s["attrs"]["active"]
+            assert set(s["attrs"]["trace_ids"]) <= ids
+        assert sched._trace_ids is None        # the last eviction's mark
+        assert sched._resident_trace_ids() == ()
+        del calls[:]
+        sched = _tiny_scheduler(max_batch=2, num_pages=40)   # no tracer
+        assert len(_submit_and_drain(sched, n=3, plen=6, new=12)) == 3
+        assert not calls and sched._trace_ids is None
+
 
 #: name -> scheduler settings and requests (count, prompt length, new
 #: tokens).  ``slots``: more requests than slots; ``pages``: slots to
