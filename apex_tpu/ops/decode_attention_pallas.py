@@ -27,10 +27,13 @@ gather, scores, online softmax, weighted sum — runs as ONE kernel:
   * a page of whole lane tiles (``page_size % 128 == 0``; the
     benchmark's cells): grid ``(rows, kv_heads // h_blk)``, the pools
     stay in HBM (``memory_space=ANY``) and :func:`_walk_kernel` copies
-    the ``ceil(length / page_size)`` live pages itself, double-buffered,
-    the next copy issued before the current one is waited for and the
-    last page of a row issuing the first page of the next live row: a
-    slot without a live position costs one grid step and no copy;
+    the ``ceil(length / page_size)`` live pages itself, through a ring
+    of ``depth`` VMEM slots (:func:`_plan`: 3 at every cell's shapes):
+    the copies run ``depth - 1`` live pages AHEAD of the page that is
+    attended, across rows and head blocks, because a page's arithmetic
+    takes 0.45 µs where its copy lands 0.75 µs after it is asked for
+    (PERF.md, PR 50).  A slot without a live position costs one grid
+    step and no copy;
   * a smaller page (lanes padded; Mosaic cannot slice such a page out
     of HBM by hand): grid ``(rows, kv_heads // h_blk, pages_per_seq)``
     with a BlockSpec whose index past the last live page names that
@@ -210,34 +213,49 @@ def decode_attention_xla(q, k_pool, v_pool, page_table, lengths,
 _VMEM_BUDGET = VMEM_BUDGET // 2
 
 
-def _head_bytes(group, head_dim, page_size, kv_dtype):
+#: the deepest ring of page slots :func:`_plan` gives the walk: the
+#: knee of the sweep (``benchmarks/decode_attention_walk.py``; PERF.md,
+#: PR 50): with three a page's arithmetic bounds the walk at the grouped
+#: cells' shapes and its copy at the others', and a fourth reads the same
+_MAX_DEPTH = 3
+
+
+def _head_bytes(group, head_dim, page_size, kv_dtype, depth=2):
     """VMEM that one kv head of a grid step's block costs: its page
-    tile four times over as a block (k and v, each double-buffered,
-    lanes padded to 128) and twice in f32 (both widened to an f32
-    query), and its group's f32 rows — running max, sum, accumulator,
-    the page's scores and probabilities."""
+    tile ``2 * depth`` times over as a block (k and v, a slot of each
+    for every page of the ring; lanes padded to 128) and twice in f32
+    (both widened to an f32 query), and its group's f32 rows — running
+    max, sum, accumulator, the page's scores and probabilities."""
     lanes = max(page_size, _LANES)
-    tile = head_dim * lanes * (4 * jnp.dtype(kv_dtype).itemsize + 2 * 4)
+    tile = head_dim * lanes * (2 * depth * jnp.dtype(kv_dtype).itemsize
+                               + 2 * 4)
     rows = 4 * max(group, 8) * (2 * _LANES + max(head_dim, _LANES)
                                 + 2 * lanes)
     return tile + rows
 
 
 def _plan(rows, h_kv, group, head_dim, pages_per_seq, page_size, kv_dtype):
-    """``(h_blk, grid)`` for these shapes: the kv heads a grid step
-    holds — the largest divisor of ``h_kv`` whose block fits the budget
-    — and the grid: ``(rows, h_kv // h_blk)`` where the kernel walks a
-    row's live pages itself (a page of whole lane tiles), with a third
-    dimension ``pages_per_seq`` where the grid does.  At GPT-2 large's
-    shapes (20 heads of 64, page 128, bf16) a block is all 20 heads: 20
-    grid steps a layer where one (row, head, page slot) a step made
-    3,200."""
-    fit = _VMEM_BUDGET // _head_bytes(group, head_dim, page_size, kv_dtype)
+    """``(h_blk, grid, depth)`` for these shapes: the kv heads a grid
+    step holds — the largest divisor of ``h_kv`` whose block fits the
+    budget with two page slots — the grid: ``(rows, h_kv // h_blk)``
+    where the kernel walks a row's live pages itself (a page of whole
+    lane tiles), with a third dimension ``pages_per_seq`` where the
+    grid does; and the page slots of the walk's ring: two, and as many
+    more as what the budget has left holds, up to ``_MAX_DEPTH`` (a
+    deeper ring never shrinks the head block; the grid's form is the
+    pipeline's two).  At GPT-2 large's shapes (20 heads of 64, page
+    128, bf16) a block is all 20 heads, three slots deep: 20 grid steps
+    a layer where one (row, head, page slot) a step made 3,200."""
+    shape = (group, head_dim, page_size, kv_dtype)
+    one = _head_bytes(*shape)
     h_blk = max(d for d in range(1, h_kv + 1)
-                if h_kv % d == 0 and d <= max(fit, 1))
+                if h_kv % d == 0 and d <= max(_VMEM_BUDGET // one, 1))
     grid = (rows, h_kv // h_blk)
-    return h_blk, grid if page_size % _LANES == 0 \
-        else grid + (pages_per_seq,)
+    if page_size % _LANES:
+        return h_blk, grid + (pages_per_seq,), 2
+    slot = h_blk * (_head_bytes(*shape, depth=3) - one)
+    spare = max(_VMEM_BUDGET - h_blk * one, 0)
+    return h_blk, grid, min(_MAX_DEPTH, 2 + spare // slot)
 
 
 def _half_lengths(len_ref, row):
@@ -311,18 +329,37 @@ def _attend(q, k, v, first, length, m_ref, l_ref, acc_ref, *, denom, scale):
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
+def _page_copies(pools, bufs, sem, where, slot):
+    """The copies of one pool page's k and v blocks into a slot of the
+    ring.  (To wait for one, any page will do: a wait reads the slot's
+    semaphore and size.)"""
+    return [pltpu.make_async_copy(hbm.at[where], buf.at[slot],
+                                  sem.at[j, slot])
+            for j, (hbm, buf) in enumerate(zip(pools, bufs))]
+
+
 def _walk_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
                  k_buf, v_buf, sem, m_ref, l_ref, acc_ref, state_ref, *,
                  h_blk, n_blk, rows, page_size, pages_per_seq, width, denom,
                  scale, halves):
     """One sequence row and one block of kv heads a grid step; the step
     walks the row's LIVE pages itself.  The pools stay in HBM; a page's
-    k and v blocks are copied into one of two VMEM slots, the copy of
-    the next page issued before this page's is waited for, and the last
-    page of a row issues the first page of the NEXT live row, so the
-    copies run back to back across rows.  ``state_ref`` (SMEM) carries
-    across grid steps the slot the next page goes to and whether this
-    row's first page is already in flight.
+    k and v blocks are copied into one of ``depth = len(k_buf)`` VMEM
+    slots, round robin, and the copies run AHEAD of the arithmetic: a
+    256 KB page's copy lands 0.75 µs after it is asked for, 0.32 of it
+    transfer, and its arithmetic takes 0.45 (PERF.md, PR 50), so
+    ``depth - 1`` pages are kept in flight beside the one attended.  The work is one list — for each row with a live
+    position, for each head block, its live pages — which the grid
+    consumes a (row, head block) a step and a page an iteration, and a
+    producer cursor names ``depth - 1`` pages further on: every page
+    step asks for the cursor's page before it waits for its own, so the
+    look-ahead crosses rows and head blocks (a row of two pages has its
+    successors' pages in flight while it is attended), skips rows
+    without a live position, and ends with the list: every copy that is
+    started is waited for.  ``state_ref`` (SMEM) carries across grid
+    steps the cursor — the (row, head block, page index) whose copy goes
+    out next and the slot it goes to — and the slot the arithmetic
+    reads next.
 
     The index arithmetic binds ``lax`` primitives directly: the kernel
     is traced with every decode program, and each ``jnp`` operator on a
@@ -330,34 +367,80 @@ def _walk_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
     warm-up took 0.35 s longer than the parent's on the chip's host,
     without 0.2 s; PERF.md, PR 27)."""
     i32 = np.int32
-    add, sub, mul, lt = lax.add, lax.sub, lax.mul, lax.lt
+    add, mul, lt = lax.add, lax.mul, lax.lt
+    depth = k_buf.shape[0]
     b, g = pl.program_id(0), pl.program_id(1)
     layer = layer_ref[0]
-    # with two halves the walk runs to the longer length and each half
-    # of the group's rows masks by its own
-    length, lengths = _row_lengths(len_ref, b, halves, q_ref.shape[3])
-    n = lax.min(lax.div(add(length, i32(page_size - 1)), i32(page_size)),
-                i32(pages_per_seq))
+
+    def pages_of(length):
+        return lax.min(lax.div(add(length, i32(page_size - 1)),
+                               i32(page_size)), i32(pages_per_seq))
+
+    def live_pages(row):
+        # with two halves the walk runs to the longer length
+        return pages_of(_longest(len_ref, row, halves))
+
+    def live_row_from(row):
+        """The first row at or after ``row`` with a live position
+        (``rows`` if there is none)."""
+        return lax.while_loop(
+            lambda r: lax.bitwise_and(
+                lt(r, i32(rows)),
+                lax.le(_longest(len_ref, lax.min(r, i32(rows - 1)), halves),
+                       i32(0))),
+            lambda r: add(r, i32(1)), row)
 
     def copies(page, blk, slot):
-        """The copies of one pool page's k and v blocks into a slot
-        (to wait for one, any page will do: a wait reads the slot's
-        semaphore and size)."""
         where = (layer, page) if n_blk == 1 else (
             layer, page, pl.ds(mul(blk, i32(h_blk)), h_blk))
-        return [pltpu.make_async_copy(hbm.at[where], buf.at[slot],
-                                      sem.at[j, slot])
-                for j, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                                (v_hbm, v_buf)))]
+        return _page_copies((k_hbm, v_hbm), (k_buf, v_buf), sem, where, slot)
 
-    def page_of(row, i):
-        return pt_ref[add(mul(lax.div(row, i32(width)), i32(pages_per_seq)),
-                          i)]
+    def next_slot(slot):
+        after = add(slot, i32(1))
+        return lax.select(lax.eq(after, i32(depth)), i32(0), after)
+
+    def ask_for_next():
+        """Start the copies of the page at the cursor, if the list has
+        one left, and move the cursor on."""
+        row, blk, i, slot = (state_ref[0], state_ref[1], state_ref[2],
+                             state_ref[3])
+
+        @pl.when(lt(row, i32(rows)))
+        def _ask():
+            page = pt_ref[add(mul(lax.div(row, i32(width)),
+                                  i32(pages_per_seq)), i)]
+            for dma in copies(page, blk, slot):
+                dma.start()
+            state_ref[3] = next_slot(slot)
+            more = lt(add(i, i32(1)), live_pages(row))
+            state_ref[2] = lax.select(more, add(i, i32(1)), i32(0))
+            # after a block's last page: the row's next head block, or
+            # the first block of the next row with a live position
+            if n_blk > 1:
+                same_row = lt(add(blk, i32(1)), i32(n_blk))
+                state_ref[1] = lax.select(
+                    more, blk, lax.select(same_row, add(blk, i32(1)), i32(0)))
+                more = lax.bitwise_or(more, same_row)
+
+            @pl.when(lax.bitwise_not(more))
+            def _next_row():
+                state_ref[0] = live_row_from(add(row, i32(1)))
 
     @pl.when(lax.eq(add(b, g), i32(0)))
-    def _reset():
-        state_ref[0] = i32(0)
-        state_ref[1] = i32(0)
+    def _start():
+        state_ref[0] = live_row_from(i32(0))
+        for j in range(1, 5):
+            state_ref[j] = i32(0)
+
+        def ask(_, carry):
+            ask_for_next()
+            return carry
+
+        lax.fori_loop(i32(0), i32(depth - 1), ask, i32(0))
+
+    # each half of the group's rows masks by its own length
+    length, lengths = _row_lengths(len_ref, b, halves, q_ref.shape[3])
+    n = pages_of(length)
 
     @pl.when(lax.eq(n, i32(0)))
     def _inactive():
@@ -366,60 +449,21 @@ def _walk_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     @pl.when(lax.gt(n, i32(0)))
     def _active():
-        first_slot = state_ref[0]
-
-        @pl.when(lax.eq(state_ref[1], i32(0)))
-        def _first_of_all():
-            for dma in copies(page_of(b, i32(0)), g, first_slot):
-                dma.start()
-
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        # the work after this one: this row's next head block, or the
-        # first block of the next row with a live position
-        if n_blk > 1:
-            same_row = lt(add(g, i32(1)), i32(n_blk))
-        else:
-            same_row = False
-        nxt = lax.while_loop(
-            lambda r: lax.bitwise_and(
-                lt(r, i32(rows)),
-                lax.le(_longest(len_ref, lax.min(r, i32(rows - 1)), halves),
-                       i32(0))),
-            lambda r: add(r, i32(1)), add(b, i32(1)))
-        has_next = lt(nxt, i32(rows))
-        next_row, next_blk = lax.min(nxt, i32(rows - 1)), i32(0)
-        if n_blk > 1:
-            has_next = lax.bitwise_or(same_row, has_next)
-            next_row = lax.select(same_row, b, next_row)
-            next_blk = lax.select(same_row, add(g, i32(1)), next_blk)
-        last = sub(n, i32(1))
-
         def page_step(i, slot):
-            # the next copy goes out BEFORE this page's is waited for:
-            # this row's next page, or after its last the next row's first
-            more = lt(i, last)
-            other = sub(i32(1), slot)
-
-            @pl.when(lax.bitwise_or(more, has_next))
-            def _next():
-                page = page_of(lax.select(more, b, next_row),
-                               lax.select(more, add(i, i32(1)), i32(0)))
-                for dma in copies(page, lax.select(more, g, next_blk),
-                                  other):
-                    dma.start()
-
+            # the ring is topped up BEFORE this page's copy is waited for
+            ask_for_next()
             for dma in copies(0, 0, slot):
                 dma.wait()
             _attend(q_ref[0, 0], k_buf[slot], v_buf[slot],
                     mul(i, i32(page_size)), lengths, m_ref, l_ref, acc_ref,
                     denom=denom, scale=scale)
-            return other
+            return next_slot(slot)
 
-        state_ref[0] = lax.fori_loop(i32(0), n, page_step, first_slot)
-        state_ref[1] = has_next.astype(jnp.int32)
+        state_ref[4] = lax.fori_loop(i32(0), n, page_step, state_ref[4])
         acc, l = acc_ref[:], l_ref[:, :, 0:1]
         if halves > 1:      # a dead half beside a live one: l == 0
             l = jnp.maximum(l, 1e-30)
@@ -494,7 +538,8 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
             f"({width})")
     group = H // h_kv
     halves = _lengths_per_row(lengths, group, width)
-    h_blk, grid = _plan(B, h_kv, group, D, P, page_size, k_pool.dtype)
+    h_blk, grid, depth = _plan(B, h_kv, group, D, P, page_size,
+                               k_pool.dtype)
     qg = q.reshape(B, h_kv // h_blk, h_blk, group, D)
     # clamp BEFORE prefetch: the index map output becomes a DMA source
     # address, where a garbage entry must hit the reserved garbage page,
@@ -512,7 +557,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
     ]
     if len(grid) == 2:      # the kernel walks the live pages itself
         pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-        tile = (2, h_blk, D, page_size)
+        tile = (depth, h_blk, D, page_size)
         kernel = functools.partial(
             _walk_kernel, h_blk=h_blk, n_blk=grid[1], rows=B,
             page_size=page_size, pages_per_seq=P, width=width,
@@ -525,8 +570,8 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths,
             scratch_shapes=[
                 pltpu.VMEM(tile, k_pool.dtype),
                 pltpu.VMEM(tile, v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ] + scratch + [pltpu.SMEM((2,), jnp.int32)],
+                pltpu.SemaphoreType.DMA((2, depth)),
+            ] + scratch + [pltpu.SMEM((5,), jnp.int32)],
         )
         semantics = ("arbitrary", "arbitrary")
     else:

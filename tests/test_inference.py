@@ -297,24 +297,80 @@ BLOCKED_CASES = {
     "mqa_bf16_cache_f32_query": (12, 1, _MIXED, None,
                                  {"pool_dtype": jnp.bfloat16}),
     "mqa_width3_straddle": (12, 1, _WIDTH3[:6], None, {"width": 3}),
+    # the walk's ring of page slots (PR 50; "depth": what the plan gives
+    # the case at page 128): lists shorter than the ring, of its depth
+    # and longer; rows without a live position before, between and after
+    # the live ones; a last row of one page
+    "ring_lists_shorter_than_depth": (4, 4, ((0, 7), (1, 1), (1, 0), (2, 0),
+                                             (0, 1), (1, 5)), None,
+                                      {"depth": 3}),
+    "ring_lists_of_depth": (4, 4, ((3, 0), (2, 1), (3, -3)), None,
+                            {"depth": 3}),
+    "ring_lists_longer_than_depth": (4, 4, ((5, 0), (9, 0), (6, 5), (8, 1)),
+                                     None, {"P": 9, "depth": 3}),
+    "ring_dead_rows_around": (4, 4, ((0, 0), (0, 0), (2, 3), (0, 0), (0, 0),
+                                     (0, 0), (6, 1), (1, 0), (0, 0), (3, 1),
+                                     (0, 0), (0, 0)), None,
+                              {"P": 7, "depth": 3}),
+    "ring_last_row_of_one_page": (16, 4, ((5, 0), (0, 0), (3, 3), (0, 9)),
+                                  None, {"P": 5, "depth": 3}),
+    "ring_one_live_row": (4, 4, ((0, 0), (0, 0), (2, 1), (0, 0)), None,
+                          {"depth": 3}),
+    # head blocks: the cursor goes through a row's blocks before the next
+    # live row; a budget of 4 heads leaves the third slot beside blocks
+    # of 3, one of 3 heads (or of 2 of 4) no slot beyond the two
+    "ring_depth3_head_blocks": (6, 6, ((0, 0), (7, 0), (0, 3), (0, 0),
+                                       (5, 1), (2, 0)), 4,
+                                {"P": 7, "depth": 3}),
+    "ring_depth2_head_blocks": (6, 6, ((3, 1), (0, 0), (7, 0), (0, 1)), 3,
+                                {"P": 7, "depth": 2}),
+    "ring_gqa_head_blocks_dead_rows": (16, 4, ((0, 0), (5, 1), (0, 0),
+                                               (1, 0), (6, 0), (0, 0)), 2,
+                                       {"P": 6, "depth": 2}),
+    "ring_mha5_one_head_a_block": (5, 5, ((2, 1), (0, 0), (6, 0), (0, 1)), 2,
+                                   {"P": 6, "depth": 3}),
+    # two lengths a row (the block step): the walk runs to the longer
+    # half; a dead half beside a live one, both dead, the first longer
+    "ring_two_lengths_dead_half": (16, 4, (((0, 0), (2, 1)),
+                                           ((3, 0), (0, 0)),
+                                           ((0, 0), (0, 0)),
+                                           ((1, 1), (6, 0)),
+                                           ((5, 2), (1, 0)),
+                                           ((0, 0), (0, 1))), None,
+                                   {"P": 6, "depth": 3}),
+    "ring_two_lengths_head_blocks": (16, 4, (((0, 0), (0, 0)),
+                                             ((4, 0), (5, 3)),
+                                             ((0, 0), (1, 0)),
+                                             ((2, 2), (0, 0))), 2,
+                                     {"P": 6, "depth": 2}),
+    "ring_width3_long_lists": (4, 4, ((5, -1), (5, 0), (5, 1), (0, 0),
+                                      (0, 0), (0, 0), (0, 1), (0, 2),
+                                      (0, 3), (7, -2), (7, -1), (7, 0)),
+                               None, {"width": 3, "P": 7, "depth": 3}),
+    "ring_width2_head_blocks": (6, 6, ((2, 0), (2, 1), (0, 0), (0, 0),
+                                       (6, -1), (6, 0)), 4,
+                                {"width": 2, "P": 6, "depth": 3}),
 }
 
 
 #: name: ((slots, q heads, kv heads, head dim, page, pages a sequence,
 #: pool pages, verify width), sha256 of the traced jaxpr's text, the
-#: Pallas kernel's inside it), taken at the commit BEFORE the kernels
-#: learnt to take two lengths a row (PR 45; a row's two lengths are a
-#: shape of ``lengths``, (B, 2), and these callers pass (B,)).  The
+#: Pallas kernel's inside it).  PR 45 taught the kernels to take two
+#: lengths a row (a shape of ``lengths``, (B, 2); these callers pass
+#: (B,)) and left these programs to the letter as they were; PR 50
+#: changed the walk on purpose (a ring of page slots, for every caller)
+#: and took the pins again from its final tree: the page-128 programs'
+#: moved, the small page's (the grid's form) did not.  The
 #: shapes are the cells': GPT-2 large (20 slots, 20 heads of 64),
 #: Falcon-H1 (96 slots, 20 query heads over 4 of 128), EvaByte (20 slots,
 #: 32 heads of 128), page 128, bf16; the verify layout; a page under 128
 #: lanes (the grid of page slots).  A PR that means to change what these
 #: callers run replaces the pins; one that does not may not move them.
 _ONE_LENGTH_JAXPRS = {
-    "gpt2-large": ((20, 20, 20, 64, 128, 8, 161, 1), "296502af29ff92ab"),
-    "falcon-h1": ((96, 20, 4, 128, 128, 20, 1024, 1), "89b4364b93b5f5e6"),
-    "evabyte": ((20, 32, 32, 128, 128, 4, 501, 1), "75f334fec120d60c"),
-    "verify4": ((8, 20, 20, 64, 128, 8, 161, 4), "c086c196e32f95ed"),
+    "gpt2-large": ((20, 20, 20, 64, 128, 8, 161, 1), "ed764d7f99e1d7c9"),
+    "falcon-h1": ((96, 20, 4, 128, 128, 20, 1024, 1), "9a6fe7bcc88f88b7"),
+    "evabyte": ((20, 32, 32, 128, 128, 4, 501, 1), "5e46b7ca0065c12d"),
+    "verify4": ((8, 20, 20, 64, 128, 8, 161, 4), "57c37bd537b56e65"),
     "small-page": ((4, 8, 2, 16, 16, 6, 40, 1), "1ef830047ca28e45"),
 }
 
@@ -324,7 +380,7 @@ def test_one_length_callers_trace_the_kernel_they_traced(name):
     """``decode_attention`` with one length a row, what ``gpt.py``,
     ``falcon_h1.py``, ``evabyte.py`` and the verify layout pass: the
     traced program, the Pallas kernel's jaxpr inside it, is to the
-    letter the one traced before ``lengths`` could be (B, 2).  The block
+    letter the pinned one, ONE kernel for all of them.  The block
     step's second length costs the other families' programs nothing, not
     an operation and not a second of tracing."""
     import hashlib
@@ -353,7 +409,11 @@ class TestDecodeAttentionBlocking:
     def test_blocked_kernel_matches_reference(self, name, page,
                                               monkeypatch):
         H, KVH, lengths, fit, kw = BLOCKED_CASES[name]
-        lengths = [pages * page + more for pages, more in lengths]
+        kw = dict(kw)
+        depth = kw.pop("depth", None)
+        # a length a row, or a pair of them a row ((B, 2))
+        lengths = np.asarray(lengths)
+        lengths = lengths[..., 0] * page + lengths[..., 1]
         width = kw.get("width", 1)
         q, kp, vp, pt, ln = _blocked_case(
             np.random.RandomState(len(name)), H, KVH, lengths,
@@ -366,10 +426,11 @@ class TestDecodeAttentionBlocking:
             h_kv_blocks = KVH // max(d for d in range(1, fit + 1)
                                      if KVH % d == 0)
             assert h_kv_blocks > 1
-        _, grid = dap._plan(len(lengths), KVH, H // KVH, q.shape[-1],
-                            pt.shape[1], page, kp.dtype)
+        _, grid, slots = dap._plan(len(lengths), KVH, H // KVH, q.shape[-1],
+                                   pt.shape[1], page, kp.dtype)
         assert grid == (len(lengths), h_kv_blocks) + (
             () if page == 128 else (pt.shape[1],))
+        assert slots == ((depth or slots) if page == 128 else 2)
         ref = decode_attention_xla(q, kp, vp, pt, ln, width=width, layer=1)
         out = paged_decode_attention_pallas(q, kp, vp, pt, ln, width=width,
                                             interpret=True, layer=1)
@@ -378,7 +439,7 @@ class TestDecodeAttentionBlocking:
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             rtol=0, atol=tol)
-        dead = np.asarray(lengths) == 0
+        dead = lengths.reshape(len(lengths), -1).max(axis=1) == 0
         assert float(np.abs(np.asarray(out, np.float32)[dead]).sum()) == 0.0
 
     @pytest.mark.parametrize("page", [8, 128])
@@ -418,6 +479,79 @@ class TestDecodeAttentionBlocking:
                     jnp.where(mask, poison, vp).astype(vp.dtype))
         assert np.isfinite(clean).all()
         np.testing.assert_array_equal(dirty, clean)
+
+    @pytest.mark.parametrize("H,KVH,width,fit,halves", [
+        (4, 4, 1, None, 1), (6, 6, 1, 4, 1), (16, 4, 3, None, 1),
+        (16, 4, 1, 2, 2)],
+        ids=["mha", "mha6_head_blocks", "gqa16_4_width3",
+             "gqa16_4_two_lengths_head_blocks"])
+    def test_every_copy_names_a_live_page(self, H, KVH, width, fit, halves,
+                                          monkeypatch):
+        """What the walk COPIES (a page of 128), from the copies
+        themselves: the source of every copy that is started is a live
+        page of its row, each once a walk of the row and head block, and
+        as many are waited for as were started.  The table's
+        entries past a row's last live page hold an id outside the pool
+        (the launcher's clamp makes it the pool's last page, a sentinel
+        no sequence owns) and a dead row's are negative (the garbage
+        page after the clamp): neither is ever a copy's source, though
+        the cursor runs ahead of the attended page across rows."""
+        page, P = 128, 6
+        first = [0, 0, 2 * page + 3, 1, 0, P * page - width, page, 0,
+                 4 * page - 1, 0]
+        lengths = np.array([(f + w + 1 if f else 0)
+                            for f in first for w in range(width)])
+        if halves == 2:     # the other half shorter, or the only live one
+            lengths = np.stack([lengths // 2, lengths], axis=1)
+            lengths[2], lengths[3] = lengths[2, ::-1], (0, 5)
+        q, kp, vp, pt, ln = _blocked_case(
+            np.random.RandomState(H + width), H, KVH, lengths, page=page,
+            P=P, width=width, pool_dtype=jnp.bfloat16, layers=2)
+        sentinel = kp.shape[1]               # one page more: no one's
+        pad = [(0, 0), (0, 1)] + [(0, 0)] * 3
+        kp, vp = jnp.pad(kp, pad), jnp.pad(vp, pad)
+        n_blk = 1
+        if fit is not None:
+            monkeypatch.setattr(dap, "_VMEM_BUDGET", fit * dap._head_bytes(
+                H // KVH, q.shape[-1], page, kp.dtype))
+            n_blk = dap._plan(len(lengths), KVH, H // KVH, q.shape[-1], P,
+                              page, kp.dtype)[1][1]
+            assert n_blk > 1
+        # the pages a q row walks (to the longer of two halves), and a
+        # sequence's live pages: those of the longest of its rows
+        walked = -(-lengths.reshape(len(lengths), -1).max(axis=1) // page)
+        pt = np.array(pt)
+        for row, n in zip(pt, walked.reshape(-1, width).max(axis=1)):
+            row[n:] = sentinel + 7 if n else -3
+        want = [int(x) for b, n in enumerate(walked)
+                for x in list(pt[b // width, :n]) * n_blk]
+        started, waits = [], []
+        page_copies = dap._page_copies
+
+        def recording(pools, bufs, sem, where, slot):
+            if isinstance(where[1], jax.core.Tracer):
+                jax.debug.callback(lambda x: started.append(int(x)),
+                                   where[1])
+            else:
+                jax.debug.callback(lambda x: waits.append(int(x)), slot)
+            return page_copies(pools, bufs, sem, where, slot)
+
+        monkeypatch.setattr(dap, "_page_copies", recording)
+        poison = np.zeros(kp.shape, bool)
+        poison[:, [GARBAGE_PAGE, sentinel]] = True
+        out = {}
+        for name, fill in (("clean", 0.0), ("dirty", np.nan)):
+            del started[:], waits[:]
+            out[name] = np.asarray(paged_decode_attention_pallas(
+                q, jnp.where(poison, fill, kp).astype(kp.dtype),
+                jnp.where(poison, fill, vp).astype(vp.dtype),
+                jnp.asarray(pt), ln, width=width, interpret=True, layer=1),
+                np.float32)
+            jax.effects_barrier()
+            assert sorted(started) == sorted(want)
+            assert len(waits) == len(want)
+        assert np.isfinite(out["clean"]).all()
+        np.testing.assert_array_equal(out["dirty"], out["clean"])
 
     @pytest.mark.parametrize("width", [1, 3])
     def test_block_index_names_live_pages_only(self, width):

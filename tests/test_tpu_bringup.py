@@ -65,6 +65,12 @@ def _decode_attn(heads, kv_heads, width, page=16, B=8, D=64, P=12,
 #: 20 kv heads of 64, page 128, 8 pages a sequence, the 36-layer
 #: stacked pool (a garbage page and every slot's pages) at a traced layer
 _CELL = dict(B=20, D=64, page=128, P=8, pages=161, layers=36)
+#: the kernel as two more serve cells run it, from their committed
+#: configurations: LFM2 (``agentgen-over``: 256 slots, 32 query heads
+#: over 8 of 64, 24 pages a sequence, the 3 attention layers' pool) and
+#: Falcon-H1 (96 slots, 20 over 4 of 128, 20 pages, 8 layers)
+_LFM2_CELL = dict(B=256, D=64, page=128, P=24, pages=3840, layers=3)
+_H1_CELL = dict(B=96, D=128, page=128, P=20, pages=1024, layers=8)
 
 
 def _kv_write(kv_heads, width, page=16, dtype=BF16, D=64):
@@ -403,6 +409,10 @@ CASES = {
     "decode_attn_mha12": (*_decode_attn(12, 12, 1), {"apex_decode_attention"}),
     "decode_attn_mha16": (*_decode_attn(16, 16, 1), {"apex_decode_attention"}),
     "decode_attn_gqa16_4": (*_decode_attn(16, 4, 1), {"apex_decode_attention"}),
+    "decode_attn_gqa32_8_lfm2_cell": (*_decode_attn(32, 8, 1, **_LFM2_CELL),
+                                      {"apex_decode_attention"}),
+    "decode_attn_gqa20_4_h1_cell": (*_decode_attn(20, 4, 1, **_H1_CELL),
+                                    {"apex_decode_attention"}),
     "decode_attn_mqa": (*_decode_attn(12, 1, 1), {"apex_decode_attention"}),
     "decode_attn_verify5": (*_decode_attn(12, 12, 5),
                             {"apex_decode_attention"}),
@@ -587,26 +597,63 @@ def test_decode_attention_grid_at_the_cells_shapes():
         return tuple(_lowered_grid_mapping(name).grid)
 
     B, P, page, heads = _CELL["B"], _CELL["P"], _CELL["page"], 20
-    h_blk, grid = _plan(B, heads, 1, _CELL["D"], P, page, BF16)
+    h_blk, grid, _ = _plan(B, heads, 1, _CELL["D"], P, page, BF16)
     assert h_blk == heads
     assert grid == (B, 1) == lowered_grid("decode_attn_cell")
     assert grid[0] * grid[1] <= B * P == 160
     # chip_smoke's shapes: page 16, 12 heads, 12 page slots a sequence
-    assert _plan(8, 12, 1, 64, 12, 16, BF16) == (12, (8, 1, 12))
+    assert _plan(8, 12, 1, 64, 12, 16, BF16) == (12, (8, 1, 12), 2)
     assert lowered_grid("decode_attn_mha12") == (8, 1, 12)
     # an fp32 cache at head dim 256 does not fit 20 heads a block: the
     # plan splits them, it does not overrun VMEM
-    h_blk, grid = _plan(B, heads, 1, 256, P, page, F32)
+    h_blk, grid, _ = _plan(B, heads, 1, 256, P, page, F32)
     assert 1 <= h_blk < heads and heads % h_blk == 0
     assert grid == (B, heads // h_blk)
     # a block step: a slot's 4 rows share their length, so they are one
     # grid step (32 query rows a key/value head, priced by the plan) and
     # the slot's pages are walked ONCE, not once a row
     c = _BLOCK_CELL
-    h_blk, grid = _plan(c["B"], c["kv"], c["W"] * c["heads"] // c["kv"],
-                        c["D"], c["P"], c["page"], BF16)
+    h_blk, grid, _ = _plan(c["B"], c["kv"], c["W"] * c["heads"] // c["kv"],
+                           c["D"], c["P"], c["page"], BF16)
     assert (h_blk, grid) == (c["kv"], (c["B"], 1))
     assert lowered_grid("block_attn_cell") == (c["B"], 1)
+
+
+#: name: ((rows, kv heads, group, head dim, pages a sequence, dtype),
+#: the head block and the ring's depth ``_plan`` gives): the five cells
+#: that call the walk (the block cell with two blocks a slot folded into
+#: the group), and shapes whose head block leaves the ring no third slot
+_RING_PLANS = {
+    "gpt2-large": ((20, 20, 1, 64, 8, BF16), (20, 3)),
+    "lfm2-agentgen": ((256, 8, 4, 64, 24, BF16), (8, 3)),
+    "falcon-h1": ((96, 4, 5, 128, 20, BF16), (4, 3)),
+    "sdar-blockgen": ((64, 4, 64, 128, 10, BF16), (4, 3)),
+    # 16 of 32 heads a block use 4.5 MiB of 8: three more slots of 1 MiB
+    # would fit, three in all is the deepest ring
+    "evabyte": ((20, 32, 1, 128, 25, BF16), (16, 3)),
+    # 20 fp32 heads of 128 fill the budget (20.3 fit): nothing beside them
+    "no-room-20-fp32-heads": ((8, 20, 1, 128, 8, F32), (20, 2)),
+    # 15 of 30 such heads a block: room for the third slot
+    "one-more-slot-15-of-30": ((8, 30, 1, 128, 8, F32), (15, 3)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_RING_PLANS))
+def test_decode_attention_ring_depth_at_the_cells_shapes(name):
+    """The walk's ring is as deep as the VMEM budget allows beside the
+    head block that was planned without it, three slots at most (the
+    sweep's knee) and never under two: 3 at every cell's shapes
+    (PERF.md, PR 50)."""
+    from apex_tpu.ops.decode_attention_pallas import (
+        _VMEM_BUDGET, _head_bytes, _plan)
+
+    (rows, kv, group, D, P, dtype), want = _RING_PLANS[name]
+    h_blk, grid, depth = _plan(rows, kv, group, D, P, 128, dtype)
+    assert (h_blk, depth) == want and grid == (rows, kv // h_blk)
+    assert h_blk * _head_bytes(group, D, 128, dtype, depth) <= _VMEM_BUDGET
+    if depth < 3:
+        assert h_blk * _head_bytes(group, D, 128, dtype, depth + 1) \
+            > _VMEM_BUDGET
 
 
 def _dispatched(Sq, Sk, D, phase, block_q=None, block_k=None):
